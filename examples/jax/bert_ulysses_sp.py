@@ -40,7 +40,7 @@ def main():
 
     import horovod_tpu as hvd
     from horovod_tpu.models import bert
-    from horovod_tpu.ops._compat import shard_map
+    from jax import shard_map
     from horovod_tpu.parallel.sequence import ulysses_attention
 
     hvd.init()

@@ -45,7 +45,7 @@ def main():
     import horovod_tpu as hvd
     from horovod_tpu.models import llama
     from horovod_tpu.models import layers as L
-    from horovod_tpu.ops._compat import shard_map
+    from jax import shard_map
     from horovod_tpu.parallel.sequence import make_ring_attn_fn
 
     hvd.init()

@@ -20,12 +20,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvd
 from horovod_tpu.checkpoint import CheckpointManager
 from horovod_tpu.models import resnet
-from horovod_tpu.ops._compat import shard_map
 from horovod_tpu.parallel.data_parallel import replicate, shard_batch
 
 

@@ -47,9 +47,17 @@ from .functions import (broadcast_parameters, broadcast_optimizer_state,
                         broadcast_object, allgather_object)
 from .checkpoint import (CheckpointManager, save_checkpoint,
                          restore_checkpoint)
-from .ops.flash_attention import flash_attention
 from .runner.api import run
-from .utils.probe import probe_backend
+
+
+def __getattr__(name):
+    # hvd.flash_attention loads on first use: importing Pallas costs about
+    # a second, and every launcher, worker and test process imports this
+    # package, while only the ones that run the kernel need it.
+    if name == "flash_attention":
+        from .ops.flash_attention import flash_attention
+        return flash_attention
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
 # ---------------------------------------------------------------- topology API
@@ -249,6 +257,6 @@ __all__ = [
     "start_timeline", "stop_timeline", "profiler", "tune",
     "CheckpointManager", "save_checkpoint", "restore_checkpoint",
     "flash_attention", "run",
-    "__version__", "probe_backend", "metrics_snapshot", "chaos",
+    "__version__", "metrics_snapshot", "chaos",
     "postmortem", "serve", "perf", "perf_report", "watch", "sentinel",
 ]

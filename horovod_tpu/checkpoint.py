@@ -29,6 +29,14 @@ from typing import Any, Dict, Optional
 
 import jax
 
+# No file of a checkpoint outgrows _DATA_FILE_BYTES + _CHUNK_BYTES: arrays
+# are cut into chunks and OCDBT starts a new data file once one is full.
+# Orbax's defaults write a shard as one chunk and pack up to 2 GiB into a
+# file, which a host with a per-file size limit (RLIMIT_FSIZE) refuses
+# with EFBIG — llama-1b's 3 GB of params landed in two files of ~1 GB.
+_CHUNK_BYTES = 4 << 20
+_DATA_FILE_BYTES = 8 << 20
+
 
 class CheckpointManager:
     """Thin orbax CheckpointManager with framework conventions."""
@@ -55,7 +63,12 @@ class CheckpointManager:
         for name, tree in dict(params=params, opt_state=opt_state,
                                **extra_trees).items():
             if tree is not None:
-                items[name] = ocp.args.StandardSave(tree)
+                items[name] = ocp.args.PyTreeSave(
+                    tree,
+                    save_args=jax.tree_util.tree_map(
+                        lambda _: ocp.SaveArgs(chunk_byte_size=_CHUNK_BYTES),
+                        tree),
+                    ocdbt_target_data_file_size=_DATA_FILE_BYTES)
         if meta:
             # Pickle-in-json keeps the full type surface (numpy scalars,
             # tuples, any picklable) that a plain JSON payload would narrow
@@ -91,7 +104,10 @@ class CheckpointManager:
                         x.shape, x.dtype,
                         sharding=getattr(x, "sharding", None))
                     if hasattr(x, "shape") else x, tree)
-                items[name] = ocp.args.StandardRestore(template)
+                items[name] = ocp.args.PyTreeRestore(
+                    item=template,
+                    restore_args=ocp.checkpoint_utils.construct_restore_args(
+                        template))
         # Only request items the checkpoint actually has (a blanket
         # try/except here would mask real restore failures and re-run the
         # whole sharded read).

@@ -119,7 +119,7 @@ def _compiled(mesh_id: int, kind: str, **static) -> Any:
     axes = _mesh_axes(mesh)
     spec = _flat_spec(mesh)
 
-    from ._compat import shard_map
+    from jax import shard_map
 
     def annotate(jitted):
         # NVTX-range analog (reference: nvtx_op_range.h wraps every

@@ -15,9 +15,9 @@ for the MXU:
   * same signature as layers.causal_attention ([B, S, H, D], GQA by
     head-count ratio) so models swap it in via ``attn_fn``.
 
-Off-TPU (tests, CPU smoke) the kernel runs in Pallas interpret mode —
-same code path, numerics checked against the XLA reference
-implementation.  Ring attention (parallel/sequence.py) composes with it:
+On the ``cpu`` backend (tests, CPU smoke) the kernel runs in Pallas
+interpret mode — same code path, numerics checked against the XLA
+reference implementation.  Ring attention (parallel/sequence.py) composes with it:
 each ring step's local block attention can use this kernel.
 """
 
@@ -37,10 +37,7 @@ NEG_INF = -1e30
 # sequential online-softmax walk over K/V lives in an in-kernel
 # fori_loop, not on the grid — so Mosaic may pipeline/reorder grid
 # iterations freely.  Ignored in interpret mode.
-# (CompilerParams was spelled TPUCompilerParams before jax 0.5.x.)
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-_GRID_SEMANTICS = _CompilerParams(
+_GRID_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "parallel"))
 
 
@@ -231,8 +228,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     blockwise in two kernels (dq; dk+dv) — the flash-attention backward
     algorithm, no [S, S] score matrix in either direction.
 
-    ``interpret=None`` auto-selects: compiled on TPU backends, Pallas
-    interpreter elsewhere (numerics-identical, for tests/CPU smoke)."""
+    ``interpret=None`` interprets only on the ``cpu`` backend (tests,
+    CPU smoke); every other backend compiles the kernel or raises."""
     return _flash_forward(q, k, v, causal, block_q, block_k, interpret)[0]
 
 
@@ -249,7 +246,7 @@ def _flash_bwd_rule(causal, block_q, block_k, interpret, res, g):
 
 def _resolve_blocks(S, block_q, block_k, interpret):
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = jax.default_backend() == "cpu"
     block_q = min(block_q, S)
     block_k = min(block_k, S)
     if S % block_q or S % block_k:

@@ -117,9 +117,7 @@ def _sync_impl(grads: Any,
     from . import runtime as _rt
     if isinstance(axis_name, str) and _rt.is_initialized():
         try:
-            # bound in this trace? (axis_size is missing on older jax;
-            # axis_index raises the same NameError when unbound)
-            getattr(jax.lax, "axis_size", jax.lax.axis_index)(axis_name)
+            jax.lax.axis_size(axis_name)  # bound in this trace?
         except NameError:
             from .parallel.hierarchical import resolve_axis
             try:

@@ -24,10 +24,10 @@ from typing import Any, Callable, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import optax
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..common.reduce_op import ReduceOp, Average
-from ..ops._compat import shard_map
 from ..ops.compression import Compression, Compressor
 from ..optimizer import distributed_optimizer
 from .hierarchical import resolve_axis

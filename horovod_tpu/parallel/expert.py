@@ -23,10 +23,8 @@ from typing import Any, Callable, Dict
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from ..ops._compat import shard_map
 
 
 def init_moe_params(key, dim: int, hidden: int, n_experts: int,

@@ -115,11 +115,8 @@ def hierarchical_allreduce(x: jax.Array,
     flat = jnp.ravel(x)
     n = flat.shape[0]
     # Axis sizes are static at trace time inside shard_map/pjit.
-    # (lax.axis_size is missing on older jax; psum(1, axis) is concrete
-    # at trace time inside shard_map the same way.)
-    _axis_size = getattr(lax, "axis_size", lambda a: lax.psum(1, a))
-    ici = int(_axis_size(ici_axis))
-    dcn = int(_axis_size(dcn_axis))
+    ici = int(lax.axis_size(ici_axis))
+    dcn = int(lax.axis_size(dcn_axis))
     pad = (-n) % ici
     if pad:
         flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
@@ -163,9 +160,8 @@ def dcn_selective_int8_allreduce(x: jax.Array,
     shape, dtype = x.shape, x.dtype
     flat = jnp.ravel(x).astype(jnp.float32)
     n = flat.shape[0]
-    _axis_size = getattr(lax, "axis_size", lambda a: lax.psum(1, a))
-    ici = int(_axis_size(ici_axis))
-    dcn = int(_axis_size(dcn_axis))
+    ici = int(lax.axis_size(ici_axis))
+    dcn = int(lax.axis_size(dcn_axis))
     pad = (-n) % ici
     if pad:
         flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])

@@ -51,11 +51,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..common.reduce_op import ReduceOp, Average
-from ..ops._compat import shard_map
 from ..perf import costmodel as _cm
 from . import zero as _zero
 from .pipeline import _spmd_pipeline, stack_stage_params

@@ -33,10 +33,8 @@ from typing import Any, Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from ..ops._compat import shard_map
 
 
 def stack_stage_params(stage_params: Sequence[Any]) -> Any:

@@ -84,15 +84,11 @@ def _block_attend(q, k, v, q_off, k_off, causal: bool,
 
 def _pvary_missing(t, axis_name):
     """Mark ``t`` varying over ``axis_name`` so fori_loop carry types line
-    up when the initial value is device-invariant (newer-JAX vma typing;
-    no-op on older JAX)."""
-    if not hasattr(lax, "pvary"):
-        return t
+    up when the initial value is device-invariant (vma typing)."""
     axes = ((axis_name,) if isinstance(axis_name, str)
             else tuple(axis_name))
-    vma = getattr(jax.typeof(t), "vma", frozenset())
-    missing = tuple(a for a in axes if a not in vma)
-    return lax.pvary(t, missing) if missing else t
+    missing = tuple(a for a in axes if a not in jax.typeof(t).vma)
+    return lax.pcast(t, missing, to="varying") if missing else t
 
 
 def _flash_ring_step(q, kk, vv, src, idx, block_q, block_k):
@@ -264,14 +260,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     o0 = jnp.zeros_like(q, dtype=jnp.float32)
     # The carries become device-varying inside the loop (they mix with q);
     # mark the initial values varying so the fori_loop types line up.
-    if hasattr(lax, "pvary"):
-        axes = (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
-
-        def _varying(t):
-            vma = getattr(jax.typeof(t), "vma", frozenset())
-            missing = tuple(a for a in axes if a not in vma)
-            return lax.pvary(t, missing) if missing else t
-        m0, l0, o0 = _varying(m0), _varying(l0), _varying(o0)
+    m0, l0, o0 = (_pvary_missing(t, axis_name) for t in (m0, l0, o0))
     q_off = idx * Sq
     perm = [(i, (i + 1) % n) for i in range(n)]
 

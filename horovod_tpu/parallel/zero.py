@@ -74,12 +74,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax import lax
+from jax import lax, shard_map
 from jax.flatten_util import ravel_pytree
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..common.reduce_op import ReduceOp, Average
-from ..ops._compat import shard_map
 from .hierarchical import resolve_axis
 
 ZERO_LEVELS = (1, 2, 3)

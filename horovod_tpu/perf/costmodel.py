@@ -8,15 +8,15 @@ roofline-style *predicted* step time per link class, which the ledger
 (``perf/ledger.py``) holds against the *measured* decomposition — so the
 model's own drift is observable (``docs/profiling.md``).
 
-One source of truth: ``bench.py``'s MFU math (``PEAK_TFLOPS``, the
-6·N FLOPs/token convention) lives HERE and is imported by the bench, the
-ledger and the tests — the constants can no longer fork.
+One source of truth: ``bench.py``'s MFU math (the ``PEAKS`` table keyed
+by ``device_kind``, the 6·N FLOPs/token convention) lives HERE and is
+imported by the bench, the ledger and the tests — the constants can no
+longer fork.
 
 Deliberately stdlib-only at module level (no jax, no package-relative
-imports), so ``bench.py``'s light supervisor and ``scripts/perf_gate.py``
-can load this file standalone by path, the way ``bench.py`` loads
-``utils/probe.py``.  Functions that consume jax objects (bucket plans,
-compiled programs) import lazily inside their bodies.
+imports), so ``scripts/perf_gate.py`` can load this file standalone by
+path.  Functions that consume jax objects (bucket plans, compiled
+programs) import lazily inside their bodies.
 """
 
 from __future__ import annotations
@@ -25,15 +25,25 @@ import math
 from typing import Any, Dict, List, Optional
 
 # ---------------------------------------------------------------- hardware
-# bf16 peak TFLOP/s per chip by TPU generation (public spec sheets).
-# 'cpu' is nominal so CPU-virtual smoke runs produce a finite ratio.
-PEAK_TFLOPS: Dict[str, float] = {
-    "v4": 275.0,
-    "v5e": 197.0,
-    "v5p": 459.0,
-    "v6e": 918.0,
-    "cpu": 0.5,
+# Published per-chip peaks, keyed by jax's ``device_kind`` (the spellings
+# are jax's own: jax/_src/pallas/mosaic/tpu_info.py).  The ONE table: a
+# kind that is not in it is an error, never a default.
+PEAKS: Dict[str, Dict[str, Any]] = {
+    "TPU v4": {"bf16_tflops": 275.0, "hbm_gbps": 1200.0, "hbm_gb": 32.0,
+               "source": "Google Cloud documentation, 'TPU v4'"},
+    "TPU v5 lite": {"bf16_tflops": 197.0, "hbm_gbps": 819.0, "hbm_gb": 16.0,
+                    "source": "Google Cloud documentation, 'TPU v5e'"},
+    "TPU v5p": {"bf16_tflops": 459.0, "hbm_gbps": 2765.0, "hbm_gb": 95.0,
+                "source": "Google Cloud documentation, 'TPU v5p'"},
+    "TPU v6 lite": {"bf16_tflops": 918.0, "hbm_gbps": 1640.0, "hbm_gb": 32.0,
+                    "source": "Google Cloud documentation, 'TPU v6e'"},
 }
+
+# What the predictive model below prices a CPU-virtual host at, so the
+# layout solver and the drift gauges have a compute term to rank with on
+# the virtual test mesh.  A modelling constant, not a peak: no
+# utilization is ever computed against it.
+CPU_MODEL_TFLOPS = 0.5
 
 # Per-chip link bandwidth by fabric class, GB/s (order-of-magnitude public
 # figures: ICI ~ hundreds of GB/s per chip, DCN ~ tens, loopback is a
@@ -48,9 +58,25 @@ LINK_GBPS: Dict[str, float] = {
 LINK_CLASSES = tuple(sorted(LINK_GBPS))
 
 
+def device_peaks(device_kind: str) -> Dict[str, Any]:
+    """One chip's published peaks by ``device_kind``; a kind outside the
+    table raises (there is no chip to fall back to)."""
+    if device_kind not in PEAKS:
+        raise ValueError(
+            f"device_kind {device_kind!r} is not in the peaks table "
+            f"(horovod_tpu/perf/costmodel.py PEAKS: {', '.join(PEAKS)}); "
+            "add its published peaks with their source before computing "
+            "a utilization on it")
+    return PEAKS[device_kind]
+
+
 def peak_flops(chip: str) -> float:
-    """Chip name -> peak FLOP/s (falls back to v5e like bench.py)."""
-    return PEAK_TFLOPS.get(chip, PEAK_TFLOPS["v5e"]) * 1e12
+    """FLOP/s the predictive model prices ``chip`` at: a ``device_kind``
+    of the peaks table, or ``"cpu"`` (the CPU-virtual modelling
+    constant)."""
+    if chip == "cpu":
+        return CPU_MODEL_TFLOPS * 1e12
+    return device_peaks(chip)["bf16_tflops"] * 1e12
 
 
 def link_bandwidth(link: str) -> float:
@@ -589,8 +615,6 @@ def compiled_flops(fn, *args, **kwargs) -> Optional[float]:
         import jax
         compiled = jax.jit(fn).lower(*args, **kwargs).compile()
         ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):  # older jax: one dict per device
-            ca = ca[0] if ca else None
         if not ca:
             return None
         flops = ca.get("flops")

@@ -1,8 +1,8 @@
 """Perf regression gate: median±MAD comparison of bench artifacts
 against a committed baseline ledger (docs/profiling.md#regression-gate).
 
-The bench trajectory (BENCH_r*.json, sweep_results.jsonl) has so far been
-read by humans; this module turns it into a self-tracking gate: every
+The bench trajectory has so far been read by humans; this module turns
+it into a self-tracking gate: every
 bench JSON artifact is keyed by its normalized metric + unit, the
 baseline ledger stores the last N values per key, and a new artifact
 fails the gate when its value sits outside the baseline's median by more
@@ -13,8 +13,8 @@ regression trips either way; an unmodified re-run passes (the acceptance
 experiment ``scripts/perf_gate.py --smoke`` runs exactly that pair).
 
 Stdlib-only at module level so ``scripts/perf_gate.py`` loads this file
-standalone by path (the bench-supervisor/probe.py pattern) — the gate
-must run without jax installed in the CI step that consumes it.
+standalone by path — the gate must run without jax installed in the CI
+step that consumes it.
 """
 
 from __future__ import annotations
@@ -120,10 +120,9 @@ def save_baseline(path: str, doc: Dict[str, Any]) -> None:
 
 def load_artifacts(paths: List[str]) -> List[Dict[str, Any]]:
     """Bench artifacts: each file holds one JSON object (bench.py's one
-    printed line) or JSONL (sweep_results.jsonl rows).  A row may carry
+    printed line) or JSONL (one such object per line).  A row may carry
     ``sub_rows`` — additional gate-able rows riding the one printed line
-    (the bench supervisor forwards only the last stdout line, so
-    multi-metric modes like ``--serve`` nest their per-leg rows)."""
+    (multi-metric modes like ``--serve`` nest their per-leg rows)."""
     rows: List[Dict[str, Any]] = []
 
     def add(row: Dict[str, Any]) -> None:

@@ -64,10 +64,6 @@ def _local_pool_worker(conn):
             conn.send(("ok", None))
         elif kind == "run":
             try:
-                # Bind the platform the "env" message requested before
-                # unpickling imports the fn's module (utils/platform.py).
-                from ..utils.platform import apply_env_platform
-                apply_env_platform()
                 fn = pickle.loads(payload)
                 conn.send(("ok", fn()))
             except BaseException as e:
@@ -146,12 +142,6 @@ class BaseHorovodWorker:
 
     def run(self, payload):
         import pickle as p
-        # Actor processes get JAX_PLATFORMS via set_env but start with
-        # the raylet's own env (the driver's trigger-var pop doesn't
-        # reach them); bind the platform before loads() imports the
-        # fn's module (utils/platform.py).
-        from horovod_tpu.utils.platform import apply_env_platform
-        apply_env_platform()
         return p.loads(payload)()
 
 

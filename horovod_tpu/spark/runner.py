@@ -51,11 +51,6 @@ class TaskExecutor:
 
 def _local_task_entry(index: int, payload: bytes, hostnames, q):
     try:
-        # Before unpickling: loads() imports the fn's module, which may
-        # import keras and initialize a backend — bind the platform the
-        # parent asked for first (see utils/platform.py).
-        from ..utils.platform import apply_env_platform
-        apply_env_platform()
         fn = pickle.loads(payload)
         q.put((index, ("ok", fn(index, hostnames))))
     except BaseException as e:  # surface remote errors with traceback
@@ -271,6 +266,4 @@ class _Task:
         env = dict(self.base_env)
         env.update(env_for_tasks(hostnames, self.coordinator_port)[index])
         os.environ.update(env)
-        from ..utils.platform import apply_env_platform
-        apply_env_platform()
         return self.fn(*self.args, **self.kwargs)

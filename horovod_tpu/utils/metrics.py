@@ -20,7 +20,7 @@ straggler report (:func:`straggler_report`).
 
 Deliberately stdlib-only with no package-relative imports at module level,
 so the CI exposition linter (``scripts/check_metrics_format.py``) can load
-this file standalone, the way ``bench.py`` loads ``utils/probe.py``.
+this file standalone by path.
 
 Histogram buckets are power-of-2 microseconds (expressed in seconds),
 matching the native core's fixed-bucket layout so native histograms import

@@ -89,9 +89,9 @@ def device_pids(pid_names) -> set:
 _HOST_FRAME = re.compile(
     r"^(\$|end: |PjitFunction|PjRt|PyClient|ExecuteSharded|ParseArguments|"
     r"Handle inputs|CommonPjRt|ThreadpoolListener|TransferTo|CopyTo|"
-    r"Tfrt\w*Executable|ThunkExecutor)")  # runtime-executor envelope/wait
-                                          # spans (newer jax CPU traces)
-                                          # cover the op spans: double-count
+    r"Tfrt\w*Executable|ThunkExecutor|SlinkyThreadPool)")
+# ^ runtime-executor envelope and thread-pool wait spans (jax 0.9 CPU
+#   traces) cover the op spans: counting them double-counts
 
 
 def op_tids(events, pids, tid_names) -> Optional[set]:

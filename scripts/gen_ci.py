@@ -85,7 +85,8 @@ SUITES = {
                 "tests/test_serve_trace.py", "tests/test_kv_shard.py",
                 "tests/test_scenario.py"],
     "perf": ["tests/test_perf.py", "tests/test_memstats.py"],
-    "bench-examples": ["tests/test_bench.py", "tests/test_examples_smoke.py",
+    "bench-examples": ["tests/test_bench.py", "tests/test_chip_smoke.py",
+                       "tests/test_examples_smoke.py",
                        "tests/test_profile_analyzer.py"],
 }
 
@@ -228,7 +229,7 @@ def build_steps():
         # tokens, exports nonzero hvd_serve_ttft at /metrics, leaves
         # per-request spans in the merged timeline, and the plan-stream
         # lockstep digests match across ranks (docs/serving.md).
-        # Tunnel-independent: loopback TCP + XLA-CPU decode only.
+        # Loopback TCP + XLA-CPU decode only.
         "serve: 2-process hvdrun --serve /generate smoke",
         f"{py} -m pytest tests/integration/test_serve_integration.py "
         f"{full}",

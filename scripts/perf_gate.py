@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """Perf regression gate over bench artifacts (docs/profiling.md).
 
-Compares bench JSON artifacts (bench.py's one printed line, BENCH_r*.json,
-sweep_results.jsonl rows) against a committed baseline ledger with the
+Compares bench JSON artifacts (bench.py's one printed line, or JSONL of
+such lines) against a committed baseline ledger with the
 median±MAD statistic in ``horovod_tpu/perf/gate.py``: a key regresses
 when its current median moves in the worse direction past BOTH the
 4×scaled-MAD band and the 10% relative floor — noise-tolerant, but a 2×
@@ -19,12 +19,12 @@ the rolling per-key windows (run it to adopt a new bench mode or refresh
 the baseline after an accepted change).  ``--smoke`` is the acceptance
 experiment: run ``bench.py --cpu`` three times, baseline the first two,
 assert the unmodified re-run PASSES, then inject a synthetic 2×
-step-time slowdown (half the throughput value) and assert the gate
-TRIPS (with a noise-tolerant smoke floor — see ``SMOKE_MIN_REL``).
+regression (half the printed value — on the CPU that value is a token
+count, so this exercises the gate, not the host's speed) and assert the
+gate TRIPS (with a noise-tolerant smoke floor — see ``SMOKE_MIN_REL``).
 
-Stdlib-only: the gate module is loaded by file path (the bench
-supervisor / probe.py pattern), so this script runs in CI steps without
-jax importable.
+Stdlib-only: the gate module is loaded by file path, so this script runs
+in CI steps without jax importable.
 """
 
 from __future__ import annotations
@@ -41,8 +41,7 @@ DEFAULT_BASELINE = os.path.join(REPO, "PERF_BASELINE.json")
 
 def _gate_mod():
     """Load horovod_tpu/perf/gate.py standalone (no package import: the
-    package __init__ pulls jax, which this supervisor-grade script must
-    not require)."""
+    package __init__ pulls jax, which this script must not require)."""
     mod = sys.modules.get("horovod_tpu.perf.gate")
     if mod is None:
         import importlib.util
@@ -140,7 +139,7 @@ def cmd_smoke(gate, args) -> int:
               file=sys.stderr)
         return 1
 
-    # Injected 2× step-time regression: tokens/sec halves.
+    # Injected 2× regression: the printed value halves.
     slowed = dict(second)
     slowed["value"] = float(second["value"]) / 2.0
     res2 = gate.check_artifacts(doc, [slowed], min_rel_delta=SMOKE_MIN_REL)

@@ -6,8 +6,7 @@ Import this as the first statement of every integration worker:
 
 Each worker process drives 4 virtual CPU chips by default (HVD_CPU_CHIPS
 overrides); with -np 2 the mesh is 8 chips across 2 real processes.
-The actual env dance (sitecustomize disarm, device count, jax config)
-lives in ONE place — scripts/_cpu_bootstrap.py — shared with the dryrun
+The env setup (platform, device count) lives in ONE place — scripts/_cpu_bootstrap.py — shared with the dryrun
 native-controller worker and the eager bench.
 """
 

@@ -121,7 +121,7 @@ def main() -> int:
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from horovod_tpu.ops._compat import shard_map
+    from jax import shard_map
     from horovod_tpu.optimizer import sync_gradients
     mesh = hvd.mesh()
     g_local = np.stack([np.full((16,), float(pos), np.float32)

@@ -28,7 +28,7 @@ def main() -> int:
     import optax  # noqa: E402
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from horovod_tpu.ops._compat import shard_map
+    from jax import shard_map
     from horovod_tpu.ops.overlap import _OverlapState
     from horovod_tpu.optimizer import distributed_optimizer
 

@@ -170,7 +170,7 @@ def test_tuned_threshold_propagates_to_bucket_planner(hvd, monkeypatch):
     from jax.sharding import PartitionSpec as P
 
     from horovod_tpu.common.knobs import Knobs
-    from horovod_tpu.ops._compat import shard_map
+    from jax import shard_map
     from horovod_tpu.ops.fusion import make_plan
     from horovod_tpu.optimizer import sync_gradients
     from horovod_tpu.utils.autotune import Autotuner

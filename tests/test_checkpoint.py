@@ -48,6 +48,27 @@ def test_save_restore_preserves_values_and_shardings(tmp_path,
     mgr.close()
 
 
+def test_no_file_outgrows_the_bound(tmp_path, hvd):
+    """A save stays writable under a per-file size limit: an array larger
+    than the bound is spread over several data files (orbax's defaults
+    would write it as one)."""
+    import os
+
+    from horovod_tpu import checkpoint
+    bound = checkpoint._CHUNK_BYTES + checkpoint._DATA_FILE_BYTES
+    w = jax.random.normal(jax.random.PRNGKey(0), (3 * bound // 4 // 1024,
+                                                  1024))   # 3x the bound
+    assert w.nbytes > 2 * bound
+    save_checkpoint(str(tmp_path / "ckpt"), 0, params={"w": w})
+    sizes = [os.path.getsize(os.path.join(r, f))
+             for r, _, fs in os.walk(tmp_path / "ckpt") for f in fs]
+    assert max(sizes) <= bound, sorted(sizes)[-3:]
+    assert sum(sizes) > bound    # it was written, and over several files
+    out = restore_checkpoint(str(tmp_path / "ckpt"), params={"w": w * 0})
+    np.testing.assert_array_equal(np.asarray(out["params"]["w"]),
+                                  np.asarray(w))
+
+
 def test_latest_step_and_retention(tmp_path, sharded_state):
     _, params, _ = sharded_state
     mgr = CheckpointManager(str(tmp_path / "ckpt"), max_to_keep=2)

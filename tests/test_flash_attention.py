@@ -83,6 +83,18 @@ def test_llama_forward_with_flash_attn():
                                rtol=5e-4, atol=5e-4)
 
 
+def test_interprets_only_on_the_cpu_backend(monkeypatch):
+    """interpret=None means the interpreter on `cpu` and nowhere else: a
+    backend that is merely not CALLED `tpu` compiles the kernel or
+    raises — it must never run a chip measurement interpreted."""
+    from horovod_tpu.ops import flash_attention as FA
+    for backend, want in (("cpu", True), ("tpu", False), ("other", False)):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert FA._resolve_blocks(128, 64, 64, None)[2] is want, backend
+    # an explicit choice is never overridden
+    assert FA._resolve_blocks(128, 64, 64, False)[2] is False
+
+
 def test_rejects_non_divisible_gqa():
     q, _, _ = _qkv(H=8, HK=2)
     _, k, v = _qkv(H=8, HK=2)

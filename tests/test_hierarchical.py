@@ -12,12 +12,11 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from horovod_tpu.common.reduce_op import ReduceOp
 from horovod_tpu.ops import spmd
-from horovod_tpu.ops._compat import shard_map
 from horovod_tpu.parallel.hierarchical import (hierarchical_allgather,
                                                hierarchical_allreduce,
                                                resolve_axis, split_hierarchy)

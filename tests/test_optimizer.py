@@ -8,13 +8,13 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 import horovod_tpu as hvd_mod
 from horovod_tpu.optimizer import sync_gradients, distributed_optimizer
 from horovod_tpu.ops.compression import Compression
 
-from horovod_tpu.ops._compat import shard_map
 
 
 def _data_mesh():

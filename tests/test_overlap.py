@@ -14,10 +14,10 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.ops import overlap
-from horovod_tpu.ops._compat import shard_map
 from horovod_tpu.ops.overlap import _OverlapState, priority_order
 from horovod_tpu.optimizer import _AccState, distributed_optimizer
 

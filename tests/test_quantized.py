@@ -7,9 +7,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from horovod_tpu.ops._compat import shard_map
 from horovod_tpu.ops.quantized import quantized_ring_allreduce
 
 

@@ -5,10 +5,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.models.layers import causal_attention
-from horovod_tpu.ops._compat import shard_map
 from horovod_tpu.parallel.sequence import ring_attention, ulysses_attention
 
 
