@@ -1,0 +1,340 @@
+"""The plain reference: forward pass, mean cross-entropy, gradients and
+AdamW of a dense GQA decoder (RMSNorm pre-norm, rotary embedding in the
+rotate-half pairing, gated SiLU FFN, no biases, untied head) in straight
+jax.numpy, float32, matmul precision "highest", no cache, no kernels.  It
+imports nothing of the program and takes nothing the program made: weights
+come from the seed (lib/weights.py), one layer at a time, so that it fits
+beside or before the program's state.
+
+``quant`` swaps every linear layer's matmul for a lower-precision one
+(the control of "How correct is decided"); None is the reference itself.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from . import weights as W
+
+F32 = jnp.float32
+
+
+# ------------------------------------------------------------ lower precision
+def _fake_int8(x):
+    """Symmetric int8 with one scale for the tensor, values returned in
+    float32; straight-through in the backward pass."""
+    scale = jnp.max(jnp.abs(x)) / 127.0
+    scale = jnp.where(scale == 0, 1.0, scale)
+    q = jnp.clip(jnp.round(x / scale), -127, 127) * scale
+    return x + jax.lax.stop_gradient(q - x)
+
+
+def int8_mm(x, w):
+    """W8A8, per tensor."""
+    return jnp.matmul(_fake_int8(x), _fake_int8(w))
+
+
+def plain_mm(x, w):
+    return jnp.matmul(x, w)
+
+
+MATMULS = {None: plain_mm, "int8": int8_mm}
+
+
+# --------------------------------------------------------------------- model
+def _dims(config):
+    d = config["hidden_size"]
+    H, KV = config["num_attention_heads"], config["num_key_value_heads"]
+    return d, H, KV, d // H
+
+
+def _eps(config):
+    # the program's layers.rmsnorm fixes eps; see the configuration's `assumed`
+    return float(config.get("assumed", {}).get("rms_norm_eps",
+                                               config["rms_norm_eps"]))
+
+
+def rmsnorm(x, scale, eps):
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
+
+
+def rope(x, theta):
+    """x: [B, S, heads, hd] at positions 0..S-1; rotate-half pairing."""
+    S, hd = x.shape[1], x.shape[-1]
+    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=F32) / hd))
+    ang = jnp.arange(S, dtype=F32)[:, None] * inv[None, :]
+    c, s = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
+    x1, x2 = jnp.split(x, 2, axis=-1)
+    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], -1)
+
+
+def layer(p, x, config, mm):
+    """One decoder layer on x [B, S, d]; p holds the layer's nine leaves."""
+    d, H, KV, hd = _dims(config)
+    B, S, _ = x.shape
+    eps, theta = _eps(config), float(config["rope_theta"])
+    h = rmsnorm(x, p["attn_norm.scale"], eps)
+    q = rope(mm(h, p["wq.kernel"]).reshape(B, S, H, hd), theta)
+    k = rope(mm(h, p["wk.kernel"]).reshape(B, S, KV, hd), theta)
+    v = mm(h, p["wv.kernel"]).reshape(B, S, KV, hd)
+    k = jnp.repeat(k, H // KV, axis=2)
+    v = jnp.repeat(v, H // KV, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(hd)
+    s = jnp.where(jnp.tril(jnp.ones((S, S), bool))[None, None], s, -jnp.inf)
+    o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
+    x = x + mm(o.reshape(B, S, H * hd), p["wo.kernel"])
+    h = rmsnorm(x, p["ffn_norm.scale"], eps)
+    g = jax.nn.silu(mm(h, p["w_gate.kernel"])) * mm(h, p["w_up.kernel"])
+    return x + mm(g, p["w_down.kernel"])
+
+
+def head_logits(final_scale, head, x, config, mm):
+    return mm(rmsnorm(x, final_scale, _eps(config)), head)
+
+
+def _highest(fn):
+    @functools.wraps(fn)
+    def wrapped(*a, **k):
+        with jax.default_matmul_precision("highest"):
+            return fn(*a, **k)
+    return wrapped
+
+
+class Weights:
+    """Seeded leaves by name, regenerated on demand in float32."""
+
+    def __init__(self, config, seed, put=None):
+        self.specs = {n: (i, s, std)
+                      for i, (n, s, std) in enumerate(W.leaf_specs(config))}
+        self.key = W.seed_key(seed)
+        self.dtype = {"bfloat16": jnp.bfloat16, "float32": F32}[
+            config.get("torch_dtype", "bfloat16")]
+        self.put = put or (lambda x: x)
+
+    def __call__(self, name):
+        i, shape, std = self.specs[name]
+        # in the type the program holds it in, then exactly upcast
+        return self.put(W.leaf_jit(self.key, i, shape, std,
+                                   self.dtype).astype(F32))
+
+    def layer(self, i):
+        pre = f"layers.{i}."
+        return {n[len(pre):]: self(n) for n in self.specs if n.startswith(pre)}
+
+
+# ------------------------------------------------------------------- serving
+def hidden_states(config, w, seqs, quant=None, row_block=4):
+    """The last layer's output [R, T, d] for token rows ``seqs`` [R, T],
+    layer by layer from the seeded weights ``w``."""
+    mm = MATMULS[quant]
+    step = jax.jit(_highest(functools.partial(layer, config=config, mm=mm)))
+    table = w("embed.table")
+    xs = [jnp.take(table, jnp.asarray(seqs[i:i + row_block]), axis=0)
+          for i in range(0, len(seqs), row_block)]
+    del table
+    for i in range(config["num_hidden_layers"]):
+        p = w.layer(i)
+        xs = [step(p, x) for x in xs]
+    return jnp.concatenate(xs, 0)
+
+
+def logits_at(config, seed, seq, positions, quant=None):
+    """Reference logits [len(positions), V] of one token row (float32, or
+    the control's with ``quant``)."""
+    w = Weights(config, seed)
+    x = hidden_states(config, w, np.asarray([seq], np.int32), quant)[0]
+    f = jax.jit(_highest(lambda s, h, x: head_logits(s, h, x, config,
+                                                     MATMULS[quant])))
+    return f(w("final_norm.scale"), w("lm_head.kernel"),
+             x[np.asarray(positions)])
+
+
+def generated_logit_stats(config, seed, seqs, spans, tokens_of, quant=None,
+                          row_block=4):
+    """Teacher-forced forward over ``seqs`` [R, T] (prompt + served tokens,
+    zero-padded at the end).  ``spans[r] = (first, n)``: logits at positions
+    first..first+n-1 predict the n served tokens.  For each such position,
+    against the float32 reference logits z: ``gap = max(z) - z[token]`` over
+    the standard deviation of z, where token is the served token
+    (``tokens_of='served'``) or the token the ``quant`` forward puts first
+    (``tokens_of='quant'``, the control).  Returns {"gap": [..], "flip":
+    [..]} over all positions, in request order."""
+    seqs = np.asarray(seqs, np.int32)
+    R, T = seqs.shape
+    w = Weights(config, seed)
+    passes = [None] + ([quant] if tokens_of == "quant" else [])
+    hidden = {q: hidden_states(config, w, seqs, q, row_block) for q in passes}
+    scale, head = w("final_norm.scale"), w("lm_head.kernel")
+
+    @jax.jit
+    @_highest
+    def stats(scale, head, x_ref, x_alt, served):
+        z = head_logits(scale, head, x_ref, config, plain_mm)
+        if tokens_of == "quant":
+            tok = jnp.argmax(head_logits(scale, head, x_alt, config,
+                                         MATMULS[quant]), -1)
+        else:
+            tok = served
+        top = jnp.max(z, -1)
+        mine = jnp.take_along_axis(z, tok[:, None], -1)[:, 0]
+        return (top - mine) / jnp.std(z, -1), tok != jnp.argmax(z, -1)
+
+    gaps, flips = [], []
+    nmax = max(n for _, n in spans)
+    for r, (first, n) in enumerate(spans):
+        pos = np.minimum(first + np.arange(nmax), T - 1)
+        served = np.zeros(nmax, np.int32)
+        served[:n] = seqs[r, first + 1:first + 1 + n]
+        g, f = stats(scale, head, hidden[None][r, pos],
+                     hidden[passes[-1]][r, pos], jnp.asarray(served))
+        gaps.extend(np.asarray(g)[:n].tolist())
+        flips.extend(np.asarray(f)[:n].tolist())
+    return {"gap": gaps, "flip": flips}
+
+
+# ------------------------------------------------------------------ training
+def _top(final_scale, head, x, targets, denom, config, mm):
+    z = head_logits(final_scale, head, x, config, mm)
+    lse = jax.nn.logsumexp(z, -1)
+    tgt = jnp.take_along_axis(z, targets[..., None], -1)[..., 0]
+    return jnp.sum(lse - tgt) / denom
+
+
+def train_steps(config, seed, batches, opt, devices=None, quant=None,
+                rows_per_device=2):
+    """AdamW steps on ``batches`` (a list of int arrays [B, S+1], one per
+    step) from the seeded weights.  Returns {"loss": [per step],
+    "mnorm": [per step {leaf: norm of Adam's first moment}], "dnorm":
+    {leaf: norm of the parameters' change after the last step}}.
+
+    Memory: parameters and first moments stay on the device; second
+    moments are stashed (on the host with one device, sharded over the
+    devices with several); gradients exist one layer at a time; rows go
+    through in blocks.  With several devices the rows of a block are
+    spread over them and the compiler adds the reduction."""
+    devices = devices or jax.devices()[:1]
+    n = len(devices)
+    mm = MATMULS[quant]
+    if n > 1:
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        mesh = Mesh(np.array(devices), ("x",))
+        rep = NamedSharding(mesh, P())
+        rows = NamedSharding(mesh, P("x"))
+        put = lambda x: jax.device_put(x, rep)
+        put_rows = lambda x: jax.device_put(x, rows)
+        stash = lambda x: [jax.device_put(x, rows)]
+        fetch = lambda box: jax.device_put(box[0], rep)
+    else:
+        put = put_rows = lambda x: jax.device_put(x, devices[0])
+        pending = []
+
+        def stash(x):
+            """To the host, copied while the device goes on; at most two
+            leaves wait on the device."""
+            x.copy_to_host_async()
+            box = [x]
+            pending.append(box)
+            if len(pending) > 1:
+                done = pending.pop(0)
+                done[0] = np.asarray(done[0])
+            return box
+        fetch = lambda box: put(box[0])
+    w = Weights(config, seed, put)
+    names = list(w.specs)
+    params = {k: w(k) for k in names}
+    m = {k: jnp.zeros_like(v) for k, v in params.items()}
+    v2 = {}      # second moments, stashed; absent means zero
+    L = config["num_hidden_layers"]
+    b1, b2, eps = opt["b1"], opt["b2"], opt["eps"]
+    lr, wd = opt["lr"], opt["weight_decay"]
+
+    fwd = jax.jit(_highest(functools.partial(layer, config=config, mm=mm)))
+
+    @jax.jit
+    @_highest
+    def bwd(p, x, ct):
+        _, pull = jax.vjp(lambda p, x: layer(p, x, config, mm), p, x)
+        return pull(ct)
+
+    @functools.partial(jax.jit, static_argnums=(4,))
+    @_highest
+    def top(scale, head, x, targets, denom):
+        loss, pull = jax.vjp(
+            lambda s, h, x: _top(s, h, x, targets, denom, config, mm),
+            scale, head, x)
+        return (loss,) + pull(jnp.ones((), F32))
+
+    @functools.partial(jax.jit, donate_argnums=(0, 2))
+    def adamw(p, g, m, v, t):
+        m = b1 * m + (1 - b1) * g
+        v = (1 - b2) * g * g + (0.0 if v is None else b2 * v)
+        u = (m / (1 - b1 ** t)) / (jnp.sqrt(v / (1 - b2 ** t)) + eps)
+        return p - lr * (u + wd * p), m, v, jnp.sqrt(jnp.sum(m * m))
+
+    add = jax.jit(lambda a, b: jax.tree_util.tree_map(jnp.add, a, b),
+                  donate_argnums=(0,))
+    embed_grad = jax.jit(
+        lambda shape_like, ids, ct: jnp.zeros_like(shape_like)
+        .at[ids.reshape(-1)].add(ct.reshape(-1, ct.shape[-1])))
+
+    out = {"loss": [], "mnorm": [], "step_s": []}
+    for t, batch in enumerate(batches, start=1):
+        t_step = time.perf_counter()
+        batch = np.asarray(batch, np.int32)
+        B, S = batch.shape[0], batch.shape[1] - 1
+        rb = rows_per_device * n
+        blocks = [put_rows(batch[i:i + rb]) for i in range(0, B, rb)]
+        acts = [[jnp.take(params["embed.table"], b[:, :-1], axis=0)]
+                for b in blocks]
+        for i in range(L):
+            p = {k[len(f"layers.{i}."):]: params[k] for k in names
+                 if k.startswith(f"layers.{i}.")}
+            for a in acts:
+                a.append(fwd(p, a[-1]))
+        mnorm, tt = {}, jnp.asarray(t, F32)
+
+        def update(k, g):
+            v = fetch(v2.pop(k)) if k in v2 else None
+            params[k], m[k], v, mnorm[k] = adamw(params[k], g, m[k], v, tt)
+            if t < len(batches):
+                v2[k] = stash(v)
+
+        loss, gs, gh, cts = 0.0, None, None, []
+        for b, a in zip(blocks, acts):
+            l, g_s, g_h, ct = top(params["final_norm.scale"],
+                                  params["lm_head.kernel"], a.pop(),
+                                  b[:, 1:], B * S)
+            loss += float(l)
+            gs, gh = (g_s, g_h) if gs is None else add((gs, gh), (g_s, g_h))
+            cts.append(ct)
+        update("final_norm.scale", gs)
+        update("lm_head.kernel", gh)
+        del gs, gh
+        for i in reversed(range(L)):
+            pre = f"layers.{i}."
+            p = {k[len(pre):]: params[k] for k in names if k.startswith(pre)}
+            g = None
+            for j, a in enumerate(acts):
+                gp, cts[j] = bwd(p, a.pop(), cts[j])
+                g = gp if g is None else add(g, gp)
+            for k in g:
+                update(pre + k, g[k])
+            del g, p
+        ge = None
+        for b, ct in zip(blocks, cts):
+            g1 = embed_grad(params["embed.table"], b[:, :-1], ct)
+            ge = g1 if ge is None else add(ge, g1)
+        update("embed.table", ge)
+        del ge, cts, acts
+        out["loss"].append(loss)
+        out["step_s"].append(time.perf_counter() - t_step)
+        out["mnorm"].append({k: float(x) for k, x in mnorm.items()})
+    dn = jax.jit(lambda a, b: jnp.sqrt(jnp.sum((a - b) ** 2)))
+    out["dnorm"] = {k: float(dn(params[k], w(k))) for k in names}
+    return out
