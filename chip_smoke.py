@@ -89,26 +89,6 @@ def check(cond, msg):
 # ===================================================================
 # Children: everything below this line until `run_child` imports jax.
 # ===================================================================
-class _CompileCounter:
-    """Programs lowered (``lowerings``: each is a compile or a persistent
-    -cache read) and persistent-cache hits, from jax's own monitoring."""
-
-    def __init__(self):
-        from jax import monitoring
-        self.lowerings = 0
-        self.cache_hits = 0
-        monitoring.register_event_duration_secs_listener(self._duration)
-        monitoring.register_event_listener(self._event)
-
-    def _duration(self, name, _secs, **_kw):
-        if name == "/jax/core/compile/jaxpr_to_mlir_module_duration":
-            self.lowerings += 1
-
-    def _event(self, name, **_kw):
-        if name == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-
 def _setup(dry):
     """Common child bring-up: the compile cache, the backend, and the
     fields every result line carries."""
@@ -119,8 +99,9 @@ def _setup(dry):
 
     from horovod_tpu.perf import costmodel
     from horovod_tpu.utils.platform import enable_compile_cache
+    from horovod_tpu.utils.profiler import compile_counts
     cache_dir = enable_compile_cache()
-    counter = _CompileCounter()
+    compile_counts()   # the program's own counter, listening from here on
     dev = jax.devices()[0]
     check(dev.platform == ("cpu" if dry else "tpu"),
           f"platform is {dev.platform!r}")
@@ -139,7 +120,7 @@ def _setup(dry):
         "compile_cache_from_env": bool(
             cache_dir and os.environ.get("JAX_COMPILATION_CACHE_DIR")),
     }
-    return fields, counter
+    return fields, compile_counts
 
 
 def _memory(key):
@@ -261,10 +242,10 @@ def phase_train(size, dry):
 
     losses, lowered, first_call_s = [], [], None
     for _ in range(2):
-        before = counter.lowerings
+        before = counter()["compiles"]
         params, opt_state, out = run(params, opt_state, batches)
         losses.append(np.asarray(out).tolist())   # D2H fence
-        lowered.append(counter.lowerings - before)
+        lowered.append(counter()["compiles"] - before)
         if first_call_s is None:
             first_call_s = time.perf_counter() - t_start
     flat = [x for call in losses for x in call]
@@ -306,7 +287,8 @@ def phase_train(size, dry):
         "first_call_s": round(first_call_s, 1),
         "first_call_is": f"child start to the first {STEPS} steps on the "
                          "host, compile included",
-        "lowerings_per_call": lowered, "cache_hits": counter.cache_hits,
+        "lowerings_per_call": lowered,
+        "cache_hits": counter()["cache_hits"],
         "bucket_plan_buckets": buckets, "hlo_collectives": kinds,
         "hlo_reduction_group_sizes": group_sizes,
         "bytes_in_use_after_init": in_use_after_init,
@@ -383,7 +365,7 @@ def phase_kernel(size, dry):
         "first_kernel_is": "child start to the first forward+backward "
                            "on the host, compile included",
         "train_model": size["flash_model"], "train_losses": losses,
-        "cache_hits": counter.cache_hits,
+        "cache_hits": counter()["cache_hits"],
         "peak_bytes_in_use": _memory("peak_bytes_in_use"),
     }
 
@@ -585,6 +567,16 @@ def phase_serve(size, dry, env, timeout, device):
             pc = stats["engine"]["prefix_cache"]
             check(pc["hits"] >= 2 and pc["hit_tokens"] >= 2 * size["prefix"],
                   f"no prefix hit: {pc}")
+            # the program's own clocks (docs/serving.md#request-lifecycle)
+            loop = stats["engine"].get("loop") or {}
+            check(loop.get("ticks", 0) >= 1 and loop.get("phase_s", {}).get(
+                "harvest_wait", 0) > 0 and loop.get("compiles", 0) >= 1,
+                f"/serve/stats has no loop table: {loop}")
+            for name, (_, lines) in answers.items():
+                done = lines[-1]
+                check({"pickup", "publish"} <= set(done["timing"]) and
+                      done.get("loop", {}).get("ticks", 0) >= 1,
+                      f"{name}: the done record lacks its hops: {done}")
             status, drained = http(port, "admin/drain", {}, timeout=120)
             check(status == 200 and drained[0].get("drained"),
                   f"drain failed: {status} {drained}")
@@ -611,6 +603,9 @@ def phase_serve(size, dry, env, timeout, device):
         "first_wave_is": "launcher start to the first two answers "
                          "complete, load and compile included",
         "ttft_s": {k: v[1][-1].get("ttft_s") for k, v in answers.items()},
+        "timing": {k: v[1][-1]["timing"] for k, v in answers.items()},
+        "loop": {k: v[1][-1]["loop"] for k, v in answers.items()},
+        "stats_loop": loop,
         "prefix_cache": pc,
         "engine_ticks": stats["engine"]["tick"],
         "router": drained[0].get("router"),

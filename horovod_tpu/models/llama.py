@@ -145,9 +145,13 @@ def _ffn(p: Dict[str, Any], x: jax.Array, cfg: LlamaConfig) -> jax.Array:
 def apply_layer(p: Dict[str, Any], x: jax.Array, cfg: LlamaConfig,
                 cos: jax.Array, sin: jax.Array,
                 attn_fn=None, pos_offset=0) -> jax.Array:
-    x = x + _attn(p, L.rmsnorm(p["attn_norm"], x), cfg, cos, sin, attn_fn,
-                  pos_offset)
-    x = x + _ffn(p, L.rmsnorm(p["ffn_norm"], x), cfg)
+    # Named scopes (here and below) only label the ops they enclose in the
+    # lowered program and the device trace (docs/profiling.md#scopes).
+    with jax.named_scope("attn"):
+        x = x + _attn(p, L.rmsnorm(p["attn_norm"], x), cfg, cos, sin,
+                      attn_fn, pos_offset)
+    with jax.named_scope("ffn"):
+        x = x + _ffn(p, L.rmsnorm(p["ffn_norm"], x), cfg)
     return x
 
 
@@ -172,7 +176,8 @@ def apply(params: Dict[str, Any], ids: jax.Array, cfg: LlamaConfig,
     feature-sharded residual layout it can only reach by full
     rematerialization (the round-1 dryrun warning)."""
     cos, sin = L.rope_freqs(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
-    x = L.embedding(params["embed"], ids).astype(cfg.dtype)
+    with jax.named_scope("embed"):
+        x = L.embedding(params["embed"], ids).astype(cfg.dtype)
 
     def pin(x):
         if act_sharding is not None:
@@ -189,7 +194,8 @@ def apply(params: Dict[str, Any], ids: jax.Array, cfg: LlamaConfig,
     x = L.rmsnorm(params["final_norm"], x)
     if return_hidden:
         return x
-    return L.dense(params["lm_head"], x)
+    with jax.named_scope("head"):
+        return L.dense(params["lm_head"], x)
 
 
 def loss_fn(params: Dict[str, Any], ids: jax.Array, cfg: LlamaConfig,
@@ -218,11 +224,13 @@ def loss_fn(params: Dict[str, Any], ids: jax.Array, cfg: LlamaConfig,
             return jnp.sum(
                 L.softmax_cross_entropy(L.dense(params["lm_head"], hc), tc))
 
-        total = jnp.sum(jax.lax.map(lambda x: chunk_nll(*x), (hs, ts)))
-        return total / (B * S)
+        with jax.named_scope("head"):
+            total = jnp.sum(jax.lax.map(lambda x: chunk_nll(*x), (hs, ts)))
+            return total / (B * S)
     logits = apply(params, ids[:, :-1], cfg, attn_fn=attn_fn, remat=remat,
                    act_sharding=act_sharding)
-    return jnp.mean(L.softmax_cross_entropy(logits, targets))
+    with jax.named_scope("head"):
+        return jnp.mean(L.softmax_cross_entropy(logits, targets))
 
 
 # ----------------------------------------------------------- decode path
@@ -299,17 +307,19 @@ def _attn_cached(p: Dict[str, Any], x: jax.Array, cfg: LlamaConfig,
     blk = jnp.take_along_axis(block_tables, slot_idx, axis=1)
     blk = jnp.where(valid, jnp.maximum(blk, 0), num_blocks)
     off = positions % block_size
-    k_pool = k_pool.at[blk, off].set(k, mode="drop")
-    v_pool = v_pool.at[blk, off].set(v, mode="drop")
+    with jax.named_scope("kv_write"):
+        k_pool = k_pool.at[blk, off].set(k, mode="drop")
+        v_pool = v_pool.at[blk, off].set(v, mode="drop")
     # Gather each slot's full context.  Table slot j covers global
     # positions [j*bs, (j+1)*bs), so gathered index t IS global position
     # t; unassigned entries (-1 -> block 0) only cover positions the
     # causal mask excludes, and masked scores softmax to exactly 0.
     bt = jnp.maximum(block_tables, 0)
-    k_ctx = jnp.take(k_pool, bt, axis=0).reshape(
-        S, max_blocks * block_size, cfg.n_kv_heads, cfg.head_dim)
-    v_ctx = jnp.take(v_pool, bt, axis=0).reshape(
-        S, max_blocks * block_size, cfg.n_kv_heads, cfg.head_dim)
+    with jax.named_scope("kv_gather"):
+        k_ctx = jnp.take(k_pool, bt, axis=0).reshape(
+            S, max_blocks * block_size, cfg.n_kv_heads, cfg.head_dim)
+        v_ctx = jnp.take(v_pool, bt, axis=0).reshape(
+            S, max_blocks * block_size, cfg.n_kv_heads, cfg.head_dim)
     key_pos = jnp.arange(max_blocks * block_size)
     mask = (key_pos[None, None, :] <= positions[:, :, None])[:, None]
     o = L.causal_attention(q, k_ctx, v_ctx, causal=False, mask=mask)
@@ -335,19 +345,25 @@ def apply_cached(params: Dict[str, Any], tokens: jax.Array,
     cos, sin = L.rope_freqs(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
     positions = lengths[:, None] + jnp.arange(C, dtype=lengths.dtype)[None]
     valid = jnp.arange(C)[None, :] < n_new[:, None]
-    x = L.embedding(params["embed"], tokens).astype(cfg.dtype)
+    with jax.named_scope("embed"):
+        x = L.embedding(params["embed"], tokens).astype(cfg.dtype)
     ks, vs = [], []
     for i, p in enumerate(params["layers"]):
-        a, k_pool, v_pool = _attn_cached(
-            p, L.rmsnorm(p["attn_norm"], x), cfg, cos, sin,
-            cache["k"][i], cache["v"][i], block_tables, positions, valid)
-        x = x + a
-        x = x + _ffn(p, L.rmsnorm(p["ffn_norm"], x), cfg)
+        with jax.named_scope("attn"):
+            a, k_pool, v_pool = _attn_cached(
+                p, L.rmsnorm(p["attn_norm"], x), cfg, cos, sin,
+                cache["k"][i], cache["v"][i], block_tables, positions, valid)
+            x = x + a
+        with jax.named_scope("ffn"):
+            x = x + _ffn(p, L.rmsnorm(p["ffn_norm"], x), cfg)
         ks.append(k_pool)
         vs.append(v_pool)
     x = L.rmsnorm(params["final_norm"], x)
-    return (L.dense(params["lm_head"], x),
-            {"k": jnp.stack(ks), "v": jnp.stack(vs)})
+    with jax.named_scope("head"):
+        logits = L.dense(params["lm_head"], x)
+    with jax.named_scope("kv_write"):
+        cache = {"k": jnp.stack(ks), "v": jnp.stack(vs)}
+    return logits, cache
 
 
 def param_count(cfg: LlamaConfig) -> int:

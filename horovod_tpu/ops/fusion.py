@@ -203,8 +203,9 @@ def fused_apply_per_bucket(leaves: Sequence[jax.Array],
     if len(fns) != plan.num_buckets:
         raise ValueError(f"{len(fns)} fns for {plan.num_buckets} buckets")
     out: List[Optional[jax.Array]] = [None] * plan.num_leaves
-    for bucket, fn in zip(plan.buckets, fns):
-        buf = pack_bucket(leaves, bucket)
-        buf = fn(buf)
-        unpack_bucket(buf, bucket, out)
+    for i, (bucket, fn) in enumerate(zip(plan.buckets, fns)):
+        with jax.named_scope(f"bucket{i}"):
+            buf = pack_bucket(leaves, bucket)
+            buf = fn(buf)
+            unpack_bucket(buf, bucket, out)
     return out  # type: ignore[return-value]

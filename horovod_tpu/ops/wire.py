@@ -339,12 +339,13 @@ def wire_sync(leaves: Sequence[jax.Array], plan, formats: Sequence[str],
         leaves = [l + r.astype(l.dtype) for l, r in zip(leaves, residuals)]
         new_res: List[jax.Array] = [jnp.zeros_like(l) for l in leaves]
     out: List[Optional[jax.Array]] = [None] * plan.num_leaves
-    for bucket, fmt in zip(plan.buckets, formats):
-        buf = pack_bucket(leaves, bucket)
-        if ef and is_lossy(fmt):
-            unpack_bucket(local_error(buf, fmt), bucket, new_res)
-        buf = reduce_bucket(buf, fmt, axis_name, op,
-                            prescale_factor=prescale_factor,
-                            postscale_factor=postscale_factor)
-        unpack_bucket(buf, bucket, out)
+    for i, (bucket, fmt) in enumerate(zip(plan.buckets, formats)):
+        with jax.named_scope(f"bucket{i}"):
+            buf = pack_bucket(leaves, bucket)
+            if ef and is_lossy(fmt):
+                unpack_bucket(local_error(buf, fmt), bucket, new_res)
+            buf = reduce_bucket(buf, fmt, axis_name, op,
+                                prescale_factor=prescale_factor,
+                                postscale_factor=postscale_factor)
+            unpack_bucket(buf, bucket, out)
     return out, (new_res if ef else None)

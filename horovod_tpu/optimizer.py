@@ -147,16 +147,20 @@ def _sync_impl(grads: Any,
                                  postscale_factor=postscale_factor)
             return legacy_comp.decompress(buf, ctx)
 
-        synced = fused_apply(leaves, plan, reduce_bucket)
+        with jax.named_scope("grad_sync"):
+            synced = fused_apply(leaves, plan, reduce_bucket)
         return jax.tree_util.tree_unflatten(treedef, synced), residuals
 
     formats = _wire.plan_formats(plan, policy, axis_name, op)
     res_leaves = (jax.tree_util.tree_leaves(residuals)
                   if residuals is not None else None)
-    synced, new_res = _wire.wire_sync(
-        leaves, plan, formats, axis_name, op,
-        prescale_factor=prescale_factor,
-        postscale_factor=postscale_factor, residuals=res_leaves)
+    # grad_sync/bucket<i> in the lowered program and the device trace
+    # (docs/profiling.md#scopes); the bucket loops open the inner scope
+    with jax.named_scope("grad_sync"):
+        synced, new_res = _wire.wire_sync(
+            leaves, plan, formats, axis_name, op,
+            prescale_factor=prescale_factor,
+            postscale_factor=postscale_factor, residuals=res_leaves)
     out = jax.tree_util.tree_unflatten(treedef, synced)
     if new_res is None:
         return out, residuals

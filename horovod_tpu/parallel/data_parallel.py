@@ -125,8 +125,9 @@ def make_train_step(loss_fn: Callable,
                 params, *batch)
         else:
             loss, grads = jax.value_and_grad(loss_fn)(params, *batch)
-        updates, opt_state = dist_opt.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = dist_opt.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         loss = jax.lax.pmean(loss, axis_name)
         if has_aux:
             return params, opt_state, loss, aux
@@ -213,8 +214,10 @@ def make_microbatched_train_step(loss_fn: Callable,
             loss, grads = jax.value_and_grad(fn)(params, mb)
             # non-final microbatches return zero updates: applying them
             # keeps the carry structure uniform and costs one no-op add
-            updates, opt_state = dist_opt.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = dist_opt.update(grads, opt_state,
+                                                     params)
+                params = optax.apply_updates(params, updates)
             return (params, opt_state), jax.lax.pmean(loss, axis_name)
 
         (params, opt_state), losses = jax.lax.scan(
@@ -277,8 +280,10 @@ def make_scanned_train_step(loss_fn: Callable,
         def one(carry, batch):
             params, opt_state = carry
             loss, grads = jax.value_and_grad(fn)(params, batch)
-            updates, opt_state = dist_opt.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = dist_opt.update(grads, opt_state,
+                                                     params)
+                params = optax.apply_updates(params, updates)
             return (params, opt_state), jax.lax.pmean(loss, axis_name)
 
         (params, opt_state), losses = jax.lax.scan(

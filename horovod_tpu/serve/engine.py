@@ -425,6 +425,14 @@ class Request:
         # (queue_s/prefill_s): the decode fleet cannot recompute them —
         # perf_counter stamps are process-local.
         self.upstream: Optional[Dict[str, float]] = None
+        # Hops and loop phases of this request's life (docs/serving.md
+        # #request-lifecycle): the front's pickup wait, the engine clock's
+        # snapshot at submit, and from it the ticks up to the first token
+        # and the whole delta at finish.
+        self.pickup_s: Optional[float] = None
+        self.loop0: Optional[Dict[str, Any]] = None
+        self.prefill_ticks: Optional[int] = None
+        self.loop: Optional[Dict[str, Any]] = None
 
     @property
     def prompt_len(self) -> int:
@@ -857,6 +865,11 @@ class ServeEngine:
                                         self._write_block)
             self.scheduler.prefix.spill = self._spill
         self._handoffs = 0
+        # Where a tick's host time goes, phase by phase (utils/profiler.py
+        # PhaseClock); the serving loop (serve/worker.py) times its own
+        # phases on the same clock.
+        from ..utils.profiler import PhaseClock
+        self.clock = PhaseClock()
         self._step_fn = self._build_step()
         # One-deep tick pipeline (the loader.prefetch deque pattern):
         # holds (plan, device next-token array) until the next step()
@@ -901,9 +914,11 @@ class ServeEngine:
             # drop).  The gather reads the pre-step pool, so a source
             # block recycled in this same tick still copies its old
             # content (functional semantics — see Scheduler._admit_blocks).
-            cache = model.copy_blocks(cache, copy_src, copy_dst)
-            out = model.apply_cached(params, tokens, mcfg, cache,
-                                     block_tables, lengths, n_new)
+            with jax.named_scope("tick/copy_blocks"):
+                cache = model.copy_blocks(cache, copy_src, copy_dst)
+            with jax.named_scope("tick/model"):
+                out = model.apply_cached(params, tokens, mcfg, cache,
+                                         block_tables, lengths, n_new)
             logits, cache = out[0], out[1]  # moe also returns aux
             # Greedy sampling ON DEVICE at EVERY chunk position: row
             # [s, j] is the greedy continuation after consuming tokens
@@ -911,8 +926,9 @@ class ServeEngine:
             # speculative decode verifies its whole draft row against
             # it.  Argmax ties break identically on every rank (SPMD
             # determinism).
-            next_tokens = jnp.argmax(
-                logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
+            with jax.named_scope("tick/sample"):
+                next_tokens = jnp.argmax(
+                    logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
             return cache, next_tokens
 
         return jax.jit(
@@ -932,6 +948,7 @@ class ServeEngine:
                       eos_id=eos_id if eos_id is not None
                       else self.cfg.eos_id)
         req.trace = trace
+        req.loop0 = self.clock.snapshot()
         return self.scheduler.submit(req)
 
     def has_work(self) -> bool:
@@ -1003,6 +1020,7 @@ class ServeEngine:
                               if handoff.get("eos_id") is not None
                               else self.cfg.eos_id))
         req.trace = handoff.get("trace")
+        req.loop0 = self.clock.snapshot()
         req.upstream = {k: float(handoff[k])
                         for k in ("queue_s", "prefill_s")
                         if handoff.get(k) is not None} or None
@@ -1055,6 +1073,48 @@ class ServeEngine:
         return out
 
     def _dispatch(self) -> None:
+        with self.clock.span("plan"):
+            work, copies = self._plan()
+        if not work:
+            return
+        cfg = self.cfg
+        with self.clock.span("stage"):
+            S, C = cfg.max_slots, cfg.prefill_chunk
+            tokens = np.zeros((S, C), np.int32)
+            lengths = np.zeros(S, np.int32)
+            n_new = np.zeros(S, np.int32)
+            for slot, req, n in work:
+                if req.state == "prefill":
+                    tokens[slot, :n] = req.tokens[req.pos:req.pos + n]
+                else:
+                    # Speculative verify row: the last emitted token plus
+                    # the drafts — one multi-token apply_cached call scores
+                    # every draft position (n == 1 + len(draft)).
+                    tokens[slot, :n] = [req.out_tokens[-1]] + req.draft
+                lengths[slot] = req.ctx_len
+                n_new[slot] = n
+            copy_src = np.zeros(S, np.int32)
+            copy_dst = np.full(S, cfg.cache_blocks, np.int32)  # no-op: dropped
+            for j, (src, dst) in enumerate(copies):
+                copy_src[j], copy_dst[j] = src, dst
+            # Async dispatch: device_put + jit return immediately; the next
+            # step() harvests, so this tick's H2D staging and compute run
+            # behind the caller's host work (the double-buffer pattern).
+            dev = [_make_global(a, self._repl)
+                   for a in (np.asarray(self.scheduler.block_tables),
+                             lengths, n_new, tokens, copy_src, copy_dst)]
+        with self.clock.span("launch"):
+            self.cache, next_tokens = self._step_fn(
+                self.params, self.cache, *dev)
+        used = int(n_new.sum())
+        self._last_fill = used / cfg.max_batch_tokens
+        self._inflight.append((self.tick, work, next_tokens, used))
+        self.tick += 1
+
+    def _plan(self):
+        """The host's decisions for one dispatch: the scheduler's work
+        list and this tick's CoW copies, with handoff imports landed and
+        the decisions folded into the lockstep digest."""
         prefix = self.scheduler.prefix
         spill = prefix.spill if prefix is not None else None
         reloads0 = spill.reloaded_total if spill is not None else 0
@@ -1081,40 +1141,10 @@ class ServeEngine:
                                    extra={"reloads": delta})
                         break
         if not work:
-            return
-        cfg = self.cfg
-        S, C = cfg.max_slots, cfg.prefill_chunk
-        tokens = np.zeros((S, C), np.int32)
-        lengths = np.zeros(S, np.int32)
-        n_new = np.zeros(S, np.int32)
-        for slot, req, n in work:
-            if req.state == "prefill":
-                tokens[slot, :n] = req.tokens[req.pos:req.pos + n]
-            else:
-                # Speculative verify row: the last emitted token plus
-                # the drafts — one multi-token apply_cached call scores
-                # every draft position (n == 1 + len(draft)).
-                tokens[slot, :n] = [req.out_tokens[-1]] + req.draft
-            lengths[slot] = req.ctx_len
-            n_new[slot] = n
+            return work, []
         copies = self.scheduler.take_copies()
-        copy_src = np.zeros(S, np.int32)
-        copy_dst = np.full(S, cfg.cache_blocks, np.int32)  # no-op: dropped
-        for j, (src, dst) in enumerate(copies):
-            copy_src[j], copy_dst[j] = src, dst
         self._fold_sched(work, copies)
-        # Async dispatch: device_put + jit return immediately; the next
-        # step() harvests, so this tick's H2D staging and compute run
-        # behind the caller's host work (the double-buffer pattern).
-        dev = [_make_global(a, self._repl)
-               for a in (np.asarray(self.scheduler.block_tables),
-                         lengths, n_new, tokens, copy_src, copy_dst)]
-        self.cache, next_tokens = self._step_fn(
-            self.params, self.cache, *dev)
-        used = int(n_new.sum())
-        self._last_fill = used / cfg.max_batch_tokens
-        self._inflight.append((self.tick, work, next_tokens, used))
-        self.tick += 1
+        return work, copies
 
     def _fold_sched(self, work, copies) -> None:
         """Fold one dispatch's scheduling decisions into the rolling
@@ -1133,9 +1163,16 @@ class ServeEngine:
         if not self._inflight:
             return {"tick": None, "processed": 0, "emitted": {},
                     "finished": [], "handoff": []}
-        from ..utils import metrics as M
         tick, work, next_tokens, used = self._inflight.popleft()
-        tokens_host = np.asarray(next_tokens)  # D2H fence for this tick
+        with self.clock.span("harvest_wait"):
+            tokens_host = np.asarray(next_tokens)  # D2H fence for this tick
+        with self.clock.span("harvest_emit"):
+            return self._emit(tick, work, tokens_host, used)
+
+    def _emit(self, tick, work, tokens_host, used) -> Dict[str, Any]:
+        """The host half of a harvest: advance every request of the tick
+        by the tokens the device sampled, finish those that are done."""
+        from ..utils import metrics as M
         now = time.perf_counter()
         emitted: Dict[str, List[int]] = {}
         finished: List[Request] = []
@@ -1162,6 +1199,7 @@ class ServeEngine:
                     self.scheduler.register_prefix(req)
                     handoffs.append(self.export_handoff(req, first))
                     self.scheduler.finish(req, "prefill_done")
+                    self._close_loop(req)
                     finished.append(req)
                     self._handoffs += 1
                     M.SERVE_HANDOFFS.inc()
@@ -1196,6 +1234,9 @@ class ServeEngine:
                 emitted_n += 1
                 if req.first_token_t is None:
                     req.first_token_t = now
+                    if req.loop0 is not None:
+                        req.prefill_ticks = self._ticks() - \
+                            req.loop0["phase_n"].get("harvest_wait", 0)
                     M.SERVE_TTFT.observe(req.ttft())
                     self._span("PREFILL", req, now - req.admitted_t,
                                end_t=now, extra={"prompt": req.prompt_len})
@@ -1204,6 +1245,7 @@ class ServeEngine:
                     reason = ("eos" if req.eos_id is not None
                               and tok == req.eos_id else "completed")
                     self.scheduler.finish(req, reason)
+                    self._close_loop(req)
                     finished.append(req)
                     tpot = req.tpot()
                     if tpot is not None:
@@ -1221,6 +1263,23 @@ class ServeEngine:
         PM.record_step(tick)  # engine liveness on the /health plane
         return {"tick": tick, "processed": used, "emitted": emitted,
                 "finished": finished, "handoff": handoffs}
+
+    def _ticks(self) -> int:
+        """Ticks harvested so far: one ``harvest_wait`` span each."""
+        return self.clock.phase_n.get("harvest_wait", 0)
+
+    def _close_loop(self, req: Request) -> None:
+        """At finish: what the loop did in this request's life, from its
+        ``submit`` to now (the done record's ``loop``)."""
+        if req.loop0 is None:
+            return
+        d = self.clock.delta(req.loop0)
+        req.loop = {
+            "ticks": d["phase_n"].get("harvest_wait", 0),
+            "prefill_ticks": req.prefill_ticks,
+            "phase_s": {k: round(v, 6) for k, v in d["phase_s"].items()},
+            "compiles": d["compiles"]}
+        req.loop0 = None
 
     def _update_gauges(self) -> None:
         from ..utils import metrics as M
@@ -1335,6 +1394,7 @@ class ServeEngine:
                     if self._spec_drafted else None),
             },
         }
+        out["loop"] = dict(self.clock.snapshot(), ticks=self._ticks())
         if prefix is not None:
             out["prefix_cache"].update({
                 "hits": prefix.hits,

@@ -64,6 +64,7 @@ import sys
 import time
 from typing import Any, Callable, Dict, List, Optional
 
+from ..utils.profiler import PhaseClock
 from .replica import REPLICA_SCOPE, replica_key, scoped
 from .router import (DRAIN_KEY, DRAINED_KEY, OUT_SCOPE, PLAN_SCOPE,
                      REQ_SCOPE, STATS_KEY, STATS_SCOPE, req_key)
@@ -143,6 +144,7 @@ class FleetFrontend:
         self._parts: Dict[str, int] = {}
         self._results: Dict[str, List[int]] = {}
         self._suppress: Dict[str, int] = {}  # rid -> tokens NOT to re-publish
+        self._first_pub: Dict[str, float] = {}  # rid -> first part sent at
         # Prefill role only: redriven requests' already-streamed prefixes,
         # forwarded through the handoff so the DECODE publisher (the one
         # that owns the client stream) suppresses them, not us.
@@ -346,13 +348,15 @@ class FleetFrontend:
             part = self._parts.get(rid, 0)
             self._publish_part(rid, part, toks)
             self._parts[rid] = part + 1
+            self._first_pub.setdefault(rid, time.perf_counter())
         for req in report["finished"]:
+            first_pub = self._first_pub.pop(req.req_id, None)
             if req.finish_reason == "prefill_done":
                 # Prefill-role completion: the request's life continues
                 # on the decode sub-fleet (via the serve_kv handoff) —
                 # the decode side owns the client-facing .done.
                 continue
-            self._publish_done(req.req_id, {
+            done = {
                 "done": True,
                 "tokens": self._results.pop(req.req_id, []),
                 "finish_reason": req.finish_reason,
@@ -360,7 +364,14 @@ class FleetFrontend:
                 "tpot_s": req.tpot(),
                 "timing": self._req_timing(req),
                 "trace": getattr(req, "trace", None),
-            })
+            }
+            ftt = getattr(req, "first_token_t", None)
+            if first_pub is not None and ftt is not None:
+                # first token sampled -> its part handed to the stream
+                done["timing"]["publish"] = max(0.0, first_pub - ftt)
+            if getattr(req, "loop", None) is not None:
+                done["loop"] = req.loop
+            self._publish_done(req.req_id, done)
             self._parts.pop(req.req_id, None)
             self._suppress.pop(req.req_id, None)
 
@@ -397,6 +408,8 @@ class FleetFrontend:
                 t["prefill"] = max(0.0, ftt - adm)
         if ftt is not None and done is not None:
             t["decode"] = max(0.0, done - ftt)
+        if getattr(req, "pickup_s", None) is not None:
+            t["pickup"] = req.pickup_s
         return t
 
     def _publish_stats(self, force: bool = False) -> None:
@@ -481,6 +494,51 @@ class FleetFrontend:
             self._next_handoff += 1
 
     # -------------------------------------------------------------- loop
+    def _submit(self, r: Dict[str, Any], kv_backed: bool) -> None:
+        """Hand one plan entry to the engine: a prefill handoff to import,
+        or a client request (answered at once where the engine refuses
+        it)."""
+        if "handoff" in r:
+            # Prefill->decode import: the prompt KV is in the payload;
+            # skips the admission queue.
+            h = r["handoff"]
+            if self.rank == 0 and kv_backed and \
+                    h.get("resume_emitted") is not None:
+                self._apply_resume(
+                    {"id": h.get("req_id"),
+                     "resume_emitted": h["resume_emitted"],
+                     "resume_part": h.get("resume_part", 0)})
+            self.engine.import_prefill(h)
+            return
+        if self.rank == 0 and kv_backed:
+            self._apply_resume(r)
+            if self.role == "prefill" and \
+                    r.get("resume_emitted") is not None:
+                self._resume_info[r["id"]] = {
+                    "resume_emitted": r["resume_emitted"],
+                    "resume_part": r.get("resume_part", 0)}
+        try:
+            req = self.engine.submit(r["tokens"], r["max_new_tokens"],
+                                     req_id=r.get("id"),
+                                     eos_id=r.get("eos_id"))
+        except ValueError as e:
+            # invalid per the engine's limits: answer it so the router
+            # stream doesn't hang to timeout
+            if self.rank == 0 and r.get("id") and kv_backed:
+                self._publish_done(r["id"], {"done": True, "tokens": [],
+                                             "error": str(e)})
+            return
+        if req is None:
+            return  # scripted test engines return None
+        # Guarded attach, not a submit kwarg: scripted engines predate
+        # trace.
+        if r.get("trace") is not None:
+            req.trace = r["trace"]
+        if r.get("submitted_t") is not None:
+            # The router's wall-clock stamp -> this hand-over: exact on
+            # one host, skewed by the clocks' difference across hosts.
+            req.pickup_s = max(0.0, time.time() - float(r["submitted_t"]))
+
     def run(self, ttl_s: float = 0.0) -> int:
         """Serve until ``ttl_s`` elapses (0 = until interrupted), or a
         drain completes.  Rank 0 paces the fleet; followers block on the
@@ -496,6 +554,9 @@ class FleetFrontend:
             # through the prefill sub-fleet, which re-hands-off with the
             # resume prefix attached (byte-identical stream resumption).
             carry = self.resume_from_kv()
+        # The engine's phase clock when it has one (scripted test engines
+        # do not): the loop's own phases land in the same table.
+        clock = getattr(self.engine, "clock", None) or PhaseClock()
         t0 = time.monotonic()
         stop = False
         drain_t: Optional[float] = None
@@ -506,88 +567,56 @@ class FleetFrontend:
                 # must look alive; only a wedged loop/engine freezes it.
                 PM.record_step(self.tick)
                 _chaos.maybe_stall("serve_tick")
-                if self.rank == 0:
-                    if drain_t is None and kv_backed and \
-                            time.monotonic() >= drain_check_t:
-                        drain_check_t = time.monotonic() + _DRAIN_POLL_S
-                        if self._drain_requested():
-                            drain_t = time.monotonic()
-                            print("[hvd.serve] rank 0: drain requested "
-                                  "— finishing in-flight work",
+                with clock.span("poll"):
+                    if self.rank == 0:
+                        if drain_t is None and kv_backed and \
+                                time.monotonic() >= drain_check_t:
+                            drain_check_t = time.monotonic() + _DRAIN_POLL_S
+                            if self._drain_requested():
+                                drain_t = time.monotonic()
+                                print("[hvd.serve] rank 0: drain requested "
+                                      "— finishing in-flight work",
+                                      flush=True)
+                        if not kv_backed:
+                            reqs = []
+                        elif self.role == "decode":
+                            # The decode sub-fleet's work arrives as
+                            # prefill handoffs, not raw client requests.
+                            reqs = self._drain_handoffs()
+                        else:
+                            reqs = self._drain_requests()
+                        if carry:
+                            reqs = carry + reqs
+                            carry = []
+                        done_serving = (
+                            (bool(ttl_s)
+                             and time.monotonic() - t0 >= ttl_s)
+                            or drain_t is not None)
+                        stop = bool(done_serving and not reqs
+                                    and not self.engine.has_work())
+                        if drain_t is not None and not stop and \
+                                time.monotonic() - drain_t >= \
+                                self.drain_timeout_s:
+                            # Degraded drain: the budget beats
+                            # completeness so a preemption deadline is
+                            # never missed.
+                            print("[hvd.serve] rank 0: drain budget "
+                                  f"({self.drain_timeout_s:.0f}s) exhausted "
+                                  "with work in flight — stopping anyway",
                                   flush=True)
-                    if not kv_backed:
-                        reqs = []
-                    elif self.role == "decode":
-                        # The decode sub-fleet's work arrives as prefill
-                        # handoffs, not raw client requests.
-                        reqs = self._drain_handoffs()
+                            stop = True
+                        if fleet:
+                            self._publish_plan(reqs, stop=stop)
                     else:
-                        reqs = self._drain_requests()
-                    if carry:
-                        reqs = carry + reqs
-                        carry = []
-                    done_serving = (
-                        (bool(ttl_s)
-                         and time.monotonic() - t0 >= ttl_s)
-                        or drain_t is not None)
-                    stop = bool(done_serving and not reqs
-                                and not self.engine.has_work())
-                    if drain_t is not None and not stop and \
-                            time.monotonic() - drain_t >= \
-                            self.drain_timeout_s:
-                        # Degraded drain: the budget beats completeness
-                        # so a preemption deadline is never missed.
-                        print("[hvd.serve] rank 0: drain budget "
-                              f"({self.drain_timeout_s:.0f}s) exhausted "
-                              "with work in flight — stopping anyway",
-                              flush=True)
-                        stop = True
-                    if fleet:
-                        self._publish_plan(reqs, stop=stop)
-                else:
-                    plan = self._fetch_plan()
-                    reqs, stop = plan["reqs"], plan["stop"]
+                        plan = self._fetch_plan()
+                        reqs, stop = plan["reqs"], plan["stop"]
                 self.tick += 1
                 if stop:
                     break
-                for r in reqs:
-                    if r is None:
-                        continue
-                    if "handoff" in r:
-                        # Prefill->decode import: the prompt KV is in
-                        # the payload; skips the admission queue.
-                        h = r["handoff"]
-                        if self.rank == 0 and kv_backed and \
-                                h.get("resume_emitted") is not None:
-                            self._apply_resume(
-                                {"id": h.get("req_id"),
-                                 "resume_emitted": h["resume_emitted"],
-                                 "resume_part": h.get("resume_part", 0)})
-                        self.engine.import_prefill(h)
-                        continue
-                    if self.rank == 0 and kv_backed:
-                        self._apply_resume(r)
-                        if self.role == "prefill" and \
-                                r.get("resume_emitted") is not None:
-                            self._resume_info[r["id"]] = {
-                                "resume_emitted": r["resume_emitted"],
-                                "resume_part": r.get("resume_part", 0)}
-                    try:
-                        req = self.engine.submit(r["tokens"],
-                                                 r["max_new_tokens"],
-                                                 req_id=r.get("id"),
-                                                 eos_id=r.get("eos_id"))
-                        # Guarded attach, not a submit kwarg: scripted
-                        # test engines return None and predate trace.
-                        if req is not None and r.get("trace") is not None:
-                            req.trace = r["trace"]
-                    except ValueError as e:
-                        # invalid per the engine's limits: answer it so
-                        # the router stream doesn't hang to timeout
-                        if self.rank == 0 and r.get("id") and kv_backed:
-                            self._publish_done(r["id"],
-                                               {"done": True, "tokens": [],
-                                                "error": str(e)})
+                with clock.span("submit"):
+                    for r in reqs:
+                        if r is not None:
+                            self._submit(r, kv_backed)
                 # Chaos step clock = the ENGINE's work-tick counter: it
                 # advances only when the fleet is decoding/prefilling,
                 # so a spec kill at step K lands mid-stream
@@ -595,13 +624,15 @@ class FleetFrontend:
                 _chaos.step(self.engine.tick)
                 report = self.engine.step()
                 if self.rank == 0 and kv_backed:
-                    self._publish_report(report)
-                    if self.role == "prefill":
-                        self._publish_handoffs(report)
-                    self._publish_stats()
+                    with clock.span("publish"):
+                        self._publish_report(report)
+                        if self.role == "prefill":
+                            self._publish_handoffs(report)
+                        self._publish_stats()
                 if not self.engine.has_work() and not reqs:
                     if self.rank == 0:
-                        time.sleep(_IDLE_SLEEP_S)
+                        with clock.span("idle"):
+                            time.sleep(_IDLE_SLEEP_S)
         except KeyboardInterrupt:
             if self.rank == 0 and fleet:
                 # release the followers blocked on the plan stream

@@ -16,9 +16,10 @@ from __future__ import annotations
 
 import contextlib
 import time
-from typing import Callable, Iterator, Optional, Tuple
+from typing import Callable, Dict, Iterator, Optional, Tuple
 
 _active_logdir: Optional[str] = None
+_compiles = {"compiles": 0, "cache_hits": 0, "listening": False}
 
 
 def start(logdir: str) -> None:
@@ -95,5 +96,75 @@ def timed(fn: Callable[[], object],
     return result, (time.perf_counter_ns() - t0) / 1e3
 
 
+def compile_counts() -> Dict[str, int]:
+    """Programs this process lowered (each a compile or a persistent-cache
+    read) and persistent-cache hits, from jax's own monitoring.  The
+    listeners are registered on the first call and count from then on."""
+    if not _compiles["listening"]:
+        from jax import monitoring
+
+        def duration(name, _secs, **_kw):
+            if name == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+                _compiles["compiles"] += 1
+
+        def event(name, **_kw):
+            if name == "/jax/compilation_cache/cache_hits":
+                _compiles["cache_hits"] += 1
+        monitoring.register_event_duration_secs_listener(duration)
+        monitoring.register_event_listener(event)
+        _compiles["listening"] = True
+    return {"compiles": _compiles["compiles"],
+            "cache_hits": _compiles["cache_hits"]}
+
+
+class _Span:
+    """One entry of a PhaseClock phase: the profiler range, and on exit
+    the phase's seconds and count."""
+    __slots__ = ("clock", "name", "note", "t0")
+
+    def __init__(self, clock: "PhaseClock", name: str):
+        self.clock, self.name = clock, name
+        self.note = annotate("hvd:" + name)
+
+    def __enter__(self):
+        self.note.__enter__()
+        self.t0 = time.perf_counter()
+
+    def __exit__(self, *exc):
+        c, dt = self.clock, time.perf_counter() - self.t0
+        c.phase_s[self.name] = c.phase_s.get(self.name, 0.0) + dt
+        c.phase_n[self.name] = c.phase_n.get(self.name, 0) + 1
+        self.note.__exit__(*exc)
+
+
+class PhaseClock:
+    """Cumulative seconds and entries per named phase of a host loop.
+    ``span(name)`` also opens the ``hvd:<name>`` profiler range, so inside
+    a trace session the phase lies on the device trace's own clock; with
+    none running that costs a flag test.  One thread enters spans; readers
+    take ``snapshot()`` and subtract (``delta``)."""
+
+    def __init__(self):
+        self.phase_s: Dict[str, float] = {}
+        self.phase_n: Dict[str, int] = {}
+        compile_counts()  # count compiles from the loop's first tick on
+
+    def span(self, name: str) -> _Span:
+        return _Span(self, name)
+
+    def snapshot(self) -> Dict[str, object]:
+        return dict(compile_counts(), phase_s=dict(self.phase_s),
+                    phase_n=dict(self.phase_n))
+
+    def delta(self, since: Dict[str, object]) -> Dict[str, object]:
+        """``snapshot()`` less the earlier snapshot ``since``."""
+        now = self.snapshot()
+        out = {k: now[k] - since.get(k, 0) for k in ("compiles", "cache_hits")}
+        for k in ("phase_s", "phase_n"):
+            out[k] = {p: v - since.get(k, {}).get(p, 0)
+                      for p, v in now[k].items()}
+        return out
+
+
 __all__ = ["start", "stop", "trace", "annotate", "annotate_function",
-           "is_active", "timed"]
+           "is_active", "timed", "compile_counts", "PhaseClock"]
