@@ -17,7 +17,10 @@ class ServeConfig:
     """Static shape/budget contract of one continuous-batching engine.
 
     ``max_slots`` and ``prefill_chunk`` fix the compiled step's shapes
-    (slot table height and chunk width); the knobs bound admission.
+    (slot table height and chunk width), ``spec_decode`` / ``spec_k`` the
+    width of its second, decode-only executable (1, or ``1 + spec_k``; the
+    engine picks one of the two per tick from its plan, serve/engine.py
+    ``tick_width``); the knobs bound admission.
     """
 
     port: int = 0

@@ -3,10 +3,12 @@
 The serving plane's core (docs/serving.md): one preallocated,
 mesh-sharded paged KV cache (models/llama.py ``init_cache``), a
 static-shape slot table, and ONE jit'd mixed prefill/decode step per
-tick.  Horovod's product was "wrap your optimizer, training scales"
-(arxiv 1802.05799); the serving analog here is "hand the engine your
-trained checkpoint, it serves" — no model rewrite, the same mesh,
-launcher and observability stack as training.
+tick, run at the chunk's width when the tick holds a prefill chunk and
+at a decode row's width when it holds none (``tick_width``).  Horovod's
+product was "wrap your optimizer, training scales" (arxiv 1802.05799);
+the serving analog here is "hand the engine your trained checkpoint, it
+serves" — no model rewrite, the same mesh, launcher and observability
+stack as training.
 
 Scheduling (in-flight/continuous batching, the Orca/vLLM discipline):
 
@@ -818,9 +820,30 @@ def decode_block_payload(enc: Dict[str, Any]) -> Dict[str, Any]:
 
 
 # ---------------------------------------------------------------- engine
+def decode_width(cfg: ServeConfig) -> int:
+    """Columns a decode row can fill: the bonus token + ``spec_k`` drafts
+    of a speculative verify row, else 1 (``validate`` holds it to
+    ``prefill_chunk``)."""
+    return 1 + cfg.spec_k if cfg.spec_decode else 1
+
+
+def tick_width(cfg: ServeConfig, work) -> int:
+    """Columns of one tick's token slab, read off its plan: the decode
+    width when every row of ``work`` fits it — decode rows, verify rows
+    and a prefill tail that short —, ``prefill_chunk`` when any row is
+    longer.  A pure function of the plan, which ``sched_digest`` folds
+    with every ``n``, so the ranks of a lockstep fleet agree on it."""
+    narrow = decode_width(cfg)
+    return narrow if max(n for _, _, n in work) <= narrow \
+        else cfg.prefill_chunk
+
+
 class ServeEngine:
     """The continuous-batching engine: host scheduler + one jit'd mixed
-    prefill/decode step over the paged cache.
+    prefill/decode step over the paged cache, compiled at two widths
+    (``tick_width``): a tick without a prefill chunk does not pay for
+    ``prefill_chunk`` positions a slot.  ``stats()["loop"]`` counts the
+    narrow ticks and their wait on the device.
 
     ``model`` is a model module exposing ``init_cache`` / ``apply_cached``
     (models/llama.py, models/moe_llama.py); ``model_cfg`` its config
@@ -871,6 +894,13 @@ class ServeEngine:
         from ..utils.profiler import PhaseClock
         self.clock = PhaseClock()
         self._step_fn = self._build_step()
+        # The step's executable at each tick width, both compiled at the
+        # first dispatch (_compile_steps): no later tick lowers anything.
+        self._steps: Dict[int, Any] = {}
+        # Ticks run at the decode width, and their share of the
+        # ``harvest_wait`` phase's seconds.
+        self._narrow_ticks = 0
+        self._narrow_wait_s = 0.0
         # One-deep tick pipeline (the loader.prefetch deque pattern):
         # holds (plan, device next-token array) until the next step()
         # harvests it, so host scheduling overlaps device compute.
@@ -939,6 +969,25 @@ class ServeEngine:
                                        self.cache),
                 self._repl))
 
+    def _compile_steps(self, staged) -> None:
+        """The step's executable at both widths of ``tick_width``, lowered
+        from the first dispatch's staged arguments with the token slab at
+        each width: a process's first decode-only tick (or its first
+        chunk, on a decode-role engine) finds its program ready."""
+        import jax
+        cfg = self.cfg
+        args = list(staged)
+        for width in {decode_width(cfg), cfg.prefill_chunk}:
+            args[3] = jax.ShapeDtypeStruct(     # the token slab
+                (cfg.max_slots, width), np.int32, sharding=self._repl)
+            self._steps[width] = self._step_fn.lower(
+                self.params, self.cache, *args).compile()
+
+    def _loop_snapshot(self) -> Dict[str, Any]:
+        """The phase clock's snapshot with the narrow-tick counters."""
+        return dict(self.clock.snapshot(), narrow_ticks=self._narrow_ticks,
+                    narrow_wait_s=self._narrow_wait_s)
+
     # ------------------------------------------------------------ intake
     def submit(self, tokens, max_new_tokens: int,
                req_id: Optional[str] = None,
@@ -948,7 +997,7 @@ class ServeEngine:
                       eos_id=eos_id if eos_id is not None
                       else self.cfg.eos_id)
         req.trace = trace
-        req.loop0 = self.clock.snapshot()
+        req.loop0 = self._loop_snapshot()
         return self.scheduler.submit(req)
 
     def has_work(self) -> bool:
@@ -1020,7 +1069,7 @@ class ServeEngine:
                               if handoff.get("eos_id") is not None
                               else self.cfg.eos_id))
         req.trace = handoff.get("trace")
-        req.loop0 = self.clock.snapshot()
+        req.loop0 = self._loop_snapshot()
         req.upstream = {k: float(handoff[k])
                         for k in ("queue_s", "prefill_s")
                         if handoff.get(k) is not None} or None
@@ -1079,7 +1128,7 @@ class ServeEngine:
             return
         cfg = self.cfg
         with self.clock.span("stage"):
-            S, C = cfg.max_slots, cfg.prefill_chunk
+            S, C = cfg.max_slots, tick_width(cfg, work)
             tokens = np.zeros((S, C), np.int32)
             lengths = np.zeros(S, np.int32)
             n_new = np.zeros(S, np.int32)
@@ -1104,7 +1153,9 @@ class ServeEngine:
                    for a in (np.asarray(self.scheduler.block_tables),
                              lengths, n_new, tokens, copy_src, copy_dst)]
         with self.clock.span("launch"):
-            self.cache, next_tokens = self._step_fn(
+            if not self._steps:
+                self._compile_steps(dev)
+            self.cache, next_tokens = self._steps[C](
                 self.params, self.cache, *dev)
         used = int(n_new.sum())
         self._last_fill = used / cfg.max_batch_tokens
@@ -1164,8 +1215,13 @@ class ServeEngine:
             return {"tick": None, "processed": 0, "emitted": {},
                     "finished": [], "handoff": []}
         tick, work, next_tokens, used = self._inflight.popleft()
+        waited = self.clock.phase_s.get("harvest_wait", 0.0)
         with self.clock.span("harvest_wait"):
             tokens_host = np.asarray(next_tokens)  # D2H fence for this tick
+        if next_tokens.shape[1] < self.cfg.prefill_chunk:
+            self._narrow_ticks += 1
+            self._narrow_wait_s += \
+                self.clock.phase_s["harvest_wait"] - waited
         with self.clock.span("harvest_emit"):
             return self._emit(tick, work, tokens_host, used)
 
@@ -1276,6 +1332,9 @@ class ServeEngine:
         d = self.clock.delta(req.loop0)
         req.loop = {
             "ticks": d["phase_n"].get("harvest_wait", 0),
+            "narrow_ticks": self._narrow_ticks - req.loop0["narrow_ticks"],
+            "narrow_wait_s": round(
+                self._narrow_wait_s - req.loop0["narrow_wait_s"], 6),
             "prefill_ticks": req.prefill_ticks,
             "phase_s": {k: round(v, 6) for k, v in d["phase_s"].items()},
             "compiles": d["compiles"]}
@@ -1394,7 +1453,7 @@ class ServeEngine:
                     if self._spec_drafted else None),
             },
         }
-        out["loop"] = dict(self.clock.snapshot(), ticks=self._ticks())
+        out["loop"] = dict(self._loop_snapshot(), ticks=self._ticks())
         if prefix is not None:
             out["prefix_cache"].update({
                 "hits": prefix.hits,

@@ -17,7 +17,9 @@ import pytest
 from horovod_tpu.serve.config import (ServeConfig, from_knobs,
                                       validate_serve_knobs)
 from horovod_tpu.serve.engine import (BlockAllocator, Request, Scheduler,
-                                      ServeEngine, cache_shardings)
+                                      ServeEngine, cache_shardings,
+                                      decode_width, tick_width)
+from horovod_tpu.utils.profiler import compile_counts
 
 
 def _cfg(**kw):
@@ -278,6 +280,138 @@ def test_engine_matches_reference_greedy_decode(family, llama_tiny,
     for i, (p, r) in enumerate(zip(prompts, reqs)):
         expect = _reference_greedy(model, cfg, params, p, 5)
         assert r.out_tokens == expect, f"req {i}"
+
+
+# ------------------------------------------------------ the two tick widths
+@pytest.mark.parametrize("spec,spec_k,rows,want", [
+    (True, 4, [1, 1, 5], 5),       # decode and a full verify row: narrow
+    (True, 4, [1, 6], 8),          # one row past the decode width: wide
+    (True, 4, [1, 3], 5),          # a 3-token prefill tail rides narrow
+    (True, 2, [3, 1], 3),          # the decode width follows spec_k
+    (True, 2, [4], 8),
+    (False, 4, [1, 1], 1),         # no speculation: one column
+    (False, 4, [1, 2], 8),
+    (False, 4, [8, 1], 8),         # a whole chunk beside a decode row
+])
+def test_tick_width_is_read_off_the_plan(spec, spec_k, rows, want):
+    cfg = _cfg(spec_decode=spec, spec_k=spec_k, prefill_chunk=8)
+    assert decode_width(cfg) == (1 + spec_k if spec else 1)
+    work = [(i, None, n) for i, n in enumerate(rows)]
+    assert tick_width(cfg, work) == want
+
+
+def _step_and_note_widths(engine, widths):
+    """One engine.step(), with the width of the tick it dispatched held
+    against tick_width of that tick's work list."""
+    engine.step()
+    if engine._inflight:    # one deep: what this step dispatched
+        _, work, next_tokens, _ = engine._inflight[-1]
+        assert next_tokens.shape == (engine.cfg.max_slots,
+                                     tick_width(engine.cfg, work))
+        widths.append(next_tokens.shape[1])
+
+
+@pytest.mark.parametrize("spec", [False, True], ids=["plain", "spec"])
+@pytest.mark.parametrize("family", ["llama", "moe"])
+def test_engine_at_two_widths_matches_reference_greedy(
+        family, spec, llama_tiny, moe_tiny, monkeypatch):
+    """Ticks with a prefill chunk run wide, ticks without run at the decode
+    width, in one request stream: every request's tokens are the full
+    forward's greedy ones.  Under speculation an oracle drafts the true
+    continuation for r0 (verify rows wholly accepted) and a wrong one for
+    the others (wholly rejected), so both outcomes pass a narrow row."""
+    model, cfg, params = llama_tiny if family == "llama" else moe_tiny
+    scfg = _cfg(max_slots=2, cache_blocks=32, max_seq_len=32,
+                max_batch_tokens=12, prefill_chunk=8, spec_decode=spec,
+                spec_k=2)
+    rng = np.random.RandomState(17)
+    prompts = [rng.randint(0, cfg.vocab, n).tolist() for n in (9, 4, 11)]
+    refs = [_reference_greedy(model, cfg, params, p, 6) for p in prompts]
+    if spec:
+        by_prompt = {tuple(p): r for p, r in zip(prompts, refs)}
+
+        def oracle(self, k):
+            done = len(self.out_tokens)
+            draft = by_prompt[tuple(self.tokens)][done:done + k]
+            return draft if self.req_id == "r0" else \
+                [(t + 1) % cfg.vocab for t in draft]
+        monkeypatch.setattr(Request, "draft_lookup", oracle)
+    engine = ServeEngine(model, cfg, params, scfg, mesh=_one_device_mesh())
+    widths = []
+    reqs = [engine.submit(prompts[0], 6, req_id="r0")]
+    for _ in range(4):      # chunk of 8, the 1-token tail, decode alone
+        _step_and_note_widths(engine, widths)
+    reqs += [engine.submit(p, 6, req_id=f"r{i + 1}")
+             for i, p in enumerate(prompts[1:])]
+    while engine.has_work():
+        _step_and_note_widths(engine, widths)
+    for r, ref in zip(reqs, refs):
+        assert r.state == "done" and r.out_tokens == ref, r.req_id
+    narrow = decode_width(scfg)
+    assert widths[:2] == [8, narrow]    # r0's tail of 1 rode a narrow tick
+    assert set(widths) == {8, narrow}
+    loop = engine.stats()["loop"]
+    assert loop["ticks"] == len(widths) == engine.tick
+    assert loop["narrow_ticks"] == widths.count(narrow)
+    assert 0.0 <= loop["narrow_wait_s"] <= loop["phase_s"]["harvest_wait"]
+    for r in reqs:
+        assert 1 <= r.loop["narrow_ticks"] < r.loop["ticks"]
+    if spec:
+        assert engine._spec_accepted >= 2 and \
+            engine._spec_drafted > engine._spec_accepted
+    engine.close()
+
+
+@pytest.mark.parametrize("first", ["wide", "narrow"])
+def test_both_widths_compile_at_the_first_dispatch(first, llama_tiny):
+    """Whatever the first tick holds, its dispatch builds both
+    executables: the first tick of the other width lowers nothing."""
+    model, cfg, params = llama_tiny
+    scfg = _cfg(max_slots=2, cache_blocks=32, max_seq_len=32,
+                max_batch_tokens=12, prefill_chunk=8, spec_k=2)
+    engine = ServeEngine(model, cfg, params, scfg, mesh=_one_device_mesh())
+    rng = np.random.RandomState(19)
+    long_, short = (rng.randint(0, cfg.vocab, n).tolist() for n in (9, 3))
+    assert engine._steps == {}
+    engine.submit(long_ if first == "wide" else short, 3, req_id="a")
+    engine.step()
+    assert set(engine._steps) == {3, 8}
+    lowered = compile_counts()["compiles"]
+    engine.flush()
+    engine.submit(short if first == "wide" else long_, 3, req_id="b")
+    engine.flush()
+    assert compile_counts()["compiles"] == lowered
+    loop = engine.stats()["loop"]
+    assert loop["ticks"] - loop["narrow_ticks"] == 1    # long_'s one chunk
+    assert loop["narrow_ticks"] >= 5
+    engine.close()
+
+
+def test_two_engines_fed_alike_agree_on_digest_and_widths(llama_tiny):
+    """The width is a function of the plan, and the plan is in the
+    digest: two engines given the same requests at the same ticks end on
+    one sched_digest, one tick count and one narrow-tick count."""
+    model, cfg, params = llama_tiny
+    scfg = _cfg(max_slots=2, cache_blocks=32, max_seq_len=32,
+                max_batch_tokens=12, prefill_chunk=8)
+    rng = np.random.RandomState(23)
+    prompts = [rng.randint(0, cfg.vocab, n).tolist() for n in (10, 5, 7)]
+    ends = []
+    for _ in range(2):
+        engine = ServeEngine(model, cfg, params, scfg,
+                             mesh=_one_device_mesh())
+        engine.submit(prompts[0], 5, req_id="r0")
+        engine.step()
+        engine.step()
+        for i, p in enumerate(prompts[1:]):
+            engine.submit(p, 4, req_id=f"r{i + 1}")
+        engine.flush()
+        loop = engine.stats()["loop"]
+        ends.append((engine.sched_digest, engine.tick, loop["ticks"],
+                     loop["narrow_ticks"]))
+        engine.close()
+    assert ends[0] == ends[1] and ends[0][0]
+    assert 0 < ends[0][3] < ends[0][2]
 
 
 def test_engine_block_reuse_and_eos_eviction(llama_tiny):

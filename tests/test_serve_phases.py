@@ -93,7 +93,8 @@ def served():
                          scfg, mesh=mesh)
     fe = FleetFrontend(engine, "127.0.0.1", port, 0, 1, direct=True)
 
-    seen = {"harvests": 0, "at_submit": {}, "first": {}, "last": {}}
+    seen = {"harvests": 0, "at_submit": {}, "first": {}, "last": {},
+            "widths": []}
     submit, harvest = engine.submit, engine._harvest
 
     def counted_submit(*a, **k):
@@ -101,9 +102,12 @@ def served():
         return submit(*a, **k)
 
     def counted_harvest():
+        width = (engine._inflight[0][2].shape[1] if engine._inflight
+                 else None)
         rep = harvest()
         if rep["tick"] is not None:
             seen["harvests"] += 1
+            seen["widths"].append(width)
             for rid in rep["emitted"]:
                 seen["first"].setdefault(rid, seen["harvests"])
             for req in rep["finished"]:
@@ -215,6 +219,39 @@ def test_compiles_on_the_first_tick_and_none_after_warm_up(served):
     assert served["done"]["cold"]["loop"]["compiles"] >= 1
     assert served["done"]["warm"]["loop"]["compiles"] == 0
     assert served["done"]["short"]["loop"]["compiles"] == 0
+
+
+def test_first_dispatch_lowers_both_widths(served):
+    # `cold` paid for the chunk's program and the decode-width one; its
+    # second tick (the 3-token tail) was the first narrow one
+    assert served["done"]["cold"]["loop"]["compiles"] >= 2
+    assert served["seen"]["widths"][:2] == [8, 5]
+    assert set(served["engine"]._steps) == {5, 8}
+
+
+def test_serve_stats_narrow_and_wide_ticks_add_up(served):
+    widths = served["seen"]["widths"]
+    for loop in (served["stats"]["engine"]["loop"],
+                 served["engine"].stats()["loop"]):
+        assert loop["narrow_ticks"] == widths.count(5)
+        assert loop["narrow_ticks"] + widths.count(8) == loop["ticks"]
+        assert 0.0 < loop["narrow_wait_s"] < loop["phase_s"]["harvest_wait"]
+    assert "narrow_ticks" not in loop["phase_n"]    # still one wait phase
+
+
+@pytest.mark.parametrize("name", ["cold", "warm", "short"])
+def test_request_narrow_ticks_equal_those_counted_outside(served, name):
+    done, seen = served["done"][name], served["seen"]
+    rid = done["trace"]["rid"]
+    life = seen["widths"][seen["at_submit"][rid]:seen["last"][rid]]
+    loop = done["loop"]
+    assert loop["narrow_ticks"] == life.count(5)
+    assert loop["narrow_ticks"] + life.count(8) == loop["ticks"]
+    # every request here decodes at least one token alone or beside
+    # another decode row, and none is prefilled in narrow ticks alone
+    # but `short`, whose 3-token prompt fits the decode width
+    assert loop["narrow_ticks"] >= 1
+    assert 0.0 <= loop["narrow_wait_s"] <= loop["phase_s"]["harvest_wait"]
 
 
 def test_scripted_engine_without_a_clock_is_served_as_before():
