@@ -77,25 +77,27 @@ def memory(key):
     return max((int(v) for v in vals if v is not None), default=0)
 
 
+def thread_placement():
+    """Where the calling thread runs and how often it was switched out: the
+    core it is on (field 39 of /proc/thread-self/stat), how many cores it
+    may use, and its voluntary and involuntary context switches so far.
+    For the notes of a run whose host phases read slow (PERF.md)."""
+    import resource
+    try:
+        with open("/proc/thread-self/stat") as f:
+            core = int(f.read().rsplit(")", 1)[1].split()[36])
+    except (OSError, ValueError, IndexError):
+        core = None
+    ru = resource.getrusage(resource.RUSAGE_THREAD)
+    return {"core": core, "cores_allowed": len(os.sched_getaffinity(0)),
+            "switches_voluntary": ru.ru_nvcsw,
+            "switches_involuntary": ru.ru_nivcsw,
+            "cpu_user_s": ru.ru_utime, "cpu_system_s": ru.ru_stime}
+
+
 def since_start():
     """Seconds since the parent started (its clock, in PB_T0)."""
     return time.time() - float(os.environ["PB_T0"])
-
-
-def llama_config(config, max_seq=None):
-    """The program's config object for a configuration file."""
-    import jax.numpy as jnp
-    from horovod_tpu.models import llama
-    return llama.LlamaConfig(
-        vocab=config["vocab_size"], dim=config["hidden_size"],
-        n_layers=config["num_hidden_layers"],
-        n_heads=config["num_attention_heads"],
-        n_kv_heads=config["num_key_value_heads"],
-        ffn_dim=config["intermediate_size"],
-        max_seq=max_seq or config["max_position_embeddings"],
-        rope_theta=float(config["rope_theta"]),
-        dtype={"bfloat16": jnp.bfloat16,
-               "float32": jnp.float32}[config["torch_dtype"]])
 
 
 def trace_options():

@@ -1,10 +1,10 @@
 """The plain reference: forward pass, mean cross-entropy, gradients and
-AdamW of a dense GQA decoder (RMSNorm pre-norm, rotary embedding in the
-rotate-half pairing, gated SiLU FFN, no biases, untied head) in straight
-jax.numpy, float32, matmul precision "highest", no cache, no kernels.  It
-imports nothing of the program and takes nothing the program made: weights
-come from the seed (lib/weights.py), one layer at a time, so that it fits
-beside or before the program's state.
+AdamW of a decoder in straight jax.numpy, float32, matmul precision
+"highest", no cache, no kernels.  The embedding, a layer and the head are the
+equations of the configuration's family (families/); what is here drives
+them.  It imports nothing of the program and takes nothing the program made:
+weights come from the seed (lib/weights.py), one layer at a time, so that it
+fits beside or before the program's state.
 
 ``quant`` swaps every linear layer's matmul for a lower-precision one
 (the control of "How correct is decided"); None is the reference itself.
@@ -13,14 +13,13 @@ beside or before the program's state.
 from __future__ import annotations
 
 import functools
-import math
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import weights as W
+from . import spec, weights as W
 
 F32 = jnp.float32
 
@@ -47,57 +46,6 @@ def plain_mm(x, w):
 MATMULS = {None: plain_mm, "int8": int8_mm}
 
 
-# --------------------------------------------------------------------- model
-def _dims(config):
-    d = config["hidden_size"]
-    H, KV = config["num_attention_heads"], config["num_key_value_heads"]
-    return d, H, KV, d // H
-
-
-def _eps(config):
-    # the program's layers.rmsnorm fixes eps; see the configuration's `assumed`
-    return float(config.get("assumed", {}).get("rms_norm_eps",
-                                               config["rms_norm_eps"]))
-
-
-def rmsnorm(x, scale, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
-
-
-def rope(x, theta):
-    """x: [B, S, heads, hd] at positions 0..S-1; rotate-half pairing."""
-    S, hd = x.shape[1], x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=F32) / hd))
-    ang = jnp.arange(S, dtype=F32)[:, None] * inv[None, :]
-    c, s = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], -1)
-
-
-def layer(p, x, config, mm):
-    """One decoder layer on x [B, S, d]; p holds the layer's nine leaves."""
-    d, H, KV, hd = _dims(config)
-    B, S, _ = x.shape
-    eps, theta = _eps(config), float(config["rope_theta"])
-    h = rmsnorm(x, p["attn_norm.scale"], eps)
-    q = rope(mm(h, p["wq.kernel"]).reshape(B, S, H, hd), theta)
-    k = rope(mm(h, p["wk.kernel"]).reshape(B, S, KV, hd), theta)
-    v = mm(h, p["wv.kernel"]).reshape(B, S, KV, hd)
-    k = jnp.repeat(k, H // KV, axis=2)
-    v = jnp.repeat(v, H // KV, axis=2)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(hd)
-    s = jnp.where(jnp.tril(jnp.ones((S, S), bool))[None, None], s, -jnp.inf)
-    o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
-    x = x + mm(o.reshape(B, S, H * hd), p["wo.kernel"])
-    h = rmsnorm(x, p["ffn_norm.scale"], eps)
-    g = jax.nn.silu(mm(h, p["w_gate.kernel"])) * mm(h, p["w_up.kernel"])
-    return x + mm(g, p["w_down.kernel"])
-
-
-def head_logits(final_scale, head, x, config, mm):
-    return mm(rmsnorm(x, final_scale, _eps(config)), head)
-
-
 def _highest(fn):
     @functools.wraps(fn)
     def wrapped(*a, **k):
@@ -110,11 +58,10 @@ class Weights:
     """Seeded leaves by name, regenerated on demand in float32."""
 
     def __init__(self, config, seed, put=None):
-        self.specs = {n: (i, s, std)
-                      for i, (n, s, std) in enumerate(W.leaf_specs(config))}
+        self.specs = {n: (i, s, std) for i, (n, s, std) in enumerate(
+            spec.family(config).leaf_specs(config))}
         self.key = W.seed_key(seed)
-        self.dtype = {"bfloat16": jnp.bfloat16, "float32": F32}[
-            config.get("torch_dtype", "bfloat16")]
+        self.dtype = W.dtype_of(config)
         self.put = put or (lambda x: x)
 
     def __call__(self, name):
@@ -127,18 +74,28 @@ class Weights:
         pre = f"layers.{i}."
         return {n[len(pre):]: self(n) for n in self.specs if n.startswith(pre)}
 
+    def part(self, names):
+        return {n: self(n) for n in names}
+
+
+def layer_steps(config, mm):
+    """[one jitted ``step(p, x)`` a layer]; layers of one kind share theirs."""
+    fam = spec.family(config)
+    kinds = fam.layer_kinds(config)
+    steps = {kind: jax.jit(_highest(functools.partial(
+        fam.layer, kind, config=config, mm=mm))) for kind in set(kinds)}
+    return [steps[kind] for kind in kinds]
+
 
 # ------------------------------------------------------------------- serving
 def hidden_states(config, w, seqs, quant=None, row_block=4):
     """The last layer's output [R, T, d] for token rows ``seqs`` [R, T],
     layer by layer from the seeded weights ``w``."""
-    mm = MATMULS[quant]
-    step = jax.jit(_highest(functools.partial(layer, config=config, mm=mm)))
-    table = w("embed.table")
-    xs = [jnp.take(table, jnp.asarray(seqs[i:i + row_block]), axis=0)
+    fam = spec.family(config)
+    p = w.part(fam.EMBED)
+    xs = [fam.embed(p, jnp.asarray(seqs[i:i + row_block]), config)
           for i in range(0, len(seqs), row_block)]
-    del table
-    for i in range(config["num_hidden_layers"]):
+    for i, step in enumerate(layer_steps(config, MATMULS[quant])):
         p = w.layer(i)
         xs = [step(p, x) for x in xs]
     return jnp.concatenate(xs, 0)
@@ -149,10 +106,9 @@ def logits_at(config, seed, seq, positions, quant=None):
     the control's with ``quant``)."""
     w = Weights(config, seed)
     x = hidden_states(config, w, np.asarray([seq], np.int32), quant)[0]
-    f = jax.jit(_highest(lambda s, h, x: head_logits(s, h, x, config,
-                                                     MATMULS[quant])))
-    return f(w("final_norm.scale"), w("lm_head.kernel"),
-             x[np.asarray(positions)])
+    fam = spec.family(config)
+    f = jax.jit(_highest(lambda p, x: fam.head(p, x, config, MATMULS[quant])))
+    return f(w.part(fam.HEAD), x[np.asarray(positions)])
 
 
 def generated_logit_stats(config, seed, seqs, spans, tokens_of, quant=None,
@@ -170,15 +126,16 @@ def generated_logit_stats(config, seed, seqs, spans, tokens_of, quant=None,
     w = Weights(config, seed)
     passes = [None] + ([quant] if tokens_of == "quant" else [])
     hidden = {q: hidden_states(config, w, seqs, q, row_block) for q in passes}
-    scale, head = w("final_norm.scale"), w("lm_head.kernel")
+    fam = spec.family(config)
+    head = w.part(fam.HEAD)
 
     @jax.jit
     @_highest
-    def stats(scale, head, x_ref, x_alt, served):
-        z = head_logits(scale, head, x_ref, config, plain_mm)
+    def stats(head, x_ref, x_alt, served):
+        z = fam.head(head, x_ref, config, plain_mm)
         if tokens_of == "quant":
-            tok = jnp.argmax(head_logits(scale, head, x_alt, config,
-                                         MATMULS[quant]), -1)
+            tok = jnp.argmax(fam.head(head, x_alt, config, MATMULS[quant]),
+                             -1)
         else:
             tok = served
         top = jnp.max(z, -1)
@@ -191,7 +148,7 @@ def generated_logit_stats(config, seed, seqs, spans, tokens_of, quant=None,
         pos = np.minimum(first + np.arange(nmax), T - 1)
         served = np.zeros(nmax, np.int32)
         served[:n] = seqs[r, first + 1:first + 1 + n]
-        g, f = stats(scale, head, hidden[None][r, pos],
+        g, f = stats(head, hidden[None][r, pos],
                      hidden[passes[-1]][r, pos], jnp.asarray(served))
         gaps.extend(np.asarray(g)[:n].tolist())
         flips.extend(np.asarray(f)[:n].tolist())
@@ -199,8 +156,8 @@ def generated_logit_stats(config, seed, seqs, spans, tokens_of, quant=None,
 
 
 # ------------------------------------------------------------------ training
-def _top(final_scale, head, x, targets, denom, config, mm):
-    z = head_logits(final_scale, head, x, config, mm)
+def _top(fam, head, x, targets, denom, config, mm):
+    z = fam.head(head, x, config, mm)
     lse = jax.nn.logsumexp(z, -1)
     tgt = jnp.take_along_axis(z, targets[..., None], -1)[..., 0]
     return jnp.sum(lse - tgt) / denom
@@ -220,7 +177,7 @@ def train_steps(config, seed, batches, opt, devices=None, quant=None,
     spread over them and the compiler adds the reduction."""
     devices = devices or jax.devices()[:1]
     n = len(devices)
-    mm = MATMULS[quant]
+    mm, fam = MATMULS[quant], spec.family(config)
     if n > 1:
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
         mesh = Mesh(np.array(devices), ("x",))
@@ -250,24 +207,23 @@ def train_steps(config, seed, batches, opt, devices=None, quant=None,
     params = {k: w(k) for k in names}
     m = {k: jnp.zeros_like(v) for k, v in params.items()}
     v2 = {}      # second moments, stashed; absent means zero
-    L = config["num_hidden_layers"]
+    fwds, kinds = layer_steps(config, mm), fam.layer_kinds(config)
+    layer_names = [[k for k in names if k.startswith(f"layers.{i}.")]
+                   for i in range(len(fwds))]
     b1, b2, eps = opt["b1"], opt["b2"], opt["eps"]
     lr, wd = opt["lr"], opt["weight_decay"]
 
-    fwd = jax.jit(_highest(functools.partial(layer, config=config, mm=mm)))
-
-    @jax.jit
+    @functools.partial(jax.jit, static_argnums=(0,))
     @_highest
-    def bwd(p, x, ct):
-        _, pull = jax.vjp(lambda p, x: layer(p, x, config, mm), p, x)
+    def bwd(kind, p, x, ct):
+        _, pull = jax.vjp(lambda p, x: fam.layer(kind, p, x, config, mm), p, x)
         return pull(ct)
 
-    @functools.partial(jax.jit, static_argnums=(4,))
+    @functools.partial(jax.jit, static_argnums=(3,))
     @_highest
-    def top(scale, head, x, targets, denom):
+    def top(head, x, targets, denom):
         loss, pull = jax.vjp(
-            lambda s, h, x: _top(s, h, x, targets, denom, config, mm),
-            scale, head, x)
+            lambda h, x: _top(fam, h, x, targets, denom, config, mm), head, x)
         return (loss,) + pull(jnp.ones((), F32))
 
     @functools.partial(jax.jit, donate_argnums=(0, 2))
@@ -279,9 +235,11 @@ def train_steps(config, seed, batches, opt, devices=None, quant=None,
 
     add = jax.jit(lambda a, b: jax.tree_util.tree_map(jnp.add, a, b),
                   donate_argnums=(0,))
-    embed_grad = jax.jit(
-        lambda shape_like, ids, ct: jnp.zeros_like(shape_like)
-        .at[ids.reshape(-1)].add(ct.reshape(-1, ct.shape[-1])))
+    embed_grad = jax.jit(lambda p, ids, ct: jax.vjp(
+        lambda p: fam.embed(p, ids, config), p)[1](ct)[0])
+
+    def part(names_, prefix=""):
+        return {k[len(prefix):]: params[k] for k in names_}
 
     out = {"loss": [], "mnorm": [], "step_s": []}
     for t, batch in enumerate(batches, start=1):
@@ -290,11 +248,10 @@ def train_steps(config, seed, batches, opt, devices=None, quant=None,
         B, S = batch.shape[0], batch.shape[1] - 1
         rb = rows_per_device * n
         blocks = [put_rows(batch[i:i + rb]) for i in range(0, B, rb)]
-        acts = [[jnp.take(params["embed.table"], b[:, :-1], axis=0)]
+        acts = [[fam.embed(part(fam.EMBED), b[:, :-1], config)]
                 for b in blocks]
-        for i in range(L):
-            p = {k[len(f"layers.{i}."):]: params[k] for k in names
-                 if k.startswith(f"layers.{i}.")}
+        for i, fwd in enumerate(fwds):
+            p = part(layer_names[i], f"layers.{i}.")
             for a in acts:
                 a.append(fwd(p, a[-1]))
         mnorm, tt = {}, jnp.asarray(t, F32)
@@ -305,33 +262,31 @@ def train_steps(config, seed, batches, opt, devices=None, quant=None,
             if t < len(batches):
                 v2[k] = stash(v)
 
-        loss, gs, gh, cts = 0.0, None, None, []
+        loss, g, cts = 0.0, None, []
         for b, a in zip(blocks, acts):
-            l, g_s, g_h, ct = top(params["final_norm.scale"],
-                                  params["lm_head.kernel"], a.pop(),
-                                  b[:, 1:], B * S)
+            l, gp, ct = top(part(fam.HEAD), a.pop(), b[:, 1:], B * S)
             loss += float(l)
-            gs, gh = (g_s, g_h) if gs is None else add((gs, gh), (g_s, g_h))
+            g = gp if g is None else add(g, gp)
             cts.append(ct)
-        update("final_norm.scale", gs)
-        update("lm_head.kernel", gh)
-        del gs, gh
-        for i in reversed(range(L)):
+        for k in fam.HEAD:
+            update(k, g[k])
+        for i in reversed(range(len(fwds))):
             pre = f"layers.{i}."
-            p = {k[len(pre):]: params[k] for k in names if k.startswith(pre)}
+            p = part(layer_names[i], pre)
             g = None
             for j, a in enumerate(acts):
-                gp, cts[j] = bwd(p, a.pop(), cts[j])
+                gp, cts[j] = bwd(kinds[i], p, a.pop(), cts[j])
                 g = gp if g is None else add(g, gp)
             for k in g:
                 update(pre + k, g[k])
-            del g, p
-        ge = None
+            del p
+        g = None
         for b, ct in zip(blocks, cts):
-            g1 = embed_grad(params["embed.table"], b[:, :-1], ct)
-            ge = g1 if ge is None else add(ge, g1)
-        update("embed.table", ge)
-        del ge, cts, acts
+            gp = embed_grad(part(fam.EMBED), b[:, :-1], ct)
+            g = gp if g is None else add(g, gp)
+        for k in fam.EMBED:
+            update(k, g[k])
+        del g, cts, acts
         out["loss"].append(loss)
         out["step_s"].append(time.perf_counter() - t_step)
         out["mnorm"].append({k: float(x) for k, x in mnorm.items()})

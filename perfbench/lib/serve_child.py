@@ -56,7 +56,6 @@ def main(argv=None):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     import horovod_tpu as hvd
-    from horovod_tpu.models import llama
     from horovod_tpu.serve.config import ServeConfig
     from horovod_tpu.serve.engine import ServeEngine
     from horovod_tpu.serve.worker import FleetFrontend
@@ -66,14 +65,15 @@ def main(argv=None):
     hvd.init()
     mesh = hvd.mesh()
     child.say(phase="hvd.init done", at_s=child.since_start())
-    cfg = child.llama_config(config)
-    params = jax.jit(lambda key: weights.make(config, key, cfg.dtype),
+    model, cfg = spec.family(config).program(config)
+    params = jax.jit(lambda key: weights.make(config, key,
+                                              weights.dtype_of(config)),
                      out_shardings=NamedSharding(mesh, P()))(
         weights.seed_key(args.seed))
     jax.block_until_ready(params)
     child.say(phase="weights made", at_s=child.since_start())
     scfg = ServeConfig(**config["engine"])
-    engine = ServeEngine(llama, cfg, params, scfg, mesh=mesh)
+    engine = ServeEngine(model, cfg, params, scfg, mesh=mesh)
     frontend = FleetFrontend(engine, "127.0.0.1", args.port, 0, 1,
                              drain_timeout_s=traffic["drain_timeout_s"])
     if args.trace:
@@ -89,7 +89,7 @@ def main(argv=None):
         def bad():
             rep = harvest()
             for toks in rep["emitted"].values():
-                toks[:] = [(t + 1) % cfg.vocab for t in toks]
+                toks[:] = [(t + 1) % config["vocab_size"] for t in toks]
             return rep
         engine._harvest = bad
     jax.block_until_ready(params)
@@ -114,6 +114,8 @@ def main(argv=None):
                     "tokens_decode": st["tokens_decode"],
                     "spec": st["spec"], "prefix_cache": st["prefix_cache"],
                     "completed": st["completed"],
+                    # the whole record, for readers that arrive later
+                    "stats": st,
                     "lowerings": counter.lowerings,
                     "hbm_peak": child.memory("peak_bytes_in_use")}
                 child.emit("mark", name=cmd[1])
@@ -135,10 +137,12 @@ def main(argv=None):
     ctl.start()
     frontend._publish_stats(force=True)     # readiness, as worker.main does
     child.emit("ready")
+    child.say(loop_thread_before=child.thread_placement())
     try:
         frontend.run()
     finally:
         engine.close()
+    child.say(loop_thread_after=child.thread_placement())
     peak = child.memory("peak_bytes_in_use")
     child.emit("stopped", marks=marks, hbm_peak=peak,
                cache_hits=counter.cache_hits)

@@ -30,6 +30,13 @@ def window_gaps(ctx):
     return out
 
 
+def gap_quantiles_ms(ctx, qs=(50, 90, 95, 98, 99, 99.5, 100)):
+    """(how many gaps, {quantile: ms}) of a window, for the run's notes."""
+    gaps = window_gaps(ctx)
+    return len(gaps), {q: round(1e3 * (stats.percentile(gaps, q) or 0), 1)
+                       for q in qs}
+
+
 def window_tokens(ctx):
     return sum(n for r in ctx["records"]
                for t, n in zip(r["part_t"], r["part_n"])

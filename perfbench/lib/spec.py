@@ -2,7 +2,8 @@
 
 A cell is one entry of ``workloads``: a configuration (``configs/<name>.json``)
 under a traffic mix (``traffic/<name>.json``).  Per-layer metrics are readers
-(``metrics/<name>.py``).  Nothing here imports jax or the program.
+(``metrics/<name>.py``), and a configuration's model family is a file
+(``families/<family>.py``).  Nothing here imports jax or the program.
 """
 
 from __future__ import annotations
@@ -13,6 +14,10 @@ import os
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
+# where family files are looked for, in order; the benchmark's tests add the
+# directory of the family that only they use
+FAMILY_DIRS = [os.path.join(BENCH_DIR, "families")]
+_families = {}
 
 
 def load_json(path):
@@ -48,25 +53,43 @@ def cell_metrics(name, bench=None):
             [m for m in bench["per_layer"] if mine(m)])
 
 
+def _load(path, module_name):
+    spec = importlib.util.spec_from_file_location(module_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def metric_reader(name):
     """The ``read(ctx)`` of ``metrics/<name>.py``; returns a number, or None
     when there is nothing to read."""
-    path = os.path.join(BENCH_DIR, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "perfbench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(os.path.join(BENCH_DIR, "metrics", name + ".py"),
+                 "perfbench_metric_" + name.replace(".", "_").replace("-", "_")
+                 ).read
+
+
+def family(config):
+    """The module ``families/<family>.py`` of a configuration (its ``family``
+    key; a file without the key is a dense GQA decoder).  What it gives is
+    set out in families/__init__.py."""
+    name = config.get("family", "dense_gqa")
+    if name not in _families:
+        path = next((p for p in (os.path.join(d, name + ".py")
+                                 for d in FAMILY_DIRS) if os.path.isfile(p)),
+                    None)
+        if path is None:
+            raise SystemExit(f"perfbench: no family file {name}.py under "
+                             + ", ".join(FAMILY_DIRS))
+        _families[name] = _load(path, "perfbench_family_" + name)
+    return _families[name]
 
 
 def tiny(config):
-    """The CPU rehearsal's copy of a configuration: same keys, toy sizes,
+    """The CPU rehearsal's copy of a configuration: the family's toy sizes in
     float32 (the limits are read at the published widths in bfloat16; a toy
-    in bfloat16 rounds coarser than they allow).  Never used on a chip."""
-    out = dict(config)
-    out.update(hidden_size=64, intermediate_size=128, num_hidden_layers=2,
-               num_attention_heads=4, num_key_value_heads=2, vocab_size=256,
-               max_position_embeddings=256, torch_dtype="float32")
+    in bfloat16 rounds coarser than they allow) under a toy engine.  Never
+    used on a chip."""
+    out = family(config).tiny(config)
     if "engine" in out:
         out["engine"] = dict(out["engine"], max_slots=4, prefill_chunk=16,
                              max_batch_tokens=64, block_size=4,

@@ -140,9 +140,7 @@ def reduce_device(ops, lo, hi, host=(), async_ops=()):
                 cover[n] = cover.get(n, 0.0) + o
         idle.append((max(cover, key=cover.get) if cover else "unattributed",
                      e - s))
-    by_op = {}
-    for n, t in self_times(ops).items():
-        by_op[n] = by_op.get(n, 0.0) + t
+    by_op = sorted(self_times(ops).items(), key=lambda kv: -kv[1])
     return {"busy_s": total(busy), "window_s": hi - lo,
             "collective_s": total(coll),
             "exposed_collective_s": total(subtract(coll, compute)),
@@ -150,7 +148,9 @@ def reduce_device(ops, lo, hi, host=(), async_ops=()):
                                     if COLLECTIVE.search(n)
                                     and not re.search(r"[-_]done", n)),
             "idle_gaps": idle,
-            "device_ops": sorted(by_op.items(), key=lambda kv: -kv[1])[:10]}
+            # every operation's self time by short name, most first, for a
+            # reader that looks a kernel up by its name; the breakdown's ten
+            "ops_s": dict(by_op), "device_ops": by_op[:10]}
 
 
 def combine(per_device):
@@ -160,6 +160,7 @@ def combine(per_device):
            for k in ("busy_s", "window_s", "collective_s",
                      "exposed_collective_s", "collective_count")}
     out["device_ops"] = [[k, v] for k, v in per_device[0]["device_ops"]]
+    out["ops_s"] = per_device[0]["ops_s"]
     out["idle_gaps"] = [[k, v] for k, v in per_device[0]["idle_gaps"]]
     return out
 
@@ -211,7 +212,7 @@ def reduce_trace(trace_dir, module=None, dry=False):
         # the readers run; the parent prints no value from a dry run
         return {"busy_s": 0.5, "window_s": 1.0, "collective_s": 0.0,
                 "exposed_collective_s": 0.0, "collective_count": 0.0,
-                "device_ops": [], "idle_gaps": [], "module_s": 0.5,
+                "device_ops": [], "ops_s": {}, "idle_gaps": [], "module_s": 0.5,
                 "module_count": 1.0, "trace_lo": 0.0, "trace_hi": 1.0,
                 "outline": [list(o) for o in outline if o[2]][:40]}
     if not devices:

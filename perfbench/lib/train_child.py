@@ -7,7 +7,6 @@ from __future__ import annotations
 
 import argparse
 import collections
-import functools
 import shutil
 import tempfile
 import threading
@@ -55,19 +54,6 @@ class Feed:
         self._ready = self._thread = None
 
 
-def attention_fn(name):
-    """traffic "attention" -> the program's attn_fn."""
-    if name == "xla-f32-scores":
-        return None
-    if name == "xla-input-scores":
-        from horovod_tpu.models import layers
-        return functools.partial(layers.causal_attention, score_dtype=None)
-    if name == "flash":
-        from horovod_tpu.ops.flash_attention import flash_attention
-        return flash_attention
-    raise SystemExit(f"unknown attention {name!r}")
-
-
 class Program:
     """What `python bench.py` builds, on seeded weights and a seeded feed:
     hvd.init, hvd.mesh, adamw inside make_scanned_train_step's
@@ -81,7 +67,6 @@ class Program:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         import horovod_tpu as hvd
-        from horovod_tpu.models import llama
         from horovod_tpu.parallel.data_parallel import (
             make_scanned_train_step, shard_batch)
 
@@ -95,19 +80,12 @@ class Program:
         hvd.init()
         mesh = hvd.mesh()
         child.say(phase="hvd.init done", at_s=child.since_start())
-        cfg = child.llama_config(config, max_seq=max(S, 128))
-        attn_fn = attention_fn(traffic["attention"])
-
-        def loss_fn(p, ids):
-            return llama.loss_fn(p, ids, cfg, attn_fn=attn_fn,
-                                 remat=traffic["remat"],
-                                 ce_chunks=traffic["ce_chunks"])
-
+        loss_fn = spec.family(config).loss(config, traffic)
         self.opt = optax.adamw(o["lr"], b1=o["b1"], b2=o["b2"], eps=o["eps"],
                                weight_decay=o["weight_decay"])
         self.run = make_scanned_train_step(loss_fn, self.opt, mesh)
         self.rep = NamedSharding(mesh, P())
-        self.dtype = cfg.dtype
+        self.dtype = weights.dtype_of(config)
         self.put = lambda host: shard_batch(jnp.asarray(host), mesh, axis=1)
         self.shape = (K, B, S + 1)
         self._make = jax.jit(

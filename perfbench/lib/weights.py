@@ -1,36 +1,23 @@
-"""Seeded weights of a dense GQA decoder (gated SiLU FFN, no biases, untied
-head), as the benchmark makes them: for the program the whole tree in one
-jitted call in the serving/training type, for the reference one leaf at a
-time.  Both read ``leaf``, so the same seed gives the same values."""
+"""Seeded weights, as the benchmark makes them: for the program the whole
+tree in one jitted call in the serving/training type, for the reference one
+leaf at a time.  Both read ``leaf``, so the same seed gives the same values.
+Which leaves a model has is its family's ``leaf_specs`` (families/)."""
 
 from __future__ import annotations
 
-import math
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from . import spec
 
 
-def leaf_specs(config):
-    """[(name, shape, std or None for a norm scale of ones)] in tree order.
-    Names are the program's pytree paths joined by dots."""
-    d, V = config["hidden_size"], config["vocab_size"]
-    hd = d // config["num_attention_heads"]
-    nq, nkv = d, config["num_key_value_heads"] * hd
-    f = config["intermediate_size"]
-    s = 1.0 / math.sqrt(d)
-    out = [("embed.table", (V, d), 0.02),
-           ("final_norm.scale", (d,), None),
-           ("lm_head.kernel", (d, V), s)]
-    for i in range(config["num_hidden_layers"]):
-        p = f"layers.{i}."
-        out += [(p + "attn_norm.scale", (d,), None),
-                (p + "wq.kernel", (d, nq), s), (p + "wk.kernel", (d, nkv), s),
-                (p + "wv.kernel", (d, nkv), s), (p + "wo.kernel", (nq, d), s),
-                (p + "ffn_norm.scale", (d,), None),
-                (p + "w_gate.kernel", (d, f), s), (p + "w_up.kernel", (d, f), s),
-                (p + "w_down.kernel", (f, d), 1.0 / math.sqrt(f))]
-    return out
+def dtype_of(config):
+    """The type the configuration states (its ``torch_dtype``)."""
+    return {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[
+        config.get("torch_dtype", "bfloat16")]
 
 
 def seed_key(seed):
@@ -52,40 +39,69 @@ def leaf(key, index, shape, std, dtype):
 leaf_jit = jax.jit(leaf, static_argnums=(2, 3, 4))
 
 
+def draw(specs, key, dtype):
+    """{name: leaf} for ``specs`` [(name, shape, std)], leaf ``i`` with the
+    bits of ``leaf(key, i, ...)``.  Leaves whose names differ only in a
+    number (a layer's or an expert's index) and that share shape and std are
+    drawn in one vmapped call, so that layers of several kinds still make a
+    small program."""
+    groups = {}
+    for i, (name, shape, std) in enumerate(specs):
+        sig = (re.sub(r"(?<=\.)\d+(?=\.)", "#", name), tuple(shape), std)
+        groups.setdefault(sig, []).append(i)
+    out = {}
+    for (_, shape, std), idx in groups.items():
+        if len(idx) == 1:
+            out[specs[idx[0]][0]] = leaf(key, idx[0], shape, std, dtype)
+            continue
+        # evenly spaced (layers of one kind): computed, since an array of
+        # constants makes the program's code 100 KB larger on the chip, which
+        # stays in HBM beside the state and moved a training step by 0.06%
+        stride = idx[1] - idx[0]
+        if all(b - a == stride for a, b in zip(idx, idx[1:])):
+            at = jnp.arange(len(idx)) * stride + idx[0]
+        else:
+            at = jnp.asarray(np.asarray(idx, np.int32))
+        stacked = jax.vmap(lambda i: leaf(key, i, shape, std, dtype))(at)
+        for k, i in enumerate(idx):
+            out[specs[i][0]] = stacked[k]
+    return out
+
+
+def tree(named):
+    """The pytree that dotted names spell: a part that is a number indexes a
+    list, any other part a dict."""
+    root = {}
+    for name, value in named.items():
+        parts = name.split(".")
+        node = root
+        for a in parts[:-1]:
+            node = node.setdefault(a, {})
+        node[parts[-1]] = value
+
+    def lists(node):
+        if not isinstance(node, dict):
+            return node
+        if node and all(k.isdigit() for k in node):
+            return [lists(node[str(i)]) for i in range(len(node))]
+        return {k: lists(v) for k, v in node.items()}
+    return lists(root)
+
+
 def make(config, key, dtype=jnp.bfloat16):
-    """The program's parameter pytree (horovod_tpu.models.llama layout)
-    from ``key = seed_key(seed)``.  Call under ``jax.jit`` with the wanted
-    out_shardings and the key as an ARGUMENT: a seed closed over would be a
-    constant of the program, and every seed would compile anew.  Leaves of one
-    kind are drawn for all layers in one vmapped call (the same values as
-    ``leaf`` gives one by one), so the program stays small."""
-    specs = leaf_specs(config)
-    L = config["num_hidden_layers"]
-    per = (len(specs) - 3) // L
-    tree = {"layers": [{} for _ in range(L)]}
-
-    def put(node, name, value):
-        a, b = name.split(".")[-2:]
-        node.setdefault(a, {})[b] = value
-
-    for index in range(3):
-        name, shape, std = specs[index]
-        put(tree, name, leaf(key, index, shape, std, dtype))
-    for k in range(per):
-        name, shape, std = specs[3 + k]
-        idx = jnp.arange(L) * per + 3 + k
-        stacked = jax.vmap(lambda i: leaf(key, i, shape, std, dtype))(idx)
-        for i in range(L):
-            put(tree["layers"][i], name, stacked[i])
-    return tree
+    """The program's parameter pytree from ``key = seed_key(seed)``.  Call
+    under ``jax.jit`` with the wanted out_shardings and the key as an
+    ARGUMENT: a seed closed over would be a constant of the program, and
+    every seed would compile anew."""
+    return tree(draw(spec.family(config).leaf_specs(config), key, dtype))
 
 
-def flat(tree):
-    """{dotted name: leaf} of a pytree laid out as ``make`` lays it."""
+def flat(params):
+    """{dotted name: leaf} of a pytree laid out as ``tree`` lays it."""
     def part(k):
         for attr in ("key", "idx", "name"):
             if hasattr(k, attr):
                 return str(getattr(k, attr))
         return str(k)
-    leaves, _ = jax.tree_util.tree_flatten_with_path(tree)
+    leaves, _ = jax.tree_util.tree_flatten_with_path(params)
     return {".".join(part(k) for k in path): x for path, x in leaves}

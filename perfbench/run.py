@@ -28,7 +28,8 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, ROOT)
 
-from perfbench.lib import checks, loadgen, peaks, spec, stats, traffic as T  # noqa: E402
+from perfbench.lib import (  # noqa: E402
+    checks, loadgen, peaks, serve_math, spec, stats, traffic as T)
 from perfbench.lib.child import TAG  # noqa: E402
 
 
@@ -180,7 +181,9 @@ def pick_sample(records, reqs, seed, k):
 
 def run_serve(args, entry, config, traffic):
     env = child_env(args, entry["chips"])
-    vocab = 256 if args.dry_run else config["vocab_size"]
+    # the sizes the server runs at: the rehearsal's toy copy on the CPU
+    sizes = spec.tiny(config) if args.dry_run else config
+    vocab = sizes["vocab_size"]
     mix = traffic
     if args.dry_run:
         mix = dict(traffic,
@@ -213,7 +216,7 @@ def run_serve(args, entry, config, traffic):
 
         # warm up the one tick shape: prefill over two chunks, then decode
         rng = random.Random(int(args.seed) + 1)
-        chunk = (16 if args.dry_run else config["engine"]["prefill_chunk"])
+        chunk = sizes["engine"]["prefill_chunk"]
         warm = loadgen.Client("127.0.0.1", port, [
             {"due": 0.0, "after": None, "max_new_tokens": 8,
              "tokens": [rng.randrange(vocab) for _ in range(chunk + 8)]}],
@@ -271,6 +274,9 @@ def run_serve(args, entry, config, traffic):
             os.makedirs(os.environ["PB_DEBUG_DIR"], exist_ok=True)
             shutil.copy(path, os.path.join(os.environ["PB_DEBUG_DIR"],
                                            f"sample-{args.seed}.json"))
+            with open(os.path.join(os.environ["PB_DEBUG_DIR"],
+                                   f"marks-{args.seed}.json"), "w") as f:
+                json.dump(stopped["marks"], f)
         server.send("check " + path)
         checked = server.expect("checked", args.limit)
         os.unlink(path)
@@ -315,6 +321,8 @@ def run_serve(args, entry, config, traffic):
            "marks": marks, "config": config, "traffic": traffic,
            "entry": entry, "trace": tr,
            "peaks": None if args.dry_run else peaks.device_peaks(device["kind"])}
+    n, qs = serve_math.gap_quantiles_ms(ctx)
+    print(f"perfbench: itl samples {n} quantiles_ms {qs}", flush=True)
     return ctx, correct, len(sent), failed, device, (
         {"device_ops": tr["device_ops"], "idle_gaps": tr["idle_gaps"]}
         if tr else None)

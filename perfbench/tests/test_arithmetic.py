@@ -3,6 +3,7 @@ traffic, data files.  No chip, no topology call, no import of jax at
 module level."""
 
 import json
+import math
 import os
 import re
 
@@ -35,6 +36,17 @@ def test_reduce_device_busy_idle_and_attribution():
     # the while's own time is what its body does not cover
     assert dict(r["device_ops"])["while"] == pytest.approx(0.0)
     assert dict(r["device_ops"])["fusion.2"] == pytest.approx(3.0)
+
+
+def test_every_op_is_kept_by_name_and_the_breakdown_is_the_first_ten():
+    ops = [(f"fusion.{i}", float(i), i + 0.1 * (i + 1)) for i in range(14)]
+    r = tr.reduce_device(ops, 0.0, 20.0)
+    assert len(r["ops_s"]) == 14 and len(r["device_ops"]) == 10
+    assert list(r["ops_s"].items())[:10] == r["device_ops"]
+    assert r["ops_s"]["fusion.0"] == pytest.approx(0.1)      # the least, kept
+    both = tr.combine([r, tr.reduce_device(ops[:2], 0.0, 20.0)])
+    assert both["ops_s"] == r["ops_s"]           # the first device's, as the
+    assert both["device_ops"] == [list(x) for x in r["device_ops"]]  # ten are
 
 
 @pytest.mark.parametrize("ops,coll,exposed,count", [
@@ -79,16 +91,11 @@ def test_combine_averages_devices():
 # ------------------------------------------------------------------ roofline
 @pytest.mark.parametrize("name", [c["name"] for c in BENCH["configs"]])
 def test_param_counts_match_weight_specs(name):
-    from perfbench.lib import weights
     config = spec.load_json(os.path.join(spec.ROOT, next(
         c["file"] for c in BENCH["configs"] if c["name"] == name)))
-    n = 0
-    for _, shape, _ in weights.leaf_specs(config):
-        k = 1
-        for s in shape:
-            k *= s
-        n += k
-    assert n == peaks.param_counts(config)["total"]
+    fam = spec.family(config)
+    n = sum(math.prod(shape) for _, shape, _ in fam.leaf_specs(config))
+    assert n == fam.param_counts(config)["total"]
 
 
 @pytest.mark.parametrize("valid,ctx", [(1, 1), (16, 16 * 300), (512, 16 * 1400),
@@ -114,7 +121,7 @@ def test_unknown_device_kind_is_an_error():
 
 def test_train_flops_per_token():
     _, config, traffic = spec.cell("train-dp1", BENCH)
-    n = peaks.param_counts(config)
+    n = spec.family(config).param_counts(config)
     assert n["total"] == 1_140_887_552
     f = peaks.train_flops_per_token(config, traffic["seq"])
     assert 6.0 * n["matmul"] < f < 6.2 * n["matmul"]
@@ -146,14 +153,20 @@ def _ctx(records, t0=100.0, seconds=10.0):
 def test_serving_window_arithmetic():
     rec = {"due": 100.5, "sent": 100.501, "part_t": [101.0, 101.2, 111.0],
            "part_n": [1, 2, 1], "prompt_len": 10, "end": 111.0}
-    late = {"due": 109.0, "sent": 109.0, "part_t": [], "part_n": [],
-            "prompt_len": 5, "end": None}
-    ctx = _ctx([rec, late])
+    late_rec = {"due": 109.0, "sent": 109.0, "part_t": [], "part_n": [],
+                "prompt_len": 5, "end": None}
+    ctx = _ctx([rec, late_rec])
     got, missing, late = serve_math.ttfts(ctx)
     assert got == [pytest.approx(0.5)] and missing == 1
     assert late == [pytest.approx(2.0)]          # waited 109 -> 111
     assert serve_math.window_tokens(ctx) == 3          # the last part is late
     assert len(serve_math.window_gaps(ctx)) == 2
+    # the two gaps are one part's 0.2 s over its two tokens
+    assert serve_math.gap_quantiles_ms(ctx, (50, 100)) == \
+        (2, {50: 100.0, 100: 100.0})
+    read = spec.metric_reader("front.itl_p99_ms.serve")
+    assert read(ctx) == pytest.approx(100.0)
+    assert read(_ctx([late_rec])) is None        # no gap: nothing to read
     # 11 positions for 0.2 s, then 13 until the end of the span
     assert serve_math.context_token_seconds([rec], 101.0, 102.0) == \
         pytest.approx(11 * 0.2 + 13 * 0.8)
@@ -243,8 +256,29 @@ def test_every_cell_finds_its_files_and_readers(cell):
                if k != "sample_requests")
 
 
+# what `reduced` may never name: a hidden, intermediate, latent, state or
+# projection size, a key that ends in _dim or _rank, a head size, an expansion
+# factor, a window, the experts per token.  Depth, the count of routed experts
+# held and the vocabulary's rows are a chip's share (model-configs, section 4).
+WIDTH = re.compile(r"hidden_size|intermediate|_dim$|_rank$|head_size|d_model|"
+                   r"d_state|d_conv|state_size|latent|proj|expand|window|"
+                   r"experts_per_tok|top_k", re.I)
+
+
 def test_no_width_is_reduced():
     for conf in BENCH["configs"]:
-        for key in conf["reduced"]:
-            assert not re.search(
-                r"hidden_size|intermediate|_dim$|_rank$|head|vocab", key)
+        assert not [k for k in conf["reduced"] if WIDTH.search(k)]
+
+
+@pytest.mark.parametrize("key,refused", [
+    ("num_hidden_layers", False), ("n_routed_experts", False),
+    ("num_local_experts", False), ("vocab_size", False),
+    ("num_nextn_predict_layers", False),
+    ("hidden_size", True), ("intermediate_size", True),
+    ("moe_intermediate_size", True), ("head_dim", True),
+    ("qk_rope_head_dim", True), ("v_head_dim", True), ("kv_lora_rank", True),
+    ("q_lora_rank", True), ("sliding_window", True), ("ssm_state_size", True),
+    ("num_experts_per_tok", True), ("expand", True),
+])
+def test_the_width_rule_on_hand_made_entries(key, refused):
+    assert bool(WIDTH.search(key)) is refused

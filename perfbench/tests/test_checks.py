@@ -1,7 +1,8 @@
 """The output checks at toy width on the CPU: what must pass passes, what
-must fail fails.  The limits are the cells' own (traffic/<mix>.json)."""
+must fail fails.  The limits are the cells' own (traffic/<mix>.json).  The
+serving checks run for every family the lookup finds: the benchmark's, and
+the one that only these tests use (tests/families/)."""
 
-import functools
 import json
 import os
 import subprocess
@@ -16,14 +17,27 @@ ROOT = spec.ROOT
 SEED = 2**31 + 17
 
 
-def _tiny(cell):
-    entry, config, traffic = spec.cell(cell)
-    return spec.tiny(config), traffic
+FAMILIES = ["dense_gqa", "moe_switch"]
+# what a family's configuration adds to the control's sizes below
+CONTROL_SIZES = {
+    "dense_gqa": dict(intermediate_size=512),
+    "moe_switch": dict(family="moe_switch", moe_intermediate_size=256,
+                       num_local_experts=4, num_experts_per_tok=2)}
+
+
+def _toy(family):
+    """(a toy configuration of the family, serve-decode's limits)"""
+    _, config, traffic = spec.cell("serve-decode")
+    toy = (spec.tiny(config) if family == "dense_gqa"
+           else spec.family({"family": family}).TOY)
+    assert spec.family(toy).__name__.endswith(family)
+    return toy, traffic["check"]["limits"]
 
 
 # ---------------------------------------------------------- serving checks
 def _decode(config, seed, prompt, n_new, flip_at=None, swap_blocks=False):
-    """Greedy decoding through llama.apply_cached as the engine drives it:
+    """Greedy decoding through the family's program module's apply_cached
+    as the engine drives it:
     chunked prefill into a paged pool with a shuffled block table, another
     slot active beside it, then one-token decode calls.  ``flip_at`` takes
     the runner-up token at the step where the top two lie closest;
@@ -31,13 +45,12 @@ def _decode(config, seed, prompt, n_new, flip_at=None, swap_blocks=False):
     after prefill."""
     import jax
     import jax.numpy as jnp
-    from horovod_tpu.models import llama
-    from perfbench.lib import child
-    cfg = child.llama_config(dict(config, torch_dtype="float32"))
+    model, cfg = spec.family(config).program(
+        dict(config, torch_dtype="float32"))
     params = jax.jit(lambda key: weights.make(config, key, jnp.float32))(
         weights.seed_key(seed))
     bs, chunk, slots, nblocks = 4, 16, 2, 64
-    cache = llama.init_cache(cfg, nblocks, bs)
+    cache = model.init_cache(cfg, nblocks, bs)
     rng = np.random.default_rng(seed)
     order = rng.permutation(nblocks)
     need = -(-(len(prompt) + n_new) // bs)
@@ -45,7 +58,8 @@ def _decode(config, seed, prompt, n_new, flip_at=None, swap_blocks=False):
     tables[0, :need] = order[:need]
     tables[1, :need] = order[need:2 * need]
     other = rng.integers(0, config["vocab_size"], len(prompt))
-    step = jax.jit(functools.partial(llama.apply_cached, cfg=cfg))
+    # (logits, cache) and, from some modules, more behind them
+    step = jax.jit(lambda *a, **k: model.apply_cached(*a, cfg=cfg, **k)[:2])
     pos, logits = 0, None
     while pos < len(prompt):
         n = min(chunk, len(prompt) - pos)
@@ -82,13 +96,13 @@ def _served_numbers(config, seed, prompt, served, T=96):
     return checks.serve_numbers(stats)
 
 
-@pytest.fixture(scope="module")
-def serve_case():
-    config, traffic = _tiny("serve-decode")
+@pytest.fixture(scope="module", params=FAMILIES)
+def serve_case(request):
+    config, limits = _toy(request.param)
     prompt = np.random.default_rng(5).integers(
         0, config["vocab_size"], 37).tolist()
     sound, margins = _decode(config, SEED, prompt, 24)
-    return config, traffic["check"]["limits"], prompt, sound, margins
+    return config, limits, prompt, sound, margins
 
 
 def _verdict(numbers, limits):
@@ -116,21 +130,21 @@ def test_a_swapped_block_table_entry_fails(serve_case):
     assert not _verdict(_served_numbers(config, SEED, prompt, wrong), limits)
 
 
+@pytest.mark.parametrize("family", FAMILIES)
 @pytest.mark.parametrize("seed", [1, 2, 3])
-def test_serving_control_int8_comes_out_not_correct(seed):
+def test_serving_control_int8_comes_out_not_correct(seed, family):
     """The control at a size a test run can hold (hidden 128, 16 layers,
     vocabulary 8192): the reference in int8 put in the program's place.
     The token it puts first lies more than 0.05 standard deviations below the
     float32 reference's best far more often than the cell's limit allows."""
-    _, traffic = _tiny("serve-decode")
-    config = dict(hidden_size=128, intermediate_size=512, num_hidden_layers=16,
+    _, limits = _toy(family)
+    config = dict(hidden_size=128, num_hidden_layers=16,
                   num_attention_heads=4, num_key_value_heads=2,
                   vocab_size=8192, rms_norm_eps=1e-5, rope_theta=1e6,
-                  assumed={"rms_norm_eps": 1e-6})
+                  assumed={"rms_norm_eps": 1e-6}, **CONTROL_SIZES[family])
     seqs = np.random.default_rng(seed).integers(0, 8192, (4, 96)).tolist()
     low = checks.serve_numbers(reference.generated_logit_stats(
         config, seed, seqs, [(31, 64)] * 4, "quant", quant="int8"))
-    limits = traffic["check"]["limits"]
     assert low["served_gap_share"] > limits["served_gap_share"]
     assert not _verdict(low, limits)
 

@@ -62,8 +62,7 @@ def test_benchmark_lists_the_five_for_the_serving_cell_only():
              "engine.device_wait_ms.serve"}
     bench = spec.benchmark()
     mine = [m for m in bench["per_layer"] if m["name"] in names]
-    assert [m["name"] for m in bench["per_layer"][-5:]] == \
-        [m["name"] for m in mine] and len(mine) == 5
+    assert len(mine) == 5
     assert all(m["workloads"] == ["serve-decode"] for m in mine)
     layer = {m["name"] for m in spec.cell_metrics("serve-decode", bench)[1]}
     assert names <= layer

@@ -1,4 +1,4 @@
-"""Precision through llama.apply_cached at the published widths, on the chip,
+"""Precision through the program module's apply_cached at the published widths, on the chip,
 against the float32 reference: relative RMS error of the logits per position,
 ||z - z_ref|| / ||z_ref - mean(z_ref)||.  With a saved sample of a served
 run it teacher-forces the served sequences (one slot active, a shuffled block
@@ -29,11 +29,10 @@ def main():
     args = ap.parse_args()
     import jax
     import jax.numpy as jnp
-    from horovod_tpu.models import llama
-    from perfbench.lib import child, reference, spec, weights
+    from perfbench.lib import reference, spec, weights
     _, config, _ = spec.cell("serve-decode")
     e = config["engine"]
-    cfg = child.llama_config(config)
+    llama, cfg = spec.family(config).program(config)
     C, bs = e["prefill_chunk"], e["block_size"]
     nblocks = 512
     step = jax.jit(functools.partial(llama.apply_cached, cfg=cfg),

@@ -5,12 +5,11 @@ import numpy as np
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 import jax, jax.numpy as jnp
-from horovod_tpu.models import llama
-from perfbench.lib import child, spec, weights
+from perfbench.lib import spec, weights
 
 seed, path, row = int(sys.argv[1]), sys.argv[2], int(sys.argv[3])
 _, config, _ = spec.cell("serve-decode")
-e = config["engine"]; cfg = child.llama_config(config)
+e = config["engine"]; llama, cfg = spec.family(config).program(config)
 C, bs, S = e["prefill_chunk"], e["block_size"], e["max_slots"]
 sample = json.load(open(path)); seq = sample["seqs"][row]
 first, n = sample["spans"][row]

@@ -13,7 +13,6 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))))
 
-import functools  # noqa: E402
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
@@ -21,7 +20,7 @@ import numpy as np  # noqa: E402
 from jax.experimental import topologies  # noqa: E402
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
 
-from perfbench.lib import child, spec, weights  # noqa: E402
+from perfbench.lib import spec, weights  # noqa: E402
 
 
 def report(name, compiled, t):
@@ -33,10 +32,9 @@ def report(name, compiled, t):
 
 
 def tick(topo, blocks):
-    from horovod_tpu.models import llama
     _, config, _ = spec.cell("serve-decode")
     e = dict(config["engine"], cache_blocks=blocks)
-    cfg = child.llama_config(config)
+    llama, cfg = spec.family(config).program(config)
     mesh = Mesh(np.array(topo.devices[:1]), ("hvd",))
     rep = NamedSharding(mesh, P())
     sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=rep)
@@ -65,22 +63,17 @@ def tick(topo, blocks):
 
 def step(topo, n):
     import optax
-    from horovod_tpu.models import layers, llama
     from horovod_tpu.parallel.data_parallel import make_scanned_train_step
     name = "train-dp1" if n == 1 else "train-dp4"
     _, config, tr = spec.cell(name)
-    cfg = child.llama_config(config, max_seq=tr["seq"])
     mesh = Mesh(np.array(topo.devices[:n]), ("hvd",))
     rep = NamedSharding(mesh, P())
-    attn = functools.partial(layers.causal_attention, score_dtype=None)
     o = tr["optimizer"]
     opt = optax.adamw(o["lr"], weight_decay=o["weight_decay"])
-    run = make_scanned_train_step(
-        lambda p, ids: llama.loss_fn(p, ids, cfg, attn_fn=attn, remat=True,
-                                     ce_chunks=tr["ce_chunks"]), opt, mesh,
-        donate=True)
+    run = make_scanned_train_step(spec.family(config).loss(config, tr), opt,
+                                  mesh, donate=True)
     params = jax.eval_shape(lambda: weights.make(config, weights.seed_key(0),
-                                                 cfg.dtype))
+                                                 weights.dtype_of(config)))
     state = jax.eval_shape(opt.init, params)
     put = lambda tree: jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=rep), tree)
