@@ -1,0 +1,413 @@
+"""Latent-attention decoder with sandwich norms and routed experts beside a
+shared one — the block of today's large sparse models, written by its
+mechanisms so that any model built from them is a config away:
+
+  * **Latent attention.**  Queries and keys/values go through low-rank
+    projections with an inner norm each (``wq_a -> q_a_norm -> wq_b``,
+    ``wkv_a -> kv_a_norm -> wkv_b``); a head's query and key are a
+    position-free part (``qk_nope_dim``) and a rotary part (``qk_rope_dim``)
+    whose KEY is one vector shared by all heads.  What is cached a position
+    is therefore the latent ``[c_kv | k_rope]`` (``kv_rank + qk_rope_dim``
+    values), never a per-head K or V.
+  * **Absorbed form over the cache.**  With ``wkv_b`` split by head into
+    ``W^K_h, W^V_h``: ``score_h = (q_nope_h W^K_h^T) . c_kv + q_rope_h .
+    k_rope`` and ``out_h = (softmax_h c_kv) W^V_h`` — the same mathematics
+    as expanding K and V (:func:`apply` does that, and the tests hold the
+    two together), at the cost of the latent's width a cached position.
+  * **Sandwich norms.**  ``x + norm(sublayer(norm(x)))``: the sublayer's
+    OUTPUT is normed too, then added.
+  * **Layers of two kinds in one stack.**  ``n_dense`` leading layers with a
+    dense gated FFN, then layers whose FFN is a shared expert plus the
+    ``top_k`` of ``n_experts`` routed ones, of which this chip holds
+    ``experts_held`` starting at ``first_expert``
+    (parallel/expert.py ``held_experts``; docs/serving.md#held-experts).
+
+Serving contract as models/llama.py: ``init_cache`` / ``copy_blocks`` /
+``apply_cached`` over ONE pool ``[n_layers, blocks, block, kv_rank +
+qk_rope_dim]``, plus ``cache_shardings`` (the pool has no head axis to
+shard) and ``TICK_COUNTERS`` (what the third value of ``apply_cached``
+counts; ServeEngine sums it into ``stats()["moe"]``).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from . import layers as L
+from ..parallel import expert as X
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentMoeConfig:
+    vocab: int = 4096
+    dim: int = 256
+    n_layers: int = 3
+    n_dense: int = 1             # leading layers with a dense FFN
+    n_heads: int = 8
+    q_rank: int = 96             # low-rank query projection
+    kv_rank: int = 64            # the cached latent, without its rotary part
+    qk_nope_dim: int = 32
+    qk_rope_dim: int = 16
+    v_dim: int = 32
+    ffn_dim: int = 512           # dense layers
+    moe_hidden: int = 128        # one expert, routed or shared
+    n_experts: int = 16          # the router's width
+    experts_held: int = 16       # ... of which this chip holds
+    first_expert: int = 0        # ... starting here
+    top_k: int = 4
+    n_shared: int = 1
+    route_scale: float = 2.5
+    norm_eps: float = 1e-5
+    max_seq: int = 512
+    rope_theta: float = 10000.0
+    dtype: Any = jnp.float32
+    # The most valid tokens one call of apply_cached holds: they are packed
+    # into that many rows.  ServeEngine sets it to its own max_batch_tokens,
+    # whatever is written here; 0, for a direct caller, is every position of
+    # the slab.
+    max_tick_tokens: int = 0
+
+    @property
+    def latent_dim(self) -> int:
+        return self.kv_rank + self.qk_rope_dim
+
+    @property
+    def qk_dim(self) -> int:
+        return self.qk_nope_dim + self.qk_rope_dim
+
+
+CONFIGS = {
+    "tiny": LatentMoeConfig(vocab=256, dim=64, n_layers=3, n_dense=1,
+                            n_heads=4, q_rank=48, kv_rank=32,
+                            qk_nope_dim=16, qk_rope_dim=8, v_dim=16,
+                            ffn_dim=128, moe_hidden=32, n_experts=8,
+                            experts_held=8, top_k=2, max_seq=128),
+}
+
+#: rows of one expert's tile (parallel/expert.py held_experts)
+EXPERT_TILE = 64
+#: the cached attention computes at most this many bytes of float32 scores
+#: at a time, a block of slots after another
+SCORE_BYTES = 256 << 20
+#: a block of slots whose rows all hold at most this many new tokens attends
+#: in its first columns only (decode rows of a prefill-width tick)
+NARROW_COLS = 8
+
+#: what apply_cached's third value counts, summed over the expert layers
+TICK_COUNTERS = ("ticks",) + X.HELD_COUNTERS
+
+
+# ----------------------------------------------------------------- weights
+def _dense(key, i, o, cfg, scale=None):
+    return L.dense_init(key, i, o, use_bias=False, scale=scale,
+                        dtype=cfg.dtype)
+
+
+def _gated_init(key, d, f, cfg):
+    k = jax.random.split(key, 3)
+    return {"w_gate": _dense(k[0], d, f, cfg), "w_up": _dense(k[1], d, f, cfg),
+            "w_down": _dense(k[2], f, d, cfg)}
+
+
+def init_layer(key, cfg: LatentMoeConfig, routed: bool) -> Dict[str, Any]:
+    k = jax.random.split(key, 8)
+    d, H = cfg.dim, cfg.n_heads
+    norm = lambda n: L.rmsnorm_init(n, cfg.dtype)
+    p = {"input_norm": norm(d), "post_attn_norm": norm(d),
+         "pre_mlp_norm": norm(d), "post_mlp_norm": norm(d),
+         "attn": {
+             "wq_a": _dense(k[0], d, cfg.q_rank, cfg),
+             "q_a_norm": norm(cfg.q_rank),
+             "wq_b": _dense(k[1], cfg.q_rank, H * cfg.qk_dim, cfg),
+             "wkv_a": _dense(k[2], d, cfg.latent_dim, cfg),
+             "kv_a_norm": norm(cfg.kv_rank),
+             "wkv_b": _dense(k[3], cfg.kv_rank,
+                             H * (cfg.qk_nope_dim + cfg.v_dim), cfg),
+             "wo": _dense(k[4], H * cfg.v_dim, d, cfg)}}
+    if routed:
+        p["moe"] = X.init_held_experts(k[5], d, cfg.moe_hidden, cfg.n_experts,
+                                       cfg.experts_held, cfg.dtype)
+        p["moe"]["shared"] = _gated_init(k[6], d,
+                                         cfg.moe_hidden * cfg.n_shared, cfg)
+    else:
+        p["ffn"] = _gated_init(k[7], d, cfg.ffn_dim, cfg)
+    return p
+
+
+def init(key, cfg: LatentMoeConfig) -> Dict[str, Any]:
+    keys = jax.random.split(key, cfg.n_layers + 2)
+    return {"embed": L.embedding_init(keys[0], cfg.vocab, cfg.dim, cfg.dtype),
+            "final_norm": L.rmsnorm_init(cfg.dim, cfg.dtype),
+            "lm_head": _dense(keys[1], cfg.dim, cfg.vocab, cfg),
+            "layers": [init_layer(keys[2 + i], cfg, routed=i >= cfg.n_dense)
+                       for i in range(cfg.n_layers)]}
+
+
+# ------------------------------------------------------------------ pieces
+def _norm(p, x, cfg):
+    return L.rmsnorm(p, x, eps=cfg.norm_eps)
+
+
+def _gated(p, x):
+    return L.dense(p["w_down"],
+                   jax.nn.silu(L.dense(p["w_gate"], x)) * L.dense(p["w_up"], x))
+
+
+def _project(p, h, cfg, cos, sin, positions):
+    """The projections of one layer's attention at explicit positions:
+    (q_nope [.., H, nope], q_rope [.., H, rope], latent [.., kv_rank + rope])
+    with the rotary parts rotated; the latent is what the cache holds."""
+    B, S, _ = h.shape
+    with jax.named_scope("attn/q_lora"):
+        q = L.dense(p["wq_b"], _norm(p["q_a_norm"], L.dense(p["wq_a"], h), cfg))
+        q = q.reshape(B, S, cfg.n_heads, cfg.qk_dim)
+        q_nope, q_rope = q[..., :cfg.qk_nope_dim], q[..., cfg.qk_nope_dim:]
+        q_rope = L.apply_rope_at(q_rope, cos, sin, positions)
+    with jax.named_scope("attn/kv_latent"):
+        kv = L.dense(p["wkv_a"], h)
+        c_kv = _norm(p["kv_a_norm"], kv[..., :cfg.kv_rank], cfg)
+        k_rope = L.apply_rope_at(kv[..., None, cfg.kv_rank:], cos, sin,
+                                 positions)[..., 0, :]
+        latent = jnp.concatenate([c_kv, k_rope], -1)
+    return q_nope, q_rope, latent
+
+
+def _wkv_b(p, cfg):
+    """``wkv_b`` by head: (W^K [kv_rank, H, nope], W^V [kv_rank, H, v])."""
+    w = p["wkv_b"]["kernel"].reshape(cfg.kv_rank, cfg.n_heads,
+                                     cfg.qk_nope_dim + cfg.v_dim)
+    return w[..., :cfg.qk_nope_dim], w[..., cfg.qk_nope_dim:]
+
+
+def _mlp(p, h, valid, cfg):
+    """A layer's FFN half on normed input h [B, S, D]: (m, counters)."""
+    if "ffn" in p:
+        with jax.named_scope("ffn"):
+            return _gated(p["ffn"], h), jnp.zeros(len(X.HELD_COUNTERS),
+                                                  jnp.int32)
+    B, S, D = h.shape
+    with jax.named_scope("moe/shared"):
+        shared = _gated(p["moe"]["shared"], h)
+    y, counters = X.held_experts(
+        p["moe"], h.reshape(B * S, D), valid.reshape(B * S),
+        first=cfg.first_expert, k=cfg.top_k, scale=cfg.route_scale,
+        tile=EXPERT_TILE)
+    with jax.named_scope("moe/combine"):
+        return shared + y.reshape(B, S, D).astype(h.dtype), counters
+
+
+# ------------------------------------------------------- full-sequence path
+def apply(params: Dict[str, Any], ids: jax.Array,
+          cfg: LatentMoeConfig) -> jax.Array:
+    """Forward without a cache: ids [B, S] -> logits [B, S, vocab], with K
+    and V EXPANDED per head from the latent (the form the cached path
+    absorbs).  For tests and for checking the cached path against."""
+    B, S = ids.shape
+    cos, sin = L.rope_freqs(cfg.qk_rope_dim, cfg.max_seq, cfg.rope_theta)
+    positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+    valid = jnp.ones((B, S), bool)
+    x = L.embedding(params["embed"], ids).astype(cfg.dtype)
+    for p in params["layers"]:
+        a = p["attn"]
+        q_nope, q_rope, latent = _project(a, _norm(p["input_norm"], x, cfg),
+                                          cfg, cos, sin, positions)
+        wk, wv = _wkv_b(a, cfg)
+        c_kv, k_rope = latent[..., :cfg.kv_rank], latent[..., cfg.kv_rank:]
+        k = jnp.concatenate(
+            [jnp.einsum("bsl,lhn->bshn", c_kv, wk),
+             jnp.broadcast_to(k_rope[:, :, None, :],
+                              (B, S, cfg.n_heads, cfg.qk_rope_dim))], -1)
+        v = jnp.einsum("bsl,lhv->bshv", c_kv, wv)
+        o = L.causal_attention(jnp.concatenate([q_nope, q_rope], -1), k, v)
+        o = L.dense(a["wo"], o.reshape(B, S, cfg.n_heads * cfg.v_dim))
+        x = x + _norm(p["post_attn_norm"], o, cfg)
+        m, _ = _mlp(p, _norm(p["pre_mlp_norm"], x, cfg), valid, cfg)
+        x = x + _norm(p["post_mlp_norm"], m, cfg)
+    return L.dense(params["lm_head"], _norm(params["final_norm"], x, cfg))
+
+
+# ------------------------------------------------------------- decode path
+def init_cache(cfg: LatentMoeConfig, num_blocks: int, block_size: int,
+               dtype=None) -> Dict[str, jax.Array]:
+    """The latent paged pool: ``{"latent": [n_layers, num_blocks,
+    block_size, kv_rank + qk_rope_dim]}`` — ``[c_kv | k_rope]`` a position,
+    whatever the number of heads."""
+    dtype = dtype if dtype is not None else cfg.dtype
+    return {"latent": jnp.zeros((cfg.n_layers, num_blocks, block_size,
+                                 cfg.latent_dim), dtype)}
+
+
+def cache_shardings(mesh, num_blocks: int):
+    """NamedSharding for the latent pool: blocks over the first mesh axis
+    that divides them; the latent is every head's, so no axis of it is
+    sharded over a model axis."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    block_axis = next((a for a in mesh.axis_names
+                       if num_blocks % mesh.shape[a] == 0), None)
+    return NamedSharding(mesh, P(None, block_axis, None, None))
+
+
+def copy_blocks(cache: Dict[str, jax.Array], src: jax.Array,
+                dst: jax.Array) -> Dict[str, jax.Array]:
+    """Clone whole pool blocks ``src[i] -> dst[i]`` across every layer
+    before the tick's writes (the prefix cache's copy-on-write, as
+    llama.copy_blocks)."""
+    pool = cache["latent"]
+    safe = jnp.clip(src, 0, pool.shape[1] - 1)
+    # a layer at a time, as the tick's own writes index the pool: one
+    # scatter across all layers makes the compiler keep the pool in a layout
+    # of its own for it and copy the whole pool there and back every tick
+    for i in range(pool.shape[0]):
+        pool = pool.at[i, dst].set(pool[i, safe], mode="drop")
+    return {"latent": pool}
+
+
+def _slots_per_block(S: int, per_slot_bytes: int, budget: int) -> int:
+    """The largest divisor of S whose scores fit the budget (at least 1)."""
+    want = max(1, budget // max(per_slot_bytes, 1))
+    return max(b for b in range(1, S + 1) if S % b == 0 and b <= want)
+
+
+def _latent_attention(q: jax.Array, lat: jax.Array, positions: jax.Array,
+                      n_new: jax.Array, cfg: LatentMoeConfig) -> jax.Array:
+    """Absorbed attention of q [S, C, H, kv_rank + rope] (``q_nope W^K``
+    beside the rotated ``q_rope``) over each slot's gathered latent context
+    lat [S, ctx, kv_rank + rope]: scores against the latent, softmax in
+    float32, values the latent's ``c_kv`` part.  Returns [S, C, H, kv_rank].
+    Computed a block of slots after another so that the float32 scores stay
+    under ``SCORE_BYTES``."""
+    S, C, H, _ = q.shape
+    ctx = lat.shape[1]
+    scale = 1.0 / math.sqrt(cfg.qk_dim)
+    key_pos = jnp.arange(ctx, dtype=positions.dtype)
+    fill = jnp.finfo(jnp.float32).min
+
+    def attend(q, lat, pos):
+        s = jnp.einsum("schx,skx->shck", q, lat,
+                       preferred_element_type=jnp.float32) * scale
+        mask = key_pos[None, None, None, :] <= pos[:, None, :, None]
+        pr = jax.nn.softmax(jnp.where(mask, s, fill), -1).astype(q.dtype)
+        return jnp.einsum("shck,skl->schl", pr, lat[..., :cfg.kv_rank])
+
+    def block(args):
+        q, lat, pos, nn = args
+        if C <= NARROW_COLS:
+            return attend(q, lat, pos)
+        W = NARROW_COLS
+
+        def narrow():
+            o = attend(q[:, :W], lat, pos[:, :W])
+            return jnp.pad(o, ((0, 0), (0, C - W), (0, 0), (0, 0)))
+        # rows past a slot's n_new are padding that nothing reads: a block
+        # of decode rows in a prefill-width tick attends in W columns
+        return lax.cond(jnp.max(nn) > W, lambda: attend(q, lat, pos), narrow)
+
+    sb = _slots_per_block(S, H * C * ctx * 4, SCORE_BYTES)
+    if sb == S:
+        return block((q, lat, positions, n_new))
+    split = lambda a: a.reshape((S // sb, sb) + a.shape[1:])
+    o = lax.map(block, tuple(map(split, (q, lat, positions, n_new))))
+    return o.reshape((S,) + o.shape[2:])
+
+
+def apply_cached(params: Dict[str, Any], tokens: jax.Array,
+                 cfg: LatentMoeConfig, cache: Dict[str, jax.Array],
+                 block_tables: jax.Array, lengths: jax.Array,
+                 n_new: jax.Array
+                 ) -> Tuple[jax.Array, Dict[str, jax.Array], jax.Array]:
+    """Mixed prefill/decode forward over the latent pool; the slot-table
+    contract of llama.apply_cached.  Returns (logits [S, C, vocab] — zero
+    at positions that were not packed —, updated cache, counters
+    int32[len(TICK_COUNTERS)] summed over the expert layers)."""
+    S, C = tokens.shape
+    T = S * C
+    pool = cache["latent"]
+    num_blocks, block_size = pool.shape[1], pool.shape[2]
+    max_blocks = block_tables.shape[1]
+    cos, sin = L.rope_freqs(cfg.qk_rope_dim, cfg.max_seq, cfg.rope_theta)
+    positions = lengths[:, None] + jnp.arange(C, dtype=lengths.dtype)[None]
+    valid = jnp.arange(C)[None, :] < n_new[:, None]
+    # where a new position's latent lands: block_tables[s, P // bs] at
+    # offset P % bs; padding and dead slots go out of bounds and are dropped
+    blk = jnp.take_along_axis(
+        block_tables, jnp.minimum(positions // block_size, max_blocks - 1),
+        axis=1)
+    blk = jnp.where(valid, jnp.maximum(blk, 0), num_blocks)
+    bt = jnp.maximum(block_tables, 0)
+    # Everything but the attention's core is a token's own: it runs on ROWS,
+    # the slab's [S * C] positions, or — when the engine promises fewer valid
+    # tokens a tick than the slab has positions (``max_tick_tokens``) — on the
+    # valid ones packed to the front (a stable sort keeps the slab's order), so
+    # that a prefill-wide tick does not push every slot's padding through
+    # every matrix.
+    R = min(T, cfg.max_tick_tokens or T)
+    flat = lambda a: a.reshape((T,) + a.shape[2:])
+    rows = (jnp.argsort(~flat(valid), stable=True)[:R] if R < T
+            else jnp.arange(T))
+    take = lambda a: flat(a)[rows][None]                # [S, C, ..] -> [1, R, ..]
+    row_valid, row_pos, row_blk = take(valid), take(positions), take(blk)
+    pos_c = jnp.minimum(row_pos, cfg.max_seq - 1)
+    with jax.named_scope("embed"):
+        x = L.embedding(params["embed"], take(tokens)).astype(cfg.dtype)
+
+    def slab(a):
+        """Rows [1, R, ..] back at their places in a zero [S, C, ..] slab."""
+        if R == T:
+            return a.reshape((S, C) + a.shape[2:])
+        z = jnp.zeros((T,) + a.shape[2:], a.dtype)
+        return z.at[rows].set(a[0], unique_indices=True).reshape(
+            (S, C) + a.shape[2:])
+
+    counters = jnp.zeros(len(X.HELD_COUNTERS), jnp.int32)
+    for i, p in enumerate(params["layers"]):
+        a = p["attn"]
+        q_nope, q_rope, latent = _project(a, _norm(p["input_norm"], x, cfg),
+                                          cfg, cos, sin, pos_c)
+        with jax.named_scope("kv_write"):
+            pool = pool.at[i, row_blk[0], row_pos[0] % block_size].set(
+                latent[0].astype(pool.dtype), mode="drop")
+        wk, wv = _wkv_b(a, cfg)
+        with jax.named_scope("kv_gather"):
+            # table slot j covers positions [j*bs, (j+1)*bs): gathered index
+            # t IS position t; unassigned entries (-1 -> block 0) cover only
+            # positions the mask excludes.  Gathered outside the slot-block
+            # loop: a pool that a loop holds is copied whole
+            lat = pool[i, bt].reshape(S, max_blocks * block_size, -1)
+        with jax.named_scope("attn/latent_scores"):
+            q = jnp.concatenate(
+                [jnp.einsum("brhn,lhn->brhl", q_nope, wk), q_rope], -1)
+            o = take(_latent_attention(slab(q), lat, positions, n_new, cfg))
+        with jax.named_scope("attn/out"):
+            o = jnp.einsum("brhl,lhv->brhv", o, wv)
+            o = L.dense(a["wo"], o.reshape(1, R, cfg.n_heads * cfg.v_dim))
+            x = x + _norm(p["post_attn_norm"], o, cfg)
+        m, c = _mlp(p, _norm(p["pre_mlp_norm"], x, cfg), row_valid, cfg)
+        x = x + _norm(p["post_mlp_norm"], m, cfg)
+        counters = counters + c     # load_max too: a sum over the layers
+    with jax.named_scope("head"):
+        logits = slab(L.dense(params["lm_head"],
+                              _norm(params["final_norm"], x, cfg)))
+    return (logits, {"latent": pool},
+            jnp.concatenate([jnp.ones(1, jnp.int32), counters]))
+
+
+def param_count(cfg: LatentMoeConfig) -> int:
+    d, H = cfg.dim, cfg.n_heads
+    attn = (d * cfg.q_rank + cfg.q_rank * H * cfg.qk_dim + d * cfg.latent_dim
+            + cfg.kv_rank * H * (cfg.qk_nope_dim + cfg.v_dim)
+            + H * cfg.v_dim * d + cfg.q_rank + cfg.kv_rank + 4 * d)
+    expert = 3 * d * cfg.moe_hidden
+    routed = d * cfg.n_experts + (cfg.experts_held + cfg.n_shared) * expert
+    return (cfg.n_layers * attn + cfg.n_dense * 3 * d * cfg.ffn_dim
+            + (cfg.n_layers - cfg.n_dense) * routed + 2 * cfg.vocab * d + d)
+
+
+__all__ = ["LatentMoeConfig", "CONFIGS", "TICK_COUNTERS", "init", "apply",
+           "init_cache", "cache_shardings", "copy_blocks", "apply_cached",
+           "param_count"]

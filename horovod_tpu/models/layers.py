@@ -249,4 +249,4 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     probs = jax.nn.softmax(logits.astype(jnp.float32),
                            axis=-1).astype(q.dtype)
     o = jnp.einsum("bhrqk,bkhd->bqhrd", probs, v)
-    return o.reshape(B, S, H, D)
+    return o.reshape(B, S, H, v.shape[-1])  # v's own width (latent heads)

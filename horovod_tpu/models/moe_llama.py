@@ -7,6 +7,12 @@ parameter layout and routing math with ``parallel/expert.py`` — the SAME
 default path, used for tests/inference) or expert-parallel over an
 ``ep`` mesh axis via :func:`horovod_tpu.parallel.expert.make_moe_fn`
 (pass it as ``moe_fn``), so checkpoints move freely between layouts.
+
+Serving here runs EVERY expert on every token (``dropfree_moe_fn``): fine
+for a handful of small experts.  A model with many gated experts of which a
+chip holds a share is served by ``parallel/expert.py`` ``held_experts``
+(sorted dispatch, work that grows with the assignments held; used by
+``models/latent_moe.py``, docs/serving.md#held-experts).
 """
 
 from __future__ import annotations
@@ -147,7 +153,10 @@ def dropfree_moe_fn(cfg: MoeLlamaConfig) -> Callable:
     requests share its tick, which serving must never allow (and which
     would break the prefill+decode ≡ full-forward equivalence).  Pass
     the same fn to :func:`apply` when comparing against the cached path
-    (tests/test_serve.py; docs/serving.md)."""
+    (tests/test_serve.py; docs/serving.md).  The price is every expert on
+    every token (capacity = T in ``moe_dense_reference``);
+    ``parallel/expert.py`` ``held_experts`` is drop-free and
+    batch-invariant at a cost that grows with the assignments instead."""
     def fn(p_moe: Dict[str, Any], tokens: jax.Array):
         return moe_dense_reference(p_moe, tokens, cfg.n_experts,
                                    capacity=tokens.shape[0],

@@ -194,5 +194,104 @@ def moe_dense_reference(params, x, n_experts: int, capacity: int,
     return y, aux
 
 
+# ------------------------------------------------------ a share of experts
+# Serving-plane expert layer (docs/serving.md#held-experts): this chip holds
+# ``held`` of ``total`` routed experts (numbers ``first .. first+held-1``),
+# routes over all of them, and computes its own experts' part of the result.
+# What the absent experts would add is the other chips' to compute; on one
+# chip the layer runs without its exchange and nothing stands in for it.
+
+
+def gated_ffn(w_gate, w_up, w_down, x):
+    """``(silu(x W_gate) * x W_up) W_down``: one gated three-matrix expert
+    (a shared expert, or one routed expert's tile of rows)."""
+    h = jax.nn.silu(jnp.dot(x, w_gate)) * jnp.dot(x, w_up)
+    return jnp.dot(h, w_down, preferred_element_type=jnp.float32)
+
+
+def init_held_experts(key, dim: int, hidden: int, total: int, held: int,
+                      dtype=jnp.float32) -> Dict[str, Any]:
+    """Router over all ``total`` experts + the ``held`` gated experts this
+    chip owns, stacked on axis 0."""
+    k0, k1, k2, k3 = jax.random.split(key, 4)
+    s_in, s_out = 1.0 / np.sqrt(dim), 1.0 / np.sqrt(hidden)
+
+    def w(k, shape, s):
+        return (jax.random.normal(k, shape) * s).astype(dtype)
+    return {"router": {"kernel": w(k0, (dim, total), s_in)},
+            "experts": {"w_gate": w(k1, (held, dim, hidden), s_in),
+                        "w_up": w(k2, (held, dim, hidden), s_in),
+                        "w_down": w(k3, (held, hidden, dim), s_out)}}
+
+
+def route_sigmoid_topk(x, router_kernel, k: int, scale: float):
+    """Sigmoid scores over ALL experts in float32, the ``k`` largest with no
+    groups and no bias, gates ``scale * s / (sum of the chosen s + 1e-20)``.
+    Returns (idx [T, k] int32, gates [T, k] float32)."""
+    s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
+                               router_kernel.astype(jnp.float32),
+                               precision=lax.Precision.HIGHEST))
+    top, idx = lax.top_k(s, k)
+    return idx, scale * top / (top.sum(-1, keepdims=True) + 1e-20)
+
+
+#: what ``held_experts`` counts, in the order of its counter vector
+HELD_COUNTERS = ("assignments", "assignments_held", "experts_touched",
+                 "load_max")
+
+
+def held_experts(p: Dict[str, Any], x, valid, *, first: int, k: int,
+                 scale: float, tile: int = 64):
+    """This chip's part of a routed layer: ``sum_{e in top-k, e held}
+    g_e E_e(x)`` for tokens ``x`` [T, D], rows with ``valid`` False routed
+    nowhere.  ``p`` as :func:`init_held_experts` gives it.
+
+    Drop-free and batch-invariant: assignments are sorted by expert (a
+    stable sort, so in token order within one) and each held expert runs
+    over its own rows in tiles of ``tile`` — as many tiles as it has rows,
+    none when it has none — so no capacity bounds anything, the work grows
+    with the assignments held here and not with experts x tokens, and a
+    token's sum is taken in expert order whoever shares its tick.
+
+    Returns (y [T, D] float32, counters int32[4] as HELD_COUNTERS)."""
+    T, D = x.shape
+    held = p["experts"]["w_gate"].shape[0]
+    with jax.named_scope("moe/route"):
+        idx, gates = route_sigmoid_topk(x, p["router"]["kernel"], k, scale)
+    with jax.named_scope("moe/dispatch"):
+        local = idx - first
+        mine = (local >= 0) & (local < held) & valid[:, None]
+        key = jnp.where(mine, local, held).reshape(T * k)
+        order = jnp.argsort(key, stable=True)
+        tok = (order // k).astype(jnp.int32)        # token of a sorted row
+        gate = gates.reshape(T * k)[order]
+        counts = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
+                         dtype=jnp.int32)
+        starts = jnp.cumsum(counts) - counts
+        n_valid = jnp.sum(valid, dtype=jnp.int32)
+        counters = jnp.stack([n_valid * k, counts.sum(),
+                              jnp.sum(counts > 0, dtype=jnp.int32),
+                              counts.max()])
+    w = p["experts"]
+    rows = jnp.arange(tile, dtype=jnp.int32)
+    y = jnp.zeros((T, D), jnp.float32)
+    for e in range(held):
+        def one_tile(t, y, e=e):
+            at = t * tile + rows
+            live = at < counts[e]
+            src = jnp.minimum(starts[e] + at, T * k - 1)
+            with jax.named_scope("moe/dispatch"):
+                t_src = tok[src]
+                xt = jnp.take(x, t_src, axis=0)
+            with jax.named_scope("moe/experts"):
+                out = gated_ffn(w["w_gate"][e], w["w_up"][e], w["w_down"][e],
+                                xt) * gate[src][:, None]
+            with jax.named_scope("moe/combine"):
+                return y.at[jnp.where(live, t_src, T)].add(out, mode="drop")
+        y = lax.fori_loop(0, (counts[e] + tile - 1) // tile, one_tile, y)
+    return y, counters
+
+
 __all__ = ["make_moe_fn", "init_moe_params", "moe_shardings",
-           "moe_dense_reference"]
+           "moe_dense_reference", "gated_ffn", "init_held_experts",
+           "route_sigmoid_topk", "held_experts", "HELD_COUNTERS"]

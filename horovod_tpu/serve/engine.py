@@ -39,6 +39,7 @@ with no new transport.
 from __future__ import annotations
 
 import collections
+import dataclasses
 import hashlib
 import json
 import os
@@ -846,7 +847,9 @@ class ServeEngine:
     narrow ticks and their wait on the device.
 
     ``model`` is a model module exposing ``init_cache`` / ``apply_cached``
-    (models/llama.py, models/moe_llama.py); ``model_cfg`` its config
+    (models/llama.py, models/moe_llama.py, models/latent_moe.py, which
+    also says how its pool is sharded and what its tick counts);
+    ``model_cfg`` its config
     dataclass; ``params`` the trained pytree (host or global arrays).
     """
 
@@ -856,6 +859,12 @@ class ServeEngine:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         cfg.validate(model_max_seq=model_cfg.max_seq)
+        # The tick's token budget is the scheduler's: a model that packs a
+        # tick's valid tokens into that many rows (models/latent_moe.py) is
+        # told it here, whoever built its config.
+        if hasattr(model_cfg, "max_tick_tokens"):
+            model_cfg = dataclasses.replace(
+                model_cfg, max_tick_tokens=cfg.max_batch_tokens)
         self.model = model
         self.model_cfg = model_cfg
         self.cfg = cfg
@@ -865,8 +874,13 @@ class ServeEngine:
         self.mesh = mesh
         self.scheduler = Scheduler(cfg, role=role)
         self._repl = NamedSharding(mesh, P())
-        self._cache_shd = cache_shardings(mesh, cfg.cache_blocks,
-                                          model_cfg.n_kv_heads)
+        # The pool's sharding is the model module's to say where its pool
+        # is not per-head K/V (models/latent_moe.py); else the five-axis one.
+        if hasattr(model, "cache_shardings"):
+            self._cache_shd = model.cache_shardings(mesh, cfg.cache_blocks)
+        else:
+            self._cache_shd = cache_shardings(mesh, cfg.cache_blocks,
+                                              model_cfg.n_kv_heads)
         leaves = jax.tree_util.tree_leaves(params)
         if leaves and isinstance(leaves[0], jax.Array):
             self.params = params
@@ -893,6 +907,11 @@ class ServeEngine:
         # phases on the same clock.
         from ..utils.profiler import PhaseClock
         self.clock = PhaseClock()
+        # What the model's tick counts beside its logits (a module with
+        # TICK_COUNTERS returns one small vector a tick: the expert layers'
+        # assignments, models/latent_moe.py), summed at every harvest.
+        self._counter_names = tuple(getattr(model, "TICK_COUNTERS", ()))
+        self._counters = np.zeros(len(self._counter_names), np.int64)
         self._step_fn = self._build_step()
         # The step's executable at each tick width, both compiled at the
         # first dispatch (_compile_steps): no later tick lowers anything.
@@ -950,6 +969,7 @@ class ServeEngine:
                 out = model.apply_cached(params, tokens, mcfg, cache,
                                          block_tables, lengths, n_new)
             logits, cache = out[0], out[1]  # moe also returns aux
+            counters = out[2] if self._counter_names else None
             # Greedy sampling ON DEVICE at EVERY chunk position: row
             # [s, j] is the greedy continuation after consuming tokens
             # [s, :j+1] — prefill reads its last valid position,
@@ -959,7 +979,7 @@ class ServeEngine:
             with jax.named_scope("tick/sample"):
                 next_tokens = jnp.argmax(
                     logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
-            return cache, next_tokens
+            return cache, next_tokens, counters
 
         return jax.jit(
             step_fn,
@@ -967,7 +987,8 @@ class ServeEngine:
             out_shardings=(
                 jax.tree_util.tree_map(lambda _: self._cache_shd,
                                        self.cache),
-                self._repl))
+                self._repl,
+                self._repl if self._counter_names else None))
 
     def _compile_steps(self, staged) -> None:
         """The step's executable at both widths of ``tick_width``, lowered
@@ -1155,11 +1176,11 @@ class ServeEngine:
         with self.clock.span("launch"):
             if not self._steps:
                 self._compile_steps(dev)
-            self.cache, next_tokens = self._steps[C](
+            self.cache, next_tokens, counters = self._steps[C](
                 self.params, self.cache, *dev)
         used = int(n_new.sum())
         self._last_fill = used / cfg.max_batch_tokens
-        self._inflight.append((self.tick, work, next_tokens, used))
+        self._inflight.append((self.tick, work, next_tokens, used, counters))
         self.tick += 1
 
     def _plan(self):
@@ -1214,10 +1235,12 @@ class ServeEngine:
         if not self._inflight:
             return {"tick": None, "processed": 0, "emitted": {},
                     "finished": [], "handoff": []}
-        tick, work, next_tokens, used = self._inflight.popleft()
+        tick, work, next_tokens, used, counters = self._inflight.popleft()
         waited = self.clock.phase_s.get("harvest_wait", 0.0)
         with self.clock.span("harvest_wait"):
             tokens_host = np.asarray(next_tokens)  # D2H fence for this tick
+            if counters is not None:
+                self._counters += np.asarray(counters)
         if next_tokens.shape[1] < self.cfg.prefill_chunk:
             self._narrow_ticks += 1
             self._narrow_wait_s += \
@@ -1454,6 +1477,9 @@ class ServeEngine:
             },
         }
         out["loop"] = dict(self._loop_snapshot(), ticks=self._ticks())
+        if self._counter_names:
+            out["moe"] = dict(zip(self._counter_names,
+                                  map(int, self._counters)))
         if prefix is not None:
             out["prefix_cache"].update({
                 "hits": prefix.hits,
@@ -1474,7 +1500,8 @@ class ServeEngine:
 SERVE_MANIFEST = "serve.json"
 
 _MODEL_MODULES = {"llama": "horovod_tpu.models.llama",
-                  "moe_llama": "horovod_tpu.models.moe_llama"}
+                  "moe_llama": "horovod_tpu.models.moe_llama",
+                  "latent_moe": "horovod_tpu.models.latent_moe"}
 
 
 def save_servable(directory: str, model_name: str, config, params,
@@ -1482,7 +1509,6 @@ def save_servable(directory: str, model_name: str, config, params,
     """Write a servable directory: ``serve.json`` (model family +
     config) beside a sharded checkpoint (checkpoint.py) — what
     ``hvdrun --serve DIR`` consumes."""
-    import dataclasses
     from .. import checkpoint as ckpt
     os.makedirs(directory, exist_ok=True)
     cfg_dict = {k: v for k, v in dataclasses.asdict(config).items()
@@ -1495,7 +1521,8 @@ def save_servable(directory: str, model_name: str, config, params,
 
 def load_servable(directory: str, mesh) -> Tuple[Any, Any, Any]:
     """Read a servable directory -> (model module, model config, global
-    replicated params).  ``serve.json``: {"model": "llama"|"moe_llama",
+    replicated params).  ``serve.json``: {"model": "llama"|"moe_llama"|
+    "latent_moe",
     "config": <name in CONFIGS or kwarg dict>, "seed": int?}.  Params
     come from the latest checkpoint under the directory (restored
     through checkpoint.py into replicated shardings); with no

@@ -305,7 +305,7 @@ def _step_and_note_widths(engine, widths):
     against tick_width of that tick's work list."""
     engine.step()
     if engine._inflight:    # one deep: what this step dispatched
-        _, work, next_tokens, _ = engine._inflight[-1]
+        _, work, next_tokens = engine._inflight[-1][:3]
         assert next_tokens.shape == (engine.cfg.max_slots,
                                      tick_width(engine.cfg, work))
         widths.append(next_tokens.shape[1])
