@@ -248,9 +248,10 @@ def loss_fn(params: Dict[str, Any], ids: jax.Array, cfg: LlamaConfig,
 def init_cache(cfg: LlamaConfig, num_blocks: int, block_size: int,
                dtype=None) -> Dict[str, jax.Array]:
     """Preallocate the paged KV pool: ``{"k","v"}`` of shape
-    ``[n_layers, num_blocks, block_size, n_kv_heads, head_dim]``.  Shard
-    it along the existing mesh axes with serve.engine.cache_shardings
-    (blocks over the data axis, kv heads over a model axis)."""
+    ``[n_layers, num_blocks, block_size, n_kv_heads, head_dim]``, one
+    stacked buffer each that apply_cached indexes by layer and never
+    unstacks.  Shard it with serve.engine.cache_shardings (blocks over
+    the data axis, kv heads over a model axis)."""
     dtype = dtype if dtype is not None else cfg.dtype
     shape = (cfg.n_layers, num_blocks, block_size, cfg.n_kv_heads,
              cfg.head_dim)
@@ -266,8 +267,6 @@ def copy_blocks(cache: Dict[str, jax.Array], src: jax.Array,
     range and are dropped; their ``src`` is clamped so the gather stays
     in bounds.  The diverging sequence then overwrites its suffix
     positions in the clone, leaving the shared original untouched."""
-    import jax.numpy as jnp
-
     def cp(pool):
         safe = jnp.clip(src, 0, pool.shape[1] - 1)
         return pool.at[:, dst].set(pool[:, safe], mode="drop")
@@ -276,11 +275,15 @@ def copy_blocks(cache: Dict[str, jax.Array], src: jax.Array,
 
 def _attn_cached(p: Dict[str, Any], x: jax.Array, cfg: LlamaConfig,
                  cos: jax.Array, sin: jax.Array,
-                 k_pool: jax.Array, v_pool: jax.Array,
+                 k_pool: jax.Array, v_pool: jax.Array, layer: int,
                  block_tables: jax.Array, positions: jax.Array,
                  valid: jax.Array
                  ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One layer's attention over the paged cache.
+    """Layer ``layer``'s attention over the paged cache, in place:
+    ``k_pool`` / ``v_pool`` are the STACKED pools (init_cache's five
+    axes) and come back with this layer's new positions scattered in — no
+    layer's pool is cut out of the stack or put back, so a donated cache
+    stays one buffer through the tick.
 
     x: [S, C, dim] — S serving slots each contributing a chunk of C new
     token positions (prefill consumes whole chunks; decode uses C with
@@ -291,7 +294,7 @@ def _attn_cached(p: Dict[str, Any], x: jax.Array, cfg: LlamaConfig,
     path (fuse_proj is a training-throughput lever; TP shards the
     separate kernels)."""
     S, C, _ = x.shape
-    num_blocks, block_size = k_pool.shape[0], k_pool.shape[1]
+    num_blocks, block_size = k_pool.shape[1], k_pool.shape[2]
     max_blocks = block_tables.shape[1]
     q = L.dense(p["wq"], x).reshape(S, C, cfg.n_heads, cfg.head_dim)
     k = L.dense(p["wk"], x).reshape(S, C, cfg.n_kv_heads, cfg.head_dim)
@@ -301,24 +304,24 @@ def _attn_cached(p: Dict[str, Any], x: jax.Array, cfg: LlamaConfig,
     k = L.apply_rope_at(k, cos, sin, pos_c)
     # Scatter the chunk's k/v into the pool: token at global position P
     # lands in block_tables[s, P // bs] at offset P % bs.  Invalid
-    # (padding / inactive-slot) positions are routed out of bounds and
-    # dropped, so a dead slot's stale table row is never written.
+    # (padding / inactive-slot) positions are routed off the block axis
+    # and dropped, so a dead slot's stale table row is never written.
     slot_idx = jnp.minimum(positions // block_size, max_blocks - 1)
     blk = jnp.take_along_axis(block_tables, slot_idx, axis=1)
     blk = jnp.where(valid, jnp.maximum(blk, 0), num_blocks)
     off = positions % block_size
     with jax.named_scope("kv_write"):
-        k_pool = k_pool.at[blk, off].set(k, mode="drop")
-        v_pool = v_pool.at[blk, off].set(v, mode="drop")
+        k_pool = k_pool.at[layer, blk, off].set(k, mode="drop")
+        v_pool = v_pool.at[layer, blk, off].set(v, mode="drop")
     # Gather each slot's full context.  Table slot j covers global
     # positions [j*bs, (j+1)*bs), so gathered index t IS global position
     # t; unassigned entries (-1 -> block 0) only cover positions the
     # causal mask excludes, and masked scores softmax to exactly 0.
     bt = jnp.maximum(block_tables, 0)
     with jax.named_scope("kv_gather"):
-        k_ctx = jnp.take(k_pool, bt, axis=0).reshape(
+        k_ctx = k_pool[layer, bt].reshape(
             S, max_blocks * block_size, cfg.n_kv_heads, cfg.head_dim)
-        v_ctx = jnp.take(v_pool, bt, axis=0).reshape(
+        v_ctx = v_pool[layer, bt].reshape(
             S, max_blocks * block_size, cfg.n_kv_heads, cfg.head_dim)
     key_pos = jnp.arange(max_blocks * block_size)
     mask = (key_pos[None, None, :] <= positions[:, :, None])[:, None]
@@ -340,30 +343,27 @@ def apply_cached(params: Dict[str, Any], tokens: jax.Array,
     unassigned).  Returns (logits [S, C, vocab], updated cache); the
     caller samples from position ``n_new[s] - 1``.  Prefill a prompt in
     ceil(len/C) calls, then decode one token per call — the serving
-    engine's one jit'd tick (horovod_tpu/serve/engine.py)."""
+    engine's one jit'd tick (horovod_tpu/serve/engine.py), which donates
+    ``cache``: the stacked pools go through the layers whole."""
     S, C = tokens.shape
     cos, sin = L.rope_freqs(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
     positions = lengths[:, None] + jnp.arange(C, dtype=lengths.dtype)[None]
     valid = jnp.arange(C)[None, :] < n_new[:, None]
     with jax.named_scope("embed"):
         x = L.embedding(params["embed"], tokens).astype(cfg.dtype)
-    ks, vs = [], []
+    k_pool, v_pool = cache["k"], cache["v"]
     for i, p in enumerate(params["layers"]):
         with jax.named_scope("attn"):
             a, k_pool, v_pool = _attn_cached(
                 p, L.rmsnorm(p["attn_norm"], x), cfg, cos, sin,
-                cache["k"][i], cache["v"][i], block_tables, positions, valid)
+                k_pool, v_pool, i, block_tables, positions, valid)
             x = x + a
         with jax.named_scope("ffn"):
             x = x + _ffn(p, L.rmsnorm(p["ffn_norm"], x), cfg)
-        ks.append(k_pool)
-        vs.append(v_pool)
     x = L.rmsnorm(params["final_norm"], x)
     with jax.named_scope("head"):
         logits = L.dense(params["lm_head"], x)
-    with jax.named_scope("kv_write"):
-        cache = {"k": jnp.stack(ks), "v": jnp.stack(vs)}
-    return logits, cache
+    return logits, {"k": k_pool, "v": v_pool}
 
 
 def param_count(cfg: LlamaConfig) -> int:

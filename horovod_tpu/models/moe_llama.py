@@ -187,7 +187,8 @@ def apply_cached(params: Dict[str, Any], tokens: jax.Array,
     """Mixed prefill/decode forward over the paged cache (the moe twin
     of llama.apply_cached; same slot-table contract).  Returns (logits
     [S, C, vocab], updated cache, mean router aux).  ``moe_fn`` defaults
-    to the drop-free dense path — the batch-invariant serving routing."""
+    to the drop-free dense path — the batch-invariant serving routing.
+    The stacked pools go through the layers whole, as in llama's."""
     S, C = tokens.shape
     lcfg = _llama_cfg(cfg)
     moe_fn = moe_fn if moe_fn is not None else dropfree_moe_fn(cfg)
@@ -195,21 +196,18 @@ def apply_cached(params: Dict[str, Any], tokens: jax.Array,
     positions = lengths[:, None] + jnp.arange(C, dtype=lengths.dtype)[None]
     valid = jnp.arange(C)[None, :] < n_new[:, None]
     x = L.embedding(params["embed"], tokens).astype(cfg.dtype)
-    ks, vs, auxes = [], [], []
+    k_pool, v_pool, auxes = cache["k"], cache["v"], []
     for i, p in enumerate(params["layers"]):
         a, k_pool, v_pool = Ll._attn_cached(
             p, L.rmsnorm(p["attn_norm"], x), lcfg, cos, sin,
-            cache["k"][i], cache["v"][i], block_tables, positions, valid)
+            k_pool, v_pool, i, block_tables, positions, valid)
         x = x + a
         y, aux = _moe_block(p["moe"], L.rmsnorm(p["ffn_norm"], x), cfg,
                             moe_fn)
         x = x + y
-        ks.append(k_pool)
-        vs.append(v_pool)
         auxes.append(aux)
     x = L.rmsnorm(params["final_norm"], x)
-    return (L.dense(params["lm_head"], x),
-            {"k": jnp.stack(ks), "v": jnp.stack(vs)},
+    return (L.dense(params["lm_head"], x), {"k": k_pool, "v": v_pool},
             jnp.mean(jnp.stack(auxes)))
 
 

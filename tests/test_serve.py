@@ -4,6 +4,7 @@ admission/eviction discipline, paged-cache block reuse, the prefill+decode
 and the HOROVOD_SERVE_* knob validation contract."""
 
 import json
+import re
 import threading
 import time
 import urllib.error
@@ -245,6 +246,56 @@ def test_paged_layout_is_length_invariant(llama_tiny):
     assert np.abs(np.asarray(out[0][1, :5]) - full_b).max() < 1e-5
 
 
+@pytest.mark.parametrize("family", ["llama", "moe"])
+def test_tick_leaves_the_rest_of_the_pool_alone(family, llama_tiny,
+                                                moe_tiny):
+    """One mixed tick over a pool full of a pattern: a prefill row with
+    two padding positions, a decode row, a dead slot with a stale table
+    row and an empty slot.  Every (layer, block, offset) no valid
+    position owns keeps its bytes; position P of slot s lands at
+    ``(layer, block_tables[s, P // bs], P % bs)`` — holding what the same
+    tick writes when every block sits elsewhere in a pool that is zero
+    but for the decode row's context (a position's k/v know nothing of
+    block numbers or of what the mask hides)."""
+    model, cfg, params = llama_tiny if family == "llama" else moe_tiny
+    bs, nb, C = 4, 16, 8
+    rng = np.random.RandomState(29)
+    shape = (cfg.n_layers, nb, bs, cfg.n_kv_heads, cfg.head_dim)
+    pattern = {n: rng.randn(*shape).astype(np.float32) for n in "kv"}
+    bt = np.array([[3, 7, -1, -1],      # prefill: positions 0..5 of 8
+                   [10, 2, 5, -1],      # decode: position 9
+                   [8, 9, -1, -1],      # dead, its row left behind
+                   [-1, -1, -1, -1]], np.int32)
+    lengths = np.array([0, 9, 5, 0], np.int32)
+    n_new = np.array([6, 1, 0, 0], np.int32)
+    toks = rng.randint(0, cfg.vocab, (4, C)).astype(np.int32)
+    step = jax.jit(lambda cache, bt: model.apply_cached(
+        params, jnp.asarray(toks), cfg, cache, bt, jnp.asarray(lengths),
+        jnp.asarray(n_new))[1])
+    got = {n: np.asarray(x) for n, x in step(
+        {n: jnp.asarray(x) for n, x in pattern.items()},
+        jnp.asarray(bt)).items()}
+    # the same tick with block b at nb - 1 - b, in a pool that holds
+    # nothing but the decode row's nine positions of context
+    moved = {n: np.zeros(shape, np.float32) for n in "kv"}
+    for n in "kv":
+        moved[n][:, nb - 1 - bt[1, :3]] = pattern[n][:, bt[1, :3]]
+    want = {n: np.asarray(x) for n, x in step(
+        {n: jnp.asarray(x) for n, x in moved.items()},
+        jnp.asarray(np.where(bt < 0, bt, nb - 1 - bt))).items()}
+    written = np.zeros((nb, bs), bool)
+    for s in range(4):
+        for P in range(lengths[s], lengths[s] + n_new[s]):
+            written[bt[s, P // bs], P % bs] = True
+    assert written.sum() == 7
+    for n in "kv":
+        assert got[n][:, ~written].tobytes() == \
+            pattern[n][:, ~written].tobytes(), n
+        np.testing.assert_allclose(
+            got[n][:, written], want[n][:, ::-1][:, written], atol=1e-6)
+        assert not np.allclose(got[n][:, written], pattern[n][:, written])
+
+
 def _reference_greedy(model, cfg, params, prompt, n_new):
     """Greedy continuation via the FULL forward, one token at a time —
     the oracle the continuous-batching engine must match exactly."""
@@ -385,6 +436,44 @@ def test_both_widths_compile_at_the_first_dispatch(first, llama_tiny):
     assert loop["ticks"] - loop["narrow_ticks"] == 1    # long_'s one chunk
     assert loop["narrow_ticks"] >= 5
     engine.close()
+
+
+@pytest.mark.parametrize("width", ["wide", "narrow"])
+@pytest.mark.parametrize("family", ["llama", "moe"])
+def test_tick_keeps_no_second_pool(family, width, llama_tiny, moe_tiny,
+                                   monkeypatch):
+    """The engine's compiled step writes and reads the stacked, donated
+    pool in place (counts of the CPU's compiler; the chip has the times):
+    its optimized HLO restacks nothing and holds no op shaped like one
+    layer's pool, and without ``copy_blocks`` its temporaries stay under
+    ONE layer's K pool — a slice a layer and a restack take more than
+    two whole stacks.  ``copy_blocks`` is left out of the second
+    count only: XLA:CPU transposes both stacks to scatter along their
+    block axis, two stacks of temporaries by itself, which the chip's
+    compiler does not."""
+    model, cfg, params = llama_tiny if family == "llama" else moe_tiny
+    scfg = _cfg(cache_blocks=2048, max_batch_tokens=12, spec_k=2)
+    C = scfg.prefill_chunk if width == "wide" else decode_width(scfg)
+
+    def compiled():
+        engine = ServeEngine(model, cfg, params, scfg,
+                             mesh=_one_device_mesh())
+        engine.submit(list(range(9)), 2, req_id="a")
+        engine.step()       # the first dispatch compiles both widths
+        engine.close()
+        return engine._steps[C], engine.cache["k"]
+
+    step, pool = compiled()
+    dims = lambda shape: "[" + ",".join(map(str, shape)) + "]"
+    ops = re.findall(r" = \w+(\[[\d,]*\])\S* ([\w-]+)\(", step.as_text())
+    assert (dims(pool.shape), "scatter") in ops     # the regex sees the pool
+    assert (dims(pool.shape), "concatenate") not in ops
+    layer = {dims(pool.shape[1:]), dims((1,) + pool.shape[1:])}
+    assert not [op for op in ops if op[0] in layer]
+    monkeypatch.setattr(model, "copy_blocks", lambda cache, src, dst: cache)
+    step, _ = compiled()
+    assert step.memory_analysis().temp_size_in_bytes < \
+        pool.nbytes // pool.shape[0]
 
 
 def test_two_engines_fed_alike_agree_on_digest_and_widths(llama_tiny):
