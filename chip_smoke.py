@@ -513,7 +513,7 @@ def phase_serve(size, dry, env, timeout, device):
                   f"devices={device['device_count']}," in ready,
                   f"server came up on another device: {ready}")
             if device["device_count"] > 1:
-                # engine.cache_shardings: blocks over the data axis
+                # models/paged.py shardings: blocks over the data axis
                 check("cache=PartitionSpec(None, '" in ready,
                       f"paged cache is not sharded over the mesh: {ready}")
             while "engine" not in http(port, "serve/stats", timeout=10)[1][0]:

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from . import layers as L
+from . import paged
 from ..parallel import expert as X
 
 
@@ -243,29 +244,12 @@ def init_cache(cfg: LatentMoeConfig, num_blocks: int, block_size: int,
                                  cfg.latent_dim), dtype)}
 
 
-def cache_shardings(mesh, num_blocks: int):
-    """NamedSharding for the latent pool: blocks over the first mesh axis
-    that divides them; the latent is every head's, so no axis of it is
-    sharded over a model axis."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    block_axis = next((a for a in mesh.axis_names
-                       if num_blocks % mesh.shape[a] == 0), None)
-    return NamedSharding(mesh, P(None, block_axis, None, None))
+def cache_shardings(mesh, cfg: LatentMoeConfig, num_blocks: int):
+    """The latent is every head's: no axis of it goes over a model axis."""
+    return paged.shardings(mesh, num_blocks)
 
 
-def copy_blocks(cache: Dict[str, jax.Array], src: jax.Array,
-                dst: jax.Array) -> Dict[str, jax.Array]:
-    """Clone whole pool blocks ``src[i] -> dst[i]`` across every layer
-    before the tick's writes (the prefix cache's copy-on-write, as
-    llama.copy_blocks)."""
-    pool = cache["latent"]
-    safe = jnp.clip(src, 0, pool.shape[1] - 1)
-    # a layer at a time, as the tick's own writes index the pool: one
-    # scatter across all layers makes the compiler keep the pool in a layout
-    # of its own for it and copy the whole pool there and back every tick
-    for i in range(pool.shape[0]):
-        pool = pool.at[i, dst].set(pool[i, safe], mode="drop")
-    return {"latent": pool}
+copy_blocks = paged.copy_blocks
 
 
 def _slots_per_block(S: int, per_slot_bytes: int, budget: int) -> int:
@@ -285,14 +269,13 @@ def _latent_attention(q: jax.Array, lat: jax.Array, positions: jax.Array,
     S, C, H, _ = q.shape
     ctx = lat.shape[1]
     scale = 1.0 / math.sqrt(cfg.qk_dim)
-    key_pos = jnp.arange(ctx, dtype=positions.dtype)
     fill = jnp.finfo(jnp.float32).min
 
     def attend(q, lat, pos):
         s = jnp.einsum("schx,skx->shck", q, lat,
                        preferred_element_type=jnp.float32) * scale
-        mask = key_pos[None, None, None, :] <= pos[:, None, :, None]
-        pr = jax.nn.softmax(jnp.where(mask, s, fill), -1).astype(q.dtype)
+        pr = jax.nn.softmax(jnp.where(paged.context_mask(pos, ctx), s, fill),
+                            -1).astype(q.dtype)
         return jnp.einsum("shck,skl->schl", pr, lat[..., :cfg.kv_rank])
 
     def block(args):
@@ -327,19 +310,10 @@ def apply_cached(params: Dict[str, Any], tokens: jax.Array,
     int32[len(TICK_COUNTERS)] summed over the expert layers)."""
     S, C = tokens.shape
     T = S * C
-    pool = cache["latent"]
-    num_blocks, block_size = pool.shape[1], pool.shape[2]
-    max_blocks = block_tables.shape[1]
     cos, sin = L.rope_freqs(cfg.qk_rope_dim, cfg.max_seq, cfg.rope_theta)
-    positions = lengths[:, None] + jnp.arange(C, dtype=lengths.dtype)[None]
-    valid = jnp.arange(C)[None, :] < n_new[:, None]
-    # where a new position's latent lands: block_tables[s, P // bs] at
-    # offset P % bs; padding and dead slots go out of bounds and are dropped
-    blk = jnp.take_along_axis(
-        block_tables, jnp.minimum(positions // block_size, max_blocks - 1),
-        axis=1)
-    blk = jnp.where(valid, jnp.maximum(blk, 0), num_blocks)
-    bt = jnp.maximum(block_tables, 0)
+    positions, valid = paged.slot_positions(lengths, n_new, C)
+    blk, off = paged.write_index(block_tables, positions, valid,
+                                 *cache["latent"].shape[1:3])
     # Everything but the attention's core is a token's own: it runs on ROWS,
     # the slab's [S * C] positions, or — when the engine promises fewer valid
     # tokens a tick than the slab has positions (``max_tick_tokens``) — on the
@@ -351,7 +325,8 @@ def apply_cached(params: Dict[str, Any], tokens: jax.Array,
     rows = (jnp.argsort(~flat(valid), stable=True)[:R] if R < T
             else jnp.arange(T))
     take = lambda a: flat(a)[rows][None]                # [S, C, ..] -> [1, R, ..]
-    row_valid, row_pos, row_blk = take(valid), take(positions), take(blk)
+    row_valid, row_pos, row_blk, row_off = map(
+        take, (valid, positions, blk, off))
     pos_c = jnp.minimum(row_pos, cfg.max_seq - 1)
     with jax.named_scope("embed"):
         x = L.embedding(params["embed"], take(tokens)).astype(cfg.dtype)
@@ -369,16 +344,13 @@ def apply_cached(params: Dict[str, Any], tokens: jax.Array,
         a = p["attn"]
         q_nope, q_rope, latent = _project(a, _norm(p["input_norm"], x, cfg),
                                           cfg, cos, sin, pos_c)
-        with jax.named_scope("kv_write"):
-            pool = pool.at[i, row_blk[0], row_pos[0] % block_size].set(
-                latent[0].astype(pool.dtype), mode="drop")
+        cache = paged.write(
+            cache, i, row_blk[0], row_off[0],
+            {"latent": latent[0].astype(cache["latent"].dtype)})
         wk, wv = _wkv_b(a, cfg)
-        with jax.named_scope("kv_gather"):
-            # table slot j covers positions [j*bs, (j+1)*bs): gathered index
-            # t IS position t; unassigned entries (-1 -> block 0) cover only
-            # positions the mask excludes.  Gathered outside the slot-block
-            # loop: a pool that a loop holds is copied whole
-            lat = pool[i, bt].reshape(S, max_blocks * block_size, -1)
+        # gathered outside the slot-block loop: a pool that a loop holds is
+        # copied whole
+        lat = paged.gather(cache, i, block_tables)["latent"]
         with jax.named_scope("attn/latent_scores"):
             q = jnp.concatenate(
                 [jnp.einsum("brhn,lhn->brhl", q_nope, wk), q_rope], -1)
@@ -393,7 +365,7 @@ def apply_cached(params: Dict[str, Any], tokens: jax.Array,
     with jax.named_scope("head"):
         logits = slab(L.dense(params["lm_head"],
                               _norm(params["final_norm"], x, cfg)))
-    return (logits, {"latent": pool},
+    return (logits, cache,
             jnp.concatenate([jnp.ones(1, jnp.int32), counters]))
 
 

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from . import layers as L
+from . import paged
 
 
 @dataclasses.dataclass(frozen=True)
@@ -234,15 +235,10 @@ def loss_fn(params: Dict[str, Any], ids: jax.Array, cfg: LlamaConfig,
 
 
 # ----------------------------------------------------------- decode path
-# Serving-plane KV cache (docs/serving.md): one PREALLOCATED pool of
-# fixed-size blocks per layer, shared by every in-flight sequence — a
-# sequence owns whole blocks via its block-table row, so sequences of
-# different lengths coexist in static shapes (the paged-attention
-# layout).  Block tables use -1 for unassigned entries; positions past a
-# slot's live length are masked with the score dtype's minimum, which
-# the fp32 softmax turns into an exact 0 — so the cached forward sums
-# the same terms as the full-sequence forward and prefill + N decode
-# steps reproduce apply()'s logits bit-near (tests/test_serve.py).
+# Serving-plane KV cache (docs/serving.md) over models/paged.py's pool.
+# Positions past a slot's live length are masked with the score dtype's
+# minimum, which the fp32 softmax turns into an exact 0 — so prefill + N
+# decode steps reproduce apply()'s logits bit-near (tests/test_serve.py).
 
 
 def init_cache(cfg: LlamaConfig, num_blocks: int, block_size: int,
@@ -250,40 +246,30 @@ def init_cache(cfg: LlamaConfig, num_blocks: int, block_size: int,
     """Preallocate the paged KV pool: ``{"k","v"}`` of shape
     ``[n_layers, num_blocks, block_size, n_kv_heads, head_dim]``, one
     stacked buffer each that apply_cached indexes by layer and never
-    unstacks.  Shard it with serve.engine.cache_shardings (blocks over
-    the data axis, kv heads over a model axis)."""
+    unstacks (models/paged.py)."""
     dtype = dtype if dtype is not None else cfg.dtype
     shape = (cfg.n_layers, num_blocks, block_size, cfg.n_kv_heads,
              cfg.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
-def copy_blocks(cache: Dict[str, jax.Array], src: jax.Array,
-                dst: jax.Array) -> Dict[str, jax.Array]:
-    """Copy-on-write support for the serving prefix cache
-    (serve/engine.py PrefixCache): clone whole pool blocks
-    ``src[i] -> dst[i]`` across every layer in one gather+scatter,
-    BEFORE the tick's KV writes.  Padding entries route ``dst`` out of
-    range and are dropped; their ``src`` is clamped so the gather stays
-    in bounds.  The diverging sequence then overwrites its suffix
-    positions in the clone, leaving the shared original untouched."""
-    def cp(pool):
-        safe = jnp.clip(src, 0, pool.shape[1] - 1)
-        return pool.at[:, dst].set(pool[:, safe], mode="drop")
-    return {"k": cp(cache["k"]), "v": cp(cache["v"])}
+def cache_shardings(mesh, cfg: LlamaConfig, num_blocks: int):
+    """Blocks over the data axis, kv heads over a model axis."""
+    return paged.shardings(mesh, num_blocks, cfg.n_kv_heads)
+
+
+copy_blocks = paged.copy_blocks
+TICK_COUNTERS = ()      # the tick counts nothing beside its logits
 
 
 def _attn_cached(p: Dict[str, Any], x: jax.Array, cfg: LlamaConfig,
-                 cos: jax.Array, sin: jax.Array,
-                 k_pool: jax.Array, v_pool: jax.Array, layer: int,
-                 block_tables: jax.Array, positions: jax.Array,
-                 valid: jax.Array
-                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Layer ``layer``'s attention over the paged cache, in place:
-    ``k_pool`` / ``v_pool`` are the STACKED pools (init_cache's five
-    axes) and come back with this layer's new positions scattered in — no
-    layer's pool is cut out of the stack or put back, so a donated cache
-    stays one buffer through the tick.
+                 cos: jax.Array, sin: jax.Array, cache: Dict[str, jax.Array],
+                 layer: int, block_tables: jax.Array, positions: jax.Array,
+                 valid: jax.Array) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """Layer ``layer``'s attention over the paged cache, in place: ``cache``
+    is the STACKED pools (init_cache's five axes) and comes back with this
+    layer's new positions scattered in — no layer's pool is cut out of the
+    stack or put back, so a donated cache stays one buffer through the tick.
 
     x: [S, C, dim] — S serving slots each contributing a chunk of C new
     token positions (prefill consumes whole chunks; decode uses C with
@@ -294,40 +280,20 @@ def _attn_cached(p: Dict[str, Any], x: jax.Array, cfg: LlamaConfig,
     path (fuse_proj is a training-throughput lever; TP shards the
     separate kernels)."""
     S, C, _ = x.shape
-    num_blocks, block_size = k_pool.shape[1], k_pool.shape[2]
-    max_blocks = block_tables.shape[1]
     q = L.dense(p["wq"], x).reshape(S, C, cfg.n_heads, cfg.head_dim)
     k = L.dense(p["wk"], x).reshape(S, C, cfg.n_kv_heads, cfg.head_dim)
     v = L.dense(p["wv"], x).reshape(S, C, cfg.n_kv_heads, cfg.head_dim)
     pos_c = jnp.minimum(positions, cfg.max_seq - 1)
     q = L.apply_rope_at(q, cos, sin, pos_c)
     k = L.apply_rope_at(k, cos, sin, pos_c)
-    # Scatter the chunk's k/v into the pool: token at global position P
-    # lands in block_tables[s, P // bs] at offset P % bs.  Invalid
-    # (padding / inactive-slot) positions are routed off the block axis
-    # and dropped, so a dead slot's stale table row is never written.
-    slot_idx = jnp.minimum(positions // block_size, max_blocks - 1)
-    blk = jnp.take_along_axis(block_tables, slot_idx, axis=1)
-    blk = jnp.where(valid, jnp.maximum(blk, 0), num_blocks)
-    off = positions % block_size
-    with jax.named_scope("kv_write"):
-        k_pool = k_pool.at[layer, blk, off].set(k, mode="drop")
-        v_pool = v_pool.at[layer, blk, off].set(v, mode="drop")
-    # Gather each slot's full context.  Table slot j covers global
-    # positions [j*bs, (j+1)*bs), so gathered index t IS global position
-    # t; unassigned entries (-1 -> block 0) only cover positions the
-    # causal mask excludes, and masked scores softmax to exactly 0.
-    bt = jnp.maximum(block_tables, 0)
-    with jax.named_scope("kv_gather"):
-        k_ctx = k_pool[layer, bt].reshape(
-            S, max_blocks * block_size, cfg.n_kv_heads, cfg.head_dim)
-        v_ctx = v_pool[layer, bt].reshape(
-            S, max_blocks * block_size, cfg.n_kv_heads, cfg.head_dim)
-    key_pos = jnp.arange(max_blocks * block_size)
-    mask = (key_pos[None, None, :] <= positions[:, :, None])[:, None]
-    o = L.causal_attention(q, k_ctx, v_ctx, causal=False, mask=mask)
+    blk, off = paged.write_index(block_tables, positions, valid,
+                                 *cache["k"].shape[1:3])
+    cache = paged.write(cache, layer, blk, off, {"k": k, "v": v})
+    ctx = paged.gather(cache, layer, block_tables)
+    mask = paged.context_mask(positions, ctx["k"].shape[1])
+    o = L.causal_attention(q, ctx["k"], ctx["v"], causal=False, mask=mask)
     return (L.dense(p["wo"], o.reshape(S, C, cfg.n_heads * cfg.head_dim)),
-            k_pool, v_pool)
+            cache)
 
 
 def apply_cached(params: Dict[str, Any], tokens: jax.Array,
@@ -345,25 +311,22 @@ def apply_cached(params: Dict[str, Any], tokens: jax.Array,
     ceil(len/C) calls, then decode one token per call — the serving
     engine's one jit'd tick (horovod_tpu/serve/engine.py), which donates
     ``cache``: the stacked pools go through the layers whole."""
-    S, C = tokens.shape
     cos, sin = L.rope_freqs(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
-    positions = lengths[:, None] + jnp.arange(C, dtype=lengths.dtype)[None]
-    valid = jnp.arange(C)[None, :] < n_new[:, None]
+    positions, valid = paged.slot_positions(lengths, n_new, tokens.shape[1])
     with jax.named_scope("embed"):
         x = L.embedding(params["embed"], tokens).astype(cfg.dtype)
-    k_pool, v_pool = cache["k"], cache["v"]
     for i, p in enumerate(params["layers"]):
         with jax.named_scope("attn"):
-            a, k_pool, v_pool = _attn_cached(
+            a, cache = _attn_cached(
                 p, L.rmsnorm(p["attn_norm"], x), cfg, cos, sin,
-                k_pool, v_pool, i, block_tables, positions, valid)
+                cache, i, block_tables, positions, valid)
             x = x + a
         with jax.named_scope("ffn"):
             x = x + _ffn(p, L.rmsnorm(p["ffn_norm"], x), cfg)
     x = L.rmsnorm(params["final_norm"], x)
     with jax.named_scope("head"):
         logits = L.dense(params["lm_head"], x)
-    return logits, {"k": k_pool, "v": v_pool}
+    return logits, cache
 
 
 def param_count(cfg: LlamaConfig) -> int:

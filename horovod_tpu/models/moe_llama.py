@@ -26,6 +26,7 @@ import jax.numpy as jnp
 
 from . import layers as L
 from . import llama as Ll
+from . import paged
 from ..parallel.expert import init_moe_params, moe_dense_reference
 
 
@@ -164,19 +165,12 @@ def dropfree_moe_fn(cfg: MoeLlamaConfig) -> Callable:
     return fn
 
 
-def init_cache(cfg: MoeLlamaConfig, num_blocks: int, block_size: int,
-               dtype=None) -> Dict[str, jax.Array]:
-    """Paged KV pool for the attention half — exactly llama's layout
-    (the attention IS llama's, so the pool is too)."""
-    return Ll.init_cache(_llama_cfg(cfg), num_blocks, block_size,
-                         dtype=dtype)
-
-
-def copy_blocks(cache: Dict[str, jax.Array], src: jax.Array,
-                dst: jax.Array) -> Dict[str, jax.Array]:
-    """CoW block clone for the serving prefix cache — exactly llama's
-    (the attention half IS llama's, so the pool layout is too)."""
-    return Ll.copy_blocks(cache, src, dst)
+# The attention half IS llama's, so the paged pool, its copy-on-write clone
+# and its sharding are too (they read n_layers, n_kv_heads, head_dim, dtype).
+init_cache, copy_blocks = Ll.init_cache, Ll.copy_blocks
+cache_shardings = Ll.cache_shardings
+#: ServeEngine ignores the third value of apply_cached, the router aux
+TICK_COUNTERS = ()
 
 
 def apply_cached(params: Dict[str, Any], tokens: jax.Array,
@@ -187,27 +181,24 @@ def apply_cached(params: Dict[str, Any], tokens: jax.Array,
     """Mixed prefill/decode forward over the paged cache (the moe twin
     of llama.apply_cached; same slot-table contract).  Returns (logits
     [S, C, vocab], updated cache, mean router aux).  ``moe_fn`` defaults
-    to the drop-free dense path — the batch-invariant serving routing.
-    The stacked pools go through the layers whole, as in llama's."""
-    S, C = tokens.shape
+    to the drop-free dense path — the batch-invariant serving routing."""
     lcfg = _llama_cfg(cfg)
     moe_fn = moe_fn if moe_fn is not None else dropfree_moe_fn(cfg)
     cos, sin = L.rope_freqs(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
-    positions = lengths[:, None] + jnp.arange(C, dtype=lengths.dtype)[None]
-    valid = jnp.arange(C)[None, :] < n_new[:, None]
+    positions, valid = paged.slot_positions(lengths, n_new, tokens.shape[1])
     x = L.embedding(params["embed"], tokens).astype(cfg.dtype)
-    k_pool, v_pool, auxes = cache["k"], cache["v"], []
+    auxes = []
     for i, p in enumerate(params["layers"]):
-        a, k_pool, v_pool = Ll._attn_cached(
+        a, cache = Ll._attn_cached(
             p, L.rmsnorm(p["attn_norm"], x), lcfg, cos, sin,
-            k_pool, v_pool, i, block_tables, positions, valid)
+            cache, i, block_tables, positions, valid)
         x = x + a
         y, aux = _moe_block(p["moe"], L.rmsnorm(p["ffn_norm"], x), cfg,
                             moe_fn)
         x = x + y
         auxes.append(aux)
     x = L.rmsnorm(params["final_norm"], x)
-    return (L.dense(params["lm_head"], x), {"k": k_pool, "v": v_pool},
+    return (L.dense(params["lm_head"], x), cache,
             jnp.mean(jnp.stack(auxes)))
 
 
@@ -223,4 +214,4 @@ def param_count(cfg: MoeLlamaConfig) -> int:
 
 __all__ = ["MoeLlamaConfig", "CONFIGS", "init", "apply", "loss_fn",
            "param_count", "init_cache", "apply_cached", "copy_blocks",
-           "dropfree_moe_fn"]
+           "cache_shardings", "TICK_COUNTERS", "dropfree_moe_fn"]

@@ -1,0 +1,131 @@
+"""How a block table addresses a paged pool — the one place that knows.
+
+A pool is any pytree of arrays ``[n_layers, num_blocks, block_size, ...]``
+(models/llama.py: ``{"k","v"}`` with ``[kv_heads, head_dim]`` behind;
+models/latent_moe.py: ``{"latent"}`` with ``[kv_rank + rope]`` behind): one
+PREALLOCATED buffer of fixed-size blocks a layer, shared by every in-flight
+sequence.  A sequence owns whole blocks through its row of ``block_tables``
+``[S, max_blocks]`` (-1 = unassigned): table slot j covers positions
+``[j*bs, (j+1)*bs)``, so sequences of different lengths coexist in static
+shapes.  The stacked pool is indexed by layer, never unstacked: a donated
+cache stays one buffer through a tick (docs/serving.md).
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+
+def slot_positions(lengths: jax.Array, n_new: jax.Array, C: int
+                   ) -> Tuple[jax.Array, jax.Array]:
+    """(positions, valid) [S, C]: slot s's column j is position ``lengths[s]
+    + j`` and holds a token when ``j < n_new[s]`` (0 = an inactive slot)."""
+    positions = lengths[:, None] + jnp.arange(C, dtype=lengths.dtype)[None]
+    return positions, jnp.arange(C)[None, :] < n_new[:, None]
+
+
+def write_index(block_tables: jax.Array, positions: jax.Array,
+                valid: jax.Array, num_blocks: int, block_size: int
+                ) -> Tuple[jax.Array, jax.Array]:
+    """(blk, off): position P of slot s lands in ``block_tables[s, P // bs]``
+    at offset ``P % bs``.  Invalid (padding / inactive-slot) positions go to
+    ``num_blocks``, off the block axis, where :func:`write` drops them — a
+    dead slot's stale table row is never written."""
+    slot_idx = jnp.minimum(positions // block_size,
+                           block_tables.shape[1] - 1)
+    blk = jnp.take_along_axis(block_tables, slot_idx, axis=1)
+    blk = jnp.where(valid, jnp.maximum(blk, 0), num_blocks)
+    return blk, positions % block_size
+
+
+def write(pool: Any, layer: int, blk: jax.Array, off: jax.Array,
+          values: Any) -> Any:
+    """Scatter ``values`` (a pytree like ``pool``) into layer ``layer`` of
+    the stacked pool at (blk, off), in place; out of range is dropped."""
+    with jax.named_scope("kv_write"):
+        return jax.tree_util.tree_map(
+            lambda p, v: p.at[layer, blk, off].set(v, mode="drop"),
+            pool, values)
+
+
+def gather(pool: Any, layer: int, block_tables: jax.Array) -> Any:
+    """Every slot's whole context of layer ``layer``, ``[S, max_blocks *
+    block_size, ...]`` a leaf: index t IS position t.  Unassigned entries
+    (-1 -> block 0) only cover positions :func:`context_mask` excludes."""
+    S, max_blocks = block_tables.shape
+    bt = jnp.maximum(block_tables, 0)
+    with jax.named_scope("kv_gather"):
+        return jax.tree_util.tree_map(
+            lambda p: p[layer, bt].reshape(
+                (S, max_blocks * p.shape[2]) + p.shape[3:]), pool)
+
+
+def context_mask(positions: jax.Array, ctx: int) -> jax.Array:
+    """[S, 1, C, ctx] bool: the query at ``positions[s, c]`` sees gathered
+    keys ``0 .. positions[s, c]`` (its own, written first, included)."""
+    return (jnp.arange(ctx)[None, None, :] <= positions[:, :, None])[:, None]
+
+
+def copy_blocks(cache: Any, src: jax.Array, dst: jax.Array) -> Any:
+    """Copy-on-write for the serving prefix cache (serve/engine.py
+    PrefixCache): clone whole blocks ``src[i] -> dst[i]`` in every layer of
+    every leaf, BEFORE the tick's writes.  Padding pairs route ``dst`` out
+    of range and are dropped, their ``src`` clamped.  A source recycled as a
+    destination in the same call still copies its old content."""
+    def cp(pool):
+        # a layer at a time, as the tick's own writes index the pool: one
+        # scatter across the layers relaid the [5,5120,16,576] pool whole
+        # every tick (PR 27); on [24,2560,16,8,128] the two forms differ by
+        # 0.06 ms of a 13.3 ms tick and nothing end to end (PERF.md §6, PR 29)
+        safe = jnp.clip(src, 0, pool.shape[1] - 1)
+        for i in range(pool.shape[0]):
+            pool = pool.at[i, dst].set(pool[i, safe], mode="drop")
+        return pool
+    return jax.tree_util.tree_map(cp, cache)
+
+
+def shardings(mesh, num_blocks: int, head_axis_size: Optional[int] = None):
+    """NamedSharding for a pool ``[L, blocks, bs, ...]`` along the training
+    mesh's own axes: a head axis (``[.., heads, head_dim]`` behind, of
+    ``head_axis_size``) over a model/tp axis that divides it, blocks over
+    the first remaining axis that divides them.  A pool without a head axis
+    (the latent is every head's) shards its blocks alone."""
+    head_axis = None
+    if head_axis_size is not None:
+        head_axis = next(
+            (a for a in mesh.axis_names
+             if str(a).split(".")[-1] in ("model", "tp")
+             and head_axis_size % mesh.shape[a] == 0), None)
+    block_axis = next(
+        (a for a in mesh.axis_names
+         if a != head_axis and num_blocks % mesh.shape[a] == 0), None)
+    if head_axis_size is None:
+        return NamedSharding(mesh, P(None, block_axis, None, None))
+    return NamedSharding(mesh, P(None, block_axis, None, head_axis, None))
+
+
+def _leaf_key(path) -> str:
+    return "/".join(str(getattr(p, "key", p)) for p in path)
+
+
+def read_block(cache: Any, block: int) -> Dict[str, np.ndarray]:
+    """One block across all layers as host numpy, keyed by leaf path (spill
+    and the prefill hand-off's export): ``[L, bs, ...]`` a leaf."""
+    leaves, _ = jax.tree_util.tree_flatten_with_path(cache)
+    return {_leaf_key(path): np.asarray(leaf[:, block])
+            for path, leaf in leaves}
+
+
+def write_block(cache: Any, block: int, payload: Dict[str, Any]) -> Any:
+    """The cache with :func:`read_block`'s payload written into ``block``
+    (spill reload / hand-off import)."""
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(cache)
+    return jax.tree_util.tree_unflatten(treedef, [
+        leaf.at[:, block].set(
+            np.asarray(payload[_leaf_key(path)]).astype(leaf.dtype))
+        for path, leaf in leaves])

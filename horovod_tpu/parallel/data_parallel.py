@@ -19,6 +19,7 @@ the fusion-bucket plan, numerics unchanged.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Optional, Sequence, Tuple, Union
 
 import jax
@@ -68,6 +69,42 @@ def _resolve_donate(donate: Optional[bool]) -> bool:
     return bool(current("HOROVOD_TPU_DONATE_BUFFERS"))
 
 
+def _step_body(loss_fn: Callable, optimizer: optax.GradientTransformation,
+               mesh: Mesh, axis_name: AxisName, donate: Optional[bool], *,
+               has_aux: bool = False, remat: bool = False,
+               compute_dtype=None, **sync) -> Tuple:
+    """What every step builder shares: ``(body, axes, donate)`` where
+    ``body(params, opt_state, *batch) -> (params, opt_state, loss[, aux])``
+    is ONE optimizer step on this chip's batch shard (forward/backward, the
+    fused gradient sync inside ``dist_opt.update`` — ``sync`` is
+    distributed_optimizer's keywords —, the update, the loss's mean over the
+    data axis), ``axes`` the mesh axes of the batch, ``donate`` resolved."""
+    axis_name = resolve_axis(axis_name, mesh)
+    dist_opt = distributed_optimizer(optimizer, axis_name=axis_name, **sync)
+    fn = _compute_cast(loss_fn, compute_dtype)
+    fn = jax.checkpoint(fn) if remat else fn
+
+    def body(params, opt_state, *batch):
+        out, grads = jax.value_and_grad(fn, has_aux=has_aux)(params, *batch)
+        loss, aux = (out[0], (out[1],)) if has_aux else (out, ())
+        with jax.named_scope("optimizer"):
+            updates, opt_state = dist_opt.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
+        return (params, opt_state, jax.lax.pmean(loss, axis_name)) + aux
+
+    axes = axis_name if isinstance(axis_name, tuple) else (axis_name,)
+    return body, axes, _resolve_donate(donate)
+
+
+def _spmd_jit(body: Callable, mesh: Mesh, batch_specs: Tuple, n_out: int,
+              donate: bool) -> Callable:
+    """``body`` as one jitted ``shard_map``: params, optimizer state and
+    outputs replicated, the batch split as ``batch_specs`` says."""
+    f = shard_map(body, mesh=mesh, in_specs=(P(), P()) + batch_specs,
+                  out_specs=(P(),) * n_out, check_vma=False)
+    return jax.jit(f, donate_argnums=(0, 1) if donate else ())
+
+
 def make_train_step(loss_fn: Callable,
                     optimizer: optax.GradientTransformation,
                     mesh: Mesh,
@@ -107,49 +144,20 @@ def make_train_step(loss_fn: Callable,
     next microbatch's compute, drive the k calls inside ONE program:
     :func:`make_microbatched_train_step`).
     """
-    axis_name = resolve_axis(axis_name, mesh)
-    donate = _resolve_donate(donate)
-    dist_opt = distributed_optimizer(
-        optimizer, axis_name=axis_name, op=op, compression=compression,
+    body, axes, donate = _step_body(
+        loss_fn, optimizer, mesh, axis_name, donate, has_aux=has_aux,
+        compute_dtype=compute_dtype, op=op, compression=compression,
         backward_passes_per_step=backward_passes_per_step,
         fusion_threshold_bytes=fusion_threshold_bytes,
         wire_policy=wire_policy, error_feedback=error_feedback,
         overlap=overlap, overlap_depth=overlap_depth)
 
-    axes = axis_name if isinstance(axis_name, tuple) else (axis_name,)
-    loss_fn = _compute_cast(loss_fn, compute_dtype)
-
-    def body(params, opt_state, *batch):
-        if has_aux:
-            (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                params, *batch)
-        else:
-            loss, grads = jax.value_and_grad(loss_fn)(params, *batch)
-        with jax.named_scope("optimizer"):
-            updates, opt_state = dist_opt.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
-        loss = jax.lax.pmean(loss, axis_name)
-        if has_aux:
-            return params, opt_state, loss, aux
-        return params, opt_state, loss
-
-    batch_spec = P(axes)
-
+    @functools.lru_cache(maxsize=None)
     def build(nbatch: int):
-        in_specs = (P(), P()) + (batch_spec,) * nbatch
-        out_specs = (P(), P(), P()) + ((P(),) if has_aux else ())
-        f = shard_map(body, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_vma=False)
-        donate_argnums = (0, 1) if donate else ()
-        return jax.jit(f, donate_argnums=donate_argnums)
-
-    cache = {}
+        return _spmd_jit(body, mesh, (P(axes),) * nbatch, 3 + has_aux, donate)
 
     def step(params, opt_state, *batch):
-        f = cache.get(len(batch))
-        if f is None:
-            f = cache[len(batch)] = build(len(batch))
-        out = f(params, opt_state, *batch)
+        out = build(len(batch))(params, opt_state, *batch)
         # Framework-level timeline mark for the compiled step (the in-jit
         # collectives are XLA-fused; per-op detail lives in xprof).
         from .. import runtime as _rt
@@ -193,42 +201,29 @@ def make_microbatched_train_step(loss_fn: Callable,
     accumulate-k-then-sync step, scanned.  ``opt_state`` comes from this
     wrapper's own ``init`` (the k > 1 contract of distributed_optimizer).
     """
-    axis_name = resolve_axis(axis_name, mesh)
-    donate = _resolve_donate(donate)
     k = backward_passes_per_step
     if k < 1:
         raise ValueError("backward_passes_per_step must be >= 1")
-    dist_opt = distributed_optimizer(
-        optimizer, axis_name=axis_name, op=op,
-        backward_passes_per_step=k,
+    one, axes, donate = _step_body(
+        loss_fn, optimizer, mesh, axis_name, donate, remat=remat,
+        compute_dtype=compute_dtype, op=op, backward_passes_per_step=k,
         fusion_threshold_bytes=fusion_threshold_bytes,
         wire_policy=wire_policy, error_feedback=error_feedback,
         overlap=overlap, overlap_depth=overlap_depth)
-    axes = axis_name if isinstance(axis_name, tuple) else (axis_name,)
-    fn = _compute_cast(loss_fn, compute_dtype)
-    fn = jax.checkpoint(fn) if remat else fn
 
     def body(params, opt_state, batch):
-        def one(carry, mb):
-            params, opt_state = carry
-            loss, grads = jax.value_and_grad(fn)(params, mb)
-            # non-final microbatches return zero updates: applying them
-            # keeps the carry structure uniform and costs one no-op add
-            with jax.named_scope("optimizer"):
-                updates, opt_state = dist_opt.update(grads, opt_state,
-                                                     params)
-                params = optax.apply_updates(params, updates)
-            return (params, opt_state), jax.lax.pmean(loss, axis_name)
+        # non-final microbatches return zero updates: applying them keeps
+        # the carry structure uniform and costs one no-op add
+        def scanned(carry, mb):
+            params, opt_state, loss = one(*carry, mb)
+            return (params, opt_state), loss
 
         (params, opt_state), losses = jax.lax.scan(
-            one, (params, opt_state), batch)
+            scanned, (params, opt_state), batch)
         return params, opt_state, jnp.mean(losses)
 
     # batch: (k, global_batch, ...) — shard the batch dim (axis 1).
-    f = shard_map(body, mesh=mesh,
-                  in_specs=(P(), P(), P(None, axes)),
-                  out_specs=(P(), P(), P()), check_vma=False)
-    return jax.jit(f, donate_argnums=(0, 1) if donate else ())
+    return _spmd_jit(body, mesh, (P(None, axes),), 3, donate)
 
 
 def make_scanned_train_step(loss_fn: Callable,
@@ -265,38 +260,23 @@ def make_scanned_train_step(loss_fn: Callable,
     iterations remove per-step loop overhead and let XLA overlap across
     step boundaries, at the cost of a proportionally bigger program.
     """
-    axis_name = resolve_axis(axis_name, mesh)
-    donate = _resolve_donate(donate)
-    dist_opt = distributed_optimizer(
-        optimizer, axis_name=axis_name, op=op, compression=compression,
+    one, axes, donate = _step_body(
+        loss_fn, optimizer, mesh, axis_name, donate, remat=remat,
+        compute_dtype=compute_dtype, op=op, compression=compression,
         fusion_threshold_bytes=fusion_threshold_bytes,
         wire_policy=wire_policy, error_feedback=error_feedback)
-    axes = axis_name if isinstance(axis_name, tuple) else (axis_name,)
-
-    fn = _compute_cast(loss_fn, compute_dtype)
-    fn = fn if not remat else jax.checkpoint(fn)
 
     def body(params, opt_state, batches):
-        def one(carry, batch):
-            params, opt_state = carry
-            loss, grads = jax.value_and_grad(fn)(params, batch)
-            with jax.named_scope("optimizer"):
-                updates, opt_state = dist_opt.update(grads, opt_state,
-                                                     params)
-                params = optax.apply_updates(params, updates)
-            return (params, opt_state), jax.lax.pmean(loss, axis_name)
+        def scanned(carry, batch):
+            params, opt_state, loss = one(*carry, batch)
+            return (params, opt_state), loss
 
         (params, opt_state), losses = jax.lax.scan(
-            one, (params, opt_state), batches, unroll=unroll)
+            scanned, (params, opt_state), batches, unroll=unroll)
         return params, opt_state, losses
 
     # batches: (K, batch, ...) — shard the *batch* dim (axis 1) per chip.
-    in_specs = (P(), P(), P(None, axes))
-    out_specs = (P(), P(), P())
-    f = shard_map(body, mesh=mesh, in_specs=in_specs,
-                  out_specs=out_specs, check_vma=False)
-    donate_argnums = (0, 1) if donate else ()
-    return jax.jit(f, donate_argnums=donate_argnums)
+    return _spmd_jit(body, mesh, (P(None, axes),), 3, donate)
 
 
 def shard_batch(batch: Any, mesh: Mesh,

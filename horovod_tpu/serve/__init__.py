@@ -29,7 +29,6 @@ _LAZY = {
     "Scheduler": ("engine", "Scheduler"),
     "BlockAllocator": ("engine", "BlockAllocator"),
     "Request": ("engine", "Request"),
-    "cache_shardings": ("engine", "cache_shardings"),
     "save_servable": ("engine", "save_servable"),
     "load_servable": ("engine", "load_servable"),
     "FleetFrontend": ("worker", "FleetFrontend"),

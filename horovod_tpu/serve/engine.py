@@ -48,6 +48,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..models import paged
 from .config import ServeConfig
 
 
@@ -737,26 +738,6 @@ class Scheduler:
 
 
 # ------------------------------------------------------------ shardings
-def cache_shardings(mesh, num_blocks: int, n_kv_heads: int):
-    """NamedSharding for the paged pool [L, blocks, bs, kv_heads, hd]:
-    kv heads over a model/tp axis when one exists and divides, blocks
-    over the first remaining (data) axis that divides — the cache rides
-    the training mesh's existing axes (docs/serving.md)."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    head_axis = None
-    for a in mesh.axis_names:
-        if str(a).split(".")[-1] in ("model", "tp") and \
-                n_kv_heads % mesh.shape[a] == 0:
-            head_axis = a
-            break
-    block_axis = None
-    for a in mesh.axis_names:
-        if a != head_axis and num_blocks % mesh.shape[a] == 0:
-            block_axis = a
-            break
-    return NamedSharding(mesh, P(None, block_axis, None, head_axis, None))
-
-
 def _make_global(arr: np.ndarray, sharding):
     """Host array -> global jax.Array under ``sharding``.  Works in
     multi-controller runs (every process holds the full host value and
@@ -846,11 +827,12 @@ class ServeEngine:
     ``prefill_chunk`` positions a slot.  ``stats()["loop"]`` counts the
     narrow ticks and their wait on the device.
 
-    ``model`` is a model module exposing ``init_cache`` / ``apply_cached``
-    (models/llama.py, models/moe_llama.py, models/latent_moe.py, which
-    also says how its pool is sharded and what its tick counts);
-    ``model_cfg`` its config
-    dataclass; ``params`` the trained pytree (host or global arrays).
+    ``model`` is a model module that defines ``init_cache``,
+    ``copy_blocks``, ``apply_cached``, ``cache_shardings`` and
+    ``TICK_COUNTERS`` (models/llama.py, models/moe_llama.py,
+    models/latent_moe.py; docs/serving.md#what-a-served-model-module-exports);
+    ``model_cfg`` its config dataclass; ``params`` the trained pytree
+    (host or global arrays).
     """
 
     def __init__(self, model, model_cfg, params, cfg: ServeConfig,
@@ -860,8 +842,8 @@ class ServeEngine:
 
         cfg.validate(model_max_seq=model_cfg.max_seq)
         # The tick's token budget is the scheduler's: a model that packs a
-        # tick's valid tokens into that many rows (models/latent_moe.py) is
-        # told it here, whoever built its config.
+        # tick's valid tokens into that many rows (latent_moe.py) is told it
+        # here.  The one probe left; it goes with ROADMAP S4 (c).
         if hasattr(model_cfg, "max_tick_tokens"):
             model_cfg = dataclasses.replace(
                 model_cfg, max_tick_tokens=cfg.max_batch_tokens)
@@ -874,13 +856,8 @@ class ServeEngine:
         self.mesh = mesh
         self.scheduler = Scheduler(cfg, role=role)
         self._repl = NamedSharding(mesh, P())
-        # The pool's sharding is the model module's to say where its pool
-        # is not per-head K/V (models/latent_moe.py); else the five-axis one.
-        if hasattr(model, "cache_shardings"):
-            self._cache_shd = model.cache_shardings(mesh, cfg.cache_blocks)
-        else:
-            self._cache_shd = cache_shardings(mesh, cfg.cache_blocks,
-                                              model_cfg.n_kv_heads)
+        self._cache_shd = model.cache_shardings(mesh, model_cfg,
+                                                cfg.cache_blocks)
         leaves = jax.tree_util.tree_leaves(params)
         if leaves and isinstance(leaves[0], jax.Array):
             self.params = params
@@ -907,10 +884,10 @@ class ServeEngine:
         # phases on the same clock.
         from ..utils.profiler import PhaseClock
         self.clock = PhaseClock()
-        # What the model's tick counts beside its logits (a module with
-        # TICK_COUNTERS returns one small vector a tick: the expert layers'
-        # assignments, models/latent_moe.py), summed at every harvest.
-        self._counter_names = tuple(getattr(model, "TICK_COUNTERS", ()))
+        # What the tick counts beside its logits: one small vector a tick
+        # (the expert layers' assignments, models/latent_moe.py), summed at
+        # every harvest.
+        self._counter_names = tuple(model.TICK_COUNTERS)
         self._counters = np.zeros(len(self._counter_names), np.int64)
         self._step_fn = self._build_step()
         # The step's executable at each tick width, both compiled at the
@@ -968,7 +945,7 @@ class ServeEngine:
             with jax.named_scope("tick/model"):
                 out = model.apply_cached(params, tokens, mcfg, cache,
                                          block_tables, lengths, n_new)
-            logits, cache = out[0], out[1]  # moe also returns aux
+            logits, cache = out[:2]
             counters = out[2] if self._counter_names else None
             # Greedy sampling ON DEVICE at EVERY chunk position: row
             # [s, j] is the greedy continuation after consuming tokens
@@ -1026,30 +1003,12 @@ class ServeEngine:
 
     # ---------------------------------------------------- block transfer
     def _read_block(self, block: int) -> Dict[str, Any]:
-        """One pool block across all layers as host numpy (the spill
-        tier's read side and the prefill handoff's export side).  D2H
-        copy of [L, bs, kv_heads, hd] per cache leaf — one block, not
-        the pool."""
-        import jax
-        flat = {}
-        leaves, treedef = jax.tree_util.tree_flatten_with_path(self.cache)
-        for path, leaf in leaves:
-            key = "/".join(str(getattr(p, "key", p)) for p in path)
-            flat[key] = np.asarray(leaf[:, block])
-        return flat
+        """One pool block as host numpy (spill, the hand-off's export)."""
+        return paged.read_block(self.cache, block)
 
     def _write_block(self, block: int, payload: Dict[str, Any]) -> None:
-        """Write one block's host payload back into the device pool
-        (spill reload / handoff import).  Functional ``.at[].set`` per
-        leaf — runs between steps, so the next dispatch reads it."""
-        import jax
-        leaves, treedef = jax.tree_util.tree_flatten_with_path(self.cache)
-        new = []
-        for path, leaf in leaves:
-            key = "/".join(str(getattr(p, "key", p)) for p in path)
-            arr = np.asarray(payload[key]).astype(leaf.dtype)
-            new.append(leaf.at[:, block].set(arr))
-        self.cache = jax.tree_util.tree_unflatten(treedef, new)
+        """Runs between steps, so the next dispatch reads it."""
+        self.cache = paged.write_block(self.cache, block, payload)
 
     # ------------------------------------------------------ disaggregation
     def export_handoff(self, req: Request, first_token: int

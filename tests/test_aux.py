@@ -96,6 +96,63 @@ def test_timeline_marks_spmd_step(tmp_path, hvd):
     assert len(steps) == 3, len(steps)
 
 
+@pytest.mark.parametrize("compute_dtype", [None, "bfloat16"])
+@pytest.mark.parametrize("remat", [False, True])
+def test_the_three_step_builders_are_one_body(hvd, compute_dtype, remat):
+    """K steps of make_train_step in a Python loop, ONE call of
+    make_scanned_train_step on the stacked batches and K calls of
+    make_microbatched_train_step at one microbatch a step: the same params,
+    optimizer state and losses, bit for bit."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+    from horovod_tpu.parallel.data_parallel import (
+        make_microbatched_train_step, make_scanned_train_step,
+        make_train_step, replicate, shard_batch)
+    mesh, K = hvd.mesh(), 3
+    dtype = compute_dtype and jnp.dtype(compute_dtype)
+
+    def loss_fn(p, b):
+        h = jnp.tanh(b[:, :-1] @ p["w1"] + p["b1"])
+        return jnp.mean(((h @ p["w2"])[:, 0] - b[:, -1]) ** 2)
+
+    k1, k2, kb = jax.random.split(jax.random.PRNGKey(7), 3)
+    params = {"w1": 0.3 * jax.random.normal(k1, (6, 16)),
+              "b1": jnp.zeros((16,)),
+              "w2": 0.3 * jax.random.normal(k2, (16, 1))}
+    batches = jax.random.normal(kb, (K, 16, 7))
+    opt = optax.adamw(1e-2)
+    start = lambda: (replicate(params, mesh), replicate(opt.init(params), mesh))
+
+    # make_train_step has no remat of its own: the caller wraps the loss
+    step = make_train_step(jax.checkpoint(loss_fn) if remat else loss_fn,
+                           opt, mesh, compute_dtype=dtype, donate=False)
+    p, s = start()
+    looped = []
+    for i in range(K):
+        p, s, loss = step(p, s, shard_batch(batches[i], mesh))
+        looped.append(loss)
+    want = jax.device_get((p, s, jnp.stack(looped)))
+
+    run = make_scanned_train_step(loss_fn, opt, mesh, remat=remat,
+                                  compute_dtype=dtype, donate=False)
+    scanned = jax.device_get(run(*start(), shard_batch(batches, mesh, axis=1)))
+
+    micro = make_microbatched_train_step(loss_fn, opt, mesh, 1, remat=remat,
+                                         compute_dtype=dtype, donate=False)
+    p, s = start()
+    stepped = []
+    for i in range(K):
+        p, s, loss = micro(p, s, shard_batch(batches[i:i + 1], mesh, axis=1))
+        stepped.append(loss)
+    stepped = jax.device_get((p, s, jnp.stack(stepped)))
+
+    for name, got in (("scanned", scanned), ("microbatched", stepped)):
+        for a, b in zip(jax.tree_util.tree_leaves(want),
+                        jax.tree_util.tree_leaves(tuple(got))):
+            assert a.dtype == b.dtype and np.array_equal(a, b), name
+
+
 def test_stall_inspector_warns_and_aborts():
     si = StallInspector(warn_seconds=0, shutdown_seconds=0, hard_exit=False)
     si.record_submit("g1")

@@ -18,8 +18,7 @@ import pytest
 from horovod_tpu.serve.config import (ServeConfig, from_knobs,
                                       validate_serve_knobs)
 from horovod_tpu.serve.engine import (BlockAllocator, Request, Scheduler,
-                                      ServeEngine, cache_shardings,
-                                      decode_width, tick_width)
+                                      ServeEngine, decode_width, tick_width)
 from horovod_tpu.utils.profiler import compile_counts
 
 
@@ -570,6 +569,12 @@ def test_cache_shardings_ride_existing_axes():
     """The paged pool shards along the training mesh's own axes: kv
     heads over a model/tp axis when it divides, blocks over a data
     axis; a 1-D mesh puts blocks on it and replicates heads."""
+    from horovod_tpu.models import llama
+
+    def cache_shardings(mesh, num_blocks, n_kv_heads):
+        # the module contract ServeEngine calls (docs/serving.md)
+        return llama.cache_shardings(
+            mesh, llama.LlamaConfig(n_kv_heads=n_kv_heads), num_blocks)
     devs = np.array(jax.devices()[:8])
     mesh2 = jax.sharding.Mesh(devs.reshape(4, 2), ("data", "model"))
     spec = cache_shardings(mesh2, num_blocks=64, n_kv_heads=4).spec

@@ -7,6 +7,7 @@ unique across tests/ and tests/integration/ (pytest basename-collision
 gotcha)."""
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -216,12 +217,20 @@ def test_engine_all_legs_on_matches_reference_greedy(llama_tiny):
     outputs byte-identical to the reference, and every leg verifiably
     FIRED (hits, chunks, accepted drafts)."""
     model, cfg, params = llama_tiny
+    # A zeroed lm_head: every logit is 0, the argmax of a tie is token 0 on
+    # any platform, so from the third output on the drafter sees the run
+    # (0, 0) and proposes it, and greedy must accept.  Whether a random
+    # checkpoint's own trajectory repeats a bigram within 10 tokens depends
+    # on the platform's rounding (on some CPUs it never drafts); the eight
+    # cases of the determinism matrix below hold the equality on
+    # trained-shaped weights.
+    params = dict(params, lm_head=jax.tree_util.tree_map(
+        jnp.zeros_like, params["lm_head"]))
     prompts = _speed_prompts(cfg.vocab)
     scfg = _cfg(max_slots=2, cache_blocks=32, max_batch_tokens=12,
                 prefill_chunk=6, spec_k=4)
-    # 10 tokens: this checkpoint's greedy trajectory for prompt 1 enters
-    # a constant run by then, so prompt-lookup drafts AND gets accepted.
     engine, outs = _run_engine(model, cfg, params, scfg, prompts, 10)
+    assert outs[0] == [0] * 10
     for i, (p, out) in enumerate(zip(prompts, outs)):
         assert out == _reference_greedy(model, cfg, params, p, 10), i
     stats = engine.stats()
@@ -231,6 +240,25 @@ def test_engine_all_legs_on_matches_reference_greedy(llama_tiny):
     assert stats["spec"]["drafted_tokens"] >= 1
     assert engine._spec_accepted >= 1  # n-gram tails actually accepted
     assert stats["spec"]["accept_rate"] is not None
+    # The tokens above are 0 whatever the pool holds; the pool is not.  One
+    # request alone, so both allocators hand out the same blocks: the cells
+    # the [slots, 1 + spec_k] verify rows wrote (several valid columns a row
+    # through paged.write_index) are the ones single-token decode writes, and
+    # hold the same K/V — which past layer 0 depend on the gathered context.
+    pools = []
+    for spec in (True, False):
+        one = _cfg(max_slots=2, cache_blocks=32, max_batch_tokens=12,
+                   prefill_chunk=6, spec_k=4, spec_decode=spec)
+        e, _ = _run_engine(model, cfg, params, one, prompts[:1], 10)
+        assert (e._spec_accepted >= 1) == spec
+        pools.append(jax.device_get(e.cache))
+    for name, with_spec in pools[0].items():
+        written = np.abs(pools[1][name]).sum(axis=(0, 3, 4)) > 0
+        assert written.sum() == len(prompts[0]) + 10 - 1   # last token unfed
+        assert np.array_equal(np.abs(with_spec).sum(axis=(0, 3, 4)) > 0,
+                              written), name
+        np.testing.assert_allclose(with_spec, pools[1][name],
+                                   rtol=1e-5, atol=1e-6, err_msg=name)
 
 
 @pytest.mark.parametrize("prefix", [False, True])
