@@ -252,10 +252,12 @@ def cache_shardings(mesh, cfg: LatentMoeConfig, num_blocks: int):
 copy_blocks = paged.copy_blocks
 
 
-def _slots_per_block(S: int, per_slot_bytes: int, budget: int) -> int:
-    """The largest divisor of S whose scores fit the budget (at least 1)."""
-    want = max(1, budget // max(per_slot_bytes, 1))
-    return max(b for b in range(1, S + 1) if S % b == 0 and b <= want)
+def attn_blocks(cfg: LatentMoeConfig, S: int, C: int, ctx: int
+                ) -> Tuple[int, int]:
+    """(slots a block, narrow columns) of :func:`_latent_attention` in a
+    ``[S, C]`` tick over ``ctx`` positions, for the engine's counters."""
+    return (paged.slots_per_block(S, cfg.n_heads * C * ctx * 4, SCORE_BYTES),
+            NARROW_COLS)
 
 
 def _latent_attention(q: jax.Array, lat: jax.Array, positions: jax.Array,
@@ -291,7 +293,7 @@ def _latent_attention(q: jax.Array, lat: jax.Array, positions: jax.Array,
         # of decode rows in a prefill-width tick attends in W columns
         return lax.cond(jnp.max(nn) > W, lambda: attend(q, lat, pos), narrow)
 
-    sb = _slots_per_block(S, H * C * ctx * 4, SCORE_BYTES)
+    sb = attn_blocks(cfg, S, C, ctx)[0]
     if sb == S:
         return block((q, lat, positions, n_new))
     split = lambda a: a.reshape((S // sb, sb) + a.shape[1:])
@@ -382,4 +384,4 @@ def param_count(cfg: LatentMoeConfig) -> int:
 
 __all__ = ["LatentMoeConfig", "CONFIGS", "TICK_COUNTERS", "init", "apply",
            "init_cache", "cache_shardings", "copy_blocks", "apply_cached",
-           "param_count"]
+           "attn_blocks", "param_count"]

@@ -20,7 +20,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -48,6 +48,11 @@ class LlamaConfig:
     # output dims and the concat would cross that sharding.  Ignored (falls
     # back to separate matmuls) when a projection carries a bias term.
     fuse_proj: bool = False
+    # Serving only: the most tokens one tick's plan holds.  ServeEngine
+    # writes the scheduler's ``max_batch_tokens`` here, and apply_cached then
+    # runs what is a token's own on that many packed rows where the tick's
+    # slab has more positions (paged.pack).  0 = no promise, nothing packed.
+    max_tick_tokens: int = 0
 
     @property
     def head_dim(self) -> int:
@@ -260,40 +265,84 @@ def cache_shardings(mesh, cfg: LlamaConfig, num_blocks: int):
 
 copy_blocks = paged.copy_blocks
 TICK_COUNTERS = ()      # the tick counts nothing beside its logits
+#: float32 scores one block of slots may hold (heads x columns x context x 4
+#: B a slot): internlm2-1.8b's [16, 128] tick over 2,048 positions attends
+#: two slots at a time, its [16, 5] tick all sixteen at once
+SCORE_BYTES = 32 << 20
+#: columns a block of decode rows attends with in a chunk-wide tick
+NARROW_COLS = 8
+
+
+def attn_blocks(cfg: LlamaConfig, S: int, C: int, ctx: int
+                ) -> Tuple[int, int]:
+    """(slots a block, narrow columns) of the cached attention in a
+    ``[S, C]`` tick over ``ctx`` gathered positions (paged.attend_by_blocks;
+    the engine's ``wide_blocks_share`` reads a plan by the same two)."""
+    return (paged.slots_per_block(S, cfg.n_heads * C * ctx * 4, SCORE_BYTES),
+            NARROW_COLS)
+
+
+class _Tick(NamedTuple):
+    """What the layers of one tick share (:func:`_tick`)."""
+    positions: jax.Array    # [S, C] (paged.slot_positions)
+    n_new: jax.Array        # [S]
+    take: Callable          # [S, C, ...] -> rows [1, R, ...] (paged.pack)
+    slab: Callable          # rows -> [S, C, ...], zero where left out
+    pos: jax.Array          # the rows' positions, inside the rope table
+    blk: jax.Array          # where the rows land (paged.write_index)
+    off: jax.Array
+
+
+def _tick(cfg: LlamaConfig, cache: Dict[str, jax.Array],
+          block_tables: jax.Array, lengths: jax.Array, n_new: jax.Array,
+          C: int) -> _Tick:
+    """One tick's slot arithmetic, done once for all layers: where each
+    position lies and lands, and the rows the tick's tokens are packed to."""
+    positions, valid = paged.slot_positions(lengths, n_new, C)
+    blk, off = paged.write_index(block_tables, positions, valid,
+                                 *cache["k"].shape[1:3])
+    take, slab = paged.pack(valid, cfg.max_tick_tokens)
+    return _Tick(positions, n_new, take, slab,
+                 take(jnp.minimum(positions, cfg.max_seq - 1)),
+                 take(blk), take(off))
 
 
 def _attn_cached(p: Dict[str, Any], x: jax.Array, cfg: LlamaConfig,
                  cos: jax.Array, sin: jax.Array, cache: Dict[str, jax.Array],
-                 layer: int, block_tables: jax.Array, positions: jax.Array,
-                 valid: jax.Array) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+                 layer: int, block_tables: jax.Array, t: _Tick
+                 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Layer ``layer``'s attention over the paged cache, in place: ``cache``
     is the STACKED pools (init_cache's five axes) and comes back with this
     layer's new positions scattered in — no layer's pool is cut out of the
     stack or put back, so a donated cache stays one buffer through the tick.
 
-    x: [S, C, dim] — S serving slots each contributing a chunk of C new
-    token positions (prefill consumes whole chunks; decode uses C with
-    one valid token).  The chunk's k/v are scattered into the pool
-    FIRST, then each query attends over its slot's full gathered context
-    with a per-position causal mask — so a single compiled step serves
-    mixed prefill/decode ticks.  Projections always take the unfused
-    path (fuse_proj is a training-throughput lever; TP shards the
-    separate kernels)."""
-    S, C, _ = x.shape
-    q = L.dense(p["wq"], x).reshape(S, C, cfg.n_heads, cfg.head_dim)
-    k = L.dense(p["wk"], x).reshape(S, C, cfg.n_kv_heads, cfg.head_dim)
-    v = L.dense(p["wv"], x).reshape(S, C, cfg.n_kv_heads, cfg.head_dim)
-    pos_c = jnp.minimum(positions, cfg.max_seq - 1)
-    q = L.apply_rope_at(q, cos, sin, pos_c)
-    k = L.apply_rope_at(k, cos, sin, pos_c)
-    blk, off = paged.write_index(block_tables, positions, valid,
-                                 *cache["k"].shape[1:3])
-    cache = paged.write(cache, layer, blk, off, {"k": k, "v": v})
-    ctx = paged.gather(cache, layer, block_tables)
-    mask = paged.context_mask(positions, ctx["k"].shape[1])
-    o = L.causal_attention(q, ctx["k"], ctx["v"], causal=False, mask=mask)
-    return (L.dense(p["wo"], o.reshape(S, C, cfg.n_heads * cfg.head_dim)),
-            cache)
+    x: [1, R, dim] — the tick's rows (``t.take``; the slab [S, C, dim]
+    itself where nothing is packed: S serving slots each contributing a
+    chunk of C new token positions; prefill consumes whole chunks, decode
+    uses C with one valid token).  The rows' k/v are
+    scattered into the pool FIRST, then the queries go back to their slots
+    and each attends over its slot's full gathered context with a
+    per-position causal mask — so a single compiled step serves mixed
+    prefill/decode ticks, a block of slots after another and only the
+    blocks that hold a chunk at chunk width (paged.attend_by_blocks).
+    Projections always take the unfused path (fuse_proj is a
+    training-throughput lever; TP shards the separate kernels)."""
+    rows = x.shape[:2]
+    heads = lambda w, n: L.dense(p[w], x).reshape(rows + (n, cfg.head_dim))
+    q = L.apply_rope_at(heads("wq", cfg.n_heads), cos, sin, t.pos)
+    k = L.apply_rope_at(heads("wk", cfg.n_kv_heads), cos, sin, t.pos)
+    cache = paged.write(cache, layer, t.blk, t.off,
+                        {"k": k, "v": heads("wv", cfg.n_kv_heads)})
+    n_ctx = block_tables.shape[1] * cache["k"].shape[2]
+
+    def attend(q, pos, tables):
+        ctx = paged.gather(cache, layer, tables)
+        return L.causal_attention(q, ctx["k"], ctx["v"], causal=False,
+                                  mask=paged.context_mask(pos, n_ctx))
+    o = paged.attend_by_blocks(
+        attend, (t.slab(q), t.positions, block_tables), t.n_new,
+        *attn_blocks(cfg, *t.positions.shape, n_ctx))
+    return L.dense(p["wo"], t.take(o).reshape(rows + (-1,))), cache
 
 
 def apply_cached(params: Dict[str, Any], tokens: jax.Array,
@@ -307,25 +356,27 @@ def apply_cached(params: Dict[str, Any], tokens: jax.Array,
     inactive slot), starting at context length ``lengths[s]``;
     ``block_tables`` [S, max_blocks] int32 indexes the pool (-1 =
     unassigned).  Returns (logits [S, C, vocab], updated cache); the
-    caller samples from position ``n_new[s] - 1``.  Prefill a prompt in
-    ceil(len/C) calls, then decode one token per call — the serving
-    engine's one jit'd tick (horovod_tpu/serve/engine.py), which donates
-    ``cache``: the stacked pools go through the layers whole."""
+    caller samples from position ``n_new[s] - 1``: logits are defined at
+    VALID positions only (zero where ``cfg.max_tick_tokens`` left a
+    position out of the packed rows).  Prefill a prompt in ceil(len/C)
+    calls, then decode one token per call — the serving engine's one jit'd
+    tick (horovod_tpu/serve/engine.py), which donates ``cache``: the
+    stacked pools go through the layers whole."""
     cos, sin = L.rope_freqs(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
-    positions, valid = paged.slot_positions(lengths, n_new, tokens.shape[1])
+    t = _tick(cfg, cache, block_tables, lengths, n_new, tokens.shape[1])
     with jax.named_scope("embed"):
-        x = L.embedding(params["embed"], tokens).astype(cfg.dtype)
+        x = L.embedding(params["embed"], t.take(tokens)).astype(cfg.dtype)
     for i, p in enumerate(params["layers"]):
         with jax.named_scope("attn"):
             a, cache = _attn_cached(
                 p, L.rmsnorm(p["attn_norm"], x), cfg, cos, sin,
-                cache, i, block_tables, positions, valid)
+                cache, i, block_tables, t)
             x = x + a
         with jax.named_scope("ffn"):
             x = x + _ffn(p, L.rmsnorm(p["ffn_norm"], x), cfg)
     x = L.rmsnorm(params["final_norm"], x)
     with jax.named_scope("head"):
-        logits = L.dense(params["lm_head"], x)
+        logits = t.slab(L.dense(params["lm_head"], x))
     return logits, cache
 
 

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 
 from . import layers as L
 from . import llama as Ll
-from . import paged
 from ..parallel.expert import init_moe_params, moe_dense_reference
 
 
@@ -45,6 +44,7 @@ class MoeLlamaConfig:
     max_seq: int = 512
     rope_theta: float = 10000.0
     dtype: Any = jnp.float32
+    max_tick_tokens: int = 0    # llama.LlamaConfig's: the engine's budget
 
     @property
     def head_dim(self) -> int:
@@ -70,7 +70,8 @@ def _llama_cfg(cfg: MoeLlamaConfig) -> Ll.LlamaConfig:
                           n_layers=cfg.n_layers, n_heads=cfg.n_heads,
                           n_kv_heads=cfg.n_kv_heads, ffn_dim=1,
                           max_seq=cfg.max_seq, rope_theta=cfg.rope_theta,
-                          dtype=cfg.dtype)
+                          dtype=cfg.dtype,
+                          max_tick_tokens=cfg.max_tick_tokens)
 
 
 def init(key, cfg: MoeLlamaConfig) -> Dict[str, Any]:
@@ -173,32 +174,38 @@ cache_shardings = Ll.cache_shardings
 TICK_COUNTERS = ()
 
 
+def attn_blocks(cfg: MoeLlamaConfig, S: int, C: int, ctx: int):
+    """llama.attn_blocks: the attention half is llama's."""
+    return Ll.attn_blocks(_llama_cfg(cfg), S, C, ctx)
+
+
 def apply_cached(params: Dict[str, Any], tokens: jax.Array,
                  cfg: MoeLlamaConfig, cache: Dict[str, jax.Array],
                  block_tables: jax.Array, lengths: jax.Array,
                  n_new: jax.Array, moe_fn: Optional[Callable] = None
                  ) -> tuple[jax.Array, Dict[str, jax.Array], jax.Array]:
     """Mixed prefill/decode forward over the paged cache (the moe twin
-    of llama.apply_cached; same slot-table contract).  Returns (logits
-    [S, C, vocab], updated cache, mean router aux).  ``moe_fn`` defaults
-    to the drop-free dense path — the batch-invariant serving routing."""
+    of llama.apply_cached; same slot-table contract, the same packed rows).
+    Returns (logits [S, C, vocab], updated cache, mean router aux over the
+    rows).  ``moe_fn`` defaults to the drop-free dense path — the
+    batch-invariant serving routing."""
     lcfg = _llama_cfg(cfg)
     moe_fn = moe_fn if moe_fn is not None else dropfree_moe_fn(cfg)
     cos, sin = L.rope_freqs(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
-    positions, valid = paged.slot_positions(lengths, n_new, tokens.shape[1])
-    x = L.embedding(params["embed"], tokens).astype(cfg.dtype)
+    t = Ll._tick(lcfg, cache, block_tables, lengths, n_new, tokens.shape[1])
+    x = L.embedding(params["embed"], t.take(tokens)).astype(cfg.dtype)
     auxes = []
     for i, p in enumerate(params["layers"]):
         a, cache = Ll._attn_cached(
             p, L.rmsnorm(p["attn_norm"], x), lcfg, cos, sin,
-            cache, i, block_tables, positions, valid)
+            cache, i, block_tables, t)
         x = x + a
         y, aux = _moe_block(p["moe"], L.rmsnorm(p["ffn_norm"], x), cfg,
                             moe_fn)
         x = x + y
         auxes.append(aux)
     x = L.rmsnorm(params["final_norm"], x)
-    return (L.dense(params["lm_head"], x), cache,
+    return (t.slab(L.dense(params["lm_head"], x)), cache,
             jnp.mean(jnp.stack(auxes)))
 
 
@@ -214,4 +221,5 @@ def param_count(cfg: MoeLlamaConfig) -> int:
 
 __all__ = ["MoeLlamaConfig", "CONFIGS", "init", "apply", "loss_fn",
            "param_count", "init_cache", "apply_cached", "copy_blocks",
-           "cache_shardings", "TICK_COUNTERS", "dropfree_moe_fn"]
+           "cache_shardings", "TICK_COUNTERS", "attn_blocks",
+           "dropfree_moe_fn"]

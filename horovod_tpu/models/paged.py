@@ -13,11 +13,12 @@ cache stays one buffer through a tick (docs/serving.md).
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 
@@ -27,6 +28,33 @@ def slot_positions(lengths: jax.Array, n_new: jax.Array, C: int
     + j`` and holds a token when ``j < n_new[s]`` (0 = an inactive slot)."""
     positions = lengths[:, None] + jnp.arange(C, dtype=lengths.dtype)[None]
     return positions, jnp.arange(C)[None, :] < n_new[:, None]
+
+
+def pack(valid: jax.Array, budget: int) -> Tuple[Callable, Callable]:
+    """(take, slab) for a tick whose plan holds at most ``budget`` tokens
+    (the scheduler's ``max_batch_tokens``; 0 = no promise): what is a
+    token's own — embedding, norms, projections, rotary, the FFN, the head,
+    the pool's write — runs on ``R = min(S * C, budget)`` ROWS, the valid
+    positions packed to the front in slab order (a stable sort), and only
+    the attention's core sees slots.  ``take`` brings ``[S, C, ...]`` to
+    ``[1, R, ...]``; ``slab`` brings rows back to their places in
+    ``[S, C, ...]``, ZERO where a position was left out, so what comes back
+    is defined at valid positions only.  A slab no larger than the budget
+    is not packed: both are the identity and the rows are ``[S, C, ...]``."""
+    S, C = valid.shape
+    T = S * C
+    R = min(T, budget or T)
+    if R == T:
+        return (lambda a: a), (lambda a: a)
+    flat = lambda a: a.reshape((T,) + a.shape[2:])
+    order = jnp.argsort(~flat(valid), stable=True)     # positions, by row
+    row_of = jnp.argsort(order).reshape(S, C)           # rows, by position
+
+    def slab(a):
+        back = a[0][jnp.minimum(row_of, R - 1)]
+        kept = (row_of < R).reshape((S, C) + (1,) * (a.ndim - 2))
+        return jnp.where(kept, back, jnp.zeros((), a.dtype))
+    return (lambda a: flat(a)[order[:R]][None]), slab
 
 
 def write_index(block_tables: jax.Array, positions: jax.Array,
@@ -69,6 +97,50 @@ def context_mask(positions: jax.Array, ctx: int) -> jax.Array:
     """[S, 1, C, ctx] bool: the query at ``positions[s, c]`` sees gathered
     keys ``0 .. positions[s, c]`` (its own, written first, included)."""
     return (jnp.arange(ctx)[None, None, :] <= positions[:, :, None])[:, None]
+
+
+def slots_per_block(S: int, per_slot_bytes: int, budget: int) -> int:
+    """The largest divisor of S whose scores fit the budget (at least 1)."""
+    want = max(1, budget // max(per_slot_bytes, 1))
+    return max(b for b in range(1, S + 1) if S % b == 0 and b <= want)
+
+
+def attend_by_blocks(attend: Callable, args: Tuple[jax.Array, ...],
+                     n_new: jax.Array, slots: int, narrow: int) -> jax.Array:
+    """``attend(q, positions, *context)`` -> ``[S, C, ...]`` at the width each
+    block of ``slots`` slots needs; every array of ``args`` is led by the
+    slot axis, the first two by ``[S, C]``.  A tick wider than ``narrow``
+    columns attends every slot's first ``narrow`` columns at once, then a
+    block after another: one that holds a slot with more than ``narrow``
+    tokens attends again with all C columns, the others are done — the
+    columns past a slot's ``n_new`` are padding that nothing reads, and
+    come back zero.  Any plan is served: every block may hold a chunk.
+    ``attend`` may gather its slots' context from the pool itself (llama.py
+    does): the loop then holds the pool, which XLA neither copies nor
+    restacks for it (tests/test_serve.py; PERF.md §6, PR 30)."""
+    S, C = args[0].shape[:2]
+    if C <= narrow:
+        return attend(*args)
+    q, pos, *context = args
+    few = attend(q[:, :narrow], pos[:, :narrow], *context)
+    o = jnp.pad(few, ((0, 0), (0, C - narrow)) + ((0, 0),) * (few.ndim - 2))
+
+    def block(i, o):
+        cut = lambda a: lax.dynamic_slice_in_dim(a, i * slots, slots)
+        return lax.cond(
+            jnp.max(cut(n_new)) > narrow,
+            lambda o, *own: lax.dynamic_update_slice_in_dim(
+                o, attend(*own), i * slots, 0),
+            lambda o, *own: o, o, *map(cut, args))
+    return lax.fori_loop(0, S // slots, block, o)
+
+
+def wide_blocks(n_new: np.ndarray, slots: int, narrow: int) -> Tuple[int, int]:
+    """:func:`attend_by_blocks`'s choice read off a plan on the host: (blocks
+    that attend at the tick's full width, blocks) for ``n_new`` tokens a
+    slot."""
+    top = np.asarray(n_new).reshape(-1, slots).max(axis=1)
+    return int((top > narrow).sum()), int(top.size)
 
 
 def copy_blocks(cache: Any, src: jax.Array, dst: jax.Array) -> Any:

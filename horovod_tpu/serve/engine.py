@@ -825,11 +825,12 @@ class ServeEngine:
     prefill/decode step over the paged cache, compiled at two widths
     (``tick_width``): a tick without a prefill chunk does not pay for
     ``prefill_chunk`` positions a slot.  ``stats()["loop"]`` counts the
-    narrow ticks and their wait on the device.
+    narrow ticks and their wait on the device, and how much of a wide
+    tick's rows and attention blocks its plan filled.
 
     ``model`` is a model module that defines ``init_cache``,
-    ``copy_blocks``, ``apply_cached``, ``cache_shardings`` and
-    ``TICK_COUNTERS`` (models/llama.py, models/moe_llama.py,
+    ``copy_blocks``, ``apply_cached``, ``cache_shardings``, ``attn_blocks``
+    and ``TICK_COUNTERS`` (models/llama.py, models/moe_llama.py,
     models/latent_moe.py; docs/serving.md#what-a-served-model-module-exports);
     ``model_cfg`` its config dataclass; ``params`` the trained pytree
     (host or global arrays).
@@ -841,12 +842,10 @@ class ServeEngine:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         cfg.validate(model_max_seq=model_cfg.max_seq)
-        # The tick's token budget is the scheduler's: a model that packs a
-        # tick's valid tokens into that many rows (latent_moe.py) is told it
-        # here.  The one probe left; it goes with ROADMAP S4 (c).
-        if hasattr(model_cfg, "max_tick_tokens"):
-            model_cfg = dataclasses.replace(
-                model_cfg, max_tick_tokens=cfg.max_batch_tokens)
+        # The tick's token budget is the scheduler's: the model packs a
+        # tick's valid tokens into that many rows (models/paged.py pack).
+        model_cfg = dataclasses.replace(
+            model_cfg, max_tick_tokens=cfg.max_batch_tokens)
         self.model = model
         self.model_cfg = model_cfg
         self.cfg = cfg
@@ -897,6 +896,14 @@ class ServeEngine:
         # ``harvest_wait`` phase's seconds.
         self._narrow_ticks = 0
         self._narrow_wait_s = 0.0
+        # What the wide ticks' plans filled of what the model computed:
+        # [valid tokens, rows] and [attention blocks at chunk width, blocks]
+        # (``model.attn_blocks``: slots a block, a narrow block's columns).
+        self._wide_rows = np.zeros(2, np.int64)
+        self._wide_blocks = np.zeros(2, np.int64)
+        self._attn_blocks = model.attn_blocks(
+            model_cfg, cfg.max_slots, cfg.prefill_chunk,
+            cfg.max_blocks_per_seq * cfg.block_size)
         # One-deep tick pipeline (the loader.prefetch deque pattern):
         # holds (plan, device next-token array) until the next step()
         # harvests it, so host scheduling overlaps device compute.
@@ -1204,8 +1211,23 @@ class ServeEngine:
             self._narrow_ticks += 1
             self._narrow_wait_s += \
                 self.clock.phase_s["harvest_wait"] - waited
+        else:
+            self._count_wide(work, used)
         with self.clock.span("harvest_emit"):
             return self._emit(tick, work, tokens_host, used)
+
+    def _count_wide(self, work, used: int) -> None:
+        """One wide tick's plan against the program that ran it: the rows
+        the model computed (its slab's positions, or the token budget it
+        packs them into) and the blocks of slots that attended at chunk
+        width.  Host arithmetic on the plan; the device is not asked."""
+        cfg = self.cfg
+        n_new = np.zeros(cfg.max_slots, np.int64)
+        for slot, _, n in work:
+            n_new[slot] = n
+        self._wide_rows += (used, min(cfg.max_slots * cfg.prefill_chunk,
+                                      cfg.max_batch_tokens))
+        self._wide_blocks += paged.wide_blocks(n_new, *self._attn_blocks)
 
     def _emit(self, tick, work, tokens_host, used) -> Dict[str, Any]:
         """The host half of a harvest: advance every request of the tick
@@ -1435,7 +1457,10 @@ class ServeEngine:
                     if self._spec_drafted else None),
             },
         }
-        out["loop"] = dict(self._loop_snapshot(), ticks=self._ticks())
+        share = lambda c: round(int(c[0]) / int(c[1]), 4) if c[1] else None
+        out["loop"] = dict(self._loop_snapshot(), ticks=self._ticks(),
+                           wide_rows_share=share(self._wide_rows),
+                           wide_blocks_share=share(self._wide_blocks))
         if self._counter_names:
             out["moe"] = dict(zip(self._counter_names,
                                   map(int, self._counters)))

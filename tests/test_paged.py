@@ -175,7 +175,7 @@ def test_shardings_ride_the_meshs_own_axes():
 
 # ------------------------------------------------ the engine's contract
 CONTRACT = ("init_cache", "copy_blocks", "apply_cached", "cache_shardings",
-            "TICK_COUNTERS")
+            "attn_blocks", "TICK_COUNTERS")
 
 
 def _engine(model, cfg, params):
@@ -187,7 +187,8 @@ def _engine(model, cfg, params):
 
 @pytest.mark.parametrize("name", sorted(_MODEL_MODULES))
 def test_every_served_module_keeps_the_contract(name):
-    """The five names, and a request served through them with no probe of
+    """The six names, a config that carries the tick's token budget, and a
+    request served through them with no probe of
     the module: a stand-in that lacks one fails loudly."""
     model = importlib.import_module(_MODEL_MODULES[name])
     for attr in CONTRACT:
@@ -196,13 +197,15 @@ def test_every_served_module_keeps_the_contract(name):
     cfg = model.CONFIGS["tiny"]
     params = model.init(jax.random.PRNGKey(0), cfg)
     engine = _engine(model, cfg, params)
+    assert cfg.max_tick_tokens == 0
+    assert engine.model_cfg.max_tick_tokens == engine.cfg.max_batch_tokens
     req = engine.submit([3, 1, 4, 1, 5, 9, 2, 6, 5, 3], 4, req_id="r")
     engine.flush()
     assert len(req.out_tokens) == 4
     counted = engine.stats().get("moe", {})
     assert sorted(counted) == sorted(model.TICK_COUNTERS)
     engine.close()
-    for attr in ("TICK_COUNTERS", "cache_shardings"):
+    for attr in ("TICK_COUNTERS", "cache_shardings", "attn_blocks"):
         stub = types.SimpleNamespace(**{
             a: getattr(model, a) for a in CONTRACT if a != attr})
         with pytest.raises(AttributeError, match=attr):

@@ -3,6 +3,7 @@ admission/eviction discipline, paged-cache block reuse, the prefill+decode
 ≡ full-forward equivalence on both model families, router backpressure,
 and the HOROVOD_SERVE_* knob validation contract."""
 
+import dataclasses
 import json
 import re
 import threading
@@ -473,6 +474,155 @@ def test_tick_keeps_no_second_pool(family, width, llama_tiny, moe_tiny,
     step, _ = compiled()
     assert step.memory_analysis().temp_size_in_bytes < \
         pool.nbytes // pool.shape[0]
+
+
+# ------------------------------------------------- packed rows, slot blocks
+# One chunk-wide tick by hand, C = 12 columns over 32 positions a slot:
+# (n_new, lengths) a slot.  "mixed": a prefill chunk, decode rows, a short
+# tail and dead slots; "worst": every slot a tail longer than the narrow
+# columns, as many valid tokens as the budget has rows.
+TICKS = {"mixed": ([12, 1, 0, 3, 0, 1], [4, 9, 7, 12, 0, 5], 20),
+         "worst": ([9, 9, 9, 9, 9, 9], [0, 3, 8, 11, 16, 20], 54)}
+TICK_C, TICK_CTX = 12, 32
+
+
+def _tick_by_hand(model, cfg, params, plan):
+    """(logits, cache, valid) of one apply_cached call on ``plan``'s slots,
+    over a pool of noise, every slot owning its own eight blocks of four."""
+    n_new, lengths, _ = TICKS[plan]
+    S, rng = len(n_new), np.random.default_rng(11)
+    table = jnp.asarray(np.arange(S * 8, dtype=np.int32).reshape(S, 8))
+    cache = {k: jnp.asarray(rng.normal(size=v.shape), v.dtype)
+             for k, v in model.init_cache(cfg, S * 8, 4).items()}
+    tokens = jnp.asarray(rng.integers(0, cfg.vocab, (S, TICK_C)), jnp.int32)
+    out = jax.jit(lambda c: model.apply_cached(
+        params, tokens, cfg, c, table, jnp.asarray(lengths, jnp.int32),
+        jnp.asarray(n_new, jnp.int32)))(cache)
+    valid = np.arange(TICK_C)[None] < np.asarray(n_new)[:, None]
+    return np.asarray(out[0]), out[1], valid
+
+
+def _family(family, llama_tiny, moe_tiny):
+    return llama_tiny if family == "llama" else moe_tiny
+
+
+@pytest.mark.parametrize("plan", sorted(TICKS))
+@pytest.mark.parametrize("family", ["llama", "moe"])
+def test_packing_a_ticks_tokens_changes_no_logit_and_no_cached_value(
+        family, plan, llama_tiny, moe_tiny):
+    """``max_tick_tokens`` rows against the whole slab on one tick: the
+    logits at every valid position and every value of the pool are the
+    same, and a position that was not packed reads zero."""
+    model, cfg, params = _family(family, llama_tiny, moe_tiny)
+    budget = TICKS[plan][2]
+    want, want_cache, valid = _tick_by_hand(model, cfg, params, plan)
+    got, got_cache, _ = _tick_by_hand(
+        model, dataclasses.replace(cfg, max_tick_tokens=budget), params,
+        plan)
+    assert float(np.max(np.abs(got - want)[valid])) < 2e-5
+    for k in want_cache:
+        assert float(jnp.max(jnp.abs(got_cache[k] - want_cache[k]))) < 2e-5
+    assert np.asarray(got)[~valid].any(-1).sum() == budget - valid.sum()
+
+
+@pytest.mark.parametrize("family", ["llama", "moe"])
+def test_a_budget_no_smaller_than_the_slab_packs_nothing(
+        family, llama_tiny, moe_tiny):
+    """``R >= S * C`` is the identity: the program of a config whose budget
+    covers the slab is the program of one that never set the field."""
+    model, cfg, params = _family(family, llama_tiny, moe_tiny)
+    S = len(TICKS["mixed"][0])
+    args = (jnp.zeros((S, TICK_C), jnp.int32),
+            model.init_cache(cfg, S * 8, 4), jnp.zeros((S, 8), jnp.int32),
+            jnp.zeros(S, jnp.int32), jnp.ones(S, jnp.int32))
+
+    def lowered(cfg):
+        return jax.jit(lambda t, c, bt, l, n: model.apply_cached(
+            params, t, cfg, c, bt, l, n)).lower(*args).as_text()
+    assert lowered(dataclasses.replace(cfg, max_tick_tokens=S * TICK_C)) \
+        == lowered(cfg)
+    assert lowered(dataclasses.replace(cfg, max_tick_tokens=S * TICK_C - 1)) \
+        != lowered(cfg)
+
+
+@pytest.mark.parametrize("slots", [1, 2, 6])
+@pytest.mark.parametrize("family", ["llama", "moe"])
+def test_blocks_of_slots_attend_alike_whatever_their_size(
+        family, slots, llama_tiny, moe_tiny, monkeypatch):
+    """The attention a block of 1, 2 or all 6 slots after another, blocks of
+    decode rows in their first columns only, against every slot at chunk
+    width in one block (no narrow columns): the same logits at every valid
+    position, the same pool."""
+    from horovod_tpu.models import llama
+    model, cfg, params = _family(family, llama_tiny, moe_tiny)
+    cfg = dataclasses.replace(cfg, max_tick_tokens=TICKS["mixed"][2])
+    monkeypatch.setattr(llama, "NARROW_COLS", TICK_C)
+    want, want_cache, valid = _tick_by_hand(model, cfg, params, "mixed")
+    monkeypatch.setattr(llama, "NARROW_COLS", 4)
+    monkeypatch.setattr(llama, "SCORE_BYTES",
+                        slots * cfg.n_heads * TICK_C * TICK_CTX * 4)
+    assert model.attn_blocks(cfg, 6, TICK_C, TICK_CTX) == (slots, 4)
+    got, got_cache, _ = _tick_by_hand(model, cfg, params, "mixed")
+    assert float(np.max(np.abs(got - want)[valid])) < 2e-5
+    for k in want_cache:
+        assert float(jnp.max(jnp.abs(got_cache[k] - want_cache[k]))) < 2e-5
+
+
+def test_the_loop_over_blocks_of_slots_keeps_no_second_pool(llama_tiny,
+                                                           monkeypatch):
+    """``test_tick_keeps_no_second_pool``'s counts on a chunk-wide step whose
+    attention runs a loop of two blocks of two slots with a conditional in
+    it (the tiny step's own chunk fits the narrow columns and holds neither):
+    the branch that attends at chunk width gathers its slots' context from
+    the pool, and the pool is still scattered in place, never restacked,
+    never an operand of a copy."""
+    from horovod_tpu.models import llama
+    model, cfg, params = llama_tiny
+    scfg = _cfg(max_slots=4, cache_blocks=2048, max_batch_tokens=12,
+                spec_k=2)
+    monkeypatch.setattr(llama, "NARROW_COLS", 2)
+    monkeypatch.setattr(llama, "SCORE_BYTES", 2 * cfg.n_heads
+                        * scfg.prefill_chunk * scfg.max_seq_len * 4)
+    monkeypatch.setattr(model, "copy_blocks", lambda cache, src, dst: cache)
+    engine = ServeEngine(model, cfg, params, scfg, mesh=_one_device_mesh())
+    engine.submit(list(range(9)), 2, req_id="a")
+    engine.step()
+    engine.close()
+    step, pool = engine._steps[scfg.prefill_chunk], engine.cache["k"]
+    text = step.as_text()
+    assert " while(" in text and " conditional(" in text
+    dims = "[" + ",".join(map(str, pool.shape)) + "]"
+    ops = re.findall(r" = \w+(\[[\d,]*\])\S* ([\w-]+)\(", text)
+    assert (dims, "scatter") in ops
+    assert not [op for op in ops if op[0] == dims
+                and op[1] in ("copy", "concatenate")]
+    assert step.memory_analysis().temp_size_in_bytes < \
+        pool.nbytes // pool.shape[0]
+
+
+def test_wide_shares_read_what_the_plan_implies(llama_tiny, monkeypatch):
+    """``stats()["loop"]``'s two shares on a plan known by hand: a prompt
+    of 20 is a chunk of 12 then a tail of 8 — two wide ticks of 20 rows each
+    (the budget; the slab has 48 positions) that hold 12 and 8 tokens, and
+    of their two blocks of two slots one, the chunk's, attends at chunk
+    width (the tail fits the narrow columns); the decode ticks are narrow
+    and count nowhere."""
+    from horovod_tpu.models import llama
+    model, cfg, params = llama_tiny
+    monkeypatch.setattr(llama, "SCORE_BYTES", 2 * cfg.n_heads * 12 * 32 * 4)
+    engine = ServeEngine(
+        model, cfg, params,
+        _cfg(max_slots=4, cache_blocks=32, max_batch_tokens=20,
+             prefill_chunk=12, spec_decode=False, prefix_cache=False),
+        mesh=_one_device_mesh())
+    assert engine.stats()["loop"]["wide_rows_share"] is None
+    engine.submit(list(range(1, 21)), 3, req_id="a")
+    engine.flush()
+    loop = engine.stats()["loop"]
+    engine.close()
+    assert loop["ticks"] - loop["narrow_ticks"] == 2
+    assert loop["wide_rows_share"] == (12 + 8) / (2 * 20)
+    assert loop["wide_blocks_share"] == 1 / (2 * 2)
 
 
 def test_two_engines_fed_alike_agree_on_digest_and_widths(llama_tiny):
