@@ -9,17 +9,49 @@ sequence.  A sequence owns whole blocks through its row of ``block_tables``
 ``[j*bs, (j+1)*bs)``, so sequences of different lengths coexist in static
 shapes.  The stacked pool is indexed by layer, never unstacked: a donated
 cache stays one buffer through a tick (docs/serving.md).
+
+A model whose layers keep state of several KINDS (models/swa_moe.py: layers
+that read the whole context beside layers that read a window of it) declares
+them (:class:`CacheKind`) and holds one pool a kind.  A kind with a window
+is addressed as a RING: its table row has :func:`ring_blocks` entries and
+position P lands in entry ``(P // bs) % entries``, so a slot holds the last
+``entries * bs`` positions whatever its context's length
+(docs/serving.md#cache-kinds).
 """
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
+
+
+class CacheKind(NamedTuple):
+    """One kind of cached state: ``layers`` layers of the model keep it, each
+    the slot's whole context (``window`` None) or its last ``window``
+    positions.  A module's ``cache_kinds(cfg)`` lists its kinds; its cache
+    and the block tables it is handed are dicts by ``name``."""
+    name: str
+    layers: int
+    window: Optional[int] = None
+
+
+def ring_blocks(window: int, tick_cols: int, block_size: int,
+                max_blocks: int) -> int:
+    """Table entries of a slot's ring in a kind with ``window``: the window
+    plus the widest tick's columns, rounded up to blocks, and never more
+    than a whole context's.  A tick writes its ``n <= tick_cols`` positions
+    ``L .. L+n-1`` BEFORE its queries read ``L-window+1 ..``: the write of
+    ``L+n-1`` lands on position ``L+n-1-R``, which no query of the tick may
+    still see, so ``R >= window + n - 1``.  The same margin covers a
+    rejected speculative draft: its stale write at ``L+j`` (``j <
+    tick_cols``) lands on ``L+j-R < L-window+1``, outside every later
+    accepted query's window too."""
+    return min(-(-(window + tick_cols) // block_size), max_blocks)
 
 
 def slot_positions(lengths: jax.Array, n_new: jax.Array, C: int
@@ -58,14 +90,16 @@ def pack(valid: jax.Array, budget: int) -> Tuple[Callable, Callable]:
 
 
 def write_index(block_tables: jax.Array, positions: jax.Array,
-                valid: jax.Array, num_blocks: int, block_size: int
-                ) -> Tuple[jax.Array, jax.Array]:
+                valid: jax.Array, num_blocks: int, block_size: int,
+                ring: bool = False) -> Tuple[jax.Array, jax.Array]:
     """(blk, off): position P of slot s lands in ``block_tables[s, P // bs]``
-    at offset ``P % bs``.  Invalid (padding / inactive-slot) positions go to
-    ``num_blocks``, off the block axis, where :func:`write` drops them — a
-    dead slot's stale table row is never written."""
-    slot_idx = jnp.minimum(positions // block_size,
-                           block_tables.shape[1] - 1)
+    at offset ``P % bs`` — in a ``ring``, in entry ``(P // bs) % entries``.
+    Invalid (padding / inactive-slot) positions go to ``num_blocks``, off
+    the block axis, where :func:`write` drops them — a dead slot's stale
+    table row is never written."""
+    entry = positions // block_size
+    slot_idx = (entry % block_tables.shape[1] if ring else
+                jnp.minimum(entry, block_tables.shape[1] - 1))
     blk = jnp.take_along_axis(block_tables, slot_idx, axis=1)
     blk = jnp.where(valid, jnp.maximum(blk, 0), num_blocks)
     return blk, positions % block_size
@@ -97,6 +131,24 @@ def context_mask(positions: jax.Array, ctx: int) -> jax.Array:
     """[S, 1, C, ctx] bool: the query at ``positions[s, c]`` sees gathered
     keys ``0 .. positions[s, c]`` (its own, written first, included)."""
     return (jnp.arange(ctx)[None, None, :] <= positions[:, :, None])[:, None]
+
+
+def ring_positions(top: jax.Array, ring: int) -> jax.Array:
+    """[S, ring] int32: the position that index j of slot s's gathered ring
+    holds once the tick's writes are in, ``top[s]`` being the slot's last
+    written position (``lengths + n_new - 1``): the largest ``p <= top``
+    with ``p % ring == j``.  Negative = never written."""
+    j = jnp.arange(ring, dtype=top.dtype)[None, :]
+    return top[:, None] - (top[:, None] - j) % ring
+
+
+def window_mask(positions: jax.Array, key_pos: jax.Array,
+                window: int) -> jax.Array:
+    """[S, 1, C, K] bool: the query at ``positions[s, c]`` sees the gathered
+    key at position ``key_pos[s, k]`` when ``0 <= q - k < window`` (its own
+    included: ``window`` keys) and the key was written (``k >= 0``)."""
+    d = positions[:, :, None] - key_pos[:, None, :]
+    return ((d >= 0) & (d < window) & (key_pos[:, None, :] >= 0))[:, None]
 
 
 def slots_per_block(S: int, per_slot_bytes: int, budget: int) -> int:

@@ -17,6 +17,7 @@ weighted by the router gate.
 
 from __future__ import annotations
 
+import functools
 from functools import partial
 from typing import Any, Callable, Dict
 
@@ -24,6 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax, shard_map
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -202,11 +205,66 @@ def moe_dense_reference(params, x, n_experts: int, capacity: int,
 # chip the layer runs without its exchange and nothing stands in for it.
 
 
-def gated_ffn(w_gate, w_up, w_down, x):
-    """``(silu(x W_gate) * x W_up) W_down``: one gated three-matrix expert
-    (a shared expert, or one routed expert's tile of rows)."""
-    h = jax.nn.silu(jnp.dot(x, w_gate)) * jnp.dot(x, w_up)
+def gated_ffn(w_gate, w_up, w_down, x, act=jax.nn.silu):
+    """``(act(x W_gate) * x W_up) W_down``: one gated three-matrix expert
+    (a shared expert, or one routed expert's tile of rows); ``act`` is the
+    gate's activation (silu, or relu for a ReLU-gated expert)."""
+    h = act(jnp.dot(x, w_gate)) * jnp.dot(x, w_up)
     return jnp.dot(h, w_down, preferred_element_type=jnp.float32)
+
+
+#: bytes of one expert's three weight blocks a grid step may hold in VMEM,
+#: both pipeline buffers counted (a v5e core has 128 MiB)
+_TILE_FFN_VMEM = 48 << 20
+
+
+def _tile_ffn_kernel(e_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref, *, act):
+    """One block of the expert's hidden width: rounds where :func:`gated_ffn`
+    rounds (the two products and their gated product in the rows' type),
+    sums the blocks in float32."""
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+    x = x_ref[...]
+    proj = lambda w_ref: jnp.dot(x, w_ref[...],
+                                 preferred_element_type=jnp.float32
+                                 ).astype(x.dtype).astype(jnp.float32)
+    h = act(proj(wg_ref)).astype(x.dtype).astype(jnp.float32) * proj(wu_ref)
+    o_ref[...] += jnp.dot(h.astype(x.dtype), wd_ref[...],
+                          preferred_element_type=jnp.float32)
+
+
+def tile_ffn(w_gate, w_up, w_down, x, e, act=jax.nn.silu):
+    """:func:`gated_ffn` of rows ``x`` [tile, D] through expert ``e`` (a
+    traced number) of the STACKED experts ``w_gate``, ``w_up`` [E, D, F] and
+    ``w_down`` [E, F, D]: float32 [tile, D].  A kernel, because the expert's
+    number is data: it reaches the blocks' index maps as a prefetched
+    scalar, so the expert's matrices stream from where they lie, a block of
+    the hidden width after another — indexed in XLA (``w[e]`` in a loop's
+    body) each matrix is first copied out of the stack, three times the
+    expert layer's bytes (PERF.md §6, PR 31).  Interpreted on the CPU."""
+    tile, D = x.shape
+    F = w_gate.shape[2]
+    per_col = 2 * 3 * D * w_gate.dtype.itemsize     # both buffers, per column
+    fb = max((b for b in range(128, F + 1, 128)
+              if F % b == 0 and b * per_col <= _TILE_FFN_VMEM), default=F)
+    return pl.pallas_call(
+        functools.partial(_tile_ffn_kernel, act=act),
+        out_shape=jax.ShapeDtypeStruct((tile, D), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(F // fb,),
+            in_specs=[
+                pl.BlockSpec((tile, D), lambda f, e: (0, 0)),
+                pl.BlockSpec((None, D, fb), lambda f, e: (e[0], 0, f)),
+                pl.BlockSpec((None, D, fb), lambda f, e: (e[0], 0, f)),
+                pl.BlockSpec((None, fb, D), lambda f, e: (e[0], f, 0))],
+            out_specs=pl.BlockSpec((tile, D), lambda f, e: (0, 0))),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=fb * per_col + 6 * tile * D * 4 + (16 << 20)),
+        interpret=jax.default_backend() == "cpu",
+        name="expert_tile_ffn",
+    )(jnp.reshape(e, (1,)).astype(jnp.int32), x, w_gate, w_up, w_down)
 
 
 def init_held_experts(key, dim: int, hidden: int, total: int, held: int,
@@ -235,29 +293,55 @@ def route_sigmoid_topk(x, router_kernel, k: int, scale: float):
     return idx, scale * top / (top.sum(-1, keepdims=True) + 1e-20)
 
 
+def route_softmax_topk(x, router_kernel, k: int):
+    """Router logits over ALL experts in float32, the ``k`` largest, gates
+    the softmax over the CHOSEN logits (a softmax over all of them followed
+    by a renormalised top-k is the same numbers).  Returns (idx [T, k]
+    int32, gates [T, k] float32)."""
+    top, idx = lax.top_k(jnp.dot(x.astype(jnp.float32),
+                                 router_kernel.astype(jnp.float32),
+                                 precision=lax.Precision.HIGHEST), k)
+    return idx, jax.nn.softmax(top, axis=-1)
+
+
 #: what ``held_experts`` counts, in the order of its counter vector
 HELD_COUNTERS = ("assignments", "assignments_held", "experts_touched",
                  "load_max")
 
 
-def held_experts(p: Dict[str, Any], x, valid, *, first: int, k: int,
-                 scale: float, tile: int = 64):
+def held_experts(p: Dict[str, Any], x, valid, *, first: int, k: int = 0,
+                 scale: float = 1.0, tile: int = 64, routing=None,
+                 act=jax.nn.silu):
     """This chip's part of a routed layer: ``sum_{e in top-k, e held}
     g_e E_e(x)`` for tokens ``x`` [T, D], rows with ``valid`` False routed
     nowhere.  ``p`` as :func:`init_held_experts` gives it.
 
+    ``routing`` is the caller's decision ``(idx [T, k] int32 over ALL
+    experts, gates [T, k] float32)`` — a model whose router reads another
+    tensor than its experts do (models/swa_moe.py: the attention's input)
+    routes there and hands it in; without it the layer routes inside
+    itself from ``x`` with :func:`route_sigmoid_topk` (``k``, ``scale``).
+    ``act`` is the experts' gate activation.
+
     Drop-free and batch-invariant: assignments are sorted by expert (a
-    stable sort, so in token order within one) and each held expert runs
-    over its own rows in tiles of ``tile`` — as many tiles as it has rows,
-    none when it has none — so no capacity bounds anything, the work grows
-    with the assignments held here and not with experts x tokens, and a
-    token's sum is taken in expert order whoever shares its tick.
+    stable sort, so in token order within one) and cut into tiles of
+    ``tile`` rows that never span two experts — as many tiles as an expert
+    has rows, none when it has none.  ONE loop runs the tiles in that order,
+    each through :func:`tile_ffn` with its expert's number, so no
+    capacity bounds anything, the work grows with the assignments held here
+    and not with experts x tokens, a token's sum is taken in expert order
+    whoever shares its tick, and the program holds one loop body a layer
+    however many experts are held (64 experts a layer in eight layers were
+    512 unrolled loops and three minutes of compilation; PERF.md §6, PR 31).
 
     Returns (y [T, D] float32, counters int32[4] as HELD_COUNTERS)."""
     T, D = x.shape
     held = p["experts"]["w_gate"].shape[0]
-    with jax.named_scope("moe/route"):
-        idx, gates = route_sigmoid_topk(x, p["router"]["kernel"], k, scale)
+    if routing is None:
+        with jax.named_scope("moe/route"):
+            routing = route_sigmoid_topk(x, p["router"]["kernel"], k, scale)
+    idx, gates = routing
+    k = idx.shape[1]
     with jax.named_scope("moe/dispatch"):
         local = idx - first
         mine = (local >= 0) & (local < held) & valid[:, None]
@@ -272,26 +356,33 @@ def held_experts(p: Dict[str, Any], x, valid, *, first: int, k: int,
         counters = jnp.stack([n_valid * k, counts.sum(),
                               jnp.sum(counts > 0, dtype=jnp.int32),
                               counts.max()])
+        # tiles, in expert order: tile t is expert ``e``'s
+        # ``t - first_tile[e]``-th, e the experts whose tiles end by t
+        n_tiles = (counts + tile - 1) // tile
+        last_tile = jnp.cumsum(n_tiles)
+        first_tile = last_tile - n_tiles
     w = p["experts"]
     rows = jnp.arange(tile, dtype=jnp.int32)
-    y = jnp.zeros((T, D), jnp.float32)
-    for e in range(held):
-        def one_tile(t, y, e=e):
-            at = t * tile + rows
+
+    def one_tile(t, y):
+        with jax.named_scope("moe/dispatch"):
+            e = jnp.sum(last_tile <= t, dtype=jnp.int32)
+            at = (t - first_tile[e]) * tile + rows
             live = at < counts[e]
             src = jnp.minimum(starts[e] + at, T * k - 1)
-            with jax.named_scope("moe/dispatch"):
-                t_src = tok[src]
-                xt = jnp.take(x, t_src, axis=0)
-            with jax.named_scope("moe/experts"):
-                out = gated_ffn(w["w_gate"][e], w["w_up"][e], w["w_down"][e],
-                                xt) * gate[src][:, None]
-            with jax.named_scope("moe/combine"):
-                return y.at[jnp.where(live, t_src, T)].add(out, mode="drop")
-        y = lax.fori_loop(0, (counts[e] + tile - 1) // tile, one_tile, y)
+            t_src = tok[src]
+            xt = jnp.take(x, t_src, axis=0)
+        with jax.named_scope("moe/experts"):
+            out = tile_ffn(w["w_gate"], w["w_up"], w["w_down"], xt, e,
+                           act) * gate[src][:, None]
+        with jax.named_scope("moe/combine"):
+            return y.at[jnp.where(live, t_src, T)].add(out, mode="drop")
+    y = lax.fori_loop(0, last_tile[-1], one_tile,
+                      jnp.zeros((T, D), jnp.float32))
     return y, counters
 
 
 __all__ = ["make_moe_fn", "init_moe_params", "moe_shardings",
-           "moe_dense_reference", "gated_ffn", "init_held_experts",
-           "route_sigmoid_topk", "held_experts", "HELD_COUNTERS"]
+           "moe_dense_reference", "gated_ffn", "tile_ffn", "init_held_experts",
+           "route_sigmoid_topk", "route_softmax_topk", "held_experts",
+           "HELD_COUNTERS"]

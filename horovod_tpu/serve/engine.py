@@ -412,6 +412,8 @@ class Request:
         self.ctx_len = 0    # tokens written into the cache
         self.slot: Optional[int] = None
         self.blocks: List[int] = []
+        # the blocks of each window kind's ring (Scheduler._with_rings)
+        self.ring_blocks: Dict[str, List[int]] = {}
         self.draft: List[int] = []      # this tick's speculative tokens
         self._bigram: Dict[Tuple[int, int], int] = {}
         self._indexed = 0   # context positions already in the index
@@ -481,6 +483,42 @@ class Request:
 
 
 # ------------------------------------------------------------- scheduler
+class _Ring:
+    """One window kind's host state (models/paged.py CacheKind with a
+    ``window``): a slot keeps at most ``entries`` blocks of it, addressed as
+    a ring, whatever its context's length — the window plus the widest
+    tick's columns, rounded up to blocks (paged.ring_blocks, where the
+    speculative margin is argued).  The pool holds ``max_slots * entries``
+    blocks, so a free slot always finds its ring; the allocator is there so
+    that admission counts, ``finish`` returns and ``kv_pool`` reports this
+    kind as they do the other."""
+
+    def __init__(self, kind, cfg: ServeConfig):
+        self.kind = kind
+        self.entries = paged.ring_blocks(kind.window, cfg.prefill_chunk,
+                                         cfg.block_size,
+                                         cfg.max_blocks_per_seq)
+        self.positions = self.entries * cfg.block_size   # a slot's at most
+        self.allocator = BlockAllocator(cfg.max_slots * self.entries)
+        self.tables = -np.ones((cfg.max_slots, self.entries), np.int32)
+        # summed over dispatched ticks and their slots: the slots, the
+        # positions this kind holds, those of them inside the window (what a
+        # tick's attention reads), and the positions a full-context cache
+        # would hold for the same slots
+        self.slot_ticks = 0
+        self.resident_position_ticks = 0
+        self.window_position_ticks = 0
+        self.full_position_ticks = 0
+
+    def count(self, ctx_len: int) -> None:
+        """One slot of one dispatched tick, its context ``ctx_len`` long once
+        the tick's positions are written."""
+        self.slot_ticks += 1
+        self.full_position_ticks += ctx_len
+        self.resident_position_ticks += min(ctx_len, self.positions)
+        self.window_position_ticks += min(ctx_len, self.kind.window)
+
+
 class Scheduler:
     """Deterministic slot-table scheduler (pure host state, no jax) —
     unit-testable without a model.  ``plan()`` returns this tick's
@@ -498,12 +536,40 @@ class Scheduler:
 
     ROLES = ("mixed", "prefill", "decode")
 
-    def __init__(self, cfg: ServeConfig, role: str = "mixed"):
+    def __init__(self, cfg: ServeConfig, role: str = "mixed", kinds=()):
+        """``kinds``: the served model's cache kinds (paged.CacheKind; none
+        = one kind that keeps whole contexts).  A kind with a window gets a
+        ring a slot (:class:`_Ring`) beside the block table of the kind
+        that keeps whole contexts."""
         if role not in self.ROLES:
             raise ValueError(f"scheduler role {role!r} invalid; expected "
                              f"one of {self.ROLES}")
         self.cfg = cfg
         self.role = role
+        self.kinds = tuple(kinds)
+        self.rings = {k.name: _Ring(k, cfg) for k in self.kinds
+                      if k.window is not None}
+        if self.rings:
+            # A ring's block stops holding a prefix's positions once the
+            # stream has passed it: a hit at n tokens would need every
+            # window layer's positions n - window .. n - 1, which no other
+            # slot's ring and no block of the tree keeps.  Refused, never
+            # silently wrong (docs/serving.md#cache-kinds).
+            for on, what in ((cfg.prefix_cache, "the radix prefix cache "
+                              "(HOROVOD_SERVE_PREFIX_CACHE) and its "
+                              "copy-on-write"),
+                             (cfg.spill_blocks, "the host spill tier "
+                              "(HOROVOD_SERVE_SPILL_BLOCKS)"),
+                             (role != "mixed", f"the {role!r} role's prefill "
+                              "hand-off")):
+                if on:
+                    raise ValueError(
+                        f"the served model keeps window cache kinds "
+                        f"({', '.join(self.rings)}): {what} cannot run over "
+                        "them — a window layer's block is overwritten once "
+                        "the stream has passed it, so a block is no prefix's "
+                        "to share, spill or hand off; turn it off "
+                        "(docs/serving.md#cache-kinds)")
         self.slots: List[Optional[Request]] = [None] * cfg.max_slots
         self.waiting: "collections.deque[Request]" = collections.deque()
         self.allocator = BlockAllocator(cfg.cache_blocks)
@@ -597,6 +663,8 @@ class Scheduler:
             self.slots[slot] = req
             self.block_tables[slot, :] = -1
             self.block_tables[slot, :len(row)] = row
+            for name, blocks in req.ring_blocks.items():
+                self.rings[name].tables[slot, :len(blocks)] = blocks
             # prefix-hit tokens are already resident: prefill resumes at
             # req.pos (match() keeps >= 1 token to compute, so n >= 1)
             n = min(chunk, req.prompt_len - req.pos, budget)
@@ -615,7 +683,7 @@ class Scheduler:
         need = -(-(req.prompt_len + req.max_new_tokens)
                  // self.cfg.block_size)
         if self.prefix is None:
-            return self.allocator.alloc(need)
+            return self._with_rings(req, need, self.allocator.alloc(need))
         shared, cow, hit = self.prefix.match(req.tokens)
         self.allocator.incref(shared)
         need_new = need - len(shared)
@@ -648,6 +716,34 @@ class Scheduler:
                 M.SERVE_PREFIX_BLOCKS_SHARED.inc(len(shared))
         req.pos = req.ctx_len = hit
         return shared + blocks
+
+    def _with_rings(self, req: Request, need: int,
+                    row: Optional[List[int]]) -> Optional[List[int]]:
+        """``row`` once every window kind has reserved what it will hold of
+        the request: ``min(need, ring entries)`` blocks each; all or nothing
+        across the kinds."""
+        if row is None:
+            return None
+        got: Dict[str, List[int]] = {}
+        for name, ring in self.rings.items():
+            blocks = ring.allocator.alloc(min(need, ring.entries))
+            if blocks is None:
+                for done, held in got.items():
+                    self.rings[done].allocator.free(held)
+                self.allocator.free(row)
+                return None
+            got[name] = blocks
+        req.ring_blocks = got
+        return row
+
+    def device_tables(self):
+        """What the tick addresses its pools with: the block table, or for
+        a model that declares cache kinds ``{kind: table}`` — the whole-
+        context kind's block table and each window kind's ring table."""
+        if not self.kinds:
+            return self.block_tables
+        return {k.name: (self.rings[k.name].tables if k.name in self.rings
+                         else self.block_tables) for k in self.kinds}
 
     def take_copies(self) -> List[Tuple[int, int]]:
         copies, self.pending_copies = self.pending_copies, []
@@ -730,9 +826,14 @@ class Scheduler:
         req.done_t = time.perf_counter()
         if req.slot is not None:
             self.block_tables[req.slot, :] = -1
+            for ring in self.rings.values():
+                ring.tables[req.slot, :] = -1
             self.slots[req.slot] = None
         self.allocator.free(req.blocks)
         req.blocks = []
+        for name, blocks in req.ring_blocks.items():
+            self.rings[name].allocator.free(blocks)
+        req.ring_blocks = {}
         req.slot = None
         self.completed += 1
 
@@ -831,9 +932,17 @@ class ServeEngine:
     ``model`` is a model module that defines ``init_cache``,
     ``copy_blocks``, ``apply_cached``, ``cache_shardings``, ``attn_blocks``
     and ``TICK_COUNTERS`` (models/llama.py, models/moe_llama.py,
-    models/latent_moe.py; docs/serving.md#what-a-served-model-module-exports);
-    ``model_cfg`` its config dataclass; ``params`` the trained pytree
-    (host or global arrays).
+    models/latent_moe.py, models/swa_moe.py;
+    docs/serving.md#what-a-served-model-module-exports); ``model_cfg`` its
+    config dataclass; ``params`` the trained pytree (host or global
+    arrays).  Two optional declarations: ``cache_kinds(model_cfg)`` — the
+    kinds of cache its layers keep (models/paged.py ``CacheKind``); its
+    cache, its block tables and ``init_cache`` / ``cache_shardings``'s
+    block counts are then dicts by kind, and a kind with a window is a ring
+    a slot (docs/serving.md#cache-kinds; swa_moe.py declares two, the other
+    modules none: one pool, one table) —, and ``greedy_cached``, the
+    tick's greedy tokens in place of its logits, for a vocabulary whose
+    ``[slots, chunk, vocab]`` slab should never exist (swa_moe.py).
     """
 
     def __init__(self, model, model_cfg, params, cfg: ServeConfig,
@@ -853,21 +962,36 @@ class ServeEngine:
             from .. import runtime as _rt
             mesh = _rt.get().mesh
         self.mesh = mesh
-        self.scheduler = Scheduler(cfg, role=role)
+        kinds = (tuple(model.cache_kinds(model_cfg))
+                 if hasattr(model, "cache_kinds") else ())
+        self.scheduler = Scheduler(cfg, role=role, kinds=kinds)
         self._repl = NamedSharding(mesh, P())
-        self._cache_shd = model.cache_shardings(mesh, model_cfg,
-                                                cfg.cache_blocks)
+        # blocks of the pool — of each kind's pool: a window kind's holds
+        # every slot's ring and is sized by the model's window, the slots
+        # and the chunk, not by ``cache_blocks``
+        num_blocks: Any = cfg.cache_blocks
+        if kinds:
+            rings = self.scheduler.rings
+            num_blocks = {k.name: (rings[k.name].allocator.num_blocks
+                                   if k.name in rings else cfg.cache_blocks)
+                          for k in kinds}
+        self._cache_shd = model.cache_shardings(mesh, model_cfg, num_blocks)
         leaves = jax.tree_util.tree_leaves(params)
         if leaves and isinstance(leaves[0], jax.Array):
             self.params = params
         else:
             self.params = replicate_global(params, mesh)
         cache_struct = jax.eval_shape(
-            lambda: model.init_cache(model_cfg, cfg.cache_blocks,
-                                     cfg.block_size))
+            lambda: model.init_cache(model_cfg, num_blocks, cfg.block_size))
+        # one sharding a leaf: a model with cache kinds gives one a kind
+        self._leaf_shd = (
+            {k.name: jax.tree_util.tree_map(
+                lambda _, n=k.name: self._cache_shd[n], cache_struct[k.name])
+             for k in kinds} if kinds else
+            jax.tree_util.tree_map(lambda _: self._cache_shd, cache_struct))
         self.cache = jax.tree_util.tree_map(
-            lambda x: _global_zeros(x.shape, x.dtype, self._cache_shd),
-            cache_struct)
+            lambda x, shd: _global_zeros(x.shape, x.dtype, shd),
+            cache_struct, self._leaf_shd)
         # Host-RAM spill tier behind the device pool
         # (docs/serving.md#replicated-tier): evicted-but-warm radix
         # blocks migrate to host instead of dying, reload on hit.
@@ -924,9 +1048,12 @@ class ServeEngine:
         # The pool's true byte footprint: the preallocated cache pytree
         # itself (this rank's shards of it are the resident bytes the
         # memory plane attributes to the kv_pool plane).
-        self._pool_bytes = sum(
+        nbytes = lambda tree: sum(
             int(np.prod(x.shape)) * x.dtype.itemsize
-            for x in jax.tree_util.tree_leaves(cache_struct))
+            for x in jax.tree_util.tree_leaves(tree))
+        self._pool_bytes = nbytes(cache_struct)
+        self._kind_bytes = {k.name: nbytes(cache_struct[k.name])
+                            for k in kinds}
         try:
             from ..perf.memstats import set_kv_pool_provider
             set_kv_pool_provider(self.kv_pool)
@@ -949,11 +1076,14 @@ class ServeEngine:
             # content (functional semantics — see Scheduler._admit_blocks).
             with jax.named_scope("tick/copy_blocks"):
                 cache = model.copy_blocks(cache, copy_src, copy_dst)
+            greedy = getattr(model, "greedy_cached", None)
             with jax.named_scope("tick/model"):
-                out = model.apply_cached(params, tokens, mcfg, cache,
-                                         block_tables, lengths, n_new)
+                out = (greedy or model.apply_cached)(
+                    params, tokens, mcfg, cache, block_tables, lengths, n_new)
             logits, cache = out[:2]
             counters = out[2] if self._counter_names else None
+            if greedy is not None:      # the module sampled on its rows
+                return cache, logits, counters
             # Greedy sampling ON DEVICE at EVERY chunk position: row
             # [s, j] is the greedy continuation after consuming tokens
             # [s, :j+1] — prefill reads its last valid position,
@@ -969,8 +1099,7 @@ class ServeEngine:
             step_fn,
             donate_argnums=(1,),
             out_shardings=(
-                jax.tree_util.tree_map(lambda _: self._cache_shd,
-                                       self.cache),
+                self._leaf_shd,
                 self._repl,
                 self._repl if self._counter_names else None))
 
@@ -1017,6 +1146,15 @@ class ServeEngine:
         """Runs between steps, so the next dispatch reads it."""
         self.cache = paged.write_block(self.cache, block, payload)
 
+    def _whole_contexts_only(self, what: str) -> None:
+        """Block transfer reads "a block in every layer": refused where a
+        window kind's ring has no such block (Scheduler.__init__)."""
+        if self.scheduler.rings:
+            raise ValueError(
+                f"{what} cannot run over the served model's window cache "
+                f"kinds ({', '.join(self.scheduler.rings)}); "
+                "docs/serving.md#cache-kinds")
+
     # ------------------------------------------------------ disaggregation
     def export_handoff(self, req: Request, first_token: int
                        ) -> Dict[str, Any]:
@@ -1024,6 +1162,7 @@ class ServeEngine:
         request identity/budget, the first sampled token, and the
         prompt blocks' KV as encoded payloads.  Pure read — the caller
         decides when to finish the request."""
+        self._whole_contexts_only("the prefill hand-off's export")
         bs = self.cfg.block_size
         n_blocks = -(-req.prompt_len // bs)
         return {
@@ -1050,6 +1189,7 @@ class ServeEngine:
         it for installation (Scheduler._drain_imports) — the request
         enters the slot table directly in decode state with its prompt
         KV written from the payload, skipping prefill entirely."""
+        self._whole_contexts_only("the prefill hand-off's import")
         req = Request(handoff["tokens"], int(handoff["max_new_tokens"]),
                       req_id=handoff.get("req_id"),
                       eos_id=(handoff.get("eos_id")
@@ -1129,6 +1269,8 @@ class ServeEngine:
                     tokens[slot, :n] = [req.out_tokens[-1]] + req.draft
                 lengths[slot] = req.ctx_len
                 n_new[slot] = n
+                for ring in self.scheduler.rings.values():
+                    ring.count(req.ctx_len + n)
             copy_src = np.zeros(S, np.int32)
             copy_dst = np.full(S, cfg.cache_blocks, np.int32)  # no-op: dropped
             for j, (src, dst) in enumerate(copies):
@@ -1136,9 +1278,11 @@ class ServeEngine:
             # Async dispatch: device_put + jit return immediately; the next
             # step() harvests, so this tick's H2D staging and compute run
             # behind the caller's host work (the double-buffer pattern).
-            dev = [_make_global(a, self._repl)
-                   for a in (np.asarray(self.scheduler.block_tables),
-                             lengths, n_new, tokens, copy_src, copy_dst)]
+            put = lambda a: _make_global(a, self._repl)
+            tables = self.scheduler.device_tables()
+            dev = [{k: put(t) for k, t in tables.items()}
+                   if isinstance(tables, dict) else put(tables)] + [
+                put(a) for a in (lengths, n_new, tokens, copy_src, copy_dst)]
         with self.clock.span("launch"):
             if not self._steps:
                 self._compile_steps(dev)
@@ -1394,7 +1538,10 @@ class ServeEngine:
         s = self.scheduler
         occ = s.allocator.occupancy()
         nb = max(occ["num_blocks"], 1)
-        block_bytes = self._pool_bytes // nb
+        # a block of the allocator's own kind (the one that keeps whole
+        # contexts, where the model declares kinds)
+        full = next((k.name for k in s.kinds if k.window is None), None)
+        block_bytes = self._kind_bytes.get(full, self._pool_bytes) // nb
         reserved_tokens = written_tokens = 0
         for req in s.slots:
             if req is not None:
@@ -1418,7 +1565,37 @@ class ServeEngine:
             spill["held_bytes_est"] = \
                 self._spill.blocks_held * block_bytes
             occ["spill"] = spill
+        if s.kinds:
+            occ["kinds"] = {k.name: self._kind_pool(k, written_tokens)
+                            for k in s.kinds}
         return occ
+
+    def _kind_pool(self, kind, written_tokens: int) -> Dict[str, Any]:
+        """One cache kind's part of :meth:`kv_pool`: its pool's blocks in
+        use and free, the positions it holds now (a ring: at most its own
+        length a slot) beside the positions a full-context cache would hold
+        for the same slots, and for a ring the same two summed over every
+        dispatched tick (what ``kv.window_resident_share.serve`` reads)."""
+        s = self.scheduler
+        ring = s.rings.get(kind.name)
+        alloc = ring.allocator if ring else s.allocator
+        out = {"layers": kind.layers, "window": kind.window,
+               "pool_bytes": self._kind_bytes[kind.name],
+               "num_blocks": alloc.num_blocks,
+               "used_blocks": alloc.num_blocks - alloc.free_count,
+               "free_blocks": alloc.free_count,
+               "positions_resident": written_tokens,
+               "positions_full_context": written_tokens}
+        if ring:
+            out.update(
+                ring_positions=ring.positions,
+                positions_resident=sum(min(r.ctx_len, ring.positions)
+                                       for r in s.slots if r is not None),
+                slot_ticks=ring.slot_ticks,
+                resident_position_ticks=ring.resident_position_ticks,
+                window_position_ticks=ring.window_position_ticks,
+                full_position_ticks=ring.full_position_ticks)
+        return out
 
     def close(self) -> None:
         """Unregister the memory plane's KV-pool provider — a torn-down
@@ -1485,7 +1662,8 @@ SERVE_MANIFEST = "serve.json"
 
 _MODEL_MODULES = {"llama": "horovod_tpu.models.llama",
                   "moe_llama": "horovod_tpu.models.moe_llama",
-                  "latent_moe": "horovod_tpu.models.latent_moe"}
+                  "latent_moe": "horovod_tpu.models.latent_moe",
+                  "swa_moe": "horovod_tpu.models.swa_moe"}
 
 
 def save_servable(directory: str, model_name: str, config, params,
@@ -1506,7 +1684,7 @@ def save_servable(directory: str, model_name: str, config, params,
 def load_servable(directory: str, mesh) -> Tuple[Any, Any, Any]:
     """Read a servable directory -> (model module, model config, global
     replicated params).  ``serve.json``: {"model": "llama"|"moe_llama"|
-    "latent_moe",
+    "latent_moe"|"swa_moe",
     "config": <name in CONFIGS or kwarg dict>, "seed": int?}.  Params
     come from the latest checkpoint under the directory (restored
     through checkpoint.py into replicated shardings); with no

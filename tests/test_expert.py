@@ -261,3 +261,32 @@ def test_moe_llama_mixtral_config_trains(hvd):
         losses.append(float(l))
     assert np.isfinite(losses).all()
     assert losses[-1] < losses[0], losses
+
+
+# --------------------------------------------- the expert tile's kernel
+@pytest.mark.parametrize("dtype,act,tol", [
+    (jnp.float32, jax.nn.silu, 1e-5), (jnp.float32, jax.nn.relu, 1e-5),
+    # bfloat16 rounds where gated_ffn rounds; the blocks' float32 sums come
+    # in another order: a few 1e-3 of the output's spread
+    (jnp.bfloat16, jax.nn.relu, 2e-2)])
+def test_tile_ffn_is_gated_ffn_of_the_numbered_expert(dtype, act, tol):
+    """The kernel (interpreted here) against the plain three-matrix expert,
+    the expert's number traced, a hidden width that is cut into blocks."""
+    from horovod_tpu.parallel import expert as X
+    E, D, F, tile = 5, 128, 384, 16
+    p = X.init_held_experts(jax.random.PRNGKey(0), D, F, E, E, dtype)["experts"]
+    x = jax.random.normal(jax.random.PRNGKey(1), (tile, D)).astype(dtype)
+    old = X._TILE_FFN_VMEM
+    X._TILE_FFN_VMEM = 2 * 3 * D * jnp.dtype(dtype).itemsize * 128  # 3 blocks
+    try:
+        run = jax.jit(lambda e: X.tile_ffn(p["w_gate"], p["w_up"],
+                                           p["w_down"], x, e, act))
+        for e in (0, 3, 4):
+            want = X.gated_ffn(p["w_gate"][e], p["w_up"][e], p["w_down"][e],
+                               x, act)
+            got = run(jnp.int32(e))
+            assert got.dtype == jnp.float32 and got.shape == (tile, D)
+            assert float(jnp.max(jnp.abs(got - want))) \
+                < tol * float(jnp.std(want)), e
+    finally:
+        X._TILE_FFN_VMEM = old

@@ -176,12 +176,17 @@ def test_shardings_ride_the_meshs_own_axes():
 # ------------------------------------------------ the engine's contract
 CONTRACT = ("init_cache", "copy_blocks", "apply_cached", "cache_shardings",
             "attn_blocks", "TICK_COUNTERS")
+#: what a module MAY declare beside them (swa_moe does): its cache kinds, and
+#: the tick's greedy tokens in place of its logits
+OPTIONAL = ("cache_kinds", "greedy_cached")
 
 
 def _engine(model, cfg, params):
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("hvd",))
     scfg = ServeConfig(max_slots=2, block_size=4, cache_blocks=32,
-                       max_seq_len=32, max_batch_tokens=12, prefill_chunk=8)
+                       max_seq_len=32, max_batch_tokens=12, prefill_chunk=8,
+                       # refused over a window cache kind
+                       prefix_cache=not hasattr(model, "cache_kinds"))
     return ServeEngine(model, cfg, params, scfg, mesh=mesh)
 
 
@@ -207,6 +212,7 @@ def test_every_served_module_keeps_the_contract(name):
     engine.close()
     for attr in ("TICK_COUNTERS", "cache_shardings", "attn_blocks"):
         stub = types.SimpleNamespace(**{
-            a: getattr(model, a) for a in CONTRACT if a != attr})
+            a: getattr(model, a) for a in CONTRACT + OPTIONAL
+            if a != attr and hasattr(model, a)})
         with pytest.raises(AttributeError, match=attr):
             _engine(stub, cfg, params)

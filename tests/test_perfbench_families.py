@@ -1,7 +1,8 @@
 """The seam between the benchmark's harness and a model family
-(perfbench/families/__init__.py), in tier 1, for the three families there
-are: the dense GQA decoder, the latent-attention expert decoder, and the
-switch family that only the benchmark's tests use.  At toy width on the CPU:
+(perfbench/families/__init__.py), in tier 1, for the four families there
+are: the dense GQA decoder, the latent-attention expert decoder, the
+window-and-global expert decoder (two kinds of cache), and the switch family
+that only the benchmark's tests use.  At toy width on the CPU:
 a family's leaf names spell the program's pytree, its program agrees with its
 plain reference, its counts are the pytree's sizes, and only the family with
 routed experts reads a tick's tokens.  Then the new cell's rehearsal."""
@@ -21,8 +22,8 @@ _TESTS_FAMILIES = os.path.join(spec.BENCH_DIR, "tests", "families")
 if _TESTS_FAMILIES not in spec.FAMILY_DIRS:
     spec.FAMILY_DIRS.append(_TESTS_FAMILIES)
 
-FAMILIES = ["dense_gqa", "moe_switch", "latent_moe"]
-ROUTED_BY_TOKENS = {"latent_moe"}
+FAMILIES = ["dense_gqa", "moe_switch", "latent_moe", "swa_moe"]
+ROUTED_BY_TOKENS = {"latent_moe", "swa_moe"}
 SEED = 2**31 + 27
 
 
@@ -30,8 +31,22 @@ def _toy(family):
     if family == "moe_switch":
         return spec.family({"family": family}).TOY
     cell = {"dense_gqa": "serve-decode",
-            "latent_moe": "serve-moe-mla-decode"}[family]
+            "latent_moe": "serve-moe-mla-decode",
+            "swa_moe": "serve-moe-swa-longdoc"}[family]
     return spec.tiny(spec.cell(cell)[1])
+
+
+def _cache(model, cfg, blocks, size, dtype=None):
+    """(cache of ``blocks`` blocks a kind, the table of one row that owns
+    them in order): one pool and one table, or one of each a cache kind for
+    a module that declares kinds (a ring as long as the whole context)."""
+    import jax.numpy as jnp
+    table = jnp.arange(blocks, dtype=jnp.int32)[None]
+    if not hasattr(model, "cache_kinds"):
+        return model.init_cache(cfg, blocks, size, dtype=dtype), table
+    kinds = [k.name for k in model.cache_kinds(cfg)]
+    return (model.init_cache(cfg, dict.fromkeys(kinds, blocks), size,
+                             dtype=dtype), dict.fromkeys(kinds, table))
 
 
 def test_each_configuration_finds_its_family_file():
@@ -111,10 +126,9 @@ def test_the_program_agrees_with_the_reference_at_toy_width(family):
         weights.seed_key(SEED))
     T, size = 48, 4
     row = np.random.default_rng(11).integers(0, config["vocab_size"], (1, T))
-    table = np.arange(T // size, dtype=np.int32)[None]
+    cache, table = _cache(model, cfg, T // size, size)
     got = model.apply_cached(
-        params, jnp.asarray(row, jnp.int32), cfg,
-        model.init_cache(cfg, T // size, size), jnp.asarray(table),
+        params, jnp.asarray(row, jnp.int32), cfg, cache, table,
         jnp.zeros((1,), jnp.int32), jnp.full((1,), T, jnp.int32))[0]
     w = reference.Weights(config, SEED)
     x = reference.hidden_states(config, w, row)
@@ -138,9 +152,21 @@ def test_the_counts_are_the_pytrees_sizes_and_the_programs_cache(family):
     assert 0 < n["matmul"] <= n["total"] - n["embed"] - vectors
     model, cfg = fam.program(config)
     blocks, size = 6, 4
-    pool = model.init_cache(cfg, blocks, size, dtype=jnp.bfloat16)
-    held = sum(x.size * x.dtype.itemsize for x in pool.values())
-    assert fam.cache_bytes_per_position(config, 2) * blocks * size == held
+    import jax
+    pool, _ = _cache(model, cfg, blocks, size, dtype=jnp.bfloat16)
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree_util.tree_leaves(pool))
+    if hasattr(fam, "cache_bytes_per_position_per_layer"):
+        # layers of several cache kinds: what a layer HOLDS a position is
+        # one number, what a new token READS a position of its context is
+        # reckoned at a stated context and is less (a window layer reads its
+        # window of it)
+        a_layer = fam.cache_bytes_per_position_per_layer(config, 2)
+        layers = config["num_hidden_layers"]
+        assert a_layer * layers * blocks * size == held
+        assert 0 < fam.cache_bytes_per_position(config, 2) <= a_layer * layers
+    else:
+        assert fam.cache_bytes_per_position(config, 2) * blocks * size == held
     assert fam.attn_flops_per_position(config) > 0
 
 
@@ -183,16 +209,150 @@ def test_the_published_cut_is_the_issues_arithmetic():
         >= config["engine"]["max_slots"] * config["engine"]["max_seq_len"]
 
 
-def test_the_new_cells_rehearsal_passes():
+def test_the_swa_cut_is_the_issues_arithmetic():
+    """ISSUE 31's reckoning, held to the configuration file: 3,966,937,600
+    parameters (7.93 GB in bfloat16), 2,048 B a cached position a layer,
+    every published width, 64 experts, 6 a token, the whole vocabulary, the
+    depth alone reduced, and 9.81 GB resident in the deployment's pools."""
+    entry, config, traffic = spec.cell("serve-moe-swa-longdoc")
+    fam = spec.family(config)
+    bench = spec.benchmark()
+    listed = next(c for c in bench["configs"] if c["name"] == entry["config"])
+    assert listed["reduced"] == list(config["reduced"]) == ["num_hidden_layers"]
+    catalog = {"head_dim": 128, "hidden_size": 2560, "moe_ffn_hidden_size": 768,
+               "moe_num_active_primary_experts": 6,
+               "moe_num_primary_experts": 64, "num_attention_heads": 28,
+               "num_key_value_heads": 4, "sliding_window_size": 4096,
+               "vocab_size": 151936, "max_position_embeddings": 16384,
+               "rope_theta": 1500000, "rms_norm_eps": 1e-06}
+    assert {k: config[k] for k in catalog} == catalog
+    assert config["rope_layout"] == config["sliding_window_layout"] \
+        == [0, 1, 1, 1] * 13 and config["num_hidden_layers"] == 8
+    assert fam.layer_kinds(config) == ["global", "window+rope", "window+rope",
+                                       "window+rope"] * 2
+    n, dep, e = fam.param_counts(config), config["deployment"], config["engine"]
+    assert n["total"] == dep["parameters"] == 3_966_937_600
+    assert n["total"] == sum(math.prod(s) for _, s, _ in fam.leaf_specs(config))
+    assert dep["weight_bytes"] == 2 * n["total"]
+    assert round(2 * n["total"] / 1e9, 2) == 7.93
+    assert fam.cache_bytes_per_position_per_layer(config, 2) \
+        == dep["cache_bytes_per_position_per_layer"] == 2048
+    # the pools: the global kind covers every slot at full length, the window
+    # kind a ring of the window plus one chunk a slot (the program's bound)
+    from horovod_tpu.models import paged
+    ring = e["block_size"] * paged.ring_blocks(
+        config["sliding_window_size"], e["prefill_chunk"], e["block_size"],
+        -(-e["max_seq_len"] // e["block_size"]))
+    assert ring == fam.ring_positions(config) == 4096 + 512
+    assert e["cache_blocks"] * e["block_size"] == 16 * 14848 \
+        == e["max_slots"] * e["max_seq_len"]
+    assert dep["global_pool_bytes"] == 2 * 16 * 14848 * 2048
+    assert dep["window_pool_bytes"] == 6 * e["max_slots"] * ring * 2048
+    assert dep["resident_bytes"] == dep["weight_bytes"] \
+        + dep["global_pool_bytes"] + dep["window_pool_bytes"] >= 9e9
+    assert e["max_seq_len"] == traffic["prompt_len"]["max"] \
+        + traffic["output_len"]["max"]
+    assert traffic["prompt_len"]["min"] > config["sliding_window_size"]
+    assert e["prefix_cache"] is False
+    # what a new token reads of a position of context, at the stated context
+    read = fam.cache_bytes_per_position(config, 2)
+    assert read == pytest.approx(2048 * (2 + 6 * 4096 / fam.MEAN_LIVE_CONTEXT))
+    assert fam.attn_flops_per_position(config) == pytest.approx(
+        4 * 28 * 128 * read / 2048)
+    assert fam.pool_op_types(config, "window") == [
+        "[6,4608,16,4,128]", "[4608,16,4,128]", "[288,16,4,128]",
+        ",288,16,4,128]", ",4608,4,128]"]
+    assert fam.pool_op_types(config, "global") == [
+        "[2,14848,16,4,128]", "[14848,16,4,128]", "[928,16,4,128]",
+        ",928,16,4,128]", ",14848,4,128]"]
+    assert fam.tick_columns(config) == [5, 8, 512]
+
+
+def test_the_parent_process_loads_the_swa_family_without_jax():
+    code = ("import sys; from perfbench.lib import peaks, spec\n"
+            "_, c, _ = spec.cell('serve-moe-swa-longdoc'); f = spec.family(c)\n"
+            "spec.tiny(c); f.param_counts(c); f.pool_op_types(c, 'window')\n"
+            "f.ring_positions(c)\n"
+            "m = {'marks': {k: {'stats': {}, 'tick': 0} for k in ('start', 'end')}}\n"
+            "assert f.window_counts(m) is None and f.ring_counts(m) is None\n"
+            "peaks.serve_required_seconds(c, peaks.PEAKS['TPU v5 lite'], 9, 9, 1)\n"
+            "f.window_attn_required_seconds(c, peaks.PEAKS['TPU v5 lite'], 16, 16, 65536)\n"
+            "assert 'jax' not in sys.modules and 'numpy' not in sys.modules\n"
+            # the two functions that ask the program (its tile's rows, its
+            # narrow columns), when a traced run's children are gone: they
+            # start no backend
+            "assert ',4608]' in f.window_attn_op_types(c)\n"
+            "types = f.expert_op_types(c)\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge._backends, 'a backend was started'\n"
+            "print(types)")
+    out = subprocess.run([sys.executable, "-c", code], check=True,
+                         cwd=spec.ROOT, timeout=60, capture_output=True,
+                         text=True)
+    from horovod_tpu.models import swa_moe
+    assert out.stdout.strip() == str(
+        [f"[{swa_moe.EXPERT_TILE},768]", f"[{swa_moe.EXPERT_TILE},2560]"])
+
+
+def test_the_swa_readers_read_a_trace_and_the_rings_counters():
+    """The five readers the cell brings, on a made-up trace and marks: pool
+    ops by kind, the window attention's share, the resident share, the
+    experts touched; each None where there is nothing to read."""
+    _, config, _ = spec.cell("serve-moe-swa-longdoc")
+    fam = spec.family(config)
+    ring = {"slot_ticks": 160, "resident_position_ticks": 4608 * 100,
+            "window_position_ticks": 4096 * 160, "full_position_ticks": 9000 * 100}
+    moe = {"ticks": 10, "assignments": 960, "assignments_held": 960,
+           "experts_touched": 4000, "load_max": 30}
+    mark = lambda t, r, m: {"tick": t, "tokens_prefill": 0, "tokens_decode": 16 * t,
+                            "stats": {"moe": m, "kv_pool": {"kinds": {"window": r}}}}
+    # short names as PR 31's first trace has them
+    ops = {"fusion bf16[4608,16,4,128]": 0.003, "fusion f32[16,4,7,5,4608]": 0.002,
+           "fusion bf16[16,4,128,7,5]": 0.0006, "fusion f32[4,7,512]": 0.0004,
+           "fusion bf16[6,4608,16,4,128]": 0.0005,
+           "fusion bf16[14848,16,4,128]": 0.007, "fusion bf16[64,768]": 0.004,
+           "expert_tile_ffn f32[64,2560]": 0.006, "while s32[]": 0.001}
+    ctx = {"config": config, "peaks": {"hbm_gbps": 819.0, "bf16_tflops": 197.0},
+           "marks": {"start": mark(0, dict.fromkeys(ring, 0), dict.fromkeys(moe, 0)),
+                     "end": mark(10, ring, moe)},
+           "trace": {"module_count": 5.0, "ops_s": ops}}
+    read = lambda name: spec.metric_reader(name)(ctx)
+    assert read("swa.window_pool_ops_ms.serve") == pytest.approx(
+        1e3 * 0.0035 / 5)
+    assert read("swa.global_pool_ops_ms.serve") == pytest.approx(1e3 * 0.007 / 5)
+    need, bound = fam.window_attn_required_seconds(
+        config, ctx["peaks"], 80, 80, 4096 * 80)
+    assert bound == "bytes" and need == pytest.approx(
+        6 * 4096 * 80 * 2048 / 819e9)
+    assert read("swa.window_attn_roofline_share.serve") == pytest.approx(
+        100 * need / 0.006)
+    assert read("kv.window_resident_share.serve") == pytest.approx(51.2)
+    assert read("moe.experts_touched_per_layer.serve") == pytest.approx(50.0)
+    assert read("moe.expert_roofline_share.serve") is not None
+    bare = dict(ctx, trace=None, marks={k: {"tick": 0, "stats": {}}
+                                        for k in ("start", "end")})
+    for name in ("swa.window_pool_ops_ms.serve", "swa.global_pool_ops_ms.serve",
+                 "swa.window_attn_roofline_share.serve",
+                 "kv.window_resident_share.serve",
+                 "moe.experts_touched_per_layer.serve"):
+        assert spec.metric_reader(name)(bare) is None, name
+
+
+@pytest.mark.parametrize("cell,metrics", [
+    ("serve-moe-mla-decode", ("moe.experts_touched.serve",
+                              "moe.load_max_over_mean.serve",
+                              "engine.tick_ms.serve")),
+    ("serve-moe-swa-longdoc", ("kv.window_resident_share.serve",
+                               "moe.experts_touched_per_layer.serve",
+                               "engine.tick_ms.serve"))])
+def test_the_new_cells_rehearsal_passes(cell, metrics):
     out = subprocess.run(
-        [sys.executable, "perfbench/run.py", "--workload",
-         "serve-moe-mla-decode", "--seed", str(2**32 + 15), "--seconds", "6",
-         "--trace", "1", "--dry-run", "1"], cwd=spec.ROOT, timeout=600,
-        capture_output=True, text=True)
+        [sys.executable, "perfbench/run.py", "--workload", cell, "--seed",
+         str(2**32 + 15), "--seconds", "6", "--trace", "1", "--dry-run", "1"],
+        cwd=spec.ROOT, timeout=600, capture_output=True, text=True)
     assert out.returncode == 0, out.stdout[-3000:] + out.stderr[-3000:]
     line = json.loads(out.stdout.strip().splitlines()[-1])
     assert line["correct"] and line["failed"] == 0
     assert line["device"]["platform"] == "cpu"
-    for name in ("moe.experts_touched.serve", "moe.load_max_over_mean.serve",
-                 "engine.tick_ms.serve"):
+    for name in metrics:
         assert name in line["metrics"], sorted(line["metrics"])
