@@ -32,12 +32,12 @@ counts; ServeEngine sums it into ``stats()["moe"]``).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from . import layers as L
 from . import paged
@@ -102,6 +102,9 @@ NARROW_COLS = 8
 
 #: what apply_cached's third value counts, summed over the expert layers
 TICK_COUNTERS = ("ticks",) + X.HELD_COUNTERS
+#: the cached attention reads a slot's context as far as it reaches, no
+#: farther (paged.attend_by_blocks with a Bound)
+BOUNDED_READ = True
 
 
 # ----------------------------------------------------------------- weights
@@ -260,45 +263,28 @@ def attn_blocks(cfg: LatentMoeConfig, S: int, C: int, ctx: int
             NARROW_COLS)
 
 
-def _latent_attention(q: jax.Array, lat: jax.Array, positions: jax.Array,
-                      n_new: jax.Array, cfg: LatentMoeConfig) -> jax.Array:
-    """Absorbed attention of q [S, C, H, kv_rank + rope] (``q_nope W^K``
-    beside the rotated ``q_rope``) over each slot's gathered latent context
-    lat [S, ctx, kv_rank + rope]: scores against the latent, softmax in
-    float32, values the latent's ``c_kv`` part.  Returns [S, C, H, kv_rank].
-    Computed a block of slots after another so that the float32 scores stay
-    under ``SCORE_BYTES``."""
-    S, C, H, _ = q.shape
-    ctx = lat.shape[1]
-    scale = 1.0 / math.sqrt(cfg.qk_dim)
-    fill = jnp.finfo(jnp.float32).min
+def _latent_tile(kv_rank: int, scale: float, q, pos, ctx, start):
+    """One tile of a block of slots' absorbed attention, as
+    paged.attend_by_blocks runs it: q [s, c, H, kv_rank + rope] (``q_nope
+    W^K`` beside the rotated ``q_rope``) against the tile's latent
+    ``ctx["latent"]`` [s, keys, kv_rank + rope], whose first key is position
+    ``start``: float32 scores [s, H, c, keys], masked, and the value product
+    with the latent's ``c_kv`` part [s, H, c, kv_rank]."""
+    lat = ctx["latent"]
+    s = jnp.einsum("schx,skx->shck", q, lat,
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where(paged.context_mask(pos - start, lat.shape[1]), s,
+                  jnp.finfo(jnp.float32).min)
+    return s, lambda p: jnp.einsum(
+        "shck,skl->shcl", p.astype(lat.dtype), lat[..., :kv_rank],
+        preferred_element_type=jnp.float32)
 
-    def attend(q, lat, pos):
-        s = jnp.einsum("schx,skx->shck", q, lat,
-                       preferred_element_type=jnp.float32) * scale
-        pr = jax.nn.softmax(jnp.where(paged.context_mask(pos, ctx), s, fill),
-                            -1).astype(q.dtype)
-        return jnp.einsum("shck,skl->schl", pr, lat[..., :cfg.kv_rank])
 
-    def block(args):
-        q, lat, pos, nn = args
-        if C <= NARROW_COLS:
-            return attend(q, lat, pos)
-        W = NARROW_COLS
-
-        def narrow():
-            o = attend(q[:, :W], lat, pos[:, :W])
-            return jnp.pad(o, ((0, 0), (0, C - W), (0, 0), (0, 0)))
-        # rows past a slot's n_new are padding that nothing reads: a block
-        # of decode rows in a prefill-width tick attends in W columns
-        return lax.cond(jnp.max(nn) > W, lambda: attend(q, lat, pos), narrow)
-
-    sb = attn_blocks(cfg, S, C, ctx)[0]
-    if sb == S:
-        return block((q, lat, positions, n_new))
-    split = lambda a: a.reshape((S // sb, sb) + a.shape[1:])
-    o = lax.map(block, tuple(map(split, (q, lat, positions, n_new))))
-    return o.reshape((S,) + o.shape[2:])
+def latent_attend(cfg: LatentMoeConfig):
+    """:func:`_latent_tile` for ``cfg``: ONE object a tick, which every
+    layer hands paged.attend_by_blocks (its trace is shared by the layers)."""
+    return functools.partial(_latent_tile, cfg.kv_rank,
+                             1.0 / math.sqrt(cfg.qk_dim))
 
 
 def apply_cached(params: Dict[str, Any], tokens: jax.Array,
@@ -311,35 +297,24 @@ def apply_cached(params: Dict[str, Any], tokens: jax.Array,
     at positions that were not packed —, updated cache, counters
     int32[len(TICK_COUNTERS)] summed over the expert layers)."""
     S, C = tokens.shape
-    T = S * C
     cos, sin = L.rope_freqs(cfg.qk_rope_dim, cfg.max_seq, cfg.rope_theta)
     positions, valid = paged.slot_positions(lengths, n_new, C)
     blk, off = paged.write_index(block_tables, positions, valid,
                                  *cache["latent"].shape[1:3])
-    # Everything but the attention's core is a token's own: it runs on ROWS,
-    # the slab's [S * C] positions, or — when the engine promises fewer valid
-    # tokens a tick than the slab has positions (``max_tick_tokens``) — on the
-    # valid ones packed to the front (a stable sort keeps the slab's order), so
-    # that a prefill-wide tick does not push every slot's padding through
-    # every matrix.
-    R = min(T, cfg.max_tick_tokens or T)
-    flat = lambda a: a.reshape((T,) + a.shape[2:])
-    rows = (jnp.argsort(~flat(valid), stable=True)[:R] if R < T
-            else jnp.arange(T))
-    take = lambda a: flat(a)[rows][None]                # [S, C, ..] -> [1, R, ..]
-    row_valid, row_pos, row_blk, row_off = map(
-        take, (valid, positions, blk, off))
-    pos_c = jnp.minimum(row_pos, cfg.max_seq - 1)
+    # Everything but the attention's core is a token's own and runs on the
+    # tick's ROWS (paged.pack): the valid positions packed to the front when
+    # the engine promises fewer of them than the slab has positions, so that
+    # a prefill-wide tick does not push every slot's padding through every
+    # matrix.  Only the absorbed queries go back to their slots.
+    take, slab = paged.pack(valid, cfg.max_tick_tokens)
+    row_valid, row_blk, row_off = map(take, (valid, blk, off))
+    pos_c = take(jnp.minimum(positions, cfg.max_seq - 1))
+    rows = pos_c.shape[:2]
+    blocks = attn_blocks(cfg, S, C,
+                         block_tables.shape[1] * cache["latent"].shape[2])
+    attend = latent_attend(cfg)
     with jax.named_scope("embed"):
         x = L.embedding(params["embed"], take(tokens)).astype(cfg.dtype)
-
-    def slab(a):
-        """Rows [1, R, ..] back at their places in a zero [S, C, ..] slab."""
-        if R == T:
-            return a.reshape((S, C) + a.shape[2:])
-        z = jnp.zeros((T,) + a.shape[2:], a.dtype)
-        return z.at[rows].set(a[0], unique_indices=True).reshape(
-            (S, C) + a.shape[2:])
 
     counters = jnp.zeros(len(X.HELD_COUNTERS), jnp.int32)
     for i, p in enumerate(params["layers"]):
@@ -347,19 +322,20 @@ def apply_cached(params: Dict[str, Any], tokens: jax.Array,
         q_nope, q_rope, latent = _project(a, _norm(p["input_norm"], x, cfg),
                                           cfg, cos, sin, pos_c)
         cache = paged.write(
-            cache, i, row_blk[0], row_off[0],
-            {"latent": latent[0].astype(cache["latent"].dtype)})
+            cache, i, row_blk, row_off,
+            {"latent": latent.astype(cache["latent"].dtype)})
         wk, wv = _wkv_b(a, cfg)
-        # gathered outside the slot-block loop: a pool that a loop holds is
-        # copied whole
-        lat = paged.gather(cache, i, block_tables)["latent"]
         with jax.named_scope("attn/latent_scores"):
             q = jnp.concatenate(
                 [jnp.einsum("brhn,lhn->brhl", q_nope, wk), q_rope], -1)
-            o = take(_latent_attention(slab(q), lat, positions, n_new, cfg))
+            o = paged.attend_by_blocks(
+                attend, (q, positions, block_tables), n_new, *blocks,
+                bound=paged.Bound(lengths, cache, i, slab))
+            # [S, H, C, kv_rank] -> the rows
+            o = take(jnp.swapaxes(o, 1, 2))
         with jax.named_scope("attn/out"):
             o = jnp.einsum("brhl,lhv->brhv", o, wv)
-            o = L.dense(a["wo"], o.reshape(1, R, cfg.n_heads * cfg.v_dim))
+            o = L.dense(a["wo"], o.reshape(rows + (cfg.n_heads * cfg.v_dim,)))
             x = x + _norm(p["post_attn_norm"], o, cfg)
         m, c = _mlp(p, _norm(p["pre_mlp_norm"], x, cfg), row_valid, cfg)
         x = x + _norm(p["post_mlp_norm"], m, cfg)
@@ -382,6 +358,7 @@ def param_count(cfg: LatentMoeConfig) -> int:
             + (cfg.n_layers - cfg.n_dense) * routed + 2 * cfg.vocab * d + d)
 
 
-__all__ = ["LatentMoeConfig", "CONFIGS", "TICK_COUNTERS", "init", "apply",
+__all__ = ["LatentMoeConfig", "CONFIGS", "TICK_COUNTERS", "BOUNDED_READ",
+           "init", "apply",
            "init_cache", "cache_shardings", "copy_blocks", "apply_cached",
            "attn_blocks", "param_count"]

@@ -250,3 +250,22 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                            axis=-1).astype(q.dtype)
     o = jnp.einsum("bhrqk,bkhd->bqhrd", probs, v)
     return o.reshape(B, S, H, v.shape[-1])  # v's own width (latent heads)
+
+
+def attention_tile(q: jax.Array, k: jax.Array, v: jax.Array,
+                   mask: jax.Array):
+    """One TILE of keys of an attention whose softmax runs across tiles
+    (models/paged.py ``attend_by_blocks``): q [B, S, H, D], k/v [B, K, Hkv,
+    D] grouped as :func:`causal_attention` groups them, mask [B, 1, S, K].
+    Returns ``(scores, weigh)``: float32 scores [B, Hkv, rep, S, K], scaled,
+    masked with float32's minimum; ``weigh(p)`` the float32 value product
+    [B, Hkv, rep, S, Dv] of probabilities shaped like the scores, which meet
+    the values in the values' dtype."""
+    B, S, H, D = q.shape
+    Hkv = k.shape[2]
+    qg = q.reshape(B, S, Hkv, H // Hkv, D)
+    s = jnp.einsum("bqhrd,bkhd->bhrqk", qg, k,
+                   preferred_element_type=jnp.float32) * (1.0 / math.sqrt(D))
+    s = jnp.where(jnp.expand_dims(mask, 2), s, jnp.finfo(jnp.float32).min)
+    return s, lambda p: jnp.einsum("bhrqk,bkhd->bhrqd", p.astype(v.dtype), v,
+                                   preferred_element_type=jnp.float32)

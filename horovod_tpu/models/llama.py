@@ -265,6 +265,9 @@ def cache_shardings(mesh, cfg: LlamaConfig, num_blocks: int):
 
 copy_blocks = paged.copy_blocks
 TICK_COUNTERS = ()      # the tick counts nothing beside its logits
+#: the cached attention reads a slot's context as far as it reaches, no
+#: farther (paged.attend_by_blocks with a Bound)
+BOUNDED_READ = True
 #: float32 scores one block of slots may hold (heads x columns x context x 4
 #: B a slot): internlm2-1.8b's [16, 128] tick over 2,048 positions attends
 #: two slots at a time, its [16, 5] tick all sixteen at once
@@ -285,9 +288,11 @@ def attn_blocks(cfg: LlamaConfig, S: int, C: int, ctx: int
 class _Tick(NamedTuple):
     """What the layers of one tick share (:func:`_tick`)."""
     positions: jax.Array    # [S, C] (paged.slot_positions)
+    lengths: jax.Array      # [S] positions a slot held before the tick
     n_new: jax.Array        # [S]
     take: Callable          # [S, C, ...] -> rows [1, R, ...] (paged.pack)
-    slab: Callable          # rows -> [S, C, ...], zero where left out
+    slab: Callable          # rows -> [S, C, ...] (or a block of it), zero
+                            # where left out
     pos: jax.Array          # the rows' positions, inside the rope table
     blk: jax.Array          # where the rows land (paged.write_index)
     off: jax.Array
@@ -302,9 +307,18 @@ def _tick(cfg: LlamaConfig, cache: Dict[str, jax.Array],
     blk, off = paged.write_index(block_tables, positions, valid,
                                  *cache["k"].shape[1:3])
     take, slab = paged.pack(valid, cfg.max_tick_tokens)
-    return _Tick(positions, n_new, take, slab,
+    return _Tick(positions, lengths, n_new, take, slab,
                  take(jnp.minimum(positions, cfg.max_seq - 1)),
                  take(blk), take(off))
+
+
+def _attend_tile(q, pos, ctx, start):
+    """One tile of a block of slots' cached attention (paged.attend_by_blocks
+    with a bound): the tile's keys and values ``ctx`` begin at position
+    ``start``."""
+    return L.attention_tile(
+        q, ctx["k"], ctx["v"],
+        paged.context_mask(pos - start, ctx["k"].shape[1]))
 
 
 def _attn_cached(p: Dict[str, Any], x: jax.Array, cfg: LlamaConfig,
@@ -321,10 +335,11 @@ def _attn_cached(p: Dict[str, Any], x: jax.Array, cfg: LlamaConfig,
     chunk of C new token positions; prefill consumes whole chunks, decode
     uses C with one valid token).  The rows' k/v are
     scattered into the pool FIRST, then the queries go back to their slots
-    and each attends over its slot's full gathered context with a
-    per-position causal mask — so a single compiled step serves mixed
-    prefill/decode ticks, a block of slots after another and only the
-    blocks that hold a chunk at chunk width (paged.attend_by_blocks).
+    and each attends over its slot's context with a per-position causal
+    mask — so a single compiled step serves mixed prefill/decode ticks, a
+    block of slots after another: only the blocks that hold a chunk at chunk
+    width, and each a tile of context after another as far as its slots'
+    contexts reach (paged.attend_by_blocks with a bound).
     Projections always take the unfused path (fuse_proj is a
     training-throughput lever; TP shards the separate kernels)."""
     rows = x.shape[:2]
@@ -333,16 +348,14 @@ def _attn_cached(p: Dict[str, Any], x: jax.Array, cfg: LlamaConfig,
     k = L.apply_rope_at(heads("wk", cfg.n_kv_heads), cos, sin, t.pos)
     cache = paged.write(cache, layer, t.blk, t.off,
                         {"k": k, "v": heads("wv", cfg.n_kv_heads)})
-    n_ctx = block_tables.shape[1] * cache["k"].shape[2]
-
-    def attend(q, pos, tables):
-        ctx = paged.gather(cache, layer, tables)
-        return L.causal_attention(q, ctx["k"], ctx["v"], causal=False,
-                                  mask=paged.context_mask(pos, n_ctx))
     o = paged.attend_by_blocks(
-        attend, (t.slab(q), t.positions, block_tables), t.n_new,
-        *attn_blocks(cfg, *t.positions.shape, n_ctx))
-    return L.dense(p["wo"], t.take(o).reshape(rows + (-1,))), cache
+        _attend_tile, (q, t.positions, block_tables), t.n_new,
+        *attn_blocks(cfg, *t.positions.shape,
+                     block_tables.shape[1] * cache["k"].shape[2]),
+        bound=paged.Bound(t.lengths, cache, layer, t.slab))
+    # [S, Hkv, rep, C, head_dim] -> the rows
+    o = t.take(jnp.moveaxis(o, 3, 1))
+    return L.dense(p["wo"], o.reshape(rows + (-1,))), cache
 
 
 def apply_cached(params: Dict[str, Any], tokens: jax.Array,

@@ -169,7 +169,7 @@ def dropfree_moe_fn(cfg: MoeLlamaConfig) -> Callable:
 # The attention half IS llama's, so the paged pool, its copy-on-write clone
 # and its sharding are too (they read n_layers, n_kv_heads, head_dim, dtype).
 init_cache, copy_blocks = Ll.init_cache, Ll.copy_blocks
-cache_shardings = Ll.cache_shardings
+cache_shardings, BOUNDED_READ = Ll.cache_shardings, Ll.BOUNDED_READ
 #: ServeEngine ignores the third value of apply_cached, the router aux
 TICK_COUNTERS = ()
 
@@ -221,5 +221,5 @@ def param_count(cfg: MoeLlamaConfig) -> int:
 
 __all__ = ["MoeLlamaConfig", "CONFIGS", "init", "apply", "loss_fn",
            "param_count", "init_cache", "apply_cached", "copy_blocks",
-           "cache_shardings", "TICK_COUNTERS", "attn_blocks",
+           "cache_shardings", "TICK_COUNTERS", "BOUNDED_READ", "attn_blocks",
            "dropfree_moe_fn"]

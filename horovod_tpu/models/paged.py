@@ -21,6 +21,7 @@ position P lands in entry ``(P // bs) % entries``, so a slot holds the last
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -62,31 +63,52 @@ def slot_positions(lengths: jax.Array, n_new: jax.Array, C: int
     return positions, jnp.arange(C)[None, :] < n_new[:, None]
 
 
-def pack(valid: jax.Array, budget: int) -> Tuple[Callable, Callable]:
+def block_of(a: jax.Array, i, per: int, cols: int) -> jax.Array:
+    """Slots ``i * per .. (i + 1) * per - 1`` of ``a`` [S, C, ...] and their
+    first ``cols`` columns (``i`` may be a device value)."""
+    return lax.dynamic_slice(a, (i * per, 0) + (0,) * (a.ndim - 2),
+                             (per, cols) + a.shape[2:])
+
+
+class Slab(NamedTuple):
+    """:func:`pack`'s ``slab``: ``slab(a)`` brings rows ``[1, R, ...]`` back
+    to their places in ``[S, C, ...]``, ZERO where a position was left out;
+    ``slab(a, i, per, cols)`` only to :func:`block_of` that slab.  ``rows``
+    [S, C] is the row of each position (None where nothing is packed: the
+    rows ARE the slab), ``kept`` the number of rows."""
+    rows: Optional[jax.Array]
+    kept: Optional[int]
+
+    def __call__(self, a: jax.Array, *block) -> jax.Array:
+        if self.rows is None:
+            return block_of(a, *block) if block else a
+        rows = block_of(self.rows, *block) if block else self.rows
+        back = a[0][jnp.minimum(rows, self.kept - 1)]
+        kept = (rows < self.kept).reshape(rows.shape + (1,) * (a.ndim - 2))
+        return jnp.where(kept, back, jnp.zeros((), a.dtype))
+
+
+def pack(valid: jax.Array, budget: int) -> Tuple[Callable, Slab]:
     """(take, slab) for a tick whose plan holds at most ``budget`` tokens
     (the scheduler's ``max_batch_tokens``; 0 = no promise): what is a
     token's own — embedding, norms, projections, rotary, the FFN, the head,
     the pool's write — runs on ``R = min(S * C, budget)`` ROWS, the valid
     positions packed to the front in slab order (a stable sort), and only
     the attention's core sees slots.  ``take`` brings ``[S, C, ...]`` to
-    ``[1, R, ...]``; ``slab`` brings rows back to their places in
-    ``[S, C, ...]``, ZERO where a position was left out, so what comes back
-    is defined at valid positions only.  A slab no larger than the budget
-    is not packed: both are the identity and the rows are ``[S, C, ...]``."""
+    ``[1, R, ...]``; ``slab`` (:class:`Slab`) brings rows back to their
+    places, whole or a block of slots' columns at a time, so that a loop
+    over blocks never makes the whole of a wide one; what comes back is
+    defined at valid positions only.  A slab no larger than the budget is
+    not packed: both are the identity and the rows are ``[S, C, ...]``."""
     S, C = valid.shape
     T = S * C
     R = min(T, budget or T)
     if R == T:
-        return (lambda a: a), (lambda a: a)
+        return (lambda a: a), Slab(None, None)
     flat = lambda a: a.reshape((T,) + a.shape[2:])
     order = jnp.argsort(~flat(valid), stable=True)     # positions, by row
     row_of = jnp.argsort(order).reshape(S, C)           # rows, by position
-
-    def slab(a):
-        back = a[0][jnp.minimum(row_of, R - 1)]
-        kept = (row_of < R).reshape((S, C) + (1,) * (a.ndim - 2))
-        return jnp.where(kept, back, jnp.zeros((), a.dtype))
-    return (lambda a: flat(a)[order[:R]][None]), slab
+    return (lambda a: flat(a)[order[:R]][None]), Slab(row_of, R)
 
 
 def write_index(block_tables: jax.Array, positions: jax.Array,
@@ -129,7 +151,9 @@ def gather(pool: Any, layer: int, block_tables: jax.Array) -> Any:
 
 def context_mask(positions: jax.Array, ctx: int) -> jax.Array:
     """[S, 1, C, ctx] bool: the query at ``positions[s, c]`` sees gathered
-    keys ``0 .. positions[s, c]`` (its own, written first, included)."""
+    keys ``0 .. positions[s, c]`` (its own, written first, included).  Over
+    a tile of a bounded read that begins at position ``start``, hand it
+    ``positions - start``."""
     return (jnp.arange(ctx)[None, None, :] <= positions[:, :, None])[:, None]
 
 
@@ -151,25 +175,133 @@ def window_mask(positions: jax.Array, key_pos: jax.Array,
     return ((d >= 0) & (d < window) & (key_pos[:, None, :] >= 0))[:, None]
 
 
+def _divisor(S: int, want: int) -> int:
+    """The largest divisor of S that is at most ``want`` (at least 1)."""
+    return max(b for b in range(1, S + 1) if S % b == 0 and b <= max(want, 1))
+
+
 def slots_per_block(S: int, per_slot_bytes: int, budget: int) -> int:
     """The largest divisor of S whose scores fit the budget (at least 1)."""
-    want = max(1, budget // max(per_slot_bytes, 1))
-    return max(b for b in range(1, S + 1) if S % b == 0 and b <= want)
+    return _divisor(S, budget // max(per_slot_bytes, 1))
+
+
+#: Positions of context that one step of a bounded read gathers, masks and
+#: scores (a whole number of pool blocks, :func:`tile_blocks`), and the slots
+#: whose first ``narrow`` columns read together, the longest context among
+#: them bounding all.  Both by a probe at the serving cells' shapes (PERF.md
+#: §6, PR 32): a smaller step reads less that nobody holds, and pays for
+#: itself more often.
+TILE = 256
+NARROW_SLOTS = 2
+
+
+def tile_blocks(block_size: int, max_blocks: int) -> int:
+    """Table entries that a tile of a bounded read covers."""
+    return min(max(TILE // block_size, 1), max_blocks)
+
+
+def narrow_slots(S: int) -> int:
+    """Slots a block of a bounded read's first pass (a divisor of S)."""
+    return _divisor(S, NARROW_SLOTS)
+
+
+def block_tiles(lengths, n_new, slots: int, tile: int):
+    """Tiles of ``tile`` positions that each block of ``slots`` slots reads
+    in a bounded read: as far as the longest context a LIVE slot of the
+    block (``n_new > 0``) holds once the tick's writes are in, and none
+    where no slot is live.  The device's trip counts and the host's count of
+    them (:func:`read_counts`) are this one function, on jax or numpy
+    arrays."""
+    held = ((lengths + n_new) * (n_new > 0)).reshape(-1, slots).max(axis=1)
+    return -(-held // tile)
+
+
+def _bounded(tile: Callable, tiles: jax.Array, stat: Tuple[int, ...],
+             acc: Tuple[int, ...], dtype) -> jax.Array:
+    """One block of slots over its first ``tiles`` tiles, an online softmax:
+    ``tile(t)`` -> (float32 scores ``stat + (keys,)``, ``weigh``) is folded,
+    a tile after another, into a running float32 maximum, denominator and
+    value sum, so the scores of one tile are all that exists at a time.
+    ``acc``-shaped, in ``dtype``; zero where nothing was read."""
+    def step(t, carry):
+        m, l, acc = carry
+        s, weigh = tile(t)
+        top = jnp.maximum(m, jnp.max(s, -1))
+        p = jnp.exp(s - jnp.expand_dims(top, -1))
+        keep = jnp.exp(m - top)
+        return (top, keep * l + jnp.sum(p, -1),
+                jnp.expand_dims(keep, -1) * acc + weigh(p))
+    _, l, acc = lax.fori_loop(
+        0, tiles, step,
+        (jnp.full(stat, jnp.finfo(jnp.float32).min, jnp.float32),
+         jnp.zeros(stat, jnp.float32), jnp.zeros(acc, jnp.float32)))
+    return (acc / jnp.expand_dims(jnp.where(l > 0, l, 1.0), -1)).astype(dtype)
+
+
+class Bound(NamedTuple):
+    """What a caller hands :func:`attend_by_blocks` to have its read bounded
+    by what the slots hold: the positions each slot held before the tick
+    (``lengths`` [S]), the stacked ``pool`` and the ``layer`` of it to read,
+    and the tick's ``slab`` (:func:`pack`), through which a block's queries
+    come back from the rows."""
+    lengths: jax.Array
+    pool: Any
+    layer: int
+    slab: Slab
 
 
 def attend_by_blocks(attend: Callable, args: Tuple[jax.Array, ...],
-                     n_new: jax.Array, slots: int, narrow: int) -> jax.Array:
-    """``attend(q, positions, *context)`` -> ``[S, C, ...]`` at the width each
-    block of ``slots`` slots needs; every array of ``args`` is led by the
-    slot axis, the first two by ``[S, C]``.  A tick wider than ``narrow``
-    columns attends every slot's first ``narrow`` columns at once, then a
-    block after another: one that holds a slot with more than ``narrow``
-    tokens attends again with all C columns, the others are done — the
-    columns past a slot's ``n_new`` are padding that nothing reads, and
-    come back zero.  Any plan is served: every block may hold a chunk.
-    ``attend`` may gather its slots' context from the pool itself (llama.py
-    does): the loop then holds the pool, which XLA neither copies nor
-    restacks for it (tests/test_serve.py; PERF.md §6, PR 30)."""
+                     n_new: jax.Array, slots: int, narrow: int,
+                     bound: Optional[Bound] = None) -> jax.Array:
+    """The cached attention at the width each block of ``slots`` slots needs
+    and, given a ``bound``, only as far as its slots' contexts reach.  Every
+    array of ``args`` is led by the slot axis, the first two (queries, their
+    positions) by ``[S, C]``.  A tick wider than ``narrow`` columns attends
+    every slot's first ``narrow`` columns, then a block after another: one
+    that holds a slot with more than ``narrow`` tokens attends again with
+    all C columns, the others are done — the columns past a slot's ``n_new``
+    are padding that nothing reads.  Any plan is served: every block may
+    hold a chunk.
+
+    WITHOUT a bound every slot's whole table is read (models/swa_moe.py):
+    ``attend(q, positions, *context)`` -> ``[S, C, ...]``, the first
+    ``narrow`` columns of all slots at once.  ``attend`` may gather its
+    slots' context from the pool itself: the loop then holds the pool, which
+    XLA neither copies nor restacks for it (tests/test_serve.py; PERF.md §6,
+    PR 30).
+
+    WITH one the read is tiled (models/llama.py, models/latent_moe.py): the
+    first of ``args`` is the queries as ROWS (what :func:`pack`'s ``take``
+    made; a block's come back through ``bound.slab`` inside the loop, so the
+    whole slab of a wide tick is never made), the third the block table,
+    and ``attend(q, positions, ctx, *rest, start)`` is handed ONE TILE of
+    context — ``ctx`` = :func:`gather` of :func:`tile_blocks` entries of the
+    block's tables from ``bound.pool`` at ``bound.layer``, whose first key
+    is position ``start`` — and returns ``(scores, weigh)``: the tile's
+    float32 scores ``[s, .., c, keys]``, scaled and masked with float32's
+    minimum, and the value product ``weigh(p)`` -> float32 ``[s, .., c, d]``
+    of unnormalised probabilities shaped like the scores.  What is the
+    model's own stops there; the tiling, the trip count (:func:`block_tiles`,
+    a device value read off ``lengths`` and ``n_new``), the block with no
+    live slot, which reads nothing, and the softmax across tiles are here,
+    once.  ``attend`` must be the SAME function object for every layer of a
+    tick and close over no array: the read is traced once a tick, not once
+    a layer (:func:`_attend_tiled`).  The first pass runs in blocks of
+    :func:`narrow_slots` slots.  Returns ``[S, .., C, d]`` in the scores'
+    layout: the model brings it to its own."""
+    if bound is None:
+        return _attend_whole(attend, args, n_new, slots, narrow)
+    q, pos, tables, *rest = args
+    bs = jax.tree_util.tree_leaves(bound.pool)[0].shape[2]
+    return _attend_tiled(
+        attend, bound.pool, jnp.int32(bound.layer), q, bound.slab.rows, pos,
+        tables, tuple(rest), bound.lengths, n_new, slots=slots,
+        narrow=narrow, kept=bound.slab.kept, per=narrow_slots(pos.shape[0]),
+        tb=tile_blocks(bs, tables.shape[1]))
+
+
+def _attend_whole(attend, args, n_new, slots, narrow):
+    """:func:`attend_by_blocks` without a bound."""
     S, C = args[0].shape[:2]
     if C <= narrow:
         return attend(*args)
@@ -187,12 +319,112 @@ def attend_by_blocks(attend: Callable, args: Tuple[jax.Array, ...],
     return lax.fori_loop(0, S // slots, block, o)
 
 
+@functools.partial(jax.jit, static_argnums=(0,),
+                   static_argnames=("slots", "narrow", "kept", "per", "tb"))
+def _attend_tiled(attend, pool, layer, q, rows, pos, tables, rest, lengths,
+                  n_new, *, slots, narrow, kept, per, tb):
+    """:func:`attend_by_blocks` with a bound: two passes of :func:`_bounded`
+    blocks, each over the blocks that have something to read.  A function
+    of arrays alone, jitted INSIDE the tick's program with the layer as a
+    value: the layers of a tick share one trace and one lowering (24 layers
+    traced one by one cost ``serve-decode`` 2.8 s of set-up, PERF.md §6,
+    PR 32); the compiler inlines the calls and sees each layer's constant.
+    ``per`` slots a block in the first pass, ``tb`` table entries a tile."""
+    slab = Slab(rows, kept)
+    bs = jax.tree_util.tree_leaves(pool)[0].shape[2]
+    S, C = pos.shape
+    # a table that is no whole number of tiles: the entries past it are
+    # unassigned, and cover positions that no query sees
+    tables = jnp.pad(tables, ((0, 0), (0, -tables.shape[1] % tb)),
+                     constant_values=-1)
+
+    def tile(i, per, cols):
+        """``t`` -> (scores, weigh) of tile ``t`` for block ``i``."""
+        cut = lambda a: lax.dynamic_slice_in_dim(a, i * per, per)
+        own, p = slab(q, i, per, cols), block_of(pos, i, per, cols)
+        tab, *more = map(cut, (tables, *rest))
+        return lambda t: attend(
+            own, p,
+            gather(pool, layer, lax.dynamic_slice_in_dim(tab, t * tb, tb, 1)),
+            *more, t * (tb * bs))
+
+    # what a tile's scores and value product look like, traced once: a
+    # block's are these with its slots in front and its columns second last
+    def one_tile():
+        s, weigh = tile(0, 1, C)(0)
+        return s, weigh(s)
+    scores, values = jax.eval_shape(one_tile)
+    dtype = q.dtype
+    sized = lambda like, per, cols: (
+        (per,) + like.shape[1:-2] + (cols, like.shape[-1]))
+
+    def read(o, cols, per, tiles):
+        """Each block of ``per`` slots that has ``tiles[block]`` to read
+        attends over them in its first ``cols`` columns, into ``o``; the
+        loop visits those blocks alone, so one that reads nothing costs
+        nothing and ``o`` keeps what it held there."""
+        order = jnp.argsort(tiles == 0, stable=True)    # readers first
+
+        def block(j, o):
+            # (indexing by a device value through lax: numpy-style indexing
+            # costs a millisecond a trace, and a tick traces this 48 times)
+            i = lax.dynamic_index_in_dim(order, j, keepdims=False)
+            got = _bounded(tile(i, per, cols),
+                           lax.dynamic_index_in_dim(tiles, i, keepdims=False),
+                           sized(scores, per, cols)[:-1],
+                           sized(values, per, cols), dtype)
+            return lax.dynamic_update_slice(
+                o, got, (i * per,) + (0,) * (o.ndim - 1))
+        return lax.fori_loop(0, jnp.sum(tiles > 0), block, o)
+
+    o = read(jnp.zeros(sized(values, S, C), dtype), min(C, narrow), per,
+             block_tiles(lengths, n_new, per, tb * bs))
+    if C <= narrow:
+        return o
+    return read(o, C, slots,
+                block_tiles(lengths, n_new, slots, tb * bs)
+                * holds_chunk(n_new, slots, narrow))
+
+
+def holds_chunk(n_new, slots: int, narrow: int):
+    """[blocks] bool: the blocks of ``slots`` slots that hold a slot with
+    more than ``narrow`` new tokens, and so attend at the tick's full width
+    (on jax or numpy arrays)."""
+    return n_new.reshape(-1, slots).max(axis=1) > narrow
+
+
 def wide_blocks(n_new: np.ndarray, slots: int, narrow: int) -> Tuple[int, int]:
     """:func:`attend_by_blocks`'s choice read off a plan on the host: (blocks
     that attend at the tick's full width, blocks) for ``n_new`` tokens a
     slot."""
-    top = np.asarray(n_new).reshape(-1, slots).max(axis=1)
-    return int((top > narrow).sum()), int(top.size)
+    wide = holds_chunk(np.asarray(n_new), slots, narrow)
+    return int(wide.sum()), int(wide.size)
+
+
+def read_counts(lengths: np.ndarray, n_new: np.ndarray, C: int, slots: int,
+                narrow: int, block_size: int, max_blocks: int,
+                bounded: bool = True) -> np.ndarray:
+    """A ``[S, C]`` tick's attention read off its plan on the host, as
+    :func:`attend_by_blocks` runs it: int64 [positions read, positions the
+    tables cover, first-pass blocks with no live slot, first-pass blocks].
+    A block reads ``its slots x its tiles x a tile's positions`` in either
+    pass (:func:`block_tiles`, the device's own trip counts); not
+    ``bounded``, every slot's whole table in the first pass and a chunk
+    block's again."""
+    lengths, n_new = np.asarray(lengths), np.asarray(n_new)
+    S = n_new.size
+    chunk = holds_chunk(n_new, slots, narrow) * (C > narrow)
+    if not bounded:
+        whole = max_blocks * block_size
+        return np.array([(S + slots * chunk.sum()) * whole, S * whole, 0, 1],
+                        np.int64)
+    tb = tile_blocks(block_size, max_blocks)
+    per = narrow_slots(S)
+    first = block_tiles(lengths, n_new, per, tb * block_size)
+    second = block_tiles(lengths, n_new, slots, tb * block_size) * chunk
+    return np.array([(per * first.sum() + slots * second.sum())
+                     * tb * block_size, S * max_blocks * block_size,
+                     (first == 0).sum(), first.size], np.int64)
 
 
 def copy_blocks(cache: Any, src: jax.Array, dst: jax.Array) -> Any:

@@ -926,8 +926,9 @@ class ServeEngine:
     prefill/decode step over the paged cache, compiled at two widths
     (``tick_width``): a tick without a prefill chunk does not pay for
     ``prefill_chunk`` positions a slot.  ``stats()["loop"]`` counts the
-    narrow ticks and their wait on the device, and how much of a wide
-    tick's rows and attention blocks its plan filled.
+    narrow ticks and their wait on the device, how much of a wide tick's
+    rows and attention blocks its plan filled, and how much of what the
+    block tables cover the ticks' attention read.
 
     ``model`` is a model module that defines ``init_cache``,
     ``copy_blocks``, ``apply_cached``, ``cache_shardings``, ``attn_blocks``
@@ -935,7 +936,11 @@ class ServeEngine:
     models/latent_moe.py, models/swa_moe.py;
     docs/serving.md#what-a-served-model-module-exports); ``model_cfg`` its
     config dataclass; ``params`` the trained pytree (host or global
-    arrays).  Two optional declarations: ``cache_kinds(model_cfg)`` — the
+    arrays).  Three optional declarations: ``BOUNDED_READ`` — true where
+    the cached attention reads a slot's context only as far as it reaches
+    (models/paged.py ``attend_by_blocks`` with a ``Bound``; swa_moe.py reads
+    whole tables), which ``stats()["loop"]["context_read_share"]`` counts
+    by —; ``cache_kinds(model_cfg)`` — the
     kinds of cache its layers keep (models/paged.py ``CacheKind``); its
     cache, its block tables and ``init_cache`` / ``cache_shardings``'s
     block counts are then dicts by kind, and a kind with a window is a ring
@@ -1028,6 +1033,14 @@ class ServeEngine:
         self._attn_blocks = model.attn_blocks(
             model_cfg, cfg.max_slots, cfg.prefill_chunk,
             cfg.max_blocks_per_seq * cfg.block_size)
+        # What every dispatched tick's attention read of what its tables
+        # cover, and its blocks of slots that held no stream and read
+        # nothing (paged.read_counts: [positions read, positions covered,
+        # dead blocks, blocks]); a module that bounds its reads by its
+        # slots' lengths says so (``BOUNDED_READ``), another reads whole
+        # tables.
+        self._bounded_read = bool(getattr(model, "BOUNDED_READ", False))
+        self._read = np.zeros(4, np.int64)
         # One-deep tick pipeline (the loader.prefetch deque pattern):
         # holds (plan, device next-token array) until the next step()
         # harvests it, so host scheduling overlaps device compute.
@@ -1289,6 +1302,9 @@ class ServeEngine:
             self.cache, next_tokens, counters = self._steps[C](
                 self.params, self.cache, *dev)
         used = int(n_new.sum())
+        self._read += paged.read_counts(
+            lengths, n_new, C, *self._attn_blocks, cfg.block_size,
+            cfg.max_blocks_per_seq, self._bounded_read)
         self._last_fill = used / cfg.max_batch_tokens
         self._inflight.append((self.tick, work, next_tokens, used, counters))
         self.tick += 1
@@ -1637,7 +1653,9 @@ class ServeEngine:
         share = lambda c: round(int(c[0]) / int(c[1]), 4) if c[1] else None
         out["loop"] = dict(self._loop_snapshot(), ticks=self._ticks(),
                            wide_rows_share=share(self._wide_rows),
-                           wide_blocks_share=share(self._wide_blocks))
+                           wide_blocks_share=share(self._wide_blocks),
+                           context_read_share=share(self._read[:2]),
+                           dead_blocks_share=share(self._read[2:]))
         if self._counter_names:
             out["moe"] = dict(zip(self._counter_names,
                                   map(int, self._counters)))

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.models import latent_moe as M
+from horovod_tpu.models import latent_moe as M, paged
 from horovod_tpu.parallel import expert as X
 from horovod_tpu.serve.config import ServeConfig
 from horovod_tpu.serve.engine import ServeEngine, load_servable, save_servable
@@ -162,8 +162,17 @@ def test_absorbed_attention_is_the_expanded_attention(tiny):
     wk, wv = M._wkv_b(a, cfg)
     q = jnp.concatenate([jnp.einsum("schn,lhn->schl", q_nope, wk), q_rope],
                         -1)
-    o = M._latent_attention(q, latent, positions,
-                            jnp.full((S,), C, jnp.int32), cfg)
+    # as apply_cached runs it: the latent lies in a pool of blocks of 4, a
+    # slot's table is its row of block numbers, and the shared loop hands
+    # the model's ``attend`` a tile of it at a time (paged.attend_by_blocks)
+    cache = {"latent": latent.reshape(1, S * ctx // 4, 4, cfg.latent_dim)}
+    tables = jnp.arange(S * ctx // 4, dtype=jnp.int32).reshape(S, ctx // 4)
+    o = paged.attend_by_blocks(
+        M.latent_attend(cfg), (q, positions, tables),
+        jnp.full((S,), C, jnp.int32), S, C,
+        bound=paged.Bound(jnp.asarray(lengths), cache, 0,
+                          paged.Slab(None, None)))      # [S, H, C, kv_rank]
+    o = jnp.swapaxes(o, 1, 2)
     absorbed = jnp.einsum("schl,lhv->schv", o, wv)
     c_kv, k_rope = latent[..., :cfg.kv_rank], latent[..., cfg.kv_rank:]
     k = jnp.concatenate(

@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.models import latent_moe, llama, paged
+from horovod_tpu.models import latent_moe, layers, llama, paged
 from horovod_tpu.serve.config import ServeConfig
 from horovod_tpu.serve.engine import _MODEL_MODULES, ServeEngine
 
@@ -100,6 +100,171 @@ def test_context_mask_by_a_plain_loop():
         for j in range(C):
             for t in range(3 * BS):
                 assert mask[s, 0, j, t] == (t <= LENGTHS[s] + j)
+
+
+# ------------------------------------------------------- the bounded read
+# Six slots over tables of 8 blocks of 4 (32 positions), read in tiles of 8
+# positions, the first pass in blocks of two slots, the second in blocks of
+# two with 4 narrow columns.  A plan is (columns, lengths, n_new).
+READ = dict(S=6, max_blocks=8, tile=8, slots=2, narrow=4)
+PLANS = {
+    # decode rows of 1 + spec_k columns over ragged contexts
+    "ragged-verify-rows": (5, [3, 17, 9, 0, 25, 12], [5, 1, 3, 1, 2, 1]),
+    # slots 0 and 1 are a whole dead block, slot 3 is dead beside a live one
+    # (their stale lengths reach farther than any live slot's)
+    "dead-block-and-dead-slot": (5, [30, 31, 6, 29, 2, 11], [0, 0, 1, 0, 4, 1]),
+    # slot 0 ends at max_blocks x block_size, the table's last position
+    "a-full-table": (5, [29, 4, 27, 8, 0, 0], [3, 1, 5, 1, 0, 1]),
+    # slot 0 ends exactly on a tile's edge (16), slot 2 one position past it
+    "a-tiles-edge-and-one-past": (5, [15, 2, 16, 3, 7, 7], [1, 1, 1, 1, 1, 1]),
+    # a chunk in block 0, a tail longer than the narrow columns in block 2,
+    # decode rows beside both and alone in block 1
+    "a-chunk-beside-decode-rows": (12, [8, 21, 13, 5, 30, 4],
+                                   [12, 1, 1, 1, 0, 7]),
+    # every block holds a chunk: the worst plan
+    "chunks-everywhere": (12, [0, 12, 4, 20, 16, 8], [12, 9, 12, 12, 5, 12]),
+}
+
+
+def _plan(name):
+    C, lengths, n_new = PLANS[name]
+    lengths, n_new = np.array(lengths, np.int32), np.array(n_new, np.int32)
+    S, mb = READ["S"], READ["max_blocks"]
+    rng = np.random.default_rng(17)
+    perm = rng.permutation(S * mb).reshape(S, mb).astype(np.int32)
+    # -1 past what a live slot holds; a dead slot keeps a stale row
+    held = -(-(lengths + n_new) // BS)
+    tables = np.where((np.arange(mb)[None] < held[:, None])
+                      | (n_new == 0)[:, None], perm, -1).astype(np.int32)
+    return C, lengths, n_new, tables
+
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    monkeypatch.setattr(paged, "TILE", READ["tile"])
+    monkeypatch.setattr(paged, "NARROW_SLOTS", READ["slots"])
+
+
+def _attends(model, cfg):
+    """(the model's ``attend`` for one tile, the same attention over a whole
+    gathered table [s, c, heads, d], the queries' trailing shape, the way
+    back from the scores' layout)."""
+    if model is latent_moe:
+        def whole(q, pos, ctx):
+            lat = ctx["latent"]
+            s = jnp.einsum("schx,skx->shck", q, lat) / np.sqrt(cfg.qk_dim)
+            s = jnp.where(paged.context_mask(pos, lat.shape[1]), s, -jnp.inf)
+            return jnp.einsum("shck,skl->schl", jax.nn.softmax(s, -1),
+                              lat[..., :cfg.kv_rank])
+        return (latent_moe.latent_attend(cfg), whole,
+                (cfg.n_heads, cfg.latent_dim),
+                lambda o: jnp.swapaxes(o, 1, 2))
+
+    def whole(q, pos, ctx):
+        return layers.causal_attention(
+            q, ctx["k"], ctx["v"], causal=False,
+            mask=paged.context_mask(pos, ctx["k"].shape[1]))
+    return (llama._attend_tile, whole, (cfg.n_heads, cfg.head_dim),
+            lambda o: jnp.moveaxis(o, 3, 1).reshape(
+                o.shape[0], o.shape[3], cfg.n_heads, -1))
+
+
+def _bound(lengths, pool, layer=1):
+    """A bound over ``pool`` for queries that are not packed."""
+    return paged.Bound(jnp.asarray(lengths), pool, layer,
+                       paged.Slab(None, None))
+
+
+@pytest.mark.parametrize("plan", sorted(PLANS))
+def test_the_bounded_read_is_the_whole_table_read(kind, plan, small_tiles):
+    """``attend_by_blocks`` with a bound — tiles of 8 positions as far as a
+    block's longest live context, an online softmax across them, dead blocks
+    skipped — against the whole table gathered, masked and softmaxed at
+    once, float32, at every valid position of the plan."""
+    model, cfg, pool = kind
+    S, mb = READ["S"], READ["max_blocks"]
+    pool = {k: jnp.asarray(np.random.default_rng(4).normal(
+        size=v.shape[:1] + (S * mb,) + v.shape[2:]).astype(np.float32))
+        for k, v in pool.items()}
+    C, lengths, n_new, tables = _plan(plan)
+    attend, whole, width, back = _attends(model, cfg)
+    q = jnp.asarray(np.random.default_rng(6).normal(
+        size=(S, C) + width).astype(np.float32))
+    positions, valid = paged.slot_positions(jnp.asarray(lengths),
+                                            jnp.asarray(n_new), C)
+    got = back(jax.jit(lambda q: paged.attend_by_blocks(
+        attend, (q, positions, jnp.asarray(tables)), jnp.asarray(n_new),
+        READ["slots"], READ["narrow"], bound=_bound(lengths, pool)))(q))
+    want = whole(q, positions, paged.gather(pool, 1, jnp.asarray(tables)))
+    assert got.shape == want.shape and bool(jnp.isfinite(got).all())
+    assert np.asarray(valid).any()
+    err = np.abs(np.asarray(got) - np.asarray(want))[np.asarray(valid)]
+    assert float(err.max()) < 2e-6, plan
+    # a block with no live slot read nothing and comes back zero
+    dead = np.repeat((n_new.reshape(-1, 2) == 0).all(axis=1), 2)
+    assert not np.asarray(got)[dead].any()
+
+
+@pytest.mark.parametrize("plan", sorted(PLANS))
+def test_the_hosts_tile_counts_are_the_trip_counts_the_device_ran(
+        plan, small_tiles):
+    """``read_counts`` (``context_read_share``'s arithmetic) against the
+    loops themselves: an ``attend`` that scores one key and adds 1 a tile
+    returns, a slot, the number of tiles its block's loop ran."""
+    S, mb, tile = READ["S"], READ["max_blocks"], READ["tile"]
+    slots, narrow = READ["slots"], READ["narrow"]
+    C, lengths, n_new, tables = _plan(plan)
+    fill = jnp.finfo(jnp.float32).min
+
+    def attend(q, pos, ctx, start):
+        s = jnp.full(q.shape[:2] + (tile,), fill).at[:, :, 0].set(
+            jnp.where(start == 0, 0.0, fill))
+        return s, lambda p: jnp.ones(p.shape[:-1] + (1,), jnp.float32)
+    pool = {"x": jnp.zeros((1, S * mb, BS, 1))}
+
+    def ran(cols):
+        q = jnp.zeros((S, cols))
+        o = jax.jit(lambda q: paged.attend_by_blocks(
+            attend, (q, q.astype(jnp.int32), jnp.asarray(tables)),
+            jnp.asarray(n_new), slots, narrow,
+            bound=_bound(lengths, pool, 0)))(q)
+        return np.asarray(o)[:, 0, 0].astype(np.int64)
+    first = ran(narrow)             # the first pass alone
+    both = ran(C)
+    chunk = np.repeat(n_new.reshape(-1, slots).max(axis=1) > narrow,
+                      slots) & (C > narrow)
+    assert np.array_equal(both[~chunk], first[~chunk])
+    want = -(-np.where(n_new > 0, lengths + n_new, 0).reshape(
+        -1, slots).max(axis=1) // tile)
+    assert np.array_equal(first, np.repeat(want, slots))
+    assert np.array_equal(both[chunk], np.repeat(want, slots)[chunk])
+    counts = paged.read_counts(lengths, n_new, C, slots, narrow, BS, mb)
+    assert counts[0] == (first.sum() + both[chunk].sum()) * tile
+    assert counts[1] == S * mb * BS
+    assert tuple(counts[2:]) == (int((want == 0).sum()), S // slots)
+    # a module that hands no bound reads every table whole, chunks twice
+    whole = paged.read_counts(lengths, n_new, C, slots, narrow, BS, mb,
+                              bounded=False)
+    assert whole[0] == (S + chunk.sum()) * mb * BS and whole[2] == 0
+
+
+def test_a_table_that_is_no_whole_number_of_tiles(kind, monkeypatch):
+    """Tables of 3 blocks read in tiles of 2: the fourth entry is padding
+    that covers positions no query sees."""
+    model, cfg, pool = kind
+    monkeypatch.setattr(paged, "TILE", 2 * BS)
+    attend, whole, width, back = _attends(model, cfg)
+    q = jnp.asarray(np.random.default_rng(8).normal(
+        size=(3, C) + width).astype(np.float32))
+    lengths, n_new = jnp.asarray([7, 6, 0]), jnp.asarray([4, 0, 3])
+    tables = jnp.asarray(np.array([[7, 2, 9], [4, 4, 4], [5, -1, -1]]))
+    positions, valid = paged.slot_positions(lengths, n_new, C)
+    got = back(paged.attend_by_blocks(
+        attend, (q, positions, tables), n_new, 1, C,
+        bound=_bound(lengths, _device(pool))))
+    want = whole(q, positions, paged.gather(_device(pool), 1, tables))
+    err = np.abs(np.asarray(got) - np.asarray(want))[np.asarray(valid)]
+    assert float(err.max()) < 2e-6
 
 
 def test_copy_blocks_padding_and_a_source_recycled_in_the_same_call(kind):

@@ -571,11 +571,11 @@ def test_blocks_of_slots_attend_alike_whatever_their_size(
 def test_the_loop_over_blocks_of_slots_keeps_no_second_pool(llama_tiny,
                                                            monkeypatch):
     """``test_tick_keeps_no_second_pool``'s counts on a chunk-wide step whose
-    attention runs a loop of two blocks of two slots with a conditional in
-    it (the tiny step's own chunk fits the narrow columns and holds neither):
-    the branch that attends at chunk width gathers its slots' context from
-    the pool, and the pool is still scattered in place, never restacked,
-    never an operand of a copy."""
+    attention runs loops over the blocks of two slots that have something to
+    read, and inside each a loop over tiles of its context (the tiny step's
+    own chunk fits the narrow columns and runs only the first): every step
+    gathers its tile from the pool the loops hold, and the pool is still
+    scattered in place, never restacked, never an operand of a copy."""
     from horovod_tpu.models import llama
     model, cfg, params = llama_tiny
     scfg = _cfg(max_slots=4, cache_blocks=2048, max_batch_tokens=12,
@@ -590,7 +590,7 @@ def test_the_loop_over_blocks_of_slots_keeps_no_second_pool(llama_tiny,
     engine.close()
     step, pool = engine._steps[scfg.prefill_chunk], engine.cache["k"]
     text = step.as_text()
-    assert " while(" in text and " conditional(" in text
+    assert text.count(" while(") >= 4 * cfg.n_layers
     dims = "[" + ",".join(map(str, pool.shape)) + "]"
     ops = re.findall(r" = \w+(\[[\d,]*\])\S* ([\w-]+)\(", text)
     assert (dims, "scatter") in ops
@@ -623,6 +623,55 @@ def test_wide_shares_read_what_the_plan_implies(llama_tiny, monkeypatch):
     assert loop["ticks"] - loop["narrow_ticks"] == 2
     assert loop["wide_rows_share"] == (12 + 8) / (2 * 20)
     assert loop["wide_blocks_share"] == 1 / (2 * 2)
+
+
+def test_read_shares_count_what_the_ticks_attention_read(llama_tiny,
+                                                         monkeypatch):
+    """``stats()["loop"]``'s ``context_read_share`` and ``dead_blocks_share``
+    on the plan above, four slots whose tables cover 32 positions each, read
+    in tiles of 8 by blocks of two slots: the chunk of 12 reads 2 tiles in
+    the first pass and 2 again at chunk width, the tail of 8 (held 20) 3
+    tiles, the two decode ticks (held 21, 22) 3 each; the other block of two
+    slots holds no stream in any of the four ticks and reads nothing."""
+    from horovod_tpu.models import llama, paged
+    model, cfg, params = llama_tiny
+    monkeypatch.setattr(llama, "SCORE_BYTES", 2 * cfg.n_heads * 12 * 32 * 4)
+    monkeypatch.setattr(paged, "TILE", 8)
+    monkeypatch.setattr(paged, "NARROW_SLOTS", 2)
+    engine = ServeEngine(
+        model, cfg, params,
+        _cfg(max_slots=4, cache_blocks=32, max_batch_tokens=20,
+             prefill_chunk=12, spec_decode=False, prefix_cache=False),
+        mesh=_one_device_mesh())
+    assert engine.stats()["loop"]["context_read_share"] is None
+    engine.submit(list(range(1, 21)), 3, req_id="a")
+    engine.flush()
+    loop = engine.stats()["loop"]
+    engine.close()
+    assert loop["ticks"] == 4
+    read = 2 * 8 * ((2 + 2) + 3 + 3 + 3)
+    assert loop["context_read_share"] == round(read / (4 * 4 * 32), 4)
+    assert loop["dead_blocks_share"] == 4 / 8
+
+
+def test_a_module_without_a_bound_counts_whole_tables(monkeypatch):
+    """swa_moe hands ``attend_by_blocks`` no bound (no ``BOUNDED_READ``):
+    every dispatched tick reads every slot's table whole, a chunk's block
+    twice, and no block is skipped."""
+    from horovod_tpu.models import swa_moe
+    cfg = swa_moe.CONFIGS["tiny"]
+    params = swa_moe.init(jax.random.PRNGKey(0), cfg)
+    engine = ServeEngine(
+        swa_moe, cfg, params,
+        _cfg(cache_blocks=32, max_batch_tokens=12, prefix_cache=False,
+             spec_decode=False), mesh=_one_device_mesh())
+    assert not hasattr(swa_moe, "BOUNDED_READ")
+    engine.submit(list(range(1, 7)), 3, req_id="a")
+    engine.flush()
+    loop = engine.stats()["loop"]
+    engine.close()
+    assert loop["context_read_share"] >= 1.0
+    assert loop["dead_blocks_share"] == 0.0
 
 
 def test_two_engines_fed_alike_agree_on_digest_and_widths(llama_tiny):

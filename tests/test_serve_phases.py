@@ -330,9 +330,14 @@ def lowered():
 @pytest.mark.parametrize("program,scopes", [
     ("train", ["embed", "attn", "ffn", "head", "optimizer",
                "grad_sync/bucket0", "grad_sync/bucket1"]),
-    ("cached", ["embed", "attn", "attn/kv_gather", "attn/kv_write", "ffn",
+    # the gather is a tile's, inside the loop over blocks of slots and the
+    # loop over a block's tiles of the read that the layers share
+    # (models/paged.py _attend_tiled, a jit of its own under attn)
+    ("cached", ["embed", "attn", "jit(_attend_tiled)",
+                "while/body/while/body/kv_gather", "attn/kv_write", "ffn",
                 "head", "kv_write"]),
-    ("tick", ["tick/copy_blocks", "tick/model/attn/kv_gather",
+    ("tick", ["tick/copy_blocks", "tick/model/attn", "jit(_attend_tiled)",
+              "while/body/while/body/kv_gather",
               "tick/model/ffn", "tick/model/head", "tick/sample"]),
 ])
 def test_lowered_program_names_each_scope(lowered, program, scopes):

@@ -52,3 +52,108 @@ def test_the_expert_tile_kernel_compiles_at_the_cells_widths(one_chip, held,
     # the expert's matrices are read where they lie: no copy of one out of
     # the stack before the kernel
     assert f"bf16[{d},{f}]" not in text and f"bf16[1,{d},{f}]" not in text
+
+
+def _tick_program(cell, C, one_chip):
+    """The serving cell's tick at ``[slots, C]`` as ServeEngine builds it
+    (copy-on-write, ``apply_cached`` on a budget of ``max_batch_tokens``
+    rows, the greedy token), compiled for the described chip: (text, pool
+    dims)."""
+    import dataclasses
+
+    from perfbench.lib import spec, weights
+    _, config, _ = spec.cell(cell)
+    e = config["engine"]
+    model, cfg = spec.family(config).program(config)
+    cfg = dataclasses.replace(cfg, max_tick_tokens=e["max_batch_tokens"])
+    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    tree = lambda t: jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), t)
+    params = tree(jax.eval_shape(lambda: weights.make(
+        config, weights.seed_key(0), weights.dtype_of(config))))
+    cache = tree(jax.eval_shape(lambda: model.init_cache(
+        cfg, e["cache_blocks"], e["block_size"])))
+    S, i32 = e["max_slots"], jnp.int32
+
+    def step(params, cache, bt, lengths, n_new, tokens, src, dst):
+        cache = model.copy_blocks(cache, src, dst)
+        out = model.apply_cached(params, tokens, cfg, cache, bt, lengths,
+                                 n_new)
+        return out[1], jnp.argmax(out[0].astype(jnp.float32), -1)
+    orig = jax.default_backend
+    jax.default_backend = lambda: "tpu"     # the expert tile is Mosaic's
+    try:
+        compiled = jax.jit(step, donate_argnums=(1,)).lower(
+            params, cache, sds((S, e["max_seq_len"] // e["block_size"]), i32),
+            sds((S,), i32), sds((S,), i32), sds((S, C), i32), sds((S,), i32),
+            sds((S,), i32)).compile()
+    finally:
+        jax.default_backend = orig
+    pool = jax.tree_util.tree_leaves(cache)[0].shape
+    return compiled.as_text(), "[" + ",".join(map(str, pool)) + "]"
+
+
+@pytest.mark.parametrize("C", [64, 5])
+def test_the_latent_tick_holds_no_whole_context_and_no_third_pool_copy(
+        one_chip, C):
+    """``serve-moe-mla-decode``'s two programs: the attention reads a tile
+    of context at a time inside the shared loop, so nothing is shaped like a
+    slot's whole context (``[slots, 2560, 576]``, whole or split by blocks
+    of slots, which the once-gathered form made and copied a layer); and
+    the loops hold the pool without copying it — the two relayouts of a
+    pool whose last axis is 576 (ROADMAP S10 (b)) are the only copies."""
+    import re
+    text, pool = _tick_program("serve-moe-mla-decode", C, one_chip)
+    ops = re.findall(r" = \w+(\[[\d,]*\])\S* ([\w-]+)\(", text)
+    assert (pool, "scatter") in ops
+    assert not [op for op in ops if op[0].endswith(",2560,576]")]
+    assert len([op for op in ops if op == (pool, "copy")]) <= 2
+    assert not [op for op in ops if op == (pool, "concatenate")]
+
+
+def _primitives(jaxpr):
+    """Every primitive of a jaxpr with those of its sub-jaxprs: (name,
+    output shapes)."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name, [v.aval.shape for v in eqn.outvars]
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _primitives(sub)
+
+
+def test_without_a_bound_every_table_is_gathered_whole_in_counted_loops(
+        monkeypatch):
+    """What models/swa_moe.py hands ``attend_by_blocks`` — no bound — keeps
+    the form its two compiled programs were measured in: each slot's whole
+    table gathered, a loop over blocks of slots whose trip count is the
+    program's (a ``scan``), and no ``while`` whose count the device reads
+    off the plan; the same call with a bound has one.  Runs without the
+    TPU's library."""
+    from horovod_tpu.models import layers, llama, paged
+    S, C, mb, bs, H, D = 4, 12, 8, 4, 2, 8
+    pool = {k: jnp.zeros((1, S * mb, bs, H, D)) for k in "kv"}
+    tables = jnp.arange(S * mb, dtype=jnp.int32).reshape(S, mb)
+    lengths, n_new = jnp.array([0, 9, 3, 5]), jnp.array([12, 1, 0, 1])
+    q = jnp.zeros((S, C, H, D))
+    pos, _ = paged.slot_positions(lengths, n_new, C)
+
+    def whole(q, pos, tab):
+        ctx = paged.gather(pool, 0, tab)
+        return layers.causal_attention(
+            q, ctx["k"], ctx["v"], causal=False,
+            mask=paged.context_mask(pos, mb * bs))
+    run = lambda attend, bound: list(_primitives(jax.make_jaxpr(
+        lambda q: paged.attend_by_blocks(
+            attend, (q, pos, tables), n_new, 2, 4, bound=bound))(q).jaxpr))
+    unbounded = run(whole, None)
+    names = {name for name, _ in unbounded}
+    assert "while" not in names and {"scan", "cond", "gather"} <= names
+    gathered = [shapes[0] for name, shapes in unbounded if name == "gather"]
+    assert (S, mb, bs, H, D) in gathered and (2, mb, bs, H, D) in gathered
+    monkeypatch.setattr(paged, "TILE", 2 * bs)
+    bounded = run(llama._attend_tile,
+                  paged.Bound(lengths, pool, 0, paged.Slab(None, None)))
+    assert "while" in {name for name, _ in bounded}
+    assert all(shape[1] == 2 for name, shapes in bounded
+               for shape in shapes if name == "gather" and len(shape) == 5)
