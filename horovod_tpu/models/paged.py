@@ -10,13 +10,23 @@ sequence.  A sequence owns whole blocks through its row of ``block_tables``
 shapes.  The stacked pool is indexed by layer, never unstacked: a donated
 cache stays one buffer through a tick (docs/serving.md).
 
-A model whose layers keep state of several KINDS (models/swa_moe.py: layers
-that read the whole context beside layers that read a window of it) declares
-them (:class:`CacheKind`) and holds one pool a kind.  A kind with a window
-is addressed as a RING: its table row has :func:`ring_blocks` entries and
-position P lands in entry ``(P // bs) % entries``, so a slot holds the last
-``entries * bs`` positions whatever its context's length
-(docs/serving.md#cache-kinds).
+A model whose layers keep state of several KINDS declares them
+(:class:`CacheKind`) and holds one pool a kind.  There are three
+(docs/serving.md#cache-kinds):
+
+  * the WHOLE CONTEXT, as above: a block table over the paged pool;
+  * a RING of a window (models/swa_moe.py: layers that read a window of the
+    context beside layers that read all of it): the table row has
+    :func:`ring_blocks` entries and position P lands in entry ``(P // bs) %
+    entries``, so a slot holds the last ``entries * bs`` positions whatever
+    its context's length;
+  * a FIXED STATE a slot (models/conv_moe.py: a short convolution's last
+    inputs): no blocks, no table, no allocator.  The pool is ``[layers,
+    slots, columns, ...]``, position P of slot s lies at ``[s, P %
+    columns]`` (:func:`state_index`), and a layer reads back the columns of
+    the last positions before its tick's own (:func:`state_read`);
+    :func:`state_columns` says how many columns keep those through a
+    rejected draft.
 """
 
 from __future__ import annotations
@@ -33,12 +43,66 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 class CacheKind(NamedTuple):
     """One kind of cached state: ``layers`` layers of the model keep it, each
-    the slot's whole context (``window`` None) or its last ``window``
-    positions.  A module's ``cache_kinds(cfg)`` lists its kinds; its cache
-    and the block tables it is handed are dicts by ``name``."""
+    the slot's whole context (``window`` and ``state`` None), its last
+    ``window`` positions in a ring of blocks, or a fixed state a slot of
+    which a tick reads back the ``state`` columns before its own (the
+    module docstring sets the three side by side).  A module's
+    ``cache_kinds(cfg)`` lists its kinds; its cache is a dict by ``name``,
+    and so are the block tables it is handed (a state kind has none)."""
     name: str
     layers: int
     window: Optional[int] = None
+    state: Optional[int] = None
+
+
+def state_columns(state: int, tick_cols: int) -> int:
+    """Columns of a slot's ring in a kind with a fixed ``state``: what a tick
+    reads back plus the widest row a tick may have to take back
+    (``tick_cols``, a verify row's ``1 + spec_k``).  A verify row writes
+    ``L .. L+n-1`` (``n <= tick_cols``); with ``a < n`` of them accepted the
+    next tick reads ``L+a-state+1 .. L+a``, at the least ``L-state+1``: the
+    positions ``L-state+1 .. L+n-1`` must lie in different columns, ``state
+    + n - 1`` of them, and one more column than that is kept.  A rejected
+    draft's column is stale data at a position that the next accepted token
+    overwrites before anything reads it (:func:`ring_blocks` makes the same
+    argument for a window's keys)."""
+    return state + tick_cols
+
+
+def state_index(lengths: jax.Array, n_new: jax.Array, valid: jax.Array,
+                positions: jax.Array, cols: int
+                ) -> Tuple[jax.Array, jax.Array]:
+    """(slot, col) [S, C] for :func:`write` into a state kind's pool
+    ``[layers, S, cols, ...]``: position P of slot s lands in ``[s, P %
+    cols]``.  Of a chunk longer than the ring only the LAST ``cols``
+    positions land (two positions of one tick never meet in a column);
+    what does not land, and every invalid position, goes to slot ``S``, off
+    the axis, where :func:`write` drops it."""
+    S = lengths.shape[0]
+    lands = valid & (positions >= (lengths + n_new)[:, None] - cols)
+    slot = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[:, None],
+                            positions.shape)
+    return jnp.where(lands, slot, S), positions % cols
+
+
+def state_read(pool: jax.Array, layer: int, own: jax.Array, slot: jax.Array,
+               positions: jax.Array, lengths: jax.Array, back: int
+               ) -> jax.Array:
+    """What lay ``back`` positions before each of a tick's rows, ``own``
+    ``[N, d]`` being the rows' own values in slab order (:func:`pack` keeps
+    a slot's tokens side by side) at ``positions`` [N] of slots ``slot``
+    [N] that held ``lengths`` [N] positions before the tick: the row
+    ``back`` rows up where that position is the tick's own (never a
+    neighbour slot's row: its position would lie below ``lengths``), else
+    the slot's column of ``pool[layer]`` ``[S, cols, d]`` AS IT WAS BEFORE
+    THE TICK — read before :func:`write` —, and zero below position 0: a
+    slot's new tenant reads nothing of the stream that left it."""
+    at = positions - back
+    kept = pool[layer, slot, at % pool.shape[2]]
+    mine = jnp.pad(own, ((back, 0), (0, 0)))[:own.shape[0]]
+    return jnp.where((at >= 0)[:, None],
+                     jnp.where((at >= lengths)[:, None], mine, kept),
+                     jnp.zeros((), own.dtype))
 
 
 def ring_blocks(window: int, tick_cols: int, block_size: int,
@@ -443,6 +507,16 @@ def copy_blocks(cache: Any, src: jax.Array, dst: jax.Array) -> Any:
             pool = pool.at[i, dst].set(pool[i, safe], mode="drop")
         return pool
     return jax.tree_util.tree_map(cp, cache)
+
+
+def no_prefix_blocks(cache: Any, src: jax.Array, dst: jax.Array) -> Any:
+    """``copy_blocks`` of a model with a window or a state kind: nothing to
+    clone.  A block is a prefix's to share only with every layer's state at
+    its end: a window layer's block stops being a prefix's once the stream
+    has passed it, and a fixed state keeps its slot's last columns alone —
+    so ServeEngine refuses prefix sharing (and with it copy-on-write) over
+    such kinds."""
+    return cache
 
 
 def shardings(mesh, num_blocks: int, head_axis_size: Optional[int] = None):

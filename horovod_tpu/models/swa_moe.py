@@ -230,11 +230,7 @@ def cache_shardings(mesh, cfg: SwaMoeConfig, num_blocks: Dict[str, int]):
             for name, n in num_blocks.items()}
 
 
-def copy_blocks(cache: Any, src: jax.Array, dst: jax.Array) -> Any:
-    """Nothing to clone: a window layer's block stops being a prefix's once
-    the stream has passed it, so ServeEngine refuses prefix sharing (and
-    with it copy-on-write) for a model with a window kind."""
-    return cache
+copy_blocks = paged.no_prefix_blocks
 
 
 def attn_blocks(cfg: SwaMoeConfig, S: int, C: int, ctx: int

@@ -282,15 +282,23 @@ def init_held_experts(key, dim: int, hidden: int, total: int, held: int,
                         "w_down": w(k3, (held, hidden, dim), s_out)}}
 
 
-def route_sigmoid_topk(x, router_kernel, k: int, scale: float):
+def route_sigmoid_topk(x, router_kernel, k: int, scale: float, bias=None,
+                       eps: float = 1e-20):
     """Sigmoid scores over ALL experts in float32, the ``k`` largest with no
-    groups and no bias, gates ``scale * s / (sum of the chosen s + 1e-20)``.
-    Returns (idx [T, k] int32, gates [T, k] float32)."""
+    groups, gates ``scale * s / (sum of the chosen s + eps)``.  A ``bias``
+    [E] (a model's load-balancing ``expert_bias``) PICKS and does not weigh:
+    the chosen are the ``k`` largest of ``s + bias``, their gates are made
+    of the unbiased ``s``.  Returns (idx [T, k] int32, gates [T, k]
+    float32)."""
     s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
                                router_kernel.astype(jnp.float32),
                                precision=lax.Precision.HIGHEST))
-    top, idx = lax.top_k(s, k)
-    return idx, scale * top / (top.sum(-1, keepdims=True) + 1e-20)
+    if bias is None:
+        top, idx = lax.top_k(s, k)
+    else:
+        _, idx = lax.top_k(s + bias.astype(jnp.float32), k)
+        top = jnp.take_along_axis(s, idx, axis=-1)
+    return idx, scale * top / (top.sum(-1, keepdims=True) + eps)
 
 
 def route_softmax_topk(x, router_kernel, k: int):

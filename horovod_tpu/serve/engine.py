@@ -519,6 +519,29 @@ class _Ring:
         self.window_position_ticks += min(ctx_len, self.kind.window)
 
 
+class _State:
+    """One state kind's host state (models/paged.py CacheKind with a
+    ``state``): a slot keeps ``columns`` columns of it whatever its
+    context's length — what a tick reads back plus the widest verify row
+    (paged.state_columns, where the margin for rejected drafts is argued).
+    The pool holds ``max_slots`` of them: a slot IS its state, so there is
+    nothing to allocate, admit against or give back, and a slot's next
+    tenant reads none of it (paged.state_read)."""
+
+    def __init__(self, kind, cfg: ServeConfig):
+        self.kind = kind
+        self.columns = paged.state_columns(kind.state, decode_width(cfg))
+        # summed over dispatched ticks and their slots: the slots, and the
+        # positions a whole-context cache would hold for them
+        self.slot_ticks = 0
+        self.full_position_ticks = 0
+
+    def count(self, ctx_len: int) -> None:
+        """One slot of one dispatched tick (as :meth:`_Ring.count`)."""
+        self.slot_ticks += 1
+        self.full_position_ticks += ctx_len
+
+
 class Scheduler:
     """Deterministic slot-table scheduler (pure host state, no jax) —
     unit-testable without a model.  ``plan()`` returns this tick's
@@ -539,8 +562,9 @@ class Scheduler:
     def __init__(self, cfg: ServeConfig, role: str = "mixed", kinds=()):
         """``kinds``: the served model's cache kinds (paged.CacheKind; none
         = one kind that keeps whole contexts).  A kind with a window gets a
-        ring a slot (:class:`_Ring`) beside the block table of the kind
-        that keeps whole contexts."""
+        ring a slot (:class:`_Ring`), a kind with a fixed state its columns
+        a slot (:class:`_State`), beside the block table of the kind that
+        keeps whole contexts."""
         if role not in self.ROLES:
             raise ValueError(f"scheduler role {role!r} invalid; expected "
                              f"one of {self.ROLES}")
@@ -549,12 +573,22 @@ class Scheduler:
         self.kinds = tuple(kinds)
         self.rings = {k.name: _Ring(k, cfg) for k in self.kinds
                       if k.window is not None}
-        if self.rings:
-            # A ring's block stops holding a prefix's positions once the
-            # stream has passed it: a hit at n tokens would need every
-            # window layer's positions n - window .. n - 1, which no other
-            # slot's ring and no block of the tree keeps.  Refused, never
-            # silently wrong (docs/serving.md#cache-kinds).
+        self.states = {k.name: _State(k, cfg) for k in self.kinds
+                       if k.state is not None}
+        # what counts a dispatched tick's slots (_Ring.count, _State.count)
+        self.counted = (*self.rings.values(), *self.states.values())
+        # the kinds over which no block is a prefix's, named for a refusal
+        self.unshared = " and ".join(
+            f"{what} cache kinds ({', '.join(names)})" for what, names
+            in (("window", self.rings), ("state", self.states)) if names)
+        if self.unshared:
+            # A block of keys is a prefix's only with every layer's state
+            # at its end.  A ring's block stops holding a prefix's positions
+            # once the stream has passed it: a hit at n tokens would need
+            # every window layer's positions n - window .. n - 1, which no
+            # other slot's ring and no block of the tree keeps; a fixed
+            # state keeps its slot's last columns and no other position's.
+            # Refused, never silently wrong (docs/serving.md#cache-kinds).
             for on, what in ((cfg.prefix_cache, "the radix prefix cache "
                               "(HOROVOD_SERVE_PREFIX_CACHE) and its "
                               "copy-on-write"),
@@ -564,12 +598,12 @@ class Scheduler:
                               "hand-off")):
                 if on:
                     raise ValueError(
-                        f"the served model keeps window cache kinds "
-                        f"({', '.join(self.rings)}): {what} cannot run over "
-                        "them — a window layer's block is overwritten once "
-                        "the stream has passed it, so a block is no prefix's "
-                        "to share, spill or hand off; turn it off "
-                        "(docs/serving.md#cache-kinds)")
+                        f"the served model keeps {self.unshared}: {what} "
+                        "cannot run over them — a window layer's block is "
+                        "overwritten once the stream has passed it and a "
+                        "fixed state keeps no earlier position's, so a block "
+                        "is no prefix's to share, spill or hand off; turn it "
+                        "off (docs/serving.md#cache-kinds)")
         self.slots: List[Optional[Request]] = [None] * cfg.max_slots
         self.waiting: "collections.deque[Request]" = collections.deque()
         self.allocator = BlockAllocator(cfg.cache_blocks)
@@ -739,11 +773,13 @@ class Scheduler:
     def device_tables(self):
         """What the tick addresses its pools with: the block table, or for
         a model that declares cache kinds ``{kind: table}`` — the whole-
-        context kind's block table and each window kind's ring table."""
+        context kind's block table and each window kind's ring table; a
+        state kind has none."""
         if not self.kinds:
             return self.block_tables
         return {k.name: (self.rings[k.name].tables if k.name in self.rings
-                         else self.block_tables) for k in self.kinds}
+                         else self.block_tables)
+                for k in self.kinds if k.state is None}
 
     def take_copies(self) -> List[Tuple[int, int]]:
         copies, self.pending_copies = self.pending_copies, []
@@ -933,7 +969,7 @@ class ServeEngine:
     ``model`` is a model module that defines ``init_cache``,
     ``copy_blocks``, ``apply_cached``, ``cache_shardings``, ``attn_blocks``
     and ``TICK_COUNTERS`` (models/llama.py, models/moe_llama.py,
-    models/latent_moe.py, models/swa_moe.py;
+    models/latent_moe.py, models/swa_moe.py, models/conv_moe.py;
     docs/serving.md#what-a-served-model-module-exports); ``model_cfg`` its
     config dataclass; ``params`` the trained pytree (host or global
     arrays).  Three optional declarations: ``BOUNDED_READ`` — true where
@@ -943,11 +979,13 @@ class ServeEngine:
     by —; ``cache_kinds(model_cfg)`` — the
     kinds of cache its layers keep (models/paged.py ``CacheKind``); its
     cache, its block tables and ``init_cache`` / ``cache_shardings``'s
-    block counts are then dicts by kind, and a kind with a window is a ring
-    a slot (docs/serving.md#cache-kinds; swa_moe.py declares two, the other
-    modules none: one pool, one table) —, and ``greedy_cached``, the
-    tick's greedy tokens in place of its logits, for a vocabulary whose
-    ``[slots, chunk, vocab]`` slab should never exist (swa_moe.py).
+    block counts are then dicts by kind, a kind with a window is a ring a
+    slot and a kind with a fixed state ``(slots, columns)`` with no table
+    (docs/serving.md#cache-kinds; swa_moe.py declares whole contexts and a
+    window, conv_moe.py whole contexts and a state, the other modules none:
+    one pool, one table) —, and ``greedy_cached``, the tick's greedy tokens
+    in place of its logits, for a vocabulary whose ``[slots, chunk, vocab]``
+    slab should never exist (swa_moe.py, conv_moe.py).
     """
 
     def __init__(self, model, model_cfg, params, cfg: ServeConfig,
@@ -973,13 +1011,15 @@ class ServeEngine:
         self._repl = NamedSharding(mesh, P())
         # blocks of the pool — of each kind's pool: a window kind's holds
         # every slot's ring and is sized by the model's window, the slots
-        # and the chunk, not by ``cache_blocks``
+        # and the chunk, not by ``cache_blocks``; a state kind's is (slots,
+        # columns a slot), sized by the model's state and the verify row
         num_blocks: Any = cfg.cache_blocks
         if kinds:
-            rings = self.scheduler.rings
-            num_blocks = {k.name: (rings[k.name].allocator.num_blocks
-                                   if k.name in rings else cfg.cache_blocks)
-                          for k in kinds}
+            rings, states = self.scheduler.rings, self.scheduler.states
+            num_blocks = {k.name: (
+                rings[k.name].allocator.num_blocks if k.name in rings else
+                (cfg.max_slots, states[k.name].columns) if k.name in states
+                else cfg.cache_blocks) for k in kinds}
         self._cache_shd = model.cache_shardings(mesh, model_cfg, num_blocks)
         leaves = jax.tree_util.tree_leaves(params)
         if leaves and isinstance(leaves[0], jax.Array):
@@ -1161,12 +1201,12 @@ class ServeEngine:
 
     def _whole_contexts_only(self, what: str) -> None:
         """Block transfer reads "a block in every layer": refused where a
-        window kind's ring has no such block (Scheduler.__init__)."""
-        if self.scheduler.rings:
+        window kind's ring or a state kind has no such block
+        (Scheduler.__init__)."""
+        if self.scheduler.unshared:
             raise ValueError(
-                f"{what} cannot run over the served model's window cache "
-                f"kinds ({', '.join(self.scheduler.rings)}); "
-                "docs/serving.md#cache-kinds")
+                f"{what} cannot run over the served model's "
+                f"{self.scheduler.unshared}; docs/serving.md#cache-kinds")
 
     # ------------------------------------------------------ disaggregation
     def export_handoff(self, req: Request, first_token: int
@@ -1282,8 +1322,8 @@ class ServeEngine:
                     tokens[slot, :n] = [req.out_tokens[-1]] + req.draft
                 lengths[slot] = req.ctx_len
                 n_new[slot] = n
-                for ring in self.scheduler.rings.values():
-                    ring.count(req.ctx_len + n)
+                for kept in self.scheduler.counted:
+                    kept.count(req.ctx_len + n)
             copy_src = np.zeros(S, np.int32)
             copy_dst = np.full(S, cfg.cache_blocks, np.int32)  # no-op: dropped
             for j, (src, dst) in enumerate(copies):
@@ -1556,8 +1596,9 @@ class ServeEngine:
         nb = max(occ["num_blocks"], 1)
         # a block of the allocator's own kind (the one that keeps whole
         # contexts, where the model declares kinds)
-        full = next((k.name for k in s.kinds if k.window is None), None)
-        block_bytes = self._kind_bytes.get(full, self._pool_bytes) // nb
+        full = self._full_kind()
+        block_bytes = (self._kind_bytes[full.name] if full
+                       else self._pool_bytes) // nb
         reserved_tokens = written_tokens = 0
         for req in s.slots:
             if req is not None:
@@ -1586,13 +1627,40 @@ class ServeEngine:
                             for k in s.kinds}
         return occ
 
+    def _full_kind(self):
+        """The kind that keeps whole contexts (a paged.CacheKind), where the
+        model declares kinds."""
+        return next((k for k in self.scheduler.kinds
+                     if k.window is None and k.state is None), None)
+
     def _kind_pool(self, kind, written_tokens: int) -> Dict[str, Any]:
         """One cache kind's part of :meth:`kv_pool`: its pool's blocks in
         use and free, the positions it holds now (a ring: at most its own
         length a slot) beside the positions a full-context cache would hold
         for the same slots, and for a ring the same two summed over every
-        dispatched tick (what ``kv.window_resident_share.serve`` reads)."""
+        dispatched tick (what ``kv.window_resident_share.serve`` reads).  A
+        state kind has no blocks: its bytes, its columns a slot, and summed
+        over every dispatched tick's slots what it holds for them beside
+        what a key-value cache of the same layers would
+        (``kv.state_resident_share.serve``)."""
         s = self.scheduler
+        state = s.states.get(kind.name)
+        if state:
+            full = self._full_kind()
+            # a cached position of one layer, as the paged kind holds it
+            kv_bytes = (self._kind_bytes[full.name] // (
+                s.allocator.num_blocks * self.cfg.block_size * full.layers)
+                if full else 0)
+            return {"layers": kind.layers, "window": None,
+                    "state": kind.state, "state_columns": state.columns,
+                    "pool_bytes": self._kind_bytes[kind.name],
+                    "slots": self.cfg.max_slots,
+                    "slots_used": sum(r is not None for r in s.slots),
+                    "slot_ticks": state.slot_ticks,
+                    "state_bytes_ticks": state.slot_ticks * (
+                        self._kind_bytes[kind.name] // self.cfg.max_slots),
+                    "kv_bytes_ticks": (state.full_position_ticks * kv_bytes
+                                       * kind.layers)}
         ring = s.rings.get(kind.name)
         alloc = ring.allocator if ring else s.allocator
         out = {"layers": kind.layers, "window": kind.window,
@@ -1681,7 +1749,8 @@ SERVE_MANIFEST = "serve.json"
 _MODEL_MODULES = {"llama": "horovod_tpu.models.llama",
                   "moe_llama": "horovod_tpu.models.moe_llama",
                   "latent_moe": "horovod_tpu.models.latent_moe",
-                  "swa_moe": "horovod_tpu.models.swa_moe"}
+                  "swa_moe": "horovod_tpu.models.swa_moe",
+                  "conv_moe": "horovod_tpu.models.conv_moe"}
 
 
 def save_servable(directory: str, model_name: str, config, params,
@@ -1702,7 +1771,7 @@ def save_servable(directory: str, model_name: str, config, params,
 def load_servable(directory: str, mesh) -> Tuple[Any, Any, Any]:
     """Read a servable directory -> (model module, model config, global
     replicated params).  ``serve.json``: {"model": "llama"|"moe_llama"|
-    "latent_moe"|"swa_moe",
+    "latent_moe"|"swa_moe"|"conv_moe",
     "config": <name in CONFIGS or kwarg dict>, "seed": int?}.  Params
     come from the latest checkpoint under the directory (restored
     through checkpoint.py into replicated shardings); with no

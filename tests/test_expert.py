@@ -290,3 +290,58 @@ def test_tile_ffn_is_gated_ffn_of_the_numbered_expert(dtype, act, tol):
                 < tol * float(jnp.std(want)), e
     finally:
         X._TILE_FFN_VMEM = old
+
+
+# ------------------------------------------------- the router's bias
+def _route_sigmoid_topk_before_the_bias(x, router_kernel, k, scale):
+    """parallel/expert.py ``route_sigmoid_topk`` as it stood before it took a
+    bias (PR 32), to hold the new one to it bit for bit."""
+    s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
+                               router_kernel.astype(jnp.float32),
+                               precision=jax.lax.Precision.HIGHEST))
+    top, idx = jax.lax.top_k(s, k)
+    return idx, scale * top / (top.sum(-1, keepdims=True) + 1e-20)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_without_a_bias_the_router_is_what_it_was(dtype):
+    from horovod_tpu.parallel import expert as X
+    x = jax.random.normal(jax.random.PRNGKey(2), (96, 48)).astype(dtype)
+    w = (jax.random.normal(jax.random.PRNGKey(3), (48, 16)) / 7).astype(dtype)
+    for run in (lambda f: f, jax.jit):
+        want = run(lambda x, w: _route_sigmoid_topk_before_the_bias(
+            x, w, 4, 2.5))(x, w)
+        got = run(lambda x, w: X.route_sigmoid_topk(x, w, 4, 2.5))(x, w)
+        got_none = run(lambda x, w: X.route_sigmoid_topk(
+            x, w, 4, 2.5, bias=None))(x, w)
+        for a, b, c in zip(want, got, got_none):
+            assert a.dtype == b.dtype and jnp.array_equal(a, b)
+            assert jnp.array_equal(a, c)
+    # ... and it lowers to the same program
+    text = lambda f: jax.jit(f).lower(x, w).as_text()
+    assert text(lambda x, w: X.route_sigmoid_topk(x, w, 4, 2.5)) == text(
+        lambda x, w: _route_sigmoid_topk_before_the_bias(x, w, 4, 2.5))
+
+
+def test_a_bias_picks_and_does_not_weigh():
+    from horovod_tpu.parallel import expert as X
+    x = jax.random.normal(jax.random.PRNGKey(4), (200, 32))
+    w = jax.random.normal(jax.random.PRNGKey(5), (32, 16)) / 6
+    bias = 0.2 * jax.random.normal(jax.random.PRNGKey(6), (16,))
+    s = jax.nn.sigmoid(jnp.dot(x, w, precision=jax.lax.Precision.HIGHEST))
+    idx, gates = X.route_sigmoid_topk(x, w, 4, 1.0, bias=bias, eps=1e-6)
+    plain, _ = X.route_sigmoid_topk(x, w, 4, 1.0, eps=1e-6)
+    # the chosen four are the largest of s + bias, another four than the
+    # largest of s for a good share of the tokens
+    assert jnp.array_equal(jnp.sort(idx, -1),
+                           jnp.sort(jax.lax.top_k(s + bias, 4)[1], -1))
+    assert float((jnp.sort(idx, -1) != jnp.sort(plain, -1)).any(-1).mean()) > .2
+    # their gates are the unbiased scores over their sum: a bias that is
+    # the same for every expert changes nothing at all
+    chosen = jnp.take_along_axis(s, idx, -1)
+    assert jnp.allclose(gates, chosen / (chosen.sum(-1, keepdims=True) + 1e-6),
+                        atol=1e-7)
+    same = X.route_sigmoid_topk(x, w, 4, 1.0, bias=jnp.full(16, 0.7), eps=1e-6)
+    for a, b in zip(same, X.route_sigmoid_topk(x, w, 4, 1.0, eps=1e-6)):
+        assert jnp.array_equal(a, b)
+

@@ -1,6 +1,7 @@
 """How a block table addresses a paged pool (horovod_tpu/models/paged.py),
 on both kinds of pool the served models keep — llama's five axes and the
-latent decoder's four — and the contract ServeEngine holds a model module to
+latent decoder's four —, how a fixed state a slot is addressed beside it, and
+the contract ServeEngine holds a model module to
 (docs/serving.md#what-a-served-model-module-exports)."""
 
 import importlib
@@ -381,3 +382,55 @@ def test_every_served_module_keeps_the_contract(name):
             if a != attr and hasattr(model, a)})
         with pytest.raises(AttributeError, match=attr):
             _engine(stub, cfg, params)
+
+
+# ------------------------------------------------ a fixed state a slot
+def test_the_state_kinds_addressing_on_a_tick():
+    """paged.state_index / state_read by hand: where a tick's columns land
+    (the last ``cols`` of a long chunk only), and what each row reads back:
+    its own slot's earlier rows, the state as it was before the tick, zero
+    before position 0 — never the neighbour's row."""
+    cols, S, C = 4, 3, 6
+    lengths = jnp.asarray([0, 5, 9], jnp.int32)
+    n_new = jnp.asarray([6, 1, 0], jnp.int32)
+    positions, valid = paged.slot_positions(lengths, n_new, C)
+    slot, col = paged.state_index(lengths, n_new, valid, positions, cols)
+    # slot 0 writes positions 2..5 of its six (the last four), slot 1 its
+    # one, slot 2 nothing; what does not land goes to slot S, off the axis
+    assert slot.tolist() == [[3, 3, 0, 0, 0, 0], [1, 3, 3, 3, 3, 3], [3] * 6]
+    assert col[0].tolist() == [0, 1, 2, 3, 0, 1] and int(col[1, 0]) == 1
+    # pool[l, s, c] = 100 s + the position that column held before the tick
+    held = np.zeros((1, S, cols, 1), np.float32)
+    for s, L in enumerate(lengths.tolist()):
+        for p in range(max(0, L - cols), L):
+            held[0, s, p % cols, 0] = 100 * s + p
+    take, _ = paged.pack(valid, 8)
+    own = take(1000.0 + 10 * jnp.arange(S)[:, None]
+               + jnp.arange(C)[None].astype(jnp.float32))[0][:, None]
+    wide = lambda a: take(jnp.broadcast_to(a[:, None], (S, C)))[0]
+    row = (wide(jnp.arange(S)), take(positions)[0], wide(lengths))
+    back1 = paged.state_read(jnp.asarray(held), 0, own, *row, 1)[:7, 0]
+    back2 = paged.state_read(jnp.asarray(held), 0, own, *row, 2)[:7, 0]
+    # rows: slot 0's six (positions 0..5), then slot 1's one (position 5)
+    assert back1.tolist() == [0, 1000, 1001, 1002, 1003, 1004, 104]
+    assert back2.tolist() == [0, 0, 1000, 1001, 1002, 1003, 103]
+
+
+@pytest.mark.parametrize("state,tick_cols", [(2, 5), (2, 1), (3, 9), (1, 2)])
+def test_a_states_ring_keeps_what_the_tick_after_a_verify_row_reads(
+        state, tick_cols):
+    """paged.state_columns' bound, position by position: a verify row writes
+    ``L .. L+n-1``; with any ``a < n`` of them accepted the next tick reads
+    the ``state`` positions before ``L+a+1``, and each still lies in its own
+    column; a ring two columns shorter loses one when nothing is accepted
+    and the row is as wide as it may be."""
+    R, L = paged.state_columns(state, tick_cols), 1000
+    for n in range(1, tick_cols + 1):
+        for a in range(n):
+            read = range(L + a + 1 - state, L + a + 1)
+            live = set(read) | set(range(L, L + n))
+            assert len({p % R for p in live}) == len(live)
+    short = R - 2
+    live = set(range(L + 1 - state, L + tick_cols))
+    assert short < 1 or len({p % short for p in live}) < len(live)
+

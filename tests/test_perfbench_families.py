@@ -1,8 +1,10 @@
 """The seam between the benchmark's harness and a model family
-(perfbench/families/__init__.py), in tier 1, for the four families there
+(perfbench/families/__init__.py), in tier 1, for the five families there
 are: the dense GQA decoder, the latent-attention expert decoder, the
-window-and-global expert decoder (two kinds of cache), and the switch family
-that only the benchmark's tests use.  At toy width on the CPU:
+window-and-global expert decoder (two kinds of cache), the
+short-convolution-and-attention expert decoder (a paged kind and a fixed
+state a slot, a tied head), and the switch family that only the benchmark's
+tests use.  At toy width on the CPU:
 a family's leaf names spell the program's pytree, its program agrees with its
 plain reference, its counts are the pytree's sizes, and only the family with
 routed experts reads a tick's tokens.  Then the new cell's rehearsal."""
@@ -22,8 +24,8 @@ _TESTS_FAMILIES = os.path.join(spec.BENCH_DIR, "tests", "families")
 if _TESTS_FAMILIES not in spec.FAMILY_DIRS:
     spec.FAMILY_DIRS.append(_TESTS_FAMILIES)
 
-FAMILIES = ["dense_gqa", "moe_switch", "latent_moe", "swa_moe"]
-ROUTED_BY_TOKENS = {"latent_moe", "swa_moe"}
+FAMILIES = ["dense_gqa", "moe_switch", "latent_moe", "swa_moe", "conv_moe"]
+ROUTED_BY_TOKENS = {"latent_moe", "swa_moe", "conv_moe"}
 SEED = 2**31 + 27
 
 
@@ -32,21 +34,32 @@ def _toy(family):
         return spec.family({"family": family}).TOY
     cell = {"dense_gqa": "serve-decode",
             "latent_moe": "serve-moe-mla-decode",
-            "swa_moe": "serve-moe-swa-longdoc"}[family]
+            "swa_moe": "serve-moe-swa-longdoc",
+            "conv_moe": "serve-moe-conv-chat"}[family]
     return spec.tiny(spec.cell(cell)[1])
 
 
 def _cache(model, cfg, blocks, size, dtype=None):
     """(cache of ``blocks`` blocks a kind, the table of one row that owns
     them in order): one pool and one table, or one of each a cache kind for
-    a module that declares kinds (a ring as long as the whole context)."""
+    a module that declares kinds (a ring as long as the whole context; a
+    state kind: one slot of ``STATE_COLS`` columns and no table)."""
     import jax.numpy as jnp
     table = jnp.arange(blocks, dtype=jnp.int32)[None]
     if not hasattr(model, "cache_kinds"):
         return model.init_cache(cfg, blocks, size, dtype=dtype), table
-    kinds = [k.name for k in model.cache_kinds(cfg)]
-    return (model.init_cache(cfg, dict.fromkeys(kinds, blocks), size,
-                             dtype=dtype), dict.fromkeys(kinds, table))
+    kinds = model.cache_kinds(cfg)
+    return (model.init_cache(
+        cfg, {k.name: (1, STATE_COLS) if k.state else blocks for k in kinds},
+        size, dtype=dtype), {k.name: table for k in kinds if not k.state})
+
+
+STATE_COLS = 7
+
+
+def _head_is_the_embedding(fam):
+    """A tied head: the embedding is also the last matmul's matrix."""
+    return fam.EMBED[0] in fam.HEAD
 
 
 def test_each_configuration_finds_its_family_file():
@@ -149,14 +162,22 @@ def test_the_counts_are_the_pytrees_sizes_and_the_programs_cache(family):
     assert n["embed"] == math.prod(
         dict((k, s) for k, s, _ in specs)[fam.EMBED[0]])
     vectors = sum(math.prod(s) for _, s, _ in specs if len(s) == 1)
-    assert 0 < n["matmul"] <= n["total"] - n["embed"] - vectors
+    tied = n["embed"] if _head_is_the_embedding(fam) else 0
+    assert 0 < n["matmul"] <= n["total"] - n["embed"] - vectors + tied
     model, cfg = fam.program(config)
     blocks, size = 6, 4
     import jax
     pool, _ = _cache(model, cfg, blocks, size, dtype=jnp.bfloat16)
     held = sum(x.size * x.dtype.itemsize
                for x in jax.tree_util.tree_leaves(pool))
-    if hasattr(fam, "cache_bytes_per_position_per_layer"):
+    if hasattr(fam, "state_bytes_per_slot"):
+        # a paged kind, which alone a new token reads a position of, and a
+        # fixed state a slot
+        state = fam.state_bytes_per_slot(config, STATE_COLS, 2)
+        assert state == 5 * STATE_COLS * config["hidden_size"] * 2
+        assert fam.cache_bytes_per_position(config, 2) * blocks * size \
+            == held - state
+    elif hasattr(fam, "cache_bytes_per_position_per_layer"):
         # layers of several cache kinds: what a layer HOLDS a position is
         # one number, what a new token READS a position of its context is
         # reckoned at a stated context and is less (a window layer reads its
@@ -183,9 +204,11 @@ def test_a_ticks_weight_bytes_grow_with_its_tokens_for_routed_experts_only(
         vectors = sum(math.prod(s) for _, s, _ in fam.leaf_specs(config)
                       if len(s) == 1)
         # at most every held expert once, and then it is all the matrices
-        assert read[3] <= 2 * (n["total"] - n["embed"] - vectors)
-        assert read[3] == pytest.approx(
-            2 * (n["total"] - n["embed"] - vectors), rel=1e-3)
+        # (a tied head's among them)
+        matrices = n["total"] - vectors - (
+            0 if _head_is_the_embedding(fam) else n["embed"])
+        assert read[3] <= 2 * matrices
+        assert read[3] == pytest.approx(2 * matrices, rel=1e-3)
     else:
         assert len(set(read)) == 1
 
@@ -338,7 +361,152 @@ def test_the_swa_readers_read_a_trace_and_the_rings_counters():
         assert spec.metric_reader(name)(bare) is None, name
 
 
+def test_the_conv_cut_is_the_issues_arithmetic():
+    """ISSUE 33's reckoning, held to the configuration file: 4,667,077,376
+    parameters (9.33 GB in bfloat16), 6,144 B a cached position over the
+    three attention layers, every published width, 32 experts, 4 a token,
+    the whole vocabulary, the depth alone reduced, the layer list kept whole,
+    and 9.65 GB resident in the deployment's pools."""
+    entry, config, traffic = spec.cell("serve-moe-conv-chat")
+    fam = spec.family(config)
+    bench = spec.benchmark()
+    listed = next(c for c in bench["configs"] if c["name"] == entry["config"])
+    assert listed["reduced"] == list(config["reduced"]) == ["num_hidden_layers"]
+    assert listed["source"] == config["source"]
+    catalog = {"conv_L_cache": 3, "conv_bias": False, "hidden_size": 2048,
+               "intermediate_size": 7168, "max_position_embeddings": 128000,
+               "model_type": "lfm2_moe", "moe_intermediate_size": 1792,
+               "norm_eps": 1e-05, "norm_topk_prob": True,
+               "num_attention_heads": 32, "num_dense_layers": 2,
+               "num_experts": 32, "num_experts_per_tok": 4,
+               "num_key_value_heads": 8, "rope_theta": 1000000,
+               "routed_scaling_factor": 1, "use_expert_bias": True,
+               "vocab_size": 65536}
+    assert {k: config[k] for k in catalog} == catalog
+    period = ["full_attention", "conv", "conv", "conv"]
+    tail = ["full_attention", "conv", "conv"]
+    assert config["layer_types"] == ["conv", "conv"] + period * 4 + tail * 2
+    assert config["num_hidden_layers"] == 14
+    assert config["reduced"]["num_hidden_layers"]["published"] == 24
+    assert fam.layer_kinds(config) == ["conv+dense"] * 2 + [
+        "attn+routed", "conv+routed", "conv+routed", "conv+routed"] * 3
+    n, dep, e = fam.param_counts(config), config["deployment"], config["engine"]
+    conv = 2048 * 6144 + 2048 * 2048 + 2048 * 3
+    attn = 2 * 2048 * 2048 + 2 * 2048 * 512 + 2 * 64
+    routed = 2048 * 32 + 32 + 32 * 3 * 2048 * 1792
+    assert (conv, attn, routed) == (16_783_360, 10_485_888, 352_387_104)
+    assert n["total"] == dep["parameters"] == 4_667_077_376 == (
+        2 * (conv + 3 * 2048 * 7168 + 4096) + 9 * (conv + routed + 4096)
+        + 3 * (attn + routed + 4096) + 65536 * 2048 + 2048)
+    assert n["total"] == sum(math.prod(s) for _, s, _ in fam.leaf_specs(config))
+    assert dep["weight_bytes"] == 2 * n["total"] == 9_334_154_752
+    # all 24 layers would be the published 8.3 B, tied
+    whole = fam.param_counts(dict(config, num_hidden_layers=24))["total"]
+    assert round(whole / 1e9, 2) == 8.34
+    assert fam.cache_bytes_per_position(config, 2) \
+        == dep["cache_bytes_per_position"] == 3 * 2048
+    assert fam.attn_flops_per_position(config) == 3 * 4 * 32 * 64
+    # the pools: the paged kind covers every slot at full length, the state
+    # kind is the program's bound of columns a slot
+    from horovod_tpu.models import paged
+    cols = paged.state_columns(config["conv_L_cache"] - 1, 1 + 4)
+    assert cols == fam.state_columns(config) == 7
+    assert e["cache_blocks"] * e["block_size"] == 32 * 1536 \
+        == e["max_slots"] * e["max_seq_len"]
+    assert dep["attn_pool_bytes"] == 32 * 1536 * 6144
+    assert dep["conv_state_bytes"] == e["max_slots"] * fam.state_bytes_per_slot(
+        config, cols, 2) == 11 * 32 * 7 * 2048 * 2
+    assert dep["resident_bytes"] == dep["weight_bytes"] \
+        + dep["attn_pool_bytes"] + dep["conv_state_bytes"] >= 9.6e9
+    assert e["max_seq_len"] == traffic["prompt_len"]["max"] \
+        + traffic["output_len"]["max"]
+    assert e["max_batch_tokens"] == e["prefill_chunk"] + 2 * e["max_slots"]
+    assert e["prefix_cache"] is False
+    # a full decode tick reads every expert: the issue's 9.3 GB
+    assert 9.2e9 < fam.tick_weight_bytes(config, 160, 2) < 9.34e9
+    assert fam.pool_op_types(config, "conv") == ["[11,32,7,2048]",
+                                                 "[32,7,2048]"]
+    # the traffic, as ISSUE 33 gives it
+    assert traffic["prompt_len"] == {"median": 192, "sigma": 0.6, "min": 32,
+                                     "max": 1024}
+    assert traffic["output_len"] == {"median": 160, "sigma": 0.5, "min": 32,
+                                     "max": 512}
+    assert "shared_prefix" not in traffic and "sessions" not in traffic
+    knee = traffic["arrivals"]["knee"]["rate_per_s"]
+    assert traffic["arrivals"]["rate_per_s"] == pytest.approx(0.8 * knee)
+
+
+def test_the_parent_process_loads_the_conv_family_without_jax():
+    code = ("import sys; from perfbench.lib import peaks, spec\n"
+            "_, c, _ = spec.cell('serve-moe-conv-chat'); f = spec.family(c)\n"
+            "spec.tiny(c); f.param_counts(c); f.pool_op_types(c, 'conv')\n"
+            "m = {'trace': None, 'config': c,\n"
+            "     'marks': {k: {'stats': {}, 'tick': 0} for k in ('start', 'end')}}\n"
+            "assert f.window_counts(m) is None and f.state_counts(m) is None\n"
+            "assert f.pool_ops_ms(m) is None and f.mixer_share(m) is None\n"
+            "peaks.serve_required_seconds(c, peaks.PEAKS['TPU v5 lite'], 9, 9, 1)\n"
+            "assert 'jax' not in sys.modules and 'numpy' not in sys.modules\n"
+            "types = f.expert_op_types(c)\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge._backends, 'a backend was started'\n"
+            "print(types)")
+    out = subprocess.run([sys.executable, "-c", code], check=True,
+                         cwd=spec.ROOT, timeout=60, capture_output=True,
+                         text=True)
+    from horovod_tpu.models import conv_moe
+    assert out.stdout.strip() == str(
+        [f"[{conv_moe.EXPERT_TILE},1792]", f"[{conv_moe.EXPERT_TILE},2048]"])
+
+
+def test_the_conv_readers_read_a_trace_and_the_states_counters():
+    """The four readers the cell brings, on a made-up trace and marks: the
+    state pool's ops, the mixer's share, the resident share, the experts
+    touched; each None where there is nothing to read, on another family's
+    configuration too."""
+    _, config, _ = spec.cell("serve-moe-conv-chat")
+    state = {"slot_ticks": 160, "state_bytes_ticks": 160 * 315392,
+             "kv_bytes_ticks": 160 * 350 * 11 * 2048}
+    moe = {"ticks": 10, "assignments": 4 * 12 * 10 * 20,
+           "assignments_held": 4 * 12 * 10 * 20, "experts_touched": 2400,
+           "load_max": 30}
+    mark = lambda t, s, m: {"tick": t, "stats": {
+        "moe": m, "kv_pool": {"kinds": {"conv": s}}}}
+    ops = {"fusion bf16[11,32,7,2048]": 0.0004, "gather bf16[160,2048]": 0.0002,
+           "gather bf16[160,2048] params_embed_table": 0.0003,
+           "fusion bf16[160,6144] params_layers_conv_in_proj_kernel": 0.003,
+           "fusion bf16[160,2048] params_layers_conv_taps": 0.0005,
+           "fusion bf16[160,2048] params_layers_conv_out_proj_kernel": 0.001,
+           "fusion bf16[160,2048] params_layers_attn_wo_kernel": 0.002,
+           "expert_tile_ffn f32[64,2048]": 0.03, "while s32[]": 0.001}
+    ctx = {"config": config, "peaks": {"hbm_gbps": 819.0, "bf16_tflops": 197.0},
+           "marks": {"start": mark(0, dict.fromkeys(state, 0),
+                                   dict.fromkeys(moe, 0)),
+                     "end": mark(10, state, moe)},
+           "trace": {"module_count": 5.0, "module_s": 0.09, "ops_s": ops}}
+    read = lambda name: spec.metric_reader(name)(ctx)
+    assert read("conv.state_ops_ms.serve") == pytest.approx(1e3 * 0.0006 / 5)
+    assert read("conv.mixer_share_of_tick.serve") == pytest.approx(
+        100 * 0.0045 / 0.09)
+    assert read("kv.state_resident_share.serve") == pytest.approx(
+        100 * 315392 / (350 * 11 * 2048))
+    assert read("moe.experts_touched_per_routed_layer.serve") == \
+        pytest.approx(20.0)
+    assert read("moe.expert_roofline_share.serve") is not None
+    names = ("conv.state_ops_ms.serve", "conv.mixer_share_of_tick.serve",
+             "kv.state_resident_share.serve",
+             "moe.experts_touched_per_routed_layer.serve")
+    bare = dict(ctx, trace=None, marks={k: {"tick": 0, "stats": {}}
+                                        for k in ("start", "end")})
+    other = dict(ctx, config=spec.cell("serve-moe-swa-longdoc")[1])
+    for name in names:
+        assert spec.metric_reader(name)(bare) is None, name
+        assert spec.metric_reader(name)(other) is None, name
+
+
 @pytest.mark.parametrize("cell,metrics", [
+    ("serve-moe-conv-chat", ("kv.state_resident_share.serve",
+                             "moe.experts_touched_per_routed_layer.serve",
+                             "engine.tick_ms.serve")),
     ("serve-moe-mla-decode", ("moe.experts_touched.serve",
                               "moe.load_max_over_mean.serve",
                               "engine.tick_ms.serve")),

@@ -29,8 +29,9 @@ def one_chip():
 
 
 # (experts held, model width, expert width): smallthinker-21b-a3b whole,
-# openpangu-ultra-moe-718b's share
-@pytest.mark.parametrize("held,d,f", [(64, 2560, 768), (16, 7680, 2048)])
+# openpangu-ultra-moe-718b's share, lfm2-8b-a1b whole
+@pytest.mark.parametrize("held,d,f", [(64, 2560, 768), (16, 7680, 2048),
+                                      (32, 2048, 1792)])
 def test_the_expert_tile_kernel_compiles_at_the_cells_widths(one_chip, held,
                                                             d, f):
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
@@ -57,7 +58,8 @@ def test_the_expert_tile_kernel_compiles_at_the_cells_widths(one_chip, held,
 def _tick_program(cell, C, one_chip):
     """The serving cell's tick at ``[slots, C]`` as ServeEngine builds it
     (copy-on-write, ``apply_cached`` on a budget of ``max_batch_tokens``
-    rows, the greedy token), compiled for the described chip: (text, pool
+    rows, the greedy token — the module's own ``greedy_cached`` where it
+    samples on its rows), compiled for the described chip: (text, pool
     dims)."""
     import dataclasses
 
@@ -70,12 +72,26 @@ def _tick_program(cell, C, one_chip):
     tree = lambda t: jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), t)
     params = tree(jax.eval_shape(lambda: weights.make(
         config, weights.seed_key(0), weights.dtype_of(config))))
-    cache = tree(jax.eval_shape(lambda: model.init_cache(
-        cfg, e["cache_blocks"], e["block_size"])))
     S, i32 = e["max_slots"], jnp.int32
+    # blocks a kind, as ServeEngine sizes them: the paged pool's, and for a
+    # state kind (slots, columns a slot)
+    blocks = e["cache_blocks"]
+    table = sds((S, e["max_seq_len"] // e["block_size"]), i32)
+    if hasattr(model, "cache_kinds"):
+        from horovod_tpu.models import paged
+        kinds = model.cache_kinds(cfg)
+        blocks = {k.name: (S, paged.state_columns(k.state, 5)) if k.state
+                  else e["cache_blocks"] for k in kinds}
+        table = {k.name: table for k in kinds if not k.state}
+    cache = tree(jax.eval_shape(lambda: model.init_cache(
+        cfg, blocks, e["block_size"])))
 
     def step(params, cache, bt, lengths, n_new, tokens, src, dst):
         cache = model.copy_blocks(cache, src, dst)
+        if hasattr(model, "greedy_cached"):
+            out = model.greedy_cached(params, tokens, cfg, cache, bt, lengths,
+                                      n_new)
+            return out[1], out[0]
         out = model.apply_cached(params, tokens, cfg, cache, bt, lengths,
                                  n_new)
         return out[1], jnp.argmax(out[0].astype(jnp.float32), -1)
@@ -83,13 +99,41 @@ def _tick_program(cell, C, one_chip):
     jax.default_backend = lambda: "tpu"     # the expert tile is Mosaic's
     try:
         compiled = jax.jit(step, donate_argnums=(1,)).lower(
-            params, cache, sds((S, e["max_seq_len"] // e["block_size"]), i32),
-            sds((S,), i32), sds((S,), i32), sds((S, C), i32), sds((S,), i32),
-            sds((S,), i32)).compile()
+            params, cache, table, sds((S,), i32), sds((S,), i32),
+            sds((S, C), i32), sds((S,), i32), sds((S,), i32)).compile()
     finally:
         jax.default_backend = orig
     pool = jax.tree_util.tree_leaves(cache)[0].shape
     return compiled.as_text(), "[" + ",".join(map(str, pool)) + "]"
+
+
+@pytest.mark.parametrize("C", [256, 5])
+def test_the_conv_tick_keeps_its_pools_where_they_lie(one_chip, C):
+    """``serve-moe-conv-chat``'s two programs: the paged pool (a position's
+    heads side by side, ``[3, 3072, 16, 512]``) and the conv layers' state
+    (``[11, 32, 7, 2048]``) are scattered into in place and never copied or
+    relaid whole — with a last axis of head_dim 64 the chip laid the pool
+    out blocks-minor and relaid it on the way into and out of every tick
+    (PERF.md §6, PR 33) —, the attention reads a tile of context inside the
+    shared loop (nothing is shaped like a slot's whole context), the expert
+    tile is Mosaic's, and no ``[.., vocab]`` slab wider than the tick's rows
+    exists."""
+    import re
+    text, pool = _tick_program("serve-moe-conv-chat", C, one_chip)
+    ops = re.findall(r" = \w+(\[[\d,]*\])\S* ([\w-]+)\(", text)
+    state = "[11,32,7,2048]"
+    assert pool == "[3,3072,16,512]"
+    assert (pool, "scatter") in ops and (state, "scatter") in ops
+    for whole in (pool, state):
+        assert not [op for op in ops if op[0] == whole
+                    and op[1] in ("copy", "concatenate")]
+    assert not [op for op in ops if op[0].endswith(",1536,512]")]
+    assert "tpu_custom_call" in text and "expert_tile_ffn" in text
+    # the wide tick's 320 packed rows; the narrow one's slab is its rows
+    rows = {256: ("[1,320,65536]", "[320,65536]"),
+            5: ("[32,5,65536]", "[160,65536]")}[C]
+    logits = {op[0] for op in ops if op[0].endswith(",65536]")}
+    assert logits and logits <= set(rows)
 
 
 @pytest.mark.parametrize("C", [64, 5])
