@@ -79,6 +79,12 @@ class LatentMoeConfig:
         return self.kv_rank + self.qk_rope_dim
 
     @property
+    def pool_dim(self) -> int:
+        """A position's columns in the paged pool: the latent and zeros up
+        to a whole number of ``POOL_LANES`` (:func:`init_cache`)."""
+        return -(-self.latent_dim // POOL_LANES) * POOL_LANES
+
+    @property
     def qk_dim(self) -> int:
         return self.qk_nope_dim + self.qk_rope_dim
 
@@ -99,6 +105,11 @@ SCORE_BYTES = 256 << 20
 #: a block of slots whose rows all hold at most this many new tokens attends
 #: in its first columns only (decode rows of a prefill-width tick)
 NARROW_COLS = 8
+
+#: the pool's last axis is a whole number of these (init_cache): a TPU tiles
+#: an array's two minor axes by (8, 128), and a last axis that is no
+#: multiple of 128 is not kept minor by the device's default layout
+POOL_LANES = 128
 
 #: what apply_cached's third value counts, summed over the expert layers
 TICK_COUNTERS = ("ticks",) + X.HELD_COUNTERS
@@ -240,11 +251,17 @@ def apply(params: Dict[str, Any], ids: jax.Array,
 def init_cache(cfg: LatentMoeConfig, num_blocks: int, block_size: int,
                dtype=None) -> Dict[str, jax.Array]:
     """The latent paged pool: ``{"latent": [n_layers, num_blocks,
-    block_size, kv_rank + qk_rope_dim]}`` — ``[c_kv | k_rope]`` a position,
-    whatever the number of heads."""
+    block_size, pool_dim]}`` — ``[c_kv | k_rope | zeros]`` a position,
+    whatever the number of heads.  The zeros make the pool's device layout
+    the one the tick works in: the shape decides it (docs/serving.md
+    #where-the-pool-lies), and with kv_rank + qk_rope_dim = 576 columns a
+    TPU keeps the BLOCK axis minor, while the tick addresses the pool by
+    ``[layer, block]`` and so relaid all of it on the way into and out of
+    every tick (PERF.md §6, PR 36).  At 640 it lies row-major, in the bytes
+    576 columns take there anyway (tiles of 128 lanes)."""
     dtype = dtype if dtype is not None else cfg.dtype
     return {"latent": jnp.zeros((cfg.n_layers, num_blocks, block_size,
-                                 cfg.latent_dim), dtype)}
+                                 cfg.pool_dim), dtype)}
 
 
 def cache_shardings(mesh, cfg: LatentMoeConfig, num_blocks: int):
@@ -313,6 +330,8 @@ def apply_cached(params: Dict[str, Any], tokens: jax.Array,
     blocks = attn_blocks(cfg, S, C,
                          block_tables.shape[1] * cache["latent"].shape[2])
     attend = latent_attend(cfg)
+    # the pool's zero columns (init_cache), as the pool in hand has them
+    pad = cache["latent"].shape[-1] - cfg.latent_dim
     with jax.named_scope("embed"):
         x = L.embedding(params["embed"], take(tokens)).astype(cfg.dtype)
 
@@ -323,14 +342,19 @@ def apply_cached(params: Dict[str, Any], tokens: jax.Array,
                                           cfg, cos, sin, pos_c)
         cache = paged.write(
             cache, i, row_blk, row_off,
-            {"latent": latent.astype(cache["latent"].dtype)})
+            {"latent": jnp.pad(latent.astype(cache["latent"].dtype),
+                               ((0, 0), (0, 0), (0, pad)))})
         wk, wv = _wkv_b(a, cfg)
         with jax.named_scope("attn/latent_scores"):
             q = jnp.concatenate(
                 [jnp.einsum("brhn,lhn->brhl", q_nope, wk), q_rope], -1)
+            # read through the pool's first latent_dim columns: on the
+            # chip the same bytes under another shape, no op of its own
             o = paged.attend_by_blocks(
                 attend, (q, positions, block_tables), n_new, *blocks,
-                bound=paged.Bound(lengths, cache, i, slab))
+                bound=paged.Bound(
+                    lengths, {"latent": cache["latent"][..., :cfg.latent_dim]},
+                    i, slab))
             # [S, H, C, kv_rank] -> the rows
             o = take(jnp.swapaxes(o, 1, 2))
         with jax.named_scope("attn/out"):

@@ -539,7 +539,9 @@ def shardings(mesh, num_blocks: int, head_axis_size: Optional[int] = None):
     return NamedSharding(mesh, P(None, block_axis, None, head_axis, None))
 
 
-def _leaf_key(path) -> str:
+def leaf_key(path) -> str:
+    """A cache leaf's name from its pytree path (block payloads and the
+    engine's record of the pool's layout are keyed by it)."""
     return "/".join(str(getattr(p, "key", p)) for p in path)
 
 
@@ -547,7 +549,7 @@ def read_block(cache: Any, block: int) -> Dict[str, np.ndarray]:
     """One block across all layers as host numpy, keyed by leaf path (spill
     and the prefill hand-off's export): ``[L, bs, ...]`` a leaf."""
     leaves, _ = jax.tree_util.tree_flatten_with_path(cache)
-    return {_leaf_key(path): np.asarray(leaf[:, block])
+    return {leaf_key(path): np.asarray(leaf[:, block])
             for path, leaf in leaves}
 
 
@@ -557,5 +559,5 @@ def write_block(cache: Any, block: int, payload: Dict[str, Any]) -> Any:
     leaves, treedef = jax.tree_util.tree_flatten_with_path(cache)
     return jax.tree_util.tree_unflatten(treedef, [
         leaf.at[:, block].set(
-            np.asarray(payload[_leaf_key(path)]).astype(leaf.dtype))
+            np.asarray(payload[leaf_key(path)]).astype(leaf.dtype))
         for path, leaf in leaves])

@@ -770,6 +770,21 @@ class Scheduler:
         req.ring_blocks = got
         return row
 
+    def pool_blocks(self):
+        """Blocks of the pool, as ``init_cache`` and ``cache_shardings`` take
+        them — of each kind's pool where the model declares kinds: a window
+        kind's holds every slot's ring and is sized by the model's window,
+        the slots and the chunk, not by ``cache_blocks``; a state kind's is
+        (slots, columns a slot), sized by the model's state and the verify
+        row."""
+        if not self.kinds:
+            return self.cfg.cache_blocks
+        return {k.name: (
+            self.rings[k.name].allocator.num_blocks if k.name in self.rings
+            else (self.cfg.max_slots, self.states[k.name].columns)
+            if k.name in self.states else self.cfg.cache_blocks)
+            for k in self.kinds}
+
     def device_tables(self):
         """What the tick addresses its pools with: the block table, or for
         a model that declares cache kinds ``{kind: table}`` — the whole-
@@ -1009,17 +1024,7 @@ class ServeEngine:
                  if hasattr(model, "cache_kinds") else ())
         self.scheduler = Scheduler(cfg, role=role, kinds=kinds)
         self._repl = NamedSharding(mesh, P())
-        # blocks of the pool — of each kind's pool: a window kind's holds
-        # every slot's ring and is sized by the model's window, the slots
-        # and the chunk, not by ``cache_blocks``; a state kind's is (slots,
-        # columns a slot), sized by the model's state and the verify row
-        num_blocks: Any = cfg.cache_blocks
-        if kinds:
-            rings, states = self.scheduler.rings, self.scheduler.states
-            num_blocks = {k.name: (
-                rings[k.name].allocator.num_blocks if k.name in rings else
-                (cfg.max_slots, states[k.name].columns) if k.name in states
-                else cfg.cache_blocks) for k in kinds}
+        num_blocks = self.scheduler.pool_blocks()
         self._cache_shd = model.cache_shardings(mesh, model_cfg, num_blocks)
         leaves = jax.tree_util.tree_leaves(params)
         if leaves and isinstance(leaves[0], jax.Array):
@@ -1107,6 +1112,18 @@ class ServeEngine:
         self._pool_bytes = nbytes(cache_struct)
         self._kind_bytes = {k.name: nbytes(cache_struct[k.name])
                             for k in kinds}
+        # ... and where the device put it: what this process's devices hold
+        # of the pool as laid out (a last axis padded to the device's tiles
+        # is more than its elements), and each leaf's axes from major to
+        # minor.  The shape decides both (docs/serving.md
+        # #where-the-pool-lies); a run's record says what it measured.
+        leaves, _ = jax.tree_util.tree_flatten_with_path(self.cache)
+        self._pool_resident = sum(
+            shard.data.on_device_size_in_bytes()
+            for _, leaf in leaves for shard in leaf.addressable_shards)
+        self._pool_layout = {
+            paged.leaf_key(path): list(leaf.format.layout.major_to_minor)
+            for path, leaf in leaves}
         try:
             from ..perf.memstats import set_kv_pool_provider
             set_kv_pool_provider(self.kv_pool)
@@ -1580,9 +1597,12 @@ class ServeEngine:
         construction; docs/memory.md#kv-pool):
 
           * the allocator's used/free/shared block split;
-          * ``pool_bytes`` — the preallocated cache pytree's true size
+          * ``pool_bytes`` — the preallocated cache pytree's logical size
             (blocks x block_bytes; resident whether or not blocks are
             used — a paged pool's cost is its reservation);
+            ``resident_bytes`` — what this process's devices hold of it
+            as laid out, and ``layout`` — each leaf's axes from major to
+            minor;
           * ``fragmentation`` — the worst-case-reservation waste: 1 -
             tokens actually written over tokens reserved across active
             requests (prefix-cache-held blocks excluded — they hold
@@ -1611,6 +1631,8 @@ class ServeEngine:
             "block_size": self.cfg.block_size,
             "block_bytes": block_bytes,
             "pool_bytes": self._pool_bytes,
+            "resident_bytes": self._pool_resident,
+            "layout": self._pool_layout,
             "used_bytes": occ["used_blocks"] * block_bytes,
             "fragmentation": round(frag, 4),
             "evictions": evictions,

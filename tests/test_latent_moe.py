@@ -303,10 +303,12 @@ def test_the_pool_holds_the_latent_and_no_head_axis(tiny):
     assert engine.model_cfg.max_tick_tokens == 12
     assert cfg.max_tick_tokens == 0
     pool = engine.cache["latent"]
-    assert pool.shape == (cfg.n_layers, 32, 4, cfg.kv_rank + cfg.qk_rope_dim)
+    # a position's 40 values and zeros up to the device's 128 lanes: the
+    # shape puts the pool's layout on the chip (PERF.md §6, PR 36)
+    assert cfg.latent_dim == cfg.kv_rank + cfg.qk_rope_dim == 40
+    assert pool.shape == (cfg.n_layers, 32, 4, 128)
     assert set(engine.cache) == {"latent"}
-    assert engine.kv_pool()["pool_bytes"] == 32 * 4 * cfg.n_layers * (
-        cfg.kv_rank + cfg.qk_rope_dim) * 4
+    assert engine.kv_pool()["pool_bytes"] == 32 * 4 * cfg.n_layers * 128 * 4
     assert engine._cache_shd.spec == jax.sharding.PartitionSpec(
         None, "hvd", None, None)
     engine.close()
@@ -324,7 +326,7 @@ def test_copy_blocks_and_block_transfer_on_the_four_axis_pool(tiny):
                           fill[:, :5].astype(np.float32))   # dst 32 dropped
     payload = engine._read_block(7)
     assert list(payload) == ["latent"]
-    assert payload["latent"].shape == (cfg.n_layers, 4, cfg.latent_dim)
+    assert payload["latent"].shape == (cfg.n_layers, 4, cfg.pool_dim)
     engine._write_block(9, payload)
     assert np.array_equal(np.asarray(engine.cache["latent"][:, 9]),
                           np.asarray(engine.cache["latent"][:, 7]))
@@ -347,38 +349,78 @@ def _is_greedy_by_full_forward(cfg, params, prompt, out, pad_to=32):
 _full = jax.jit(M.apply, static_argnums=2)
 
 
-def test_prefix_hits_cow_and_spill_keep_the_engines_tokens(tiny):
-    """Shared prefixes (whole blocks and a divergence inside one), then pool
-    pressure that spills the prefix to the host and reloads it: the tokens
-    stay the full forward's greedy ones."""
+@pytest.mark.parametrize("path", ["cow", "spill", "handoff"])
+def test_prefix_hits_cow_and_spill_keep_the_engines_tokens(tiny, path):
+    """Shared prefixes (whole blocks and a divergence inside one); pool
+    pressure that spills the prefix to the host and reloads it; a prefill
+    engine's hand-off exported, sent as JSON and imported by a decode
+    engine: the tokens stay the full forward's greedy ones.  The pool's
+    last axis is the latent and zeros up to 128 lanes (``init_cache``):
+    ``paged.read_block`` / ``write_block`` move a block as it lies, zeros
+    and all, nothing ever lands in the zero columns, and ``kv_pool`` says
+    what the pool holds, logically and as laid out, and where it lies."""
+    import json
     cfg, params = tiny
     rng = np.random.RandomState(5)
-    system = rng.randint(0, cfg.vocab, 9).tolist()
-    prompts = [system + [11, 12, 11, 12], system + [11, 12, 11, 99],
-               system + rng.randint(0, cfg.vocab, 3).tolist()]
-    engine = ServeEngine(M, cfg, params, _scfg(prefill_chunk=6, spec_k=4),
-                         mesh=_mesh())
-    reqs = [engine.submit(p, 6, req_id=f"r{i}") for i, p in enumerate(prompts)]
-    engine.flush()
-    for p, r in zip(prompts, reqs):
-        assert _is_greedy_by_full_forward(cfg, params, p, r.out_tokens)
-    st = engine.stats()
-    assert st["prefix_cache"]["hits"] >= 1
-    assert st["prefix_cache"]["cow_copies"] >= 1
-    assert st["moe"]["ticks"] == st["tick"] and st["moe"]["assignments"] > 0
-    engine.close()
-    pa, pb = (rng.randint(0, cfg.vocab, 12).tolist() for _ in range(2))
-    engine = ServeEngine(M, cfg, params, _scfg(
-        max_slots=1, cache_blocks=6, spill_blocks=8, spec_decode=False),
-        mesh=_mesh())
-    for i, p in enumerate((pa, pb, pa)):
-        req = engine.submit(p, 4, req_id=f"s{i}")
+    engines = []
+
+    def build(**kw):
+        role = kw.pop("role", "mixed")
+        engines.append(ServeEngine(M, cfg, params, _scfg(**kw), mesh=_mesh(),
+                                   role=role))
+        return engines[-1]
+
+    def greedy(prompt, out, n):
+        return len(out) == n and _is_greedy_by_full_forward(
+            cfg, params, prompt, out)
+
+    if path == "cow":
+        system = rng.randint(0, cfg.vocab, 9).tolist()
+        prompts = [system + [11, 12, 11, 12], system + [11, 12, 11, 99],
+                   system + rng.randint(0, cfg.vocab, 3).tolist()]
+        engine = build(prefill_chunk=6, spec_k=4)
+        reqs = [engine.submit(p, 6, req_id=f"r{i}")
+                for i, p in enumerate(prompts)]
         engine.flush()
-        assert len(req.out_tokens) == 4 and _is_greedy_by_full_forward(
-            cfg, params, p, req.out_tokens), i
-    spill = engine.kv_pool()["spill"]
-    assert spill["spilled_total"] >= 1 and spill["reloaded_total"] >= 1
-    engine.close()
+        assert all(greedy(p, r.out_tokens, 6) for p, r in zip(prompts, reqs))
+        st = engine.stats()
+        assert st["prefix_cache"]["hits"] >= 1
+        assert st["prefix_cache"]["cow_copies"] >= 1
+        assert st["moe"]["ticks"] == st["tick"] and st["moe"]["assignments"] > 0
+    elif path == "spill":
+        pa, pb = (rng.randint(0, cfg.vocab, 12).tolist() for _ in range(2))
+        engine = build(max_slots=1, cache_blocks=6, spill_blocks=8,
+                       spec_decode=False)
+        for i, p in enumerate((pa, pb, pa)):
+            req = engine.submit(p, 4, req_id=f"s{i}")
+            engine.flush()
+            assert greedy(p, req.out_tokens, 4), i
+        spill = engine.kv_pool()["spill"]
+        assert spill["spilled_total"] >= 1 and spill["reloaded_total"] >= 1
+    else:
+        prompts = [rng.randint(0, cfg.vocab, n).tolist() for n in (9, 13)]
+        pre = build(spec_decode=False, role="prefill")
+        engine = build(spec_decode=False, role="decode")
+        for i, p in enumerate(prompts):
+            pre.submit(p, 6, req_id=f"r{i}")
+        handoffs = []
+        while pre.has_work():
+            handoffs.extend(pre.step().get("handoff", []))
+        assert handoffs and all(
+            b["latent"]["shape"] == [cfg.n_layers, 4, cfg.pool_dim]
+            for h in handoffs for b in h["blocks"])
+        reqs = [engine.import_prefill(json.loads(json.dumps(h)))
+                for h in handoffs]
+        engine.flush()
+        assert all(greedy(p, r.out_tokens, 6) for p, r in zip(prompts, reqs))
+    for e in engines:
+        pool, kv = np.asarray(e.cache["latent"]), e.kv_pool()
+        assert pool.shape[-1] == cfg.pool_dim == 128
+        assert np.any(pool[..., :cfg.latent_dim])
+        assert not np.any(pool[..., cfg.latent_dim:])
+        assert kv["pool_bytes"] == kv["resident_bytes"] == pool.nbytes
+        assert kv["layout"] == {"latent": [0, 1, 2, 3]}
+        e.close()
 
 
 def test_the_engine_serves_the_references_greedy_tokens(toy):

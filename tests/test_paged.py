@@ -149,7 +149,9 @@ def small_tiles(monkeypatch):
 def _attends(model, cfg):
     """(the model's ``attend`` for one tile, the same attention over a whole
     gathered table [s, c, heads, d], the queries' trailing shape, the way
-    back from the scores' layout)."""
+    back from the scores' layout, the pool as the model hands it to the
+    read: latent_moe pads a position to 128 lanes and reads through the
+    latent's own columns)."""
     if model is latent_moe:
         def whole(q, pos, ctx):
             lat = ctx["latent"]
@@ -159,7 +161,8 @@ def _attends(model, cfg):
                               lat[..., :cfg.kv_rank])
         return (latent_moe.latent_attend(cfg), whole,
                 (cfg.n_heads, cfg.latent_dim),
-                lambda o: jnp.swapaxes(o, 1, 2))
+                lambda o: jnp.swapaxes(o, 1, 2),
+                lambda pool: {"latent": pool["latent"][..., :cfg.latent_dim]})
 
     def whole(q, pos, ctx):
         return layers.causal_attention(
@@ -167,7 +170,7 @@ def _attends(model, cfg):
             mask=paged.context_mask(pos, ctx["k"].shape[1]))
     return (llama._attend_tile, whole, (cfg.n_heads, cfg.head_dim),
             lambda o: jnp.moveaxis(o, 3, 1).reshape(
-                o.shape[0], o.shape[3], cfg.n_heads, -1))
+                o.shape[0], o.shape[3], cfg.n_heads, -1), lambda pool: pool)
 
 
 def _bound(lengths, pool, layer=1):
@@ -188,15 +191,16 @@ def test_the_bounded_read_is_the_whole_table_read(kind, plan, small_tiles):
         size=v.shape[:1] + (S * mb,) + v.shape[2:]).astype(np.float32))
         for k, v in pool.items()}
     C, lengths, n_new, tables = _plan(plan)
-    attend, whole, width, back = _attends(model, cfg)
+    attend, whole, width, back, seen = _attends(model, cfg)
     q = jnp.asarray(np.random.default_rng(6).normal(
         size=(S, C) + width).astype(np.float32))
     positions, valid = paged.slot_positions(jnp.asarray(lengths),
                                             jnp.asarray(n_new), C)
     got = back(jax.jit(lambda q: paged.attend_by_blocks(
         attend, (q, positions, jnp.asarray(tables)), jnp.asarray(n_new),
-        READ["slots"], READ["narrow"], bound=_bound(lengths, pool)))(q))
-    want = whole(q, positions, paged.gather(pool, 1, jnp.asarray(tables)))
+        READ["slots"], READ["narrow"], bound=_bound(lengths, seen(pool))))(q))
+    want = whole(q, positions,
+                 paged.gather(seen(pool), 1, jnp.asarray(tables)))
     assert got.shape == want.shape and bool(jnp.isfinite(got).all())
     assert np.asarray(valid).any()
     err = np.abs(np.asarray(got) - np.asarray(want))[np.asarray(valid)]
@@ -254,7 +258,7 @@ def test_a_table_that_is_no_whole_number_of_tiles(kind, monkeypatch):
     that covers positions no query sees."""
     model, cfg, pool = kind
     monkeypatch.setattr(paged, "TILE", 2 * BS)
-    attend, whole, width, back = _attends(model, cfg)
+    attend, whole, width, back, seen = _attends(model, cfg)
     q = jnp.asarray(np.random.default_rng(8).normal(
         size=(3, C) + width).astype(np.float32))
     lengths, n_new = jnp.asarray([7, 6, 0]), jnp.asarray([4, 0, 3])
@@ -262,8 +266,8 @@ def test_a_table_that_is_no_whole_number_of_tiles(kind, monkeypatch):
     positions, valid = paged.slot_positions(lengths, n_new, C)
     got = back(paged.attend_by_blocks(
         attend, (q, positions, tables), n_new, 1, C,
-        bound=_bound(lengths, _device(pool))))
-    want = whole(q, positions, paged.gather(_device(pool), 1, tables))
+        bound=_bound(lengths, seen(_device(pool)))))
+    want = whole(q, positions, paged.gather(seen(_device(pool)), 1, tables))
     err = np.abs(np.asarray(got) - np.asarray(want))[np.asarray(valid)]
     assert float(err.max()) < 2e-6
 

@@ -187,7 +187,15 @@ def test_the_counts_are_the_pytrees_sizes_and_the_programs_cache(family):
         assert a_layer * layers * blocks * size == held
         assert 0 < fam.cache_bytes_per_position(config, 2) <= a_layer * layers
     else:
-        assert fam.cache_bytes_per_position(config, 2) * blocks * size == held
+        # what a position HOLDS: the latent pool pads it with zeros to whole
+        # 128 lanes (latent_moe.init_cache; PERF.md §6, PR 36), which no
+        # token reads and the yardstick does not count
+        values = getattr(cfg, "latent_dim", None)
+        assert fam.cache_bytes_per_position(config, 2) * blocks * size == sum(
+            x[..., :values].size * x.dtype.itemsize
+            for x in jax.tree_util.tree_leaves(pool))
+        assert all(x.shape[-1] - (values or x.shape[-1]) < 128
+                   for x in jax.tree_util.tree_leaves(pool))
     assert fam.attn_flops_per_position(config) > 0
 
 

@@ -6,6 +6,7 @@ no measurement.  All such tests live in this ONE file: the worker that runs
 it loads the TPU's library, and the topology is described inside a fixture,
 never while a module is imported."""
 
+import functools
 import os
 
 import jax
@@ -55,36 +56,35 @@ def test_the_expert_tile_kernel_compiles_at_the_cells_widths(one_chip, held,
     assert f"bf16[{d},{f}]" not in text and f"bf16[1,{d},{f}]" not in text
 
 
-def _tick_program(cell, C, one_chip):
-    """The serving cell's tick at ``[slots, C]`` as ServeEngine builds it
-    (copy-on-write, ``apply_cached`` on a budget of ``max_batch_tokens``
+@functools.lru_cache(maxsize=None)      # a cell compiles once a test run
+def _tick_programs(cell, one_chip):
+    """The serving cell's tick at both of its widths as ServeEngine builds
+    it (copy-on-write, ``apply_cached`` on a budget of ``max_batch_tokens``
     rows, the greedy token — the module's own ``greedy_cached`` where it
-    samples on its rows), compiled for the described chip: (text, pool
-    dims)."""
+    samples on its rows; each kind's pool sized by the scheduler, in the
+    device's default layout for its shape), compiled for the described
+    chip: ({width: text}, (narrow, wide), {leaf: pool dims}, {leaf: the
+    pool's axes from major to minor as the program takes it})."""
     import dataclasses
 
+    from horovod_tpu.models import paged
+    from horovod_tpu.serve import engine as E
     from perfbench.lib import spec, weights
     _, config, _ = spec.cell(cell)
-    e = config["engine"]
+    scfg = E.ServeConfig(**config["engine"])
     model, cfg = spec.family(config).program(config)
-    cfg = dataclasses.replace(cfg, max_tick_tokens=e["max_batch_tokens"])
+    cfg = dataclasses.replace(cfg, max_tick_tokens=scfg.max_batch_tokens)
     sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
     tree = lambda t: jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), t)
     params = tree(jax.eval_shape(lambda: weights.make(
         config, weights.seed_key(0), weights.dtype_of(config))))
-    S, i32 = e["max_slots"], jnp.int32
-    # blocks a kind, as ServeEngine sizes them: the paged pool's, and for a
-    # state kind (slots, columns a slot)
-    blocks = e["cache_blocks"]
-    table = sds((S, e["max_seq_len"] // e["block_size"]), i32)
-    if hasattr(model, "cache_kinds"):
-        from horovod_tpu.models import paged
-        kinds = model.cache_kinds(cfg)
-        blocks = {k.name: (S, paged.state_columns(k.state, 5)) if k.state
-                  else e["cache_blocks"] for k in kinds}
-        table = {k.name: table for k in kinds if not k.state}
+    S, i32 = scfg.max_slots, jnp.int32
+    sched = E.Scheduler(scfg, kinds=model.cache_kinds(cfg)
+                        if hasattr(model, "cache_kinds") else ())
+    tables = jax.tree_util.tree_map(lambda t: sds(t.shape, i32),
+                                    sched.device_tables())
     cache = tree(jax.eval_shape(lambda: model.init_cache(
-        cfg, blocks, e["block_size"])))
+        cfg, sched.pool_blocks(), scfg.block_size)))
 
     def step(params, cache, bt, lengths, n_new, tokens, src, dst):
         cache = model.copy_blocks(cache, src, dst)
@@ -95,16 +95,63 @@ def _tick_program(cell, C, one_chip):
         out = model.apply_cached(params, tokens, cfg, cache, bt, lengths,
                                  n_new)
         return out[1], jnp.argmax(out[0].astype(jnp.float32), -1)
+    widths = (E.decode_width(scfg), scfg.prefill_chunk)
     orig = jax.default_backend
     jax.default_backend = lambda: "tpu"     # the expert tile is Mosaic's
     try:
-        compiled = jax.jit(step, donate_argnums=(1,)).lower(
-            params, cache, table, sds((S,), i32), sds((S,), i32),
+        steps = {C: jax.jit(step, donate_argnums=(1,)).lower(
+            params, cache, tables, sds((S,), i32), sds((S,), i32),
             sds((S, C), i32), sds((S,), i32), sds((S,), i32)).compile()
+            for C in widths}
     finally:
         jax.default_backend = orig
-    pool = jax.tree_util.tree_leaves(cache)[0].shape
-    return compiled.as_text(), "[" + ",".join(map(str, pool)) + "]"
+    fmt = steps[widths[0]].input_formats[0][1]
+    by_leaf = lambda f, t: {paged.leaf_key(path): f(x) for path, x in
+                            jax.tree_util.tree_flatten_with_path(t)[0]}
+    return ({C: c.as_text() for C, c in steps.items()}, widths,
+            by_leaf(lambda x: "[" + ",".join(map(str, x.shape)) + "]", cache),
+            by_leaf(lambda f: f.layout.major_to_minor, fmt))
+
+
+def _tick_program(cell, C, one_chip):
+    """(text, the first leaf's pool dims) of ``_tick_programs``'s program at
+    ``[slots, C]``."""
+    texts, _, pools, _ = _tick_programs(cell, one_chip)
+    return texts[C], next(iter(pools.values()))
+
+
+_OPS = r" = \w+(\[[\d,]*\])\S* ([\w-]+)\("    # (dims, op) of every HLO line
+
+
+# each leaf's axes from major to minor where the device's default is not
+# row-major: the conv state keeps its 32 slots inside its 7 columns (7 would
+# pad to 8 sublanes), and its tick works on it there
+_NOT_ROW_MAJOR = {"serve-moe-conv-chat": {"conv/u": (0, 2, 1, 3)}}
+
+
+@pytest.mark.parametrize("width", ["narrow", "wide"])
+@pytest.mark.parametrize("cell", ["serve-decode", "serve-moe-mla-decode",
+                                  "serve-moe-swa-longdoc",
+                                  "serve-moe-conv-chat"])
+def test_no_tick_relays_a_pool_on_its_way_in_or_out(one_chip, cell, width):
+    """Every serving cell's two programs take each pool as the device's
+    default layout for its SHAPE has it — a module's ``init_cache`` decides
+    the layout by the shape it gives, nothing else can (docs/serving.md
+    #where-the-pool-lies) — and work on it there: every pool is scattered
+    into in place, and no op shaped like a whole pool is a ``copy`` or a
+    ``concatenate``.  A pool that the chip would relay on the way into and
+    out of every tick, as it did the latent pool at ``[5, 5120, 16, 576]``
+    (blocks minor by default: PERF.md §6, PR 36), fails here, on a CPU."""
+    import re
+    texts, widths, pools, layouts = _tick_programs(cell, one_chip)
+    ops = re.findall(_OPS, texts[widths[width == "wide"]])
+    for leaf, pool in pools.items():
+        assert (pool, "scatter") in ops, leaf
+        assert not [op for op in ops if op[0] == pool
+                    and op[1] in ("copy", "concatenate")], leaf
+    row_major = {leaf: tuple(range(pool.count(",") + 1))
+                 for leaf, pool in pools.items()}
+    assert layouts == {**row_major, **_NOT_ROW_MAJOR.get(cell, {})}
 
 
 @pytest.mark.parametrize("C", [256, 5])
@@ -120,7 +167,7 @@ def test_the_conv_tick_keeps_its_pools_where_they_lie(one_chip, C):
     exists."""
     import re
     text, pool = _tick_program("serve-moe-conv-chat", C, one_chip)
-    ops = re.findall(r" = \w+(\[[\d,]*\])\S* ([\w-]+)\(", text)
+    ops = re.findall(_OPS, text)
     state = "[11,32,7,2048]"
     assert pool == "[3,3072,16,512]"
     assert (pool, "scatter") in ops and (state, "scatter") in ops
@@ -142,16 +189,24 @@ def test_the_latent_tick_holds_no_whole_context_and_no_third_pool_copy(
     """``serve-moe-mla-decode``'s two programs: the attention reads a tile
     of context at a time inside the shared loop, so nothing is shaped like a
     slot's whole context (``[slots, 2560, 576]``, whole or split by blocks
-    of slots, which the once-gathered form made and copied a layer); and
-    the loops hold the pool without copying it — the two relayouts of a
-    pool whose last axis is 576 (ROADMAP S10 (b)) are the only copies."""
+    of slots, which the once-gathered form made and copied a layer); the
+    loops hold the pool without copying it; and the pool, a position's 576
+    values padded to 640, lies row-major by default, so the two relayouts a
+    tick of the ``[5, 5120, 16, 576]`` pool began and ended with are gone
+    (ROADMAP S10 (b)): no copy of it at all.  The tiles' gathers read the
+    pool through its 576-wide view, which is the same bytes and no op of its
+    own, and bring back blocks of ``[16, 576]`` (what ``mla.cache_ops_ms.
+    serve`` finds them by)."""
     import re
     text, pool = _tick_program("serve-moe-mla-decode", C, one_chip)
-    ops = re.findall(r" = \w+(\[[\d,]*\])\S* ([\w-]+)\(", text)
-    assert (pool, "scatter") in ops
+    ops = re.findall(_OPS, text)
+    assert pool == "[5,5120,16,640]" and (pool, "scatter") in ops
     assert not [op for op in ops if op[0].endswith(",2560,576]")]
-    assert len([op for op in ops if op == (pool, "copy")]) <= 2
-    assert not [op for op in ops if op == (pool, "concatenate")]
+    assert not [op for op in ops if op[0] == pool
+                and op[1] in ("copy", "concatenate")]
+    seen = {op[1] for op in ops if op[0] == "[5,5120,16,576]"}
+    assert seen and seen <= {"bitcast", "parameter", "get-tuple-element"}
+    assert ("[32,16,576]", "fusion") in ops
 
 
 def _primitives(jaxpr):
