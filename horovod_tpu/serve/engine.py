@@ -49,6 +49,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 from ..models import paged
+from ..utils.profiler import PhaseClock, annotate
 from .config import ServeConfig
 
 
@@ -972,6 +973,40 @@ def tick_width(cfg: ServeConfig, work) -> int:
         else cfg.prefill_chunk
 
 
+# The host's critical path between two programs, part by part: what lies
+# between the instant a tick's tokens were found ready and the return of the
+# next tick's launch (ServeEngine._count_gap).
+TURNAROUND_PARTS = ("fence_copy", "harvest_emit", "plan", "stage", "launch")
+# What the loop keeps beside its phases (PhaseClock.add): cumulative, in a
+# request's done record by the clock's delta, and a second in its timeline.
+_TURN_SUMS = tuple("turn_" + part for part in TURNAROUND_PARTS)
+_LOOP_SUMS = ("fence_ready_s", "fence_copy_s", "narrow", "narrow_wait_s",
+              "wide", "wide_wait_s", "used", "turnaround_s", "turnaround_n",
+              "after_idle_n", "iteration_s") + _TURN_SUMS
+
+
+def _loop_figures(sums: Dict[str, float]) -> Dict[str, Any]:
+    """A clock snapshot's sums under the names ``stats()["loop"]`` gives
+    them."""
+    by_width = {w: {"ticks": int(sums[w]), "wait_s": sums[w + "_wait_s"]}
+                for w in ("narrow", "wide")}
+    parts = {part: sums[name] for part, name in
+             zip(TURNAROUND_PARTS, _TURN_SUMS)}
+    # the code between the spans, and the spans' own entry and exit
+    parts["unspanned"] = sums["turnaround_s"] - sum(parts.values())
+    return {
+        "fence_ready_s": sums["fence_ready_s"],
+        "fence_copy_s": sums["fence_copy_s"],
+        "turnaround_s": sums["turnaround_s"],
+        "turnaround_n": int(sums["turnaround_n"]),
+        "turnaround_parts_s": parts,
+        "after_idle_n": int(sums["after_idle_n"]),
+        "iteration_s": sums["iteration_s"],
+        "by_width": by_width,
+        "narrow_ticks": by_width["narrow"]["ticks"],
+        "narrow_wait_s": by_width["narrow"]["wait_s"]}
+
+
 class ServeEngine:
     """The continuous-batching engine: host scheduler + one jit'd mixed
     prefill/decode step over the paged cache, compiled at two widths
@@ -1055,8 +1090,14 @@ class ServeEngine:
         # Where a tick's host time goes, phase by phase (utils/profiler.py
         # PhaseClock); the serving loop (serve/worker.py) times its own
         # phases on the same clock.
-        from ..utils.profiler import PhaseClock
         self.clock = PhaseClock()
+        for name in _LOOP_SUMS:     # every figure is there from the start
+            self.clock.add(name, 0)
+        # The gap between two programs (_count_gap): the ready stamp and the
+        # two spans of the tick the last _harvest fenced, None where it
+        # fenced none, and when the launch before returned.
+        self._fenced: Optional[Tuple[float, Any, Any]] = None
+        self._launch_t = 0.0
         # What the tick counts beside its logits: one small vector a tick
         # (the expert layers' assignments, models/latent_moe.py), summed at
         # every harvest.
@@ -1066,10 +1107,6 @@ class ServeEngine:
         # The step's executable at each tick width, both compiled at the
         # first dispatch (_compile_steps): no later tick lowers anything.
         self._steps: Dict[int, Any] = {}
-        # Ticks run at the decode width, and their share of the
-        # ``harvest_wait`` phase's seconds.
-        self._narrow_ticks = 0
-        self._narrow_wait_s = 0.0
         # What the wide ticks' plans filled of what the model computed:
         # [valid tokens, rows] and [attention blocks at chunk width, blocks]
         # (``model.attn_blocks``: slots a block, a narrow block's columns).
@@ -1187,11 +1224,6 @@ class ServeEngine:
             self._steps[width] = self._step_fn.lower(
                 self.params, self.cache, *args).compile()
 
-    def _loop_snapshot(self) -> Dict[str, Any]:
-        """The phase clock's snapshot with the narrow-tick counters."""
-        return dict(self.clock.snapshot(), narrow_ticks=self._narrow_ticks,
-                    narrow_wait_s=self._narrow_wait_s)
-
     # ------------------------------------------------------------ intake
     def submit(self, tokens, max_new_tokens: int,
                req_id: Optional[str] = None,
@@ -1201,7 +1233,7 @@ class ServeEngine:
                       eos_id=eos_id if eos_id is not None
                       else self.cfg.eos_id)
         req.trace = trace
-        req.loop0 = self._loop_snapshot()
+        req.loop0 = self.clock.snapshot()
         return self.scheduler.submit(req)
 
     def has_work(self) -> bool:
@@ -1266,7 +1298,7 @@ class ServeEngine:
                               if handoff.get("eos_id") is not None
                               else self.cfg.eos_id))
         req.trace = handoff.get("trace")
-        req.loop0 = self._loop_snapshot()
+        req.loop0 = self.clock.snapshot()
         req.upstream = {k: float(handoff[k])
                         for k in ("queue_s", "prefill_s")
                         if handoff.get(k) is not None} or None
@@ -1319,12 +1351,12 @@ class ServeEngine:
         return out
 
     def _dispatch(self) -> None:
-        with self.clock.span("plan"):
+        with self.clock.span("plan") as plan:
             work, copies = self._plan()
         if not work:
             return
         cfg = self.cfg
-        with self.clock.span("stage"):
+        with self.clock.span("stage") as stage:
             S, C = cfg.max_slots, tick_width(cfg, work)
             tokens = np.zeros((S, C), np.int32)
             lengths = np.zeros(S, np.int32)
@@ -1353,11 +1385,12 @@ class ServeEngine:
             dev = [{k: put(t) for k, t in tables.items()}
                    if isinstance(tables, dict) else put(tables)] + [
                 put(a) for a in (lengths, n_new, tokens, copy_src, copy_dst)]
-        with self.clock.span("launch"):
+        with self.clock.span("launch") as launch:
             if not self._steps:
                 self._compile_steps(dev)
             self.cache, next_tokens, counters = self._steps[C](
                 self.params, self.cache, *dev)
+        self._count_gap(plan, stage, launch)
         used = int(n_new.sum())
         self._read += paged.read_counts(
             lengths, n_new, C, *self._attn_blocks, cfg.block_size,
@@ -1365,6 +1398,29 @@ class ServeEngine:
         self._last_fill = used / cfg.max_batch_tokens
         self._inflight.append((self.tick, work, next_tokens, used, counters))
         self.tick += 1
+
+    def _count_gap(self, plan, stage, launch) -> None:
+        """One launch against the fence before it.  Back to back — this
+        step() fenced a tick and launched the next —, the seconds from the
+        ready stamp to the launch's return are the host's whole critical
+        path between two programs (``turnaround_s``), kept with the spans
+        it is made of (the rest of it is ``unspanned``), and
+        launch return to launch return is the busy loop's period
+        (``iteration_s``).  A launch with nothing fenced before it in its
+        step() waited for traffic, not for the host: counted, not timed."""
+        add = self.clock.add
+        if self._fenced is None:
+            add("after_idle_n", 1)
+        else:
+            ready, wait, emit = self._fenced
+            add("turnaround_s", launch.t1 - ready)
+            add("turnaround_n", 1)
+            for name, part in zip(_TURN_SUMS, (
+                    wait.t1 - ready, emit.t1 - emit.t0, plan.t1 - plan.t0,
+                    stage.t1 - stage.t0, launch.t1 - launch.t0)):
+                add(name, part)
+            add("iteration_s", launch.t1 - self._launch_t)
+        self._launch_t = launch.t1
 
     def _plan(self):
         """The host's decisions for one dispatch: the scheduler's work
@@ -1416,22 +1472,37 @@ class ServeEngine:
 
     def _harvest(self) -> Dict[str, Any]:
         if not self._inflight:
+            self._fenced = None
+            self.clock.second()
             return {"tick": None, "processed": 0, "emitted": {},
                     "finished": [], "handoff": []}
         tick, work, next_tokens, used, counters = self._inflight.popleft()
-        waited = self.clock.phase_s.get("harvest_wait", 0.0)
-        with self.clock.span("harvest_wait"):
-            tokens_host = np.asarray(next_tokens)  # D2H fence for this tick
-            if counters is not None:
-                self._counters += np.asarray(counters)
-        if next_tokens.shape[1] < self.cfg.prefill_chunk:
-            self._narrow_ticks += 1
-            self._narrow_wait_s += \
-                self.clock.phase_s["harvest_wait"] - waited
-        else:
+        clock = self.clock
+        # The fence in two parts under its one phase: the device still runs
+        # (or has not started), then it is done and idle while the host
+        # fetches the tokens.
+        with clock.span("harvest_wait") as wait:
+            with annotate("hvd:fence_ready"):
+                next_tokens.block_until_ready()
+            ready = time.perf_counter()
+            clock.second()      # a tick lies in the second it was fenced in
+            with annotate("hvd:fence_copy"):
+                tokens_host = np.asarray(next_tokens)
+                if counters is not None:
+                    self._counters += np.asarray(counters)
+        width = ("narrow" if next_tokens.shape[1] < self.cfg.prefill_chunk
+                 else "wide")
+        clock.add("fence_ready_s", ready - wait.t0)
+        clock.add("fence_copy_s", wait.t1 - ready)
+        clock.add(width, 1)
+        clock.add(width + "_wait_s", wait.t1 - wait.t0)
+        clock.add("used", used)
+        if width == "wide":
             self._count_wide(work, used)
-        with self.clock.span("harvest_emit"):
-            return self._emit(tick, work, tokens_host, used)
+        with clock.span("harvest_emit") as emit:
+            report = self._emit(tick, work, tokens_host, used)
+        self._fenced = (ready, wait, emit)
+        return report
 
     def _count_wide(self, work, used: int) -> None:
         """One wide tick's plan against the program that ran it: the rows
@@ -1551,13 +1622,16 @@ class ServeEngine:
         if req.loop0 is None:
             return
         d = self.clock.delta(req.loop0)
+        sums = d["sums"]
         req.loop = {
             "ticks": d["phase_n"].get("harvest_wait", 0),
-            "narrow_ticks": self._narrow_ticks - req.loop0["narrow_ticks"],
-            "narrow_wait_s": round(
-                self._narrow_wait_s - req.loop0["narrow_wait_s"], 6),
+            "narrow_ticks": int(sums["narrow"]),
+            "narrow_wait_s": round(sums["narrow_wait_s"], 6),
             "prefill_ticks": req.prefill_ticks,
             "phase_s": {k: round(v, 6) for k, v in d["phase_s"].items()},
+            # how much of its life was the host's path between two programs
+            "turnaround_s": round(sums["turnaround_s"], 6),
+            "fence_copy_s": round(sums["fence_copy_s"], 6),
             "compiles": d["compiles"]}
         req.loop0 = None
 
@@ -1741,7 +1815,11 @@ class ServeEngine:
             },
         }
         share = lambda c: round(int(c[0]) / int(c[1]), 4) if c[1] else None
-        out["loop"] = dict(self._loop_snapshot(), ticks=self._ticks(),
+        snap = self.clock.snapshot()
+        sums = snap.pop("sums")
+        out["loop"] = dict(snap, **_loop_figures(sums),
+                           timeline=self.clock.timeline(),
+                           ticks=self._ticks(),
                            wide_rows_share=share(self._wide_rows),
                            wide_blocks_share=share(self._wide_blocks),
                            context_read_share=share(self._read[:2]),

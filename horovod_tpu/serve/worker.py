@@ -421,6 +421,10 @@ class FleetFrontend:
             payload = dict(self.engine.stats(),
                            replica_id=self.replica_id)
             payload["queue_depth"] = int(payload.get("waiting", 0))
+            if not force:
+                # the periodic payload is encoded inside ``hvd:publish``
+                # and keeps its size; the one at exit has the timeline
+                payload.get("loop", {}).pop("timeline", None)
             fps = getattr(self.engine, "prefix_fps", None)
             if fps is not None:
                 # Affinity piggyback (serve/replica.py): the router

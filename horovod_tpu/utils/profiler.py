@@ -18,6 +18,8 @@ import contextlib
 import time
 from typing import Callable, Dict, Iterator, Optional, Tuple
 
+import numpy as np
+
 _active_logdir: Optional[str] = None
 _compiles = {"compiles": 0, "cache_hits": 0, "listening": False}
 
@@ -119,8 +121,9 @@ def compile_counts() -> Dict[str, int]:
 
 class _Span:
     """One entry of a PhaseClock phase: the profiler range, and on exit
-    the phase's seconds and count."""
-    __slots__ = ("clock", "name", "note", "t0")
+    the phase's seconds and count.  ``t0`` and ``t1`` are its two
+    ``perf_counter`` readings, for a caller that stamps between them."""
+    __slots__ = ("clock", "name", "note", "t0", "t1")
 
     def __init__(self, clock: "PhaseClock", name: str):
         self.clock, self.name = clock, name
@@ -129,10 +132,13 @@ class _Span:
     def __enter__(self):
         self.note.__enter__()
         self.t0 = time.perf_counter()
+        return self
 
     def __exit__(self, *exc):
-        c, dt = self.clock, time.perf_counter() - self.t0
-        c.phase_s[self.name] = c.phase_s.get(self.name, 0.0) + dt
+        c = self.clock
+        self.t1 = time.perf_counter()
+        c.phase_s[self.name] = c.phase_s.get(self.name, 0.0) \
+            + (self.t1 - self.t0)
         c.phase_n[self.name] = c.phase_n.get(self.name, 0) + 1
         self.note.__exit__(*exc)
 
@@ -141,26 +147,90 @@ class PhaseClock:
     """Cumulative seconds and entries per named phase of a host loop.
     ``span(name)`` also opens the ``hvd:<name>`` profiler range, so inside
     a trace session the phase lies on the device trace's own clock; with
-    none running that costs a flag test.  One thread enters spans; readers
-    take ``snapshot()`` and subtract (``delta``)."""
+    none running that costs a flag test.  ``add(name, value)`` keeps any
+    other running figure of the loop (``sums``).  One thread enters spans
+    and adds; readers take ``snapshot()`` and subtract (``delta``).
+
+    A total cannot say WHEN.  ``second()``, called once a tick, closes a
+    bucket whenever the wall second has moved on: what every phase and sum
+    gained since the bucket was opened, one row of a preallocated ring of
+    the last ``SECONDS`` such seconds, which ``timeline()`` reads.  A span
+    or an ``add`` does nothing for it."""
+
+    SECONDS = 128
+    # the ring's columns, in the order the names were first seen: a name
+    # past them is kept in its total and not in the ring
+    PHASES, SUMS = 16, 32
 
     def __init__(self):
         self.phase_s: Dict[str, float] = {}
         self.phase_n: Dict[str, int] = {}
+        self.sums: Dict[str, float] = {}
+        # a row of the ring: where each table's columns start, and how many
+        self._tables = ((0, self.phase_s, self.PHASES),
+                        (self.PHASES, self.phase_n, self.PHASES),
+                        (2 * self.PHASES, self.sums, self.SUMS))
+        self._ring = np.zeros((self.SECONDS, 2 * self.PHASES + self.SUMS))
+        # row 0 takes what comes before the first ``second()``: it keeps
+        # second 0, which ``timeline()`` never shows
+        self._sec = [0] * self.SECONDS
+        self._row = 0
+        self._opened = self._figures()
         compile_counts()  # count compiles from the loop's first tick on
 
     def span(self, name: str) -> _Span:
         return _Span(self, name)
 
+    def add(self, name: str, value: float) -> None:
+        self.sums[name] = self.sums.get(name, 0) + value
+
+    def _figures(self) -> np.ndarray:
+        """The running figures as one row of the ring."""
+        row = np.zeros(self._ring.shape[1])
+        for at, table, width in self._tables:
+            values = list(table.values())[:width]
+            row[at:at + len(values)] = values
+        return row
+
+    def second(self) -> None:
+        """Bucket by the present wall second (``int(time.time())``: the
+        clock an observer outside the process and a profiler's host plane
+        share); the loop calls it once a tick."""
+        sec = int(time.time())
+        if sec == self._sec[self._row]:
+            return
+        now = self._figures()
+        self._ring[self._row] = now - self._opened
+        self._opened = now
+        self._row = (self._row + 1) % self.SECONDS
+        self._sec[self._row] = sec
+
+    def timeline(self) -> Dict[str, object]:
+        """The ring's seconds as column lists, oldest first, the open one
+        (so far) last: ``sec``, every phase's seconds and entries under
+        ``phase_s`` and ``phase_n``, every sum under its name."""
+        order = [(self._row + 1 + i) % self.SECONDS
+                 for i in range(self.SECONDS)]
+        order = [row for row in order if self._sec[row]]
+        table = self._ring[order]
+        if order:
+            table[-1] = self._figures() - self._opened
+        columns = np.round(table, 6).T.tolist()
+        phase_s, phase_n, sums = (
+            dict(zip(list(names)[:width], columns[at:]))
+            for at, names, width in self._tables)
+        return dict(sums, sec=[self._sec[row] for row in order],
+                    phase_s=phase_s, phase_n=phase_n)
+
     def snapshot(self) -> Dict[str, object]:
         return dict(compile_counts(), phase_s=dict(self.phase_s),
-                    phase_n=dict(self.phase_n))
+                    phase_n=dict(self.phase_n), sums=dict(self.sums))
 
     def delta(self, since: Dict[str, object]) -> Dict[str, object]:
         """``snapshot()`` less the earlier snapshot ``since``."""
         now = self.snapshot()
         out = {k: now[k] - since.get(k, 0) for k in ("compiles", "cache_hits")}
-        for k in ("phase_s", "phase_n"):
+        for k in ("phase_s", "phase_n", "sums"):
             out[k] = {p: v - since.get(k, {}).get(p, 0)
                       for p, v in now[k].items()}
         return out

@@ -65,6 +65,92 @@ def test_phase_clock_counts_a_span_that_raises():
     assert clock.phase_n == {"a": 1}
 
 
+def _fake_seconds(monkeypatch, start=1_000_000):
+    """The profiler module's wall clock, moved by hand."""
+    now = {"t": float(start)}
+    monkeypatch.setattr("horovod_tpu.utils.profiler.time.time",
+                        lambda: now["t"])
+    return now
+
+
+def test_phase_clock_sums_ride_in_snapshot_and_delta():
+    clock = PhaseClock()
+    clock.add("n", 2)
+    snap = clock.snapshot()
+    clock.add("n", 3)
+    clock.add("s", 0.25)
+    assert clock.delta(snap)["sums"] == {"n": 3, "s": 0.25}
+    assert snap["sums"] == {"n": 2}
+    # a sum is no phase: the table the phase readers add up has none
+    assert clock.phase_s == {} and clock.phase_n == {}
+
+
+def test_phase_clock_timeline_buckets_by_the_wall_second(monkeypatch):
+    now = _fake_seconds(monkeypatch)
+    clock = PhaseClock()
+    with clock.span("early"):       # before the first second(): in no bucket
+        pass
+    clock.add("n", 7)
+    assert clock.timeline()["sec"] == []
+    clock.second()
+    clock.add("n", 1)
+    now["t"] += 0.5
+    clock.second()                  # the same second: the same bucket
+    clock.add("n", 1)
+    now["t"] += 2.0                 # a second with no tick has no bucket
+    clock.second()
+    clock.add("n", 5)
+    with clock.span("late"):
+        pass
+    tl = clock.timeline()
+    assert tl["sec"] == [1_000_000, 1_000_002]
+    assert tl["n"] == [2.0, 5.0]    # the open second shows what it has so far
+    assert tl["phase_n"]["late"] == [0.0, 1.0]
+    assert tl["phase_n"]["early"] == [0.0, 0.0]
+    assert set(tl["phase_s"]) == {"early", "late"}
+
+
+def test_phase_clock_ring_wraps_at_128_without_growing(monkeypatch):
+    now = _fake_seconds(monkeypatch)
+    clock = PhaseClock()
+    shape = clock._ring.shape
+    for i in range(300):
+        now["t"] += 1.0
+        clock.second()
+        clock.add("i", i)
+        with clock.span("a"):
+            pass
+    tl = clock.timeline()
+    assert clock._ring.shape == shape and shape[0] == PhaseClock.SECONDS == 128
+    assert len(tl["sec"]) == 128 and tl["sec"] == sorted(tl["sec"])
+    assert tl["sec"][-1] == int(now["t"])
+    # the last 128 seconds' adds and entries, and nothing older
+    assert tl["i"] == [float(i) for i in range(172, 300)]
+    assert tl["phase_n"]["a"] == [1.0] * 128
+    assert clock.sums["i"] == sum(range(300))
+
+
+def test_phase_clock_keeps_a_name_past_the_rings_columns_in_its_total(
+        monkeypatch):
+    _fake_seconds(monkeypatch)
+    clock = PhaseClock()
+    clock.second()
+    for i in range(PhaseClock.PHASES + 4):
+        with clock.span(f"p{i}"):
+            pass
+    for i in range(PhaseClock.SUMS + 4):
+        clock.add(f"s{i}", 1)
+    tl = clock.timeline()
+    assert list(tl["phase_s"]) == list(tl["phase_n"]) == \
+        [f"p{i}" for i in range(PhaseClock.PHASES)]
+    assert all(tl["phase_n"][name] == [1.0] for name in tl["phase_n"])
+    assert [k for k in tl if k.startswith("s") and k != "sec"] == \
+        [f"s{i}" for i in range(PhaseClock.SUMS)]
+    assert all(tl[f"s{i}"] == [1.0] for i in range(PhaseClock.SUMS))
+    assert len(clock.phase_n) == PhaseClock.PHASES + 4
+    assert len(clock.sums) == PhaseClock.SUMS + 4
+
+
 def test_compile_counts_sees_a_new_program_once():
     before = compile_counts()["compiles"]
     f = jax.jit(lambda x: x * 3 + 1)
@@ -150,13 +236,19 @@ def served():
             f"http://127.0.0.1:{port}/admin/drain", data=b"{}",
             headers={"Content-Type": "application/json"}, method="POST"),
             timeout=30).read()
+        loop.join(timeout=30)
+        # what the loop published as it ended (`_publish_stats(force=True)`)
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{port}/serve/stats", timeout=10) as r:
+            at_exit = json.loads(r.read())
     finally:
         loop.join(timeout=30)
         engine.close()
         server.stop()
     assert not loop.is_alive()
     done = {name: lines[-1] for name, lines in answers.items()}
-    return {"done": done, "seen": seen, "engine": engine, "stats": stats}
+    return {"done": done, "seen": seen, "engine": engine, "stats": stats,
+            "at_exit": at_exit}
 
 
 def test_every_phase_has_a_count(served):
@@ -252,6 +344,120 @@ def test_request_narrow_ticks_equal_those_counted_outside(served, name):
     # but `short`, whose 3-token prompt fits the decode width
     assert loop["narrow_ticks"] >= 1
     assert 0.0 <= loop["narrow_wait_s"] <= loop["phase_s"]["harvest_wait"]
+
+
+# ------------------------------------------- the gap between two programs
+GAP_KEYS = {"fence_ready_s", "fence_copy_s", "turnaround_s", "turnaround_n",
+            "turnaround_parts_s", "after_idle_n", "iteration_s", "by_width",
+            "timeline"}
+PARTS = {"fence_copy", "harvest_emit", "plan", "stage", "launch", "unspanned"}
+
+
+def test_phase_table_keeps_the_parents_nine_keys(served):
+    loop = served["engine"].stats()["loop"]
+    assert GAP_KEYS <= set(loop)
+    # none of the new figures is a phase: `engine.loop_host_ms.serve` adds
+    # up every key of phase_s, and a request's phases sum to its life
+    assert set(loop["phase_s"]) == set(loop["phase_n"]) == PHASES
+    for done in served["done"].values():
+        assert set(done["loop"]["phase_s"]) <= PHASES
+
+
+def test_fence_parts_add_up_to_the_wait(served):
+    loop = served["engine"].stats()["loop"]
+    assert loop["fence_ready_s"] > 0 and loop["fence_copy_s"] > 0
+    assert abs(loop["fence_ready_s"] + loop["fence_copy_s"]
+               - loop["phase_s"]["harvest_wait"]) < 1e-4
+
+
+def test_turnaround_parts_add_up_to_it(served):
+    loop = served["engine"].stats()["loop"]
+    parts = loop["turnaround_parts_s"]
+    assert set(parts) == PARTS
+    assert abs(sum(parts.values()) - loop["turnaround_s"]) < 1e-6
+    assert all(v >= 0 for v in parts.values())
+    # a span's own part is at most its phase: it counts back-to-back ticks
+    for name in PARTS - {"fence_copy", "unspanned"}:
+        assert parts[name] <= loop["phase_s"][name] + 1e-9
+    assert parts["fence_copy"] <= loop["fence_copy_s"] + 1e-9
+
+
+def test_every_launch_is_back_to_back_or_after_idle(served):
+    loop = served["engine"].stats()["loop"]
+    assert loop["turnaround_n"] + loop["after_idle_n"] == \
+        loop["phase_n"]["launch"] == loop["ticks"]
+    # `cold` found the engine with nothing in flight, and its ticks after
+    # the first were launched in the step() that fenced the one before
+    assert loop["after_idle_n"] >= 1 and loop["turnaround_n"] >= 1
+
+
+def test_turnaround_lies_inside_the_busy_period(served):
+    loop = served["engine"].stats()["loop"]
+    assert 0 < loop["turnaround_s"] <= loop["iteration_s"]
+    # the busy period has the programs in it: the waits of the ticks fenced
+    assert loop["iteration_s"] <= sum(loop["phase_s"].values())
+
+
+def test_narrow_figures_are_read_off_by_width(served):
+    loop = served["engine"].stats()["loop"]
+    narrow, wide = loop["by_width"]["narrow"], loop["by_width"]["wide"]
+    assert loop["narrow_ticks"] == narrow["ticks"]
+    assert loop["narrow_wait_s"] == narrow["wait_s"]
+    assert narrow["ticks"] + wide["ticks"] == loop["ticks"]
+    assert wide["ticks"] == served["seen"]["widths"].count(8)
+    assert abs(narrow["wait_s"] + wide["wait_s"]
+               - loop["phase_s"]["harvest_wait"]) < 1e-6
+    assert not hasattr(served["engine"], "_narrow_ticks")
+
+
+def test_timeline_columns_add_up_to_the_running_figures(served):
+    loop = served["engine"].stats()["loop"]
+    tl = loop["timeline"]
+    assert 1 <= len(tl["sec"]) <= PhaseClock.SECONDS
+    assert tl["sec"] == sorted(set(tl["sec"]))
+    assert all(len(col) == len(tl["sec"]) for col in
+               [*tl["phase_s"].values(), *tl["phase_n"].values(),
+                tl["narrow"], tl["wide"], tl["used"], tl["fence_copy_s"],
+                tl["turnaround_s"], tl["turnaround_n"], tl["after_idle_n"]])
+    assert set(tl["phase_s"]) == PHASES
+    # the engine opens the first bucket in its first step(): every figure
+    # it keeps lies in the timeline whole
+    for name in ("fence_copy_s", "turnaround_s", "turnaround_n",
+                 "after_idle_n"):
+        assert abs(sum(tl[name]) - loop[name]) < 1e-4, name
+    assert sum(tl["narrow"]) == loop["narrow_ticks"]
+    assert sum(tl["narrow"]) + sum(tl["wide"]) == loop["ticks"]
+    stats = served["engine"].stats()
+    assert sum(tl["used"]) >= stats["tokens_prefill"] > 0
+    for name in ("harvest_wait", "harvest_emit", "plan", "stage", "launch"):
+        assert abs(sum(tl["phase_s"][name]) - loop["phase_s"][name]) < 1e-4
+        assert sum(tl["phase_n"][name]) == loop["phase_n"][name]
+    # the loop's own phases but for its first iteration, before that step()
+    for name in ("poll", "submit", "publish", "idle"):
+        assert sum(tl["phase_s"][name]) <= loop["phase_s"][name] + 1e-4
+        assert loop["phase_n"][name] - 1 <= sum(tl["phase_n"][name]) <= \
+            loop["phase_n"][name]
+
+
+@pytest.mark.parametrize("name", ["cold", "warm", "short"])
+def test_request_turnaround_is_part_of_its_life(served, name):
+    loop = served["done"][name]["loop"]
+    assert 0.0 <= loop["turnaround_s"] <= sum(loop["phase_s"].values())
+    assert 0.0 < loop["fence_copy_s"] <= loop["phase_s"]["harvest_wait"]
+    # every request here decodes behind its own prefill: some of its ticks
+    # were launched in the step() that fenced the one before
+    assert loop["turnaround_s"] > 0.0
+
+
+def test_only_the_forced_stats_payload_carries_the_timeline(served):
+    # the once-a-second payload is encoded inside `hvd:publish`
+    periodic = served["stats"]["engine"]["loop"]
+    assert "timeline" not in periodic
+    assert GAP_KEYS - {"timeline"} <= set(periodic)
+    at_exit = served["at_exit"]["engine"]["loop"]
+    assert at_exit["timeline"]["sec"]
+    assert at_exit["ticks"] == served["seen"]["harvests"] == \
+        sum(at_exit["timeline"]["narrow"]) + sum(at_exit["timeline"]["wide"])
 
 
 def test_scripted_engine_without_a_clock_is_served_as_before():
