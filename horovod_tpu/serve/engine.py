@@ -23,11 +23,13 @@ Scheduling (in-flight/continuous batching, the Orca/vLLM discipline):
     blocks the same tick, so the next waiting request replaces it
     mid-flight.
 
-The tick is pipelined one deep (the ``data/loader.py prefetch`` deque
-pattern on the host<->device legs): ``step()`` first harvests the
-PREVIOUS tick's device results, then plans/assembles/dispatches the next
-tick asynchronously — host scheduling overlaps device compute instead of
-serializing after it.
+The decode chain closes on the device (docs/serving.md#the-loops-order):
+a tick's program hands the next, as device arrays, every slot's length, the
+end of its stream and the token history its n-gram drafter reads, so
+``step()`` launches tick N+1 while tick N is still unfenced and only then
+fences N and emits what the device reports.  The next program is queued on
+the device when the running one ends; the host's plan, staging, copy and
+publishing run behind a program, not between two.
 
 Determinism: greedy (argmax) sampling on device, FCFS admission, LIFO
 block reuse — given the same request sequence every rank computes the
@@ -415,7 +417,12 @@ class Request:
         self.blocks: List[int] = []
         # the blocks of each window kind's ring (Scheduler._with_rings)
         self.ring_blocks: Dict[str, List[int]] = {}
-        self.draft: List[int] = []      # this tick's speculative tokens
+        # its history is on the device and a row of it has been launched:
+        # its decode rows take token and length from the tick before
+        self.carried = False
+        # its launched rows that sample and no step() has fenced yet (at
+        # most one between two): each emits a token, or its stream has ended
+        self.unfenced = 0
         self._bigram: Dict[Tuple[int, int], int] = {}
         self._indexed = 0   # context positions already in the index
         self.submitted_t = time.perf_counter()
@@ -470,7 +477,9 @@ class Request:
         generated token) and deliberately excludes the final bigram
         itself, so a repeating tail still finds its earlier occurrence.
         A pure function of prompt + emitted tokens — deterministic on
-        every rank (the lockstep contract)."""
+        every rank (the lockstep contract).  The serving tick drafts by
+        this rule on the device (``draft_rows``); this is the rule as the
+        host states it, the oracle the tests hold the device to."""
         L = len(self.tokens) + len(self.out_tokens)
         if k < 1 or L < 3:
             return []
@@ -651,28 +660,30 @@ class Scheduler:
     # -------------------------------------------------------------- plan
     def plan(self) -> List[Tuple[int, Request, int]]:
         """One tick's work under the token budget: decode slots first
-        (1 token + up to spec_k verified drafts each, latency-critical),
+        (1 token + room for spec_k verified drafts each, latency-critical),
         prefill continuations next, FCFS admissions into the remainder.
-        Deterministic given state."""
+        Deterministic given state.  A request is in ``decode`` from the
+        launch of its prompt's last chunk on (ServeEngine._dispatch): its
+        decode row follows without waiting for that tick's fence."""
         budget = self.cfg.max_batch_tokens
         chunk = self.cfg.prefill_chunk
         work: List[Tuple[int, Request, int]] = []
         self._drain_imports()
         for i, req in enumerate(self.slots):
             if req is not None and req.state == "decode" and budget >= 1:
-                req.draft = []
+                if len(req.out_tokens) + req.unfenced >= req.max_new_tokens:
+                    continue    # the unfenced tick ends it: nothing to run
+                # A decode row is charged the columns it may fill: the
+                # device drafts (``tick_program``) and the plan
+                # does not wait to learn how much.  Its width is capped
+                # by the tick budget (each column costs 1) and the verify
+                # row (bonus token + K drafts); the device caps the draft
+                # by the remaining generation (a draft past max_new could
+                # be verified at positions the reservation never covered).
+                n = 1
                 if self.cfg.spec_decode:
-                    # Draft length caps: the tick budget (each draft
-                    # token costs 1), the verify row width (bonus token
-                    # + K drafts per row), and the remaining generation
-                    # budget (a draft past max_new could be verified at
-                    # RoPE positions the reservation never covered).
-                    cap = min(self.cfg.spec_k, budget - 1,
-                              self.cfg.prefill_chunk - 1,
-                              req.max_new_tokens - len(req.out_tokens) - 1)
-                    if cap >= 1:
-                        req.draft = req.draft_lookup(cap)
-                n = 1 + len(req.draft)
+                    n += min(self.cfg.spec_k, budget - 1,
+                             self.cfg.prefill_chunk - 1)
                 work.append((i, req, n))
                 budget -= n
         for i, req in enumerate(self.slots):
@@ -969,8 +980,163 @@ def tick_width(cfg: ServeConfig, work) -> int:
     longer.  A pure function of the plan, which ``sched_digest`` folds
     with every ``n``, so the ranks of a lockstep fleet agree on it."""
     narrow = decode_width(cfg)
-    return narrow if max(n for _, _, n in work) <= narrow \
+    return narrow if max(row[2] for row in work) <= narrow \
         else cfg.prefill_chunk
+
+
+# What the host tells the tick's program of its rows, one int32 a slot each:
+# the rows of the one ``[len(ROW), slots]`` array a tick stages beside its
+# block tables and its token slab (ServeEngine._dispatch).  ``len`` counts
+# only where ``carried`` is 0; ``limit`` is prompt + ``max_new_tokens``, the
+# context's length at which the stream ends; ``eos`` -1 where it has none.
+ROW = ("len", "n", "kind", "carried", "limit", "eos", "copy_src", "copy_dst")
+_LEN, _N, _KIND, _CARRIED, _LIMIT, _EOS, _COPY_SRC, _COPY_DST = range(len(ROW))
+# A row's kind: no row, a prompt's chunk that samples nothing, its last
+# chunk (samples the first token), a decode or verify row.
+IDLE, CHUNK, LAST, DECODE = range(4)
+
+
+def draft_rows(hist, ctx, cap, width: int):
+    """``Request.draft_lookup`` for every slot at once, on the device:
+    ``hist`` ``[slots, positions]`` int32 holds each slot's prompt and emitted
+    tokens, ``ctx`` how many of them, ``cap`` the most a slot may draft.  The
+    most recent PRIOR occurrence of a context's final bigram proposes the
+    tokens that followed it, at most ``min(cap, width)``, never past the
+    context's end.  Returns ``(draft [slots, width], n [slots])``; columns
+    from ``n`` on hold nothing a reader may use."""
+    import jax.numpy as jnp
+    S, H = hist.shape
+    at = lambda i: jnp.take_along_axis(
+        hist, jnp.clip(i, 0, H - 1)[:, None], axis=1)
+    i = jnp.arange(H)[None, :]
+    before = jnp.pad(hist, ((0, 0), (1, 0)))[:, :H]     # before[i] = hist[i-1]
+    # the final bigram itself (i = ctx - 1) is no prior occurrence
+    seen = ((before == at(ctx - 2)) & (hist == at(ctx - 1))
+            & (i >= 1) & (i <= ctx[:, None] - 2))
+    last = jnp.max(jnp.where(seen, i, -1), axis=1)      # -1: never seen
+    p = last + 1
+    n = jnp.where((last >= 0) & (ctx >= 3),
+                  jnp.clip(jnp.minimum(cap, ctx - p), 0, width), 0)
+    draft = jnp.take_along_axis(
+        hist, jnp.clip(p[:, None] + jnp.arange(width)[None, :], 0, H - 1),
+        axis=1)
+    return draft, n.astype(jnp.int32)
+
+
+def tick_program(model, mcfg, cfg: ServeConfig):
+    """One tick as a function of device arrays, for ``jit`` (ServeEngine.
+    _build_step; tests/test_tpu_compile.py compiles it for a described
+    chip): ``(params, cache, hist, length, done, block_tables, rows, tokens)
+    -> (cache, hist, length, done, report, counters)``.  ``cache`` and the
+    chain — ``hist`` ``[slots, max_seq_len]``, ``length`` and ``done``
+    ``[slots]``, int32 — are the tick before's and are donated; ``rows`` is
+    ``ROW``; ``report`` holds, a slot a row, the greedy tokens of every
+    column, the verify row as it was fed, the row's columns and the length
+    it ran at (ServeEngine._harvest reads it)."""
+    import jax
+    import jax.numpy as jnp
+
+    W = decode_width(cfg)
+    counted = bool(model.TICK_COUNTERS)
+
+    def step_fn(params, cache, hist, length, done, block_tables, rows,
+                tokens):
+        S, C = tokens.shape
+        # CoW prefix sharing: clone diverged blocks BEFORE this
+        # tick's writes (padding entries route dst out of bounds and
+        # drop).  The gather reads the pre-step pool, so a source
+        # block recycled in this same tick still copies its old
+        # content (functional semantics — see Scheduler._admit_blocks).
+        with jax.named_scope("tick/copy_blocks"):
+            cache = model.copy_blocks(cache, rows[_COPY_SRC],
+                                      rows[_COPY_DST])
+        # The chain, in: a decode row's token and length are the tick
+        # before's, its drafts the history's (draft_rows); a row whose
+        # stream ended in a tick the host had not fenced runs 0 columns.
+        with jax.named_scope("tick/chain"):
+            decode = rows[_KIND] == DECODE
+            carried = rows[_CARRIED] > 0
+            ctx = jnp.where(carried, length, rows[_LEN])
+            ended = carried & (done > 0)
+            # a draft past max_new could be verified at positions the
+            # reservation never covered: limit - (ctx + 1) - 1 at most
+            draft, n_draft = draft_rows(
+                hist, ctx + 1,
+                jnp.minimum(rows[_N] - 1, rows[_LIMIT] - ctx - 2), W - 1)
+            last = jnp.take_along_axis(
+                hist, jnp.clip(ctx, 0, hist.shape[1] - 1)[:, None], 1)
+            n_new = jnp.where(decode,
+                              jnp.where(ended, 0, 1 + n_draft), rows[_N])
+            fed = tokens.at[:, :W].set(jnp.where(
+                decode[:, None], jnp.concatenate([last, draft], axis=1),
+                tokens[:, :W]))
+            # a row that runs nothing reads nothing, like a free slot
+            lengths = jnp.where(n_new > 0, ctx, 0)
+        greedy = getattr(model, "greedy_cached", None)
+        with jax.named_scope("tick/model"):
+            out = (greedy or model.apply_cached)(
+                params, fed, mcfg, cache, block_tables, lengths, n_new)
+        logits, cache = out[:2]
+        counters = out[2] if counted else None
+        if greedy is not None:      # the module sampled on its rows
+            next_tokens = logits
+        else:
+            # Greedy sampling ON DEVICE at EVERY chunk position: row
+            # [s, j] is the greedy continuation after consuming tokens
+            # [s, :j+1] — prefill reads its last valid position,
+            # speculative decode verifies its whole draft row against
+            # it.  Argmax ties break identically on every rank (SPMD
+            # determinism).
+            with jax.named_scope("tick/sample"):
+                next_tokens = jnp.argmax(
+                    logits.astype(jnp.float32),
+                    axis=-1).astype(jnp.int32)
+        # The chain, out: what ``_emit`` will do with this tick's tokens
+        # at its fence, done here for the tick after.  draft[j] is
+        # accepted iff it EQUALS the greedy token before it; the stream
+        # ends at ``eos`` or at its limit; the history takes what was
+        # emitted.
+        with jax.named_scope("tick/chain"):
+            cols = jnp.arange(W)[None, :]
+            first = jnp.where(decode, 0, n_new - 1)[:, None]
+            new = jnp.take_along_axis(
+                next_tokens, jnp.clip(first + cols, 0, C - 1), axis=1)
+            agree = ((fed[:, 1:W] == next_tokens[:, :W - 1])
+                     & (cols[:, 1:] < n_new[:, None]) & decode[:, None])
+            accepted = jnp.sum(jnp.cumprod(agree, axis=1), axis=1)
+            emits = (n_new > 0) & (decode | (rows[_KIND] == LAST))
+            eos_at = jnp.min(jnp.where(
+                (new == rows[_EOS][:, None]) & (cols <= accepted[:, None]),
+                cols, W), axis=1)
+            n_out = jnp.where(emits,
+                              jnp.minimum(accepted, eos_at) + 1, 0)
+            # the history's length before: the decode row's own token
+            # is in it, a prompt whole from its admission
+            held = jnp.where(decode, ctx + 1, ctx + n_new)
+            hist = hist.at[jnp.arange(S)[:, None], jnp.where(
+                cols < n_out[:, None], held[:, None] + cols,
+                hist.shape[1])].set(new, mode="drop")
+            ran = n_new > 0
+            length = jnp.where(
+                ran, ctx + jnp.where(decode, 1 + accepted, n_new), length)
+            done = jnp.where(ran, (emits & (
+                (eos_at <= accepted) | (held + n_out >= rows[_LIMIT]))
+                ).astype(jnp.int32), done)
+            # one array for the fence's one copy: the greedy tokens,
+            # the verify rows as they were fed, each row's columns and
+            # the length it ran at
+            report = jnp.concatenate(
+                [next_tokens, fed[:, :W], n_new[:, None], ctx[:, None]],
+                axis=1).astype(jnp.int32)
+        return cache, hist, length, done, report, counters
+
+    return step_fn
+
+
+def admit_program(hist, slot, row):
+    """An admitted request's tokens into its slot's history (donated)."""
+    import jax
+    return jax.lax.dynamic_update_slice(hist, row[None, :], (slot, 0))
 
 
 # The host's critical path between two programs, part by part: what lies
@@ -982,7 +1148,8 @@ TURNAROUND_PARTS = ("fence_copy", "harvest_emit", "plan", "stage", "launch")
 _TURN_SUMS = tuple("turn_" + part for part in TURNAROUND_PARTS)
 _LOOP_SUMS = ("fence_ready_s", "fence_copy_s", "narrow", "narrow_wait_s",
               "wide", "wide_wait_s", "used", "turnaround_s", "turnaround_n",
-              "after_idle_n", "iteration_s") + _TURN_SUMS
+              "after_idle_n", "iteration_s", "ahead_n",
+              "ahead_idle_rows") + _TURN_SUMS
 
 
 def _loop_figures(sums: Dict[str, float]) -> Dict[str, Any]:
@@ -1001,6 +1168,8 @@ def _loop_figures(sums: Dict[str, float]) -> Dict[str, Any]:
         "turnaround_n": int(sums["turnaround_n"]),
         "turnaround_parts_s": parts,
         "after_idle_n": int(sums["after_idle_n"]),
+        "ahead_n": int(sums["ahead_n"]),
+        "ahead_idle_rows": int(sums["ahead_idle_rows"]),
         "iteration_s": sums["iteration_s"],
         "by_width": by_width,
         "narrow_ticks": by_width["narrow"]["ticks"],
@@ -1094,19 +1263,31 @@ class ServeEngine:
         for name in _LOOP_SUMS:     # every figure is there from the start
             self.clock.add(name, 0)
         # The gap between two programs (_count_gap): the ready stamp and the
-        # two spans of the tick the last _harvest fenced, None where it
-        # fenced none, and when the launch before returned.
+        # two spans of the tick fenced last, None once a launch has followed
+        # it or the engine has run dry, and when the launch before returned.
         self._fenced: Optional[Tuple[float, Any, Any]] = None
         self._launch_t = 0.0
+        self._launched = False      # this step() launched a tick
+        # The decode chain's state, the tick program's own (``tick_program``):
+        # every slot's token history, its length, whether its stream has
+        # ended.  A tick takes them from the tick before and returns them
+        # for the tick after; the host writes an admitted prompt into the
+        # history (_admit_history) and reads none of them.
+        hist = (cfg.max_slots, cfg.max_seq_len)
+        self._chain = tuple(
+            _global_zeros(shape, np.int32, self._repl)
+            for shape in (hist, hist[:1], hist[:1]))
         # What the tick counts beside its logits: one small vector a tick
         # (the expert layers' assignments, models/latent_moe.py), summed at
         # every harvest.
         self._counter_names = tuple(model.TICK_COUNTERS)
         self._counters = np.zeros(len(self._counter_names), np.int64)
         self._step_fn = self._build_step()
-        # The step's executable at each tick width, both compiled at the
-        # first dispatch (_compile_steps): no later tick lowers anything.
+        # The step's executable at each tick width and the history's
+        # (``_admit``, ``admit_program``), all compiled at the first dispatch
+        # (_compile_steps): no later tick lowers anything.
         self._steps: Dict[int, Any] = {}
+        self._admit = None
         # What the wide ticks' plans filled of what the model computed:
         # [valid tokens, rows] and [attention blocks at chunk width, blocks]
         # (``model.attn_blocks``: slots a block, a narrow block's columns).
@@ -1123,9 +1304,9 @@ class ServeEngine:
         # tables.
         self._bounded_read = bool(getattr(model, "BOUNDED_READ", False))
         self._read = np.zeros(4, np.int64)
-        # One-deep tick pipeline (the loader.prefetch deque pattern):
-        # holds (plan, device next-token array) until the next step()
-        # harvests it, so host scheduling overlaps device compute.
+        # The launched ticks no step() has fenced yet, oldest first: (tick,
+        # width, rows, the device's report, counters).  Two while a step()
+        # runs (it launches before it fences), at most one between two.
         self._inflight: "collections.deque" = collections.deque()
         self.tick = 0
         self._tokens_prefill = 0
@@ -1170,59 +1351,41 @@ class ServeEngine:
     # ----------------------------------------------------------- compile
     def _build_step(self):
         import jax
-        import jax.numpy as jnp
-
-        model, mcfg = self.model, self.model_cfg
-
-        def step_fn(params, cache, block_tables, lengths, n_new, tokens,
-                    copy_src, copy_dst):
-            # CoW prefix sharing: clone diverged blocks BEFORE this
-            # tick's writes (padding entries route dst out of bounds and
-            # drop).  The gather reads the pre-step pool, so a source
-            # block recycled in this same tick still copies its old
-            # content (functional semantics — see Scheduler._admit_blocks).
-            with jax.named_scope("tick/copy_blocks"):
-                cache = model.copy_blocks(cache, copy_src, copy_dst)
-            greedy = getattr(model, "greedy_cached", None)
-            with jax.named_scope("tick/model"):
-                out = (greedy or model.apply_cached)(
-                    params, tokens, mcfg, cache, block_tables, lengths, n_new)
-            logits, cache = out[:2]
-            counters = out[2] if self._counter_names else None
-            if greedy is not None:      # the module sampled on its rows
-                return cache, logits, counters
-            # Greedy sampling ON DEVICE at EVERY chunk position: row
-            # [s, j] is the greedy continuation after consuming tokens
-            # [s, :j+1] — prefill reads its last valid position,
-            # speculative decode verifies its whole draft row against
-            # it.  Argmax ties break identically on every rank (SPMD
-            # determinism).
-            with jax.named_scope("tick/sample"):
-                next_tokens = jnp.argmax(
-                    logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
-            return cache, next_tokens, counters
-
         return jax.jit(
-            step_fn,
-            donate_argnums=(1,),
+            tick_program(self.model, self.model_cfg, self.cfg),
+            donate_argnums=(1, 2, 3, 4),    # the pool and the chain
             out_shardings=(
-                self._leaf_shd,
-                self._repl,
+                self._leaf_shd, *(self._repl,) * 4,
                 self._repl if self._counter_names else None))
 
-    def _compile_steps(self, staged) -> None:
-        """The step's executable at both widths of ``tick_width``, lowered
-        from the first dispatch's staged arguments with the token slab at
-        each width: a process's first decode-only tick (or its first
-        chunk, on a decode-role engine) finds its program ready."""
+    def _tick_shapes(self, width: int):
+        """What a tick of ``width`` columns stages, as shapes: the block
+        tables, the rows (``ROW``) and the token slab."""
         import jax
         cfg = self.cfg
-        args = list(staged)
+        shape = lambda *dims: jax.ShapeDtypeStruct(dims, np.int32,
+                                                   sharding=self._repl)
+        return (jax.tree_util.tree_map(lambda t: shape(*t.shape),
+                                       self.scheduler.device_tables()),
+                shape(len(ROW), cfg.max_slots), shape(cfg.max_slots, width))
+
+    def _compile_steps(self) -> None:
+        """The step's executable at both widths of ``tick_width`` and the
+        history's, lowered at the first dispatch from the shapes a tick
+        stages: a process's first decode-only tick (or its first chunk, on
+        a decode-role engine) finds its program ready."""
+        import jax
+        cfg = self.cfg
         for width in {decode_width(cfg), cfg.prefill_chunk}:
-            args[3] = jax.ShapeDtypeStruct(     # the token slab
-                (cfg.max_slots, width), np.int32, sharding=self._repl)
             self._steps[width] = self._step_fn.lower(
-                self.params, self.cache, *args).compile()
+                self.params, self.cache, *self._chain,
+                *self._tick_shapes(width)).compile()
+        hist = self._chain[0]
+        self._admit = jax.jit(
+            admit_program, donate_argnums=(0,), out_shardings=self._repl
+        ).lower(hist, *(
+            jax.ShapeDtypeStruct(dims, np.int32, sharding=self._repl)
+            for dims in ((), hist.shape[1:]))).compile()
 
     # ------------------------------------------------------------ intake
     def submit(self, tokens, max_new_tokens: int,
@@ -1327,12 +1490,13 @@ class ServeEngine:
 
     # -------------------------------------------------------------- tick
     def step(self) -> Dict[str, Any]:
-        """Run one engine tick.  Returns the COMPLETED tick's report
-        (one tick of pipeline lag): {"tick", "processed", "emitted":
-        {req_id: [new tokens]}, "finished": [Request]} — an idle report
-        when nothing completed."""
-        report = self._harvest()
+        """Run one engine tick: launch the next program, THEN fence the one
+        before it.  Returns the fenced tick's report (one tick of pipeline
+        lag): {"tick", "processed", "emitted": {req_id: [new tokens]},
+        "finished": [Request]} — an idle report when none was fenced."""
+        self.clock.second()     # the open bucket is this step's second
         self._dispatch()
+        report = self._harvest()
         # Handoff installs surface their first token (sampled by the
         # prefill rank) in this report — the emission order a mixed
         # engine would have produced at prefill completion.
@@ -1340,6 +1504,8 @@ class ServeEngine:
             report["emitted"].setdefault(req.req_id, []).extend(toks)
             if req.state == "done":
                 report["finished"].append(req)
+        if not self.has_work():
+            self._fenced = None     # the next launch waits for traffic
         self._update_gauges()
         return report
 
@@ -1351,65 +1517,101 @@ class ServeEngine:
         return out
 
     def _dispatch(self) -> None:
+        """Plan, stage and launch the next tick, whether or not the one
+        before has been fenced: what a decode row needs of it (its last
+        token, its length, its drafts, the end of its stream) the program
+        takes from that tick's on the device (``tick_program``).  The host
+        gives what only it knows: the rows, prefill chunks' tokens, an
+        admitted prompt's history, block tables, CoW copies."""
+        self._launched = False
         with self.clock.span("plan") as plan:
             work, copies = self._plan()
         if not work:
             return
         cfg = self.cfg
         with self.clock.span("stage") as stage:
+            if not self._steps:
+                self._compile_steps()
             S, C = cfg.max_slots, tick_width(cfg, work)
             tokens = np.zeros((S, C), np.int32)
-            lengths = np.zeros(S, np.int32)
-            n_new = np.zeros(S, np.int32)
+            rows = np.zeros((len(ROW), S), np.int32)
+            rows[_COPY_DST] = cfg.cache_blocks      # no-op: dropped
+            launched = []
             for slot, req, n in work:
+                if not req.carried:
+                    self._admit_history(slot, req)
+                kind = DECODE
                 if req.state == "prefill":
                     tokens[slot, :n] = req.tokens[req.pos:req.pos + n]
+                    rows[_LEN, slot] = req.pos
+                    req.pos += n
+                    kind = CHUNK
+                    if req.pos == req.prompt_len:
+                        # its decode row follows without waiting for this
+                        # tick's fence; a prefill rank's job ends here
+                        kind = LAST
+                        req.state = ("handoff" if self.scheduler.role ==
+                                     "prefill" else "decode")
                 else:
                     # Speculative verify row: the last emitted token plus
-                    # the drafts — one multi-token apply_cached call scores
-                    # every draft position (n == 1 + len(draft)).
-                    tokens[slot, :n] = [req.out_tokens[-1]] + req.draft
-                lengths[slot] = req.ctx_len
-                n_new[slot] = n
-                for kept in self.scheduler.counted:
-                    kept.count(req.ctx_len + n)
-            copy_src = np.zeros(S, np.int32)
-            copy_dst = np.full(S, cfg.cache_blocks, np.int32)  # no-op: dropped
-            for j, (src, dst) in enumerate(copies):
-                copy_src[j], copy_dst[j] = src, dst
-            # Async dispatch: device_put + jit return immediately; the next
-            # step() harvests, so this tick's H2D staging and compute run
-            # behind the caller's host work (the double-buffer pattern).
+                    # the drafts, both the device's — one multi-token
+                    # apply_cached call scores every draft position.  A
+                    # hand-off's first row runs at the host's length.
+                    rows[_LEN, slot] = req.ctx_len
+                    rows[_CARRIED, slot] = req.carried
+                req.carried = True
+                req.unfenced += kind != CHUNK
+                rows[_N, slot], rows[_KIND, slot] = n, kind
+                rows[_LIMIT, slot] = req.prompt_len + req.max_new_tokens
+                rows[_EOS, slot] = -1 if req.eos_id is None else req.eos_id
+                launched.append((slot, req, n, kind))
+            for j, pair in enumerate(copies):
+                rows[_COPY_SRC:, j] = pair
+            # Async dispatch: device_put + jit return immediately; this
+            # tick's H2D staging and compute run behind the tick before
+            # and the caller's host work.
             put = lambda a: _make_global(a, self._repl)
             tables = self.scheduler.device_tables()
             dev = [{k: put(t) for k, t in tables.items()}
-                   if isinstance(tables, dict) else put(tables)] + [
-                put(a) for a in (lengths, n_new, tokens, copy_src, copy_dst)]
+                   if isinstance(tables, dict) else put(tables),
+                   put(rows), put(tokens)]
         with self.clock.span("launch") as launch:
-            if not self._steps:
-                self._compile_steps(dev)
-            self.cache, next_tokens, counters = self._steps[C](
-                self.params, self.cache, *dev)
+            self.cache, *self._chain, report, counters = self._steps[C](
+                self.params, self.cache, *self._chain, *dev)
         self._count_gap(plan, stage, launch)
-        used = int(n_new.sum())
-        self._read += paged.read_counts(
-            lengths, n_new, C, *self._attn_blocks, cfg.block_size,
-            cfg.max_blocks_per_seq, self._bounded_read)
-        self._last_fill = used / cfg.max_batch_tokens
-        self._inflight.append((self.tick, work, next_tokens, used, counters))
+        self._inflight.append((self.tick, C, launched, report, counters))
+        self._launched = True
         self.tick += 1
 
+    def _admit_history(self, slot: int, req: Request) -> None:
+        """A request's first row: its prompt (a hand-off's first token
+        behind it) into its slot's history, device-ordered between the tick
+        before and its own."""
+        row = np.zeros(self.cfg.max_seq_len, np.int32)
+        held = req.tokens + req.out_tokens
+        row[:len(held)] = held
+        self._chain = (self._admit(
+            self._chain[0], _make_global(np.int32(slot), self._repl),
+            _make_global(row, self._repl)), *self._chain[1:])
+
     def _count_gap(self, plan, stage, launch) -> None:
-        """One launch against the fence before it.  Back to back — this
-        step() fenced a tick and launched the next —, the seconds from the
-        ready stamp to the launch's return are the host's whole critical
-        path between two programs (``turnaround_s``), kept with the spans
-        it is made of (the rest of it is ``unspanned``), and
-        launch return to launch return is the busy loop's period
-        (``iteration_s``).  A launch with nothing fenced before it in its
-        step() waited for traffic, not for the host: counted, not timed."""
+        """One launch against the tick before it.  Ahead — that tick is
+        unfenced, so this program is queued behind it on the device —,
+        nothing of the host's lay between the two: a turnaround of 0
+        (``ahead_n``).  With nothing in flight the device waited for this
+        launch: from a fence's ready stamp, the seconds to the launch's
+        return are the host's whole critical path between two programs
+        (``turnaround_s``), kept with the spans it is made of (the rest of
+        it, the caller's own work between two ``step()`` among it, is
+        ``unspanned``).  Launch return to launch return is the busy loop's
+        period (``iteration_s``).  A launch with no fence before it waited
+        for traffic, not for the host: counted, not timed."""
         add = self.clock.add
-        if self._fenced is None:
+        if self._inflight:
+            add("ahead_n", 1)
+            add("turnaround_n", 1)
+            add("iteration_s", launch.t1 - self._launch_t)
+        elif self._fenced is None:
             add("after_idle_n", 1)
         else:
             ready, wait, emit = self._fenced
@@ -1420,6 +1622,7 @@ class ServeEngine:
                     stage.t1 - stage.t0, launch.t1 - launch.t0)):
                 add(name, part)
             add("iteration_s", launch.t1 - self._launch_t)
+        self._fenced = None
         self._launch_t = launch.t1
 
     def _plan(self):
@@ -1436,8 +1639,8 @@ class ServeEngine:
         for b, payload in self.scheduler.take_pending_writes():
             self._write_block(b, payload)
         for slot, req, n in work:
-            if req.admitted_t is not None and not req.pos and \
-                    req.state == "prefill" and req.ctx_len == 0:
+            if req.admitted_t is not None and not req.carried and \
+                    req.state == "prefill":
                 # queue-wait span, emitted once at admission
                 self._span("NEGOTIATE", req,
                            req.admitted_t - req.submitted_t,
@@ -1454,88 +1657,116 @@ class ServeEngine:
         if not work:
             return work, []
         copies = self.scheduler.take_copies()
-        self._fold_sched(work, copies)
+        # Fold the dispatch's scheduling decisions into the rolling digest:
+        # slot/request/phase/width (width encodes chunk boundaries), the
+        # admission-resume positions (prefix hits) and the CoW copy pairs;
+        # the drafts follow at the fence that reports them (_emit).
+        self._fold_sched([[(slot, req.req_id, req.state, n, req.pos)
+                           for slot, req, n in work], copies])
         return work, copies
 
-    def _fold_sched(self, work, copies) -> None:
-        """Fold one dispatch's scheduling decisions into the rolling
-        digest: slot/request/phase/width (width encodes chunk boundaries
-        and draft length), the admission-resume positions (prefix hits),
-        the draft tokens themselves, and the CoW copy pairs."""
-        summary = [(slot, req.req_id, req.state, n,
-                    req.pos if req.state == "prefill" else req.ctx_len,
-                    [] if req.state == "prefill" else list(req.draft))
-                   for slot, req, n in work]
-        rec = json.dumps([summary, copies], separators=(",", ":"))
+    def _fold_sched(self, decisions) -> None:
+        """Fold what every rank must have decided alike into the rolling
+        digest (serve/worker.py compares it tick by tick)."""
+        rec = json.dumps(decisions, separators=(",", ":"))
         self.sched_digest = hashlib.sha1(
             (self.sched_digest + rec).encode()).hexdigest()[:16]
 
     def _harvest(self) -> Dict[str, Any]:
-        if not self._inflight:
-            self._fenced = None
-            self.clock.second()
+        """Fence the oldest launched tick and emit what the device reports
+        of it — once a newer one is queued behind it, or when this step()
+        had nothing to launch."""
+        if len(self._inflight) <= self._launched:
             return {"tick": None, "processed": 0, "emitted": {},
                     "finished": [], "handoff": []}
-        tick, work, next_tokens, used, counters = self._inflight.popleft()
+        tick, C, launched, report, counters = self._inflight.popleft()
         clock = self.clock
         # The fence in two parts under its one phase: the device still runs
-        # (or has not started), then it is done and idle while the host
-        # fetches the tokens.
+        # (or has not started), then it is done while the host fetches the
+        # report.
         with clock.span("harvest_wait") as wait:
             with annotate("hvd:fence_ready"):
-                next_tokens.block_until_ready()
+                report.block_until_ready()
             ready = time.perf_counter()
             clock.second()      # a tick lies in the second it was fenced in
             with annotate("hvd:fence_copy"):
-                tokens_host = np.asarray(next_tokens)
+                report_host = np.asarray(report)
                 if counters is not None:
                     self._counters += np.asarray(counters)
-        width = ("narrow" if next_tokens.shape[1] < self.cfg.prefill_chunk
-                 else "wide")
+        W = decode_width(self.cfg)
+        width = "narrow" if C < self.cfg.prefill_chunk else "wide"
         clock.add("fence_ready_s", ready - wait.t0)
         clock.add("fence_copy_s", wait.t1 - ready)
         clock.add(width, 1)
         clock.add(width + "_wait_s", wait.t1 - wait.t0)
+        # what the tick ran, by the device's word: each slot's columns and
+        # the length it ran them at
+        n_new, lengths = report_host[:, -2], report_host[:, -1]
+        used = int(n_new.sum())
         clock.add("used", used)
+        self._last_fill = used / self.cfg.max_batch_tokens
+        self._read += paged.read_counts(
+            lengths * (n_new > 0), n_new, C, *self._attn_blocks,
+            self.cfg.block_size, self.cfg.max_blocks_per_seq,
+            self._bounded_read)
         if width == "wide":
-            self._count_wide(work, used)
+            self._count_wide(n_new, used)
         with clock.span("harvest_emit") as emit:
-            report = self._emit(tick, work, tokens_host, used)
+            out = self._emit(tick, launched, report_host[:, :C],
+                             report_host[:, C:C + W], n_new, lengths)
         self._fenced = (ready, wait, emit)
-        return report
+        return out
 
-    def _count_wide(self, work, used: int) -> None:
-        """One wide tick's plan against the program that ran it: the rows
+    def _count_wide(self, n_new: np.ndarray, used: int) -> None:
+        """One wide tick's rows against the program that ran them: the rows
         the model computed (its slab's positions, or the token budget it
         packs them into) and the blocks of slots that attended at chunk
-        width.  Host arithmetic on the plan; the device is not asked."""
+        width.  Host arithmetic on the report's columns."""
         cfg = self.cfg
-        n_new = np.zeros(cfg.max_slots, np.int64)
-        for slot, _, n in work:
-            n_new[slot] = n
         self._wide_rows += (used, min(cfg.max_slots * cfg.prefill_chunk,
                                       cfg.max_batch_tokens))
-        self._wide_blocks += paged.wide_blocks(n_new, *self._attn_blocks)
+        self._wide_blocks += paged.wide_blocks(n_new.astype(np.int64),
+                                               *self._attn_blocks)
 
-    def _emit(self, tick, work, tokens_host, used) -> Dict[str, Any]:
-        """The host half of a harvest: advance every request of the tick
-        by the tokens the device sampled, finish those that are done."""
+    def _emit(self, tick, launched, tokens_host, fed, n_new, lengths
+              ) -> Dict[str, Any]:
+        """The host half of a fence: advance every request of the tick by
+        what the device reports — the greedy tokens, the verify rows as it
+        fed them, each row's columns and length —, finish those that are
+        done.  The device has advanced its own chain by the same rules
+        (``tick_program``)."""
         from ..utils import metrics as M
         now = time.perf_counter()
         emitted: Dict[str, List[int]] = {}
         finished: List[Request] = []
         handoffs: List[Dict[str, Any]] = []
-        for slot, req, n in work:
-            decode_row = req.state != "prefill"
-            if not decode_row:
-                req.pos += n
+        drafts = []
+        for slot, req, n, kind in launched:
+            req.unfenced -= kind != CHUNK
+            idle = kind == DECODE and not n_new[slot]
+            if idle != (req.state == "done") or \
+                    not idle and req.ctx_len != lengths[slot]:
+                # the chain on the device and the host's view of it are one
+                # function of the plan stream: a fork is never served
+                raise RuntimeError(
+                    f"request {req.req_id} ({req.state}, {req.ctx_len} "
+                    f"tokens cached): the device ran {int(n_new[slot])} "
+                    f"columns at length {int(lengths[slot])}")
+            if idle:
+                # launched for a stream that ended in the tick before,
+                # unfenced then: the row ran nothing and wrote nothing
+                self.clock.add("ahead_idle_rows", 1)
+                continue
+            for kept in self.scheduler.counted:
+                kept.count(req.ctx_len + int(n_new[slot]))
+            if kind != DECODE:
                 req.ctx_len += n
                 self._tokens_prefill += n
                 self._prefill_chunks += 1
                 M.SERVE_TOKENS.inc(n, phase="prefill")
                 M.SERVE_PREFILL_CHUNKS.inc()
-                if req.pos < req.prompt_len:
-                    continue  # still prefilling next tick
+                if kind == CHUNK:
+                    continue  # still prefilling
                 if self.scheduler.role == "prefill":
                     # Disaggregation: this rank's job ends at prefill
                     # completion — export the prompt KV + first token
@@ -1552,7 +1783,6 @@ class ServeEngine:
                     self._handoffs += 1
                     M.SERVE_HANDOFFS.inc()
                     continue
-                req.state = "decode"
                 self.scheduler.register_prefix(req)
                 new_toks = [int(tokens_host[slot, n - 1])]
             else:
@@ -1562,17 +1792,19 @@ class ServeEngine:
                 # emitted output is bit-identical to plain greedy, only
                 # the tokens-per-tick rate changes.
                 row = tokens_host[slot]
+                draft = fed[slot, 1:n_new[slot]].tolist()
                 new_toks = [int(row[0])]
-                for j, d in enumerate(req.draft):
-                    if int(d) != new_toks[-1]:
+                for j, d in enumerate(draft):
+                    if d != new_toks[-1]:
                         break
                     new_toks.append(int(row[j + 1]))
                 accepted = len(new_toks) - 1
                 req.ctx_len += 1 + accepted
-                if req.draft:
-                    self._spec_drafted += len(req.draft)
+                if draft:
+                    drafts.append((slot, draft))
+                    self._spec_drafted += len(draft)
                     self._spec_accepted += accepted
-                    M.SERVE_SPEC_DRAFTED.inc(len(req.draft))
+                    M.SERVE_SPEC_DRAFTED.inc(len(draft))
                     if accepted:
                         M.SERVE_SPEC_ACCEPTED.inc(accepted)
             emitted_n = 0
@@ -1604,13 +1836,16 @@ class ServeEngine:
                                end_t=req.done_t,
                                extra={"generated": len(req.out_tokens)})
                     break  # verified-but-post-EOS drafts are discarded
-            if decode_row:
+            if kind == DECODE:
                 self._tokens_decode += emitted_n
                 M.SERVE_TOKENS.inc(emitted_n, phase="decode")
+        if drafts:
+            self._fold_sched(drafts)    # the device's drafts, rank by rank
         from .. import postmortem as PM
         PM.record_step(tick)  # engine liveness on the /health plane
-        return {"tick": tick, "processed": used, "emitted": emitted,
-                "finished": finished, "handoff": handoffs}
+        return {"tick": tick, "processed": int(n_new.sum()),
+                "emitted": emitted, "finished": finished,
+                "handoff": handoffs}
 
     def _ticks(self) -> int:
         """Ticks harvested so far: one ``harvest_wait`` span each."""

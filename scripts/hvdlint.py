@@ -251,7 +251,8 @@ def check_metrics_documented(
 _DETERMINISM_SCOPES = {
     "horovod_tpu/serve/engine.py": ["Scheduler", "PrefixCache",
                                     "BlockAllocator", "HostSpillPool",
-                                    "draft_lookup",
+                                    "draft_lookup", "draft_rows",
+                                    "tick_program",
                                     "_dispatch", "_fold_sched"],
     "horovod_tpu/serve/worker.py": ["plan_key", "_publish_plan",
                                     "_fetch_plan", "_apply_resume"],

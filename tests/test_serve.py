@@ -64,9 +64,10 @@ def test_scheduler_decode_preempts_prefill_budget():
     d.state = "decode"
     d.out_tokens = [7]
     plan = s.plan()
-    # d (decode, slot 1) outranks p (prefill, slot 0)
-    assert (plan[0][1].req_id, plan[0][2]) == ("d", 1)
-    assert (plan[1][1].req_id, plan[1][2]) == ("p", 4)
+    # d (decode, slot 1) outranks p (prefill, slot 0), and is charged the
+    # columns its verify row may fill (the row of 4: 1 + 3 drafts)
+    assert (plan[0][1].req_id, plan[0][2]) == ("d", 4)
+    assert (plan[1][1].req_id, plan[1][2]) == ("p", 1)
 
 
 def test_scheduler_admit_on_slot_free_and_evict():
@@ -354,45 +355,40 @@ def test_tick_width_is_read_off_the_plan(spec, spec_k, rows, want):
 def _step_and_note_widths(engine, widths):
     """One engine.step(), with the width of the tick it dispatched held
     against tick_width of that tick's work list."""
+    tick = engine.tick
     engine.step()
-    if engine._inflight:    # one deep: what this step dispatched
-        _, work, next_tokens = engine._inflight[-1][:3]
-        assert next_tokens.shape == (engine.cfg.max_slots,
-                                     tick_width(engine.cfg, work))
-        widths.append(next_tokens.shape[1])
+    if engine.tick > tick:  # what this step launched is the newest in flight
+        _, width, rows, report = engine._inflight[-1][:4]
+        assert width == tick_width(engine.cfg, rows)
+        # the greedy tokens, the verify rows as fed, a row's columns, length
+        assert report.shape == (engine.cfg.max_slots,
+                                width + decode_width(engine.cfg) + 2)
+        widths.append(width)
 
 
 @pytest.mark.parametrize("spec", [False, True], ids=["plain", "spec"])
 @pytest.mark.parametrize("family", ["llama", "moe"])
 def test_engine_at_two_widths_matches_reference_greedy(
-        family, spec, llama_tiny, moe_tiny, monkeypatch):
+        family, spec, llama_tiny, moe_tiny):
     """Ticks with a prefill chunk run wide, ticks without run at the decode
     width, in one request stream: every request's tokens are the full
-    forward's greedy ones.  Under speculation an oracle drafts the true
-    continuation for r0 (verify rows wholly accepted) and a wrong one for
-    the others (wholly rejected), so both outcomes pass a narrow row."""
+    forward's greedy ones.  Under speculation the device drafts from each
+    stream's own repeats (16 tokens of a `tiny` model's greedy stream hold
+    some): drafts that are accepted and drafts that are rejected both pass
+    a narrow row."""
     model, cfg, params = llama_tiny if family == "llama" else moe_tiny
     scfg = _cfg(max_slots=2, cache_blocks=32, max_seq_len=32,
                 max_batch_tokens=12, prefill_chunk=8, spec_decode=spec,
                 spec_k=2)
     rng = np.random.RandomState(17)
     prompts = [rng.randint(0, cfg.vocab, n).tolist() for n in (9, 4, 11)]
-    refs = [_reference_greedy(model, cfg, params, p, 6) for p in prompts]
-    if spec:
-        by_prompt = {tuple(p): r for p, r in zip(prompts, refs)}
-
-        def oracle(self, k):
-            done = len(self.out_tokens)
-            draft = by_prompt[tuple(self.tokens)][done:done + k]
-            return draft if self.req_id == "r0" else \
-                [(t + 1) % cfg.vocab for t in draft]
-        monkeypatch.setattr(Request, "draft_lookup", oracle)
+    refs = [_reference_greedy(model, cfg, params, p, 16) for p in prompts]
     engine = ServeEngine(model, cfg, params, scfg, mesh=_one_device_mesh())
     widths = []
-    reqs = [engine.submit(prompts[0], 6, req_id="r0")]
+    reqs = [engine.submit(prompts[0], 16, req_id="r0")]
     for _ in range(4):      # chunk of 8, the 1-token tail, decode alone
         _step_and_note_widths(engine, widths)
-    reqs += [engine.submit(p, 6, req_id=f"r{i + 1}")
+    reqs += [engine.submit(p, 16, req_id=f"r{i + 1}")
              for i, p in enumerate(prompts[1:])]
     while engine.has_work():
         _step_and_note_widths(engine, widths)
@@ -408,8 +404,7 @@ def test_engine_at_two_widths_matches_reference_greedy(
     for r in reqs:
         assert 1 <= r.loop["narrow_ticks"] < r.loop["ticks"]
     if spec:
-        assert engine._spec_accepted >= 2 and \
-            engine._spec_drafted > engine._spec_accepted
+        assert engine._spec_drafted > engine._spec_accepted >= 1
     engine.close()
 
 

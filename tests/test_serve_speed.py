@@ -8,6 +8,8 @@ gotcha)."""
 
 import jax
 import jax.numpy as jnp
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -159,24 +161,33 @@ def test_ngram_draft_lookup_prompt_and_self():
 
 
 def test_plan_budget_accounts_draft_tokens():
-    """A decode slot with a k-token draft costs 1 + k of the tick
-    budget, and drafting never exceeds the remaining generation."""
+    """A decode slot is charged the columns its verify row may fill, 1 +
+    spec_k, whatever the device will draft for it (the plan does not wait
+    to learn it) — less where the tick budget or the row's width is short.
+    The remaining generation caps the draft on the device
+    (test_device_drafter_caps); the plan only leaves out a stream that the
+    unfenced tick is sure to end."""
     s = Scheduler(_cfg(max_slots=2, max_batch_tokens=6, prefill_chunk=5,
                        spec_k=4))
     d = s.submit(Request([7, 8, 7, 8, 7], 8, req_id="d"))
+    e = s.submit(Request([1, 2, 3], 8, req_id="e"))
     s.plan()
-    d.pos = d.ctx_len = 5
-    d.state = "decode"
-    d.out_tokens = [8]
-    plan = s.plan()
-    # context ...7, 8 -> bigram (7,8) drafts [7, 8, 7] capped at
-    # spec_k=4 / row width-1=4 / budget-1=5 -> draft from the lookup
-    assert plan[0][:2] == (0, d) and plan[0][2] == 1 + len(d.draft)
-    assert len(d.draft) >= 1
-    # one token of generation left: no draft may be planned at all
+    for r in (d, e):
+        r.pos = r.ctx_len = r.prompt_len
+        r.state = "decode"
+        r.out_tokens = [8]
+    # spec_k=4 / row width-1=4 / budget-1=5: d's row is 1 + 4, and e's the
+    # one column the budget of 6 has left
+    assert s.plan() == [(0, d, 5), (1, e, 1)]
+    s.cfg = dataclasses.replace(s.cfg, spec_k=2)
+    assert s.plan() == [(0, d, 3), (1, e, 3)]
+    # one token of generation left and a launched row that will emit it:
+    # the stream ends in the unfenced tick, nothing is planned for it
     d.out_tokens = [0] * 7
-    plan = s.plan()
-    assert plan[0][2] == 1 and d.draft == []
+    d.unfenced = 1
+    assert s.plan() == [(1, e, 3)]
+    d.unfenced = 0      # fenced: the last token is still to run
+    assert s.plan() == [(0, d, 3), (1, e, 3)]
 
 
 # ---------------------------------------------- determinism proof (THE

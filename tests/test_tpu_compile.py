@@ -58,13 +58,15 @@ def test_the_expert_tile_kernel_compiles_at_the_cells_widths(one_chip, held,
 
 @functools.lru_cache(maxsize=None)      # a cell compiles once a test run
 def _tick_programs(cell, one_chip):
-    """The serving cell's tick at both of its widths as ServeEngine builds
-    it (copy-on-write, ``apply_cached`` on a budget of ``max_batch_tokens``
-    rows, the greedy token — the module's own ``greedy_cached`` where it
-    samples on its rows; each kind's pool sized by the scheduler, in the
+    """The serving cell's tick at both of its widths, ServeEngine's own
+    program (``tick_program``: copy-on-write, the decode chain in,
+    ``apply_cached`` on a budget of ``max_batch_tokens`` rows, the greedy
+    token — the module's own ``greedy_cached`` where it samples on its rows
+    —, the chain out; each kind's pool sized by the scheduler, in the
     device's default layout for its shape), compiled for the described
-    chip: ({width: text}, (narrow, wide), {leaf: pool dims}, {leaf: the
-    pool's axes from major to minor as the program takes it})."""
+    chip as the engine jits it: ({width: text}, (narrow, wide), {leaf: pool
+    dims}, {leaf: the pool's axes from major to minor as the program takes
+    it})."""
     import dataclasses
 
     from horovod_tpu.models import paged
@@ -85,24 +87,15 @@ def _tick_programs(cell, one_chip):
                                     sched.device_tables())
     cache = tree(jax.eval_shape(lambda: model.init_cache(
         cfg, sched.pool_blocks(), scfg.block_size)))
-
-    def step(params, cache, bt, lengths, n_new, tokens, src, dst):
-        cache = model.copy_blocks(cache, src, dst)
-        if hasattr(model, "greedy_cached"):
-            out = model.greedy_cached(params, tokens, cfg, cache, bt, lengths,
-                                      n_new)
-            return out[1], out[0]
-        out = model.apply_cached(params, tokens, cfg, cache, bt, lengths,
-                                 n_new)
-        return out[1], jnp.argmax(out[0].astype(jnp.float32), -1)
+    chain = (sds((S, scfg.max_seq_len), i32), sds((S,), i32), sds((S,), i32))
     widths = (E.decode_width(scfg), scfg.prefill_chunk)
     orig = jax.default_backend
     jax.default_backend = lambda: "tpu"     # the expert tile is Mosaic's
     try:
-        steps = {C: jax.jit(step, donate_argnums=(1,)).lower(
-            params, cache, tables, sds((S,), i32), sds((S,), i32),
-            sds((S, C), i32), sds((S,), i32), sds((S,), i32)).compile()
-            for C in widths}
+        steps = {C: jax.jit(E.tick_program(model, cfg, scfg),
+                            donate_argnums=(1, 2, 3, 4)).lower(
+            params, cache, *chain, tables, sds((len(E.ROW), S), i32),
+            sds((S, C), i32)).compile() for C in widths}
     finally:
         jax.default_backend = orig
     fmt = steps[widths[0]].input_formats[0][1]
@@ -152,6 +145,32 @@ def test_no_tick_relays_a_pool_on_its_way_in_or_out(one_chip, cell, width):
     row_major = {leaf: tuple(range(pool.count(",") + 1))
                  for leaf, pool in pools.items()}
     assert layouts == {**row_major, **_NOT_ROW_MAJOR.get(cell, {})}
+
+
+@pytest.mark.parametrize("width", ["narrow", "wide"])
+@pytest.mark.parametrize("cell", ["serve-decode", "serve-moe-mla-decode",
+                                  "serve-moe-swa-longdoc",
+                                  "serve-moe-conv-chat"])
+def test_the_token_history_is_handed_on_in_place(one_chip, cell, width):
+    """The decode chain's state is the tick program's own: the token history
+    ``[slots, max_seq_len]`` int32 comes from the tick before donated, is
+    scattered into in place and goes to the tick after — no op shaped like it
+    is a ``copy``, and the program aliases it (and the pools) to its
+    outputs."""
+    import re
+
+    from perfbench.lib import spec
+    texts, widths, pools, _ = _tick_programs(cell, one_chip)
+    text = texts[widths[width == "wide"]]
+    engine = spec.cell(cell)[1]["engine"]
+    hist = f"[{engine['max_slots']},{engine['max_seq_len']}]"
+    ops = [op for op in re.findall(_OPS, text) if op[0] == hist]
+    assert (hist, "scatter") in ops
+    assert not [op for op in ops if op[1] in ("copy", "concatenate")]
+    # every pool leaf, the history, the lengths and the ends of streams
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
+    assert aliased.count("may-alias") + aliased.count("must-alias") == \
+        len(pools) + 3
 
 
 @pytest.mark.parametrize("C", [256, 5])
