@@ -25,6 +25,30 @@ without the layer's prefix, to its array)
   embed(p, ids, config) -> x [.., d]
   layer(kind, p, x, config, mm) -> x, on x [B, S, d] at positions 0..S-1
   head(p, x, config, mm) -> logits
+  served_stats(config, seed, sample, tokens_of, quant=None) -> {"gap": [..],
+      "flip": [..], "numbers": {name: value}}: OPTIONAL, the served-path
+      check's teacher-forced statistics, for a family whose served token is
+      not the next token of one causal pass over the finished row.  Without
+      it the check is ``lib/reference.generated_logit_stats`` on the
+      sample's ``seqs`` and ``spans``; ``lib/reference.served_stats_for``
+      decides.  ``sample`` is what ``run.build_sample`` wrote, one entry a
+      sampled request: ``seqs`` (prompt + served tokens, zero-padded),
+      ``spans`` ([index of the prompt's last token, served tokens]), ``done``
+      (the stream's done record as the program wrote it: whatever the
+      program reports of how a token came about reaches the check here) and
+      ``part_n`` (tokens a streamed part).  ``gap`` and ``flip`` hold one
+      entry a served token, requests in the sample's order, tokens in order
+      of position: against the float32 reference's logits z in the state the
+      token was chosen in, how far z[token] lies under the best, over the
+      standard deviation of z, and whether it is not the best; the token is
+      the served one (``tokens_of="served"``) or the one the ``quant``
+      forward puts first in that state (``tokens_of="quant"``, the control).
+      ``numbers``, optional too: compared numbers of the family's own, each
+      judged against ``check.limits[name]`` of the cell's traffic file (one
+      without a limit is an error, one named as a number the harness
+      computes, ``lib/checks.HARNESS_NUMBERS``, is refused).  What such a
+      driver needs of ``lib/reference`` is public there: ``Weights``,
+      ``MATMULS``, ``highest``, ``hidden_states``, ``layer_steps``.
 The toy copy
   tiny(config) -> the CPU rehearsal's copy: same keys, toy sizes, float32.
 The yardstick (plain integers and floats, no jax)

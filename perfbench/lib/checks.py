@@ -46,6 +46,24 @@ def serve_numbers(stats):
             "served_gap_max": max(gaps)}
 
 
+# a serving cell's compared numbers that the harness computes itself
+HARNESS_NUMBERS = ("served_gap_share", "served_gap_max",
+                   "protocol_violations", "window_compilations")
+
+
+def family_numbers(stats):
+    """{name: value} of the compared numbers a family's own ``served_stats``
+    adds under ``numbers`` (families/__init__.py); each needs a limit in the
+    cell's traffic file, as ``judge`` demands.  One named as a number of the
+    harness's own would be overwritten unseen, so it is refused."""
+    own = dict(stats.get("numbers") or {})
+    taken = sorted(set(own) & set(HARNESS_NUMBERS))
+    if taken:
+        raise ValueError(f"a family's served_stats may not name {taken}: "
+                         "the harness computes them")
+    return own
+
+
 def judge(numbers, limits):
     """[(name, value, limit, ok)] and the verdict; a number without a
     finite value fails, a limit that is missing is an error."""

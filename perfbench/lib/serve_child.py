@@ -37,6 +37,24 @@ def _annotate(obj, method, label):
     setattr(obj, method, wrapped)
 
 
+def served_check(config, seed, sample, tokens_of, quant=None):
+    """(teacher-forced statistics of the served sample that run.build_sample
+    wrote, the compared numbers read from them).  The statistics are the
+    family's own where it brings them, handed the whole sample, and else
+    ``generated_logit_stats`` on the sample's ``seqs`` and ``spans`` alone;
+    the numbers are the two every cell has and the family's own beside
+    them."""
+    stats_of = reference.served_stats_for(config)
+    if stats_of is reference.generated_logit_stats:
+        stats = stats_of(config, seed, sample["seqs"],
+                         [tuple(s) for s in sample["spans"]], tokens_of,
+                         quant=quant)
+    else:
+        stats = stats_of(config, seed, sample, tokens_of, quant=quant)
+    return stats, {**checks.serve_numbers(stats),
+                   **checks.family_numbers(stats)}
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
@@ -160,13 +178,10 @@ def main(argv=None):
     ctl.join()
     with open(trace["check"]) as f:
         sample = json.load(f)
-    numbers, stats = {}, None
+    numbers = {}
     if sample["seqs"]:
         t = time.perf_counter()
-        stats = reference.generated_logit_stats(
-            config, args.seed, sample["seqs"],
-            [tuple(s) for s in sample["spans"]], "served")
-        numbers = checks.serve_numbers(stats)
+        stats, numbers = served_check(config, args.seed, sample, "served")
         where = [(r, k, first + k) for r, (first, n) in
                  enumerate(sample["spans"]) for k in range(n)]
         worst = sorted(range(len(where)), key=lambda i: -stats["gap"][i])[:8]
@@ -181,9 +196,7 @@ def main(argv=None):
     if args.control and sample["seqs"]:
         # the control: the reference in int8 in the program's place, at the
         # same prompts and tokens (never in the benchmark's own runs)
-        low = checks.serve_numbers(reference.generated_logit_stats(
-            config, args.seed, sample["seqs"],
-            [tuple(s) for s in sample["spans"]], "quant", quant="int8"))
+        _, low = served_check(config, args.seed, sample, "quant", "int8")
         print("READING " + json.dumps({"cell": args.workload, "seed": args.seed,
                                        "sound": numbers, "control": low}),
               flush=True)

@@ -260,6 +260,7 @@ def main(argv=None):
               hbm_peak=peak, last_loss=losses_seen[-1])
 
     out = {"correct": correct, "attempted": done_calls * K, "failed": 0,
+           "checks": [row[:3] for row in rows],
            "device": dict(device, memory_peak_bytes=peak),
            "setup_s": setup_s, "reference_s": ref_s, "window_s": window_s,
            "steps": done_calls * K, "tokens": done_calls * tokens_per_call,

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import math
 import os
 import random
 import signal
@@ -136,6 +137,16 @@ def report(ctx, metrics_e2e, metrics_layer, traced, correct, attempted,
             "failed": int(failed), "metrics": values, "device": device}
     if traced and breakdown:
         line["breakdown"] = breakdown
+    # each number compared beside its limit: the end of standard error and
+    # the last key of the line are what the driver's record keeps of a run
+    # that is not correct (a value that JSON cannot spell, as text)
+    line["compared"] = {
+        name: {"value": value if value is None or math.isfinite(value)
+               else repr(value), "limit": limit}
+        for name, value, limit in ctx["compared"]}
+    for name, c in line["compared"].items():
+        print(f"perfbench: compared {name} = {c['value']} "
+              f"(limit {c['limit']})", file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)
 
 
@@ -158,6 +169,7 @@ def run_train(args, entry, config, traffic):
         device = dict(device, busy_s=tr["busy_s"], window_s=tr["window_s"])
     ctx = {"kind": "train", "dry_run": args.dry_run, "result": res, "config": config,
            "traffic": traffic, "entry": entry, "trace": tr,
+           "compared": res["checks"],
            "peaks": None if args.dry_run else peaks.device_peaks(device["kind"])}
     return ctx, res["correct"], res["attempted"], res["failed"], device, (
         {"device_ops": tr["device_ops"], "idle_gaps": tr["idle_gaps"]}
@@ -177,6 +189,24 @@ def pick_sample(records, reqs, seed, k):
     rest = [i for i in done if i != longest]
     random.Random(int(seed) ^ 0x5EED).shuffle(rest)
     return [longest] + rest[:k - 1]
+
+
+def build_sample(sample, reqs, records, Tmax):
+    """What the served-path check is handed, one entry a sampled request and
+    all in the sample's order.  ``seqs``: prompt + streamed tokens,
+    zero-padded to Tmax; ``spans``: [first, n], the logits at positions
+    first..first+n-1 of a causal pass predict the n served tokens.  Beside
+    them what the stream delivered, for a family whose check needs more than
+    the tokens (families/__init__.py, ``served_stats``): ``done``, the done
+    record as loadgen kept it, and ``part_n``, the tokens a streamed part."""
+    out = {"seqs": [], "spans": [], "done": [], "part_n": []}
+    for i in sample:
+        p, o = reqs[i]["tokens"], records[i]["tokens"]
+        out["seqs"].append((p + o + [0] * Tmax)[:Tmax])
+        out["spans"].append([len(p) - 1, len(o)])
+        out["done"].append(records[i]["done"])
+        out["part_n"].append(records[i]["part_n"])
+    return out
 
 
 def run_serve(args, entry, config, traffic):
@@ -260,15 +290,11 @@ def run_serve(args, entry, config, traffic):
         records = client.records
         sample = [] if args.skip_check else pick_sample(
             records, reqs, args.seed, traffic["check"]["sample_requests"])
-        Tmax = mix["prompt_len"]["max"] + mix["output_len"]["max"]
-        seqs, spans = [], []
-        for i in sample:
-            p, o = reqs[i]["tokens"], records[i]["tokens"]
-            seqs.append((p + o + [0] * Tmax)[:Tmax])
-            spans.append([len(p) - 1, len(o)])
         fd, path = tempfile.mkstemp(prefix="pb-sample-", suffix=".json")
         with os.fdopen(fd, "w") as f:
-            json.dump({"seqs": seqs, "spans": spans}, f)
+            json.dump(build_sample(sample, reqs, records,
+                                   mix["prompt_len"]["max"]
+                                   + mix["output_len"]["max"]), f)
         if os.environ.get("PB_DEBUG_DIR"):     # the builder's look at a sample
             import shutil
             os.makedirs(os.environ["PB_DEBUG_DIR"], exist_ok=True)
@@ -320,6 +346,7 @@ def run_serve(args, entry, config, traffic):
            "t_end": t_end, "seconds": args.seconds, "setup_s": setup_s,
            "marks": marks, "config": config, "traffic": traffic,
            "entry": entry, "trace": tr,
+           "compared": [row[:3] for row in rows],
            "peaks": None if args.dry_run else peaks.device_peaks(device["kind"])}
     n, qs = serve_math.gap_quantiles_ms(ctx)
     print(f"perfbench: itl samples {n} quantiles_ms {qs}", flush=True)

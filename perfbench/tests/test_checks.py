@@ -208,5 +208,11 @@ def test_a_broken_timed_path_comes_out_not_correct(cell, broken):
 def test_a_dry_run_reports_no_number():
     line = _run("train-dp1")
     assert line["correct"] is True
-    assert set(line) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert list(line) == ["correct", "attempted", "failed", "metrics",
+                          "device", "compared"]
+    _, _, traffic = spec.cell("train-dp1")
+    # each number compared beside its limit, the line's last key
+    assert {k: c["limit"] for k, c in line["compared"].items()} == {
+        k: traffic["check"][k] for k in line["compared"]}
+    assert len(line["compared"]) == 5
     assert all(m["value"] is None for m in line["metrics"].values())

@@ -58,14 +58,15 @@ def test_nothing_to_read_is_none_and_prints_nothing(records, capsys):
     assert capsys.readouterr().out == ""
 
 
-def test_benchmark_lists_it_for_the_serving_cell_only():
+def test_benchmark_lists_it_for_the_serving_cells_only(serving_cells):
     bench = spec.benchmark()
     entries = [m for m in bench["per_layer"] if m["name"] == NAME]
+    assert "serve-decode" in serving_cells
     assert entries == [{"name": NAME, "unit": "%", "better": "higher",
                      "source": "program_counter",
                      "layer": "serving engine: tick",
                      "moves": "serve_out_tokens_per_s",
-                     "workloads": ["serve-decode"]}]
+                     "workloads": serving_cells}]
     assert NAME in {m["name"] for m in
                     spec.cell_metrics("serve-decode", bench)[1]}
     assert NAME not in {m["name"] for m in
