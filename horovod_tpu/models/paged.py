@@ -213,12 +213,27 @@ def gather(pool: Any, layer: int, block_tables: jax.Array) -> Any:
                 (S, max_blocks * p.shape[2]) + p.shape[3:]), pool)
 
 
-def context_mask(positions: jax.Array, ctx: int) -> jax.Array:
+def context_mask(positions: jax.Array, ctx: int, block: int = 1,
+                 held: Optional[jax.Array] = None) -> jax.Array:
     """[S, 1, C, ctx] bool: the query at ``positions[s, c]`` sees gathered
     keys ``0 .. positions[s, c]`` (its own, written first, included).  Over
     a tile of a bounded read that begins at position ``start``, hand it
-    ``positions - start``."""
-    return (jnp.arange(ctx)[None, None, :] <= positions[:, :, None])[:, None]
+    ``positions - start``.
+
+    With a ``block`` length B above 1 the mask is BLOCK-CAUSAL
+    (models/blockdiff_moe.py): key ``j`` is visible iff ``j // B <=
+    positions[s, c] // B`` — a query sees every position of its own block
+    of B and of earlier blocks — and ``j`` lies below ``held[s]``, the
+    slot's length once the tick's rows are in (a block that the tick fills
+    only in part ends there).  A tile's ``start`` is a multiple of B, so
+    ``positions - start`` and ``held - start`` keep the blocks.  ``block``
+    1 is the causal mask above, the same expression as before there was a
+    block length."""
+    keys = jnp.arange(ctx)[None, None, :]
+    if block == 1:
+        return (keys <= positions[:, :, None])[:, None]
+    return ((keys // block <= positions[:, :, None] // block)
+            & (keys < held[:, None, None]))[:, None]
 
 
 def ring_positions(top: jax.Array, ring: int) -> jax.Array:

@@ -54,7 +54,8 @@ class ServeConfig:
     def max_blocks_per_seq(self) -> int:
         return -(-self.max_seq_len // self.block_size)  # ceil
 
-    def validate(self, model_max_seq: Optional[int] = None) -> None:
+    def validate(self, model_max_seq: Optional[int] = None,
+                 model_block: int = 0) -> None:
         if not (0 <= self.port <= 65535):
             raise ValueError(
                 f"HOROVOD_SERVE_PORT={self.port} invalid; must be in "
@@ -113,6 +114,32 @@ class ServeConfig:
                 "the radix prefix cache on (HOROVOD_SERVE_PREFIX_CACHE); "
                 "only tree-held cold blocks spill "
                 "(docs/serving.md#replicated-tier)")
+        if model_block:
+            # A served model that denoises blocks of B positions
+            # (docs/serving.md#block-denoising): its rows are whole blocks
+            # from a block's first position, so what cuts a row or a
+            # context keeps to B; a draft row has no meaning there.
+            for name, v in (("HOROVOD_SERVE_PREFILL_CHUNK",
+                             self.prefill_chunk),
+                            ("serve block_size", self.block_size),
+                            ("HOROVOD_SERVE_MAX_BATCH_TOKENS",
+                             self.max_batch_tokens),
+                            ("HOROVOD_SERVE_MAX_SEQ_LEN", self.max_seq_len)):
+                if v % model_block:
+                    raise ValueError(
+                        f"{name}={v} is no multiple of the served model's "
+                        f"block_length={model_block}: a prompt's chunks, a "
+                        "pool block and a tick's budget share must end on "
+                        "the boundaries of its blocks "
+                        "(docs/serving.md#block-denoising)")
+            if self.spec_decode:
+                raise ValueError(
+                    "HOROVOD_SERVE_SPEC=1 cannot run over a served model "
+                    f"that denoises blocks of {model_block} positions: a "
+                    "tick's row is the block, every position of it a "
+                    "candidate of the model's own, and an n-gram draft has "
+                    "no place in it; turn it off "
+                    "(docs/serving.md#block-denoising)")
         if model_max_seq is not None and self.max_seq_len > model_max_seq:
             raise ValueError(
                 f"HOROVOD_SERVE_MAX_SEQ_LEN={self.max_seq_len} exceeds "

@@ -411,6 +411,13 @@ class Request:
         self.eos_id = eos_id
         self.state = "waiting"
         self.out_tokens: List[int] = []
+        # Block denoising (docs/serving.md#block-denoising): the pass,
+        # 0-based and counted a block, in which each served token was fixed;
+        # the block being filled, {position: (token, pass)}; and what the
+        # last block holds behind the answer's cut, [[token, pass], ..]
+        self.steps: List[int] = []
+        self.block: Dict[int, Tuple[int, int]] = {}
+        self.tail: List[List[int]] = []
         self.pos = 0        # prompt tokens consumed
         self.ctx_len = 0    # tokens written into the cache
         self.slot: Optional[int] = None
@@ -569,17 +576,45 @@ class Scheduler:
 
     ROLES = ("mixed", "prefill", "decode")
 
-    def __init__(self, cfg: ServeConfig, role: str = "mixed", kinds=()):
+    def __init__(self, cfg: ServeConfig, role: str = "mixed", kinds=(),
+                 block: int = 0):
         """``kinds``: the served model's cache kinds (paged.CacheKind; none
         = one kind that keeps whole contexts).  A kind with a window gets a
         ring a slot (:class:`_Ring`), a kind with a fixed state its columns
         a slot (:class:`_State`), beside the block table of the kind that
-        keeps whole contexts."""
+        keeps whole contexts.  ``block``: the positions a served model
+        denoises at a time (:func:`block_length`; 0 = it decodes a token
+        after another)."""
         if role not in self.ROLES:
             raise ValueError(f"scheduler role {role!r} invalid; expected "
                              f"one of {self.ROLES}")
         self.cfg = cfg
         self.role = role
+        self.block = int(block)
+        if self.block:
+            # A cached block of keys is a prefix's to share only where it
+            # ends on a boundary of the model's blocks AND was computed by
+            # a prefill: under the block mask a position's keys depend on
+            # its whole block, so a partial tail's, a copy-on-write
+            # clone's and a block still being denoised are another
+            # prompt's keys.  Sound for whole prefilled blocks where
+            # block_size % B == 0; not built, so refused, never silently
+            # wrong (docs/serving.md#block-denoising).
+            for on, what in ((cfg.prefix_cache, "the radix prefix cache "
+                              "(HOROVOD_SERVE_PREFIX_CACHE) and its "
+                              "copy-on-write"),
+                             (cfg.spill_blocks, "the host spill tier "
+                              "(HOROVOD_SERVE_SPILL_BLOCKS)"),
+                             (role != "mixed", f"the {role!r} role's prefill "
+                              "hand-off")):
+                if on:
+                    raise ValueError(
+                        f"the served model denoises blocks of {self.block} "
+                        f"positions: {what} cannot run over it — a "
+                        "position's keys depend on its whole block, so only "
+                        "whole prefilled blocks are a prefix's to share, "
+                        "spill or hand off, and no path keeps to them yet; "
+                        "turn it off (docs/serving.md#block-denoising)")
         self.kinds = tuple(kinds)
         self.rings = {k.name: _Ring(k, cfg) for k in self.kinds
                       if k.window is not None}
@@ -664,14 +699,23 @@ class Scheduler:
         prefill continuations next, FCFS admissions into the remainder.
         Deterministic given state.  A request is in ``decode`` from the
         launch of its prompt's last chunk on (ServeEngine._dispatch): its
-        decode row follows without waiting for that tick's fence."""
+        decode row follows without waiting for that tick's fence.
+
+        For a model that denoises blocks of B positions a decode row is a
+        BLOCK ROW of B columns whatever the pass, a prompt's ``p // B``
+        whole blocks are prefilled in chunks that end on block boundaries
+        (its last ``p % B`` tokens are known positions of the first block
+        row), and a stream's end is the device's to find: its rows are
+        planned until a fence has finished it."""
         budget = self.cfg.max_batch_tokens
         chunk = self.cfg.prefill_chunk
+        unit = self.block or 1      # what a row's columns are a multiple of
         work: List[Tuple[int, Request, int]] = []
         self._drain_imports()
         for i, req in enumerate(self.slots):
-            if req is not None and req.state == "decode" and budget >= 1:
-                if len(req.out_tokens) + req.unfenced >= req.max_new_tokens:
+            if req is not None and req.state == "decode" and budget >= unit:
+                if not self.block and len(req.out_tokens) + req.unfenced \
+                        >= req.max_new_tokens:
                     continue    # the unfenced tick ends it: nothing to run
                 # A decode row is charged the columns it may fill: the
                 # device drafts (``tick_program``) and the plan
@@ -680,19 +724,20 @@ class Scheduler:
                 # row (bonus token + K drafts); the device caps the draft
                 # by the remaining generation (a draft past max_new could
                 # be verified at positions the reservation never covered).
-                n = 1
+                n = unit
                 if self.cfg.spec_decode:
                     n += min(self.cfg.spec_k, budget - 1,
                              self.cfg.prefill_chunk - 1)
                 work.append((i, req, n))
                 budget -= n
+        budget -= budget % unit
         for i, req in enumerate(self.slots):
-            if req is not None and req.state == "prefill" and budget >= 1:
-                n = min(chunk, req.prompt_len - req.pos, budget)
+            if req is not None and req.state == "prefill" and budget >= unit:
+                n = min(chunk, self.prefill_end(req) - req.pos, budget)
                 if n >= 1:
                     work.append((i, req, n))
                     budget -= n
-        while self.waiting and budget >= 1 and self.role != "decode":
+        while self.waiting and budget >= unit and self.role != "decode":
             free_slots = [i for i, s in enumerate(self.slots) if s is None]
             if not free_slots:
                 break
@@ -713,10 +758,20 @@ class Scheduler:
                 self.rings[name].tables[slot, :len(blocks)] = blocks
             # prefix-hit tokens are already resident: prefill resumes at
             # req.pos (match() keeps >= 1 token to compute, so n >= 1)
-            n = min(chunk, req.prompt_len - req.pos, budget)
+            n = min(chunk, self.prefill_end(req) - req.pos, budget)
+            if n == 0:
+                # a prompt shorter than the model's block: nothing to
+                # prefill, its first row is a block row
+                req.state, n = "decode", unit
             work.append((slot, req, n))
             budget -= n
         return work
+
+    def prefill_end(self, req: Request) -> int:
+        """The prompt tokens a request's prefill consumes: all of them, or
+        under a block length the prompt's whole blocks."""
+        return req.prompt_len - (req.prompt_len % self.block
+                                 if self.block else 0)
 
     def _admit_blocks(self, req: Request) -> Optional[List[int]]:
         """One admission's block-table row.  With the prefix cache on,
@@ -966,20 +1021,28 @@ def decode_block_payload(enc: Dict[str, Any]) -> Dict[str, Any]:
 
 
 # ---------------------------------------------------------------- engine
-def decode_width(cfg: ServeConfig) -> int:
+def block_length(model_cfg) -> int:
+    """The positions a served model denoises at a time, as its config
+    declares them (``block_length``; models/blockdiff_moe.py), or 0: it
+    decodes a token after another."""
+    return int(getattr(model_cfg, "block_length", 0) or 0)
+
+
+def decode_width(cfg: ServeConfig, block: int = 0) -> int:
     """Columns a decode row can fill: the bonus token + ``spec_k`` drafts
     of a speculative verify row, else 1 (``validate`` holds it to
-    ``prefill_chunk``)."""
-    return 1 + cfg.spec_k if cfg.spec_decode else 1
+    ``prefill_chunk``); a block row's ``block`` columns for a model that
+    denoises blocks (:func:`block_length`)."""
+    return block or (1 + cfg.spec_k if cfg.spec_decode else 1)
 
 
-def tick_width(cfg: ServeConfig, work) -> int:
+def tick_width(cfg: ServeConfig, work, block: int = 0) -> int:
     """Columns of one tick's token slab, read off its plan: the decode
     width when every row of ``work`` fits it — decode rows, verify rows
     and a prefill tail that short —, ``prefill_chunk`` when any row is
     longer.  A pure function of the plan, which ``sched_digest`` folds
     with every ``n``, so the ranks of a lockstep fleet agree on it."""
-    narrow = decode_width(cfg)
+    narrow = decode_width(cfg, block)
     return narrow if max(row[2] for row in work) <= narrow \
         else cfg.prefill_chunk
 
@@ -992,8 +1055,12 @@ def tick_width(cfg: ServeConfig, work) -> int:
 ROW = ("len", "n", "kind", "carried", "limit", "eos", "copy_src", "copy_dst")
 _LEN, _N, _KIND, _CARRIED, _LIMIT, _EOS, _COPY_SRC, _COPY_DST = range(len(ROW))
 # A row's kind: no row, a prompt's chunk that samples nothing, its last
-# chunk (samples the first token), a decode or verify row.
+# chunk (samples the first token), a decode or verify row (a block row).
 IDLE, CHUNK, LAST, DECODE = range(4)
+# ... and of a model that denoises blocks (``block_tick_program``) one more:
+# the prompt's length, from which on a block's positions are generated.
+BLOCK_ROW = ROW + ("prompt",)
+_PROMPT = len(ROW)
 
 
 def draft_rows(hist, ctx, cap, width: int):
@@ -1036,6 +1103,8 @@ def tick_program(model, mcfg, cfg: ServeConfig):
     import jax
     import jax.numpy as jnp
 
+    if block_length(mcfg):
+        return block_tick_program(model, mcfg, cfg)
     W = decode_width(cfg)
     counted = bool(model.TICK_COUNTERS)
 
@@ -1133,10 +1202,114 @@ def tick_program(model, mcfg, cfg: ServeConfig):
     return step_fn
 
 
+def block_tick_program(model, mcfg, cfg: ServeConfig):
+    """:func:`tick_program` for a model that fills a block of B positions by
+    denoising (docs/serving.md#block-denoising): ``(params, cache, hist,
+    length, done, masked, passes, block_tables, rows, tokens) -> (cache,
+    hist, length, done, masked, passes, report, counters)``.  The chain
+    carries two more states a slot: ``masked`` ``[slots, B]``, the positions
+    of the slot's block that no pass has fixed yet (a state of the chain,
+    not ``token == M``: a prompt may hold M as an ordinary id; a position
+    below the row's ``prompt`` is known whatever the bit), and ``passes``
+    ``[slots]``, the denoising passes the block has had.  ``rows`` is
+    ``BLOCK_ROW``.
+
+    A decode row is a BLOCK ROW: the B positions from the slot's length,
+    fed from the history, M where masked.  A row that comes in with a
+    position masked is a DENOISING pass: the module's candidates and
+    confidences (``greedy_cached``), the module's rule (``fix_positions``),
+    the fixed tokens into the history; when that fills the block the stream
+    has ended if the block reaches its limit or a served position holds
+    ``eos``.  A row that comes in with none masked is the COMMIT pass: its
+    keys and values, computed from the full block, stand in the pool, the
+    length moves by B and the next block is all masked.  A denoising pass's
+    keys and values are written too and the block's next pass overwrites
+    them: the length has not moved, so no reader sees them.  A prompt's
+    chunk samples nothing.  ``report``: a slot a row, the block's tokens
+    after the pass, which of them this pass fixed, which by the threshold,
+    the pass's number, whether it committed, the row's columns and the
+    length it ran at (ServeEngine._emit_block reads it)."""
+    import jax
+    import jax.numpy as jnp
+
+    B = block_length(mcfg)
+    M = int(mcfg.mask_token_id)
+    counted = bool(model.TICK_COUNTERS)
+
+    def step_fn(params, cache, hist, length, done, masked, passes,
+                block_tables, rows, tokens):
+        S, C = tokens.shape
+        cols = jnp.arange(B)[None, :]
+        # The chain, in: a block row's tokens, length and masked positions
+        # are the tick before's; a row whose stream ended in a tick the
+        # host had not fenced runs 0 columns.
+        with jax.named_scope("tick/chain"):
+            decode = rows[_KIND] == DECODE
+            carried = rows[_CARRIED] > 0
+            ctx = jnp.where(carried, length, rows[_LEN])
+            ended = carried & (done > 0)
+            n_new = jnp.where(decode, jnp.where(ended, 0, B), rows[_N])
+            pos = ctx[:, None] + cols
+            at = jnp.clip(pos, 0, hist.shape[1] - 1)
+            mk = (masked > 0) & (pos >= rows[_PROMPT][:, None])
+            ids = jnp.where(mk, M, jnp.take_along_axis(hist, at, axis=1))
+            fed = tokens.at[:, :B].set(
+                jnp.where(decode[:, None], ids, tokens[:, :B]))
+            # a row that runs nothing reads nothing, like a free slot
+            lengths = jnp.where(n_new > 0, ctx, 0)
+        with jax.named_scope("tick/model"):
+            (cand, conf), cache, *more = model.greedy_cached(
+                params, fed, mcfg, cache, block_tables, lengths, n_new)
+        counters = more[0] if counted else None
+        # The chain, out: what ``_emit_block`` will do with this tick's
+        # report at its fence, done here for the tick after.
+        with jax.named_scope("tick/unmask"):
+            block = decode & (n_new > 0)
+            denoise = block & jnp.any(mk, axis=1)
+            commit = block & ~denoise
+            fix, sure = model.fix_positions(
+                conf[:, :B], mk & denoise[:, None], mcfg)
+            toks = jnp.where(fix, cand[:, :B], ids)
+            left = mk & ~fix
+        with jax.named_scope("tick/chain"):
+            hist = hist.at[jnp.arange(S)[:, None], jnp.where(
+                fix, pos, hist.shape[1])].set(toks, mode="drop")
+            full = denoise & ~jnp.any(left, axis=1)
+            limit = rows[_LIMIT][:, None]
+            served = (pos >= rows[_PROMPT][:, None]) & (pos < limit)
+            over = full & ((ctx + B >= rows[_LIMIT]) | jnp.any(
+                served & (toks == rows[_EOS][:, None]), axis=1))
+            ran = n_new > 0
+            length = jnp.where(
+                ran, ctx + jnp.where(decode, B * commit, n_new), length)
+            done = jnp.where(ran, over.astype(jnp.int32), done)
+            masked = jnp.where(commit[:, None], 1, jnp.where(
+                denoise[:, None], left.astype(jnp.int32), masked))
+            report = jnp.concatenate(
+                [toks, fix, sure, passes[:, None], commit[:, None],
+                 n_new[:, None], ctx[:, None]], axis=1).astype(jnp.int32)
+            passes = jnp.where(commit, 0, passes + denoise)
+        return cache, hist, length, done, masked, passes, report, counters
+
+    return step_fn
+
+
 def admit_program(hist, slot, row):
     """An admitted request's tokens into its slot's history (donated)."""
     import jax
     return jax.lax.dynamic_update_slice(hist, row[None, :], (slot, 0))
+
+
+def block_admit_program(hist, masked, passes, slot, row):
+    """:func:`admit_program` for a model that denoises blocks: the slot's
+    first block is all masked and has had no pass (the chain, donated)."""
+    import jax
+    import jax.numpy as jnp
+    ones = jnp.ones((1, masked.shape[1]), masked.dtype)
+    return (admit_program(hist, slot, row),
+            jax.lax.dynamic_update_slice(masked, ones, (slot, 0)),
+            jax.lax.dynamic_update_slice(passes, jnp.zeros(1, passes.dtype),
+                                         (slot,)))
 
 
 # The host's critical path between two programs, part by part: what lies
@@ -1212,7 +1385,11 @@ class ServeEngine:
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        cfg.validate(model_max_seq=model_cfg.max_seq)
+        # a model that fills a block of positions by denoising says so in
+        # its config (block_length): the engine takes its rule from there
+        self._block = block_length(model_cfg)
+        cfg.validate(model_max_seq=model_cfg.max_seq,
+                     model_block=self._block)
         # The tick's token budget is the scheduler's: the model packs a
         # tick's valid tokens into that many rows (models/paged.py pack).
         model_cfg = dataclasses.replace(
@@ -1226,7 +1403,8 @@ class ServeEngine:
         self.mesh = mesh
         kinds = (tuple(model.cache_kinds(model_cfg))
                  if hasattr(model, "cache_kinds") else ())
-        self.scheduler = Scheduler(cfg, role=role, kinds=kinds)
+        self.scheduler = Scheduler(cfg, role=role, kinds=kinds,
+                                   block=self._block)
         self._repl = NamedSharding(mesh, P())
         num_blocks = self.scheduler.pool_blocks()
         self._cache_shd = model.cache_shardings(mesh, model_cfg, num_blocks)
@@ -1273,10 +1451,18 @@ class ServeEngine:
         # ended.  A tick takes them from the tick before and returns them
         # for the tick after; the host writes an admitted prompt into the
         # history (_admit_history) and reads none of them.
+        # A model that denoises blocks adds the block's masked positions
+        # and its passes so far (``block_tick_program``).
         hist = (cfg.max_slots, cfg.max_seq_len)
         self._chain = tuple(
             _global_zeros(shape, np.int32, self._repl)
-            for shape in (hist, hist[:1], hist[:1]))
+            for shape in (hist, hist[:1], hist[:1]) + (
+                ((hist[0], self._block), hist[:1]) if self._block else ()))
+        # What block denoising did, counted at the fences
+        # (stats()["diffusion"]; docs/profiling.md).
+        self._diffusion = dict.fromkeys(
+            ("slot_passes", "commit_passes", "tokens_fixed",
+             "fixed_by_threshold", "fixed_as_surest", "blocks_done"), 0)
         # What the tick counts beside its logits: one small vector a tick
         # (the expert layers' assignments, models/latent_moe.py), summed at
         # every harvest.
@@ -1351,11 +1537,12 @@ class ServeEngine:
     # ----------------------------------------------------------- compile
     def _build_step(self):
         import jax
+        n = len(self._chain)
         return jax.jit(
             tick_program(self.model, self.model_cfg, self.cfg),
-            donate_argnums=(1, 2, 3, 4),    # the pool and the chain
+            donate_argnums=tuple(range(1, 2 + n)),  # the pool and the chain
             out_shardings=(
-                self._leaf_shd, *(self._repl,) * 4,
+                self._leaf_shd, *(self._repl,) * (n + 1),
                 self._repl if self._counter_names else None))
 
     def _tick_shapes(self, width: int):
@@ -1367,7 +1554,8 @@ class ServeEngine:
                                                    sharding=self._repl)
         return (jax.tree_util.tree_map(lambda t: shape(*t.shape),
                                        self.scheduler.device_tables()),
-                shape(len(ROW), cfg.max_slots), shape(cfg.max_slots, width))
+                shape(len(BLOCK_ROW if self._block else ROW), cfg.max_slots),
+                shape(cfg.max_slots, width))
 
     def _compile_steps(self) -> None:
         """The step's executable at both widths of ``tick_width`` and the
@@ -1376,16 +1564,21 @@ class ServeEngine:
         a decode-role engine) finds its program ready."""
         import jax
         cfg = self.cfg
-        for width in {decode_width(cfg), cfg.prefill_chunk}:
+        for width in {decode_width(cfg, self._block), cfg.prefill_chunk}:
             self._steps[width] = self._step_fn.lower(
                 self.params, self.cache, *self._chain,
                 *self._tick_shapes(width)).compile()
-        hist = self._chain[0]
+        # what an admission rewrites of the chain: the history, and a
+        # block's masked positions and passes
+        held = (self._chain[0], *self._chain[3:])
         self._admit = jax.jit(
-            admit_program, donate_argnums=(0,), out_shardings=self._repl
-        ).lower(hist, *(
+            block_admit_program if self._block else admit_program,
+            donate_argnums=tuple(range(len(held))),
+            out_shardings=(self._repl,) * len(held) if self._block
+            else self._repl
+        ).lower(*held, *(
             jax.ShapeDtypeStruct(dims, np.int32, sharding=self._repl)
-            for dims in ((), hist.shape[1:]))).compile()
+            for dims in ((), held[0].shape[1:]))).compile()
 
     # ------------------------------------------------------------ intake
     def submit(self, tokens, max_new_tokens: int,
@@ -1532,9 +1725,10 @@ class ServeEngine:
         with self.clock.span("stage") as stage:
             if not self._steps:
                 self._compile_steps()
-            S, C = cfg.max_slots, tick_width(cfg, work)
+            S, C = cfg.max_slots, tick_width(cfg, work, self._block)
             tokens = np.zeros((S, C), np.int32)
-            rows = np.zeros((len(ROW), S), np.int32)
+            rows = np.zeros((len(BLOCK_ROW if self._block else ROW), S),
+                            np.int32)
             rows[_COPY_DST] = cfg.cache_blocks      # no-op: dropped
             launched = []
             for slot, req, n in work:
@@ -1546,10 +1740,12 @@ class ServeEngine:
                     rows[_LEN, slot] = req.pos
                     req.pos += n
                     kind = CHUNK
-                    if req.pos == req.prompt_len:
+                    if req.pos == self.scheduler.prefill_end(req):
                         # its decode row follows without waiting for this
-                        # tick's fence; a prefill rank's job ends here
-                        kind = LAST
+                        # tick's fence; a prefill rank's job ends here.  A
+                        # block model's prompt samples nothing: its first
+                        # tokens come of its first block row
+                        kind = CHUNK if self._block else LAST
                         req.state = ("handoff" if self.scheduler.role ==
                                      "prefill" else "decode")
                 else:
@@ -1564,6 +1760,8 @@ class ServeEngine:
                 rows[_N, slot], rows[_KIND, slot] = n, kind
                 rows[_LIMIT, slot] = req.prompt_len + req.max_new_tokens
                 rows[_EOS, slot] = -1 if req.eos_id is None else req.eos_id
+                if self._block:
+                    rows[_PROMPT, slot] = req.prompt_len
                 launched.append((slot, req, n, kind))
             for j, pair in enumerate(copies):
                 rows[_COPY_SRC:, j] = pair
@@ -1590,9 +1788,14 @@ class ServeEngine:
         row = np.zeros(self.cfg.max_seq_len, np.int32)
         held = req.tokens + req.out_tokens
         row[:len(held)] = held
-        self._chain = (self._admit(
-            self._chain[0], _make_global(np.int32(slot), self._repl),
-            _make_global(row, self._repl)), *self._chain[1:])
+        at = (_make_global(np.int32(slot), self._repl),
+              _make_global(row, self._repl))
+        if self._block:
+            hist, *state = self._admit(self._chain[0], *self._chain[3:], *at)
+            self._chain = (hist, *self._chain[1:3], *state)
+        else:
+            self._chain = (self._admit(self._chain[0], *at),
+                           *self._chain[1:])
 
     def _count_gap(self, plan, stage, launch) -> None:
         """One launch against the tick before it.  Ahead — that tick is
@@ -1693,7 +1896,7 @@ class ServeEngine:
                 report_host = np.asarray(report)
                 if counters is not None:
                     self._counters += np.asarray(counters)
-        W = decode_width(self.cfg)
+        W = decode_width(self.cfg, self._block)
         width = "narrow" if C < self.cfg.prefill_chunk else "wide"
         clock.add("fence_ready_s", ready - wait.t0)
         clock.add("fence_copy_s", wait.t1 - ready)
@@ -1712,8 +1915,12 @@ class ServeEngine:
         if width == "wide":
             self._count_wide(n_new, used)
         with clock.span("harvest_emit") as emit:
-            out = self._emit(tick, launched, report_host[:, :C],
-                             report_host[:, C:C + W], n_new, lengths)
+            if self._block:
+                out = self._emit_block(tick, launched, report_host, n_new,
+                                       lengths)
+            else:
+                out = self._emit(tick, launched, report_host[:, :C],
+                                 report_host[:, C:C + W], n_new, lengths)
         self._fenced = (ready, wait, emit)
         return out
 
@@ -1742,20 +1949,7 @@ class ServeEngine:
         handoffs: List[Dict[str, Any]] = []
         drafts = []
         for slot, req, n, kind in launched:
-            req.unfenced -= kind != CHUNK
-            idle = kind == DECODE and not n_new[slot]
-            if idle != (req.state == "done") or \
-                    not idle and req.ctx_len != lengths[slot]:
-                # the chain on the device and the host's view of it are one
-                # function of the plan stream: a fork is never served
-                raise RuntimeError(
-                    f"request {req.req_id} ({req.state}, {req.ctx_len} "
-                    f"tokens cached): the device ran {int(n_new[slot])} "
-                    f"columns at length {int(lengths[slot])}")
-            if idle:
-                # launched for a stream that ended in the tick before,
-                # unfenced then: the row ran nothing and wrote nothing
-                self.clock.add("ahead_idle_rows", 1)
+            if not self._fenced_row(slot, req, kind, n_new, lengths):
                 continue
             for kept in self.scheduler.counted:
                 kept.count(req.ctx_len + int(n_new[slot]))
@@ -1807,35 +2001,7 @@ class ServeEngine:
                     M.SERVE_SPEC_DRAFTED.inc(len(draft))
                     if accepted:
                         M.SERVE_SPEC_ACCEPTED.inc(accepted)
-            emitted_n = 0
-            for tok in new_toks:
-                req.out_tokens.append(tok)
-                emitted.setdefault(req.req_id, []).append(tok)
-                emitted_n += 1
-                if req.first_token_t is None:
-                    req.first_token_t = now
-                    if req.loop0 is not None:
-                        req.prefill_ticks = self._ticks() - \
-                            req.loop0["phase_n"].get("harvest_wait", 0)
-                    M.SERVE_TTFT.observe(req.ttft())
-                    self._span("PREFILL", req, now - req.admitted_t,
-                               end_t=now, extra={"prompt": req.prompt_len})
-                if (req.eos_id is not None and tok == req.eos_id) or \
-                        len(req.out_tokens) >= req.max_new_tokens:
-                    reason = ("eos" if req.eos_id is not None
-                              and tok == req.eos_id else "completed")
-                    self.scheduler.finish(req, reason)
-                    self._close_loop(req)
-                    finished.append(req)
-                    tpot = req.tpot()
-                    if tpot is not None:
-                        M.SERVE_TPOT.observe(tpot)
-                    M.SERVE_REQUESTS.inc(outcome=reason)
-                    self._span("DECODE", req,
-                               req.done_t - req.first_token_t,
-                               end_t=req.done_t,
-                               extra={"generated": len(req.out_tokens)})
-                    break  # verified-but-post-EOS drafts are discarded
+            emitted_n = self._serve(req, new_toks, now, emitted, finished)
             if kind == DECODE:
                 self._tokens_decode += emitted_n
                 M.SERVE_TOKENS.inc(emitted_n, phase="decode")
@@ -1846,6 +2012,120 @@ class ServeEngine:
         return {"tick": tick, "processed": int(n_new.sum()),
                 "emitted": emitted, "finished": finished,
                 "handoff": handoffs}
+
+    def _fenced_row(self, slot, req, kind, n_new, lengths) -> bool:
+        """One launched row at its fence, by the device's word: whether it
+        ran.  The chain on the device and the host's view of it are one
+        function of the plan stream: a fork is never served."""
+        req.unfenced -= kind != CHUNK
+        idle = kind == DECODE and not n_new[slot]
+        if idle != (req.state == "done") or \
+                not idle and req.ctx_len != lengths[slot]:
+            raise RuntimeError(
+                f"request {req.req_id} ({req.state}, {req.ctx_len} "
+                f"tokens cached): the device ran {int(n_new[slot])} "
+                f"columns at length {int(lengths[slot])}")
+        if idle:
+            # launched for a stream that ended in the tick before,
+            # unfenced then: the row ran nothing and wrote nothing
+            self.clock.add("ahead_idle_rows", 1)
+        return not idle
+
+    def _serve(self, req: Request, new_toks: List[int], now: float,
+               emitted: Dict[str, List[int]], finished: List[Request]) -> int:
+        """Hand a request the tokens a fence found for it, in order, as far
+        as its stream goes: it ends at ``eos`` or at ``max_new_tokens``.
+        Returns how many were served."""
+        from ..utils import metrics as M
+        emitted_n = 0
+        for tok in new_toks:
+            req.out_tokens.append(tok)
+            emitted.setdefault(req.req_id, []).append(tok)
+            emitted_n += 1
+            if req.first_token_t is None:
+                req.first_token_t = now
+                if req.loop0 is not None:
+                    req.prefill_ticks = self._ticks() - \
+                        req.loop0["phase_n"].get("harvest_wait", 0)
+                M.SERVE_TTFT.observe(req.ttft())
+                self._span("PREFILL", req, now - req.admitted_t,
+                           end_t=now, extra={"prompt": req.prompt_len})
+            if (req.eos_id is not None and tok == req.eos_id) or \
+                    len(req.out_tokens) >= req.max_new_tokens:
+                reason = ("eos" if req.eos_id is not None
+                          and tok == req.eos_id else "completed")
+                self.scheduler.finish(req, reason)
+                self._close_loop(req)
+                finished.append(req)
+                tpot = req.tpot()
+                if tpot is not None:
+                    M.SERVE_TPOT.observe(tpot)
+                M.SERVE_REQUESTS.inc(outcome=reason)
+                self._span("DECODE", req,
+                           req.done_t - req.first_token_t,
+                           end_t=req.done_t,
+                           extra={"generated": len(req.out_tokens)})
+                break  # verified-but-post-EOS drafts are discarded
+        return emitted_n
+
+    def _emit_block(self, tick, launched, report, n_new, lengths
+                    ) -> Dict[str, Any]:
+        """:meth:`_emit` for a model that denoises blocks, by the report of
+        ``block_tick_program``: a prompt's chunk advances its context, a
+        commit pass moves it by a block, a denoising pass notes the tokens
+        it fixed and the pass's number.  The fence of the pass that FILLS a
+        block serves its tokens, in order of position and cut to
+        ``max_new_tokens`` (the commit pass that follows serves nothing);
+        what the last block holds behind the cut is kept for the done
+        record (``Request.tail``).  The device has advanced its own chain by
+        the same rules."""
+        from ..utils import metrics as M
+        B, count = self._block, self._diffusion
+        toks, fix, sure = (report[:, i * B:(i + 1) * B] for i in range(3))
+        passes, commit = report[:, 3 * B], report[:, 3 * B + 1]
+        now = time.perf_counter()
+        emitted: Dict[str, List[int]] = {}
+        finished: List[Request] = []
+        for slot, req, n, kind in launched:
+            if not self._fenced_row(slot, req, kind, n_new, lengths):
+                continue
+            if kind != DECODE:
+                req.ctx_len += n
+                self._tokens_prefill += n
+                self._prefill_chunks += 1
+                M.SERVE_TOKENS.inc(n, phase="prefill")
+                M.SERVE_PREFILL_CHUNKS.inc()
+                continue
+            count["slot_passes"] += 1
+            if commit[slot]:
+                count["commit_passes"] += 1
+                req.ctx_len += B
+                continue
+            start, p = req.ctx_len, req.prompt_len
+            for j in np.flatnonzero(fix[slot]):
+                req.block[start + int(j)] = (int(toks[slot, j]),
+                                             int(passes[slot]))
+            by_threshold = int(sure[slot].sum())
+            count["tokens_fixed"] += int(fix[slot].sum())
+            count["fixed_by_threshold"] += by_threshold
+            count["fixed_as_surest"] += int(fix[slot].sum()) - by_threshold
+            generated = range(max(start, p), start + B)
+            if len(req.block) < len(generated):
+                continue    # a position of the block is still masked
+            count["blocks_done"] += 1
+            block, req.block = req.block, {}
+            room = req.max_new_tokens - len(req.out_tokens)
+            served = self._serve(req, [block[q][0] for q in generated][:room],
+                                 now, emitted, finished)
+            req.steps += [block[q][1] for q in generated][:served]
+            if req.state == "done":
+                req.tail = [list(block[q]) for q in generated][served:]
+            self._tokens_decode += served
+            M.SERVE_TOKENS.inc(served, phase="decode")
+        from .. import postmortem as PM
+        PM.record_step(tick)  # engine liveness on the /health plane
+        return {"tick": tick, "processed": int(n_new.sum()),
+                "emitted": emitted, "finished": finished, "handoff": []}
 
     def _ticks(self) -> int:
         """Ticks harvested so far: one ``harvest_wait`` span each."""
@@ -2062,6 +2342,8 @@ class ServeEngine:
         if self._counter_names:
             out["moe"] = dict(zip(self._counter_names,
                                   map(int, self._counters)))
+        if self._block:
+            out["diffusion"] = dict(self._diffusion)
         if prefix is not None:
             out["prefix_cache"].update({
                 "hits": prefix.hits,
@@ -2085,7 +2367,8 @@ _MODEL_MODULES = {"llama": "horovod_tpu.models.llama",
                   "moe_llama": "horovod_tpu.models.moe_llama",
                   "latent_moe": "horovod_tpu.models.latent_moe",
                   "swa_moe": "horovod_tpu.models.swa_moe",
-                  "conv_moe": "horovod_tpu.models.conv_moe"}
+                  "conv_moe": "horovod_tpu.models.conv_moe",
+                  "blockdiff_moe": "horovod_tpu.models.blockdiff_moe"}
 
 
 def save_servable(directory: str, model_name: str, config, params,
@@ -2106,7 +2389,7 @@ def save_servable(directory: str, model_name: str, config, params,
 def load_servable(directory: str, mesh) -> Tuple[Any, Any, Any]:
     """Read a servable directory -> (model module, model config, global
     replicated params).  ``serve.json``: {"model": "llama"|"moe_llama"|
-    "latent_moe"|"swa_moe"|"conv_moe",
+    "latent_moe"|"swa_moe"|"conv_moe"|"blockdiff_moe",
     "config": <name in CONFIGS or kwarg dict>, "seed": int?}.  Params
     come from the latest checkpoint under the directory (restored
     through checkpoint.py into replicated shardings); with no

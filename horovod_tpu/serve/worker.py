@@ -371,6 +371,15 @@ class FleetFrontend:
                 done["timing"]["publish"] = max(0.0, first_pub - ftt)
             if getattr(req, "loop", None) is not None:
                 done["loop"] = req.loop
+            if getattr(req, "steps", None):
+                # block denoising (docs/serving.md#block-denoising): the
+                # pass, 0-based and counted a block, in which each served
+                # token was fixed, and [token, pass] of what the last block
+                # holds behind the answer's cut — what a check needs to
+                # rebuild the state a token was chosen in
+                done["steps"] = req.steps[len(req.steps)
+                                          - len(done["tokens"]):]
+                done["tail"] = req.tail
             self._publish_done(req.req_id, done)
             self._parts.pop(req.req_id, None)
             self._suppress.pop(req.req_id, None)

@@ -88,6 +88,8 @@ SUITES = {
                 "tests/test_kv_shard.py", "tests/test_scenario.py",
                 "tests/test_latent_moe.py", "tests/test_paged.py",
                 "tests/test_swa_moe.py", "tests/test_conv_moe.py",
+                "tests/test_blockdiff_moe.py",
+                "tests/test_serve_blockdiff.py",
                 "tests/test_tpu_compile.py"],
     "perf": ["tests/test_perf.py", "tests/test_memstats.py",
              "tests/test_perfbench_families.py"],

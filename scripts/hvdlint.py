@@ -252,8 +252,12 @@ _DETERMINISM_SCOPES = {
     "horovod_tpu/serve/engine.py": ["Scheduler", "PrefixCache",
                                     "BlockAllocator", "HostSpillPool",
                                     "draft_lookup", "draft_rows",
-                                    "tick_program",
+                                    "tick_program", "block_tick_program",
+                                    "block_admit_program",
                                     "_dispatch", "_fold_sched"],
+    # the rule of a denoising pass, which the block tick applies on the
+    # device and every rank must apply alike
+    "horovod_tpu/models/blockdiff_moe.py": ["fix_positions", "candidates"],
     "horovod_tpu/serve/worker.py": ["plan_key", "_publish_plan",
                                     "_fetch_plan", "_apply_resume"],
     # The whole replicated tier is lockstep-grade: routing decisions

@@ -14,7 +14,8 @@ import pytest
 
 from horovod_tpu.models import latent_moe, layers, llama, paged
 from horovod_tpu.serve.config import ServeConfig
-from horovod_tpu.serve.engine import _MODEL_MODULES, ServeEngine
+from horovod_tpu.serve.engine import (_MODEL_MODULES, ServeEngine,
+                                      block_length)
 
 BLOCKS, BS = 12, 4
 KINDS = {"llama": (llama, llama.CONFIGS["tiny"]),
@@ -355,8 +356,11 @@ def _engine(model, cfg, params):
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("hvd",))
     scfg = ServeConfig(max_slots=2, block_size=4, cache_blocks=32,
                        max_seq_len=32, max_batch_tokens=12, prefill_chunk=8,
-                       # refused over a window cache kind
-                       prefix_cache=not hasattr(model, "cache_kinds"))
+                       # refused over a window cache kind, and like the
+                       # drafts for a model that denoises blocks
+                       prefix_cache=not (hasattr(model, "cache_kinds")
+                                         or block_length(cfg)),
+                       spec_decode=not block_length(cfg))
     return ServeEngine(model, cfg, params, scfg, mesh=mesh)
 
 

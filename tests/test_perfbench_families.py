@@ -1,10 +1,11 @@
 """The seam between the benchmark's harness and a model family
-(perfbench/families/__init__.py), in tier 1, for the five families there
+(perfbench/families/__init__.py), in tier 1, for the six families there
 are: the dense GQA decoder, the latent-attention expert decoder, the
 window-and-global expert decoder (two kinds of cache), the
 short-convolution-and-attention expert decoder (a paged kind and a fixed
-state a slot, a tied head), and the switch family that only the benchmark's
-tests use.  At toy width on the CPU:
+state a slot, a tied head), the expert decoder that denoises blocks (its own
+served-path check), and the switch family that only the benchmark's tests
+use.  At toy width on the CPU:
 a family's leaf names spell the program's pytree, its program agrees with its
 plain reference, its counts are the pytree's sizes, and only the family with
 routed experts reads a tick's tokens.  Then the new cell's rehearsal."""
@@ -24,8 +25,9 @@ _TESTS_FAMILIES = os.path.join(spec.BENCH_DIR, "tests", "families")
 if _TESTS_FAMILIES not in spec.FAMILY_DIRS:
     spec.FAMILY_DIRS.append(_TESTS_FAMILIES)
 
-FAMILIES = ["dense_gqa", "moe_switch", "latent_moe", "swa_moe", "conv_moe"]
-ROUTED_BY_TOKENS = {"latent_moe", "swa_moe", "conv_moe"}
+FAMILIES = ["dense_gqa", "moe_switch", "latent_moe", "swa_moe", "conv_moe",
+            "blockdiff_moe"]
+ROUTED_BY_TOKENS = {"latent_moe", "swa_moe", "conv_moe", "blockdiff_moe"}
 SEED = 2**31 + 27
 
 
@@ -35,7 +37,8 @@ def _toy(family):
     cell = {"dense_gqa": "serve-decode",
             "latent_moe": "serve-moe-mla-decode",
             "swa_moe": "serve-moe-swa-longdoc",
-            "conv_moe": "serve-moe-conv-chat"}[family]
+            "conv_moe": "serve-moe-conv-chat",
+            "blockdiff_moe": "serve-moe-blockdiff-gen"}[family]
     return spec.tiny(spec.cell(cell)[1])
 
 
@@ -511,7 +514,299 @@ def test_the_conv_readers_read_a_trace_and_the_states_counters():
         assert spec.metric_reader(name)(other) is None, name
 
 
+# ------------------------------------------- the block-denoising family
+def test_the_blockdiff_cut_is_the_issues_arithmetic():
+    """ISSUE 40: a layer of 623,120,640 parameters, 4,984,176,384 in seven
+    layers with embedding and head, 9.968 GB in bfloat16; 14,336 B a cached
+    position; a narrow tick of 128 rows reads 9.34 GB; the engine and the
+    traffic as set out."""
+    _, config, traffic = spec.cell("serve-moe-blockdiff-gen")
+    fam = spec.family(config)
+    n = fam.param_counts(config)
+    layer = sum(math.prod(s) for k, s, _ in fam.leaf_specs(config)
+                if k.startswith("layers.0."))
+    assert layer == 623_120_640
+    assert n["total"] == 7 * layer + 2 * 151_936 * 2_048 + 2_048 \
+        == 4_984_176_384 == config["deployment"]["parameters"]
+    assert config["deployment"]["weight_bytes"] == 2 * n["total"]
+    assert fam.cache_bytes_per_position(config, 2) == 14_336 \
+        == config["deployment"]["cache_bytes_per_position"]
+    e = config["engine"]
+    pool = 14_336 * e["cache_blocks"] * e["block_size"]
+    assert pool == config["deployment"]["pool_bytes"] == 939_524_096
+    assert config["deployment"]["resident_bytes"] == 2 * n["total"] + pool
+    assert 0.25 * 16e9 < config["deployment"]["resident_bytes"] < 16e9
+    assert 9.3e9 < fam.tick_weight_bytes(config, 128, 2) < 9.4e9
+    assert fam.experts_touched(config, 128) > 127.9
+    assert fam.attn_flops_per_position(config) == 4 * 32 * 128 * 7
+    # every width, the experts and the vocabulary as published; depth alone cut
+    assert config["reduced"].keys() == {"num_hidden_layers"}
+    assert config["reduced"]["num_hidden_layers"]["published"] == 48
+    assert (config["hidden_size"], config["moe_intermediate_size"],
+            config["num_experts"], config["num_experts_per_tok"],
+            config["vocab_size"], config["head_dim"]) == (
+                2048, 768, 128, 8, 151_936, 128)
+    g = fam.gen(config)
+    assert (g["B"], g["steps"], g["tau"], g["M"]) == (4, 4, 0.9, 151_669)
+    assert e == {"max_slots": 32, "prefill_chunk": 256,
+                 "max_batch_tokens": 384, "block_size": 16,
+                 "max_seq_len": 2048, "cache_blocks": 4096,
+                 "prefix_cache": False, "spec_decode": False}
+    assert e["max_batch_tokens"] == e["prefill_chunk"] + 4 * e["max_slots"]
+    assert traffic["prompt_len"] == {"median": 256, "sigma": 0.6, "min": 64,
+                                     "max": 1024}
+    assert traffic["output_len"] == {"median": 384, "sigma": 0.4, "min": 128,
+                                     "max": 1024}
+    assert e["max_seq_len"] == traffic["prompt_len"]["max"] \
+        + traffic["output_len"]["max"]
+    assert "shared_prefix" not in traffic and "sessions" not in traffic
+    knee = traffic["arrivals"]["knee"]["rate_per_s"]
+    assert traffic["arrivals"]["rate_per_s"] == pytest.approx(0.8 * knee)
+    assert set(traffic["check"]["limits"]) == {
+        "served_gap_share", "served_gap_max", "early_unmask_share",
+        "protocol_violations", "window_compilations"}
+
+
+def test_the_parent_process_loads_the_blockdiff_family_without_jax():
+    code = ("import sys; from perfbench.lib import peaks, spec\n"
+            "_, c, _ = spec.cell('serve-moe-blockdiff-gen'); f = spec.family(c)\n"
+            "spec.tiny(c); f.param_counts(c); f.gen(c)\n"
+            "m = {'trace': None, 'config': c,\n"
+            "     'marks': {k: {'stats': {}, 'tick': 0} for k in ('start', 'end')}}\n"
+            "assert f.window_counts(m) is None\n"
+            "for name in ('diffusion.tokens_per_pass.serve',\n"
+            "             'diffusion.commit_pass_share.serve'):\n"
+            "    assert spec.metric_reader(name)(m) is None\n"
+            "peaks.serve_required_seconds(c, peaks.PEAKS['TPU v5 lite'], 9, 9, 1)\n"
+            "assert 'jax' not in sys.modules and 'numpy' not in sys.modules\n"
+            "types = f.expert_op_types(c)\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge._backends, 'a backend was started'\n"
+            "print(types)")
+    out = subprocess.run([sys.executable, "-c", code], check=True,
+                         cwd=spec.ROOT, timeout=60, capture_output=True,
+                         text=True)
+    from horovod_tpu.models import blockdiff_moe
+    assert out.stdout.strip() == str(
+        [f"[{blockdiff_moe.EXPERT_TILE},768]",
+         f"[{blockdiff_moe.EXPERT_TILE},2048]"])
+
+
+def test_the_diffusion_readers_read_a_hand_made_stats_pair():
+    """The two readers the cell brings on made-up marks: positions a
+    denoising pass, the commit passes' share of the block rows; None where
+    there is nothing to read, on another family's marks too; and the expert
+    roofline through the family's hooks."""
+    _, config, _ = spec.cell("serve-moe-blockdiff-gen")
+    a = {"slot_passes": 100, "commit_passes": 20, "tokens_fixed": 90,
+         "fixed_by_threshold": 30, "fixed_as_surest": 60, "blocks_done": 21}
+    b = {"slot_passes": 500, "commit_passes": 120, "tokens_fixed": 510,
+         "fixed_by_threshold": 150, "fixed_as_surest": 360,
+         "blocks_done": 125}
+    moe = {"ticks": 10, "assignments": 8 * 7 * 10 * 128,
+           "assignments_held": 8 * 7 * 10 * 128, "experts_touched": 8960,
+           "load_max": 300}
+    mark = lambda d, m: {"tick": m["ticks"], "stats": {"diffusion": d,
+                                                       "moe": m}}
+    ctx = {"config": config, "peaks": {"hbm_gbps": 819.0, "bf16_tflops": 197.0},
+           "marks": {"start": mark(a, dict.fromkeys(moe, 0)),
+                     "end": mark(b, moe)},
+           "trace": {"module_count": 5.0, "module_s": 0.07, "ops_s": {
+               "expert_tile_ffn f32[64,2048]": 0.04, "fusion bf16[64,768]": 0.001,
+               "while s32[]": 0.004}}}
+    read = lambda name, c=ctx: spec.metric_reader(name)(c)
+    assert read("diffusion.tokens_per_pass.serve") == pytest.approx(
+        420 / 300)
+    assert read("diffusion.commit_pass_share.serve") == pytest.approx(25.0)
+    # 1 / (1 + passes a block): 100 commits beside 300 denoising passes
+    assert read("diffusion.commit_pass_share.serve") == pytest.approx(
+        100 / (1 + 300 / 100))
+    fam = spec.family(config)
+    need, bound = fam.expert_required_seconds(config, ctx["peaks"],
+                                              8960 / 2, 8 * 7 * 128 * 5)
+    assert bound == "bytes"
+    assert read("moe.expert_roofline_share.serve") == pytest.approx(
+        100 * need / 0.045)
+    assert fam.window_counts(ctx)["experts_touched"] == 8960
+    bare = dict(ctx, marks={k: {"tick": 0, "stats": {}}
+                            for k in ("start", "end")})
+    still = dict(ctx, marks={"start": mark(a, moe), "end": mark(a, moe)})
+    for name in ("diffusion.tokens_per_pass.serve",
+                 "diffusion.commit_pass_share.serve"):
+        assert read(name, bare) is None and read(name, still) is None
+
+
+@pytest.fixture(scope="module")
+def block_toy():
+    """(toy configuration, a function that serves six requests through the
+    program's engine — one fault planted in the program or none — and
+    returns the sample as ``run.build_sample`` writes it)."""
+    import importlib.util
+    import jax
+    import jax.numpy as jnp
+    from horovod_tpu.models import blockdiff_moe
+    from horovod_tpu.serve.config import ServeConfig
+    from horovod_tpu.serve.engine import ServeEngine
+    from perfbench import run
+    planted = importlib.util.spec_from_file_location(
+        "pb_planted", os.path.join(spec.BENCH_DIR, "tools", "planted",
+                                   "sitecustomize.py"))
+    plant = importlib.util.module_from_spec(planted)
+    planted.loader.exec_module(plant)
+    config = spec.tiny(spec.cell("serve-moe-blockdiff-gen")[1])
+    params = jax.jit(lambda k: weights.make(config, k, jnp.float32))(
+        weights.seed_key(SEED))
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("hvd",))
+    rng = np.random.default_rng(3)
+    reqs = [{"tokens": rng.integers(0, config["vocab_size"], p).tolist()}
+            for p in (13, 22, 7, 16, 30, 9)]
+    new = (23, 18, 30, 9, 14, 21)
+
+    def serve(fault=None):
+        keep = blockdiff_moe._attend_tile, blockdiff_moe.fix_positions
+        if fault:
+            plant.plant(blockdiff_moe, fault)
+        try:
+            model, cfg = spec.family(config).program(config)
+            engine = ServeEngine(model, cfg, params,
+                                 ServeConfig(**config["engine"]), mesh=mesh)
+            live = [engine.submit(r["tokens"], n, req_id=f"r{i}")
+                    for i, (r, n) in enumerate(zip(reqs, new))]
+            engine.flush()
+            engine.close()
+        finally:
+            blockdiff_moe._attend_tile, blockdiff_moe.fix_positions = keep
+        records = [{"tokens": q.out_tokens, "part_n": [len(q.out_tokens)],
+                    "done": {"done": True, "tokens": q.out_tokens,
+                             "steps": q.steps, "tail": q.tail}}
+                   for q in live]
+        return run.build_sample(range(len(reqs)), reqs, records, 64)
+    return config, serve
+
+
+#: The toy's limits.  The cell's own are read on the chip at the published
+#: widths in bfloat16; the toy runs float32 on both sides, where the sound
+#: program reads 0 in all three, the int8 control 0.07 and 1.2 in the gaps,
+#: the causal mask 0.6, 2.5 and 0.04, the early unmasking 0, 0 and 0.34.
+TOY_LIMITS = {"served_gap_share": 0.02, "served_gap_max": 0.3,
+              "early_unmask_share": 0.05}
+
+
+def _judge(config, sample, tokens_of="served", quant=None):
+    from perfbench.lib import checks, serve_child
+    stats, numbers = serve_child.served_check(config, SEED, sample,
+                                              tokens_of, quant)
+    return stats, numbers, checks.judge(numbers, TOY_LIMITS)[1]
+
+
+def test_the_blockdiff_check_passes_the_sound_program(block_toy):
+    """The engine's streams through the family's own ``served_stats``, as
+    serve_child calls it: one gap a served token, every served token the
+    reference's best in the state its pass saw (float32 on both sides),
+    nothing fixed early; judged correct."""
+    config, serve = block_toy
+    sample = serve()
+    assert reference.served_stats_for(config) is \
+        spec.family(config).served_stats
+    stats, numbers, ok = _judge(config, sample)
+    assert len(stats["gap"]) == len(stats["flip"]) == sum(
+        n for _, n in sample["spans"])
+    assert not any(stats["flip"]) and max(stats["gap"]) < 1e-3
+    assert numbers == {"served_gap_share": 0.0,
+                       "served_gap_max": max(stats["gap"]),
+                       "early_unmask_share": 0.0}
+    assert ok
+    # the states the records name: every pass of every block, the first
+    # block's known positions never masked
+    rows, at = spec.family(config).states(config, sample)
+    assert rows.shape == (6, 64)
+    M = spec.family(config).gen(config)["M"]
+    first = next(a for a in at if a[0] == 0)
+    assert first[1] == 12 and first[2][0] == sample["seqs"][0][12] != M
+    assert len({(r, P) for r, P, *_ in at}) == sum(
+        -(-(f + 1 + n) // 4) - (f + 1) // 4 for f, n in sample["spans"])
+
+
+def test_the_blockdiff_check_fails_the_causal_mask_by_the_gaps(block_toy):
+    """Planted fault A: the plain causal mask inside a block.  The served
+    tokens lie far under the reference's best: not correct, by the gaps."""
+    config, serve = block_toy
+    stats, numbers, ok = _judge(config, serve("A"))
+    assert not ok
+    assert numbers["served_gap_share"] > 10 * TOY_LIMITS["served_gap_share"]
+    assert numbers["served_gap_max"] > 5 * TOY_LIMITS["served_gap_max"]
+
+
+def test_the_blockdiff_check_fails_early_unmasking_by_its_own_number(
+        block_toy):
+    """Planted fault B: every position fixed in its block's first pass, the
+    steps reported honestly.  Each served token is the reference's best in
+    the state it was chosen in, so the gaps pass; the family's own number
+    does not."""
+    config, serve = block_toy
+    sample = serve("B")
+    assert all(set(d["steps"]) == {0} for d in sample["done"])
+    stats, numbers, ok = _judge(config, sample)
+    assert numbers["served_gap_max"] < 1e-3 and not any(stats["flip"])
+    assert numbers["early_unmask_share"] > 4 * TOY_LIMITS["early_unmask_share"]
+    assert not ok
+
+
+def test_the_blockdiff_control_is_not_correct(block_toy):
+    """The reference in int8 in the program's place, at the same states:
+    its tokens lie under the float32 reference's best at a good share of
+    the positions."""
+    config, serve = block_toy
+    _, low, ok = _judge(config, serve(), "quant", "int8")
+    assert not ok
+    assert low["served_gap_share"] > 2 * TOY_LIMITS["served_gap_share"]
+    assert low["served_gap_max"] > 2 * TOY_LIMITS["served_gap_max"]
+
+
+# ----------------------------- what PR 39 had to leave out of tier 1, lifted
+@pytest.mark.parametrize("family", FAMILIES)
+def test_who_gives_a_configurations_served_statistics(family):
+    """``served_stats_for``: ``generated_logit_stats`` itself, by identity,
+    for the five families that bring none of their own, the family's own
+    for the one that does."""
+    config = _toy(family)
+    fam = spec.family(config)
+    if family == "blockdiff_moe":
+        assert reference.served_stats_for(config) is fam.served_stats
+    else:
+        assert not hasattr(fam, "served_stats")
+        assert reference.served_stats_for(config) is \
+            reference.generated_logit_stats
+
+
+def test_the_sample_is_the_golden_one():
+    """``run.build_sample``: prompt + streamed tokens zero-padded, the span
+    of positions whose logits predict them, and beside them the done record
+    and the parts as the stream delivered them, in the sample's order."""
+    from perfbench import run
+    reqs = [{"tokens": [11, 12, 13]}, {"tokens": [21]},
+            {"tokens": [31, 32, 33, 34, 35]}]
+    done = [{"done": True, "tokens": [14, 15], "steps": [0, 1],
+             "tail": [[16, 1], [17, 0]]}, None,
+            {"done": True, "tokens": [36, 37, 38], "timing": {"queue": 0.1}}]
+    records = [{"tokens": [14, 15], "part_n": [2], "done": done[0]},
+               {"tokens": [], "part_n": [], "done": None},
+               {"tokens": [36, 37, 38], "part_n": [2, 1], "done": done[2]}]
+    sample = run.build_sample([2, 0], reqs, records, 7)
+    assert sample == {"seqs": [[31, 32, 33, 34, 35, 36, 37],
+                               [11, 12, 13, 14, 15, 0, 0]],
+                      "spans": [[4, 3], [2, 2]],
+                      "done": [done[2], done[0]],
+                      "part_n": [[2, 1], [2]]}
+    assert json.loads(json.dumps(sample)) == sample
+    assert run.build_sample([], reqs, records, 7) == {
+        "seqs": [], "spans": [], "done": [], "part_n": []}
+
+
 @pytest.mark.parametrize("cell,metrics", [
+    ("serve-moe-blockdiff-gen", ("diffusion.tokens_per_pass.serve",
+                                 "diffusion.commit_pass_share.serve",
+                                 "engine.tick_ms.serve")),
     ("serve-moe-conv-chat", ("kv.state_resident_share.serve",
                              "moe.experts_touched_per_routed_layer.serve",
                              "engine.tick_ms.serve")),

@@ -30,9 +30,9 @@ def one_chip():
 
 
 # (experts held, model width, expert width): smallthinker-21b-a3b whole,
-# openpangu-ultra-moe-718b's share, lfm2-8b-a1b whole
+# openpangu-ultra-moe-718b's share, lfm2-8b-a1b whole, sdar-30b-a3b-chat whole
 @pytest.mark.parametrize("held,d,f", [(64, 2560, 768), (16, 7680, 2048),
-                                      (32, 2048, 1792)])
+                                      (32, 2048, 1792), (128, 2048, 768)])
 def test_the_expert_tile_kernel_compiles_at_the_cells_widths(one_chip, held,
                                                             d, f):
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
@@ -87,14 +87,19 @@ def _tick_programs(cell, one_chip):
                                     sched.device_tables())
     cache = tree(jax.eval_shape(lambda: model.init_cache(
         cfg, sched.pool_blocks(), scfg.block_size)))
-    chain = (sds((S, scfg.max_seq_len), i32), sds((S,), i32), sds((S,), i32))
-    widths = (E.decode_width(scfg), scfg.prefill_chunk)
+    # a model that denoises blocks hands on two more states a slot
+    B = E.block_length(cfg)
+    chain = (sds((S, scfg.max_seq_len), i32), sds((S,), i32), sds((S,), i32)
+             ) + ((sds((S, B), i32), sds((S,), i32)) if B else ())
+    widths = (E.decode_width(scfg, B), scfg.prefill_chunk)
     orig = jax.default_backend
     jax.default_backend = lambda: "tpu"     # the expert tile is Mosaic's
     try:
         steps = {C: jax.jit(E.tick_program(model, cfg, scfg),
-                            donate_argnums=(1, 2, 3, 4)).lower(
-            params, cache, *chain, tables, sds((len(E.ROW), S), i32),
+                            donate_argnums=tuple(range(1, 2 + len(chain)))
+                            ).lower(
+            params, cache, *chain, tables,
+            sds((len(E.BLOCK_ROW if B else E.ROW), S), i32),
             sds((S, C), i32)).compile() for C in widths}
     finally:
         jax.default_backend = orig
@@ -125,7 +130,8 @@ _NOT_ROW_MAJOR = {"serve-moe-conv-chat": {"conv/u": (0, 2, 1, 3)}}
 @pytest.mark.parametrize("width", ["narrow", "wide"])
 @pytest.mark.parametrize("cell", ["serve-decode", "serve-moe-mla-decode",
                                   "serve-moe-swa-longdoc",
-                                  "serve-moe-conv-chat"])
+                                  "serve-moe-conv-chat",
+                                  "serve-moe-blockdiff-gen"])
 def test_no_tick_relays_a_pool_on_its_way_in_or_out(one_chip, cell, width):
     """Every serving cell's two programs take each pool as the device's
     default layout for its SHAPE has it — a module's ``init_cache`` decides
@@ -150,7 +156,8 @@ def test_no_tick_relays_a_pool_on_its_way_in_or_out(one_chip, cell, width):
 @pytest.mark.parametrize("width", ["narrow", "wide"])
 @pytest.mark.parametrize("cell", ["serve-decode", "serve-moe-mla-decode",
                                   "serve-moe-swa-longdoc",
-                                  "serve-moe-conv-chat"])
+                                  "serve-moe-conv-chat",
+                                  "serve-moe-blockdiff-gen"])
 def test_the_token_history_is_handed_on_in_place(one_chip, cell, width):
     """The decode chain's state is the tick program's own: the token history
     ``[slots, max_seq_len]`` int32 comes from the tick before donated, is
@@ -167,10 +174,11 @@ def test_the_token_history_is_handed_on_in_place(one_chip, cell, width):
     ops = [op for op in re.findall(_OPS, text) if op[0] == hist]
     assert (hist, "scatter") in ops
     assert not [op for op in ops if op[1] in ("copy", "concatenate")]
-    # every pool leaf, the history, the lengths and the ends of streams
+    # every pool leaf, the history, the lengths and the ends of streams (and
+    # a block's masked positions and passes where the model denoises blocks)
     aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
     assert aliased.count("may-alias") + aliased.count("must-alias") == \
-        len(pools) + 3
+        len(pools) + (5 if cell == "serve-moe-blockdiff-gen" else 3)
 
 
 @pytest.mark.parametrize("C", [256, 5])
@@ -199,6 +207,31 @@ def test_the_conv_tick_keeps_its_pools_where_they_lie(one_chip, C):
     rows = {256: ("[1,320,65536]", "[320,65536]"),
             5: ("[32,5,65536]", "[160,65536]")}[C]
     logits = {op[0] for op in ops if op[0].endswith(",65536]")}
+    assert logits and logits <= set(rows)
+
+
+@pytest.mark.parametrize("C", [256, 4])
+def test_the_block_tick_samples_on_its_rows_and_reads_tiles(one_chip, C):
+    """``serve-moe-blockdiff-gen``'s two programs: the pool (a position's
+    heads side by side, ``[7, 4096, 16, 512]``) is scattered into in place
+    and never copied whole, the block-masked attention reads a tile of
+    context inside the shared loop (nothing is shaped like a slot's whole
+    context), the expert tile is Mosaic's, and the float32 logits exist on
+    the tick's rows alone: no ``[.., 151936]`` slab wider than them at
+    either width."""
+    import re
+    text, pool = _tick_program("serve-moe-blockdiff-gen", C, one_chip)
+    ops = re.findall(_OPS, text)
+    assert pool == "[7,4096,16,512]" and (pool, "scatter") in ops
+    assert not [op for op in ops if op[0] == pool
+                and op[1] in ("copy", "concatenate")]
+    assert not [op for op in ops if op[0].endswith(",2048,512]")]
+    assert "tpu_custom_call" in text and "expert_tile_ffn" in text
+    # the wide tick's 384 packed rows; the narrow one's slab is its rows
+    rows = {256: ("[1,384,151936]", "[384,151936]"),
+            4: ("[32,4,151936]", "[128,151936]")}[C]
+    logits = {op[0] for op in ops if op[0].endswith(",151936]")
+              } - {"[2048,151936]"}       # the head's own matrix
     assert logits and logits <= set(rows)
 
 
