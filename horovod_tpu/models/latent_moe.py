@@ -304,15 +304,10 @@ def latent_attend(cfg: LatentMoeConfig):
                              1.0 / math.sqrt(cfg.qk_dim))
 
 
-def apply_cached(params: Dict[str, Any], tokens: jax.Array,
-                 cfg: LatentMoeConfig, cache: Dict[str, jax.Array],
-                 block_tables: jax.Array, lengths: jax.Array,
-                 n_new: jax.Array
-                 ) -> Tuple[jax.Array, Dict[str, jax.Array], jax.Array]:
-    """Mixed prefill/decode forward over the latent pool; the slot-table
-    contract of llama.apply_cached.  Returns (logits [S, C, vocab] — zero
-    at positions that were not packed —, updated cache, counters
-    int32[len(TICK_COUNTERS)] summed over the expert layers)."""
+def _forward(params, tokens, cfg, cache, block_tables, lengths, n_new, head):
+    """The tick's rows through the stack: (``head(slab, x)`` of the tick's
+    paged.Slab and its rows' last hidden states ``[1, R, dim]``, under the
+    ``head`` scope; cache; counters)."""
     S, C = tokens.shape
     cos, sin = L.rope_freqs(cfg.qk_rope_dim, cfg.max_seq, cfg.rope_theta)
     positions, valid = paged.slot_positions(lengths, n_new, C)
@@ -365,10 +360,43 @@ def apply_cached(params: Dict[str, Any], tokens: jax.Array,
         x = x + _norm(p["post_mlp_norm"], m, cfg)
         counters = counters + c     # load_max too: a sum over the layers
     with jax.named_scope("head"):
-        logits = slab(L.dense(params["lm_head"],
-                              _norm(params["final_norm"], x, cfg)))
-    return (logits, cache,
-            jnp.concatenate([jnp.ones(1, jnp.int32), counters]))
+        return (head(slab, x), cache,
+                jnp.concatenate([jnp.ones(1, jnp.int32), counters]))
+
+
+def _logits(params, cfg, x):
+    """The final norm and the output head on hidden states ``[.., dim]``."""
+    return L.dense(params["lm_head"], _norm(params["final_norm"], x, cfg))
+
+
+def apply_cached(params: Dict[str, Any], tokens: jax.Array,
+                 cfg: LatentMoeConfig, cache: Dict[str, jax.Array],
+                 block_tables: jax.Array, lengths: jax.Array,
+                 n_new: jax.Array
+                 ) -> Tuple[jax.Array, Dict[str, jax.Array], jax.Array]:
+    """Mixed prefill/decode forward over the latent pool; the slot-table
+    contract of llama.apply_cached.  Returns (logits [S, C, vocab] — zero
+    at positions that were not packed —, updated cache, counters
+    int32[len(TICK_COUNTERS)] summed over the expert layers)."""
+    return _forward(params, tokens, cfg, cache, block_tables, lengths, n_new,
+                    lambda slab, x: slab(_logits(params, cfg, x)))
+
+
+def greedy_cached(params: Dict[str, Any], tokens: jax.Array,
+                  cfg: LatentMoeConfig, cache: Dict[str, jax.Array],
+                  block_tables: jax.Array, lengths: jax.Array,
+                  n_new: jax.Array, read: jax.Array
+                  ) -> Tuple[jax.Array, Dict[str, jax.Array], jax.Array]:
+    """llama.greedy_cached's contract over the latent pool: (tokens int32
+    [S, W] — the greedy token after each slot's columns ``read``, the
+    float32 argmax of :func:`apply_cached`'s logits row there —, cache,
+    counters); the final norm, the head and the argmax run on those ``S *
+    W`` rows alone (paged.Slab.at)."""
+    return _forward(
+        params, tokens, cfg, cache, block_tables, lengths, n_new,
+        lambda slab, x: jnp.argmax(
+            _logits(params, cfg, slab.at(x, read)).astype(jnp.float32),
+            axis=-1).astype(jnp.int32))
 
 
 def param_count(cfg: LatentMoeConfig) -> int:
@@ -385,4 +413,4 @@ def param_count(cfg: LatentMoeConfig) -> int:
 __all__ = ["LatentMoeConfig", "CONFIGS", "TICK_COUNTERS", "BOUNDED_READ",
            "init", "apply",
            "init_cache", "cache_shardings", "copy_blocks", "apply_cached",
-           "attn_blocks", "param_count"]
+           "greedy_cached", "attn_blocks", "param_count"]

@@ -291,8 +291,8 @@ class _Tick(NamedTuple):
     lengths: jax.Array      # [S] positions a slot held before the tick
     n_new: jax.Array        # [S]
     take: Callable          # [S, C, ...] -> rows [1, R, ...] (paged.pack)
-    slab: Callable          # rows -> [S, C, ...] (or a block of it), zero
-                            # where left out
+    slab: paged.Slab        # rows -> [S, C, ...] (or a block of it, or the
+                            # columns a tick reads), zero where left out
     pos: jax.Array          # the rows' positions, inside the rope table
     blk: jax.Array          # where the rows land (paged.write_index)
     off: jax.Array
@@ -358,6 +358,41 @@ def _attn_cached(p: Dict[str, Any], x: jax.Array, cfg: LlamaConfig,
     return L.dense(p["wo"], o.reshape(rows + (-1,))), cache
 
 
+def _forward(params, tokens, cfg, cache, block_tables, lengths, n_new, head):
+    """The tick's rows through the stack: (``head(t, x)`` of the tick and
+    its rows' last hidden states ``[1, R, dim]``, under the ``head`` scope;
+    cache)."""
+    cos, sin = L.rope_freqs(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
+    t = _tick(cfg, cache, block_tables, lengths, n_new, tokens.shape[1])
+    with jax.named_scope("embed"):
+        x = L.embedding(params["embed"], t.take(tokens)).astype(cfg.dtype)
+    for i, p in enumerate(params["layers"]):
+        with jax.named_scope("attn"):
+            a, cache = _attn_cached(
+                p, L.rmsnorm(p["attn_norm"], x), cfg, cos, sin,
+                cache, i, block_tables, t)
+            x = x + a
+        with jax.named_scope("ffn"):
+            x = x + _ffn(p, L.rmsnorm(p["ffn_norm"], x), cfg)
+    with jax.named_scope("head"):
+        return head(t, x), cache
+
+
+def _logits(params: Dict[str, Any], x: jax.Array) -> jax.Array:
+    """The final norm and the output head on hidden states ``[.., dim]``."""
+    return L.dense(params["lm_head"], L.rmsnorm(params["final_norm"], x))
+
+
+def greedy_at(params: Dict[str, Any], read: jax.Array) -> Callable:
+    """:func:`_forward`'s ``head`` for :func:`greedy_cached` (and
+    models/moe_llama.py's): the float32 argmax of the logits of the rows
+    that hold columns ``read`` [S, W] of each slot (``Slab.at``) — ``S * W``
+    rows through the final norm and the head, whatever the tick's width."""
+    return lambda t, x: jnp.argmax(
+        _logits(params, t.slab.at(x, read)).astype(jnp.float32),
+        axis=-1).astype(jnp.int32)
+
+
 def apply_cached(params: Dict[str, Any], tokens: jax.Array,
                  cfg: LlamaConfig, cache: Dict[str, jax.Array],
                  block_tables: jax.Array, lengths: jax.Array,
@@ -372,25 +407,30 @@ def apply_cached(params: Dict[str, Any], tokens: jax.Array,
     caller samples from position ``n_new[s] - 1``: logits are defined at
     VALID positions only (zero where ``cfg.max_tick_tokens`` left a
     position out of the packed rows).  Prefill a prompt in ceil(len/C)
-    calls, then decode one token per call — the serving engine's one jit'd
-    tick (horovod_tpu/serve/engine.py), which donates ``cache``: the
-    stacked pools go through the layers whole."""
-    cos, sin = L.rope_freqs(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
-    t = _tick(cfg, cache, block_tables, lengths, n_new, tokens.shape[1])
-    with jax.named_scope("embed"):
-        x = L.embedding(params["embed"], t.take(tokens)).astype(cfg.dtype)
-    for i, p in enumerate(params["layers"]):
-        with jax.named_scope("attn"):
-            a, cache = _attn_cached(
-                p, L.rmsnorm(p["attn_norm"], x), cfg, cos, sin,
-                cache, i, block_tables, t)
-            x = x + a
-        with jax.named_scope("ffn"):
-            x = x + _ffn(p, L.rmsnorm(p["ffn_norm"], x), cfg)
-    x = L.rmsnorm(params["final_norm"], x)
-    with jax.named_scope("head"):
-        logits = t.slab(L.dense(params["lm_head"], x))
-    return logits, cache
+    calls, then decode one token per call.  The logit-level contract, which
+    tests and references hold the model to; the serving engine's one jit'd
+    tick (horovod_tpu/serve/engine.py), which donates ``cache`` — the
+    stacked pools go through the layers whole —, asks for the tokens it
+    reads and no logits (:func:`greedy_cached`)."""
+    return _forward(params, tokens, cfg, cache, block_tables, lengths, n_new,
+                    lambda t, x: t.slab(_logits(params, x)))
+
+
+def greedy_cached(params: Dict[str, Any], tokens: jax.Array,
+                  cfg: LlamaConfig, cache: Dict[str, jax.Array],
+                  block_tables: jax.Array, lengths: jax.Array,
+                  n_new: jax.Array, read: jax.Array
+                  ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """:func:`apply_cached` for the serving tick: (tokens int32 [S, W],
+    cache), the greedy token after column ``read[s, j]`` of slot s — the
+    float32 argmax of the logits row ``apply_cached`` has there.  ``read``
+    [S, W] int32 names the columns the tick reads (inside ``0 .. C-1``;
+    serve/engine.py ``tick_program``); the final norm, the head and the
+    argmax run on those ``S * W`` rows alone, so a chunk-wide tick builds
+    neither ``[S, C, vocab]`` nor ``[R, vocab]``.  A column past
+    ``n_new[s]``, or one the pack left out, yields a token nobody may use."""
+    return _forward(params, tokens, cfg, cache, block_tables, lengths, n_new,
+                    greedy_at(params, read))
 
 
 def param_count(cfg: LlamaConfig) -> int:

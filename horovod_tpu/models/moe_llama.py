@@ -179,16 +179,10 @@ def attn_blocks(cfg: MoeLlamaConfig, S: int, C: int, ctx: int):
     return Ll.attn_blocks(_llama_cfg(cfg), S, C, ctx)
 
 
-def apply_cached(params: Dict[str, Any], tokens: jax.Array,
-                 cfg: MoeLlamaConfig, cache: Dict[str, jax.Array],
-                 block_tables: jax.Array, lengths: jax.Array,
-                 n_new: jax.Array, moe_fn: Optional[Callable] = None
-                 ) -> tuple[jax.Array, Dict[str, jax.Array], jax.Array]:
-    """Mixed prefill/decode forward over the paged cache (the moe twin
-    of llama.apply_cached; same slot-table contract, the same packed rows).
-    Returns (logits [S, C, vocab], updated cache, mean router aux over the
-    rows).  ``moe_fn`` defaults to the drop-free dense path — the
-    batch-invariant serving routing."""
+def _forward(params, tokens, cfg, cache, block_tables, lengths, n_new,
+             moe_fn, head):
+    """llama._forward with the expert block for the FFN: (``head(t, x)``,
+    cache, mean router aux over the rows)."""
     lcfg = _llama_cfg(cfg)
     moe_fn = moe_fn if moe_fn is not None else dropfree_moe_fn(cfg)
     cos, sin = L.rope_freqs(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
@@ -204,9 +198,35 @@ def apply_cached(params: Dict[str, Any], tokens: jax.Array,
                             moe_fn)
         x = x + y
         auxes.append(aux)
-    x = L.rmsnorm(params["final_norm"], x)
-    return (t.slab(L.dense(params["lm_head"], x)), cache,
-            jnp.mean(jnp.stack(auxes)))
+    with jax.named_scope("head"):
+        return head(t, x), cache, jnp.mean(jnp.stack(auxes))
+
+
+def apply_cached(params: Dict[str, Any], tokens: jax.Array,
+                 cfg: MoeLlamaConfig, cache: Dict[str, jax.Array],
+                 block_tables: jax.Array, lengths: jax.Array,
+                 n_new: jax.Array, moe_fn: Optional[Callable] = None
+                 ) -> tuple[jax.Array, Dict[str, jax.Array], jax.Array]:
+    """Mixed prefill/decode forward over the paged cache (the moe twin
+    of llama.apply_cached; same slot-table contract, the same packed rows).
+    Returns (logits [S, C, vocab], updated cache, mean router aux over the
+    rows).  ``moe_fn`` defaults to the drop-free dense path — the
+    batch-invariant serving routing."""
+    return _forward(params, tokens, cfg, cache, block_tables, lengths, n_new,
+                    moe_fn, lambda t, x: t.slab(Ll._logits(params, x)))
+
+
+def greedy_cached(params: Dict[str, Any], tokens: jax.Array,
+                  cfg: MoeLlamaConfig, cache: Dict[str, jax.Array],
+                  block_tables: jax.Array, lengths: jax.Array,
+                  n_new: jax.Array, read: jax.Array,
+                  moe_fn: Optional[Callable] = None
+                  ) -> tuple[jax.Array, Dict[str, jax.Array], jax.Array]:
+    """llama.greedy_cached's twin: (tokens int32 [S, W] — the greedy token
+    after each slot's columns ``read`` —, cache, mean router aux); the head
+    runs on those ``S * W`` rows alone."""
+    return _forward(params, tokens, cfg, cache, block_tables, lengths, n_new,
+                    moe_fn, Ll.greedy_at(params, read))
 
 
 def param_count(cfg: MoeLlamaConfig) -> int:
@@ -220,6 +240,7 @@ def param_count(cfg: MoeLlamaConfig) -> int:
 
 
 __all__ = ["MoeLlamaConfig", "CONFIGS", "init", "apply", "loss_fn",
-           "param_count", "init_cache", "apply_cached", "copy_blocks",
+           "param_count", "init_cache", "apply_cached", "greedy_cached",
+           "copy_blocks",
            "cache_shardings", "TICK_COUNTERS", "BOUNDED_READ", "attn_blocks",
            "dropfree_moe_fn"]

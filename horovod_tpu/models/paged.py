@@ -137,7 +137,8 @@ def block_of(a: jax.Array, i, per: int, cols: int) -> jax.Array:
 class Slab(NamedTuple):
     """:func:`pack`'s ``slab``: ``slab(a)`` brings rows ``[1, R, ...]`` back
     to their places in ``[S, C, ...]``, ZERO where a position was left out;
-    ``slab(a, i, per, cols)`` only to :func:`block_of` that slab.  ``rows``
+    ``slab(a, i, per, cols)`` only to :func:`block_of` that slab,
+    ``slab.at(a, read)`` only the columns ``read`` of each slot.  ``rows``
     [S, C] is the row of each position (None where nothing is packed: the
     rows ARE the slab), ``kept`` the number of rows."""
     rows: Optional[jax.Array]
@@ -150,6 +151,18 @@ class Slab(NamedTuple):
         back = a[0][jnp.minimum(rows, self.kept - 1)]
         kept = (rows < self.kept).reshape(rows.shape + (1,) * (a.ndim - 2))
         return jnp.where(kept, back, jnp.zeros((), a.dtype))
+
+    def at(self, a: jax.Array, read: jax.Array) -> jax.Array:
+        """The rows of ``a`` ``[1, R, ...]`` that hold each slot's columns
+        ``read`` [S, W] (inside ``0 .. C-1``), as ``[S, W, ...]``: ``take``
+        undone for those positions alone, so that what only they need — a
+        tick's output head (``greedy_cached(.., read)``) — runs on ``S * W``
+        rows.  A position that was left out comes back as the last row's:
+        defined where the position was packed, like ``slab(a)``."""
+        if self.rows is None:
+            return a[jnp.arange(a.shape[0])[:, None], read]
+        rows = jnp.take_along_axis(self.rows, read, axis=1)
+        return a[0][jnp.minimum(rows, self.kept - 1)]
 
 
 def pack(valid: jax.Array, budget: int) -> Tuple[Callable, Slab]:

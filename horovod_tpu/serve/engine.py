@@ -43,6 +43,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import hashlib
+import inspect
 import json
 import os
 import time
@@ -1090,6 +1091,18 @@ def draft_rows(hist, ctx, cap, width: int):
     return draft, n.astype(jnp.int32)
 
 
+def samples_read(model) -> bool:
+    """Whether the module samples only where the tick reads: its
+    ``greedy_cached`` takes ``read``, each slot's ``decode_width`` columns
+    whose greedy token the tick uses, and returns those tokens alone
+    (models/llama.py, moe_llama.py, latent_moe.py).  The module's signature
+    declares it; nothing else chooses (docs/serving.md
+    #what-a-served-model-module-exports)."""
+    greedy = getattr(model, "greedy_cached", None)
+    return greedy is not None and \
+        "read" in inspect.signature(greedy).parameters
+
+
 def tick_program(model, mcfg, cfg: ServeConfig):
     """One tick as a function of device arrays, for ``jit`` (ServeEngine.
     _build_step; tests/test_tpu_compile.py compiles it for a described
@@ -1097,9 +1110,11 @@ def tick_program(model, mcfg, cfg: ServeConfig):
     -> (cache, hist, length, done, report, counters)``.  ``cache`` and the
     chain — ``hist`` ``[slots, max_seq_len]``, ``length`` and ``done``
     ``[slots]``, int32 — are the tick before's and are donated; ``rows`` is
-    ``ROW``; ``report`` holds, a slot a row, the greedy tokens of every
-    column, the verify row as it was fed, the row's columns and the length
-    it ran at (ServeEngine._harvest reads it)."""
+    ``ROW``; ``report`` holds, a slot a row, the greedy tokens — of the W
+    columns the tick reads where the module samples there
+    (:func:`samples_read`), else of every column —, the verify row as it was
+    fed, the row's columns and the length it ran at (ServeEngine._harvest
+    reads it)."""
     import jax
     import jax.numpy as jnp
 
@@ -1107,6 +1122,16 @@ def tick_program(model, mcfg, cfg: ServeConfig):
         return block_tick_program(model, mcfg, cfg)
     W = decode_width(cfg)
     counted = bool(model.TICK_COUNTERS)
+    reads = samples_read(model)
+
+    def read_columns(decode, n_new, C):
+        """(0 .. W-1, the columns [S, W] whose greedy token a ``[S, C]``
+        tick reads): a decode or verify row's from 0, a prompt's last
+        chunk's last; what lies past a row's columns is read by no one
+        (``_emit`` on the host, the chain below)."""
+        cols = jnp.arange(W)[None, :]
+        first = jnp.where(decode, 0, n_new - 1)[:, None]
+        return cols, jnp.clip(first + cols, 0, C - 1)
 
     def step_fn(params, cache, hist, length, done, block_tables, rows,
                 tokens):
@@ -1141,10 +1166,13 @@ def tick_program(model, mcfg, cfg: ServeConfig):
                 tokens[:, :W]))
             # a row that runs nothing reads nothing, like a free slot
             lengths = jnp.where(n_new > 0, ctx, 0)
+            if reads:
+                cols, read = read_columns(decode, n_new, C)
         greedy = getattr(model, "greedy_cached", None)
         with jax.named_scope("tick/model"):
             out = (greedy or model.apply_cached)(
-                params, fed, mcfg, cache, block_tables, lengths, n_new)
+                params, fed, mcfg, cache, block_tables, lengths, n_new,
+                *((read,) if reads else ()))
         logits, cache = out[:2]
         counters = out[2] if counted else None
         if greedy is not None:      # the module sampled on its rows
@@ -1166,10 +1194,13 @@ def tick_program(model, mcfg, cfg: ServeConfig):
         # ends at ``eos`` or at its limit; the history takes what was
         # emitted.
         with jax.named_scope("tick/chain"):
-            cols = jnp.arange(W)[None, :]
-            first = jnp.where(decode, 0, n_new - 1)[:, None]
-            new = jnp.take_along_axis(
-                next_tokens, jnp.clip(first + cols, 0, C - 1), axis=1)
+            if reads:       # [S, W], sampled at ``read`` and nowhere else
+                new = next_tokens
+            else:           # [S, C]: the S x W of them that are read
+                cols, read = read_columns(decode, n_new, C)
+                new = jnp.take_along_axis(next_tokens, read, axis=1)
+            # (a decode row reads from column 0: its first W greedy tokens
+            # are ``new``'s in either form)
             agree = ((fed[:, 1:W] == next_tokens[:, :W - 1])
                      & (cols[:, 1:] < n_new[:, None]) & decode[:, None])
             accepted = jnp.sum(jnp.cumprod(agree, axis=1), axis=1)
@@ -1191,9 +1222,9 @@ def tick_program(model, mcfg, cfg: ServeConfig):
             done = jnp.where(ran, (emits & (
                 (eos_at <= accepted) | (held + n_out >= rows[_LIMIT]))
                 ).astype(jnp.int32), done)
-            # one array for the fence's one copy: the greedy tokens,
-            # the verify rows as they were fed, each row's columns and
-            # the length it ran at
+            # one array for the fence's one copy: the greedy tokens (the
+            # W that are read, or every column's), the verify rows as they
+            # were fed, each row's columns and the length it ran at
             report = jnp.concatenate(
                 [next_tokens, fed[:, :W], n_new[:, None], ctx[:, None]],
                 axis=1).astype(jnp.int32)
@@ -1377,7 +1408,14 @@ class ServeEngine:
     window, conv_moe.py whole contexts and a state, the other modules none:
     one pool, one table) —, and ``greedy_cached``, the tick's greedy tokens
     in place of its logits, for a vocabulary whose ``[slots, chunk, vocab]``
-    slab should never exist (swa_moe.py, conv_moe.py).
+    slab should never exist: with ``read``, the columns whose token the tick
+    reads, the head runs on those rows alone and the tokens come back
+    ``[slots, decode width]`` (llama.py, moe_llama.py, latent_moe.py;
+    ``samples_read``); without, on every packed row, ``[slots, chunk]``
+    (swa_moe.py, conv_moe.py, until the PR that next changes their programs
+    moves them over: ROADMAP S11, S12).  ``stats()["loop"]`` counts the rows
+    of the wide ticks and those their head ran on (``packed_rows``,
+    ``head_rows``).
     """
 
     def __init__(self, model, model_cfg, params, cfg: ServeConfig,
@@ -1479,6 +1517,11 @@ class ServeEngine:
         # (``model.attn_blocks``: slots a block, a narrow block's columns).
         self._wide_rows = np.zeros(2, np.int64)
         self._wide_blocks = np.zeros(2, np.int64)
+        # ... and how many rows the wide ticks' output head ran on: the
+        # columns the tick reads where the module samples there
+        # (``samples_read``), else every row of the program.
+        self._samples_read = samples_read(model)
+        self._head_rows = 0
         self._attn_blocks = model.attn_blocks(
             model_cfg, cfg.max_slots, cfg.prefill_chunk,
             cfg.max_blocks_per_seq * cfg.block_size)
@@ -1919,29 +1962,35 @@ class ServeEngine:
                 out = self._emit_block(tick, launched, report_host, n_new,
                                        lengths)
             else:
-                out = self._emit(tick, launched, report_host[:, :C],
-                                 report_host[:, C:C + W], n_new, lengths)
+                T = W if self._samples_read else C  # the tokens' columns
+                out = self._emit(tick, launched, report_host[:, :T],
+                                 report_host[:, T:T + W], n_new, lengths)
         self._fenced = (ready, wait, emit)
         return out
 
     def _count_wide(self, n_new: np.ndarray, used: int) -> None:
         """One wide tick's rows against the program that ran them: the rows
         the model computed (its slab's positions, or the token budget it
-        packs them into) and the blocks of slots that attended at chunk
-        width.  Host arithmetic on the report's columns."""
+        packs them into), those of them its output head ran on (the
+        columns the tick reads, where the module samples there) and the
+        blocks of slots that attended at chunk width.  Host arithmetic on
+        the report's columns."""
         cfg = self.cfg
-        self._wide_rows += (used, min(cfg.max_slots * cfg.prefill_chunk,
-                                      cfg.max_batch_tokens))
+        packed = min(cfg.max_slots * cfg.prefill_chunk, cfg.max_batch_tokens)
+        self._wide_rows += (used, packed)
+        self._head_rows += (cfg.max_slots * decode_width(cfg)
+                            if self._samples_read else packed)
         self._wide_blocks += paged.wide_blocks(n_new.astype(np.int64),
                                                *self._attn_blocks)
 
     def _emit(self, tick, launched, tokens_host, fed, n_new, lengths
               ) -> Dict[str, Any]:
         """The host half of a fence: advance every request of the tick by
-        what the device reports — the greedy tokens, the verify rows as it
-        fed them, each row's columns and length —, finish those that are
-        done.  The device has advanced its own chain by the same rules
-        (``tick_program``)."""
+        what the device reports — the greedy tokens (of the columns the tick
+        reads, a prompt's last in column 0, where the module samples there;
+        else of every column), the verify rows as it fed them, each row's
+        columns and length —, finish those that are done.  The device has
+        advanced its own chain by the same rules (``tick_program``)."""
         from ..utils import metrics as M
         now = time.perf_counter()
         emitted: Dict[str, List[int]] = {}
@@ -1961,6 +2010,8 @@ class ServeEngine:
                 M.SERVE_PREFILL_CHUNKS.inc()
                 if kind == CHUNK:
                     continue  # still prefilling
+                sampled = int(tokens_host[
+                    slot, 0 if self._samples_read else n - 1])
                 if self.scheduler.role == "prefill":
                     # Disaggregation: this rank's job ends at prefill
                     # completion — export the prompt KV + first token
@@ -1968,9 +2019,8 @@ class ServeEngine:
                     # tree (the next shared prompt still hits), free
                     # the slot.  The first token is NOT emitted here;
                     # the decode side emits it (exactly-once).
-                    first = int(tokens_host[slot, n - 1])
                     self.scheduler.register_prefix(req)
-                    handoffs.append(self.export_handoff(req, first))
+                    handoffs.append(self.export_handoff(req, sampled))
                     self.scheduler.finish(req, "prefill_done")
                     self._close_loop(req)
                     finished.append(req)
@@ -1978,7 +2028,7 @@ class ServeEngine:
                     M.SERVE_HANDOFFS.inc()
                     continue
                 self.scheduler.register_prefix(req)
-                new_toks = [int(tokens_host[slot, n - 1])]
+                new_toks = [sampled]
             else:
                 # Greedy verification: row[j] is the greedy continuation
                 # after consuming input positions <= j, so draft[j] is
@@ -2336,6 +2386,8 @@ class ServeEngine:
                            timeline=self.clock.timeline(),
                            ticks=self._ticks(),
                            wide_rows_share=share(self._wide_rows),
+                           head_rows=self._head_rows,
+                           packed_rows=int(self._wide_rows[1]),
                            wide_blocks_share=share(self._wide_blocks),
                            context_read_share=share(self._read[:2]),
                            dead_blocks_share=share(self._read[2:]))

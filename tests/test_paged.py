@@ -347,8 +347,9 @@ def test_shardings_ride_the_meshs_own_axes():
 # ------------------------------------------------ the engine's contract
 CONTRACT = ("init_cache", "copy_blocks", "apply_cached", "cache_shardings",
             "attn_blocks", "TICK_COUNTERS")
-#: what a module MAY declare beside them (swa_moe does): its cache kinds, and
-#: the tick's greedy tokens in place of its logits
+#: what a module MAY declare beside them: its cache kinds (swa_moe, conv_moe)
+#: and the tick's greedy tokens in place of its logits — of every column, or
+#: with ``read`` of the columns the tick reads (tests/test_greedy_read.py)
 OPTIONAL = ("cache_kinds", "greedy_cached")
 
 

@@ -19,7 +19,8 @@ import pytest
 from horovod_tpu.serve.config import (ServeConfig, from_knobs,
                                       validate_serve_knobs)
 from horovod_tpu.serve.engine import (BlockAllocator, Request, Scheduler,
-                                      ServeEngine, decode_width, tick_width)
+                                      ServeEngine, decode_width, samples_read,
+                                      tick_width)
 from horovod_tpu.utils.profiler import compile_counts
 
 
@@ -360,9 +361,12 @@ def _step_and_note_widths(engine, widths):
     if engine.tick > tick:  # what this step launched is the newest in flight
         _, width, rows, report = engine._inflight[-1][:4]
         assert width == tick_width(engine.cfg, rows)
-        # the greedy tokens, the verify rows as fed, a row's columns, length
-        assert report.shape == (engine.cfg.max_slots,
-                                width + decode_width(engine.cfg) + 2)
+        # the greedy tokens (of the columns the tick reads, where the module
+        # samples there), the verify rows as fed, a row's columns, length
+        W = decode_width(engine.cfg)
+        assert report.shape == (
+            engine.cfg.max_slots,
+            (W if samples_read(engine.model) else width) + W + 2)
         widths.append(width)
 
 

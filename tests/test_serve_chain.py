@@ -6,6 +6,7 @@ model whose streams are known by hand (tests/test_serve_chain_families.py
 has the five model families)."""
 
 import dataclasses
+import types
 
 import jax
 import jax.numpy as jnp
@@ -16,7 +17,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from horovod_tpu.models import paged
 from horovod_tpu.serve.config import ServeConfig
 from horovod_tpu.serve.engine import (DECODE, LAST, ROW, Request, ServeEngine,
-                                      decode_width, draft_rows)
+                                      decode_width, draft_rows, samples_read)
 
 KIND = ROW.index("kind")
 
@@ -75,13 +76,14 @@ def test_device_drafter_is_draft_lookup_token_for_token(name):
     # a context of two tokens has no prior bigram
     ("short", 16, 6, [[7]]),
 ])
-def test_device_drafter_caps(case, budget, new, prompts):
+def test_device_drafter_caps(scripted, case, budget, new, prompts):
     """Every verify row of a run on the scripted model, replayed on the
     host: the drafts the tick fed are ``draft_lookup``'s under the row's
     caps — ``spec_k``, the columns the plan gave the row (the tick budget;
     the verify row's width), the remaining generation, a stream shorter
     than three — token for token."""
-    engine = _markov_engine(max_batch_tokens=budget, prefill_chunk=5)
+    engine = _markov_engine(model=scripted, max_batch_tokens=budget,
+                            prefill_chunk=5)
     ticks = _record_ticks(engine)
     reqs = [engine.submit(p, new, req_id=str(i))
             for i, p in enumerate(prompts)]
@@ -156,6 +158,34 @@ class Markov:
         return params["next"][tokens], cache
 
 
+class MarkovRead(Markov):
+    """``Markov`` sampling where the tick reads (``greedy_cached(.., read)``,
+    as models/llama.py has it): the token after each slot's columns ``read``
+    [S, W] and after no other."""
+
+    @staticmethod
+    def greedy_cached(params, tokens, cfg, cache, block_tables, lengths,
+                      n_new, read):
+        every, cache = Markov.greedy_cached(
+            params, tokens, cfg, cache, block_tables, lengths, n_new)
+        return jnp.take_along_axis(every, read, axis=1), cache
+
+
+@pytest.fixture(params=[Markov, MarkovRead], ids=["columns", "read"])
+def scripted(request):
+    """The scripted model in either form of ``greedy_cached``: a token a
+    column of the tick, or a token a column the tick reads."""
+    return request.param
+
+
+def stripped(model):
+    """``model`` (a module) without its ``greedy_cached``: the tick takes
+    ``apply_cached``'s ``[slots, chunk, vocab]`` logits and their argmax
+    itself (``tick/sample``), and reports a token a column."""
+    return types.SimpleNamespace(**{k: v for k, v in vars(model).items()
+                                    if k != "greedy_cached"})
+
+
 def _markov_stream(prompt, n):
     out, tok = [], prompt[-1]
     for _ in range(n):
@@ -164,22 +194,24 @@ def _markov_stream(prompt, n):
     return out
 
 
-def _markov_engine(role="mixed", **kw):
+def _markov_engine(role="mixed", model=Markov, **kw):
     base = dict(max_slots=2, block_size=4, cache_blocks=32, max_seq_len=48,
                 max_batch_tokens=16, prefill_chunk=8, spec_k=3,
                 prefix_cache=False)
     base.update(kw)
-    return ServeEngine(Markov, _MarkovConfig(), {"next": Markov.NEXT},
+    return ServeEngine(model, _MarkovConfig(), {"next": Markov.NEXT},
                        ServeConfig(**base), mesh=_mesh(), role=role)
 
 
 def _record_ticks(engine):
     """Every launched tick, fetched as it is launched: the rows the host
     staged, and what the program returned — the pool, the chain, the report
-    (greedy tokens | the verify rows as fed | columns | lengths)."""
+    (greedy tokens, of every column or of the W the tick reads | the verify
+    rows as fed | columns | lengths)."""
     engine._compile_steps()
     ticks = []
     W = decode_width(engine.cfg)
+    read = samples_read(engine.model)
 
     def recording(C, step):
         def run(params, cache, hist, length, done, tables, rows, tokens):
@@ -187,9 +219,10 @@ def _record_ticks(engine):
                        tokens)
             cache, hist, length, done, report = jax.device_get(out[:5])
             rows = np.asarray(rows)
+            T = W if read else C
             ticks.append({"kind": rows[KIND], "rows": rows, "cache": cache,
                           "hist": hist, "length": length, "done": done,
-                          "greedy": report[:, :C], "fed": report[:, C:C + W],
+                          "greedy": report[:, :T], "fed": report[:, T:T + W],
                           "n": report[:, -2], "at": report[:, -1]})
             return out
         return run
@@ -203,14 +236,14 @@ def _same_tree(a, b):
         jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)))
 
 
-def test_eos_in_the_middle_of_a_verify_row_ends_the_stream_there():
+def test_eos_in_the_middle_of_a_verify_row_ends_the_stream_there(scripted):
     """1 -> 2 -> 3 -> 1 ...: from the second period on the drafter is right,
     the verify rows are wholly accepted, and an ``eos_id`` of 3 falls in the
     middle of one.  The stream ends at the 3, the drafts verified behind it
     are discarded, and the row launched ahead for the ended stream runs
     nothing: pool, history, length and end of stream as the tick before left
     them."""
-    engine = _markov_engine(max_slots=1)
+    engine = _markov_engine(model=scripted, max_slots=1)
     ticks = _record_ticks(engine)
     req = engine.submit([1, 2, 3, 1], 20, req_id="a", eos_id=3)
     reports = engine.flush()
@@ -235,13 +268,14 @@ def test_eos_in_the_middle_of_a_verify_row_ends_the_stream_there():
     engine.close()
 
 
-def test_max_new_at_a_verify_rows_end_and_the_slot_reused_the_tick_after():
+def test_max_new_at_a_verify_rows_end_and_the_slot_reused_the_tick_after(
+        scripted):
     """One slot, two requests.  ``a`` (7, 8, 7, 8 ...) reaches its
     ``max_new_tokens`` with the last token of an accepted verify row, which
     the host cannot know before the fence: the row launched ahead runs
     nothing, and ``b``, waiting for the slot, is admitted into it the tick
     after — its chunk, not the chain's stale end of stream, decides."""
-    engine = _markov_engine(max_slots=1)
+    engine = _markov_engine(model=scripted, max_slots=1)
     ticks = _record_ticks(engine)
     a = engine.submit([7, 8, 7, 8, 7], 6, req_id="a")
     b = engine.submit([5, 6, 5], 4, req_id="b")

@@ -14,9 +14,9 @@ import numpy as np
 import pytest
 
 from horovod_tpu.serve.config import ServeConfig
-from horovod_tpu.serve.engine import DECODE, ROW, ServeEngine
+from horovod_tpu.serve.engine import DECODE, ROW, ServeEngine, samples_read
 
-from test_serve_chain import _mesh, _record_ticks, _same_tree
+from test_serve_chain import _mesh, _record_ticks, _same_tree, stripped
 
 FAMILIES = ["llama", "moe_llama", "latent_moe", "swa_moe", "conv_moe"]
 SHARING = FAMILIES[:3]      # whole contexts only: prefix cache and hand-off
@@ -110,6 +110,50 @@ def test_launch_ahead_serves_the_plain_greedy_references_tokens(family):
     loop = stats["loop"]
     assert loop["ahead_n"] >= loop["ticks"] - 3     # but after an idle engine
     assert loop["turnaround_n"] + loop["after_idle_n"] == loop["ticks"]
+
+
+def test_the_slab_branch_serves_the_same_streams_counts_and_ends(family):
+    """The module as it samples in the tick — llama, moe_llama and
+    latent_moe on the rows of the columns the tick reads (``greedy_cached(..,
+    read)``), swa_moe and conv_moe on every packed row — against the same
+    module without ``greedy_cached``, through the tick's slab branch (the
+    argmax of ``apply_cached``'s ``[slots, chunk, vocab]`` logits): with
+    speculation on and an ``eos_id`` out of the streams, the same tokens,
+    drafts made and accepted, ends of stream and ticks at each width.  The
+    head of a wide tick ran on 3 slots x (1 + spec_k) rows of the 24 packed
+    where the module takes ``read``, on all 24 elsewhere."""
+    name, model, cfg, params, plain, _ = family
+    eos = plain[0].out_tokens[3]
+    runs = {}
+    for form, served in (("module", model), ("slab", stripped(model))):
+        engine = ServeEngine(served, cfg, params, _scfg(name), mesh=_mesh())
+        reqs = _script(engine, cfg.vocab, eos=eos)
+        stats = engine.stats()
+        engine.close()
+        runs[form] = ([(r.out_tokens, r.finish_reason) for r in reqs],
+                      stats["spec"], stats["loop"])
+    (ours, spec, loop), (theirs, slab_spec, slab_loop) = \
+        runs["module"], runs["slab"]
+    assert ours == theirs and ours[0][1] == "eos"
+    assert spec == slab_spec
+    wide = loop["by_width"]["wide"]["ticks"]
+    assert wide == slab_loop["by_width"]["wide"]["ticks"] > 0
+    assert loop["by_width"]["narrow"]["ticks"] == \
+        slab_loop["by_width"]["narrow"]["ticks"] > 0
+    assert loop["packed_rows"] == slab_loop["packed_rows"] == 24 * wide
+    assert slab_loop["head_rows"] == 24 * wide
+    assert samples_read(model) == (name in SHARING)
+    assert loop["head_rows"] == (12 if samples_read(model) else 24) * wide
+    # ... and where every draft is known (``_flat``: zeros), the slab branch
+    # drafts and accepts what the module's form does
+    # (test_speculation_drafts_and_accepts_what_the_fenced_order_did)
+    engine = ServeEngine(stripped(model), cfg, _flat(params), _scfg(name),
+                         mesh=_mesh())
+    _script(engine, cfg.vocab)
+    spec = engine.stats()["spec"]
+    engine.close()
+    assert (spec["drafted_tokens"], spec["accepted_tokens"]) == \
+        FENCED_ORDER_SPEC
 
 
 # drafted / accepted tokens of ``_script`` under ``_flat`` weights on the
@@ -243,3 +287,23 @@ def test_a_prefill_roles_hand_off_and_a_decode_roles_import(sharing):
     _assert_reference(model, cfg, params, reqs)
     pre.close()
     dec.close()
+
+
+def test_a_hand_offs_first_token_is_the_slab_branchs(sharing):
+    """A ``prefill`` role reads one token a prompt, its last chunk's last
+    column — column 0 of what a module that takes ``read`` reports: the
+    first tokens handed off are those of the tick's slab branch."""
+    name, model, cfg, params = sharing
+    first = {}
+    for served in (model, stripped(model)):
+        engine = ServeEngine(served, cfg, params, _scfg(name), mesh=_mesh(),
+                             role="prefill")
+        for i, p in enumerate(_prompts(cfg.vocab)):
+            engine.submit(p, 8, req_id=f"r{i}")
+        handoffs = []
+        while engine.has_work():
+            handoffs.extend(engine.step().get("handoff", []))
+        first[served is model] = {h["req_id"]: h["first_token"]
+                                  for h in handoffs}
+        engine.close()
+    assert first[True] == first[False] and len(first[True]) == 6

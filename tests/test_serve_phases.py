@@ -23,6 +23,7 @@ from horovod_tpu.serve.engine import ServeEngine
 from horovod_tpu.serve.router import RouterState
 from horovod_tpu.serve.worker import FleetFrontend
 from horovod_tpu.utils.profiler import PhaseClock, compile_counts
+from tests.test_serve_chain import stripped
 from tests.test_serve_ft import ScriptedEngine
 
 CFG = llama.CONFIGS["tiny"]
@@ -521,15 +522,19 @@ def _lowered_texts():
         debug_info=True)
     scfg = ServeConfig(max_slots=2, block_size=4, cache_blocks=16,
                        max_seq_len=32, max_batch_tokens=16, prefill_chunk=8)
-    engine = ServeEngine(llama, CFG, params, scfg, mesh=jax.sharding.Mesh(
-        np.array(jax.devices()[:1]), ("hvd",)))
-    try:
-        tick = engine._step_fn.lower(
-            engine.params, engine.cache, *engine._chain,
-            *engine._tick_shapes(8)).as_text(debug_info=True)
-    finally:
-        engine.close()
-    return {"train": train, "cached": cached, "tick": tick}
+    # the module as it is (it samples where the tick reads), and without its
+    # ``greedy_cached``: the tick takes the logits' argmax itself
+    ticks = {}
+    for name, model in (("tick", llama), ("tick_logits", stripped(llama))):
+        engine = ServeEngine(model, CFG, params, scfg, mesh=jax.sharding.Mesh(
+            np.array(jax.devices()[:1]), ("hvd",)))
+        try:
+            ticks[name] = engine._step_fn.lower(
+                engine.params, engine.cache, *engine._chain,
+                *engine._tick_shapes(8)).as_text(debug_info=True)
+        finally:
+            engine.close()
+    return {"train": train, "cached": cached, **ticks}
 
 
 @pytest.fixture(scope="module")
@@ -548,7 +553,8 @@ def lowered():
                 "head", "kv_write"]),
     ("tick", ["tick/copy_blocks", "tick/chain", "tick/model/attn",
               "jit(_attend_tiled)", "while/body/while/body/kv_gather",
-              "tick/model/ffn", "tick/model/head", "tick/sample"]),
+              "tick/model/ffn", "tick/model/head"]),
+    ("tick_logits", ["tick/chain", "tick/model/head", "tick/sample"]),
 ])
 def test_lowered_program_names_each_scope(lowered, program, scopes):
     text = lowered[program]

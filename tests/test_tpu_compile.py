@@ -67,42 +67,11 @@ def _tick_programs(cell, one_chip):
     chip as the engine jits it: ({width: text}, (narrow, wide), {leaf: pool
     dims}, {leaf: the pool's axes from major to minor as the program takes
     it})."""
-    import dataclasses
-
     from horovod_tpu.models import paged
-    from horovod_tpu.serve import engine as E
-    from perfbench.lib import spec, weights
-    _, config, _ = spec.cell(cell)
-    scfg = E.ServeConfig(**config["engine"])
-    model, cfg = spec.family(config).program(config)
-    cfg = dataclasses.replace(cfg, max_tick_tokens=scfg.max_batch_tokens)
-    sds = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
-    tree = lambda t: jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), t)
-    params = tree(jax.eval_shape(lambda: weights.make(
-        config, weights.seed_key(0), weights.dtype_of(config))))
-    S, i32 = scfg.max_slots, jnp.int32
-    sched = E.Scheduler(scfg, kinds=model.cache_kinds(cfg)
-                        if hasattr(model, "cache_kinds") else ())
-    tables = jax.tree_util.tree_map(lambda t: sds(t.shape, i32),
-                                    sched.device_tables())
-    cache = tree(jax.eval_shape(lambda: model.init_cache(
-        cfg, sched.pool_blocks(), scfg.block_size)))
-    # a model that denoises blocks hands on two more states a slot
-    B = E.block_length(cfg)
-    chain = (sds((S, scfg.max_seq_len), i32), sds((S,), i32), sds((S,), i32)
-             ) + ((sds((S, B), i32), sds((S,), i32)) if B else ())
-    widths = (E.decode_width(scfg, B), scfg.prefill_chunk)
-    orig = jax.default_backend
-    jax.default_backend = lambda: "tpu"     # the expert tile is Mosaic's
-    try:
-        steps = {C: jax.jit(E.tick_program(model, cfg, scfg),
-                            donate_argnums=tuple(range(1, 2 + len(chain)))
-                            ).lower(
-            params, cache, *chain, tables,
-            sds((len(E.BLOCK_ROW if B else E.ROW), S), i32),
-            sds((S, C), i32)).compile() for C in widths}
-    finally:
-        jax.default_backend = orig
+    from perfbench.tools import tick_text
+    lowered, cache = tick_text.lowered(cell, one_chip)
+    widths = tuple(lowered)
+    steps = {C: low.compile() for C, low in lowered.items()}
     fmt = steps[widths[0]].input_formats[0][1]
     by_leaf = lambda f, t: {paged.leaf_key(path): f(x) for path, x in
                             jax.tree_util.tree_flatten_with_path(t)[0]}
@@ -179,6 +148,30 @@ def test_the_token_history_is_handed_on_in_place(one_chip, cell, width):
     aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
     assert aliased.count("may-alias") + aliased.count("must-alias") == \
         len(pools) + (5 if cell == "serve-moe-blockdiff-gen" else 3)
+
+
+@pytest.mark.parametrize("cell", ["serve-decode", "serve-moe-mla-decode"])
+def test_the_wide_tick_runs_its_head_on_the_rows_it_reads(one_chip, cell):
+    """The chunk-wide program of a module that samples where the tick reads
+    (``greedy_cached(.., read)``: models/llama.py, models/latent_moe.py):
+    beside the vocabulary no array but the head's own matrix holds more rows
+    than the ``slots x (1 + spec_k)`` whose token the tick reads — no
+    ``[16,128,92544]`` slab and no ``[512,92544]`` logits of every packed
+    row in ``serve-decode`` —, and the logits of those rows are there."""
+    import math
+    import re
+
+    from perfbench.lib import spec
+    texts, (W, wide), _, _ = _tick_programs(cell, one_chip)
+    config = spec.cell(cell)[1]
+    S = config["engine"]["max_slots"]
+    mcfg = spec.family(config).program(config)[1]
+    V, head = mcfg.vocab, (mcfg.dim, mcfg.vocab)
+    shaped = {tuple(map(int, d.split(",")))
+              for d in re.findall(r"\[([\d,]+)\]", texts[wide])}
+    rows = {math.prod(d[:-1]) for d in shaped
+            if len(d) > 1 and d[-1] == V and d != head}
+    assert rows and max(rows) == S * W, (cell, sorted(rows))
 
 
 @pytest.mark.parametrize("C", [256, 5])
