@@ -11,8 +11,8 @@ shapes.  The stacked pool is indexed by layer, never unstacked: a donated
 cache stays one buffer through a tick (docs/serving.md).
 
 A model whose layers keep state of several KINDS declares them
-(:class:`CacheKind`) and holds one pool a kind.  There are three
-(docs/serving.md#cache-kinds):
+(:class:`CacheKind`) and holds one pool a kind.  There are three, the
+third in two forms (docs/serving.md#cache-kinds):
 
   * the WHOLE CONTEXT, as above: a block table over the paged pool;
   * a RING of a window (models/swa_moe.py: layers that read a window of the
@@ -26,7 +26,14 @@ A model whose layers keep state of several KINDS declares them
     columns]`` (:func:`state_index`), and a layer reads back the columns of
     the last positions before its tick's own (:func:`state_read`);
     :func:`state_columns` says how many columns keep those through a
-    rejected draft.
+    rejected draft.  Where what a layer keeps FOLDS all earlier positions
+    (models/sambay.py: a selective scan's carry) a column is no input of a
+    position but the state AFTER it: a tick reads the ONE column at its
+    slot's last position (:func:`carry_read`, ``state`` = 1) and writes one
+    after each of its last rows at the same ``[s, P % columns]``, so that a
+    verify row of which ``a`` drafts are accepted leaves the carry after
+    row ``a`` where the next tick looks for it.  What lies behind the
+    columns is the model's: ``[.., d]``, or a carry's ``[.., d_state, d]``.
 """
 
 from __future__ import annotations
@@ -103,6 +110,21 @@ def state_read(pool: jax.Array, layer: int, own: jax.Array, slot: jax.Array,
     return jnp.where((at >= 0)[:, None],
                      jnp.where((at >= lengths)[:, None], mine, kept),
                      jnp.zeros((), own.dtype))
+
+
+def carry_read(pool: jax.Array, layer: int, lengths: jax.Array) -> jax.Array:
+    """The state that each slot's LAST position left, ``[S, ...]``: the
+    column of position ``lengths - 1`` of ``pool[layer]`` ``[S, cols, ...]``
+    AS IT WAS BEFORE THE TICK, and zero for a slot that holds nothing (a
+    slot's new tenant starts from nothing; so does a slot that runs no row,
+    whose length the tick is handed as 0).  The counterpart of
+    :func:`state_read` for a state that folds its past: the tick's own rows
+    are scanned from it, and :func:`write` at :func:`state_index` keeps the
+    state after each of the last ``cols`` of them."""
+    S = lengths.shape[0]
+    kept = pool[layer, jnp.arange(S), (lengths - 1) % pool.shape[2]]
+    held = (lengths > 0).reshape((S,) + (1,) * (kept.ndim - 1))
+    return jnp.where(held, kept, jnp.zeros((), pool.dtype))
 
 
 def ring_blocks(window: int, tick_cols: int, block_size: int,

@@ -544,7 +544,11 @@ class _State:
     (paged.state_columns, where the margin for rejected drafts is argued).
     The pool holds ``max_slots`` of them: a slot IS its state, so there is
     nothing to allocate, admit against or give back, and a slot's next
-    tenant reads none of it (paged.state_read)."""
+    tenant reads none of it (paged.state_read, paged.carry_read).  What a
+    column holds is the model's — a position's input ``[d]``, or the state a
+    scan left after it ``[d_state, d]`` (models/sambay.py) —, so a kind's
+    bytes a slot are read off its pool, never reckoned from ``columns``
+    (:meth:`ServeEngine._kind_pool`); a model may keep several such kinds."""
 
     def __init__(self, kind, cfg: ServeConfig):
         self.kind = kind
@@ -1405,13 +1409,14 @@ class ServeEngine:
     block counts are then dicts by kind, a kind with a window is a ring a
     slot and a kind with a fixed state ``(slots, columns)`` with no table
     (docs/serving.md#cache-kinds; swa_moe.py declares whole contexts and a
-    window, conv_moe.py whole contexts and a state, the other modules none:
-    one pool, one table) —, and ``greedy_cached``, the tick's greedy tokens
+    window, conv_moe.py whole contexts and a state, sambay.py all three and
+    a second state, a scan's carry; the other modules none: one pool, one
+    table) —, and ``greedy_cached``, the tick's greedy tokens
     in place of its logits, for a vocabulary whose ``[slots, chunk, vocab]``
     slab should never exist: with ``read``, the columns whose token the tick
     reads, the head runs on those rows alone and the tokens come back
-    ``[slots, decode width]`` (llama.py, moe_llama.py, latent_moe.py;
-    ``samples_read``); without, on every packed row, ``[slots, chunk]``
+    ``[slots, decode width]`` (llama.py, moe_llama.py, latent_moe.py,
+    sambay.py; ``samples_read``); without, on every packed row, ``[slots, chunk]``
     (swa_moe.py, conv_moe.py, until the PR that next changes their programs
     moves them over: ROADMAP S11, S12).  ``stats()["loop"]`` counts the rows
     of the wide ticks and those their head ran on (``packed_rows``,
@@ -2420,7 +2425,8 @@ _MODEL_MODULES = {"llama": "horovod_tpu.models.llama",
                   "latent_moe": "horovod_tpu.models.latent_moe",
                   "swa_moe": "horovod_tpu.models.swa_moe",
                   "conv_moe": "horovod_tpu.models.conv_moe",
-                  "blockdiff_moe": "horovod_tpu.models.blockdiff_moe"}
+                  "blockdiff_moe": "horovod_tpu.models.blockdiff_moe",
+                  "sambay": "horovod_tpu.models.sambay"}
 
 
 def save_servable(directory: str, model_name: str, config, params,
@@ -2441,7 +2447,7 @@ def save_servable(directory: str, model_name: str, config, params,
 def load_servable(directory: str, mesh) -> Tuple[Any, Any, Any]:
     """Read a servable directory -> (model module, model config, global
     replicated params).  ``serve.json``: {"model": "llama"|"moe_llama"|
-    "latent_moe"|"swa_moe"|"conv_moe"|"blockdiff_moe",
+    "latent_moe"|"swa_moe"|"conv_moe"|"blockdiff_moe"|"sambay",
     "config": <name in CONFIGS or kwarg dict>, "seed": int?}.  Params
     come from the latest checkpoint under the directory (restored
     through checkpoint.py into replicated shardings); with no

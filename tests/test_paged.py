@@ -443,3 +443,46 @@ def test_a_states_ring_keeps_what_the_tick_after_a_verify_row_reads(
     live = set(range(L + 1 - state, L + tick_cols))
     assert short < 1 or len({p % short for p in live}) < len(live)
 
+
+
+# ------------------------------------ a state that folds its past: a carry
+def test_the_carrys_read_and_its_snapshots_on_a_tick():
+    """paged.carry_read / state_index / write by hand, on a carry with a
+    shape behind its columns: a tick reads the ONE column of each slot's
+    last position as it was before the tick (zero for a slot that holds
+    nothing), and the carries after its rows land at ``[s, P % cols]``, the
+    last ``cols`` of a long chunk only, nothing of a slot that runs no
+    row."""
+    cols, S, C = 4, 4, 6
+    lengths = jnp.asarray([0, 5, 9, 0], jnp.int32)
+    n_new = jnp.asarray([6, 2, 0, 0], jnp.int32)
+    # pool[l, s, c] = 100 s + the position whose carry the column holds, on
+    # a [2, 3] shape behind; slot 3 holds a stream that left
+    held = np.zeros((2, S, cols, 2, 3), np.float32)
+    for s, L in enumerate([0, 5, 9, 7]):
+        for p in range(max(0, L - cols), L):
+            held[1, s, p % cols] = 100 * s + p
+    pool = jnp.asarray(held)
+    got = paged.carry_read(pool, 1, lengths)
+    assert got.shape == (S, 2, 3)
+    assert got[:, 0, 0].tolist() == [0.0, 104.0, 208.0, 0.0]
+    assert bool((got == got[:, :1, :1]).all())
+    # the carries after the tick's rows: 1000 + 10 s + column, time-major as
+    # a scan hands them out
+    positions, valid = paged.slot_positions(lengths, n_new, C)
+    slot, col = paged.state_index(lengths, n_new, valid, positions, cols)
+    after = jnp.broadcast_to(
+        (1000.0 + 10 * jnp.arange(S)[None, :] + jnp.arange(C)[:, None]
+         )[..., None, None], (C, S, 2, 3))
+    out = np.asarray(paged.write({"h": pool}, 1, slot.T, col.T,
+                                 {"h": after})["h"])
+    assert (out[0] == held[0]).all()                     # the other layer
+    # slot 0 keeps the carries after its positions 2..5 (rows 2..5), slot 1
+    # those after 5 and 6 beside the two it held, slots 2 and 3 what they had
+    assert out[1, 0, :, 0, 0].tolist() == [1004, 1005, 1002, 1003]
+    assert out[1, 1, :, 0, 0].tolist() == [104, 1010, 1011, 103]
+    assert (out[1, 2:] == held[1, 2:]).all()
+    # the tick after reads what the last ACCEPTED row left: of slot 1's two
+    # rows one accepted -> length 6 -> the carry after position 5
+    nxt = paged.carry_read(jnp.asarray(out), 1, jnp.asarray([6, 6, 9, 0]))
+    assert nxt[:, 0, 0].tolist() == [1005.0, 1010.0, 208.0, 0.0]

@@ -1,10 +1,11 @@
 """The seam between the benchmark's harness and a model family
-(perfbench/families/__init__.py), in tier 1, for the six families there
+(perfbench/families/__init__.py), in tier 1, for the seven families there
 are: the dense GQA decoder, the latent-attention expert decoder, the
 window-and-global expert decoder (two kinds of cache), the
 short-convolution-and-attention expert decoder (a paged kind and a fixed
 state a slot, a tied head), the expert decoder that denoises blocks (its own
-served-path check), and the switch family that only the benchmark's tests
+served-path check), the decoder-hybrid-decoder (four kinds of cache, a scan's
+carry among them), and the switch family that only the benchmark's tests
 use.  At toy width on the CPU:
 a family's leaf names spell the program's pytree, its program agrees with its
 plain reference, its counts are the pytree's sizes, and only the family with
@@ -26,7 +27,7 @@ if _TESTS_FAMILIES not in spec.FAMILY_DIRS:
     spec.FAMILY_DIRS.append(_TESTS_FAMILIES)
 
 FAMILIES = ["dense_gqa", "moe_switch", "latent_moe", "swa_moe", "conv_moe",
-            "blockdiff_moe"]
+            "blockdiff_moe", "sambay"]
 ROUTED_BY_TOKENS = {"latent_moe", "swa_moe", "conv_moe", "blockdiff_moe"}
 SEED = 2**31 + 27
 
@@ -38,7 +39,8 @@ def _toy(family):
             "latent_moe": "serve-moe-mla-decode",
             "swa_moe": "serve-moe-swa-longdoc",
             "conv_moe": "serve-moe-conv-chat",
-            "blockdiff_moe": "serve-moe-blockdiff-gen"}[family]
+            "blockdiff_moe": "serve-moe-blockdiff-gen",
+            "sambay": "serve-ssm-yoco-reason"}[family]
     return spec.tiny(spec.cell(cell)[1])
 
 
@@ -173,7 +175,22 @@ def test_the_counts_are_the_pytrees_sizes_and_the_programs_cache(family):
     pool, _ = _cache(model, cfg, blocks, size, dtype=jnp.bfloat16)
     held = sum(x.size * x.dtype.itemsize
                for x in jax.tree_util.tree_leaves(pool))
-    if hasattr(fam, "state_bytes_per_slot"):
+    if family == "sambay":
+        # one full layer and the window layers hold a position each; the
+        # state-space layers two fixed states a slot, the carries in float32
+        # whatever the pool's type; what a new token READS of a position is
+        # the full layer once for itself and once a cross layer, and a
+        # window layer's share
+        state = fam.state_bytes_per_slot(config, STATE_COLS, 2)
+        assert state == {
+            "conv": 3 * STATE_COLS * 2 * config["hidden_size"] * 2,
+            "carry": 3 * STATE_COLS * 16 * 2 * config["hidden_size"] * 4}
+        a_layer = fam.cache_bytes_per_position_per_layer(config, 2)
+        assert a_layer * (1 + 2) * blocks * size + sum(state.values()) == held
+        assert fam.cache_bytes_per_position(config, 2) == pytest.approx(
+            a_layer * (2 + 2 * config["sliding_window"]
+                       / fam.MEAN_LIVE_CONTEXT))
+    elif hasattr(fam, "state_bytes_per_slot"):
         # a paged kind, which alone a new token reads a position of, and a
         # fixed state a slot
         state = fam.state_bytes_per_slot(config, STATE_COLS, 2)
@@ -514,6 +531,248 @@ def test_the_conv_readers_read_a_trace_and_the_states_counters():
         assert spec.metric_reader(name)(other) is None, name
 
 
+# --------------------------------------- the decoder-hybrid-decoder family
+NEW_CELL, NEW_CONFIG = "serve-ssm-yoco-reason", "phi-4-mini-flash-reasoning"
+NEW_METRICS = ("ssm.scan_share_of_tick.serve", "ssm.state_ops_ms.serve",
+               "yoco.shared_kv_ops_ms.serve")
+
+
+def test_the_benchmark_holds_the_new_configuration_and_its_cell():
+    """What PR 43 lacked: ``BENCHMARK.json`` itself has the configuration,
+    the cell on one chip, the cell in both serving end-to-end lists and in
+    the per-layer lists it reports, and the three new per-layer entries,
+    each with a reader file."""
+    bench = spec.benchmark()
+    conf = next(c for c in bench["configs"] if c["name"] == NEW_CONFIG)
+    assert conf["file"] == f"perfbench/configs/{NEW_CONFIG}.json"
+    assert conf["reduced"] == [] and conf["source"].startswith(
+        "https://huggingface.co/microsoft/Phi-4-mini-flash-reasoning/")
+    cell = next(w for w in bench["workloads"] if w["name"] == NEW_CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        NEW_CONFIG, "reason-decode", 1) and len(cell["why"]) <= 200
+    e2e, layer = spec.cell_metrics(NEW_CELL, bench)
+    assert {m["name"] for m in e2e} == {"ttft_p50_ms",
+                                        "serve_out_tokens_per_s", "setup_s"}
+    for name in ("ttft_p50_ms", "serve_out_tokens_per_s"):
+        listed = next(m for m in bench["end_to_end"] if m["name"] == name)
+        assert NEW_CELL in listed["workloads"]
+    mine = {m["name"]: m for m in layer}
+    for name in NEW_METRICS:
+        assert NEW_CELL in mine[name]["workloads"], name
+        assert mine[name]["moves"] == "serve_out_tokens_per_s"
+        assert mine[name]["source"] == "device_trace"
+        assert callable(spec.metric_reader(name))
+    # (``model_step.required_roofline_share.serve`` is owed: PERF.md §7)
+    for name in ("kv.state_resident_share.serve",
+                 "kv.window_resident_share.serve", "device.idle_share.serve",
+                 "engine.tick_ms.serve", "engine.ahead_share.serve",
+                 "engine.head_rows_share.serve",
+                 "swa.window_pool_ops_ms.serve"):
+        assert name in mine, name
+    # every per-layer metric that lists all the serving cells lists this one
+    serving = {w["name"] for w in bench["workloads"]
+               if w["name"].startswith("serve-")}
+    for m in bench["per_layer"]:
+        if {"serve-decode", "serve-moe-blockdiff-gen"} <= set(m["workloads"]) \
+                and len(m["workloads"]) >= 5:
+            assert set(m["workloads"]) == serving, m["name"]
+
+
+def test_the_sambay_configuration_is_the_issues_arithmetic():
+    """ISSUE 44's reckoning, held to the configuration file: 3,852,457,984
+    parameters by kind of layer (7.705 GB in bfloat16), nothing cut, every
+    catalog key, 5,120 B a cached position an attention layer, the four
+    pools' bytes (the carries at the program's 6 columns, not the issue's
+    8: paged.state_columns(1, 5)), and the traffic as the issue gives it."""
+    entry, config, traffic = spec.cell(NEW_CELL)
+    fam = spec.family(config)
+    assert config["reduced"] == {} and config["family"] == "sambay"
+    catalog = {"embd_pdrop": 0, "hidden_act": "silu", "hidden_size": 2560,
+               "intermediate_size": 10240, "layer_norm_eps": 1e-05,
+               "max_position_embeddings": 262144, "mb_per_layer": 2,
+               "model_type": "phi4flash", "num_attention_heads": 40,
+               "num_hidden_layers": 32, "num_key_value_heads": 20,
+               "resid_pdrop": 0, "sliding_window": 512,
+               "tie_word_embeddings": True, "mlp_bias": False,
+               "lm_head_bias": False, "vocab_size": 200064}
+    assert {k: config[k] for k in catalog} == catalog
+    assert config["assumed"]["sizes"] == {"d_state": 16, "d_conv": 4,
+                                          "expand": 2, "dt_rank": 160}
+    n = fam.dims(config)
+    assert (n["di"], n["N"], n["K"], n["R"], n["hd"]) == (5120, 16, 4, 160, 64)
+    kinds = [fam.layer_kind(config, i) for i in range(32)]
+    assert kinds[:18] == ["mamba", "swa"] * 8 + ["mamba", "full"]
+    assert kinds[18:] == ["gmu", "cross"] * 7
+    assert fam.layer_kinds(config)[16:20] == ["mamba+m", ("full", 17), "gmu",
+                                              ("cross", 19)]
+    by_kind = fam.params_by_kind(config)
+    mixer = {k: v - 3 * 2560 * 10240 - 4 * 2560 for k, v in by_kind.items()}
+    assert mixer == {"mamba": 41_241_600, "swa": 19_661_184,
+                     "full": 19_661_184, "cross": 13_107_584,
+                     "gmu": 26_214_400}
+    dep, e = config["deployment"], config["engine"]
+    assert by_kind == dep["parameters_by_kind_of_layer"] == {
+        "mamba": 119_895_040, "swa": 98_314_624, "full": 98_314_624,
+        "cross": 91_761_024, "gmu": 104_867_840}
+    total = fam.param_counts(config)["total"]
+    assert total == dep["parameters"] == 3_852_457_984 == (
+        9 * 119_895_040 + 9 * 98_314_624 + 7 * 91_761_024 + 7 * 104_867_840
+        + 512_163_840 + 5_120)
+    assert total == sum(math.prod(s) for _, s, _ in fam.leaf_specs(config))
+    assert dep["weight_bytes"] == 2 * total and round(2 * total / 1e9, 3) \
+        == 7.705
+    assert fam.cache_bytes_per_position_per_layer(config, 2) \
+        == dep["cache_bytes_per_position_per_layer"] == 5120
+    from horovod_tpu.models import paged
+    cols = fam.state_columns(config)
+    assert cols == {"conv": paged.state_columns(3, 5),
+                    "carry": paged.state_columns(1, 5)} == {"conv": 8,
+                                                             "carry": 6}
+    ring = e["block_size"] * paged.ring_blocks(
+        512, e["prefill_chunk"], e["block_size"],
+        -(-e["max_seq_len"] // e["block_size"]))
+    assert ring == fam.ring_positions(config) == 768
+    assert e["cache_blocks"] * e["block_size"] == 32 * 2560 \
+        == e["max_slots"] * e["max_seq_len"]
+    assert dep["kv_pool_bytes"] == 32 * 2560 * 5120
+    assert dep["window_pool_bytes"] == 8 * 32 * 768 * 5120
+    assert dep["conv_state_bytes"] == 9 * 32 * 8 * 10_240 == 32 * \
+        fam.state_bytes_per_slot(config, 8, 2)["conv"]
+    assert dep["carry_state_bytes"] == 9 * 32 * 6 * 327_680 == 32 * \
+        fam.state_bytes_per_slot(config, 6, 2)["carry"]
+    assert dep["resident_bytes"] == sum(dep[k] for k in (
+        "weight_bytes", "kv_pool_bytes", "window_pool_bytes",
+        "conv_state_bytes", "carry_state_bytes")) >= 0.25 * 16e9
+    assert (e["max_slots"], e["prefill_chunk"], e["max_batch_tokens"],
+            e["block_size"], e["max_seq_len"], e["prefix_cache"]) == (
+        32, 256, 384, 16, 2560, False)
+    # (the pool is the issue's, prompt 1,024 + answer 1,536; the answers are
+    # cut below, the engine is not)
+    assert e["max_seq_len"] == traffic["prompt_len"]["max"] + 1536 \
+        >= traffic["prompt_len"]["max"] + traffic["output_len"]["max"]
+    # a tick reads every matrix once; a new token reads the one full layer
+    # eight times and the eight rings' share of a position
+    assert fam.tick_weight_bytes(config, 1, 2) == fam.tick_weight_bytes(
+        config, 384, 2) == 2 * fam.param_counts(config)["matmul"]
+    assert fam.cache_bytes_per_position(config, 2) == pytest.approx(
+        5120 * (8 + 8 * 512 / fam.MEAN_LIVE_CONTEXT))
+    assert fam.attn_flops_per_position(config) == pytest.approx(
+        2 * 40 * (64 + 128) * (8 + 8 * 512 / fam.MEAN_LIVE_CONTEXT))
+    assert fam.state_op_types(config) == [
+        "[9,32,6,16,5120]", "[32,6,16,5120]", "[9,32,8,5120]", "[32,8,5120]",
+        "[5,32,16,5120]", "[384,1,16,5120]"]
+    # the rings' pool, and the 34 or 48 entries of a ring that a verify row's
+    # or a chunk's windows can reach
+    assert fam.pool_op_types(config, "global") == []
+    assert fam.pool_op_types(config, "window") == [
+        "[8,1536,16,1280]",
+        ",34,16,1280]", "[34,16,1280]", "[1088,16,1280]", ",544,1280]",
+        ",544,10,128]",
+        ",48,16,1280]", "[48,16,1280]", "[1536,16,1280]", ",768,1280]",
+        ",768,10,128]"]
+    # the traffic, as ISSUE 44 gives it
+    assert traffic["prompt_len"] == {"median": 192, "sigma": 0.6, "min": 32,
+                                     "max": 1024}
+    # (the longest answer is what 26 s of ticks give, ISSUE 44's rule for a
+    # tick over 17 ms: 1,536 as given, 1,088 at the measured 23.5 ms)
+    assert traffic["output_len"] == {"median": 768, "sigma": 0.4, "min": 256,
+                                     "max": 1088}
+    assert "shared_prefix" not in traffic and "sessions" not in traffic
+    knee = traffic["arrivals"]["knee"]["rate_per_s"]
+    assert traffic["arrivals"]["rate_per_s"] == pytest.approx(0.8 * knee)
+
+
+def test_the_parent_process_loads_the_sambay_family_without_jax():
+    code = ("import sys; from perfbench.lib import peaks, spec\n"
+            "_, c, _ = spec.cell('serve-ssm-yoco-reason'); f = spec.family(c)\n"
+            "spec.tiny(c); f.param_counts(c); f.state_op_types(c)\n"
+            "f.leaf_specs(c); f.layer_kinds(c)\n"
+            "m = {'trace': None, 'config': c,\n"
+            "     'marks': {k: {'stats': {}, 'tick': 0} for k in ('start', 'end')}}\n"
+            "assert f.state_counts(m) is None and f.ring_counts(m) is None\n"
+            "assert f.scan_share(m) is None and f.state_ops_ms(m) is None\n"
+            "assert f.shared_kv_ops_ms(m) is None\n"
+            "assert f.pool_ops_ms(m, 'window') is None\n"
+            "peaks.serve_required_seconds(c, peaks.PEAKS['TPU v5 lite'], 9, 9, 1)\n"
+            "assert 'jax' not in sys.modules and 'numpy' not in sys.modules\n"
+            # the one function that asks the program (its tile's positions),
+            # when a traced run's children are gone: it starts no backend
+            "types = f.shared_kv_op_types(c)\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge._backends, 'a backend was started'\n"
+            "print(types)")
+    out = subprocess.run([sys.executable, "-c", code], check=True,
+                         cwd=spec.ROOT, timeout=60, capture_output=True,
+                         text=True)
+    from horovod_tpu.models import paged
+    assert paged.TILE == 256 and out.stdout.strip() == str(
+        [",16,16,1280]", ",256,1280]", "[32,16,1280]", "[16,16,1280]",
+         "[1,5120,16,1280]", "[5120,16,1280]"])
+
+
+def test_the_sambay_readers_read_a_trace_and_the_kinds_counters():
+    """The three readers the cell brings and those it joins, on a made-up
+    trace and marks: the scans' share (the carries' pool and the buffer the
+    snapshots are taken from apart), the state kinds' ops, the shared
+    layer's fetches, the rings' ops, the two resident shares, the head's
+    rows; each None where there is nothing to read, on another family's
+    configuration too."""
+    _, config, _ = spec.cell(NEW_CELL)
+    conv = {"slot_ticks": 160, "state_bytes_ticks": 160 * 737_280,
+            "kv_bytes_ticks": 160 * 700 * 9 * 5120}
+    carry = dict(conv, state_bytes_ticks=160 * 17_694_720)
+    ring = {"slot_ticks": 160, "resident_position_ticks": 160 * 600,
+            "window_position_ticks": 160 * 500,
+            "full_position_ticks": 160 * 700}
+    loop = {"head_rows": 160 * 3, "packed_rows": 384 * 3}
+    mark = lambda t, zero: {"tick": t, "stats": {
+        "loop": dict.fromkeys(loop, 0) if zero else loop,
+        "kv_pool": {"kinds": {
+            name: dict.fromkeys(d, 0) if zero else d for name, d in
+            (("conv", conv), ("carry", carry), ("window", ring))}}}}
+    ops = {"fusion f32[32,16,5120]": 0.004,
+           "broadcast_select_fusion f32[32,16,5120]": 0.002,
+           "copy-done f32[5,32,16,5120]": 0.0021,
+           "fusion f32[384,1,16,5120]": 0.0007,
+           "gather f32[32,16,5120]": 0.0003,
+           "scatter f32[9,32,6,16,5120]": 0.0007,
+           "scatter bf16[9,32,8,5120]": 0.0002,
+           "fusion bf16[32,5,5120]": 0.05,
+           "fusion bf16[32,16,1280]": 0.0011,
+           "fusion bf16[2,256,1280]": 0.0004,
+           "scatter bf16[1,5120,16,1280]": 0.0001,
+           "fusion bf16[1088,16,1280]": 0.006,
+           "reshape bf16[32,544,10,128]": 0.002,
+           "scatter bf16[8,1536,16,1280]": 0.001,
+           "gather bf16[1,48,16,1280]": 0.0005, "while s32[]": 0.001}
+    ctx = {"config": config, "peaks": {"hbm_gbps": 819.0, "bf16_tflops": 197.0},
+           "marks": {"start": mark(0, True), "end": mark(10, False)},
+           "trace": {"module_count": 5.0, "module_s": 0.09, "ops_s": ops}}
+    read = lambda name: spec.metric_reader(name)(ctx)
+    assert read("ssm.scan_share_of_tick.serve") == pytest.approx(
+        100 * 0.006 / 0.09)
+    assert read("ssm.state_ops_ms.serve") == pytest.approx(1e3 * 0.004 / 5)
+    assert read("yoco.shared_kv_ops_ms.serve") == pytest.approx(
+        1e3 * 0.0016 / 5)
+    assert read("kv.state_resident_share.serve") == pytest.approx(
+        100 * (737_280 + 17_694_720) / (700 * 9 * 5120))
+    assert read("kv.window_resident_share.serve") == pytest.approx(
+        100 * 600 / 700)
+    assert read("swa.window_pool_ops_ms.serve") == pytest.approx(
+        1e3 * 0.0095 / 5)
+    assert read("engine.head_rows_share.serve") == pytest.approx(
+        100 * 160 / 384)
+    bare = dict(ctx, trace=None, marks={k: {"tick": 0, "stats": {}}
+                                        for k in ("start", "end")})
+    other = dict(ctx, config=spec.cell("serve-decode")[1])
+    for name in NEW_METRICS + ("kv.state_resident_share.serve",
+                               "kv.window_resident_share.serve",
+                               "swa.window_pool_ops_ms.serve"):
+        assert spec.metric_reader(name)(bare) is None, name
+        assert spec.metric_reader(name)(other) is None, name
+    assert spec.metric_reader("engine.head_rows_share.serve")(bare) is None
+
+
 # ------------------------------------------- the block-denoising family
 def test_the_blockdiff_cut_is_the_issues_arithmetic():
     """ISSUE 40: a layer of 623,120,640 parameters, 4,984,176,384 in seven
@@ -815,6 +1074,9 @@ def test_the_sample_is_the_golden_one():
                               "engine.tick_ms.serve")),
     ("serve-moe-swa-longdoc", ("kv.window_resident_share.serve",
                                "moe.experts_touched_per_layer.serve",
+                               "engine.tick_ms.serve")),
+    ("serve-ssm-yoco-reason", ("kv.state_resident_share.serve",
+                               "kv.window_resident_share.serve",
                                "engine.tick_ms.serve"))])
 def test_the_new_cells_rehearsal_passes(cell, metrics):
     out = subprocess.run(

@@ -1,5 +1,5 @@
 """The engine that launches tick N+1 before it fences tick N
-(horovod_tpu/serve/engine.py; docs/serving.md#the-loops-order) on the five
+(horovod_tpu/serve/engine.py; docs/serving.md#the-loops-order) on six
 model families at toy sizes: the plain greedy reference's tokens, the
 speculation counts of the fenced order, ends of stream, slots reused, a
 copy-on-write admitted while a tick is in flight, the hand-off.
@@ -18,11 +18,13 @@ from horovod_tpu.serve.engine import DECODE, ROW, ServeEngine, samples_read
 
 from test_serve_chain import _mesh, _record_ticks, _same_tree, stripped
 
-FAMILIES = ["llama", "moe_llama", "latent_moe", "swa_moe", "conv_moe"]
+FAMILIES = ["llama", "moe_llama", "latent_moe", "swa_moe", "conv_moe",
+            "sambay"]
 SHARING = FAMILIES[:3]      # whole contexts only: prefix cache and hand-off
+READS = SHARING + ["sambay"]    # ``greedy_cached`` takes ``read``
 
 
-# ------------------------------------------------------ the five families
+# ------------------------------------------------------- the six families
 def _load(name):
     model = importlib.import_module("horovod_tpu.models." + name)
     cfg = model.CONFIGS["tiny"]
@@ -113,8 +115,8 @@ def test_launch_ahead_serves_the_plain_greedy_references_tokens(family):
 
 
 def test_the_slab_branch_serves_the_same_streams_counts_and_ends(family):
-    """The module as it samples in the tick — llama, moe_llama and
-    latent_moe on the rows of the columns the tick reads (``greedy_cached(..,
+    """The module as it samples in the tick — llama, moe_llama, latent_moe
+    and sambay on the rows of the columns the tick reads (``greedy_cached(..,
     read)``), swa_moe and conv_moe on every packed row — against the same
     module without ``greedy_cached``, through the tick's slab branch (the
     argmax of ``apply_cached``'s ``[slots, chunk, vocab]`` logits): with
@@ -142,7 +144,7 @@ def test_the_slab_branch_serves_the_same_streams_counts_and_ends(family):
         slab_loop["by_width"]["narrow"]["ticks"] > 0
     assert loop["packed_rows"] == slab_loop["packed_rows"] == 24 * wide
     assert slab_loop["head_rows"] == 24 * wide
-    assert samples_read(model) == (name in SHARING)
+    assert samples_read(model) == (name in READS)
     assert loop["head_rows"] == (12 if samples_read(model) else 24) * wide
     # ... and where every draft is known (``_flat``: zeros), the slab branch
     # drafts and accepts what the module's form does

@@ -100,7 +100,8 @@ _NOT_ROW_MAJOR = {"serve-moe-conv-chat": {"conv/u": (0, 2, 1, 3)}}
 @pytest.mark.parametrize("cell", ["serve-decode", "serve-moe-mla-decode",
                                   "serve-moe-swa-longdoc",
                                   "serve-moe-conv-chat",
-                                  "serve-moe-blockdiff-gen"])
+                                  "serve-moe-blockdiff-gen",
+                                  "serve-ssm-yoco-reason"])
 def test_no_tick_relays_a_pool_on_its_way_in_or_out(one_chip, cell, width):
     """Every serving cell's two programs take each pool as the device's
     default layout for its SHAPE has it — a module's ``init_cache`` decides
@@ -114,8 +115,11 @@ def test_no_tick_relays_a_pool_on_its_way_in_or_out(one_chip, cell, width):
     texts, widths, pools, layouts = _tick_programs(cell, one_chip)
     ops = re.findall(_OPS, texts[widths[width == "wide"]])
     for leaf, pool in pools.items():
-        assert (pool, "scatter") in ops, leaf
-        assert not [op for op in ops if op[0] == pool
+        # (a pool of ONE layer is scattered into as that layer: the leading
+        # 1 goes in a bitcast)
+        forms = (pool, "[" + pool[3:]) if pool.startswith("[1,") else (pool,)
+        assert [f for f in forms if (f, "scatter") in ops], leaf
+        assert not [op for op in ops if op[0] in forms
                     and op[1] in ("copy", "concatenate")], leaf
     row_major = {leaf: tuple(range(pool.count(",") + 1))
                  for leaf, pool in pools.items()}
@@ -126,7 +130,8 @@ def test_no_tick_relays_a_pool_on_its_way_in_or_out(one_chip, cell, width):
 @pytest.mark.parametrize("cell", ["serve-decode", "serve-moe-mla-decode",
                                   "serve-moe-swa-longdoc",
                                   "serve-moe-conv-chat",
-                                  "serve-moe-blockdiff-gen"])
+                                  "serve-moe-blockdiff-gen",
+                                  "serve-ssm-yoco-reason"])
 def test_the_token_history_is_handed_on_in_place(one_chip, cell, width):
     """The decode chain's state is the tick program's own: the token history
     ``[slots, max_seq_len]`` int32 comes from the tick before donated, is
@@ -150,10 +155,12 @@ def test_the_token_history_is_handed_on_in_place(one_chip, cell, width):
         len(pools) + (5 if cell == "serve-moe-blockdiff-gen" else 3)
 
 
-@pytest.mark.parametrize("cell", ["serve-decode", "serve-moe-mla-decode"])
+@pytest.mark.parametrize("cell", ["serve-decode", "serve-moe-mla-decode",
+                                  "serve-ssm-yoco-reason"])
 def test_the_wide_tick_runs_its_head_on_the_rows_it_reads(one_chip, cell):
     """The chunk-wide program of a module that samples where the tick reads
-    (``greedy_cached(.., read)``: models/llama.py, models/latent_moe.py):
+    (``greedy_cached(.., read)``: models/llama.py, models/latent_moe.py,
+    models/sambay.py, whose head is its embedding):
     beside the vocabulary no array but the head's own matrix holds more rows
     than the ``slots x (1 + spec_k)`` whose token the tick reads — no
     ``[16,128,92544]`` slab and no ``[512,92544]`` logits of every packed
@@ -201,6 +208,37 @@ def test_the_conv_tick_keeps_its_pools_where_they_lie(one_chip, C):
             5: ("[32,5,65536]", "[160,65536]")}[C]
     logits = {op[0] for op in ops if op[0].endswith(",65536]")}
     assert logits and logits <= set(rows)
+
+
+@pytest.mark.parametrize("C", [256, 5])
+def test_the_scan_tick_keeps_its_four_pools_where_they_lie(one_chip, C):
+    """``serve-ssm-yoco-reason``'s two programs: the ONE full layer's pool
+    (``[1, 5120, 16, 1280]``), the eight rings, the nine layers' conv inputs
+    and their scan carries (``[9, 32, 6, 16, 5120]`` float32, d_inner minor)
+    are scattered into in place and never copied or relaid whole; the full
+    layer and the seven cross layers read a tile of that one pool inside the
+    shared loop (nothing is shaped like a slot's whole context of 2,560);
+    of a ring a decode row gathers the 34 blocks its windows reach and a
+    chunk all 48 (768 positions a slot whatever it holds: S12's debt, shared
+    with serve-moe-swa-longdoc); and the scan's carry never exists with d_state
+    minor."""
+    import re
+    texts, _, pools, _ = _tick_programs("serve-ssm-yoco-reason", one_chip)
+    ops = re.findall(_OPS, texts[C])
+    pool = pools["kv/k"]
+    assert pool == "[1,5120,16,1280]" and sorted(pools) == [
+        "carry/h", "conv/u", "kv/k", "kv/v", "window/k", "window/v"]
+    # (the one-layer pool is scattered into as its layer, a bitcast away)
+    for whole in ("[5120,16,1280]", "[8,1536,16,1280]", "[9,32,8,5120]",
+                  "[9,32,6,16,5120]"):
+        assert (whole, "scatter") in ops, whole
+        assert not [op for op in ops if op[0] in (whole, pool)
+                    and op[1] in ("copy", "concatenate")], whole
+    assert not [op for op in ops if op[0].endswith(",2560,1280]")]
+    ring = {256: (",768,1280]", "[48,16,1280]"),     # the chunk's one slot
+            5: (",544,1280]", ",34,16,1280]")}[C]
+    assert [op for op in ops if op[0].endswith(ring)]
+    assert not [op for op in ops if op[0].endswith(",5120,16]")]
 
 
 @pytest.mark.parametrize("C", [256, 4])
