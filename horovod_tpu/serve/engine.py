@@ -53,6 +53,7 @@ import numpy as np
 
 from ..models import paged
 from ..utils.profiler import PhaseClock, annotate
+from .commit import SKIPS, SUMS as _COMMIT_SUMS, CommitPoint
 from .config import ServeConfig
 
 
@@ -712,36 +713,10 @@ class Scheduler:
         (its last ``p % B`` tokens are known positions of the first block
         row), and a stream's end is the device's to find: its rows are
         planned until a fence has finished it."""
-        budget = self.cfg.max_batch_tokens
+        self._drain_imports()
         chunk = self.cfg.prefill_chunk
         unit = self.block or 1      # what a row's columns are a multiple of
-        work: List[Tuple[int, Request, int]] = []
-        self._drain_imports()
-        for i, req in enumerate(self.slots):
-            if req is not None and req.state == "decode" and budget >= unit:
-                if not self.block and len(req.out_tokens) + req.unfenced \
-                        >= req.max_new_tokens:
-                    continue    # the unfenced tick ends it: nothing to run
-                # A decode row is charged the columns it may fill: the
-                # device drafts (``tick_program``) and the plan
-                # does not wait to learn how much.  Its width is capped
-                # by the tick budget (each column costs 1) and the verify
-                # row (bonus token + K drafts); the device caps the draft
-                # by the remaining generation (a draft past max_new could
-                # be verified at positions the reservation never covered).
-                n = unit
-                if self.cfg.spec_decode:
-                    n += min(self.cfg.spec_k, budget - 1,
-                             self.cfg.prefill_chunk - 1)
-                work.append((i, req, n))
-                budget -= n
-        budget -= budget % unit
-        for i, req in enumerate(self.slots):
-            if req is not None and req.state == "prefill" and budget >= unit:
-                n = min(chunk, self.prefill_end(req) - req.pos, budget)
-                if n >= 1:
-                    work.append((i, req, n))
-                    budget -= n
+        work, budget = self._running_rows()
         while self.waiting and budget >= unit and self.role != "decode":
             free_slots = [i for i, s in enumerate(self.slots) if s is None]
             if not free_slots:
@@ -771,6 +746,51 @@ class Scheduler:
             work.append((slot, req, n))
             budget -= n
         return work
+
+    def _running_rows(self) -> Tuple[List[Tuple[int, Request, int]], int]:
+        """The rows of the streams that hold a slot — decode rows, then
+        prefill continuations — and what they leave of the tick's token
+        budget.  Changes nothing."""
+        budget = self.cfg.max_batch_tokens
+        chunk = self.cfg.prefill_chunk
+        unit = self.block or 1
+        work: List[Tuple[int, Request, int]] = []
+        for i, req in enumerate(self.slots):
+            if req is not None and req.state == "decode" and budget >= unit:
+                if not self.block and len(req.out_tokens) + req.unfenced \
+                        >= req.max_new_tokens:
+                    continue    # the unfenced tick ends it: nothing to run
+                # A decode row is charged the columns it may fill: the
+                # device drafts (``tick_program``) and the plan
+                # does not wait to learn how much.  Its width is capped
+                # by the tick budget (each column costs 1) and the verify
+                # row (bonus token + K drafts); the device caps the draft
+                # by the remaining generation (a draft past max_new could
+                # be verified at positions the reservation never covered).
+                n = unit
+                if self.cfg.spec_decode:
+                    n += min(self.cfg.spec_k, budget - 1,
+                             self.cfg.prefill_chunk - 1)
+                work.append((i, req, n))
+                budget -= n
+        budget -= budget % unit
+        for i, req in enumerate(self.slots):
+            if req is not None and req.state == "prefill" and budget >= unit:
+                n = min(chunk, self.prefill_end(req) - req.pos, budget)
+                if n >= 1:
+                    work.append((i, req, n))
+                    budget -= n
+        return work, budget
+
+    def room(self) -> bool:
+        """Whether a request that arrives now could still get a row of the
+        next tick: nothing waits in front of it, a slot is free and the
+        streams that hold one leave some of the budget (ServeEngine.
+        commit_due; a ``decode`` role's arrivals are hand-offs, which need
+        the same)."""
+        return not self.waiting and not self.import_queue and \
+            None in self.slots and \
+            self._running_rows()[1] >= (self.block or 1)
 
     def prefill_end(self, req: Request) -> int:
         """The prompt tokens a request's prefill consumes: all of them, or
@@ -1357,7 +1377,7 @@ _TURN_SUMS = tuple("turn_" + part for part in TURNAROUND_PARTS)
 _LOOP_SUMS = ("fence_ready_s", "fence_copy_s", "narrow", "narrow_wait_s",
               "wide", "wide_wait_s", "used", "turnaround_s", "turnaround_n",
               "after_idle_n", "iteration_s", "ahead_n",
-              "ahead_idle_rows") + _TURN_SUMS
+              "ahead_idle_rows") + _TURN_SUMS + _COMMIT_SUMS
 
 
 def _loop_figures(sums: Dict[str, float]) -> Dict[str, Any]:
@@ -1378,6 +1398,11 @@ def _loop_figures(sums: Dict[str, float]) -> Dict[str, Any]:
         "after_idle_n": int(sums["after_idle_n"]),
         "ahead_n": int(sums["ahead_n"]),
         "ahead_idle_rows": int(sums["ahead_idle_rows"]),
+        "hold_n": int(sums["hold_n"]),
+        "hold_s": sums["hold_s"],
+        "hold_skipped_n": {why: int(sums["hold_skip_" + why])
+                           for why in SKIPS},
+        "late_n": int(sums["late_n"]),
         "iteration_s": sums["iteration_s"],
         "by_width": by_width,
         "narrow_ticks": by_width["narrow"]["ticks"],
@@ -1489,6 +1514,8 @@ class ServeEngine:
         self._fenced: Optional[Tuple[float, Any, Any]] = None
         self._launch_t = 0.0
         self._launched = False      # this step() launched a tick
+        # When the loop should commit the next tick's plan (commit_due).
+        self._commit = CommitPoint(self.clock.add)
         # The decode chain's state, the tick program's own (``tick_program``):
         # every slot's token history, its length, whether its stream has
         # ended.  A tick takes them from the tick before and returns them
@@ -1734,7 +1761,10 @@ class ServeEngine:
         """Run one engine tick: launch the next program, THEN fence the one
         before it.  Returns the fenced tick's report (one tick of pipeline
         lag): {"tick", "processed", "emitted": {req_id: [new tokens]},
-        "finished": [Request]} — an idle report when none was fenced."""
+        "finished": [Request]} — an idle report when none was fenced.
+        It never waits before it launches: holding the launch until the
+        tick in flight is about to end is the serving loop's
+        (:meth:`commit_due`; serve/worker.py ``FleetFrontend._hold``)."""
         self.clock.second()     # the open bucket is this step's second
         self._dispatch()
         report = self._harvest()
@@ -1757,6 +1787,22 @@ class ServeEngine:
             out.append(self.step())
         return out
 
+    def _width(self, C: int) -> str:
+        """Which of the two executables a tick of ``C`` columns ran."""
+        return "narrow" if C < self.cfg.prefill_chunk else "wide"
+
+    def commit_due(self) -> Optional[float]:
+        """For the loop that drives ``step()``, asked once an iteration
+        after it has published the fenced tick's tokens: the
+        ``perf_counter`` instant until which to hold before it polls,
+        submits and calls ``step()`` — the tick in flight is then about to
+        end, and the plan the launch fixes is a few milliseconds old
+        instead of a whole tick (serve/commit.py;
+        docs/serving.md#the-loops-order).  None: do not hold
+        (``stats()["loop"]["hold_skipped_n"]`` says why)."""
+        behind = self._width(self._inflight[0][1]) if self._inflight else None
+        return self._commit.due(behind, self.scheduler.room)
+
     def _dispatch(self) -> None:
         """Plan, stage and launch the next tick, whether or not the one
         before has been fenced: what a decode row needs of it (its last
@@ -1771,7 +1817,8 @@ class ServeEngine:
             return
         cfg = self.cfg
         with self.clock.span("stage") as stage:
-            if not self._steps:
+            built = not self._steps
+            if built:
                 self._compile_steps()
             S, C = cfg.max_slots, tick_width(cfg, work, self._block)
             tokens = np.zeros((S, C), np.int32)
@@ -1825,6 +1872,11 @@ class ServeEngine:
             self.cache, *self._chain, report, counters = self._steps[C](
                 self.params, self.cache, *self._chain, *dev)
         self._count_gap(plan, stage, launch)
+        behind = self._inflight[-1] if self._inflight else None
+        self._commit.launched(
+            launch.t1, behind and self._width(behind[1]),
+            late=behind is not None and behind[3].is_ready(),
+            timed=not built)
         self._inflight.append((self.tick, C, launched, report, counters))
         self._launched = True
         self.tick += 1
@@ -1936,6 +1988,7 @@ class ServeEngine:
         # (or has not started), then it is done while the host fetches the
         # report.
         with clock.span("harvest_wait") as wait:
+            exact = not report.is_ready()   # the wait will see it end
             with annotate("hvd:fence_ready"):
                 report.block_until_ready()
             ready = time.perf_counter()
@@ -1945,7 +1998,8 @@ class ServeEngine:
                 if counters is not None:
                     self._counters += np.asarray(counters)
         W = decode_width(self.cfg, self._block)
-        width = "narrow" if C < self.cfg.prefill_chunk else "wide"
+        width = self._width(C)
+        self._commit.fenced(width, ready, exact, ahead=bool(self._inflight))
         clock.add("fence_ready_s", ready - wait.t0)
         clock.add("fence_copy_s", wait.t1 - ready)
         clock.add(width, 1)
@@ -2202,6 +2256,10 @@ class ServeEngine:
             # how much of its life was the host's path between two programs
             "turnaround_s": round(sums["turnaround_s"], 6),
             "fence_copy_s": round(sums["fence_copy_s"], 6),
+            # the loop's holds at the commit point in that life
+            "hold_n": int(sums["hold_n"]),
+            "hold_s": round(sums["hold_s"], 6),
+            "late_n": int(sums["late_n"]),
             "compiles": d["compiles"]}
         req.loop0 = None
 
@@ -2389,6 +2447,7 @@ class ServeEngine:
         sums = snap.pop("sums")
         out["loop"] = dict(snap, **_loop_figures(sums),
                            timeline=self.clock.timeline(),
+                           commit=self._commit.view(),
                            ticks=self._ticks(),
                            wide_rows_share=share(self._wide_rows),
                            head_rows=self._head_rows,

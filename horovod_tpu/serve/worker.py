@@ -552,6 +552,24 @@ class FleetFrontend:
             # one host, skewed by the clocks' difference across hosts.
             req.pickup_s = max(0.0, time.time() - float(r["submitted_t"]))
 
+    def _hold(self, clock) -> None:
+        """The commit point (docs/serving.md#the-loops-order): the tick
+        fenced last is published, the one in flight has most of its time
+        left, and whatever ``step()`` launches now starts only when that
+        one ends.  So wait, for as long as the engine's own measurements
+        allow (``ServeEngine.commit_due``), and poll then: a request that
+        arrives meanwhile is in the next program instead of the one after.
+        Booked as ``idle`` — the loop waiting on purpose, not the host's
+        work — and counted (``hold_n``, ``hold_s``)."""
+        commit_due = getattr(self.engine, "commit_due", None)
+        due = commit_due() if commit_due is not None else None
+        if due is None:
+            return
+        with clock.span("idle") as held:
+            time.sleep(max(0.0, due - time.perf_counter()))
+        clock.add("hold_n", 1)
+        clock.add("hold_s", held.t1 - held.t0)
+
     def run(self, ttl_s: float = 0.0) -> int:
         """Serve until ``ttl_s`` elapses (0 = until interrupted), or a
         drain completes.  Rank 0 paces the fleet; followers block on the
@@ -580,6 +598,11 @@ class FleetFrontend:
                 # must look alive; only a wedged loop/engine freezes it.
                 PM.record_step(self.tick)
                 _chaos.maybe_stall("serve_tick")
+                if not fleet:
+                    # a fleet's program starts when its last rank has
+                    # launched, a plan-stream hop after rank 0, which rank 0
+                    # cannot see: it launches at once, as before
+                    self._hold(clock)
                 with clock.span("poll"):
                     if self.rank == 0:
                         if drain_t is None and kv_backed and \

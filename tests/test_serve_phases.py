@@ -278,7 +278,10 @@ def test_request_phases_sum_to_its_life(served, name):
     total = sum(done["loop"]["phase_s"].values())
     assert abs(total - life) <= SUM_TOL_S + SUM_TOL_SHARE * life, \
         (total, life, done["loop"])
-    assert done["loop"]["phase_s"].get("idle", 0.0) == 0.0
+    # the loop never slept for want of work in a request's life: what it
+    # idled there it held on purpose, at the commit point (worker._hold)
+    assert done["loop"]["phase_s"].get("idle", 0.0) == \
+        pytest.approx(done["loop"]["hold_s"], abs=1e-5)
 
 
 @pytest.mark.parametrize("name", ["cold", "warm", "short"])
