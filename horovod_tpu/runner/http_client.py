@@ -24,12 +24,16 @@ fail alone while every other scope's traffic proceeds.
 
 from __future__ import annotations
 
+import http.client
 import os
+import socket
+import threading
 import time
 import urllib.error
 import urllib.request
 from typing import List, Optional, Tuple
 
+from .http_server import WAITED_HEADER
 from .kvshard import parse_shard_addrs, shard_for_scope
 
 # Explicit override (tests, ShardedKVClient): wins over the env map.
@@ -166,6 +170,84 @@ def get_kv(addr: str, port: int, scope: str, key: str,
             if not _transient(e) or time.time() >= deadline:
                 raise
             time.sleep(poll_interval)
+
+
+class KeyWaiter:
+    """GETs that the server holds (``?wait=SECONDS``; runner/http_server.py
+    ``_serve_waited``), over one connection that is kept from key to key:
+    how rank 0's arrivals reader learns of the next request
+    (serve/arrivals.py).  One thread calls ``wait_kv`` and ``close``;
+    ``interrupt`` may come from another."""
+
+    def __init__(self, addr: str, port: int, scope: str):
+        self.scope = scope
+        self._addr, self._port, self._shard = resolve_kv_addr(addr, port,
+                                                              scope)
+        self._conn: Optional[http.client.HTTPConnection] = None
+        # ``interrupt`` against the sending of a request: a wait that began
+        # just after ``interrupt`` had looked for its socket would last
+        self._lock = threading.Lock()
+        self._closed = False
+
+    def wait_kv(self, key: str, wait: float
+                ) -> Tuple[Optional[bytes], bool]:
+        """(the value, or None where the key is still absent; whether the
+        server held the GET).  A server that does not know the parameter
+        answers its 404 at once and the second is False: the caller paces
+        itself.  Raises what ``get_kv`` raises at its deadline; retrying
+        is the caller's (``_kv_op``), and starts on a new connection."""
+        try:
+            _chaos_kv("get", self.scope)
+            with self._lock:
+                if self._closed:
+                    raise ConnectionError("KeyWaiter is closed")
+                conn = self._conn
+                if conn is None:
+                    # the held GET answers within ``wait``; the rest is
+                    # the slack every KV leg has
+                    conn = self._conn = http.client.HTTPConnection(
+                        self._addr, self._port, timeout=wait + 10)
+                conn.request("GET", f"/{self.scope}/{key}?wait={wait}",
+                             headers={"Connection": "keep-alive"})
+            resp = conn.getresponse()
+            body = resp.read()
+        except Exception as e:
+            self.close()
+            if self._closed:
+                return None, True  # interrupted: nothing came, and say so
+            if _transient(e):
+                _count_shard_unavailable(self._shard)
+            if isinstance(e, http.client.HTTPException):
+                # a torn or garbled answer is the connection's fault
+                raise ConnectionError(f"{type(e).__name__}: {e}") from e
+            raise
+        if resp.status == 200:
+            return body, True
+        if resp.status == 404:
+            return None, resp.getheader(WAITED_HEADER) is not None
+        raise urllib.error.HTTPError(
+            f"http://{self._addr}:{self._port}/{self.scope}/{key}",
+            resp.status, resp.reason, resp.headers, None)
+
+    def close(self) -> None:
+        """Drop the connection; a later wait opens a new one.  For the
+        thread that waits, or once none does."""
+        with self._lock:
+            conn, self._conn = self._conn, None
+        if conn is not None:
+            conn.close()
+
+    def interrupt(self) -> None:
+        """From any thread: the wait in progress, and every later one,
+        returns at once with nothing."""
+        with self._lock:
+            self._closed = True
+            sock = self._conn.sock if self._conn is not None else None
+            if sock is not None:
+                try:
+                    sock.shutdown(socket.SHUT_RDWR)
+                except OSError:
+                    pass
 
 
 def delete_kv(addr: str, port: int, scope: str, key: str,

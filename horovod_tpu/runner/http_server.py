@@ -65,6 +65,7 @@ from __future__ import annotations
 
 import itertools
 import json
+import socket
 import sys
 import threading
 import time
@@ -84,10 +85,18 @@ ALERTS_ROUTE = "alerts"
 GENERATE_ROUTE = "generate"
 # serve_out writes wake the router's stream drains (serve/router.py
 # waits on kv_wakeup instead of busy-polling; docs/control-plane.md);
-# serve_kv writes wake the decode sub-fleet's handoff long-polls.
+# serve_kv writes wake the decode sub-fleet's handoff long-polls;
+# serve_req writes wake the GET that rank 0's arrivals reader has
+# waiting on the next request's key (``?wait=``; serve/arrivals.py).
 # Matching is on the base name so per-replica scoped variants
 # (serve_out.r01, ...; serve/replica.py) wake the same condition.
-_WAKEUP_SCOPES = ("serve_out", "serve_kv")
+_WAKEUP_SCOPES = ("serve_out", "serve_kv", "serve_req")
+# A GET ``?wait=SECONDS`` on one of those scopes is held until the key is
+# written or the wait, at most this long, runs out; the 404 that ends a
+# wait says so in this header, so that a client can tell a server that
+# waited from one that does not know the parameter.
+MAX_WAIT_S = 30.0
+WAITED_HEADER = "X-Hvd-Waited"
 
 
 def add_stream_waiter(server, scope: str, req_key: str):
@@ -323,8 +332,11 @@ class _KVHandler(BaseHTTPRequestHandler):
             self._serve_alerts()
             return
         self._count_request()
-        with self.server.kv_lock:  # type: ignore[attr-defined]
-            value = self.server.kv.get(scope, {}).get(key)  # type: ignore
+        wait = self._wait_s(scope)
+        if wait is not None:
+            self._serve_waited(scope, key, wait)
+            return
+        value = self._lookup(scope, key)
         if value is None:
             self.send_response(404)
             self.end_headers()
@@ -333,6 +345,65 @@ class _KVHandler(BaseHTTPRequestHandler):
         self.send_header("Content-Length", str(len(value)))
         self.end_headers()
         self.wfile.write(value)
+
+    def _lookup(self, scope: str, key: str) -> Optional[bytes]:
+        with self.server.kv_lock:  # type: ignore[attr-defined]
+            return self.server.kv.get(scope, {}).get(key)  # type: ignore
+
+    def _wait_s(self, scope: str) -> Optional[float]:
+        """How long the caller lets this GET be held (``?wait=SECONDS``),
+        or None: a scope whose writes wake nobody, a server without the
+        wakeup condition, no such parameter or a malformed one — the GET
+        is answered at once, as by a server that never knew it."""
+        query = getattr(self, "_query", "")
+        if "wait=" not in query or \
+                scope.split(".r", 1)[0] not in _WAKEUP_SCOPES or \
+                getattr(self.server, "kv_wakeup", None) is None:
+            return None
+        from urllib.parse import parse_qs
+        try:
+            wait = float(parse_qs(query)["wait"][0])
+        except (KeyError, ValueError):
+            return None
+        return min(max(0.0, wait), MAX_WAIT_S)
+
+    def _serve_waited(self, scope: str, key: str, wait: float) -> None:
+        """The held GET: block on the key's wakeup condition until a
+        writer has put the key (``wake_stream``) or the wait has run out.
+        The caller keeps its connection (``Connection: keep-alive``) and
+        asks for the next key over it, so both answers carry their
+        length, and the value follows its headers at once."""
+        server = self.server
+        cond = add_stream_waiter(server, scope, key) or server.kv_wakeup
+        deadline = time.monotonic() + wait
+        try:
+            # registered before the first look: a writer that comes after
+            # it finds the waiter, one that came before it left the value
+            with cond:
+                while True:
+                    value = self._lookup(scope, key)
+                    left = deadline - time.monotonic()
+                    if value is not None or left <= 0:
+                        break
+                    cond.wait(left)
+        finally:
+            drop_stream_waiter(server, scope, key)
+        keep = self.headers.get("Connection", "").lower() == "keep-alive"
+        try:
+            self.connection.setsockopt(socket.IPPROTO_TCP,
+                                       socket.TCP_NODELAY, 1)
+            self.send_response(404 if value is None else 200)
+            self.send_header("Content-Length", str(len(value or b"")))
+            if value is None:
+                self.send_header(WAITED_HEADER, "1")
+            if keep:
+                self.send_header("Connection", "keep-alive")
+            self.end_headers()
+            if value:
+                self.wfile.write(value)
+        except OSError:
+            return  # the caller went away while its GET was held
+        self.close_connection = not keep
 
     def _serve_metrics(self) -> None:
         """Fleet Prometheus exposition: local (driver) registry + every
@@ -643,6 +714,7 @@ class RendezvousServer:
             store.kv.setdefault(scope, {})[key] = value  # type: ignore
             store.kv_times.setdefault(scope, {})[key] = \
                 time.time()  # type: ignore[attr-defined]
+        wake_stream(self._httpd, scope, key)
 
     def get(self, scope: str, key: str) -> Optional[bytes]:
         if self._httpd is None:

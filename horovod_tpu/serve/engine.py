@@ -53,6 +53,7 @@ import numpy as np
 
 from ..models import paged
 from ..utils.profiler import PhaseClock, annotate
+from .arrivals import SUMS as _ARRIVAL_SUMS
 from .commit import SKIPS, SUMS as _COMMIT_SUMS, CommitPoint
 from .config import ServeConfig
 
@@ -1377,7 +1378,7 @@ _TURN_SUMS = tuple("turn_" + part for part in TURNAROUND_PARTS)
 _LOOP_SUMS = ("fence_ready_s", "fence_copy_s", "narrow", "narrow_wait_s",
               "wide", "wide_wait_s", "used", "turnaround_s", "turnaround_n",
               "after_idle_n", "iteration_s", "ahead_n",
-              "ahead_idle_rows") + _TURN_SUMS + _COMMIT_SUMS
+              "ahead_idle_rows") + _TURN_SUMS + _COMMIT_SUMS + _ARRIVAL_SUMS
 
 
 def _loop_figures(sums: Dict[str, float]) -> Dict[str, Any]:
@@ -1403,6 +1404,13 @@ def _loop_figures(sums: Dict[str, float]) -> Dict[str, Any]:
         "hold_skipped_n": {why: int(sums["hold_skip_" + why])
                            for why in SKIPS},
         "late_n": int(sums["late_n"]),
+        # how the loop learns of a request (serve/arrivals.py): records the
+        # reader handed over and their lag behind the router's stamp; an
+        # idle loop's waits, and those of them that a record ended
+        "arrival_n": int(sums["arrival_n"]),
+        "arrival_lag_s": sums["arrival_lag_s"],
+        "arrival_wake_n": int(sums["arrival_wake_n"]),
+        "idle_wait_n": int(sums["idle_wait_n"]),
         "iteration_s": sums["iteration_s"],
         "by_width": by_width,
         "narrow_ticks": by_width["narrow"]["ticks"],

@@ -355,7 +355,10 @@ def _enqueue_request(server, state: RouterState, rid: int,
                      req: Dict[str, Any], key: str) -> None:
     """Journal + enqueue one request under replica ``rid``'s scopes in
     ONE critical section (both owning stores' locks held): the
-    journaled set and the promised set cannot diverge."""
+    journaled set and the promised set cannot diverge.  Then wake the
+    GET that the replica's rank 0 has waiting on this key
+    (serve/arrivals.py), as a PUT over HTTP would."""
+    from ..runner.http_server import wake_stream
     rq_scope = scoped(REQ_SCOPE, rid)
     jn_scope = scoped(JOURNAL_SCOPE, rid)
     encoded = json.dumps(req).encode()
@@ -368,6 +371,7 @@ def _enqueue_request(server, state: RouterState, rid: int,
             jn = stores[jn_scope]
             jn.kv.setdefault(jn_scope, {})[key] = encoded
             jn.kv_times.setdefault(jn_scope, {})[key] = now
+    wake_stream(server, rq_scope, key)
 
 
 # -------------------------------------------------------- trace records

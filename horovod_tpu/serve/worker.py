@@ -6,8 +6,11 @@ trains.  Fleet coordination rides the existing rendezvous KV:
 
   * the router (runner/http_server.py + serve/router.py) enqueues
     requests with dense sequence numbers into scope ``serve_req``;
-  * rank 0 drains them, publishes a per-tick PLAN (scope ``serve_plan``
-    key ``e<epoch>.tick.N``) carrying the admitted requests verbatim,
+  * rank 0 learns of them from its arrivals reader (serve/arrivals.py:
+    a GET the server holds on the next request's key), drains the
+    reader's queue once a tick, publishes a per-tick PLAN (scope
+    ``serve_plan`` key ``e<epoch>.tick.N``) carrying the admitted
+    requests verbatim,
     and every rank — rank 0 included — applies the same plan to its own
     engine copy.  Engine scheduling and sampling are deterministic
     (serve/engine.py), so the fleet stays in lockstep without any new
@@ -65,6 +68,7 @@ import time
 from typing import Any, Callable, Dict, List, Optional
 
 from ..utils.profiler import PhaseClock
+from .arrivals import Arrivals, decode
 from .replica import REPLICA_SCOPE, replica_key, scoped
 from .router import (DRAIN_KEY, DRAINED_KEY, OUT_SCOPE, PLAN_SCOPE,
                      REQ_SCOPE, STATS_KEY, STATS_SCOPE, req_key)
@@ -75,6 +79,12 @@ from .router import (DRAIN_KEY, DRAINED_KEY, OUT_SCOPE, PLAN_SCOPE,
 # decode sub-fleet drains it in order.
 KV_SCOPE = "serve_kv"
 
+# How long an idle loop sleeps where nothing can wake it (no KV, the
+# decode role's handoff probes, a poll that a subclass or a test has
+# replaced), and the cadence at which the arrivals reader probes a server
+# that cannot hold a GET.  A loop with a reader beside it does not sleep:
+# it blocks on the reader's queue (serve/arrivals.py), at most until its
+# next drain probe or stats snapshot is due.
 _IDLE_SLEEP_S = 0.02
 _STATS_INTERVAL_S = 1.0
 # Drain-latch probe cadence: the latch is a driver/human-scale signal,
@@ -150,6 +160,9 @@ class FleetFrontend:
         # that owns the client stream) suppresses them, not us.
         self._resume_info: Dict[str, Dict[str, Any]] = {}
         self._last_stats = 0.0
+        # run() on rank 0 of a KV-backed front: the reader that waits on
+        # the next request's key, started by the loop's first poll
+        self._arrivals: Optional[Arrivals] = None
 
     # ------------------------------------------------------------ KV I/O
     def _kv(self):
@@ -187,18 +200,37 @@ class FleetFrontend:
             f"put {scope}/{key}")
 
     def _drain_requests(self) -> List[Dict[str, Any]]:
-        """Rank 0: consume newly-arrived requests in sequence order
-        (dense router numbering -> nonblocking probes, no listing)."""
+        """Rank 0: consume newly-arrived requests in sequence order (a
+        torn PUT is a None that holds its number).  This is the loop's
+        poll: inside ``run`` it empties the queue that the arrivals
+        reader fills (serve/arrivals.py) and touches no socket; its first
+        call starts the reader, so a front whose poll is replaced starts
+        none.  Called by hand, outside ``run``, it probes the dense
+        numbering itself (nonblocking, no listing)."""
+        if self._arrivals is not None:
+            if not self._arrivals.started:
+                self._arrivals.start()
+            reqs = self._arrivals.drain()
+            self._next_seq += len(reqs)
+            return reqs
         reqs = []
         while True:
             raw = self._kv_get(self.req_scope, req_key(self._next_seq))
             if raw is None:
                 return reqs
-            try:
-                reqs.append(json.loads(raw))
-            except (ValueError, TypeError):
-                reqs.append(None)  # torn PUT: hold the dense numbering
+            reqs.append(decode(raw))
             self._next_seq += 1
+
+    def _request_in_store(self) -> bool:
+        """Asked once, when the loop is about to stop: does the store hold
+        a request that the reader has not handed over yet (it lags by a
+        round trip, and by an outage's length when it rides one out)?
+        Everything accepted is finished first, as when the loop probed
+        for itself."""
+        if self._arrivals is None or not self._arrivals.started:
+            return False
+        return self._kv_get(self.req_scope,
+                            req_key(self._next_seq)) is not None
 
     def _publish_plan(self, reqs: List[Dict[str, Any]],
                       stop: bool = False) -> None:
@@ -570,6 +602,20 @@ class FleetFrontend:
         clock.add("hold_n", 1)
         clock.add("hold_s", held.t1 - held.t0)
 
+    def _idle_wait(self, until: float) -> None:
+        """Nothing in the engine and nothing polled.  With the arrivals
+        reader beside the loop: block on its queue, and wake the moment a
+        request is put there; at the latest when the loop has something
+        of its own to do — ``until`` (``time.monotonic``: the next drain
+        probe, the end of ``ttl_s``) or the next stats snapshot — so that
+        an idle loop still looks alive (``PM.record_step``).  Without
+        one: sleep, as ever."""
+        if self._arrivals is None or not self._arrivals.started:
+            time.sleep(_IDLE_SLEEP_S)
+            return
+        until = min(until, self._last_stats + _STATS_INTERVAL_S)
+        self._arrivals.wait(until - time.monotonic())
+
     def run(self, ttl_s: float = 0.0) -> int:
         """Serve until ``ttl_s`` elapses (0 = until interrupted), or a
         drain completes.  Rank 0 paces the fleet; followers block on the
@@ -588,6 +634,12 @@ class FleetFrontend:
         # The engine's phase clock when it has one (scripted test engines
         # do not): the loop's own phases land in the same table.
         clock = getattr(self.engine, "clock", None) or PhaseClock()
+        if self.rank == 0 and kv_backed and self.role != "decode":
+            # after the redrive has set the cursor; a fleet's followers
+            # get their requests from the plan stream
+            self._arrivals = Arrivals(
+                self._kv().KeyWaiter(self.addr, self.port, self.req_scope),
+                self._kv_op, self._next_seq, clock.add, _IDLE_SLEEP_S)
         t0 = time.monotonic()
         stop = False
         drain_t: Optional[float] = None
@@ -629,7 +681,8 @@ class FleetFrontend:
                              and time.monotonic() - t0 >= ttl_s)
                             or drain_t is not None)
                         stop = bool(done_serving and not reqs
-                                    and not self.engine.has_work())
+                                    and not self.engine.has_work()
+                                    and not self._request_in_store())
                         if drain_t is not None and not stop and \
                                 time.monotonic() - drain_t >= \
                                 self.drain_timeout_s:
@@ -668,7 +721,9 @@ class FleetFrontend:
                 if not self.engine.has_work() and not reqs:
                     if self.rank == 0:
                         with clock.span("idle"):
-                            time.sleep(_IDLE_SLEEP_S)
+                            self._idle_wait(
+                                min(drain_check_t, t0 + ttl_s) if ttl_s
+                                else drain_check_t)
         except KeyboardInterrupt:
             if self.rank == 0 and fleet:
                 # release the followers blocked on the plan stream
@@ -677,6 +732,10 @@ class FleetFrontend:
                 except Exception:
                     pass
             raise
+        finally:
+            if self._arrivals is not None:
+                self._arrivals.stop()
+                self._arrivals = None
         if self._dstream is not None:
             # Orderly end of the direct stream: everything sent is
             # already stored router-side, so this only releases the

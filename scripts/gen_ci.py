@@ -86,6 +86,7 @@ SUITES = {
                 "tests/test_serve_chain.py",
                 "tests/test_serve_chain_families.py",
                 "tests/test_serve_commit.py",
+                "tests/test_serve_arrivals.py",
                 "tests/test_greedy_read.py",
                 "tests/test_kv_shard.py", "tests/test_scenario.py",
                 "tests/test_latent_moe.py", "tests/test_paged.py",

@@ -37,8 +37,9 @@ default run checks the whole repo and exits nonzero on any violation:
                          and SLO rows everywhere (docs/scenarios.md).
   serve-kv-retry         serve-worker KV legs go through the _kv_op
                          bounded-backoff wrapper, never raw
-                         get_kv/put_kv/delete_kv (a transient rendezvous
-                         outage must stall serving, not kill it).
+                         get_kv/put_kv/delete_kv/wait_kv (a transient
+                         rendezvous outage must stall serving, not kill
+                         it).
   unique-test-basenames  test and worker module basenames are unique
                          across tests/ and tests/integration/ (no
                          __init__.py there, so a duplicate basename
@@ -432,7 +433,8 @@ def check_kvshard_determinism(
 
 
 # ----------------------------------------------------------- serve-kv-retry
-_KV_OPS = {"get_kv", "put_kv", "delete_kv"}
+# ``wait_kv`` is the arrivals reader's held GET (http_client.KeyWaiter)
+_KV_OPS = {"get_kv", "put_kv", "delete_kv", "wait_kv"}
 _KV_WRAPPERS = {"_kv_op", "_kv_get", "_kv_put", "_kv_delete"}
 
 
@@ -486,6 +488,7 @@ def check_scenario_determinism(
 def check_serve_kv_retry(
         root: str = REPO,
         files: Sequence[str] = ("horovod_tpu/serve/worker.py",
+                                "horovod_tpu/serve/arrivals.py",
                                 "horovod_tpu/serve/journal.py"),
 ) -> List[Violation]:
     """Serve-worker KV legs must ride the _kv_op backoff wrapper."""

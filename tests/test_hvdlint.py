@@ -188,6 +188,26 @@ def test_kv_retry_flags_raw_call(lint, tmp_path):
     assert out[0].line == 3
 
 
+def test_kv_retry_covers_the_arrivals_readers_held_get(lint, tmp_path):
+    """``wait_kv`` (http_client.KeyWaiter) is a KV leg like the others:
+    clean as a thunk handed to ``_kv_op``, flagged raw — and the reader's
+    own file is among those the rule reads."""
+    _write(tmp_path, "a.py", """\
+        class Arrivals:
+            def _read(self):
+                return self._kv_op(lambda: self._waiter.wait_kv("k", 1.0),
+                                   "wait")
+            def _raw(self):
+                return self._waiter.wait_kv("k", 1.0)
+        """)
+    out = lint.check_serve_kv_retry(str(tmp_path), files=("a.py",))
+    assert [(v.line, "raw wait_kv" in v.message) for v in out] == [(6, True)]
+    import inspect
+    files = inspect.signature(lint.check_serve_kv_retry).parameters["files"]
+    assert "horovod_tpu/serve/arrivals.py" in files.default
+    assert lint.check_serve_kv_retry() == []
+
+
 # ----------------------------------------------------- unique-test-basenames
 def test_basenames_clean(lint, tmp_path):
     _write(tmp_path, "tests/test_a.py", "")

@@ -352,6 +352,28 @@ def test_an_arrival_during_the_hold_is_in_the_next_launched_tick(fake, hold):
     engine.close()
 
 
+def test_a_poll_that_costs_nothing_shortens_the_margin_and_lengthens_the_hold(
+        fake):
+    """The poll is the margin on the stepped clock (nothing else moves it
+    between "stop holding" and "launch returned"): a poll that empties a
+    queue instead of probing a socket takes its 2 ms out of the margin,
+    and the loop holds that much longer into the tick in flight, to the
+    tick's end.  No launch is late for it, and the newcomer is in the next
+    program either way."""
+    for poll_s in (2 * MS, 0.0):
+        engine = _markov_engine(max_slots=2)
+        device, front, t0 = _serve(fake, engine, MARKOV, hold=True,
+                                   poll_s=poll_s)
+        loop = engine.stats()["loop"]
+        assert loop["late_n"] == 0 and loop["hold_n"] >= 10
+        assert loop["commit"]["margin_s"] == pytest.approx(poll_s, abs=1e-9)
+        assert loop["hold_s"] / loop["hold_n"] == pytest.approx(
+            10 * MS - poll_s, abs=1e-4)
+        assert device.first_tick("b") == \
+            device.tick_at(t0 + MARKOV[1]["at"]) + 1
+        engine.close()
+
+
 def test_a_launch_that_finds_the_tick_over_is_counted_late(fake):
     """The poll suddenly takes 5 ms where the margin, from the 2 ms polls
     before it, left 2: the launch returns after the tick in flight has
