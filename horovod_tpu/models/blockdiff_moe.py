@@ -40,15 +40,18 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, Dict, List, NamedTuple, Tuple
+from typing import Any, Callable, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import decoder
 from . import layers as L
 from . import paged
+from .paged import NARROW_COLS
 from ..parallel import expert as X
+from ..parallel.expert import EXPERT_TILE
 
 
 @dataclasses.dataclass(frozen=True)
@@ -101,13 +104,9 @@ CONFIGS = {
                                unmask_threshold=0.05),
 }
 
-#: rows of one expert's tile (parallel/expert.py held_experts)
-EXPERT_TILE = 64
 #: float32 scores one block of slots may hold (heads x columns x one tile of
 #: context x 4 B a slot)
 SCORE_BYTES = 32 << 20
-#: columns a block row's slots attend with in a chunk-wide tick
-NARROW_COLS = 8
 #: the cached attention reads a slot's context as far as it reaches
 BOUNDED_READ = True
 
@@ -151,42 +150,20 @@ def init(key, cfg: BlockDiffMoeConfig, head_std: float = None
 
 
 # ------------------------------------------------------------------ pieces
-def _norm(p, x, cfg):
-    return L.rmsnorm(p, x, eps=cfg.norm_eps)
-
-
-def _qkv(p, h, cfg, cos, sin, positions):
-    """The attention's projections of h [B, S, D] by head, queries and keys
-    normed over their head_dim and THEN rotated at ``positions`` [B, S]."""
-    heads = lambda w, n: L.dense(p[w], h).reshape(
-        h.shape[:2] + (n, cfg.head_dim))
-    q = _norm(p["q_norm"], heads("wq", cfg.n_heads), cfg)
-    k = _norm(p["k_norm"], heads("wk", cfg.n_kv_heads), cfg)
-    return (L.apply_rope_at(q, cos, sin, positions),
-            L.apply_rope_at(k, cos, sin, positions),
-            heads("wv", cfg.n_kv_heads))
-
-
 def _moe(p, h, valid, cfg):
     """This chip's experts on h [B, S, D] under the renormalised softmax
     router: (y, counters)."""
-    B, S, D = h.shape
-    rows = h.reshape(B * S, D)
-    with jax.named_scope("moe/route"):
-        routing = X.route_softmax_topk(rows, p["router"]["kernel"],
-                                       cfg.top_k)
-    y, counters = X.held_experts(
-        p, rows, valid.reshape(B * S), first=cfg.first_expert,
-        routing=routing, act=jax.nn.silu, tile=EXPERT_TILE)
-    with jax.named_scope("moe/combine"):
-        return y.reshape(B, S, D).astype(h.dtype), counters
+    route = lambda rows: X.route_softmax_topk(rows, p["router"]["kernel"],
+                                              cfg.top_k)
+    return X.held_ffn(p, h, valid, route=route, first=cfg.first_expert,
+                      act=jax.nn.silu, tile=EXPERT_TILE)
 
 
 def _logits(params, x, cfg):
     """Float32 logits of hidden states x [.., D]: the final norm, then the
     head, its products summed in float32 and never rounded to the rows'
     type (a confidence is compared with a threshold)."""
-    return jnp.dot(_norm(params["final_norm"], x, cfg),
+    return jnp.dot(L.norm(params["final_norm"], x, cfg),
                    params["head"]["kernel"],
                    preferred_element_type=jnp.float32)
 
@@ -238,11 +215,11 @@ def apply(params: Dict[str, Any], ids: jax.Array, cfg: BlockDiffMoeConfig,
     see = (jnp.arange(S)[None, :] // Bk <= jnp.arange(S)[:, None] // Bk)
     x = L.embedding(params["embed"], ids).astype(cfg.dtype)
     for p in params["layers"][:cfg.n_layers]:
-        q, k, v = _qkv(p["attn"], _norm(p["attn_norm"], x, cfg), cfg, cos,
-                       sin, positions)
+        q, k, v = L.qkv(p["attn"], L.norm(p["attn_norm"], x, cfg), cfg, cos,
+                        sin, positions, qk_norm=True)
         o = L.causal_attention(q, k, v, causal=False, mask=see[None, None])
         x = x + L.dense(p["attn"]["wo"], o.reshape(B, S, -1))
-        y, _ = _moe(p["moe"], _norm(p["ffn_norm"], x, cfg), valid, cfg)
+        y, _ = _moe(p["moe"], L.norm(p["ffn_norm"], x, cfg), valid, cfg)
         x = x + y
     return _logits(params, x, cfg)
 
@@ -283,19 +260,25 @@ def denoise(params: Dict[str, Any], prompt: List[int], max_new_tokens: int,
 
 
 # ------------------------------------------------------------- decode path
+def _pool(cfg) -> Tuple[paged.CacheKind, ...]:
+    """The one kind of cache, without a name: every layer's keys and values,
+    a position's heads side by side."""
+    heads = (cfg.n_kv_heads * cfg.head_dim,)
+    return (paged.CacheKind(None, cfg.n_layers,
+                            leaves={"k": heads, "v": heads}),)
+
+
 def init_cache(cfg: BlockDiffMoeConfig, num_blocks: int, block_size: int,
                dtype=None) -> Dict[str, jax.Array]:
     """``{"k", "v"}`` of ``[layers, num_blocks, block_size, n_kv_heads *
     head_dim]``, a position's heads side by side."""
-    dtype = dtype if dtype is not None else cfg.dtype
-    shape = (cfg.n_layers, num_blocks, block_size,
-             cfg.n_kv_heads * cfg.head_dim)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    return paged.init_pools(_pool(cfg), num_blocks, block_size,
+                            dtype if dtype is not None else cfg.dtype)
 
 
 def cache_shardings(mesh, cfg: BlockDiffMoeConfig, num_blocks: int):
     """The pool's blocks over the data axis."""
-    return paged.shardings(mesh, num_blocks)
+    return paged.pool_shardings(mesh, _pool(cfg), num_blocks)
 
 
 #: Prefix blocks' clones, as every whole-context pool has them; the engine
@@ -307,31 +290,8 @@ def attn_blocks(cfg: BlockDiffMoeConfig, S: int, C: int, ctx: int
                 ) -> Tuple[int, int]:
     """(slots a block, narrow columns) of the cached attention in a
     ``[S, C]`` tick over ``ctx`` gathered positions."""
-    return (paged.slots_per_block(S, cfg.n_heads * C * ctx * 4, SCORE_BYTES),
-            NARROW_COLS)
-
-
-class _Tick(NamedTuple):
-    """What the layers of one tick share."""
-    positions: jax.Array    # [S, C] (paged.slot_positions)
-    lengths: jax.Array      # [S] positions a slot held before the tick
-    n_new: jax.Array        # [S]
-    take: Callable          # [S, C, ...] -> rows [1, R, ...] (paged.pack)
-    slab: Callable          # rows -> [S, C, ...], zero where left out
-    valid: jax.Array        # the rows that hold a token
-    pos: jax.Array          # the rows' positions, inside the rope table
-    where: Tuple[jax.Array, jax.Array]   # the rows' (blk, off) in the pool
-    attend: Callable        # one tile of the block-masked attention
-
-
-def _tick(cfg, cache, table, lengths, n_new, C) -> _Tick:
-    positions, valid = paged.slot_positions(lengths, n_new, C)
-    take, slab = paged.pack(valid, cfg.max_tick_tokens)
-    blk, off = paged.write_index(table, positions, valid,
-                                 *cache["k"].shape[1:3])
-    return _Tick(positions, lengths, n_new, take, slab, take(valid),
-                 take(jnp.minimum(positions, cfg.max_seq - 1)),
-                 (take(blk), take(off)), _attend_tile(cfg.block_length))
+    return paged.attn_blocks(cfg.n_heads, S, C, ctx, SCORE_BYTES,
+                             NARROW_COLS)
 
 
 @functools.lru_cache(maxsize=None)
@@ -352,20 +312,23 @@ def _attend_tile(block: int) -> Callable:
     return attend
 
 
-def _attn_cached(p, h, cfg, i, cos, sin, cache, table, t: _Tick):
+def _attn_cached(p, h, cfg, i, cos, sin, attend, cache, table,
+                 t: paged.Tick):
     """Attention layer i over the pool, in place: the rows' k/v are
     scattered in first (``kv_commit``: a committing pass's stand, a
     denoising pass's are overwritten by the block's next pass), then each
     block of slots attends a tile of context after another as far as its
-    slots' contexts reach, under the block mask (``attn/block``)."""
+    slots' contexts reach, under the block mask (``attn/block``; ``attend``
+    is :func:`_attend_tile` of the block length)."""
     rows = h.shape[:2]
-    q, k, v = _qkv(p, h, cfg, cos, sin, t.pos)
+    q, k, v = L.qkv(p, h, cfg, cos, sin, t.pos, qk_norm=True)
     flat = lambda a: a.reshape(rows + (-1,))
     with jax.named_scope("kv_commit"):
-        pool = paged.write(cache, i, *t.where, {"k": flat(k), "v": flat(v)})
+        pool = paged.write(cache, i, *t.where[None],
+                           {"k": flat(k), "v": flat(v)})
     with jax.named_scope("attn/block"):
         o = paged.attend_by_blocks(
-            t.attend, (q, t.positions, table, t.lengths + t.n_new), t.n_new,
+            attend, (q, t.positions, table, t.lengths + t.n_new), t.n_new,
             *attn_blocks(cfg, *t.positions.shape,
                          table.shape[1] * pool["k"].shape[2]),
             bound=paged.Bound(t.lengths, pool, i, t.slab))
@@ -375,50 +338,31 @@ def _attn_cached(p, h, cfg, i, cos, sin, cache, table, t: _Tick):
 
 
 def _forward(params, tokens, cfg, cache, table, lengths, n_new, head):
-    """The tick's rows through the stack: (head(rows' float32 logits [1, R,
-    V]) back in the slab [S, C, ...], cache, counters)."""
+    """The tick's rows through the stack (decoder.forward): attention under
+    the block mask, then the routed experts; the head's logits float32."""
     cos, sin = L.rope_freqs(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
-    t = _tick(cfg, cache, table, lengths, n_new, tokens.shape[1])
-    with jax.named_scope("embed"):
-        x = L.embedding(params["embed"], t.take(tokens)).astype(cfg.dtype)
-    counters = jnp.zeros(len(X.HELD_COUNTERS), jnp.int32)
-    for i, p in enumerate(params["layers"][:cfg.n_layers]):
-        a, cache = _attn_cached(p["attn"], _norm(p["attn_norm"], x, cfg),
-                                cfg, i, cos, sin, cache, table, t)
+    attend = _attend_tile(cfg.block_length)
+
+    def layer(i, p, x, cache, t):
+        a, cache = _attn_cached(p["attn"], L.norm(p["attn_norm"], x, cfg),
+                                cfg, i, cos, sin, attend, cache, table, t)
         x = x + a
-        y, c = _moe(p["moe"], _norm(p["ffn_norm"], x, cfg), t.valid, cfg)
-        x = x + y
-        counters = counters + c     # load_max too: a sum over the layers
-    with jax.named_scope("head"):
-        out = jax.tree_util.tree_map(t.slab, head(_logits(params, x, cfg)))
-    return out, cache, jnp.concatenate([jnp.ones(1, jnp.int32), counters])
+        y, c = _moe(p["moe"], L.norm(p["ffn_norm"], x, cfg), t.valid, cfg)
+        return x + y, cache, c
+    return decoder.forward(
+        layer, lambda x: _logits(params, x, cfg), _pool(cfg), params, tokens,
+        cfg, cache, table, lengths, n_new, head, counters=TICK_COUNTERS,
+        max_seq=cfg.max_seq, reads=("valid", "pos"))
 
 
-def apply_cached(params: Dict[str, Any], tokens: jax.Array,
-                 cfg: BlockDiffMoeConfig, cache: Dict[str, Any],
-                 block_tables: jax.Array, lengths: jax.Array,
-                 n_new: jax.Array):
-    """Mixed prefill/denoise forward over the paged pool; the slot-table
-    contract of llama.apply_cached under the block mask: a slot's rows are
-    positions ``lengths .. lengths + n_new - 1`` — whole blocks, from a
-    block's first position — and see each other and everything before them.
-    Returns (float32 logits [S, C, vocab], zero at positions that were not
-    packed; updated cache; counters int32[len(TICK_COUNTERS)] summed over
-    the layers)."""
-    return _forward(params, tokens, cfg, cache, block_tables, lengths, n_new,
-                    lambda logits: logits)
-
-
-def greedy_cached(params: Dict[str, Any], tokens: jax.Array,
-                  cfg: BlockDiffMoeConfig, cache: Dict[str, Any],
-                  block_tables: jax.Array, lengths: jax.Array,
-                  n_new: jax.Array):
-    """:func:`apply_cached` with each position's candidate and confidence in
-    place of its logits: ((candidate int32 [S, C], confidence float32 [S,
-    C]), cache, counters), both taken on the packed rows ``[1, R, vocab]``
-    (:func:`candidates`; ServeEngine samples through this)."""
-    return _forward(params, tokens, cfg, cache, block_tables, lengths, n_new,
-                    lambda logits: candidates(logits, cfg))
+#: decoder.cached_pair has the contract, here under the block mask: a slot's
+#: rows are positions ``lengths .. lengths + n_new - 1`` — whole blocks, from
+#: a block's first position — and see each other and everything before them.
+#: The logits are float32, the third value the counters summed over the
+#: layers, and in the greedy token's place stand each position's candidate
+#: and confidence (:func:`candidates`): ``((int32 [S, C], float32 [S, C]),
+#: cache, counters)``.
+apply_cached, greedy_cached = decoder.cached_pair(_forward, sample=candidates)
 
 
 def param_count(cfg: BlockDiffMoeConfig) -> int:

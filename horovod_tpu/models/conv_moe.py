@@ -35,14 +35,17 @@ samples on the tick's packed rows (``greedy_cached``).
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, NamedTuple, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from . import decoder
 from . import layers as L
 from . import paged
+from .paged import NARROW_COLS
 from ..parallel import expert as X
+from ..parallel.expert import EXPERT_TILE
 
 
 @dataclasses.dataclass(frozen=True)
@@ -97,15 +100,11 @@ CONFIGS = {
                           top_k=2, max_seq=128),
 }
 
-#: rows of one expert's tile (parallel/expert.py held_experts)
-EXPERT_TILE = 64
 #: the epsilon under the gates' sum
 GATE_EPS = 1e-6
 #: float32 scores one block of slots may hold (heads x columns x one tile of
 #: context x 4 B a slot)
 SCORE_BYTES = 32 << 20
-#: columns a block of decode rows attends with in a chunk-wide tick
-NARROW_COLS = 8
 #: the names of the two cache kinds
 ATTN, CONV = "attn", "conv"
 #: the cached attention reads a slot's context as far as it reaches
@@ -157,10 +156,6 @@ def init(key, cfg: ConvMoeConfig) -> Dict[str, Any]:
 
 
 # ------------------------------------------------------------------ pieces
-def _norm(p, x, cfg):
-    return L.rmsnorm(p, x, eps=cfg.norm_eps)
-
-
 def _conv_in(p, h, cfg):
     """(C, u) of the conv operator's input h [.., D]: the output gate and the
     gated input ``u = B * X`` that the taps run over."""
@@ -184,44 +179,27 @@ def _conv_out(p, c, u, before, cfg):
         return L.dense(p["out_proj"], gated)
 
 
-def _qkv(p, h, cfg, cos, sin, positions):
-    """The attention's projections of h [B, S, D] by head, queries and keys
-    normed over their head_dim and THEN rotated at ``positions`` [B, S]."""
-    heads = lambda w, n: L.dense(p[w], h).reshape(
-        h.shape[:2] + (n, cfg.head_dim))
-    q = _norm(p["q_norm"], heads("wq", cfg.n_heads), cfg)
-    k = _norm(p["k_norm"], heads("wk", cfg.n_kv_heads), cfg)
-    return (L.apply_rope_at(q, cos, sin, positions),
-            L.apply_rope_at(k, cos, sin, positions),
-            heads("wv", cfg.n_kv_heads))
-
-
 def _ffn(p, h, valid, cfg, i):
     """Layer i's feed-forward part on h [B, S, D]: (y, counters) — the dense
     gated FFN, or this chip's experts under the biased router."""
-    B, S, D = h.shape
     if not cfg.routed(i):
         with jax.named_scope("ffn"):
             f = p["ffn"]
             y = X.gated_ffn(f["w1"]["kernel"], f["w3"]["kernel"],
                             f["w2"]["kernel"], h)
         return y.astype(h.dtype), 0
-    rows = h.reshape(B * S, D)
-    with jax.named_scope("moe/route"):
-        routing = X.route_sigmoid_topk(
-            rows, p["moe"]["router"]["kernel"], cfg.top_k, cfg.route_scale,
-            bias=p["moe"]["bias"], eps=GATE_EPS)
-    y, counters = X.held_experts(
-        p["moe"], rows, valid.reshape(B * S), first=cfg.first_expert,
-        routing=routing, act=jax.nn.silu, tile=EXPERT_TILE)
-    with jax.named_scope("moe/combine"):
-        return y.reshape(B, S, D).astype(h.dtype), counters
+    route = lambda rows: X.route_sigmoid_topk(
+        rows, p["moe"]["router"]["kernel"], cfg.top_k, cfg.route_scale,
+        bias=p["moe"]["bias"], eps=GATE_EPS)
+    return X.held_ffn(p["moe"], h, valid, route=route,
+                      first=cfg.first_expert, act=jax.nn.silu,
+                      tile=EXPERT_TILE)
 
 
 def _head(params, x, cfg):
     """Logits of hidden states x [.., D]: the final norm, then the embedding
     as the head."""
-    return jnp.dot(_norm(params["final_norm"], x, cfg),
+    return jnp.dot(L.norm(params["final_norm"], x, cfg),
                    params["embed"]["table"].T)
 
 
@@ -236,17 +214,18 @@ def apply(params: Dict[str, Any], ids: jax.Array, cfg: ConvMoeConfig
     valid = jnp.ones((B, S), bool)
     x = L.embedding(params["embed"], ids).astype(cfg.dtype)
     for i, p in enumerate(params["layers"][:cfg.n_layers]):
-        h = _norm(p["op_norm"], x, cfg)
+        h = L.norm(p["op_norm"], x, cfg)
         if cfg.conv(i):
             c, u = _conv_in(p["conv"], h, cfg)
             before = lambda back, u=u: jnp.pad(
                 u, ((0, 0), (back, 0), (0, 0)))[:, :S]
             x = x + _conv_out(p["conv"], c, u, before, cfg)
         else:
-            q, k, v = _qkv(p["attn"], h, cfg, cos, sin, positions)
+            q, k, v = L.qkv(p["attn"], h, cfg, cos, sin, positions,
+                            qk_norm=True)
             o = L.causal_attention(q, k, v)
             x = x + L.dense(p["attn"]["wo"], o.reshape(B, S, -1))
-        y, _ = _ffn(p, _norm(p["ffn_norm"], x, cfg), valid, cfg, i)
+        y, _ = _ffn(p, L.norm(p["ffn_norm"], x, cfg), valid, cfg, i)
         x = x + y
     return _head(params, x, cfg)
 
@@ -257,46 +236,32 @@ def cache_kinds(cfg: ConvMoeConfig) -> Tuple[paged.CacheKind, ...]:
     attention layers' whole contexts, the conv layers' fixed state a slot
     (``u`` at the last ``conv_taps - 1`` positions)."""
     n_conv = sum(cfg.conv(i) for i in range(cfg.n_layers))
-    kinds = (paged.CacheKind(ATTN, cfg.n_layers - n_conv),
-             paged.CacheKind(CONV, n_conv, state=cfg.conv_taps - 1))
+    # a position's heads side by side: with a last axis of ``head_dim`` 64,
+    # half a lane tile, the chip lays the pool out blocks-minor and relays it
+    # on the way into and out of every tick (PERF.md §6, PR 33)
+    heads = (cfg.n_kv_heads * cfg.head_dim,)
+    kinds = (paged.CacheKind(ATTN, cfg.n_layers - n_conv,
+                             leaves={"k": heads, "v": heads}),
+             paged.CacheKind(CONV, n_conv, state=cfg.conv_taps - 1,
+                             leaves={"u": (cfg.dim,)}))
     return tuple(k for k in kinds if k.layers)
-
-
-def _kind_of(cfg: ConvMoeConfig, i: int) -> Tuple[str, int]:
-    """(cache kind of layer i, its index among that kind's layers)."""
-    c = cfg.conv(i)
-    return CONV if c else ATTN, sum(cfg.conv(j) == c for j in range(i))
 
 
 def init_cache(cfg: ConvMoeConfig, num_blocks: Dict[str, Any],
                block_size: int, dtype=None) -> Dict[str, Dict[str, jax.Array]]:
     """One pool a kind: ``{ATTN: {"k", "v"}}`` of ``[attention layers,
     num_blocks[ATTN], block_size, n_kv_heads * head_dim]`` — a position's
-    heads side by side: with a last axis of ``head_dim`` 64, half a lane
-    tile, the chip lays the pool out blocks-minor and relays it on the way
-    into and out of every tick (PERF.md §6, PR 33) — and ``{CONV: {"u"}}``
-    of ``[conv layers, slots, columns, dim]``, ``num_blocks[CONV]`` being
-    the state kind's ``(slots, columns)``."""
-    dtype = dtype if dtype is not None else cfg.dtype
-    out = {}
-    for kind in cache_kinds(cfg):
-        if kind.state is not None:
-            out[kind.name] = {"u": jnp.zeros(
-                (kind.layers,) + tuple(num_blocks[kind.name]) + (cfg.dim,),
-                dtype)}
-        else:
-            shape = (kind.layers, num_blocks[kind.name], block_size,
-                     cfg.n_kv_heads * cfg.head_dim)
-            out[kind.name] = {"k": jnp.zeros(shape, dtype),
-                              "v": jnp.zeros(shape, dtype)}
-    return out
+    heads side by side (:func:`cache_kinds` says why) — and ``{CONV:
+    {"u"}}`` of ``[conv layers, slots, columns, dim]``, ``num_blocks[CONV]``
+    being the state kind's ``(slots, columns)``."""
+    return paged.init_pools(cache_kinds(cfg), num_blocks, block_size,
+                            dtype if dtype is not None else cfg.dtype)
 
 
 def cache_shardings(mesh, cfg: ConvMoeConfig, num_blocks: Dict[str, Any]):
     """{kind: sharding}: the paged pool's blocks and the state's slots over
     the data axis."""
-    return {name: paged.shardings(mesh, n[0] if name == CONV else n)
-            for name, n in num_blocks.items()}
+    return paged.pool_shardings(mesh, cache_kinds(cfg), num_blocks)
 
 
 #: Nothing to clone (paged.no_prefix_blocks): the engine refuses prefix
@@ -308,48 +273,11 @@ def attn_blocks(cfg: ConvMoeConfig, S: int, C: int, ctx: int
                 ) -> Tuple[int, int]:
     """(slots a block, narrow columns) of the cached attention in a
     ``[S, C]`` tick over ``ctx`` gathered positions."""
-    return (paged.slots_per_block(S, cfg.n_heads * C * ctx * 4, SCORE_BYTES),
-            NARROW_COLS)
+    return paged.attn_blocks(cfg.n_heads, S, C, ctx, SCORE_BYTES,
+                             NARROW_COLS)
 
 
-class _Tick(NamedTuple):
-    """What the layers of one tick share."""
-    positions: jax.Array    # [S, C] (paged.slot_positions)
-    lengths: jax.Array      # [S] positions a slot held before the tick
-    n_new: jax.Array        # [S]
-    take: Callable          # [S, C, ...] -> rows [1, R, ...] (paged.pack)
-    slab: Callable          # rows -> [S, C, ...], zero where left out
-    valid: jax.Array        # the rows that hold a token
-    pos: jax.Array          # the rows' positions, inside the rope table
-    where: Tuple[jax.Array, jax.Array]   # the rows' (blk, off) in the pool
-    # the state kind, by row: where u lands (paged.state_index), and what
-    # paged.state_read asks (slot, position, the slot's length), flat [N]
-    lands: Tuple[jax.Array, jax.Array]
-    row: Tuple[jax.Array, jax.Array, jax.Array]
-
-
-def _tick(cfg, cache, tables, lengths, n_new, C) -> _Tick:
-    positions, valid = paged.slot_positions(lengths, n_new, C)
-    take, slab = paged.pack(valid, cfg.max_tick_tokens)
-    where = lands = row = ()
-    if ATTN in cache:
-        blk, off = paged.write_index(tables[ATTN], positions, valid,
-                                     *cache[ATTN]["k"].shape[1:3])
-        where = (take(blk), take(off))
-    if CONV in cache:
-        slot, col = paged.state_index(lengths, n_new, valid, positions,
-                                      cache[CONV]["u"].shape[2])
-        lands = (take(slot), take(col))
-        wide = lambda a: take(jnp.broadcast_to(a[:, None], positions.shape)
-                              ).reshape(-1)
-        row = (wide(jnp.arange(lengths.shape[0], dtype=jnp.int32)),
-               take(positions).reshape(-1), wide(lengths))
-    return _Tick(positions, lengths, n_new, take, slab, take(valid),
-                 take(jnp.minimum(positions, cfg.max_seq - 1)), where, lands,
-                 row)
-
-
-def _conv_cached(p, h, cfg, j, cache, t: _Tick):
+def _conv_cached(p, h, cfg, j, cache, t: paged.Tick):
     """Conv layer (the state kind's j-th) on the tick's rows h: a row's
     earlier ``u`` are the rows before it where those are its own slot's new
     tokens, else the slot's state as the LAST tick left it, read before
@@ -361,8 +289,8 @@ def _conv_cached(p, h, cfg, j, cache, t: _Tick):
         before = lambda back: paged.state_read(
             pool, j, flat, *t.row, back).reshape(u.shape)
         earlier = [before(back) for back in range(1, cfg.conv_taps)]
-        cache = dict(cache, **{CONV: paged.write(cache[CONV], j, *t.lands,
-                                                 {"u": u})})
+        cache = dict(cache, **{CONV: paged.write(
+            cache[CONV], j, *t.lands[CONV], {"u": u})})
     return _conv_out(p, c, u, lambda back: earlier[back - 1], cfg), cache
 
 
@@ -377,16 +305,16 @@ def _attend_tile(q, pos, ctx, start):
         paged.context_mask(pos - start, ctx["k"].shape[1]))
 
 
-def _attn_cached(p, h, cfg, j, cos, sin, cache, tables, t: _Tick):
+def _attn_cached(p, h, cfg, j, cos, sin, cache, tables, t: paged.Tick):
     """Attention layer (the paged kind's j-th) over the pool, in place, as
     models/llama.py ``_attn_cached``: the rows' k/v are scattered in first,
     then each block of slots attends a tile of context after another as far
     as its slots' contexts reach."""
     rows = h.shape[:2]
-    q, k, v = _qkv(p, h, cfg, cos, sin, t.pos)
+    q, k, v = L.qkv(p, h, cfg, cos, sin, t.pos, qk_norm=True)
     with jax.named_scope("attn"):
         flat = lambda a: a.reshape(rows + (-1,))
-        pool = paged.write(cache[ATTN], j, *t.where,
+        pool = paged.write(cache[ATTN], j, *t.where[ATTN],
                            {"k": flat(k), "v": flat(v)})
         o = paged.attend_by_blocks(
             _attend_tile, (q, t.positions, tables[ATTN]), t.n_new,
@@ -400,55 +328,34 @@ def _attn_cached(p, h, cfg, j, cos, sin, cache, tables, t: _Tick):
 
 
 def _forward(params, tokens, cfg, cache, tables, lengths, n_new, head):
-    """The tick's rows through the stack: (head(rows' logits [1, R, V]) back
-    in the slab [S, C, ...], cache, counters)."""
+    """The tick's rows through the stack (decoder.forward): the layer's
+    operator by its kind, then its feed-forward part."""
     cos, sin = L.rope_freqs(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
-    t = _tick(cfg, cache, tables, lengths, n_new, tokens.shape[1])
-    with jax.named_scope("embed"):
-        x = L.embedding(params["embed"], t.take(tokens)).astype(cfg.dtype)
-    counters = jnp.zeros(len(X.HELD_COUNTERS), jnp.int32)
-    for i, p in enumerate(params["layers"][:cfg.n_layers]):
-        h = _norm(p["op_norm"], x, cfg)
-        kind, j = _kind_of(cfg, i)
+
+    def layer(i, p, x, cache, t):
+        h = L.norm(p["op_norm"], x, cfg)
+        kind, j = paged.layer_of_kind(
+            lambda j: CONV if cfg.conv(j) else ATTN, i)
         if kind == CONV:
             a, cache = _conv_cached(p["conv"], h, cfg, j, cache, t)
         else:
             a, cache = _attn_cached(p["attn"], h, cfg, j, cos, sin, cache,
                                     tables, t)
         x = x + a
-        y, c = _ffn(p, _norm(p["ffn_norm"], x, cfg), t.valid, cfg, i)
-        x = x + y
-        counters = counters + c     # load_max too: a sum over the layers
-    with jax.named_scope("head"):
-        out = t.slab(head(_head(params, x, cfg)))
-    return out, cache, jnp.concatenate([jnp.ones(1, jnp.int32), counters])
+        y, c = _ffn(p, L.norm(p["ffn_norm"], x, cfg), t.valid, cfg, i)
+        return x + y, cache, c
+    return decoder.forward(
+        layer, lambda x: _head(params, x, cfg), cache_kinds(cfg), params,
+        tokens, cfg, cache, tables, lengths, n_new, head,
+        counters=TICK_COUNTERS, max_seq=cfg.max_seq,
+        reads=("row", "valid", "pos"))
 
 
-def apply_cached(params: Dict[str, Any], tokens: jax.Array,
-                 cfg: ConvMoeConfig, cache: Dict[str, Any],
-                 block_tables: Dict[str, jax.Array], lengths: jax.Array,
-                 n_new: jax.Array):
-    """Mixed prefill/decode forward over both kinds of cache; the slot-table
-    contract of llama.apply_cached with ``cache`` a dict by kind and
-    ``block_tables`` the attention kind's alone (``{ATTN: table}``).
-    Returns (logits [S, C, vocab], zero at positions that were not packed;
-    updated cache; counters int32[len(TICK_COUNTERS)] summed over the routed
-    layers)."""
-    return _forward(params, tokens, cfg, cache, block_tables, lengths, n_new,
-                    lambda logits: logits)
-
-
-def greedy_cached(params: Dict[str, Any], tokens: jax.Array,
-                  cfg: ConvMoeConfig, cache: Dict[str, Any],
-                  block_tables: Dict[str, jax.Array], lengths: jax.Array,
-                  n_new: jax.Array):
-    """:func:`apply_cached` with the greedy token in place of the logits:
-    (next tokens int32 [S, C], cache, counters), the argmax taken on the
-    packed rows ``[1, R, vocab]`` (ServeEngine samples through this)."""
-    return _forward(
-        params, tokens, cfg, cache, block_tables, lengths, n_new,
-        lambda logits: jnp.argmax(logits.astype(jnp.float32),
-                                  axis=-1).astype(jnp.int32))
+#: decoder.cached_pair has the contract: ``cache`` is a dict by kind,
+#: ``block_tables`` the attention kind's alone (``{ATTN: table}``), the third
+#: value the counters summed over the routed layers, the greedy token every
+#: position's.
+apply_cached, greedy_cached = decoder.cached_pair(_forward)
 
 
 def param_count(cfg: ConvMoeConfig) -> int:

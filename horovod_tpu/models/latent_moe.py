@@ -39,9 +39,12 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from . import decoder
 from . import layers as L
 from . import paged
+from .paged import NARROW_COLS
 from ..parallel import expert as X
+from ..parallel.expert import EXPERT_TILE
 
 
 @dataclasses.dataclass(frozen=True)
@@ -81,7 +84,7 @@ class LatentMoeConfig:
     @property
     def pool_dim(self) -> int:
         """A position's columns in the paged pool: the latent and zeros up
-        to a whole number of ``POOL_LANES`` (:func:`init_cache`)."""
+        to a whole number of ``POOL_LANES`` (:func:`_pool`)."""
         return -(-self.latent_dim // POOL_LANES) * POOL_LANES
 
     @property
@@ -97,16 +100,11 @@ CONFIGS = {
                             experts_held=8, top_k=2, max_seq=128),
 }
 
-#: rows of one expert's tile (parallel/expert.py held_experts)
-EXPERT_TILE = 64
 #: the cached attention computes at most this many bytes of float32 scores
 #: at a time, a block of slots after another
 SCORE_BYTES = 256 << 20
-#: a block of slots whose rows all hold at most this many new tokens attends
-#: in its first columns only (decode rows of a prefill-width tick)
-NARROW_COLS = 8
 
-#: the pool's last axis is a whole number of these (init_cache): a TPU tiles
+#: the pool's last axis is a whole number of these (_pool): a TPU tiles
 #: an array's two minor axes by (8, 128), and a last axis that is no
 #: multiple of 128 is not kept minor by the device's default layout
 POOL_LANES = 128
@@ -165,10 +163,6 @@ def init(key, cfg: LatentMoeConfig) -> Dict[str, Any]:
 
 
 # ------------------------------------------------------------------ pieces
-def _norm(p, x, cfg):
-    return L.rmsnorm(p, x, eps=cfg.norm_eps)
-
-
 def _gated(p, x):
     return L.dense(p["w_down"],
                    jax.nn.silu(L.dense(p["w_gate"], x)) * L.dense(p["w_up"], x))
@@ -180,13 +174,14 @@ def _project(p, h, cfg, cos, sin, positions):
     with the rotary parts rotated; the latent is what the cache holds."""
     B, S, _ = h.shape
     with jax.named_scope("attn/q_lora"):
-        q = L.dense(p["wq_b"], _norm(p["q_a_norm"], L.dense(p["wq_a"], h), cfg))
+        q = L.dense(p["wq_b"],
+                    L.norm(p["q_a_norm"], L.dense(p["wq_a"], h), cfg))
         q = q.reshape(B, S, cfg.n_heads, cfg.qk_dim)
         q_nope, q_rope = q[..., :cfg.qk_nope_dim], q[..., cfg.qk_nope_dim:]
         q_rope = L.apply_rope_at(q_rope, cos, sin, positions)
     with jax.named_scope("attn/kv_latent"):
         kv = L.dense(p["wkv_a"], h)
-        c_kv = _norm(p["kv_a_norm"], kv[..., :cfg.kv_rank], cfg)
+        c_kv = L.norm(p["kv_a_norm"], kv[..., :cfg.kv_rank], cfg)
         k_rope = L.apply_rope_at(kv[..., None, cfg.kv_rank:], cos, sin,
                                  positions)[..., 0, :]
         latent = jnp.concatenate([c_kv, k_rope], -1)
@@ -206,15 +201,13 @@ def _mlp(p, h, valid, cfg):
         with jax.named_scope("ffn"):
             return _gated(p["ffn"], h), jnp.zeros(len(X.HELD_COUNTERS),
                                                   jnp.int32)
-    B, S, D = h.shape
     with jax.named_scope("moe/shared"):
         shared = _gated(p["moe"]["shared"], h)
-    y, counters = X.held_experts(
-        p["moe"], h.reshape(B * S, D), valid.reshape(B * S),
-        first=cfg.first_expert, k=cfg.top_k, scale=cfg.route_scale,
-        tile=EXPERT_TILE)
+    y, counters = X.held_ffn(
+        p["moe"], h, valid, first=cfg.first_expert, k=cfg.top_k,
+        scale=cfg.route_scale, tile=EXPERT_TILE)
     with jax.named_scope("moe/combine"):
-        return shared + y.reshape(B, S, D).astype(h.dtype), counters
+        return shared + y, counters
 
 
 # ------------------------------------------------------- full-sequence path
@@ -230,7 +223,7 @@ def apply(params: Dict[str, Any], ids: jax.Array,
     x = L.embedding(params["embed"], ids).astype(cfg.dtype)
     for p in params["layers"]:
         a = p["attn"]
-        q_nope, q_rope, latent = _project(a, _norm(p["input_norm"], x, cfg),
+        q_nope, q_rope, latent = _project(a, L.norm(p["input_norm"], x, cfg),
                                           cfg, cos, sin, positions)
         wk, wv = _wkv_b(a, cfg)
         c_kv, k_rope = latent[..., :cfg.kv_rank], latent[..., cfg.kv_rank:]
@@ -241,32 +234,37 @@ def apply(params: Dict[str, Any], ids: jax.Array,
         v = jnp.einsum("bsl,lhv->bshv", c_kv, wv)
         o = L.causal_attention(jnp.concatenate([q_nope, q_rope], -1), k, v)
         o = L.dense(a["wo"], o.reshape(B, S, cfg.n_heads * cfg.v_dim))
-        x = x + _norm(p["post_attn_norm"], o, cfg)
-        m, _ = _mlp(p, _norm(p["pre_mlp_norm"], x, cfg), valid, cfg)
-        x = x + _norm(p["post_mlp_norm"], m, cfg)
-    return L.dense(params["lm_head"], _norm(params["final_norm"], x, cfg))
+        x = x + L.norm(p["post_attn_norm"], o, cfg)
+        m, _ = _mlp(p, L.norm(p["pre_mlp_norm"], x, cfg), valid, cfg)
+        x = x + L.norm(p["post_mlp_norm"], m, cfg)
+    return _logits(params, cfg, x)
 
 
 # ------------------------------------------------------------- decode path
+def _pool(cfg) -> Tuple[paged.CacheKind, ...]:
+    """The one kind of cache, without a name: ``[c_kv | k_rope | zeros]`` a
+    position, whatever the number of heads.  The zeros make the pool's
+    device layout the one the tick works in: the shape decides it
+    (docs/serving.md#where-the-pool-lies), and with kv_rank + qk_rope_dim =
+    576 columns a TPU keeps the BLOCK axis minor, while the tick addresses
+    the pool by ``[layer, block]`` and so relaid all of it on the way into
+    and out of every tick (PERF.md §6, PR 36).  At 640 it lies row-major, in
+    the bytes 576 columns take there anyway (tiles of 128 lanes)."""
+    return (paged.CacheKind(None, cfg.n_layers,
+                            leaves={"latent": (cfg.pool_dim,)}),)
+
+
 def init_cache(cfg: LatentMoeConfig, num_blocks: int, block_size: int,
                dtype=None) -> Dict[str, jax.Array]:
     """The latent paged pool: ``{"latent": [n_layers, num_blocks,
-    block_size, pool_dim]}`` — ``[c_kv | k_rope | zeros]`` a position,
-    whatever the number of heads.  The zeros make the pool's device layout
-    the one the tick works in: the shape decides it (docs/serving.md
-    #where-the-pool-lies), and with kv_rank + qk_rope_dim = 576 columns a
-    TPU keeps the BLOCK axis minor, while the tick addresses the pool by
-    ``[layer, block]`` and so relaid all of it on the way into and out of
-    every tick (PERF.md §6, PR 36).  At 640 it lies row-major, in the bytes
-    576 columns take there anyway (tiles of 128 lanes)."""
-    dtype = dtype if dtype is not None else cfg.dtype
-    return {"latent": jnp.zeros((cfg.n_layers, num_blocks, block_size,
-                                 cfg.pool_dim), dtype)}
+    block_size, pool_dim]}`` (:func:`_pool` says why that wide)."""
+    return paged.init_pools(_pool(cfg), num_blocks, block_size,
+                            dtype if dtype is not None else cfg.dtype)
 
 
 def cache_shardings(mesh, cfg: LatentMoeConfig, num_blocks: int):
     """The latent is every head's: no axis of it goes over a model axis."""
-    return paged.shardings(mesh, num_blocks)
+    return paged.pool_shardings(mesh, _pool(cfg), num_blocks)
 
 
 copy_blocks = paged.copy_blocks
@@ -276,8 +274,8 @@ def attn_blocks(cfg: LatentMoeConfig, S: int, C: int, ctx: int
                 ) -> Tuple[int, int]:
     """(slots a block, narrow columns) of :func:`_latent_attention` in a
     ``[S, C]`` tick over ``ctx`` positions, for the engine's counters."""
-    return (paged.slots_per_block(S, cfg.n_heads * C * ctx * 4, SCORE_BYTES),
-            NARROW_COLS)
+    return paged.attn_blocks(cfg.n_heads, S, C, ctx, SCORE_BYTES,
+                             NARROW_COLS)
 
 
 def _latent_tile(kv_rank: int, scale: float, q, pos, ctx, start):
@@ -304,39 +302,29 @@ def latent_attend(cfg: LatentMoeConfig):
                              1.0 / math.sqrt(cfg.qk_dim))
 
 
+def _logits(params, cfg, x):
+    """The final norm and the output head on hidden states ``[.., dim]``."""
+    return L.dense(params["lm_head"], L.norm(params["final_norm"], x, cfg))
+
+
 def _forward(params, tokens, cfg, cache, block_tables, lengths, n_new, head):
-    """The tick's rows through the stack: (``head(slab, x)`` of the tick's
-    paged.Slab and its rows' last hidden states ``[1, R, dim]``, under the
-    ``head`` scope; cache; counters)."""
+    """The tick's rows through the stack (decoder.forward): sandwich norms
+    round the absorbed attention over the latent pool and round the FFN
+    half; only the absorbed queries go back to their slots."""
     S, C = tokens.shape
     cos, sin = L.rope_freqs(cfg.qk_rope_dim, cfg.max_seq, cfg.rope_theta)
-    positions, valid = paged.slot_positions(lengths, n_new, C)
-    blk, off = paged.write_index(block_tables, positions, valid,
-                                 *cache["latent"].shape[1:3])
-    # Everything but the attention's core is a token's own and runs on the
-    # tick's ROWS (paged.pack): the valid positions packed to the front when
-    # the engine promises fewer of them than the slab has positions, so that
-    # a prefill-wide tick does not push every slot's padding through every
-    # matrix.  Only the absorbed queries go back to their slots.
-    take, slab = paged.pack(valid, cfg.max_tick_tokens)
-    row_valid, row_blk, row_off = map(take, (valid, blk, off))
-    pos_c = take(jnp.minimum(positions, cfg.max_seq - 1))
-    rows = pos_c.shape[:2]
     blocks = attn_blocks(cfg, S, C,
                          block_tables.shape[1] * cache["latent"].shape[2])
     attend = latent_attend(cfg)
-    # the pool's zero columns (init_cache), as the pool in hand has them
+    # the pool's zero columns (:func:`_pool`), as the pool in hand has them
     pad = cache["latent"].shape[-1] - cfg.latent_dim
-    with jax.named_scope("embed"):
-        x = L.embedding(params["embed"], take(tokens)).astype(cfg.dtype)
 
-    counters = jnp.zeros(len(X.HELD_COUNTERS), jnp.int32)
-    for i, p in enumerate(params["layers"]):
-        a = p["attn"]
-        q_nope, q_rope, latent = _project(a, _norm(p["input_norm"], x, cfg),
-                                          cfg, cos, sin, pos_c)
+    def layer(i, p, x, cache, t):
+        a, rows = p["attn"], x.shape[:2]
+        q_nope, q_rope, latent = _project(
+            a, L.norm(p["input_norm"], x, cfg), cfg, cos, sin, t.pos)
         cache = paged.write(
-            cache, i, row_blk, row_off,
+            cache, i, *t.where[None],
             {"latent": jnp.pad(latent.astype(cache["latent"].dtype),
                                ((0, 0), (0, 0), (0, pad)))})
         wk, wv = _wkv_b(a, cfg)
@@ -346,57 +334,29 @@ def _forward(params, tokens, cfg, cache, block_tables, lengths, n_new, head):
             # read through the pool's first latent_dim columns: on the
             # chip the same bytes under another shape, no op of its own
             o = paged.attend_by_blocks(
-                attend, (q, positions, block_tables), n_new, *blocks,
+                attend, (q, t.positions, block_tables), t.n_new, *blocks,
                 bound=paged.Bound(
-                    lengths, {"latent": cache["latent"][..., :cfg.latent_dim]},
-                    i, slab))
+                    t.lengths,
+                    {"latent": cache["latent"][..., :cfg.latent_dim]},
+                    i, t.slab))
             # [S, H, C, kv_rank] -> the rows
-            o = take(jnp.swapaxes(o, 1, 2))
+            o = t.take(jnp.swapaxes(o, 1, 2))
         with jax.named_scope("attn/out"):
             o = jnp.einsum("brhl,lhv->brhv", o, wv)
             o = L.dense(a["wo"], o.reshape(rows + (cfg.n_heads * cfg.v_dim,)))
-            x = x + _norm(p["post_attn_norm"], o, cfg)
-        m, c = _mlp(p, _norm(p["pre_mlp_norm"], x, cfg), row_valid, cfg)
-        x = x + _norm(p["post_mlp_norm"], m, cfg)
-        counters = counters + c     # load_max too: a sum over the layers
-    with jax.named_scope("head"):
-        return (head(slab, x), cache,
-                jnp.concatenate([jnp.ones(1, jnp.int32), counters]))
+            x = x + L.norm(p["post_attn_norm"], o, cfg)
+        m, c = _mlp(p, L.norm(p["pre_mlp_norm"], x, cfg), t.valid, cfg)
+        return x + L.norm(p["post_mlp_norm"], m, cfg), cache, c
+    return decoder.forward(
+        layer, functools.partial(_logits, params, cfg), _pool(cfg), params,
+        tokens, cfg, cache, block_tables, lengths, n_new, head,
+        counters=TICK_COUNTERS, max_seq=cfg.max_seq, reads=("valid", "pos"))
 
 
-def _logits(params, cfg, x):
-    """The final norm and the output head on hidden states ``[.., dim]``."""
-    return L.dense(params["lm_head"], _norm(params["final_norm"], x, cfg))
-
-
-def apply_cached(params: Dict[str, Any], tokens: jax.Array,
-                 cfg: LatentMoeConfig, cache: Dict[str, jax.Array],
-                 block_tables: jax.Array, lengths: jax.Array,
-                 n_new: jax.Array
-                 ) -> Tuple[jax.Array, Dict[str, jax.Array], jax.Array]:
-    """Mixed prefill/decode forward over the latent pool; the slot-table
-    contract of llama.apply_cached.  Returns (logits [S, C, vocab] — zero
-    at positions that were not packed —, updated cache, counters
-    int32[len(TICK_COUNTERS)] summed over the expert layers)."""
-    return _forward(params, tokens, cfg, cache, block_tables, lengths, n_new,
-                    lambda slab, x: slab(_logits(params, cfg, x)))
-
-
-def greedy_cached(params: Dict[str, Any], tokens: jax.Array,
-                  cfg: LatentMoeConfig, cache: Dict[str, jax.Array],
-                  block_tables: jax.Array, lengths: jax.Array,
-                  n_new: jax.Array, read: jax.Array
-                  ) -> Tuple[jax.Array, Dict[str, jax.Array], jax.Array]:
-    """llama.greedy_cached's contract over the latent pool: (tokens int32
-    [S, W] — the greedy token after each slot's columns ``read``, the
-    float32 argmax of :func:`apply_cached`'s logits row there —, cache,
-    counters); the final norm, the head and the argmax run on those ``S *
-    W`` rows alone (paged.Slab.at)."""
-    return _forward(
-        params, tokens, cfg, cache, block_tables, lengths, n_new,
-        lambda slab, x: jnp.argmax(
-            _logits(params, cfg, slab.at(x, read)).astype(jnp.float32),
-            axis=-1).astype(jnp.int32))
+#: decoder.cached_pair has the contract: the third value is the counters
+#: summed over the expert layers, the greedy tokens those of the columns the
+#: tick reads.
+apply_cached, greedy_cached = decoder.cached_pair(_forward, read=True)
 
 
 def param_count(cfg: LatentMoeConfig) -> int:

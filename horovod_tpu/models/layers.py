@@ -72,6 +72,11 @@ def rmsnorm(p: Params, x: jax.Array, eps: float = 1e-6) -> jax.Array:
     return (y * p["scale"]).astype(x.dtype)
 
 
+def norm(p: Params, x: jax.Array, cfg) -> jax.Array:
+    """:func:`rmsnorm` with the config's own epsilon (``cfg.norm_eps``)."""
+    return rmsnorm(p, x, eps=cfg.norm_eps)
+
+
 def gelu(x: jax.Array) -> jax.Array:
     return jax.nn.gelu(x, approximate=True)
 
@@ -202,6 +207,27 @@ def apply_rope_at(x: jax.Array, cos: jax.Array, sin: jax.Array,
     x1, x2 = jnp.split(x, 2, axis=-1)
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s],
                            axis=-1).astype(x.dtype)
+
+
+def qkv(p: Params, h: jax.Array, cfg, cos: jax.Array, sin: jax.Array,
+        positions: jax.Array, qk_norm: bool = False, rotary: bool = True
+        ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """A grouped-query attention's projections of h [B, S, D] by head
+    (``wq``, ``wk``, ``wv`` of ``p``; ``cfg.n_heads`` query and
+    ``cfg.n_kv_heads`` key and value heads of ``cfg.head_dim``).  With
+    ``qk_norm`` queries and keys are normed over their head_dim
+    (:func:`norm` with the gains ``q_norm``, ``k_norm``) and THEN, with
+    ``rotary``, rotated at ``positions`` [B, S]; a layer without ``rotary``
+    has no positional encoding at all."""
+    def heads(w, n, gain=None):
+        a = dense(p[w], h).reshape(h.shape[:2] + (n, cfg.head_dim))
+        return norm(p[gain], a, cfg) if qk_norm and gain else a
+    q = heads("wq", cfg.n_heads, "q_norm")
+    k = heads("wk", cfg.n_kv_heads, "k_norm")
+    if rotary:
+        q = apply_rope_at(q, cos, sin, positions)
+        k = apply_rope_at(k, cos, sin, positions)
+    return q, k, heads("wv", cfg.n_kv_heads)
 
 
 def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,

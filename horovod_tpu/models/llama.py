@@ -19,14 +19,17 @@ for tests and the single-chip bench.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
-from typing import Any, Callable, Dict, NamedTuple, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from . import decoder
 from . import layers as L
 from . import paged
+from .paged import NARROW_COLS
 
 
 @dataclasses.dataclass(frozen=True)
@@ -246,21 +249,27 @@ def loss_fn(params: Dict[str, Any], ids: jax.Array, cfg: LlamaConfig,
 # decode steps reproduce apply()'s logits bit-near (tests/test_serve.py).
 
 
+def _pool(cfg) -> Tuple[paged.CacheKind, ...]:
+    """The one kind of cache, without a name (the cache is the pool itself,
+    its table an array): every layer's keys and values by kv head."""
+    behind = (cfg.n_kv_heads, cfg.head_dim)
+    return (paged.CacheKind(None, cfg.n_layers,
+                            leaves={"k": behind, "v": behind}),)
+
+
 def init_cache(cfg: LlamaConfig, num_blocks: int, block_size: int,
                dtype=None) -> Dict[str, jax.Array]:
     """Preallocate the paged KV pool: ``{"k","v"}`` of shape
     ``[n_layers, num_blocks, block_size, n_kv_heads, head_dim]``, one
     stacked buffer each that apply_cached indexes by layer and never
     unstacks (models/paged.py)."""
-    dtype = dtype if dtype is not None else cfg.dtype
-    shape = (cfg.n_layers, num_blocks, block_size, cfg.n_kv_heads,
-             cfg.head_dim)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    return paged.init_pools(_pool(cfg), num_blocks, block_size,
+                            dtype if dtype is not None else cfg.dtype)
 
 
 def cache_shardings(mesh, cfg: LlamaConfig, num_blocks: int):
     """Blocks over the data axis, kv heads over a model axis."""
-    return paged.shardings(mesh, num_blocks, cfg.n_kv_heads)
+    return paged.pool_shardings(mesh, _pool(cfg), num_blocks)
 
 
 copy_blocks = paged.copy_blocks
@@ -272,8 +281,6 @@ BOUNDED_READ = True
 #: B a slot): internlm2-1.8b's [16, 128] tick over 2,048 positions attends
 #: two slots at a time, its [16, 5] tick all sixteen at once
 SCORE_BYTES = 32 << 20
-#: columns a block of decode rows attends with in a chunk-wide tick
-NARROW_COLS = 8
 
 
 def attn_blocks(cfg: LlamaConfig, S: int, C: int, ctx: int
@@ -281,35 +288,8 @@ def attn_blocks(cfg: LlamaConfig, S: int, C: int, ctx: int
     """(slots a block, narrow columns) of the cached attention in a
     ``[S, C]`` tick over ``ctx`` gathered positions (paged.attend_by_blocks;
     the engine's ``wide_blocks_share`` reads a plan by the same two)."""
-    return (paged.slots_per_block(S, cfg.n_heads * C * ctx * 4, SCORE_BYTES),
-            NARROW_COLS)
-
-
-class _Tick(NamedTuple):
-    """What the layers of one tick share (:func:`_tick`)."""
-    positions: jax.Array    # [S, C] (paged.slot_positions)
-    lengths: jax.Array      # [S] positions a slot held before the tick
-    n_new: jax.Array        # [S]
-    take: Callable          # [S, C, ...] -> rows [1, R, ...] (paged.pack)
-    slab: paged.Slab        # rows -> [S, C, ...] (or a block of it, or the
-                            # columns a tick reads), zero where left out
-    pos: jax.Array          # the rows' positions, inside the rope table
-    blk: jax.Array          # where the rows land (paged.write_index)
-    off: jax.Array
-
-
-def _tick(cfg: LlamaConfig, cache: Dict[str, jax.Array],
-          block_tables: jax.Array, lengths: jax.Array, n_new: jax.Array,
-          C: int) -> _Tick:
-    """One tick's slot arithmetic, done once for all layers: where each
-    position lies and lands, and the rows the tick's tokens are packed to."""
-    positions, valid = paged.slot_positions(lengths, n_new, C)
-    blk, off = paged.write_index(block_tables, positions, valid,
-                                 *cache["k"].shape[1:3])
-    take, slab = paged.pack(valid, cfg.max_tick_tokens)
-    return _Tick(positions, lengths, n_new, take, slab,
-                 take(jnp.minimum(positions, cfg.max_seq - 1)),
-                 take(blk), take(off))
+    return paged.attn_blocks(cfg.n_heads, S, C, ctx, SCORE_BYTES,
+                             NARROW_COLS)
 
 
 def _attend_tile(q, pos, ctx, start):
@@ -323,7 +303,7 @@ def _attend_tile(q, pos, ctx, start):
 
 def _attn_cached(p: Dict[str, Any], x: jax.Array, cfg: LlamaConfig,
                  cos: jax.Array, sin: jax.Array, cache: Dict[str, jax.Array],
-                 layer: int, block_tables: jax.Array, t: _Tick
+                 layer: int, block_tables: jax.Array, t: paged.Tick
                  ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Layer ``layer``'s attention over the paged cache, in place: ``cache``
     is the STACKED pools (init_cache's five axes) and comes back with this
@@ -346,7 +326,7 @@ def _attn_cached(p: Dict[str, Any], x: jax.Array, cfg: LlamaConfig,
     heads = lambda w, n: L.dense(p[w], x).reshape(rows + (n, cfg.head_dim))
     q = L.apply_rope_at(heads("wq", cfg.n_heads), cos, sin, t.pos)
     k = L.apply_rope_at(heads("wk", cfg.n_kv_heads), cos, sin, t.pos)
-    cache = paged.write(cache, layer, t.blk, t.off,
+    cache = paged.write(cache, layer, *t.where[None],
                         {"k": k, "v": heads("wv", cfg.n_kv_heads)})
     o = paged.attend_by_blocks(
         _attend_tile, (q, t.positions, block_tables), t.n_new,
@@ -358,15 +338,17 @@ def _attn_cached(p: Dict[str, Any], x: jax.Array, cfg: LlamaConfig,
     return L.dense(p["wo"], o.reshape(rows + (-1,))), cache
 
 
+def _logits(params: Dict[str, Any], x: jax.Array) -> jax.Array:
+    """The final norm and the output head on hidden states ``[.., dim]``."""
+    return L.dense(params["lm_head"], L.rmsnorm(params["final_norm"], x))
+
+
 def _forward(params, tokens, cfg, cache, block_tables, lengths, n_new, head):
-    """The tick's rows through the stack: (``head(t, x)`` of the tick and
-    its rows' last hidden states ``[1, R, dim]``, under the ``head`` scope;
-    cache)."""
+    """The tick's rows through the stack (decoder.forward): pre-norm
+    attention over the paged cache, then the gated FFN."""
     cos, sin = L.rope_freqs(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
-    t = _tick(cfg, cache, block_tables, lengths, n_new, tokens.shape[1])
-    with jax.named_scope("embed"):
-        x = L.embedding(params["embed"], t.take(tokens)).astype(cfg.dtype)
-    for i, p in enumerate(params["layers"]):
+
+    def layer(i, p, x, cache, t):
         with jax.named_scope("attn"):
             a, cache = _attn_cached(
                 p, L.rmsnorm(p["attn_norm"], x), cfg, cos, sin,
@@ -374,63 +356,16 @@ def _forward(params, tokens, cfg, cache, block_tables, lengths, n_new, head):
             x = x + a
         with jax.named_scope("ffn"):
             x = x + _ffn(p, L.rmsnorm(p["ffn_norm"], x), cfg)
-    with jax.named_scope("head"):
-        return head(t, x), cache
+        return x, cache
+    return decoder.forward(
+        layer, functools.partial(_logits, params), _pool(cfg), params, tokens,
+        cfg, cache, block_tables, lengths, n_new, head, max_seq=cfg.max_seq,
+        reads=("pos",))
 
 
-def _logits(params: Dict[str, Any], x: jax.Array) -> jax.Array:
-    """The final norm and the output head on hidden states ``[.., dim]``."""
-    return L.dense(params["lm_head"], L.rmsnorm(params["final_norm"], x))
-
-
-def greedy_at(params: Dict[str, Any], read: jax.Array) -> Callable:
-    """:func:`_forward`'s ``head`` for :func:`greedy_cached` (and
-    models/moe_llama.py's): the float32 argmax of the logits of the rows
-    that hold columns ``read`` [S, W] of each slot (``Slab.at``) — ``S * W``
-    rows through the final norm and the head, whatever the tick's width."""
-    return lambda t, x: jnp.argmax(
-        _logits(params, t.slab.at(x, read)).astype(jnp.float32),
-        axis=-1).astype(jnp.int32)
-
-
-def apply_cached(params: Dict[str, Any], tokens: jax.Array,
-                 cfg: LlamaConfig, cache: Dict[str, jax.Array],
-                 block_tables: jax.Array, lengths: jax.Array,
-                 n_new: jax.Array
-                 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Mixed prefill/decode forward over the paged cache.
-
-    ``tokens`` [S, C] int32 — slot s's next ``n_new[s]`` tokens (0 =
-    inactive slot), starting at context length ``lengths[s]``;
-    ``block_tables`` [S, max_blocks] int32 indexes the pool (-1 =
-    unassigned).  Returns (logits [S, C, vocab], updated cache); the
-    caller samples from position ``n_new[s] - 1``: logits are defined at
-    VALID positions only (zero where ``cfg.max_tick_tokens`` left a
-    position out of the packed rows).  Prefill a prompt in ceil(len/C)
-    calls, then decode one token per call.  The logit-level contract, which
-    tests and references hold the model to; the serving engine's one jit'd
-    tick (horovod_tpu/serve/engine.py), which donates ``cache`` — the
-    stacked pools go through the layers whole —, asks for the tokens it
-    reads and no logits (:func:`greedy_cached`)."""
-    return _forward(params, tokens, cfg, cache, block_tables, lengths, n_new,
-                    lambda t, x: t.slab(_logits(params, x)))
-
-
-def greedy_cached(params: Dict[str, Any], tokens: jax.Array,
-                  cfg: LlamaConfig, cache: Dict[str, jax.Array],
-                  block_tables: jax.Array, lengths: jax.Array,
-                  n_new: jax.Array, read: jax.Array
-                  ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """:func:`apply_cached` for the serving tick: (tokens int32 [S, W],
-    cache), the greedy token after column ``read[s, j]`` of slot s — the
-    float32 argmax of the logits row ``apply_cached`` has there.  ``read``
-    [S, W] int32 names the columns the tick reads (inside ``0 .. C-1``;
-    serve/engine.py ``tick_program``); the final norm, the head and the
-    argmax run on those ``S * W`` rows alone, so a chunk-wide tick builds
-    neither ``[S, C, vocab]`` nor ``[R, vocab]``.  A column past
-    ``n_new[s]``, or one the pack left out, yields a token nobody may use."""
-    return _forward(params, tokens, cfg, cache, block_tables, lengths, n_new,
-                    greedy_at(params, read))
+#: decoder.cached_pair has the contract; the greedy tokens are those of the
+#: columns the tick reads (``greedy_cached(.., read)``).
+apply_cached, greedy_cached = decoder.cached_pair(_forward, read=True)
 
 
 def param_count(cfg: LlamaConfig) -> int:

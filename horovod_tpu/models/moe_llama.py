@@ -18,12 +18,14 @@ chip holds a share is served by ``parallel/expert.py`` ``held_experts``
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 
+from . import decoder
 from . import layers as L
 from . import llama as Ll
 from ..parallel.expert import init_moe_params, moe_dense_reference
@@ -168,7 +170,7 @@ def dropfree_moe_fn(cfg: MoeLlamaConfig) -> Callable:
 
 # The attention half IS llama's, so the paged pool, its copy-on-write clone
 # and its sharding are too (they read n_layers, n_kv_heads, head_dim, dtype).
-init_cache, copy_blocks = Ll.init_cache, Ll.copy_blocks
+_pool, init_cache, copy_blocks = Ll._pool, Ll.init_cache, Ll.copy_blocks
 cache_shardings, BOUNDED_READ = Ll.cache_shardings, Ll.BOUNDED_READ
 #: ServeEngine ignores the third value of apply_cached, the router aux
 TICK_COUNTERS = ()
@@ -179,54 +181,34 @@ def attn_blocks(cfg: MoeLlamaConfig, S: int, C: int, ctx: int):
     return Ll.attn_blocks(_llama_cfg(cfg), S, C, ctx)
 
 
-def _forward(params, tokens, cfg, cache, block_tables, lengths, n_new,
-             moe_fn, head):
-    """llama._forward with the expert block for the FFN: (``head(t, x)``,
-    cache, mean router aux over the rows)."""
+def _forward(params, tokens, cfg, cache, block_tables, lengths, n_new, head,
+             moe_fn: Optional[Callable] = None):
+    """llama._forward with the expert block for the FFN (decoder.forward):
+    (``head``'s, cache, mean router aux over the rows).  ``moe_fn`` defaults
+    to the drop-free dense path — the batch-invariant serving routing."""
     lcfg = _llama_cfg(cfg)
     moe_fn = moe_fn if moe_fn is not None else dropfree_moe_fn(cfg)
     cos, sin = L.rope_freqs(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
-    t = Ll._tick(lcfg, cache, block_tables, lengths, n_new, tokens.shape[1])
-    x = L.embedding(params["embed"], t.take(tokens)).astype(cfg.dtype)
     auxes = []
-    for i, p in enumerate(params["layers"]):
+
+    def layer(i, p, x, cache, t):
         a, cache = Ll._attn_cached(
             p, L.rmsnorm(p["attn_norm"], x), lcfg, cos, sin,
             cache, i, block_tables, t)
         x = x + a
         y, aux = _moe_block(p["moe"], L.rmsnorm(p["ffn_norm"], x), cfg,
                             moe_fn)
-        x = x + y
         auxes.append(aux)
-    with jax.named_scope("head"):
-        return head(t, x), cache, jnp.mean(jnp.stack(auxes))
+        return x + y, cache
+    return decoder.forward(
+        layer, functools.partial(Ll._logits, params), _pool(cfg), params,
+        tokens, cfg, cache, block_tables, lengths, n_new, head,
+        max_seq=cfg.max_seq, reads=("pos",)) + (jnp.mean(jnp.stack(auxes)),)
 
 
-def apply_cached(params: Dict[str, Any], tokens: jax.Array,
-                 cfg: MoeLlamaConfig, cache: Dict[str, jax.Array],
-                 block_tables: jax.Array, lengths: jax.Array,
-                 n_new: jax.Array, moe_fn: Optional[Callable] = None
-                 ) -> tuple[jax.Array, Dict[str, jax.Array], jax.Array]:
-    """Mixed prefill/decode forward over the paged cache (the moe twin
-    of llama.apply_cached; same slot-table contract, the same packed rows).
-    Returns (logits [S, C, vocab], updated cache, mean router aux over the
-    rows).  ``moe_fn`` defaults to the drop-free dense path — the
-    batch-invariant serving routing."""
-    return _forward(params, tokens, cfg, cache, block_tables, lengths, n_new,
-                    moe_fn, lambda t, x: t.slab(Ll._logits(params, x)))
-
-
-def greedy_cached(params: Dict[str, Any], tokens: jax.Array,
-                  cfg: MoeLlamaConfig, cache: Dict[str, jax.Array],
-                  block_tables: jax.Array, lengths: jax.Array,
-                  n_new: jax.Array, read: jax.Array,
-                  moe_fn: Optional[Callable] = None
-                  ) -> tuple[jax.Array, Dict[str, jax.Array], jax.Array]:
-    """llama.greedy_cached's twin: (tokens int32 [S, W] — the greedy token
-    after each slot's columns ``read`` —, cache, mean router aux); the head
-    runs on those ``S * W`` rows alone."""
-    return _forward(params, tokens, cfg, cache, block_tables, lengths, n_new,
-                    moe_fn, Ll.greedy_at(params, read))
+#: llama's pair (decoder.cached_pair has the contract) with the mean router
+#: aux over the rows behind the cache; each takes ``moe_fn``.
+apply_cached, greedy_cached = decoder.cached_pair(_forward, read=True)
 
 
 def param_count(cfg: MoeLlamaConfig) -> int:

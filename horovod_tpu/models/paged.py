@@ -34,6 +34,12 @@ third in two forms (docs/serving.md#cache-kinds):
     verify row of which ``a`` drafts are accepted leaves the carry after
     row ``a`` where the next tick looks for it.  What lies behind the
     columns is the model's: ``[.., d]``, or a carry's ``[.., d_state, d]``.
+
+What a module declares of its kinds is enough for what every module needs of
+them: :func:`tick` does one tick's slot arithmetic for all of them, once
+before the layers (:class:`Tick`), and :func:`init_pools` and
+:func:`pool_shardings` make each kind's pool and its sharding from its
+``leaves`` (models/decoder.py has the frame round the layers).
 """
 
 from __future__ import annotations
@@ -55,11 +61,22 @@ class CacheKind(NamedTuple):
     which a tick reads back the ``state`` columns before its own (the
     module docstring sets the three side by side).  A module's
     ``cache_kinds(cfg)`` lists its kinds; its cache is a dict by ``name``,
-    and so are the block tables it is handed (a state kind has none)."""
-    name: str
+    and so are the block tables it is handed (a state kind has none).  A
+    module with ONE pool and no ``cache_kinds`` is the case of one kind
+    without a name (``name`` None): its cache is the pool itself and its
+    table the array (models/llama.py).
+
+    ``leaves`` says what the kind's pool holds, name -> the shape BEHIND
+    ``[layers, blocks, block_size]`` (a state kind: behind ``[layers, slots,
+    columns]``), in ``dtype`` where that is not the cache's own
+    (:func:`init_pools`).  A paged pool whose leaves are ``[heads,
+    head_dim]`` behind has a head axis to shard (:func:`pool_shardings`)."""
+    name: Optional[str]
     layers: int
     window: Optional[int] = None
     state: Optional[int] = None
+    leaves: Optional[Dict[str, Tuple[int, ...]]] = None
+    dtype: Any = None
 
 
 def state_columns(state: int, tick_cols: int) -> int:
@@ -226,6 +243,84 @@ def write_index(block_tables: jax.Array, positions: jax.Array,
     return blk, positions % block_size
 
 
+def _of(tree: Any, kind: CacheKind) -> Any:
+    """``kind``'s part of a cache, of block tables or of block counts: the
+    entry by its name, or all of it for the one kind without a name."""
+    return tree if kind.name is None else tree[kind.name]
+
+
+class Tick(NamedTuple):
+    """What the layers of one tick share (:func:`tick`).  The last six are
+    None unless the family reads them."""
+    positions: jax.Array    # [S, C] (slot_positions)
+    lengths: jax.Array      # [S] positions a slot held before the tick
+    n_new: jax.Array        # [S]
+    take: Callable          # [S, C, ...] -> rows [1, R, ...] (pack)
+    slab: Slab              # rows -> [S, C, ...] (or a block of it, or the
+                            # columns a tick reads), zero where left out
+    # by the name of a kind: where the rows land in a paged or a ring kind's
+    # pool, (blk, off) for write (write_index), and in a state kind's,
+    # (slot, col) (state_index)
+    where: Dict[Optional[str], Tuple[jax.Array, jax.Array]]
+    lands: Dict[Optional[str], Tuple[jax.Array, jax.Array]]
+    valid: Optional[jax.Array] = None   # the rows that hold a token
+    pos: Optional[jax.Array] = None     # the rows' positions, inside the
+                                        # rope table
+    top: Optional[jax.Array] = None     # [S] a slot's last written position
+    # by row: whose slot a row is, what state_read asks (slot, position, the
+    # slot's length; flat [N]), and where a slot's rows begin
+    slot: Optional[jax.Array] = None
+    row: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None
+    first: Optional[jax.Array] = None
+
+
+def tick(kinds: Tuple[CacheKind, ...], cache: Any, tables: Any,
+         lengths: jax.Array, n_new: jax.Array, C: int, rows: int = 0,
+         max_seq: Optional[int] = None, reads: Tuple[str, ...] = ()) -> Tick:
+    """One ``[S, C]`` tick's slot arithmetic, done once for all layers and
+    for every kind of cache the module declares: where each position lies
+    (:func:`slot_positions`), the ``rows`` the tick's tokens are packed to
+    (:func:`pack`; the config's ``max_tick_tokens``), and where the rows
+    land in each of ``kinds`` — a ring where ``kind.window``, a slot's
+    column where ``kind.state``.  ``cache`` and ``tables`` are the module's
+    own, dicts by kind or the one pool and its table.
+
+    ``reads`` names what else the family's mixers read of :class:`Tick`,
+    and is worked out here too, in that order, before any layer: a field
+    first made inside a layer's loop or branch would be a value of that
+    trace alone.  ``pos`` needs ``max_seq``, the rope table's length."""
+    positions, valid = slot_positions(lengths, n_new, C)
+    take, slab = pack(valid, rows)
+    where, lands = {}, {}
+    for kind in kinds:
+        pool = jax.tree_util.tree_leaves(_of(cache, kind))[0]
+        if kind.state is None:
+            blk, off = write_index(_of(tables, kind), positions, valid,
+                                   *pool.shape[1:3],
+                                   ring=kind.window is not None)
+            where[kind.name] = (take(blk), take(off))
+        else:
+            slot, col = state_index(lengths, n_new, valid, positions,
+                                    pool.shape[2])
+            lands[kind.name] = (take(slot), take(col))
+    wide = lambda a: take(jnp.broadcast_to(a[:, None], positions.shape))
+    slots = lambda: wide(jnp.arange(lengths.shape[0], dtype=jnp.int32))
+    read: Dict[str, Any] = {}
+    how = {
+        "valid": lambda: take(valid),
+        "pos": lambda: take(jnp.minimum(positions, max_seq - 1)),
+        "top": lambda: lengths + n_new - 1,
+        "slot": slots,
+        "row": lambda: ((read["slot"] if "slot" in read else slots()
+                         ).reshape(-1), take(positions).reshape(-1),
+                        wide(lengths).reshape(-1)),
+        "first": lambda: take(positions) == wide(lengths),
+    }
+    for name in reads:
+        read[name] = how[name]()
+    return Tick(positions, lengths, n_new, take, slab, where, lands, **read)
+
+
 def write(pool: Any, layer: int, blk: jax.Array, off: jax.Array,
           values: Any) -> Any:
     """Scatter ``values`` (a pytree like ``pool``) into layer ``layer`` of
@@ -307,6 +402,21 @@ def slots_per_block(S: int, per_slot_bytes: int, budget: int) -> int:
 #: itself more often.
 TILE = 256
 NARROW_SLOTS = 2
+#: columns a block of decode rows attends with in a chunk-wide tick: the
+#: number every served module declares as its ``NARROW_COLS``
+NARROW_COLS = 8
+
+
+def attn_blocks(heads: int, S: int, C: int, ctx: int, score_bytes: int,
+                narrow_cols: int) -> Tuple[int, int]:
+    """(slots a block, narrow columns) of a cached attention of ``heads``
+    query heads in a ``[S, C]`` tick over ``ctx`` gathered positions
+    (:func:`attend_by_blocks`; the engine's ``wide_blocks_share`` reads a
+    plan by the same two): as many slots as keep a block's float32 scores
+    (heads x columns x context x 4 B a slot) within ``score_bytes``.  A
+    module's ``attn_blocks`` hands in its own ``SCORE_BYTES`` and
+    ``NARROW_COLS`` as they stand when it is called."""
+    return slots_per_block(S, heads * C * ctx * 4, score_bytes), narrow_cols
 
 
 def tile_blocks(block_size: int, max_blocks: int) -> int:
@@ -587,6 +697,49 @@ def shardings(mesh, num_blocks: int, head_axis_size: Optional[int] = None):
     if head_axis_size is None:
         return NamedSharding(mesh, P(None, block_axis, None, None))
     return NamedSharding(mesh, P(None, block_axis, None, head_axis, None))
+
+
+def init_pools(kinds: Tuple[CacheKind, ...], num_blocks: Any,
+               block_size: int, dtype) -> Any:
+    """One preallocated pool a kind, by what each declares
+    (``CacheKind.leaves``): a paged or a ring kind's leaves are ``[kind's
+    layers, num_blocks[kind], block_size, ...]``, a state kind's ``[kind's
+    layers, slots, columns, ...]``, its ``num_blocks`` being ``(slots,
+    columns)``; in ``dtype`` unless the kind names its own.  ``{kind:
+    {leaf: array}}``, or the one pool of a kind without a name (whose
+    ``num_blocks`` is the number)."""
+    def pool(kind):
+        n = _of(num_blocks, kind)
+        lead = (kind.layers,) + (tuple(n) if kind.state is not None
+                                 else (n, block_size))
+        return {name: jnp.zeros(lead + tuple(behind), kind.dtype or dtype)
+                for name, behind in kind.leaves.items()}
+    pools = {kind.name: pool(kind) for kind in kinds}
+    return pools[None] if None in pools else pools
+
+
+def pool_shardings(mesh, kinds: Tuple[CacheKind, ...], num_blocks: Any):
+    """:func:`shardings` of each of ``kinds``' pools (``{kind: sharding}``,
+    or the one of a kind without a name): a paged pool's blocks and a
+    state's slots over the data axis, each pool by its own number of them,
+    and the heads of a paged pool that has a head axis (``[heads,
+    head_dim]`` behind) over a model axis."""
+    def one(kind):
+        n = _of(num_blocks, kind)
+        if kind.state is not None:
+            return shardings(mesh, n[0])
+        behind = list(kind.leaves.values())
+        return shardings(mesh, n, behind[0][0] if all(
+            len(b) == 2 for b in behind) else None)
+    out = {kind.name: one(kind) for kind in kinds}
+    return out[None] if None in out else out
+
+
+def layer_of_kind(kind_of: Callable[[int], str], i: int) -> Tuple[str, int]:
+    """(cache kind of layer i, its index among that kind's layers, which is
+    its layer in the kind's pool), ``kind_of(j)`` naming layer j's kind."""
+    kind = kind_of(i)
+    return kind, sum(kind_of(j) == kind for j in range(i))
 
 
 def leaf_key(path) -> str:

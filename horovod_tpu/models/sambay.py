@@ -50,14 +50,16 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from . import decoder
 from . import layers as L
 from . import paged
+from .paged import NARROW_COLS
 
 
 @dataclasses.dataclass(frozen=True)
@@ -128,8 +130,6 @@ CONFIGS = {
 #: float32 scores one block of slots may hold (query heads x columns x keys
 #: x 4 B a slot): a chunk-wide tick attends a slot at a time in either kind
 SCORE_BYTES = 32 << 20
-#: columns a block of decode rows attends with in a chunk-wide tick
-NARROW_COLS = 8
 #: the names of the four cache kinds
 KV, WINDOW, CONV, CARRY = "kv", "window", "conv", "carry"
 #: the full layer's and the cross layers' reads go as far as a slot's context
@@ -417,15 +417,21 @@ def cache_kinds(cfg: SambaYConfig) -> Tuple[paged.CacheKind, ...]:
     contexts, the window layers' rings, and of the state-space layers the
     convolution's last ``d_conv - 1`` inputs and the scan's one carry."""
     n = cfg.count(MAMBA)
-    return (paged.CacheKind(KV, cfg.count(FULL)),
-            paged.CacheKind(WINDOW, cfg.count(SWA), cfg.window),
-            paged.CacheKind(CONV, n, state=cfg.d_conv - 1),
-            paged.CacheKind(CARRY, n, state=1))
+    # a position's heads side by side, lanes-minor; the carry ALWAYS float32
+    heads = (cfg.n_kv_heads * cfg.head_dim,)
+    return (paged.CacheKind(KV, cfg.count(FULL),
+                            leaves={"k": heads, "v": heads}),
+            paged.CacheKind(WINDOW, cfg.count(SWA), cfg.window,
+                            leaves={"k": heads, "v": heads}),
+            paged.CacheKind(CONV, n, state=cfg.d_conv - 1,
+                            leaves={"u": (cfg.d_inner,)}),
+            paged.CacheKind(CARRY, n, state=1, dtype=jnp.float32,
+                            leaves={"h": (cfg.d_state, cfg.d_inner)}))
 
 
 def _index_in_kind(cfg: SambaYConfig, i: int) -> int:
     """Layer i's index among the layers of its kind."""
-    return sum(cfg.kind(j) == cfg.kind(i) for j in range(i))
+    return paged.layer_of_kind(cfg.kind, i)[1]
 
 
 def init_cache(cfg: SambaYConfig, num_blocks: Dict[str, Any],
@@ -436,30 +442,14 @@ def init_cache(cfg: SambaYConfig, num_blocks: Dict[str, Any],
     ``[state-space layers, slots, columns, d_inner]`` and ``{CARRY: {"h"}}``
     of ``[.., slots, columns, d_state, d_inner]``, ALWAYS float32, the state
     kinds' ``num_blocks`` being ``(slots, columns)``."""
-    dtype = dtype if dtype is not None else cfg.dtype
-    out = {}
-    for kind in cache_kinds(cfg):
-        n = num_blocks[kind.name]
-        if kind.name == CONV:
-            out[CONV] = {"u": jnp.zeros(
-                (kind.layers,) + tuple(n) + (cfg.d_inner,), dtype)}
-        elif kind.name == CARRY:
-            out[CARRY] = {"h": jnp.zeros(
-                (kind.layers,) + tuple(n) + (cfg.d_state, cfg.d_inner),
-                jnp.float32)}
-        else:
-            shape = (kind.layers, n, block_size,
-                     cfg.n_kv_heads * cfg.head_dim)
-            out[kind.name] = {"k": jnp.zeros(shape, dtype),
-                              "v": jnp.zeros(shape, dtype)}
-    return out
+    return paged.init_pools(cache_kinds(cfg), num_blocks, block_size,
+                            dtype if dtype is not None else cfg.dtype)
 
 
 def cache_shardings(mesh, cfg: SambaYConfig, num_blocks: Dict[str, Any]):
     """{kind: sharding}: the paged pools' blocks and the states' slots over
     the data axis."""
-    return {name: paged.shardings(mesh, n[0] if name in (CONV, CARRY) else n)
-            for name, n in num_blocks.items()}
+    return paged.pool_shardings(mesh, cache_kinds(cfg), num_blocks)
 
 
 #: Nothing to clone (paged.no_prefix_blocks): the engine refuses prefix
@@ -471,51 +461,11 @@ def attn_blocks(cfg: SambaYConfig, S: int, C: int, ctx: int
                 ) -> Tuple[int, int]:
     """(slots a block, narrow columns) of the cached attention in a
     ``[S, C]`` tick over ``ctx`` gathered positions, either kind."""
-    return (paged.slots_per_block(S, cfg.n_heads * C * ctx * 4, SCORE_BYTES),
-            NARROW_COLS)
+    return paged.attn_blocks(cfg.n_heads, S, C, ctx, SCORE_BYTES,
+                             NARROW_COLS)
 
 
-class _Tick(NamedTuple):
-    """What the layers of one tick share."""
-    positions: jax.Array    # [S, C] (paged.slot_positions)
-    lengths: jax.Array      # [S] positions a slot held before the tick
-    n_new: jax.Array        # [S]
-    top: jax.Array          # [S] a slot's last written position
-    take: Callable          # [S, C, ...] -> rows [1, R, ...] (paged.pack)
-    slab: paged.Slab        # rows -> [S, C, ...], zero where left out
-    where: Dict[str, Tuple[jax.Array, jax.Array]]   # kind -> rows' (blk, off)
-    # the state kinds, by row: where a row's conv input and carry land
-    # (paged.state_index), what paged.state_read asks (slot, position, the
-    # slot's length), flat [N], and by row where a slot's rows begin and
-    # whose they are
-    lands: Dict[str, Tuple[jax.Array, jax.Array]]
-    row: Tuple[jax.Array, jax.Array, jax.Array]
-    first: jax.Array
-    slot: jax.Array
-
-
-def _tick(cfg, cache, tables, lengths, n_new, C) -> _Tick:
-    positions, valid = paged.slot_positions(lengths, n_new, C)
-    take, slab = paged.pack(valid, cfg.max_tick_tokens)
-    where, lands = {}, {}
-    for kind in (KV, WINDOW):
-        blk, off = paged.write_index(
-            tables[kind], positions, valid, *cache[kind]["k"].shape[1:3],
-            ring=kind == WINDOW)
-        where[kind] = (take(blk), take(off))
-    for kind, leaf in ((CONV, "u"), (CARRY, "h")):
-        slot, col = paged.state_index(lengths, n_new, valid, positions,
-                                      cache[kind][leaf].shape[2])
-        lands[kind] = (take(slot), take(col))
-    wide = lambda a: take(jnp.broadcast_to(a[:, None], positions.shape))
-    slot = wide(jnp.arange(lengths.shape[0], dtype=jnp.int32))
-    row = (slot.reshape(-1), take(positions).reshape(-1),
-           wide(lengths).reshape(-1))
-    return _Tick(positions, lengths, n_new, lengths + n_new - 1, take, slab,
-                 where, lands, row, take(positions) == wide(lengths), slot)
-
-
-def _mamba_cached(p, a, cfg, j, cache, t: _Tick):
+def _mamba_cached(p, a, cfg, j, cache, t: paged.Tick):
     """State-space layer (the state kinds' j-th) on the tick's rows a: the
     convolution reads its earlier inputs as models/conv_moe.py's does
     (paged.state_read), the scan starts each slot's rows from the carry its
@@ -557,7 +507,7 @@ def _shared_tile(q, pos, ctx, start):
                      paged.context_mask(pos - start, ctx["k"].shape[1]))
 
 
-def _shared_read(q, cfg, cache, tables, t: _Tick):
+def _shared_read(q, cfg, cache, tables, t: paged.Tick):
     """Queries (rows, by head) against the ONE full layer's pool, as far as
     each slot's context reaches: [S, C, G, rep, 2, 2 hd]."""
     pool = cache[KV]
@@ -569,7 +519,7 @@ def _shared_read(q, cfg, cache, tables, t: _Tick):
     return jnp.moveaxis(o, 4, 1)
 
 
-def _attn_cached(p, a, cfg, i, cache, tables, t: _Tick):
+def _attn_cached(p, a, cfg, i, cache, tables, t: paged.Tick):
     """Attention layer i on the tick's rows a.  ``swa``: the rows' k/v go
     into the layer's ring (as models/swa_moe.py), then each slot attends
     the blocks of it that its queries' windows reach.  ``full``: they go into the
@@ -620,54 +570,34 @@ def _attn_cached(p, a, cfg, i, cache, tables, t: _Tick):
 
 
 def _forward(params, tokens, cfg, cache, tables, lengths, n_new, head):
-    """The tick's rows through the stack: (``head(t, x)`` of the tick and
-    its rows' last hidden states, under the ``head`` scope; cache)."""
-    t = _tick(cfg, cache, tables, lengths, n_new, tokens.shape[1])
-    with jax.named_scope("embed"):
-        x = L.embedding(params["embed"], t.take(tokens)).astype(cfg.dtype)
-    m = None
-    for i, p in enumerate(params["layers"][:cfg.n_layers]):
+    """The tick's rows through the stack (decoder.forward): the mixer of
+    the layer's kind, then the gated FFN; what the LAST state-space layer
+    scanned is handed down the tick to the gated memory units."""
+    memory = {}
+
+    def layer(i, p, x, cache, t):
         a, kind = _norm(p["mix_norm"], x, cfg), cfg.kind(i)
         if kind == MAMBA:
             y, scanned, cache = _mamba_cached(
                 p["mamba"], a, cfg, _index_in_kind(cfg, i), cache, t)
-            m = scanned if i == cfg.half else m
+            if i == cfg.half:
+                memory["m"] = scanned
         elif kind == GMU:
-            y = _gmu(p["gmu"], a, m)
+            y = _gmu(p["gmu"], a, memory["m"])
         else:
             y, cache = _attn_cached(p["attn"], a, cfg, i, cache, tables, t)
         x = x + y
-        x = x + _ffn(p["ffn"], _norm(p["ffn_norm"], x, cfg))
-    with jax.named_scope("head"):
-        return head(t, x), cache
+        return x + _ffn(p["ffn"], _norm(p["ffn_norm"], x, cfg)), cache
+    return decoder.forward(
+        layer, lambda x: _logits(params, x, cfg), cache_kinds(cfg), params,
+        tokens, cfg, cache, tables, lengths, n_new, head,
+        reads=("slot", "row", "top", "first"))
 
 
-def apply_cached(params: Dict[str, Any], tokens: jax.Array,
-                 cfg: SambaYConfig, cache: Dict[str, Any],
-                 block_tables: Dict[str, jax.Array], lengths: jax.Array,
-                 n_new: jax.Array):
-    """Mixed prefill/decode forward over the four kinds of cache; the
-    slot-table contract of llama.apply_cached with ``cache`` a dict by kind
-    and ``block_tables`` ``{KV: table, WINDOW: ring table}``.  Returns
-    (logits [S, C, vocab], zero at positions that were not packed; updated
-    cache)."""
-    return _forward(params, tokens, cfg, cache, block_tables, lengths, n_new,
-                    lambda t, x: t.slab(_logits(params, x, cfg)))
-
-
-def greedy_cached(params: Dict[str, Any], tokens: jax.Array,
-                  cfg: SambaYConfig, cache: Dict[str, Any],
-                  block_tables: Dict[str, jax.Array], lengths: jax.Array,
-                  n_new: jax.Array, read: jax.Array):
-    """:func:`apply_cached` for the serving tick: (tokens int32 [S, W],
-    cache), the greedy token after column ``read[s, j]`` of slot s, as
-    models/llama.py ``greedy_cached``: the final norm, the head and the
-    float32 argmax run on those ``S * W`` rows alone."""
-    return _forward(
-        params, tokens, cfg, cache, block_tables, lengths, n_new,
-        lambda t, x: jnp.argmax(
-            _logits(params, t.slab.at(x, read), cfg).astype(jnp.float32),
-            axis=-1).astype(jnp.int32))
+#: decoder.cached_pair has the contract: ``cache`` is a dict by kind,
+#: ``block_tables`` ``{KV: table, WINDOW: ring table}``, the greedy tokens
+#: those of the columns the tick reads.
+apply_cached, greedy_cached = decoder.cached_pair(_forward, read=True)
 
 
 def param_count(cfg: SambaYConfig) -> int:

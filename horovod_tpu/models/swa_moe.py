@@ -33,14 +33,18 @@ the tick's largest array, so the module gives the engine
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, NamedTuple, Tuple
+import functools
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from . import decoder
 from . import layers as L
 from . import paged
+from .paged import NARROW_COLS
 from ..parallel import expert as X
+from ..parallel.expert import EXPERT_TILE
 
 
 @dataclasses.dataclass(frozen=True)
@@ -88,13 +92,9 @@ CONFIGS = {
                          top_k=2, max_seq=128),
 }
 
-#: rows of one expert's tile (parallel/expert.py held_experts)
-EXPERT_TILE = 64
 #: float32 scores one block of slots may hold (heads x columns x keys x 4 B
 #: a slot): a chunk-wide tick attends a slot at a time in either kind
 SCORE_BYTES = 256 << 20
-#: columns a block of decode rows attends with in a chunk-wide tick
-NARROW_COLS = 8
 #: the names of the two cache kinds
 GLOBAL, WINDOW = "global", "window"
 
@@ -129,10 +129,6 @@ def init(key, cfg: SwaMoeConfig) -> Dict[str, Any]:
 
 
 # ------------------------------------------------------------------ pieces
-def _norm(p, x, cfg):
-    return L.rmsnorm(p, x, eps=cfg.norm_eps)
-
-
 def _route(p, h, cfg):
     """The routing decision, from the ATTENTION's input h [.., D]:
     (idx [T, k], gates [T, k])."""
@@ -144,25 +140,8 @@ def _route(p, h, cfg):
 def _experts(p, h2, valid, routing, cfg):
     """The held experts' part on h2 [B, S, D] under ``routing``:
     (y [B, S, D], counters)."""
-    B, S, D = h2.shape
-    y, counters = X.held_experts(
-        p["moe"], h2.reshape(B * S, D), valid.reshape(B * S),
-        first=cfg.first_expert, routing=routing, act=jax.nn.relu,
-        tile=EXPERT_TILE)
-    with jax.named_scope("moe/combine"):
-        return y.reshape(B, S, D).astype(h2.dtype), counters
-
-
-def _qkv(p, h, cfg, i, cos, sin, positions):
-    """Layer i's projections of h [B, S, D] by head, rotated at
-    ``positions`` [B, S] where the layer has a rotary encoding."""
-    heads = lambda w, n: L.dense(p[w], h).reshape(
-        h.shape[:2] + (n, cfg.head_dim))
-    q, k = heads("wq", cfg.n_heads), heads("wk", cfg.n_kv_heads)
-    if cfg.rotary(i):
-        q = L.apply_rope_at(q, cos, sin, positions)
-        k = L.apply_rope_at(k, cos, sin, positions)
-    return q, k, heads("wv", cfg.n_kv_heads)
+    return X.held_ffn(p["moe"], h2, valid, first=cfg.first_expert,
+                      routing=routing, act=jax.nn.relu, tile=EXPERT_TILE)
 
 
 # ------------------------------------------------------- full-sequence path
@@ -180,17 +159,18 @@ def apply(params: Dict[str, Any], ids: jax.Array, cfg: SwaMoeConfig,
     valid = jnp.ones((B, S), bool)
     x = L.embedding(params["embed"], ids).astype(cfg.dtype)
     for i, p in enumerate(params["layers"]):
-        h = _norm(p["input_norm"], x, cfg)
+        h = L.norm(p["input_norm"], x, cfg)
         routing = _route(p, h, cfg)
-        q, k, v = _qkv(p["attn"], h, cfg, i, cos, sin, rot)
+        q, k, v = L.qkv(p["attn"], h, cfg, cos, sin, rot,
+                        rotary=cfg.rotary(i))
         mask = (paged.window_mask(positions, positions, cfg.window)
                 if cfg.windowed(i) else None)
         o = L.causal_attention(q, k, v, causal=mask is None, mask=mask)
         x = x + L.dense(p["attn"]["wo"], o.reshape(B, S, -1))
-        y, _ = _experts(p, _norm(p["post_attn_norm"], x, cfg), valid,
+        y, _ = _experts(p, L.norm(p["post_attn_norm"], x, cfg), valid,
                         routing, cfg)
         x = x + y
-    return L.dense(params["lm_head"], _norm(params["final_norm"], x, cfg))
+    return _logits(params, cfg, x)
 
 
 # ------------------------------------------------------------- decode path
@@ -198,36 +178,25 @@ def cache_kinds(cfg: SwaMoeConfig) -> Tuple[paged.CacheKind, ...]:
     """The kinds of cache this stack keeps, those with layers only: the
     global layers' whole contexts, the window layers' rings."""
     n_win = sum(cfg.windowed(i) for i in range(cfg.n_layers))
-    kinds = (paged.CacheKind(GLOBAL, cfg.n_layers - n_win),
-             paged.CacheKind(WINDOW, n_win, cfg.window))
+    behind = (cfg.n_kv_heads, cfg.head_dim)
+    leaves = {"k": behind, "v": behind}
+    kinds = (paged.CacheKind(GLOBAL, cfg.n_layers - n_win, leaves=leaves),
+             paged.CacheKind(WINDOW, n_win, cfg.window, leaves=leaves))
     return tuple(k for k in kinds if k.layers)
-
-
-def _kind_of(cfg: SwaMoeConfig, i: int) -> Tuple[str, int]:
-    """(cache kind of layer i, its index among that kind's layers)."""
-    w = cfg.windowed(i)
-    return (WINDOW if w else GLOBAL,
-            sum(cfg.windowed(j) == w for j in range(i)))
 
 
 def init_cache(cfg: SwaMoeConfig, num_blocks: Dict[str, int],
                block_size: int, dtype=None) -> Dict[str, Dict[str, jax.Array]]:
     """One paged pool a kind, ``{kind: {"k", "v"}}`` of ``[kind's layers,
     num_blocks[kind], block_size, n_kv_heads, head_dim]``."""
-    dtype = dtype if dtype is not None else cfg.dtype
-
-    def pool(kind):
-        shape = (kind.layers, num_blocks[kind.name], block_size,
-                 cfg.n_kv_heads, cfg.head_dim)
-        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-    return {kind.name: pool(kind) for kind in cache_kinds(cfg)}
+    return paged.init_pools(cache_kinds(cfg), num_blocks, block_size,
+                            dtype if dtype is not None else cfg.dtype)
 
 
 def cache_shardings(mesh, cfg: SwaMoeConfig, num_blocks: Dict[str, int]):
     """{kind: sharding}: blocks over the data axis, kv heads over a model
     axis, each pool by its own number of blocks."""
-    return {name: paged.shardings(mesh, n, cfg.n_kv_heads)
-            for name, n in num_blocks.items()}
+    return paged.pool_shardings(mesh, cache_kinds(cfg), num_blocks)
 
 
 copy_blocks = paged.no_prefix_blocks
@@ -237,46 +206,21 @@ def attn_blocks(cfg: SwaMoeConfig, S: int, C: int, ctx: int
                 ) -> Tuple[int, int]:
     """(slots a block, narrow columns) of the cached attention in a
     ``[S, C]`` tick over ``ctx`` gathered positions, either kind."""
-    return (paged.slots_per_block(S, cfg.n_heads * C * ctx * 4, SCORE_BYTES),
-            NARROW_COLS)
+    return paged.attn_blocks(cfg.n_heads, S, C, ctx, SCORE_BYTES,
+                             NARROW_COLS)
 
 
-class _Tick(NamedTuple):
-    """What the layers of one tick share."""
-    positions: jax.Array    # [S, C] (paged.slot_positions)
-    n_new: jax.Array        # [S]
-    top: jax.Array          # [S] a slot's last written position
-    take: Callable          # [S, C, ...] -> rows [1, R, ...] (paged.pack)
-    slab: Callable          # rows -> [S, C, ...], zero where left out
-    valid: jax.Array        # the rows that hold a token
-    pos: jax.Array          # the rows' positions, inside the rope table
-    where: Dict[str, Tuple[jax.Array, jax.Array]]   # kind -> rows' (blk, off)
-
-
-def _tick(cfg, cache, tables, lengths, n_new, C) -> _Tick:
-    positions, valid = paged.slot_positions(lengths, n_new, C)
-    take, slab = paged.pack(valid, cfg.max_tick_tokens)
-    where = {}
-    for kind in cache_kinds(cfg):
-        blk, off = paged.write_index(
-            tables[kind.name], positions, valid,
-            *cache[kind.name]["k"].shape[1:3], ring=kind.window is not None)
-        where[kind.name] = (take(blk), take(off))
-    return _Tick(positions, n_new, lengths + n_new - 1, take, slab,
-                 take(valid), take(jnp.minimum(positions, cfg.max_seq - 1)),
-                 where)
-
-
-def _attn_cached(p, h, cfg, i, cos, sin, cache, tables, t: _Tick):
+def _attn_cached(p, h, cfg, i, cos, sin, cache, tables, t: paged.Tick):
     """Layer i's attention over its kind's pool, in place (the pools stay
     stacked, as models/llama.py keeps its one): the rows' k/v are scattered
     in FIRST, then the queries go back to their slots and each attends over
     what its kind gathers — the slot's whole context under the causal mask,
     or its ring under the window's — a block of slots after another, only
     the blocks that hold a chunk at chunk width (paged.attend_by_blocks)."""
-    kind, j = _kind_of(cfg, i)
+    kind, j = paged.layer_of_kind(
+        lambda j: WINDOW if cfg.windowed(j) else GLOBAL, i)
     rows = h.shape[:2]
-    q, k, v = _qkv(p, h, cfg, i, cos, sin, t.pos)
+    q, k, v = L.qkv(p, h, cfg, cos, sin, t.pos, rotary=cfg.rotary(i))
     with jax.named_scope("attn/" + kind):
         pool = paged.write(cache[kind], j, *t.where[kind], {"k": k, "v": v})
         cache = dict(cache, **{kind: pool})
@@ -295,55 +239,37 @@ def _attn_cached(p, h, cfg, i, cos, sin, cache, tables, t: _Tick):
     return L.dense(p["wo"], t.take(o).reshape(rows + (-1,))), cache
 
 
+def _logits(params, cfg, x):
+    """The final norm and the output head on hidden states ``[.., dim]``."""
+    return L.dense(params["lm_head"], L.norm(params["final_norm"], x, cfg))
+
+
 def _forward(params, tokens, cfg, cache, tables, lengths, n_new, head):
-    """The tick's rows through the stack: (head(rows' hidden states
-    [1, R, D]) back in the slab [S, C, ...], cache, counters)."""
+    """The tick's rows through the stack (decoder.forward): the router
+    reads the attention's input, its decision is applied after the
+    attention."""
     cos, sin = L.rope_freqs(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
-    t = _tick(cfg, cache, tables, lengths, n_new, tokens.shape[1])
-    with jax.named_scope("embed"):
-        x = L.embedding(params["embed"], t.take(tokens)).astype(cfg.dtype)
-    counters = jnp.zeros(len(X.HELD_COUNTERS), jnp.int32)
-    for i, p in enumerate(params["layers"]):
-        h = _norm(p["input_norm"], x, cfg)
+
+    def layer(i, p, x, cache, t):
+        h = L.norm(p["input_norm"], x, cfg)
         routing = _route(p, h, cfg)
         a, cache = _attn_cached(p["attn"], h, cfg, i, cos, sin, cache,
                                 tables, t)
         x = x + a
-        y, c = _experts(p, _norm(p["post_attn_norm"], x, cfg), t.valid,
+        y, c = _experts(p, L.norm(p["post_attn_norm"], x, cfg), t.valid,
                         routing, cfg)
-        x = x + y
-        counters = counters + c     # load_max too: a sum over the layers
-    with jax.named_scope("head"):
-        out = t.slab(head(L.dense(params["lm_head"],
-                                  _norm(params["final_norm"], x, cfg))))
-    return out, cache, jnp.concatenate([jnp.ones(1, jnp.int32), counters])
+        return x + y, cache, c
+    return decoder.forward(
+        layer, functools.partial(_logits, params, cfg), cache_kinds(cfg),
+        params, tokens, cfg, cache, tables, lengths, n_new, head,
+        counters=TICK_COUNTERS, max_seq=cfg.max_seq,
+        reads=("top", "valid", "pos"))
 
 
-def apply_cached(params: Dict[str, Any], tokens: jax.Array,
-                 cfg: SwaMoeConfig, cache: Dict[str, Any],
-                 block_tables: Dict[str, jax.Array], lengths: jax.Array,
-                 n_new: jax.Array):
-    """Mixed prefill/decode forward over both kinds of cache; the slot-table
-    contract of llama.apply_cached with ``cache`` and ``block_tables`` dicts
-    by kind (the window kind's table is a ring).  Returns (logits [S, C,
-    vocab], zero at positions that were not packed; updated cache; counters
-    int32[len(TICK_COUNTERS)] summed over the layers)."""
-    return _forward(params, tokens, cfg, cache, block_tables, lengths, n_new,
-                    lambda logits: logits)
-
-
-def greedy_cached(params: Dict[str, Any], tokens: jax.Array,
-                  cfg: SwaMoeConfig, cache: Dict[str, Any],
-                  block_tables: Dict[str, jax.Array], lengths: jax.Array,
-                  n_new: jax.Array):
-    """:func:`apply_cached` with the greedy token in place of the logits:
-    (next tokens int32 [S, C], cache, counters).  The argmax is taken on the
-    packed rows ``[1, R, vocab]``; what comes back to the slab is an id a
-    position (ServeEngine samples through this where a module has it)."""
-    return _forward(
-        params, tokens, cfg, cache, block_tables, lengths, n_new,
-        lambda logits: jnp.argmax(logits.astype(jnp.float32),
-                                  axis=-1).astype(jnp.int32))
+#: decoder.cached_pair has the contract: ``cache`` and ``block_tables`` are
+#: dicts by kind (the window kind's table is a ring), the third value the
+#: counters summed over the layers, the greedy token every position's.
+apply_cached, greedy_cached = decoder.cached_pair(_forward)
 
 
 def param_count(cfg: SwaMoeConfig) -> int:

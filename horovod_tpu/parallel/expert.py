@@ -313,6 +313,9 @@ def route_softmax_topk(x, router_kernel, k: int):
 
 
 #: what ``held_experts`` counts, in the order of its counter vector
+#: rows of one expert's tile (:func:`held_experts`): the number every model
+#: that serves held experts declares as its ``EXPERT_TILE``
+EXPERT_TILE = 64
 HELD_COUNTERS = ("assignments", "assignments_held", "experts_touched",
                  "load_max")
 
@@ -390,7 +393,25 @@ def held_experts(p: Dict[str, Any], x, valid, *, first: int, k: int = 0,
     return y, counters
 
 
+def held_ffn(p: Dict[str, Any], h, valid, *, route=None, **held):
+    """:func:`held_experts` as a layer's feed-forward part on hidden states
+    ``h`` [B, S, D] (``valid`` [B, S]): (y [B, S, D] in ``h``'s type,
+    counters).  ``route(rows [T, D])`` -> ``(idx, gates)`` is the model's
+    router, run here on what the experts read (under ``moe/route``); without
+    one the decision is ``held``'s ``routing``, made elsewhere, or the
+    layer's own.  ``held`` is :func:`held_experts`'s: ``first``, ``act``,
+    ``tile`` ..."""
+    B, S, D = h.shape
+    rows = h.reshape(B * S, D)
+    if route is not None:
+        with jax.named_scope("moe/route"):
+            held["routing"] = route(rows)
+    y, counters = held_experts(p, rows, valid.reshape(B * S), **held)
+    with jax.named_scope("moe/combine"):
+        return y.reshape(B, S, D).astype(h.dtype), counters
+
+
 __all__ = ["make_moe_fn", "init_moe_params", "moe_shardings",
            "moe_dense_reference", "gated_ffn", "tile_ffn", "init_held_experts",
            "route_sigmoid_topk", "route_softmax_topk", "held_experts",
-           "HELD_COUNTERS"]
+           "held_ffn", "HELD_COUNTERS", "EXPERT_TILE"]

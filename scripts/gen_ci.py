@@ -90,6 +90,7 @@ SUITES = {
                 "tests/test_greedy_read.py",
                 "tests/test_kv_shard.py", "tests/test_scenario.py",
                 "tests/test_latent_moe.py", "tests/test_paged.py",
+                "tests/test_decoder.py",
                 "tests/test_swa_moe.py", "tests/test_conv_moe.py",
                 "tests/test_blockdiff_moe.py",
                 "tests/test_serve_blockdiff.py",

@@ -293,7 +293,16 @@ def test_composed_core_bit_near():
     """Fast-tier slice of the composition matrix: the full (2, 2, 2)
     mesh at level 2 against the dp-only composed reference at level 1 —
     losses track the pure reference and final params agree to float32
-    accumulation-order noise."""
+    accumulation-order noise.
+
+    The params' tolerance is 5e-4, a twentieth of ONE of the three Adam
+    steps (lr 1e-2), and not the 1e-4 the losses' noise would suggest: Adam
+    divides a gradient by the root of its second moment, so where a
+    gradient element is near zero the 1e-8 by which two orders of a float32
+    sum differ is a few percent of the normalised step.  The two meshes
+    reduce in different orders, and one element of 16,384 then lands 1.6e-4
+    apart after three steps (every run since PR 44); a wrong gradient moves
+    an element by whole steps of 1e-2."""
     ref_loss = float(Ll.loss_fn(Ll.init(jax.random.PRNGKey(0), CFG),
                                 _ids(seed=1), CFG))
     base_losses, base_p = _train_llama(8, 1, 1, level=1)
@@ -304,7 +313,7 @@ def test_composed_core_bit_near():
     for a, b in zip(_flat_leaves(p), _flat_leaves(base_p)):
         np.testing.assert_allclose(np.asarray(a, np.float32),
                                    np.asarray(b, np.float32),
-                                   atol=1e-4)
+                                   atol=5e-4)
 
 
 def test_chain_trace_gauges_pin_cost_model():

@@ -180,12 +180,13 @@ def test_the_router_reads_the_attentions_input(toy):
     # the two readings choose other experts for some token of layer 0 ...
     p = params["layers"][0]
     x = params["embed"]["table"][jnp.asarray(ids)]
-    h = M._norm(p["input_norm"], x, cfg)
-    q, k, v = M._qkv(p["attn"], h, cfg, 0, None, None, None)
+    h = M.L.norm(p["input_norm"], x, cfg)
+    q, k, v = M.L.qkv(p["attn"], h, cfg, None, None, None,
+                      rotary=cfg.rotary(0))
     a = M.L.dense(p["attn"]["wo"], M.L.causal_attention(q, k, v).reshape(
         1, 48, -1))
     assert float(jnp.abs(a).max()) > 0          # W_o is not zero
-    h2 = M._norm(p["post_attn_norm"], x + a, cfg)
+    h2 = M.L.norm(p["post_attn_norm"], x + a, cfg)
     early, late = (np.sort(np.asarray(M._route(p, t, cfg)[0]), -1)
                    for t in (h, h2))
     assert (early != late).any()
