@@ -1,6 +1,6 @@
 """The frame of a served decoder — written once for every family.
 
-A family (models/llama.py ... models/sambay.py) writes its config, its
+A family (models/llama.py ... models/gdn_hybrid.py) writes its config, its
 weights, its mixers and its ``layer``, and declares its cache kinds
 (models/paged.py ``CacheKind``).  What a tick's layers share is
 :func:`paged.tick`; what stands round the layers — the tick, the embedding,

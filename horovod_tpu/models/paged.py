@@ -12,7 +12,7 @@ cache stays one buffer through a tick (docs/serving.md).
 
 A model whose layers keep state of several KINDS declares them
 (:class:`CacheKind`) and holds one pool a kind.  There are three, the
-third in two forms (docs/serving.md#cache-kinds):
+third in three forms (docs/serving.md#cache-kinds):
 
   * the WHOLE CONTEXT, as above: a block table over the paged pool;
   * a RING of a window (models/swa_moe.py: layers that read a window of the
@@ -34,6 +34,14 @@ third in two forms (docs/serving.md#cache-kinds):
     verify row of which ``a`` drafts are accepted leaves the carry after
     row ``a`` where the next tick looks for it.  What lies behind the
     columns is the model's: ``[.., d]``, or a carry's ``[.., d_state, d]``.
+    Where that folded state is too large for a column a row
+    (models/gdn_hybrid.py: a delta rule's MATRIX a head) the kind declares
+    what a row feeds the recurrence (``replay``) and keeps ONE committed
+    state a slot, the position it stands after (``at``) and a ring of the
+    last verify row's inputs: a tick reads the state (:func:`committed`),
+    REPLAYS the ring's rows from ``at`` up to its slot's length
+    (:func:`replay_read`), runs its own, and commits the state after its
+    last row that no later tick can take back (:func:`commit_row`).
 
 What a module declares of its kinds is enough for what every module needs of
 them: :func:`tick` does one tick's slot arithmetic for all of them, once
@@ -70,13 +78,21 @@ class CacheKind(NamedTuple):
     ``[layers, blocks, block_size]`` (a state kind: behind ``[layers, slots,
     columns]``), in ``dtype`` where that is not the cache's own
     (:func:`init_pools`).  A paged pool whose leaves are ``[heads,
-    head_dim]`` behind has a head axis to shard (:func:`pool_shardings`)."""
+    head_dim]`` behind has a head axis to shard (:func:`pool_shardings`).
+
+    ``replay`` (a state kind of ``state`` 1 alone) names what ONE ROW feeds
+    the recurrence that made the state, name -> shape: the pool then holds
+    ``leaves`` ONCE a slot (one column), the position that state stands
+    after (``at``, int32) and ``replay``'s leaves for the :func:`replay_rows`
+    rows of a verify row that a later tick may take back, and the kind's
+    ``num_blocks`` is ``(slots, those rows)``."""
     name: Optional[str]
     layers: int
     window: Optional[int] = None
     state: Optional[int] = None
     leaves: Optional[Dict[str, Tuple[int, ...]]] = None
     dtype: Any = None
+    replay: Optional[Dict[str, Tuple[int, ...]]] = None
 
 
 def state_columns(state: int, tick_cols: int) -> int:
@@ -142,6 +158,96 @@ def carry_read(pool: jax.Array, layer: int, lengths: jax.Array) -> jax.Array:
     kept = pool[layer, jnp.arange(S), (lengths - 1) % pool.shape[2]]
     held = (lengths > 0).reshape((S,) + (1,) * (kept.ndim - 1))
     return jnp.where(held, kept, jnp.zeros((), pool.dtype))
+
+
+#: the leaf of a ``replay`` kind's pool that holds the position the committed
+#: state stands after, ``[layers, slots, 1, 1]`` int32
+AT = "at"
+
+
+def replay_rows(tick_cols: int) -> int:
+    """Rows of a slot's ring in a kind with ``replay``: the widest row a
+    tick may have to take back (``tick_cols``, a verify row's ``1 +
+    spec_k``) less its first.  A verify row runs ``L .. L+n-1`` (``n <=
+    tick_cols``) and the tick after starts at ``L+1+a`` with ``a < n`` of its
+    drafts accepted: row ``L`` — the token the tick before emitted — stands
+    whatever is accepted, so the state AFTER it is committed
+    (:func:`commit_row`), and rows ``L+1 .. L+n-1`` are the ``n - 1 <=
+    tick_cols - 1`` that may be taken back: their inputs go to the ring at
+    ``position % rows`` (:func:`replay_index`), no two of them in one entry,
+    and the next tick replays ``L+1 .. L+a`` of them (:func:`replay_read`)
+    before anything overwrites them — its own write comes after its read.
+    At least one row, so that no pool is empty without speculation."""
+    return max(tick_cols - 1, 1)
+
+
+def commit_row(n_new: jax.Array, rows: int) -> jax.Array:
+    """[S] the column of its own rows after which a slot's state is
+    committed in a kind with ``replay``: a row that fits the ring behind its
+    first column (``n_new <= rows + 1``: a verify row, a decode row, a short
+    tail of a prompt) commits after column 0 and leaves the rest to the
+    ring; a longer one is a prompt's chunk, which nothing takes back, and
+    commits after its last column, the ring left alone (:func:`replay_rows`
+    has the argument).  The tick cannot tell a verify row from a prompt's
+    tail of the same width and need not: a tail's rows are replayed all."""
+    return jnp.where(n_new <= rows + 1, 0, n_new - 1)
+
+
+def replay_index(lengths: jax.Array, n_new: jax.Array, valid: jax.Array,
+                 positions: jax.Array, rows: int
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """(slot, entry) [S, C] for :func:`write` into a ``replay`` kind's ring
+    ``[layers, S, rows, ...]``: of a row that commits after its first
+    column (:func:`commit_row`) every LATER column lands in ``[s, P %
+    rows]``; everything else goes to slot ``S``, off the axis, where
+    :func:`write` drops it."""
+    S = lengths.shape[0]
+    keeps = (valid & (n_new <= rows + 1)[:, None]
+             & (positions > lengths[:, None]))
+    slot = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[:, None],
+                            positions.shape)
+    return jnp.where(keeps, slot, S), positions % rows
+
+
+def committed(pool: Any, layer: int, lengths: jax.Array, leaf: str
+              ) -> Tuple[jax.Array, jax.Array]:
+    """(each slot's committed ``leaf`` ``[S, ...]``, the position ``at`` [S]
+    it stands after) of a ``replay`` kind's ``pool[layer]`` AS IT WAS BEFORE
+    THE TICK: zero and 0 for a slot that holds nothing (a new tenant starts
+    from nothing, as :func:`carry_read` has it).  ``lengths - at`` rows of
+    the ring are the tick's to replay."""
+    held = lengths > 0
+    kept = pool[leaf][layer, :, 0]
+    return (jnp.where(held.reshape((-1,) + (1,) * (kept.ndim - 1)), kept,
+                      jnp.zeros((), kept.dtype)),
+            jnp.where(held, pool[AT][layer, :, 0, 0], 0))
+
+
+def replay_read(ring: jax.Array, layer: int, at: jax.Array) -> jax.Array:
+    """A slot's ring in the order it is replayed, ``[S, rows, ...]``: index
+    ``e`` is what position ``at + e`` left in ``ring[layer]`` ``[S, rows,
+    ...]`` (:func:`replay_index`); the first ``lengths - at`` of them were
+    accepted."""
+    S, rows = ring.shape[1:3]
+    entry = (at[:, None] + jnp.arange(rows)[None, :]) % rows
+    return ring[layer, jnp.arange(S)[:, None], entry]
+
+
+def commit(pool: Any, layer: int, lengths: jax.Array, n_new: jax.Array,
+           rows: int, **state: jax.Array) -> Any:
+    """``pool`` with the ``state`` (leaf = ``[S, ...]``) that each slot's
+    tick left after its :func:`commit_row` written into ``pool[layer]``, and
+    with it the position it stands after; a slot that ran no row keeps what
+    it held."""
+    S = lengths.shape[0]
+    slot = jnp.where(n_new > 0, jnp.arange(S), S)
+    at = lengths + commit_row(n_new, rows) + 1
+    out = dict(pool, **{AT: pool[AT].at[layer, slot, 0, 0].set(
+        at.astype(pool[AT].dtype), mode="drop")})
+    for name, value in state.items():
+        out[name] = pool[name].at[layer, slot, 0].set(
+            value.astype(pool[name].dtype), mode="drop")
+    return out
 
 
 def ring_blocks(window: int, tick_cols: int, block_size: int,
@@ -282,7 +388,7 @@ def tick(kinds: Tuple[CacheKind, ...], cache: Any, tables: Any,
     (:func:`slot_positions`), the ``rows`` the tick's tokens are packed to
     (:func:`pack`; the config's ``max_tick_tokens``), and where the rows
     land in each of ``kinds`` — a ring where ``kind.window``, a slot's
-    column where ``kind.state``.  ``cache`` and ``tables`` are the module's
+    column where ``kind.state``, the ring's entry where ``kind.replay``.  ``cache`` and ``tables`` are the module's
     own, dicts by kind or the one pool and its table.
 
     ``reads`` names what else the family's mixers read of :class:`Tick`,
@@ -300,8 +406,11 @@ def tick(kinds: Tuple[CacheKind, ...], cache: Any, tables: Any,
                                    ring=kind.window is not None)
             where[kind.name] = (take(blk), take(off))
         else:
-            slot, col = state_index(lengths, n_new, valid, positions,
-                                    pool.shape[2])
+            if kind.replay:     # the ring's rows, not the ONE state's column
+                pool = _of(cache, kind)[next(iter(kind.replay))]
+            index = replay_index if kind.replay else state_index
+            slot, col = index(lengths, n_new, valid, positions,
+                              pool.shape[2])
             lands[kind.name] = (take(slot), take(col))
     wide = lambda a: take(jnp.broadcast_to(a[:, None], positions.shape))
     slots = lambda: wide(jnp.arange(lengths.shape[0], dtype=jnp.int32))
@@ -705,15 +814,25 @@ def init_pools(kinds: Tuple[CacheKind, ...], num_blocks: Any,
     (``CacheKind.leaves``): a paged or a ring kind's leaves are ``[kind's
     layers, num_blocks[kind], block_size, ...]``, a state kind's ``[kind's
     layers, slots, columns, ...]``, its ``num_blocks`` being ``(slots,
-    columns)``; in ``dtype`` unless the kind names its own.  ``{kind:
+    columns)`` — with ``replay``, the leaves at ONE column beside ``at`` and
+    the ring ``[kind's layers, slots, rows, ...]``, ``num_blocks`` ``(slots,
+    rows)`` —; in ``dtype`` unless the kind names its own.  ``{kind:
     {leaf: array}}``, or the one pool of a kind without a name (whose
     ``num_blocks`` is the number)."""
     def pool(kind):
         n = _of(num_blocks, kind)
         lead = (kind.layers,) + (tuple(n) if kind.state is not None
                                  else (n, block_size))
-        return {name: jnp.zeros(lead + tuple(behind), kind.dtype or dtype)
-                for name, behind in kind.leaves.items()}
+        zeros = lambda lead, leaves, dtype: {
+            name: jnp.zeros(lead + tuple(behind), dtype)
+            for name, behind in leaves.items()}
+        if kind.replay is None:
+            return zeros(lead, kind.leaves, kind.dtype or dtype)
+        # ONE state a slot and where it stands, beside the ring of rows
+        one = lead[:2] + (1,)
+        return {**zeros(one, kind.leaves, kind.dtype or dtype),
+                **zeros(one, {AT: (1,)}, jnp.int32),
+                **zeros(lead, kind.replay, kind.dtype or dtype)}
     pools = {kind.name: pool(kind) for kind in kinds}
     return pools[None] if None in pools else pools
 

@@ -550,11 +550,17 @@ class _State:
     column holds is the model's — a position's input ``[d]``, or the state a
     scan left after it ``[d_state, d]`` (models/sambay.py) —, so a kind's
     bytes a slot are read off its pool, never reckoned from ``columns``
-    (:meth:`ServeEngine._kind_pool`); a model may keep several such kinds."""
+    (:meth:`ServeEngine._kind_pool`); a model may keep several such kinds.
+    A kind that declares what a row feeds its state (``kind.replay``;
+    models/gdn_hybrid.py: a matrix a head) keeps ONE state a slot and is
+    sized by that: ``columns`` is then the rows of the verify row that the
+    next tick may have to replay (paged.replay_rows)."""
 
     def __init__(self, kind, cfg: ServeConfig):
         self.kind = kind
-        self.columns = paged.state_columns(kind.state, decode_width(cfg))
+        self.columns = (paged.replay_rows(decode_width(cfg)) if kind.replay
+                        else paged.state_columns(kind.state,
+                                                 decode_width(cfg)))
         # summed over dispatched ticks and their slots: the slots, and the
         # positions a whole-context cache would hold for them
         self.slot_ticks = 0
@@ -869,7 +875,8 @@ class Scheduler:
         kind's holds every slot's ring and is sized by the model's window,
         the slots and the chunk, not by ``cache_blocks``; a state kind's is
         (slots, columns a slot), sized by the model's state and the verify
-        row."""
+        row (with ``replay``: ONE state a slot and that many rows to
+        replay)."""
         if not self.kinds:
             return self.cfg.cache_blocks
         return {k.name: (
@@ -1443,13 +1450,14 @@ class ServeEngine:
     slot and a kind with a fixed state ``(slots, columns)`` with no table
     (docs/serving.md#cache-kinds; swa_moe.py declares whole contexts and a
     window, conv_moe.py whole contexts and a state, sambay.py all three and
-    a second state, a scan's carry; the other modules none: one pool, one
-    table) —, and ``greedy_cached``, the tick's greedy tokens
+    a second state, a scan's carry, gdn_hybrid.py whole contexts, a state
+    and ONE matrix state a slot with the rows to replay; the other modules
+    none: one pool, one table) —, and ``greedy_cached``, the tick's greedy tokens
     in place of its logits, for a vocabulary whose ``[slots, chunk, vocab]``
     slab should never exist: with ``read``, the columns whose token the tick
     reads, the head runs on those rows alone and the tokens come back
     ``[slots, decode width]`` (llama.py, moe_llama.py, latent_moe.py,
-    sambay.py; ``samples_read``); without, on every packed row, ``[slots, chunk]``
+    sambay.py, gdn_hybrid.py; ``samples_read``); without, on every packed row, ``[slots, chunk]``
     (swa_moe.py, conv_moe.py, until the PR that next changes their programs
     moves them over: ROADMAP S11, S12).  ``stats()["loop"]`` counts the rows
     of the wide ticks and those their head ran on (``packed_rows``,
@@ -2385,6 +2393,8 @@ class ServeEngine:
                 if full else 0)
             return {"layers": kind.layers, "window": None,
                     "state": kind.state, "state_columns": state.columns,
+                    "slot_bytes": (self._kind_bytes[kind.name]
+                                   // self.cfg.max_slots),
                     "pool_bytes": self._kind_bytes[kind.name],
                     "slots": self.cfg.max_slots,
                     "slots_used": sum(r is not None for r in s.slots),
@@ -2493,7 +2503,8 @@ _MODEL_MODULES = {"llama": "horovod_tpu.models.llama",
                   "swa_moe": "horovod_tpu.models.swa_moe",
                   "conv_moe": "horovod_tpu.models.conv_moe",
                   "blockdiff_moe": "horovod_tpu.models.blockdiff_moe",
-                  "sambay": "horovod_tpu.models.sambay"}
+                  "sambay": "horovod_tpu.models.sambay",
+                  "gdn_hybrid": "horovod_tpu.models.gdn_hybrid"}
 
 
 def save_servable(directory: str, model_name: str, config, params,
@@ -2514,7 +2525,8 @@ def save_servable(directory: str, model_name: str, config, params,
 def load_servable(directory: str, mesh) -> Tuple[Any, Any, Any]:
     """Read a servable directory -> (model module, model config, global
     replicated params).  ``serve.json``: {"model": "llama"|"moe_llama"|
-    "latent_moe"|"swa_moe"|"conv_moe"|"blockdiff_moe"|"sambay",
+    "latent_moe"|"swa_moe"|"conv_moe"|"blockdiff_moe"|"sambay"|
+    "gdn_hybrid",
     "config": <name in CONFIGS or kwarg dict>, "seed": int?}.  Params
     come from the latest checkpoint under the directory (restored
     through checkpoint.py into replicated shardings); with no

@@ -95,6 +95,8 @@ SUITES = {
                 "tests/test_blockdiff_moe.py",
                 "tests/test_serve_blockdiff.py",
                 "tests/test_sambay.py", "tests/test_serve_sambay.py",
+                "tests/test_gdn_hybrid.py",
+                "tests/test_serve_gdn_hybrid.py",
                 "tests/test_tpu_compile.py"],
     "perf": ["tests/test_perf.py", "tests/test_memstats.py",
              "tests/test_perfbench_families.py"],

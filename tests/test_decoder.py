@@ -18,7 +18,7 @@ import pytest
 from horovod_tpu.models import decoder, paged
 
 MODULES = ["llama", "moe_llama", "latent_moe", "swa_moe", "conv_moe",
-           "blockdiff_moe", "sambay"]
+           "blockdiff_moe", "sambay", "gdn_hybrid"]
 BS, TICK_COLS, MAX_BLOCKS, POOL = 4, 5, 8, 40
 # One plan, a slot a row — (columns, length before the tick): a decode row,
 # a verify row with two drafts, a chunk in the middle of a prompt, a
@@ -52,7 +52,8 @@ def _sizes(kinds):
     blocks, tables = {}, {}
     for k in kinds:
         if k.state is not None:
-            blocks[k.name] = (S, paged.state_columns(k.state, TICK_COLS))
+            blocks[k.name] = (S, paged.replay_rows(TICK_COLS) if k.replay
+                              else paged.state_columns(k.state, TICK_COLS))
             continue
         entries = (MAX_BLOCKS if k.window is None else paged.ring_blocks(
             k.window, TICK_COLS, BS, MAX_BLOCKS))
@@ -70,7 +71,10 @@ def _by_hand(kind, table, cols):
     out = []
     for s, (n, L) in enumerate(PLAN):
         for P in range(L, L + n):
-            if kind.state is not None:
+            if kind.replay:     # behind a row's first column, if it fits
+                lands = n <= cols + 1 and P > L
+                out.append((s if lands else S, P % cols))
+            elif kind.state is not None:
                 lands = P >= L + n - cols
                 out.append((s if lands else S, P % cols))
             elif kind.window is not None:
@@ -121,6 +125,8 @@ def test_the_tick_is_the_addressing_by_hand(name):
             want = _by_hand(k, _part(tables, k.name), None)
             got, off_axis = t.where[k.name], pool.shape[1]
         else:
+            if k.replay:    # the ring's rows, not the ONE state's column
+                pool = _part(cache, k.name)[next(iter(k.replay))]
             want = _by_hand(k, None, pool.shape[2])
             got, off_axis = t.lands[k.name], S
         got = np.stack([np.asarray(a)[0] for a in got], axis=1)
@@ -146,9 +152,10 @@ def test_the_tick_holds_only_what_the_family_reads():
 
 F32, BF16 = jnp.float32, jnp.bfloat16
 #: name -> ({kind: {leaf: (shape behind [layers, blocks, BS] or [layers,
-#: slots, columns], dtype under a bfloat16 cache)}}, {kind: layers}, whether
-#: the pools have a head axis to shard), from the modules' own
-#: ``init_cache`` and ``cache_shardings`` before they shared one
+#: slots, columns], dtype under a bfloat16 cache[, its columns where they
+#: are not the kind's: a ``replay`` kind keeps its state ONCE a slot])}},
+#: {kind: layers}, whether the pools have a head axis to shard), from the
+#: modules' own ``init_cache`` and ``cache_shardings`` before they shared one
 POOLS = {
     "llama": ({None: {"k": ((2, 16), BF16), "v": ((2, 16), BF16)}},
               {None: 2}, True),
@@ -168,8 +175,15 @@ POOLS = {
                 "conv": {"u": ((128,), BF16)},
                 "carry": {"h": ((16, 128), F32)}},
                {"kv": 1, "window": 2, "conv": 3, "carry": 3}, False),
+    "gdn_hybrid": ({"kv": {"k": ((64,), BF16), "v": ((64,), BF16)},
+                    "conv": {"u": ((128,), BF16)},
+                    "delta": {"S": ((4, 16, 8), F32, 1),
+                              "at": ((1,), jnp.int32, 1),
+                              "row": ((4 * (8 + 16 + 2),), F32)}},
+                   {"kv": 2, "conv": 6, "delta": 6}, False),
 }
-STATE = {"conv_moe": {"conv": 2}, "sambay": {"conv": 3, "carry": 1}}
+STATE = {"conv_moe": {"conv": 2}, "sambay": {"conv": 3, "carry": 1},
+         "gdn_hybrid": {"conv": 3, "delta": 1}}
 
 
 @pytest.mark.parametrize("name", MODULES)
@@ -186,13 +200,16 @@ def test_every_modules_pools_and_their_shardings(name):
     cache = jax.eval_shape(lambda: model.init_cache(cfg, blocks, BS, BF16))
     for kind, want in leaves.items():
         pool, n = _part(cache, kind), _part(blocks, kind)
-        lead = (layers[kind],) + ((S, 7) if kind in states else (n, BS))
+        lead = lambda cols=7: (layers[kind],) + (
+            (S, cols) if kind in states else (n, BS))
         assert {k: (v.shape, v.dtype) for k, v in pool.items()} == {
-            k: (lead + behind, dtype) for k, (behind, dtype) in want.items()}
-    # without a dtype the pools are the config's
+            k: (lead(*cols) + behind, dtype)
+            for k, (behind, dtype, *cols) in want.items()}
+    # without a dtype the pools are the config's (a position is an integer)
     own = jax.tree_util.tree_leaves(jax.eval_shape(
         lambda: model.init_cache(cfg, blocks, BS)))
-    assert {x.dtype for x in own} == {jnp.dtype(cfg.dtype)}
+    assert {x.dtype for x in own} - {jnp.dtype(jnp.int32)} == {
+        jnp.dtype(cfg.dtype)}
     PS = jax.sharding.PartitionSpec
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:8]).reshape(4, 2),
                              ("data", "model"))

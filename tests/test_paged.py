@@ -486,3 +486,85 @@ def test_the_carrys_read_and_its_snapshots_on_a_tick():
     # rows one accepted -> length 6 -> the carry after position 5
     nxt = paged.carry_read(jnp.asarray(out), 1, jnp.asarray([6, 6, 9, 0]))
     assert nxt[:, 0, 0].tolist() == [1005.0, 1010.0, 208.0, 0.0]
+
+
+# ------------- a state too large for a column a row: ONE a slot, and a replay
+def test_the_replay_kinds_addressing_on_a_tick():
+    """paged.replay_index / committed / replay_read / commit by hand, on a
+    state with a shape behind it: a row that fits the ring behind its first
+    column commits after that column and leaves the rest to the ring at
+    ``[s, P % rows]``; a longer one (a prompt's chunk) commits after its
+    last column and keeps nothing; a tick reads ONE state a slot as it was
+    before the tick with the position it stands after (zero and 0 for a
+    slot that holds nothing) and the ring in the order it is replayed."""
+    rows, S, C = paged.replay_rows(5), 4, 8
+    assert rows == 4 and paged.replay_rows(1) == 1
+    kind = paged.CacheKind("delta", 2, state=1, dtype=jnp.float32,
+                           leaves={"S": (2, 3)}, replay={"x": (2,)})
+    pool = paged.init_pools((kind,), {"delta": (S, rows)}, 4,
+                            jnp.bfloat16)["delta"]
+    assert {k: (v.shape, str(v.dtype)) for k, v in pool.items()} == {
+        "S": ((2, S, 1, 2, 3), "float32"), "x": ((2, S, rows, 2), "float32"),
+        paged.AT: ((2, S, 1, 1), "int32")}
+    # slot 0 a verify row of five at 9, slot 1 a chunk of eight at 16, slot
+    # 2 nothing, slot 3 a new tenant's tail of three where a stream left
+    lengths = jnp.asarray([9, 16, 0, 0], jnp.int32)
+    n_new = jnp.asarray([5, 8, 0, 3], jnp.int32)
+    assert paged.commit_row(n_new, rows).tolist() == [0, 7, 0, 0]
+    positions, valid = paged.slot_positions(lengths, n_new, C)
+    slot, col = paged.replay_index(lengths, n_new, valid, positions, rows)
+    assert slot.tolist() == [[4, 0, 0, 0, 0, 4, 4, 4], [4] * 8, [4] * 8,
+                             [4, 3, 3, 4, 4, 4, 4, 4]]
+    assert col[0, 1:5].tolist() == [2, 3, 0, 1] and col[3, 1:3].tolist() \
+        == [1, 2]
+    # the tick's own arithmetic gives the same
+    t = paged.tick((kind,), {"delta": pool}, {}, lengths, n_new, C)
+    assert np.array_equal(t.lands["delta"][0], slot)
+    # what the pool held: state 100 s, standing after 7, 16, 5 and 11; the
+    # ring's entry e of slot s holds 10 s + e
+    held = dict(pool)
+    held["S"] = held["S"].at[1].set(
+        100.0 * jnp.arange(S)[:, None, None, None] + jnp.ones((S, 1, 2, 3)))
+    held[paged.AT] = held[paged.AT].at[1, :, 0, 0].set(
+        jnp.asarray([7, 16, 5, 11]))
+    held["x"] = held["x"].at[1].set(
+        (10.0 * jnp.arange(S)[:, None] + jnp.arange(rows)[None])[..., None]
+        * jnp.ones(2))
+    state, at = paged.committed(held, 1, lengths, "S")
+    assert state.shape == (S, 2, 3) and at.tolist() == [7, 16, 0, 0]
+    assert state[:, 0, 0].tolist() == [1.0, 101.0, 0.0, 0.0]
+    # slot 0 replays positions 7 and 8 (entries 3 and 0), then would 9, 10
+    ring = paged.replay_read(held["x"], 1, at)
+    assert ring.shape == (S, rows, 2)
+    assert ring[0, :, 0].tolist() == [3.0, 0.0, 1.0, 2.0]
+    assert ring[1, :, 0].tolist() == [10.0, 11.0, 12.0, 13.0]
+    # the commit: the new state and where it stands; slot 2 keeps its own
+    new = 1000.0 + jnp.arange(S)[:, None, None] * jnp.ones((S, 2, 3))
+    out = paged.commit(held, 1, lengths, n_new, rows, S=new)
+    assert out[paged.AT][1, :, 0, 0].tolist() == [10, 24, 5, 1]
+    assert out["S"][1, :, 0, 0, 0].tolist() == [1000.0, 1001.0, 201.0, 1003.0]
+    assert bool((out["S"][0] == held["S"][0]).all())     # the other layer
+    assert out["x"] is held["x"]
+
+
+@pytest.mark.parametrize("tick_cols", [1, 2, 5, 9])
+def test_a_replay_ring_keeps_what_the_tick_after_a_verify_row_replays(
+        tick_cols):
+    """paged.replay_rows' bound, position by position: a verify row runs
+    ``L .. L+n-1`` and commits after ``L``; with any ``a < n`` drafts
+    accepted the next tick replays ``L+1 .. L+a``, each still in its own
+    entry of the ring, before its own rows overwrite any; a ring one row
+    shorter loses one when the row is as wide as it may be."""
+    R, L = paged.replay_rows(tick_cols), 1000
+    for n in range(1, tick_cols + 1):
+        assert int(paged.commit_row(jnp.asarray(n), R)) == 0
+        kept = range(L + 1, L + n)
+        assert len({p % R for p in kept}) == len(kept)
+        for a in range(n):
+            assert set(range(L + 1, L + 1 + a)) <= set(kept)
+    # a row wider than the ring holds is a chunk: it commits after its last
+    assert int(paged.commit_row(jnp.asarray(R + 2), R)) == R + 1
+    if tick_cols > 2:
+        short = R - 1
+        kept = range(L + 1, L + tick_cols)
+        assert len({p % short for p in kept}) < len(kept)

@@ -27,7 +27,7 @@ if _TESTS_FAMILIES not in spec.FAMILY_DIRS:
     spec.FAMILY_DIRS.append(_TESTS_FAMILIES)
 
 FAMILIES = ["dense_gqa", "moe_switch", "latent_moe", "swa_moe", "conv_moe",
-            "blockdiff_moe", "sambay"]
+            "blockdiff_moe", "sambay", "gdn_hybrid"]
 ROUTED_BY_TOKENS = {"latent_moe", "swa_moe", "conv_moe", "blockdiff_moe"}
 SEED = 2**31 + 27
 
@@ -40,7 +40,8 @@ def _toy(family):
             "swa_moe": "serve-moe-swa-longdoc",
             "conv_moe": "serve-moe-conv-chat",
             "blockdiff_moe": "serve-moe-blockdiff-gen",
-            "sambay": "serve-ssm-yoco-reason"}[family]
+            "sambay": "serve-ssm-yoco-reason",
+            "gdn_hybrid": "serve-gdn-mixedlen"}[family]
     return spec.tiny(spec.cell(cell)[1])
 
 
@@ -190,6 +191,20 @@ def test_the_counts_are_the_pytrees_sizes_and_the_programs_cache(family):
         assert fam.cache_bytes_per_position(config, 2) == pytest.approx(
             a_layer * (2 + 2 * config["sliding_window"]
                        / fam.MEAN_LIVE_CONTEXT))
+    elif family == "gdn_hybrid":
+        # the full layers hold a position each, and a new token reads just
+        # those; the linear layers two fixed states a slot, of which the
+        # matrix state is ONE a layer, float32 whatever the pool's type,
+        # beside the position it stands after and a ring of rows
+        state = fam.state_bytes_per_slot(config, 2, columns=STATE_COLS,
+                                         rows=STATE_COLS)
+        assert state == {
+            "conv": 6 * STATE_COLS * 4 * (8 + 8 + 16) * 2,
+            "delta": 6 * (4 * 16 * 8 * 4 + 4
+                          + STATE_COLS * 4 * (8 + 16 + 2) * 4)}
+        a_layer = fam.cache_bytes_per_position_per_layer(config, 2)
+        assert a_layer * 2 * blocks * size + sum(state.values()) == held
+        assert fam.cache_bytes_per_position(config, 2) == a_layer * 2
     elif hasattr(fam, "state_bytes_per_slot"):
         # a paged kind, which alone a new token reads a position of, and a
         # fixed state a slot
@@ -773,6 +788,320 @@ def test_the_sambay_readers_read_a_trace_and_the_kinds_counters():
     assert spec.metric_reader("engine.head_rows_share.serve")(bare) is None
 
 
+# ------------------------------------------- the gated delta-rule family
+GDN_CELL, GDN_CONFIG = "serve-gdn-mixedlen", "olmo-hybrid-7b"
+GDN_METRICS = ("gdn.mix_share_of_tick.serve", "gdn.state_ops_ms.serve")
+
+
+def test_the_benchmark_holds_the_gdn_configuration_and_its_cell():
+    """``BENCHMARK.json`` itself has the configuration with its file, the
+    cell on one chip, the cell in both serving end-to-end lists and in the
+    per-layer lists it reports, and the two new per-layer entries at the END
+    of the list, each with a reader file."""
+    bench = spec.benchmark()
+    conf = bench["configs"][-1]
+    assert conf["name"] == GDN_CONFIG
+    assert conf["file"] == f"perfbench/configs/{GDN_CONFIG}.json"
+    assert conf["reduced"] == ["num_hidden_layers"] and conf["source"] == \
+        "https://huggingface.co/allenai/Olmo-Hybrid-7B/blob/main/config.json"
+    cell = bench["workloads"][-1]
+    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
+        GDN_CELL, GDN_CONFIG, "mixedlen-decode", 1) and len(cell["why"]) <= 200
+    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
+    e2e, layer = spec.cell_metrics(GDN_CELL, bench)
+    assert {m["name"] for m in e2e} == {"ttft_p50_ms",
+                                        "serve_out_tokens_per_s", "setup_s"}
+    mine = {m["name"]: m for m in layer}
+    assert tuple(m["name"] for m in bench["per_layer"][-2:]) == GDN_METRICS
+    for name, source in zip(GDN_METRICS, ("device_trace", "device_trace")):
+        assert mine[name]["workloads"] == [GDN_CELL], name
+        assert mine[name]["moves"] == "serve_out_tokens_per_s"
+        assert mine[name]["layer"] == "gated delta rule"
+        assert mine[name]["source"] == source
+        assert callable(spec.metric_reader(name))
+    # (``model_step.required_roofline_share.serve`` is not joined: PERF.md §7)
+    assert "model_step.required_roofline_share.serve" not in mine
+    for name in ("kv.state_resident_share.serve", "device.idle_share.serve",
+                 "engine.tick_ms.serve", "engine.ahead_share.serve",
+                 "engine.late_launch_share.serve",
+                 # the module samples on the rows a tick reads
+                 "engine.head_rows_share.serve"):
+        assert name in mine, name
+    # the rows a tick replayed are counted by the program (TICK_COUNTERS)
+    # and read by no metric: uniform tokens never draft (PERF.md section 7)
+    assert not any("replayed" in m["name"] for m in bench["per_layer"])
+    assert len(json.dumps(bench)) < 64 * 1024
+
+
+def test_the_gdn_cut_is_whole_periods_and_the_issues_arithmetic():
+    """ISSUE 48's reckoning, held to the configuration file: every catalog
+    key but the depth, the first 16 ``layer_types`` four whole periods of
+    (linear, linear, linear, full), 4,100,788,944 parameters by kind of
+    layer (8.20 GB in bfloat16), the uncut model 7,430,870,688, 15,360 B a
+    cached position a full layer, the pools' bytes with ONE matrix state a
+    slot a layer, the engine's settings and the traffic as the issue gives
+    them."""
+    entry, config, traffic = spec.cell(GDN_CELL)
+    fam = spec.family(config)
+    assert set(config["reduced"]) == {"num_hidden_layers"}
+    assert config["family"] == "gdn_hybrid"
+    with open("/opt/skills/guides/model-configs/architectures.jsonl") \
+            if os.path.isfile(
+                "/opt/skills/guides/model-configs/architectures.jsonl") \
+            else open(os.devnull) as f:
+        rows = [json.loads(line) for line in f]
+    catalog = next((r["config"] for r in rows
+                    if r["name"] == "Olmo-Hybrid-7B"), None) or {
+        "model_type": "olmo_hybrid", "vocab_size": 100352,
+        "hidden_size": 3840, "intermediate_size": 11008,
+        "num_attention_heads": 30, "num_key_value_heads": 30,
+        "max_position_embeddings": 65536, "rms_norm_eps": 1e-06,
+        "tie_word_embeddings": False, "linear_num_key_heads": 30,
+        "linear_num_value_heads": 30, "linear_key_head_dim": 96,
+        "linear_value_head_dim": 192, "linear_conv_kernel_dim": 4,
+        "linear_allow_neg_eigval": True,
+        "rope_parameters": {"rope_theta": None}}
+    assert {k: config[k] for k in catalog if k != "num_hidden_layers"} == {
+        k: v for k, v in catalog.items() if k != "num_hidden_layers"}
+    assert config["num_hidden_layers"] == 16
+    period = ["linear_attention"] * 3 + ["full_attention"]
+    assert config["layer_types"] == period * 8           # kept whole
+    assert config["layer_types"][:16] == period * 4 and fam.full_every(
+        config) == 4
+    assert fam.layer_kinds(config) == (["linear"] * 3 + ["full"]) * 4
+    for key in ("block", "no_rotary", "qk_norm", "head_size", "convolution",
+                "norms_and_scale", "beta", "gate", "state", "weights",
+                "tokens"):
+        assert len(config["assumed"][key]) > 40, key
+    n = fam.dims(config)
+    assert (n["hd"], n["Hl"], n["dk"], n["dv"], n["K"]) == (128, 30, 96, 192,
+                                                            4)
+    by_kind = fam.params_by_kind(config)
+    mixer = {k: v - 3 * 3840 * 11008 - 2 * 3840 for k, v in by_kind.items()}
+    assert mixer == {"linear": 88_750_332, "full": 58_990_080}
+    dep, e = config["deployment"], config["engine"]
+    assert by_kind == dep["parameters_by_kind_of_layer"] == {
+        "linear": 215_570_172, "full": 185_809_920}
+    total = fam.param_counts(config)["total"]
+    assert total == dep["parameters"] == 4_100_788_944 == (
+        12 * 215_570_172 + 4 * 185_809_920 + 770_707_200)
+    assert total == sum(math.prod(s) for _, s, _ in fam.leaf_specs(config))
+    assert fam.uncut_param_count(config) == dep["parameters_uncut"] == \
+        7_430_870_688 == 24 * 215_570_172 + 8 * 185_809_920 + 770_707_200
+    assert dep["weight_bytes"] == 2 * total and round(2 * total / 1e9, 2) \
+        == 8.2
+    assert (dep["stages"], dep["layers_per_stage"], dep["this_stage"],
+            dep["chips_per_layer"]) == (2, 16, 0, 1)
+    assert fam.cache_bytes_per_position_per_layer(config, 2) \
+        == dep["cache_bytes_per_position_per_layer"] == 15_360
+    assert fam.cache_bytes_per_position(config, 2) == 61_440
+    from horovod_tpu.models import paged
+    assert fam.replay_rows(config) == paged.replay_rows(5) == 4
+    state = fam.state_bytes_per_slot(config, 2)
+    assert state == {"conv": 12 * paged.state_columns(3, 5) * 11_520 * 2,
+                     "delta": 12 * (2_211_840 + 4 + 4 * 34_800)}
+    assert (e["max_slots"], e["prefill_chunk"], e["max_batch_tokens"],
+            e["block_size"], e["max_seq_len"], e["cache_blocks"],
+            e["prefix_cache"]) == (16, 512, 576, 16, 12_800, 3_072, False)
+    assert e["max_batch_tokens"] == e["prefill_chunk"] + e["max_slots"] * 4
+    assert dep["kv_pool_bytes"] == 3_072 * 16 * 61_440
+    assert dep["conv_state_bytes"] == 16 * state["conv"]
+    assert dep["delta_state_bytes"] + dep["delta_ring_bytes"] \
+        == 16 * state["delta"]
+    assert dep["delta_state_bytes"] == 12 * 16 * 30 * 192 * 96 * 4
+    assert dep["resident_bytes"] == sum(dep[k] for k in (
+        "weight_bytes", "kv_pool_bytes", "conv_state_bytes",
+        "delta_state_bytes", "delta_ring_bytes"))
+    assert 0.25 * 16e9 < 10.5e9 < dep["resident_bytes"] < 13e9
+    assert e["max_seq_len"] == traffic["prompt_len"]["max"] \
+        + traffic["output_len"]["max"]
+    assert fam.tick_weight_bytes(config, 1, 2) == fam.tick_weight_bytes(
+        config, 576, 2) == 2 * fam.param_counts(config)["matmul"]
+    assert fam.attn_flops_per_position(config) == 2 * 30 * 256 * 4
+    # the program the file asks for: four kinds of cache, chunks of 64
+    model, cfg = fam.program(config)
+    assert (cfg.n_layers, cfg.chunk, cfg.full_every, cfg.max_seq) == (
+        16, 64, 4, 12_800)
+    assert [(k.name, k.layers) for k in model.cache_kinds(cfg)] == [
+        ("kv", 4), ("conv", 12), ("delta", 12)]
+    # the traffic, as ISSUE 48 gives it
+    assert traffic["prompt_len"] == {"median": 2048, "sigma": 0.9, "min": 256,
+                                     "max": 12288}
+    assert traffic["output_len"] == {"median": 256, "sigma": 0.5, "min": 64,
+                                     "max": 512}
+    assert "shared_prefix" not in traffic and "sessions" not in traffic
+    knee = traffic["arrivals"]["knee"]["rate_per_s"]
+    assert traffic["arrivals"]["rate_per_s"] == pytest.approx(0.8 * knee)
+    assert set(traffic["check"]["limits"]) == {
+        "served_gap_share", "served_gap_max", "protocol_violations",
+        "window_compilations"}
+
+
+def test_the_parent_process_loads_the_gdn_family_without_jax():
+    code = ("import sys; from perfbench.lib import peaks, spec\n"
+            "_, c, _ = spec.cell('serve-gdn-mixedlen'); f = spec.family(c)\n"
+            "spec.tiny(c); f.param_counts(c); f.state_op_group('x f32[1]', c)\n"
+            "f.mix_op_group('x f32[1]', c); f.leaf_specs(c); f.layer_kinds(c)\n"
+            "m = {'trace': None, 'config': c,\n"
+            "     'marks': {k: {'stats': {}, 'tick': 0} for k in ('start', 'end')}}\n"
+            "assert f.state_counts(m) is None\n"
+            "assert f.mix_share(m) is None and f.state_ops_ms(m) is None\n"
+            "peaks.serve_required_seconds(c, peaks.PEAKS['TPU v5 lite'], 9, 9, 1)\n"
+            "assert 'jax' not in sys.modules and 'numpy' not in sys.modules\n")
+    subprocess.run([sys.executable, "-c", code], check=True, cwd=spec.ROOT,
+                   timeout=60)
+
+
+def test_the_gdn_readers_read_a_trace_and_the_ticks_counters():
+    """The two readers the cell brings and the one it joins, on a made-up
+    trace and marks: the recurrence's share (the states' and the ring's
+    moves apart), the state kinds' ops, the resident share; each None where
+    there is nothing to read, on another family's configuration too, and
+    where the trace lacks one of the groups a reader expects — a program
+    that runs the recurrence in other ops must not read as a smaller
+    share."""
+    _, config, _ = spec.cell(GDN_CELL)
+    conv = {"slot_ticks": 100, "state_bytes_ticks": 100 * 2_211_840,
+            "kv_bytes_ticks": 100 * 3000 * 12 * 15_360}
+    delta = dict(conv, state_bytes_ticks=100 * 28_212_528)
+    mark = lambda t, zero: {"tick": t, "stats": {"kv_pool": {"kinds": {
+        name: dict.fromkeys(d, 0) if zero else d for name, d in
+        (("conv", conv), ("delta", delta))}}}}
+    mix = {n: s for n, (_, s) in GDN_MIX_OPS.items()}
+    state = {n: s for n, (_, s) in GDN_STATE_OPS.items()}
+    ops = dict(mix, **state, **dict.fromkeys(GDN_OTHER_OPS, 0.01))
+    ctx = {"config": config, "peaks": {"hbm_gbps": 819.0, "bf16_tflops": 197.0},
+           "marks": {"start": mark(0, True), "end": mark(10, False)},
+           "trace": {"module_count": 5.0, "module_s": 0.2, "ops_s": ops}}
+    read = lambda name, ctx=ctx: spec.metric_reader(name)(ctx)
+    assert read("gdn.mix_share_of_tick.serve") == pytest.approx(
+        100 * sum(mix.values()) / 0.2)
+    assert read("gdn.state_ops_ms.serve") == pytest.approx(
+        1e3 * sum(state.values()) / 5)
+    assert read("kv.state_resident_share.serve") == pytest.approx(
+        100 * (2_211_840 + 28_212_528) / (3000 * 12 * 15_360))
+    bare = dict(ctx, trace=None, marks={k: {"tick": 0, "stats": {}}
+                                        for k in ("start", "end")})
+    other = dict(ctx, config=spec.cell("serve-decode")[1])
+    for name in GDN_METRICS + ("kv.state_resident_share.serve",):
+        assert spec.metric_reader(name)(bare) is None, name
+        assert spec.metric_reader(name)(other) is None, name
+    # a group with no op in the trace: None, not what is left of the share
+    without = lambda group: dict(ctx, trace=dict(ctx["trace"], ops_s={
+        n: s for n, s in ops.items()
+        if (GDN_MIX_OPS.get(n) or GDN_STATE_OPS.get(n) or ("",))[0]
+        != group}))
+    for group in ("state", "square", "rows"):
+        assert read("gdn.mix_share_of_tick.serve", without(group)) is None
+        assert read("gdn.state_ops_ms.serve", without(group)) is not None
+    for group in ("states", "ring", "conv"):
+        assert read("gdn.state_ops_ms.serve", without(group)) is None
+        assert read("gdn.mix_share_of_tick.serve", without(group)) is not None
+    for group in ("fed", "at"):     # fused away, or too small to matter
+        assert read("gdn.mix_share_of_tick.serve", without(group))
+        assert read("gdn.state_ops_ms.serve", without(group))
+
+
+#: device ops as the cell's two programs name them on the chip (my chip
+#: runs, PR 48), {name: (the group the family's readers give it, seconds)}:
+#: the recurrence's own; the moves of the states, the ring and the conv
+#: inputs; and what is neither — the projections (a weight's name behind the
+#: type), the norms on the tick's own rows, the running sums, the attention
+GDN_MIX_OPS = {"fusion f32[16,30,9,9]": ("square", 0.004),
+               "fusion f32[16,30,1,9,9]": ("square", 0.0001),
+               "fusion f32[16,30,18,192]": ("rows", 0.003),
+               "convolution_add_fusion f32[16,30,192,96]": ("state", 0.005),
+               "slice_add_fusion f32[16,30,9,192]": ("rows", 0.001),
+               "fusion.494.remat = f32[16,30,18,192]{3,2,1,0:T(8,128)S(1)} "
+               "fusion(f32[12,16,1,30": ("rows", 0.0002),
+               "fusion f32[144,30,192]": ("fed", 0.0003),
+               "fusion f32[8,30,64,288]": ("rows", 0.006),
+               "fusion f32[8,30,2,32,32]": ("square", 0.002),
+               "fusion f32[512,30,96]": ("fed", 0.001),
+               "fusion f32[30,128,192]": ("rows", 0.003),
+               "fusion f32[32,30,64,192]": ("rows", 0.0007),
+               "fusion f32[30,192,96]": ("state", 0.002)}
+GDN_STATE_OPS = {"fusion f32[12,16,30,192,96]": ("states", 0.003),
+                 "fusion f32[12,16,1,30,192,96]": ("states", 0.004),
+                 "copy-done f32[12,16,4,8700]": ("ring", 0.0005),
+                 "fusion f32[16,4,8700]": ("ring", 0.0004),
+                 "concatenate f32[16,5,8700]": ("ring", 0.0001),
+                 "reshape f32[16,4,30,192]": ("ring", 0.0001),
+                 "fusion s32[12,16]": ("at", 0.0001),
+                 "copy s32[12,16,1,1]": ("at", 0.0001),
+                 "fusion bf16[12,16,8,11520]": ("conv", 0.0006),
+                 "fusion bf16[16,8,11520]": ("conv", 0.0002),
+                 "slice-done bf16[3,16,8,11520]": ("conv", 0.0001),
+                 "fusion bf16[80,11520]": ("conv", 0.0008),
+                 "fusion bf16[576,11520]": ("conv", 0.002)}
+GDN_OTHER_OPS = ("fusion bf16[576,11520] params_layers_gdn_qkv_kernel",
+                 "fusion bf16[16,5,11520] params_layers_gdn_qkv_kernel",
+                 "fusion f32[576,30,192]", "fusion f32[80,30,96]",
+                 "reshape f32[16,5,30,96]", "reduce_window_sum f32[16,30,9]",
+                 "reshape bf16[2,256,30,128]", "fusion f32[2,30,5]",
+                 "fusion f32[2,30,1,5,128]", "fusion bf16[16,5,3840]",
+                 "fusion f32[16,5,11520]", "while s32[]")
+
+
+@pytest.mark.parametrize("name", sorted(GDN_MIX_OPS) + sorted(GDN_STATE_OPS)
+                         + sorted(GDN_OTHER_OPS))
+def test_a_device_op_of_the_gdn_cell_is_told_by_the_models_sizes(name):
+    """``mix_op_group`` / ``state_op_group``: an op is the recurrence's, a
+    pool's or neither by the type it makes and H, dk, dv, the slots and the
+    tick's rows alone — no chunk's length and no grouping of the program's
+    is in the rule, so a program that cuts its chunks another way keeps its
+    readers."""
+    _, config, _ = spec.cell(GDN_CELL)
+    fam = spec.family(config)
+    mix, state = (GDN_MIX_OPS.get(name, (None,))[0],
+                  GDN_STATE_OPS.get(name, (None,))[0])
+    assert fam.mix_op_group(name, config) == mix
+    assert fam.state_op_group(name, config) == state
+
+
+def test_the_gdn_readers_rules_hold_no_constant_of_the_program():
+    """The yardstick's rules are the configuration's: with another chunk
+    length (128) and grouping (4) the same ops of the same kinds are found;
+    and where the full layers' head size is one of dk, dv, dk + dv nothing
+    is told apart and nothing is counted."""
+    _, config, _ = spec.cell(GDN_CELL)
+    fam = spec.family(config)
+    for name, group in (("fusion f32[4,30,128,288]", "rows"),
+                        ("fusion f32[4,30,4,32,32]", "square"),
+                        ("fusion f32[30,256,192]", "rows"),
+                        ("fusion f32[16,30,11,11]", "square")):
+        assert fam.mix_op_group(name, config) == group
+    same = dict(config, hidden_size=30 * 96)
+    assert fam.mix_op_group("fusion f32[16,30,9,9]", same) is None
+
+
+@pytest.mark.parametrize("fraction", [0.0, 0.01, 0.5, 0.99])
+def test_the_gdn_cells_traced_window_leaves_the_timelines_reader_its_seconds(
+        fraction):
+    """``engine.warm_excess_ms.serve`` reads the window's first 8 whole
+    seconds against the whole seconds after them and BEFORE the profiler
+    session, and is None where none lies between: the cell's trace began at
+    7.9 s once and the check refused the PR for the missing metric.  At the
+    file's ``trace.start_s`` the reader has a second to read at whatever
+    fraction of a wall second the window starts, and the session still ends
+    with the 17 s the profiler took to stop inside the window of 45 s."""
+    _, _, traffic = spec.cell(GDN_CELL)
+    start, seconds = traffic["trace"]["start_s"], traffic["trace"]["seconds"]
+    assert start + seconds + 17.0 < 45.0
+    t0 = 1000.0 + fraction
+    sec = list(range(998, 1050))
+    cols = lambda value: [value] * len(sec)
+    timeline = {"sec": sec, "narrow": cols(30.0), "wide": cols(3.0),
+                "phase_s": {"stage": cols(0.033), "idle": cols(0.2)},
+                "phase_n": {"stage": cols(33.0), "idle": cols(10.0)}}
+    ctx = {"trace": {"t0": t0 + start}, "marks": {
+        "start": {"t": t0}, "end": {"t": t0 + 45.0, "stats": {
+            "loop": {"timeline": timeline}}}}}
+    read = spec.metric_reader("engine.warm_excess_ms.serve")
+    assert read(ctx) == pytest.approx(0.0)
+    assert read(dict(ctx, trace={"t0": t0 + 7.9})) is None
+
+
 # ------------------------------------------- the block-denoising family
 def test_the_blockdiff_cut_is_the_issues_arithmetic():
     """ISSUE 40: a layer of 623,120,640 parameters, 4,984,176,384 in seven
@@ -1026,11 +1355,11 @@ def test_the_blockdiff_control_is_not_correct(block_toy):
 @pytest.mark.parametrize("family", FAMILIES)
 def test_who_gives_a_configurations_served_statistics(family):
     """``served_stats_for``: ``generated_logit_stats`` itself, by identity,
-    for the five families that bring none of their own, the family's own
-    for the one that does."""
+    for the families that bring none of their own, the family's own for the
+    two that do (a block's passes; rows cut to the sample's longest)."""
     config = _toy(family)
     fam = spec.family(config)
-    if family == "blockdiff_moe":
+    if family in ("blockdiff_moe", "gdn_hybrid"):
         assert reference.served_stats_for(config) is fam.served_stats
     else:
         assert not hasattr(fam, "served_stats")
@@ -1077,7 +1406,10 @@ def test_the_sample_is_the_golden_one():
                                "engine.tick_ms.serve")),
     ("serve-ssm-yoco-reason", ("kv.state_resident_share.serve",
                                "kv.window_resident_share.serve",
-                               "engine.tick_ms.serve"))])
+                               "engine.tick_ms.serve")),
+    ("serve-gdn-mixedlen", ("kv.state_resident_share.serve",
+                            "engine.head_rows_share.serve",
+                            "engine.tick_ms.serve"))])
 def test_the_new_cells_rehearsal_passes(cell, metrics):
     out = subprocess.run(
         [sys.executable, "perfbench/run.py", "--workload", cell, "--seed",

@@ -1,5 +1,5 @@
 """The engine that launches tick N+1 before it fences tick N
-(horovod_tpu/serve/engine.py; docs/serving.md#the-loops-order) on six
+(horovod_tpu/serve/engine.py; docs/serving.md#the-loops-order) on seven
 model families at toy sizes: the plain greedy reference's tokens, the
 speculation counts of the fenced order, ends of stream, slots reused, a
 copy-on-write admitted while a tick is in flight, the hand-off.
@@ -19,12 +19,12 @@ from horovod_tpu.serve.engine import DECODE, ROW, ServeEngine, samples_read
 from test_serve_chain import _mesh, _record_ticks, _same_tree, stripped
 
 FAMILIES = ["llama", "moe_llama", "latent_moe", "swa_moe", "conv_moe",
-            "sambay"]
+            "sambay", "gdn_hybrid"]
 SHARING = FAMILIES[:3]      # whole contexts only: prefix cache and hand-off
-READS = SHARING + ["sambay"]    # ``greedy_cached`` takes ``read``
+READS = SHARING + ["sambay", "gdn_hybrid"]  # ``greedy_cached`` takes ``read``
 
 
-# ------------------------------------------------------- the six families
+# ----------------------------------------------------- the seven families
 def _load(name):
     model = importlib.import_module("horovod_tpu.models." + name)
     cfg = model.CONFIGS["tiny"]
@@ -115,9 +115,9 @@ def test_launch_ahead_serves_the_plain_greedy_references_tokens(family):
 
 
 def test_the_slab_branch_serves_the_same_streams_counts_and_ends(family):
-    """The module as it samples in the tick — llama, moe_llama, latent_moe
-    and sambay on the rows of the columns the tick reads (``greedy_cached(..,
-    read)``), swa_moe and conv_moe on every packed row — against the same
+    """The module as it samples in the tick — llama, moe_llama, latent_moe,
+    sambay and gdn_hybrid on the rows of the columns the tick reads
+    (``greedy_cached(.., read)``), swa_moe and conv_moe on every packed row — against the same
     module without ``greedy_cached``, through the tick's slab branch (the
     argmax of ``apply_cached``'s ``[slots, chunk, vocab]`` logits): with
     speculation on and an ``eos_id`` out of the streams, the same tokens,

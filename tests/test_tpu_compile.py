@@ -7,6 +7,7 @@ it loads the TPU's library, and the topology is described inside a fixture,
 never while a module is imported."""
 
 import functools
+import math
 import os
 
 import jax
@@ -131,7 +132,8 @@ def test_no_tick_relays_a_pool_on_its_way_in_or_out(one_chip, cell, width):
                                   "serve-moe-swa-longdoc",
                                   "serve-moe-conv-chat",
                                   "serve-moe-blockdiff-gen",
-                                  "serve-ssm-yoco-reason"])
+                                  "serve-ssm-yoco-reason",
+                                  "serve-gdn-mixedlen"])
 def test_the_token_history_is_handed_on_in_place(one_chip, cell, width):
     """The decode chain's state is the tick program's own: the token history
     ``[slots, max_seq_len]`` int32 comes from the tick before donated, is
@@ -156,11 +158,12 @@ def test_the_token_history_is_handed_on_in_place(one_chip, cell, width):
 
 
 @pytest.mark.parametrize("cell", ["serve-decode", "serve-moe-mla-decode",
-                                  "serve-ssm-yoco-reason"])
+                                  "serve-ssm-yoco-reason",
+                                  "serve-gdn-mixedlen"])
 def test_the_wide_tick_runs_its_head_on_the_rows_it_reads(one_chip, cell):
     """The chunk-wide program of a module that samples where the tick reads
     (``greedy_cached(.., read)``: models/llama.py, models/latent_moe.py,
-    models/sambay.py, whose head is its embedding):
+    models/sambay.py, whose head is its embedding, models/gdn_hybrid.py):
     beside the vocabulary no array but the head's own matrix holds more rows
     than the ``slots x (1 + spec_k)`` whose token the tick reads — no
     ``[16,128,92544]`` slab and no ``[512,92544]`` logits of every packed
@@ -239,6 +242,63 @@ def test_the_scan_tick_keeps_its_four_pools_where_they_lie(one_chip, C):
             5: (",544,1280]", ",34,16,1280]")}[C]
     assert [op for op in ops if op[0].endswith(ring)]
     assert not [op for op in ops if op[0].endswith(",5120,16]")]
+
+
+@pytest.mark.parametrize("C", [512, 5])
+def test_the_delta_tick_keeps_one_state_a_slot_in_place(one_chip, C):
+    """``serve-gdn-mixedlen``'s two programs: the four full layers' pool
+    (``[4, 3072, 16, 3840]``), the twelve linear layers' conv inputs, their
+    ONE committed state a slot (``[12, 16, 1, 30, 192, 96]`` float32, which
+    the commit scatters into as ``[12, 16, 30, 192, 96]``, a bitcast away)
+    and the ring of rows to replay (``[12, 16, 4, 8700]``: k, v, g and beta
+    side by side) are scattered into in place and never relaid or
+    concatenated whole, and the states never copied; the program takes each
+    row-major.  NO state a row exists: nothing with the state's ``[30, 192,
+    96]`` behind it holds more than the 16 slots' (576 rows of it would be
+    1.27 GB; a loop over positions that kept its carries would make them),
+    and nothing is shaped like a slot's whole context of 12,800.  The
+    recurrence is the chunked form in both: a verify row's chunk of 9 a
+    slot, a prompt's chunks of 64, eight at a time, and no solver's custom
+    call (``lax.linalg.triangular_solve`` was 47 of a 139 ms wide tick on
+    the chip, PERF.md section 6, PR 48: the inverse is forward substitution
+    in blocks of 16, joined by products)."""
+    import re
+    texts, _, pools, layouts = _tick_programs("serve-gdn-mixedlen", one_chip)
+    ops = re.findall(_OPS, texts[C])
+    assert pools == {"conv/u": "[12,16,8,11520]",
+                     "delta/S": "[12,16,1,30,192,96]",
+                     "delta/at": "[12,16,1,1]",
+                     "delta/row": "[12,16,4,8700]",
+                     "kv/k": "[4,3072,16,3840]", "kv/v": "[4,3072,16,3840]"}
+    # (``at``'s two axes of one element lie where the device likes: 768 B)
+    assert {k: v for k, v in layouts.items() if k != "delta/at"} == {
+        leaf: tuple(range(pool.count(",") + 1))
+        for leaf, pool in pools.items() if leaf != "delta/at"}
+    state = "[12,16,30,192,96]"
+    for whole in ("[4,3072,16,3840]", "[12,16,8,11520]", state,
+                  "[12,16,4,8700]"):
+        assert (whole, "scatter") in ops, whole
+        assert not [op for op in ops if op[0] == whole
+                    and op[1] == "concatenate"], whole
+    for whole in ("[4,3072,16,3840]", state, pools["delta/S"]):
+        assert not [op for op in ops if op[0] == whole and op[1] == "copy"]
+    # (the compiler moves the 27 MB ring whole into its fast memory before
+    # some layers' scatters of the narrow program; its layout stays)
+    assert not re.search(r"\[12,16,4,8700\]\{(?!3,2,1,0)", texts[C])
+    behind = [tuple(map(int, d[1:-1].split(","))) for d, _ in ops
+              if d.endswith(",30,192,96]")]
+    # the stacked pool, and never more states at once than the 16 slots'
+    assert behind and not [d for d in behind if d[0] != 12
+                           and math.prod(d[:-3]) > 16], sorted(set(behind))
+    assert not [op for op in ops if op[0].endswith((",12800,3840]",
+                                                    ",800,16,3840]"))]
+    # a verify row's chunk a slot; eight of a prompt's chunks at a time
+    chunk = {512: "[8,30,64,288]", 5: "[16,30,9,288]"}[C]
+    assert [op for op in ops if op[0] == chunk], chunk
+    assert not [op for op in ops if op[1] == "triangular-solve"]
+    # (the solver was ``custom-call f32[26,30,1,64,64]`` / ``[16,30,1,9,9]``)
+    assert not [op for op in ops if op[1] == "custom-call"
+                and op[0].endswith((",1,64,64]", ",1,9,9]"))]
 
 
 @pytest.mark.parametrize("C", [256, 4])
