@@ -280,16 +280,14 @@ def attn_blocks(cfg: ConvMoeConfig, S: int, C: int, ctx: int
 def _conv_cached(p, h, cfg, j, cache, t: paged.Tick):
     """Conv layer (the state kind's j-th) on the tick's rows h: a row's
     earlier ``u`` are the rows before it where those are its own slot's new
-    tokens, else the slot's state as the LAST tick left it, read before
-    this tick's ``u`` are written over it (paged.state_read)."""
+    tokens, else the slot's state as the LAST tick left it, read a slot at
+    a time before this tick's last ``u`` are laid over it
+    (paged.state_read, paged.write_slots)."""
     c, u = _conv_in(p, h, cfg)
     with jax.named_scope("conv/state"):
-        pool = cache[CONV]["u"]
-        flat = u.reshape(-1, u.shape[-1])
-        before = lambda back: paged.state_read(
-            pool, j, flat, *t.row, back).reshape(u.shape)
-        earlier = [before(back) for back in range(1, cfg.conv_taps)]
-        cache = dict(cache, **{CONV: paged.write(
+        earlier = paged.state_read(cache[CONV]["u"], j, u, t,
+                                   cfg.conv_taps - 1)
+        cache = dict(cache, **{CONV: paged.write_slots(
             cache[CONV], j, *t.lands[CONV], {"u": u})})
     return _conv_out(p, c, u, lambda back: earlier[back - 1], cfg), cache
 
@@ -348,7 +346,7 @@ def _forward(params, tokens, cfg, cache, tables, lengths, n_new, head):
         layer, lambda x: _head(params, x, cfg), cache_kinds(cfg), params,
         tokens, cfg, cache, tables, lengths, n_new, head,
         counters=TICK_COUNTERS, max_seq=cfg.max_seq,
-        reads=("row", "valid", "pos"))
+        reads=("valid", "pos"))
 
 
 #: decoder.cached_pair has the contract: ``cache`` is a dict by kind,

@@ -605,11 +605,9 @@ def _delta_cached(p, x, cfg, j, cache, t: paged.Tick):
     u, z, a_, b_ = _gdn_in(p, x)
     flat = lambda a: a.reshape((-1,) + a.shape[2:])
     with jax.named_scope("gdn/state"):
-        pool = cache[CONV]["u"]
-        earlier = [paged.state_read(pool, j, flat(u), *t.row, back
-                                    ).reshape(u.shape)
-                   for back in range(1, cfg.conv_kernel)]
-        conv = paged.write(cache[CONV], j, *t.lands[CONV], {"u": u})
+        earlier = paged.state_read(cache[CONV]["u"], j, u, t,
+                                   cfg.conv_kernel - 1)
+        conv = paged.write_slots(cache[CONV], j, *t.lands[CONV], {"u": u})
         delta = cache[DELTA]
         S0, at = paged.committed(delta, j, t.lengths, "S")
         ring = _unpack(paged.replay_read(delta["row"], j, at), cfg)
@@ -622,11 +620,8 @@ def _delta_cached(p, x, cfg, j, cache, t: paged.Tick):
     R = delta["row"].shape[2]                   # the ring's rows
     n = t.n_new
     a = jnp.where(n > 0, t.lengths - at, 0)     # rows to replay
-    # where a slot's own rows begin among the tick's rows
-    start = (t.slab.rows[:, 0] if t.slab.rows is not None
-             else jnp.arange(S) * C)
     back = n <= R + 1                           # may be taken back
-    o_n, S1 = _narrow(rows, ring, S0, start, a, jnp.where(back, n, 0),
+    o_n, S1 = _narrow(rows, ring, S0, t.start, a, jnp.where(back, n, 0),
                       paged.commit_row(n, R) * back, 2 * R + 1)
     slot, pos, length = t.row
     col = pos - length + a[slot]                # a row's place in its slot's
@@ -635,14 +630,14 @@ def _delta_cached(p, x, cfg, j, cache, t: paged.Tick):
         N, T = rows["q"].shape[0], cfg.chunk
         most = min(S, N // (R + 2))             # slots that hold a chunk
         o_w, begin, S1 = _wide(
-            rows, ring, S0, S1, start, a, n, ~back & (n > 0), T,
+            rows, ring, S0, S1, t.start, a, n, ~back & (n > 0), T,
             most + -(-(N + most * R) // T))
         o = jnp.where(back[slot][:, None, None], o, o_w[
             jnp.clip(begin[slot] + col // T, 0, o_w.shape[0] - 1), :,
             col % T])
     with jax.named_scope("gdn/state"):
-        kept = paged.write({"row": delta["row"]}, j, *t.lands[DELTA],
-                           {"row": _pack(own)})
+        kept = paged.write_slots({"row": delta["row"]}, j,
+                                 *t.lands[DELTA], {"row": _pack(own)})
         cache = dict(cache, **{
             CONV: conv,
             DELTA: paged.commit(dict(delta, **kept), j, t.lengths, n, R,

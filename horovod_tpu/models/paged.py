@@ -23,16 +23,22 @@ third in three forms (docs/serving.md#cache-kinds):
   * a FIXED STATE a slot (models/conv_moe.py: a short convolution's last
     inputs): no blocks, no table, no allocator.  The pool is ``[layers,
     slots, columns, ...]``, position P of slot s lies at ``[s, P %
-    columns]`` (:func:`state_index`), and a layer reads back the columns of
-    the last positions before its tick's own (:func:`state_read`);
+    columns]``, and a tick reads and writes it a SLOT, not a row: a row's
+    predecessors are the tick's own rows above it, and only a slot's first
+    rows need the pool, which gives the ``state`` columns of the slot's
+    last positions ONCE a layer (:func:`state_head`; :func:`state_read`
+    lays them before the slot's rows); of a slot's rows its LAST
+    ``columns`` land, gathered from the tick's rows and laid over the
+    layer's columns (:func:`state_lands`, :func:`write_slots`);
     :func:`state_columns` says how many columns keep those through a
     rejected draft.  Where what a layer keeps FOLDS all earlier positions
     (models/sambay.py: a selective scan's carry) a column is no input of a
     position but the state AFTER it: a tick reads the ONE column at its
     slot's last position (:func:`carry_read`, ``state`` = 1) and writes one
-    after each of its last rows at the same ``[s, P % columns]``, so that a
-    verify row of which ``a`` drafts are accepted leaves the carry after
-    row ``a`` where the next tick looks for it.  What lies behind the
+    after each of its last rows at the same ``[s, P % columns]``
+    (:func:`carry_index`: the scan hands them out a row at a time), so
+    that a verify row of which ``a`` drafts are accepted leaves the carry
+    after row ``a`` where the next tick looks for it.  What lies behind the
     columns is the model's: ``[.., d]``, or a carry's ``[.., d_state, d]``.
     Where that folded state is too large for a column a row
     (models/gdn_hybrid.py: a delta rule's MATRIX a head) the kind declares
@@ -40,8 +46,10 @@ third in three forms (docs/serving.md#cache-kinds):
     state a slot, the position it stands after (``at``) and a ring of the
     last verify row's inputs: a tick reads the state (:func:`committed`),
     REPLAYS the ring's rows from ``at`` up to its slot's length
-    (:func:`replay_read`), runs its own, and commits the state after its
-    last row that no later tick can take back (:func:`commit_row`).
+    (:func:`replay_read`), runs its own, commits the state after its last
+    row that no later tick can take back (:func:`commit_row`) and lays the
+    rows behind it over the ring, a slot at a time as well
+    (:func:`state_lands` with ``replay``).
 
 What a module declares of its kinds is enough for what every module needs of
 them: :func:`tick` does one tick's slot arithmetic for all of them, once
@@ -53,7 +61,7 @@ before the layers (:class:`Tick`), and :func:`init_pools` and
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -109,40 +117,78 @@ def state_columns(state: int, tick_cols: int) -> int:
     return state + tick_cols
 
 
-def state_index(lengths: jax.Array, n_new: jax.Array, valid: jax.Array,
-                positions: jax.Array, cols: int
+def state_lands(lengths: jax.Array, n_new: jax.Array, start: jax.Array,
+                cols: int, rows: int, replay: bool = False
                 ) -> Tuple[jax.Array, jax.Array]:
-    """(slot, col) [S, C] for :func:`write` into a state kind's pool
-    ``[layers, S, cols, ...]``: position P of slot s lands in ``[s, P %
-    cols]``.  Of a chunk longer than the ring only the LAST ``cols``
-    positions land (two positions of one tick never meet in a column);
-    what does not land, and every invalid position, goes to slot ``S``, off
-    the axis, where :func:`write` drops it."""
-    S = lengths.shape[0]
-    lands = valid & (positions >= (lengths + n_new)[:, None] - cols)
-    slot = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[:, None],
-                            positions.shape)
-    return jnp.where(lands, slot, S), positions % cols
+    """(row, keeps) [S, cols] for :func:`write_slots` into a state kind's
+    pool ``[layers, S, cols, ...]``, BY SLOT: column c of slot s takes the
+    tick's row ``row[s, c]`` — a slot's rows lie side by side in slab order
+    from ``start[s]`` on (:func:`pack`) — where ``keeps[s, c]``, and keeps
+    what it held elsewhere.  Position P of slot s lands in ``[s, P %
+    cols]``, so the column holds the LAST of the slot's positions that
+    falls into it: of a chunk longer than the ring only the last ``cols``
+    positions land, and a slot that runs no row keeps all it held.  With
+    ``replay`` (the ring ``[layers, S, rows, ...]`` of a ``replay`` kind)
+    every column BEHIND the first of a row that commits after its first
+    (:func:`commit_row`) lands, and a longer row leaves the ring alone.  A
+    position that the tick's ``rows`` rows do not hold does not land."""
+    at = ring_positions(lengths + n_new - 1, cols)
+    own = at - lengths[:, None]         # the position's column in the tick
+    keeps = own >= int(replay)
+    if replay:
+        keeps &= (n_new <= cols + 1)[:, None]
+    row = start[:, None] + own
+    return jnp.clip(row, 0, rows - 1), keeps & (row < rows)
 
 
-def state_read(pool: jax.Array, layer: int, own: jax.Array, slot: jax.Array,
-               positions: jax.Array, lengths: jax.Array, back: int
+def state_head(pool: jax.Array, layer: int, lengths: jax.Array, state: int
                ) -> jax.Array:
-    """What lay ``back`` positions before each of a tick's rows, ``own``
-    ``[N, d]`` being the rows' own values in slab order (:func:`pack` keeps
-    a slot's tokens side by side) at ``positions`` [N] of slots ``slot``
-    [N] that held ``lengths`` [N] positions before the tick: the row
-    ``back`` rows up where that position is the tick's own (never a
-    neighbour slot's row: its position would lie below ``lengths``), else
-    the slot's column of ``pool[layer]`` ``[S, cols, d]`` AS IT WAS BEFORE
-    THE TICK — read before :func:`write` —, and zero below position 0: a
-    slot's new tenant reads nothing of the stream that left it."""
-    at = positions - back
-    kept = pool[layer, slot, at % pool.shape[2]]
-    mine = jnp.pad(own, ((back, 0), (0, 0)))[:own.shape[0]]
-    return jnp.where((at >= 0)[:, None],
-                     jnp.where((at >= lengths)[:, None], mine, kept),
-                     jnp.zeros((), own.dtype))
+    """What each slot held at its last ``state`` positions, ``[S, state,
+    d]``: index i is position ``lengths - state + i`` of ``pool[layer]``
+    ``[S, cols, d]`` AS IT WAS BEFORE THE TICK — read before
+    :func:`write_slots` —, and zero below position 0: a slot's new tenant
+    reads nothing of the stream that left it.  The one read of the pool a
+    layer makes: ``S * state`` rows whatever the tick's width."""
+    at = lengths[:, None] - state + jnp.arange(state)[None, :]
+    kept = jnp.take_along_axis(
+        pool[layer], (at % pool.shape[2])[..., None], axis=1)
+    return jnp.where((at >= 0)[..., None], kept, jnp.zeros((), pool.dtype))
+
+
+def state_read(pool: jax.Array, layer: int, own: jax.Array, t: "Tick",
+               state: int) -> List[jax.Array]:
+    """What lay ``back`` = 1 .. ``state`` positions before each of a tick's
+    rows (index ``back - 1``, each shaped like ``own``), ``own`` ``[.., d]``
+    being the rows' own values in slab order (:func:`pack` keeps a slot's
+    tokens side by side): a tick's own rows are their own predecessors —
+    the row ``back`` rows up — but for the first ``back`` rows of each live
+    slot, before which :func:`state_head`'s rows are laid (never a
+    neighbour slot's row, and nothing over a row that is not the slot's
+    own).  The ``back``s share the one read of the pool."""
+    rows = own.reshape(-1, own.shape[-1])
+    N = rows.shape[0]
+    head = state_head(pool, layer, t.lengths, state).astype(own.dtype)
+    col = jnp.arange(state)[None, :]
+    at = jnp.where(col < t.n_new[:, None], t.start[:, None] + col, N)
+    return [jnp.pad(rows, ((back, 0), (0, 0)))[:N].at[at[:, :back]].set(
+        head[:, state - back:], mode="drop").reshape(own.shape)
+        for back in range(1, state + 1)]
+
+
+def carry_index(t: "Tick", cols: int) -> Tuple[jax.Array, jax.Array]:
+    """(slot, col) BY ROW, shaped like the tick's rows (``t.slot``), for
+    :func:`write` of the carries a scan leaves after EACH row into
+    ``[layers, S, cols, ...]``: the carry after position P of slot s lands
+    in ``[s, P % cols]``, the last ``cols`` of a long chunk only; what does
+    not land, and every row that holds no token, goes to slot ``S``, off
+    the axis, where :func:`write` drops it.  Needs ``t.slot`` and
+    ``t.row``."""
+    slot, pos, length = t.row
+    held = length + t.n_new[slot]
+    lands = (pos < held) & (pos >= held - cols)
+    shaped = lambda a: a.reshape(t.slot.shape)
+    return (shaped(jnp.where(lands, slot, t.lengths.shape[0])),
+            shaped(pos % cols))
 
 
 def carry_read(pool: jax.Array, layer: int, lengths: jax.Array) -> jax.Array:
@@ -152,7 +198,7 @@ def carry_read(pool: jax.Array, layer: int, lengths: jax.Array) -> jax.Array:
     slot's new tenant starts from nothing; so does a slot that runs no row,
     whose length the tick is handed as 0).  The counterpart of
     :func:`state_read` for a state that folds its past: the tick's own rows
-    are scanned from it, and :func:`write` at :func:`state_index` keeps the
+    are scanned from it, and :func:`write` at :func:`carry_index` keeps the
     state after each of the last ``cols`` of them."""
     S = lengths.shape[0]
     kept = pool[layer, jnp.arange(S), (lengths - 1) % pool.shape[2]]
@@ -174,7 +220,7 @@ def replay_rows(tick_cols: int) -> int:
     whatever is accepted, so the state AFTER it is committed
     (:func:`commit_row`), and rows ``L+1 .. L+n-1`` are the ``n - 1 <=
     tick_cols - 1`` that may be taken back: their inputs go to the ring at
-    ``position % rows`` (:func:`replay_index`), no two of them in one entry,
+    ``position % rows`` (:func:`state_lands`), no two of them in one entry,
     and the next tick replays ``L+1 .. L+a`` of them (:func:`replay_read`)
     before anything overwrites them — its own write comes after its read.
     At least one row, so that no pool is empty without speculation."""
@@ -191,22 +237,6 @@ def commit_row(n_new: jax.Array, rows: int) -> jax.Array:
     has the argument).  The tick cannot tell a verify row from a prompt's
     tail of the same width and need not: a tail's rows are replayed all."""
     return jnp.where(n_new <= rows + 1, 0, n_new - 1)
-
-
-def replay_index(lengths: jax.Array, n_new: jax.Array, valid: jax.Array,
-                 positions: jax.Array, rows: int
-                 ) -> Tuple[jax.Array, jax.Array]:
-    """(slot, entry) [S, C] for :func:`write` into a ``replay`` kind's ring
-    ``[layers, S, rows, ...]``: of a row that commits after its first
-    column (:func:`commit_row`) every LATER column lands in ``[s, P %
-    rows]``; everything else goes to slot ``S``, off the axis, where
-    :func:`write` drops it."""
-    S = lengths.shape[0]
-    keeps = (valid & (n_new <= rows + 1)[:, None]
-             & (positions > lengths[:, None]))
-    slot = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[:, None],
-                            positions.shape)
-    return jnp.where(keeps, slot, S), positions % rows
 
 
 def committed(pool: Any, layer: int, lengths: jax.Array, leaf: str
@@ -226,7 +256,7 @@ def committed(pool: Any, layer: int, lengths: jax.Array, leaf: str
 def replay_read(ring: jax.Array, layer: int, at: jax.Array) -> jax.Array:
     """A slot's ring in the order it is replayed, ``[S, rows, ...]``: index
     ``e`` is what position ``at + e`` left in ``ring[layer]`` ``[S, rows,
-    ...]`` (:func:`replay_index`); the first ``lengths - at`` of them were
+    ...]`` (:func:`state_lands`); the first ``lengths - at`` of them were
     accepted."""
     S, rows = ring.shape[1:3]
     entry = (at[:, None] + jnp.arange(rows)[None, :]) % rows
@@ -364,17 +394,20 @@ class Tick(NamedTuple):
     take: Callable          # [S, C, ...] -> rows [1, R, ...] (pack)
     slab: Slab              # rows -> [S, C, ...] (or a block of it, or the
                             # columns a tick reads), zero where left out
+    start: jax.Array        # [S] where a slot's rows begin among the rows
     # by the name of a kind: where the rows land in a paged or a ring kind's
-    # pool, (blk, off) for write (write_index), and in a state kind's,
-    # (slot, col) (state_index)
+    # pool, (blk, off) by row for write (write_index), and which of the rows
+    # land in a state kind's, (row, keeps) by slot for write_slots
+    # (state_lands)
     where: Dict[Optional[str], Tuple[jax.Array, jax.Array]]
     lands: Dict[Optional[str], Tuple[jax.Array, jax.Array]]
     valid: Optional[jax.Array] = None   # the rows that hold a token
     pos: Optional[jax.Array] = None     # the rows' positions, inside the
                                         # rope table
     top: Optional[jax.Array] = None     # [S] a slot's last written position
-    # by row: whose slot a row is, what state_read asks (slot, position, the
-    # slot's length; flat [N]), and where a slot's rows begin
+    # by row: whose slot a row is, (slot, position, the slot's length) flat
+    # [N] (carry_index and a family's own arithmetic ask them), and whether
+    # a row is its slot's first
     slot: Optional[jax.Array] = None
     row: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None
     first: Optional[jax.Array] = None
@@ -387,9 +420,12 @@ def tick(kinds: Tuple[CacheKind, ...], cache: Any, tables: Any,
     for every kind of cache the module declares: where each position lies
     (:func:`slot_positions`), the ``rows`` the tick's tokens are packed to
     (:func:`pack`; the config's ``max_tick_tokens``), and where the rows
-    land in each of ``kinds`` — a ring where ``kind.window``, a slot's
-    column where ``kind.state``, the ring's entry where ``kind.replay``.  ``cache`` and ``tables`` are the module's
-    own, dicts by kind or the one pool and its table.
+    land in each of ``kinds`` — a ring where ``kind.window``; where
+    ``kind.state``, which rows land in a slot's columns, or in the ring's
+    entries where ``kind.replay`` (a kind whose writer goes by row, a
+    carry's snapshots through :func:`carry_index`, leaves its entry unread,
+    and nothing of it is lowered).  ``cache`` and ``tables`` are the
+    module's own, dicts by kind or the one pool and its table.
 
     ``reads`` names what else the family's mixers read of :class:`Tick`,
     and is worked out here too, in that order, before any layer: a field
@@ -397,6 +433,9 @@ def tick(kinds: Tuple[CacheKind, ...], cache: Any, tables: Any,
     trace alone.  ``pos`` needs ``max_seq``, the rope table's length."""
     positions, valid = slot_positions(lengths, n_new, C)
     take, slab = pack(valid, rows)
+    S = lengths.shape[0]
+    start = (slab.rows[:, 0] if slab.rows is not None
+             else jnp.arange(S, dtype=jnp.int32) * C)
     where, lands = {}, {}
     for kind in kinds:
         pool = jax.tree_util.tree_leaves(_of(cache, kind))[0]
@@ -408,12 +447,11 @@ def tick(kinds: Tuple[CacheKind, ...], cache: Any, tables: Any,
         else:
             if kind.replay:     # the ring's rows, not the ONE state's column
                 pool = _of(cache, kind)[next(iter(kind.replay))]
-            index = replay_index if kind.replay else state_index
-            slot, col = index(lengths, n_new, valid, positions,
-                              pool.shape[2])
-            lands[kind.name] = (take(slot), take(col))
+            lands[kind.name] = state_lands(
+                lengths, n_new, start, pool.shape[2], slab.kept or S * C,
+                replay=bool(kind.replay))
     wide = lambda a: take(jnp.broadcast_to(a[:, None], positions.shape))
-    slots = lambda: wide(jnp.arange(lengths.shape[0], dtype=jnp.int32))
+    slots = lambda: wide(jnp.arange(S, dtype=jnp.int32))
     read: Dict[str, Any] = {}
     how = {
         "valid": lambda: take(valid),
@@ -427,7 +465,8 @@ def tick(kinds: Tuple[CacheKind, ...], cache: Any, tables: Any,
     }
     for name in reads:
         read[name] = how[name]()
-    return Tick(positions, lengths, n_new, take, slab, where, lands, **read)
+    return Tick(positions, lengths, n_new, take, slab, start, where, lands,
+                **read)
 
 
 def write(pool: Any, layer: int, blk: jax.Array, off: jax.Array,
@@ -438,6 +477,22 @@ def write(pool: Any, layer: int, blk: jax.Array, off: jax.Array,
         return jax.tree_util.tree_map(
             lambda p, v: p.at[layer, blk, off].set(v, mode="drop"),
             pool, values)
+
+
+def write_slots(pool: Any, layer: int, row: jax.Array, keeps: jax.Array,
+                values: Any) -> Any:
+    """``values`` (a pytree like ``pool``; the tick's rows ``[1, R, ...]``
+    or ``[S, C, ...]``) into layer ``layer`` of a state kind's stacked pool,
+    BY SLOT (:func:`state_lands`): the ``S * cols`` rows that may land are
+    gathered from the tick's rows and laid over the layer's ``[S, cols,
+    ...]`` where ``keeps``; every other column, and a slot that runs no
+    row, keeps what it held."""
+    def lay(p, v):
+        got = v.reshape((-1,) + v.shape[2:])[row].astype(p.dtype)
+        keep = keeps.reshape(keeps.shape + (1,) * (got.ndim - 2))
+        return p.at[layer].set(jnp.where(keep, got, p[layer]))
+    with jax.named_scope("kv_write"):
+        return jax.tree_util.tree_map(lay, pool, values)
 
 
 def gather(pool: Any, layer: int, block_tables: jax.Array) -> Any:

@@ -474,12 +474,9 @@ def _mamba_cached(p, a, cfg, j, cache, t: paged.Tick):
     mixer's output, y before the gate, cache)."""
     u, z = _ssm_in(p, a)
     with jax.named_scope("ssm/state"):
-        pool = cache[CONV]["u"]
-        flat = u.reshape(-1, u.shape[-1])
-        earlier = [paged.state_read(pool, j, flat, *t.row, back
-                                    ).reshape(u.shape)
-                   for back in range(1, cfg.d_conv)]
-        conv = paged.write(cache[CONV], j, *t.lands[CONV], {"u": u})
+        earlier = paged.state_read(cache[CONV]["u"], j, u, t,
+                                   cfg.d_conv - 1)
+        conv = paged.write_slots(cache[CONV], j, *t.lands[CONV], {"u": u})
         carry = paged.carry_read(cache[CARRY]["h"], j, t.lengths)
     u = _ssm_conv(p, u, lambda back: earlier[back - 1], cfg)
     if t.slab.rows is None:     # the rows are the slab: a slot a row of it
@@ -491,7 +488,8 @@ def _mamba_cached(p, a, cfg, j, cache, t: paged.Tick):
     with jax.named_scope("ssm/state"):
         # the carries come out of the loop row-major [T, G, ..]: the small
         # index arrays turn, not the carries
-        slot, col = (jnp.moveaxis(x, 1, 0) for x in t.lands[CARRY])
+        slot, col = (jnp.moveaxis(x, 1, 0) for x in paged.carry_index(
+            t, cache[CARRY]["h"].shape[2]))
         cache = dict(cache, **{
             CONV: conv,
             CARRY: paged.write(cache[CARRY], j, slot, col, {"h": hs})})

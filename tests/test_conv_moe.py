@@ -456,12 +456,11 @@ def test_a_tenant_that_read_its_predecessors_state_would_differ(
         jnp.zeros(1, jnp.int32), jnp.full(1, 8, jnp.int32))[0]
     assert _gap(run(), want) < TOL
 
-    def unmasked(pool, layer, own, slot, positions, lengths, back):
-        at = positions - back
-        mine = jnp.pad(own, ((back, 0), (0, 0)))[:own.shape[0]]
-        return jnp.where((at >= lengths)[:, None], mine,
-                         pool[layer, slot, at % pool.shape[2]])
-    monkeypatch.setattr(paged, "state_read", unmasked)
+    def unmasked(pool, layer, lengths, state):
+        at = lengths[:, None] - state + jnp.arange(state)[None, :]
+        return pool[layer, jnp.arange(pool.shape[1])[:, None],
+                    at % pool.shape[2]]
+    monkeypatch.setattr(paged, "state_head", unmasked)
     assert _gap(run(), want) > 40 * TOL
 
 

@@ -66,30 +66,33 @@ def _sizes(kinds):
 
 
 def _by_hand(kind, table, cols):
-    """Where each packed row lands in ``kind``'s pool, a row a valid
-    position in slab order, then the rows nobody holds (off the axis)."""
-    out = []
+    """Where each packed row lands in a paged or a ring ``kind``'s pool, a
+    row a valid position in slab order; in a state kind's, by slot: {(slot,
+    column): the row that lands there}, every other column keeping what it
+    held."""
+    out, by_slot, row = [], {}, 0
     for s, (n, L) in enumerate(PLAN):
         for P in range(L, L + n):
             if kind.replay:     # behind a row's first column, if it fits
-                lands = n <= cols + 1 and P > L
-                out.append((s if lands else S, P % cols))
+                if n <= cols + 1 and P > L:
+                    by_slot[s, P % cols] = row
             elif kind.state is not None:
-                lands = P >= L + n - cols
-                out.append((s if lands else S, P % cols))
+                if P >= L + n - cols:
+                    by_slot[s, P % cols] = row
             elif kind.window is not None:
                 out.append((table[s, (P // BS) % table.shape[1]], P % BS))
             else:
                 out.append((table[s, P // BS], P % BS))
-    return np.asarray(out)
+            row += 1
+    return by_slot if kind.state is not None else np.asarray(out)
 
 
 @pytest.mark.parametrize("name", MODULES)
 def test_the_tick_is_the_addressing_by_hand(name):
     """``paged.tick`` over a module's declared kinds: the rows' positions,
     where they land in a paged kind's pool, in a ring's and in a state's
-    columns, what the state's read asks by row — and nothing for a kind the
-    module did not declare."""
+    columns by slot, what a family reads by row — and nothing for a kind
+    the module did not declare."""
     model, cfg, kinds = _module(name)
     blocks, tables = _sizes(kinds)
     cache = jax.eval_shape(lambda: model.init_cache(cfg, blocks, BS))
@@ -119,19 +122,25 @@ def test_the_tick_is_the_addressing_by_hand(name):
         (k.name for k in paged_kinds), key=str)
     assert sorted(t.lands) == sorted(
         k.name for k in kinds if k.state is not None)
+    # a slot's rows begin where the packed rows before it end
+    assert np.array_equal(np.asarray(t.start)[N_NEW > 0],
+                          (np.cumsum(N_NEW) - N_NEW)[N_NEW > 0])
     for k in kinds:
         pool = jax.tree_util.tree_leaves(_part(cache, k.name))[0]
         if k.state is None:
             want = _by_hand(k, _part(tables, k.name), None)
-            got, off_axis = t.where[k.name], pool.shape[1]
-        else:
-            if k.replay:    # the ring's rows, not the ONE state's column
-                pool = _part(cache, k.name)[next(iter(k.replay))]
-            want = _by_hand(k, None, pool.shape[2])
-            got, off_axis = t.lands[k.name], S
-        got = np.stack([np.asarray(a)[0] for a in got], axis=1)
-        assert np.array_equal(got[:n], want), k
-        assert (got[n:, 0] == off_axis).all(), k
+            got = np.stack([np.asarray(a)[0] for a in t.where[k.name]],
+                           axis=1)
+            assert np.array_equal(got[:n], want), k
+            assert (got[n:, 0] == pool.shape[1]).all(), k
+            continue
+        if k.replay:    # the ring's rows, not the ONE state's column
+            pool = _part(cache, k.name)[next(iter(k.replay))]
+        row, keeps = (np.asarray(a) for a in t.lands[k.name])
+        assert row.shape == keeps.shape == (S, pool.shape[2])
+        want = _by_hand(k, None, pool.shape[2])
+        assert {(s, c): row[s, c] for s, c in zip(*np.nonzero(keeps))} \
+            == want, k
 
 
 def test_the_tick_holds_only_what_the_family_reads():

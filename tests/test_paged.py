@@ -394,35 +394,134 @@ def test_every_served_module_keeps_the_contract(name):
 
 
 # ------------------------------------------------ a fixed state a slot
+def _state_tick(kind, pool, lengths, n_new, C, budget=0,
+                reads=("slot", "row")):
+    """The tick of ONE state kind's pool (a dict of its leaves)."""
+    return paged.tick((kind,), {kind.name: pool}, {}, jnp.asarray(lengths),
+                      jnp.asarray(n_new), C, rows=budget, reads=reads)
+
+
 def test_the_state_kinds_addressing_on_a_tick():
-    """paged.state_index / state_read by hand: where a tick's columns land
-    (the last ``cols`` of a long chunk only), and what each row reads back:
-    its own slot's earlier rows, the state as it was before the tick, zero
-    before position 0 — never the neighbour's row."""
+    """paged.state_lands / state_read by hand: which of a tick's rows land
+    in a slot's columns (the last ``cols`` of a long chunk only), and what
+    each row reads back: its own slot's earlier rows, the state as it was
+    before the tick, zero before position 0 — never the neighbour's row."""
     cols, S, C = 4, 3, 6
     lengths = jnp.asarray([0, 5, 9], jnp.int32)
     n_new = jnp.asarray([6, 1, 0], jnp.int32)
-    positions, valid = paged.slot_positions(lengths, n_new, C)
-    slot, col = paged.state_index(lengths, n_new, valid, positions, cols)
-    # slot 0 writes positions 2..5 of its six (the last four), slot 1 its
-    # one, slot 2 nothing; what does not land goes to slot S, off the axis
-    assert slot.tolist() == [[3, 3, 0, 0, 0, 0], [1, 3, 3, 3, 3, 3], [3] * 6]
-    assert col[0].tolist() == [0, 1, 2, 3, 0, 1] and int(col[1, 0]) == 1
     # pool[l, s, c] = 100 s + the position that column held before the tick
     held = np.zeros((1, S, cols, 1), np.float32)
     for s, L in enumerate(lengths.tolist()):
         for p in range(max(0, L - cols), L):
             held[0, s, p % cols, 0] = 100 * s + p
-    take, _ = paged.pack(valid, 8)
-    own = take(1000.0 + 10 * jnp.arange(S)[:, None]
-               + jnp.arange(C)[None].astype(jnp.float32))[0][:, None]
-    wide = lambda a: take(jnp.broadcast_to(a[:, None], (S, C)))[0]
-    row = (wide(jnp.arange(S)), take(positions)[0], wide(lengths))
-    back1 = paged.state_read(jnp.asarray(held), 0, own, *row, 1)[:7, 0]
-    back2 = paged.state_read(jnp.asarray(held), 0, own, *row, 2)[:7, 0]
+    kind = paged.CacheKind("conv", 1, state=2, leaves={"u": (1,)})
+    t = _state_tick(kind, {"u": jnp.asarray(held)}, lengths, n_new, C, 8)
+    row, keeps = t.lands["conv"]
+    # slot 0 writes positions 2..5 of its six (the last four: rows 2..5, at
+    # columns 2, 3, 0, 1), slot 1 its one (row 6, column 5 % 4), slot 2
+    # nothing; what does not land keeps what it held
+    assert keeps.tolist() == [[True] * 4, [False, True, False, False],
+                              [False] * 4]
+    assert row[0].tolist() == [4, 5, 2, 3] and int(row[1, 1]) == 6
+    own = t.take(1000.0 + 10 * jnp.arange(S)[:, None]
+                 + jnp.arange(C)[None].astype(jnp.float32))[0][:, None]
+    back1, back2 = (e[:7, 0] for e in paged.state_read(
+        jnp.asarray(held), 0, own, t, 2))
     # rows: slot 0's six (positions 0..5), then slot 1's one (position 5)
     assert back1.tolist() == [0, 1000, 1001, 1002, 1003, 1004, 104]
     assert back2.tolist() == [0, 0, 1000, 1001, 1002, 1003, 103]
+    out = np.asarray(paged.write_slots(
+        {"u": jnp.asarray(held)}, 0, row, keeps, {"u": own[None]})["u"])
+    assert out[0, 0, :, 0].tolist() == [1004, 1005, 1002, 1003]
+    assert out[0, 1, :, 0].tolist() == [104, 1010, 102, 103]
+    assert (out[0, 2] == held[0, 2]).all()
+
+
+# ------- a slot at a time against a row at a time: the reference kept here
+def _by_row_read(pool, layer, own, slot, positions, lengths, back):
+    """``paged.state_read`` as it was by ROW: every row gathers its slot's
+    column of the pool, then the tick's own rows are laid over."""
+    at = positions - back
+    kept = pool[layer, slot, at % pool.shape[2]]
+    mine = jnp.pad(own, ((back, 0), (0, 0)))[:own.shape[0]]
+    return jnp.where((at >= 0)[:, None],
+                     jnp.where((at >= lengths)[:, None], mine, kept),
+                     jnp.zeros((), own.dtype))
+
+
+def _by_row_lands(lengths, n_new, valid, positions, cols, replay):
+    """``paged.state_index`` / ``replay_index`` as they were: (slot, col)
+    [S, C] by row, what does not land at slot ``S``, off the axis."""
+    S = lengths.shape[0]
+    if replay:
+        lands = (valid & (n_new <= cols + 1)[:, None]
+                 & (positions > lengths[:, None]))
+    else:
+        lands = valid & (positions >= (lengths + n_new)[:, None] - cols)
+    slot = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[:, None],
+                            positions.shape)
+    return jnp.where(lands, slot, S), positions % cols
+
+
+#: name -> (lengths, n_new) of five slots in a tick of twelve columns over a
+#: state of 3 with a verify row of 5: eight columns, or a ring of four rows
+SLOT_PLANS = {
+    "a_chunk_longer_than_the_ring": ([16, 3, 40, 0, 7], [12, 1, 10, 0, 9]),
+    "a_chunk_shorter_than_the_state": ([5, 0, 11, 2, 0], [2, 1, 2, 1, 2]),
+    "a_dead_slot": ([9, 7, 0, 30, 4], [5, 0, 0, 1, 0]),
+    "a_new_tenant": ([0, 0, 0, 6, 0], [12, 3, 1, 4, 5]),
+    "a_verify_row": ([9, 21, 2, 0, 100], [5, 5, 5, 5, 5]),
+}
+
+
+@pytest.mark.parametrize("replay", [False, True], ids=["state", "replay"])
+@pytest.mark.parametrize("packed", [False, True], ids=["slab", "packed"])
+@pytest.mark.parametrize("plan", sorted(SLOT_PLANS))
+def test_a_state_kind_by_slot_is_the_addressing_by_row(plan, packed, replay):
+    """What a tick reads of a state kind's pool and leaves in it, a SLOT at
+    a time (paged.state_read, state_lands, write_slots), is bit for bit
+    what it read and left a ROW at a time — every row's gather of
+    ``pool[layer, slot, at % cols]`` and the scatter of all rows with the
+    ones that do not land dropped —: ``earlier[back]`` at every row that
+    holds a token, and the pool's contents whole, the other layer and the
+    slots that run no row included.  Over a pool of noise that another
+    stream left in every slot."""
+    state, spec, S, C, d = 3, 5, 5, 12, 3
+    lengths, n_new = (jnp.asarray(x, jnp.int32) for x in SLOT_PLANS[plan])
+    cols = (paged.replay_rows(spec) if replay
+            else paged.state_columns(state, spec))
+    kind = (paged.CacheKind("k", 2, state=1, leaves={"S": (2, 2)},
+                            replay={"u": (d,)}) if replay else
+            paged.CacheKind("k", 2, state=state, leaves={"u": (d,)}))
+    rng = np.random.default_rng(sorted(SLOT_PLANS).index(plan))
+    pool = paged.init_pools((kind,), {"k": (S, cols)}, 4, jnp.bfloat16)["k"]
+    pool = dict(pool, u=jnp.asarray(
+        rng.normal(size=pool["u"].shape), jnp.bfloat16))
+    budget = int(n_new.sum()) + 2 if packed else 0
+    t = _state_tick(kind, pool, lengths, n_new, C, budget)
+    assert (t.slab.rows is not None) == packed
+    own = t.take(jnp.asarray(rng.normal(size=(S, C, d)), jnp.bfloat16))
+    flat = own.reshape(-1, d)
+    valid = np.asarray(t.take(paged.slot_positions(lengths, n_new, C)[1])
+                       ).reshape(-1)
+    assert valid.sum() == int(n_new.sum())
+    if not replay:
+        got = paged.state_read(pool["u"], 1, flat, t, state)
+        for back in range(1, state + 1):
+            want = _by_row_read(pool["u"], 1, flat, *t.row, back)
+            assert np.array_equal(np.asarray(got[back - 1])[valid],
+                                  np.asarray(want)[valid]), back
+    positions, slab_valid = paged.slot_positions(lengths, n_new, C)
+    slot, col = _by_row_lands(lengths, n_new, slab_valid, positions, cols,
+                              replay)
+    want = paged.write({"u": pool["u"]}, 1, t.take(slot), t.take(col),
+                       {"u": own})["u"]
+    got = paged.write_slots({"u": pool["u"]}, 1, *t.lands["k"],
+                            {"u": own})["u"]
+    assert got.dtype == want.dtype and np.array_equal(
+        np.asarray(got, np.float32), np.asarray(want, np.float32))
+    assert np.array_equal(np.asarray(got[0], np.float32),
+                          np.asarray(pool["u"][0], np.float32))
 
 
 @pytest.mark.parametrize("state,tick_cols", [(2, 5), (2, 1), (3, 9), (1, 2)])
@@ -447,7 +546,7 @@ def test_a_states_ring_keeps_what_the_tick_after_a_verify_row_reads(
 
 # ------------------------------------ a state that folds its past: a carry
 def test_the_carrys_read_and_its_snapshots_on_a_tick():
-    """paged.carry_read / state_index / write by hand, on a carry with a
+    """paged.carry_read / carry_index / write by hand, on a carry with a
     shape behind its columns: a tick reads the ONE column of each slot's
     last position as it was before the tick (zero for a slot that holds
     nothing), and the carries after its rows land at ``[s, P % cols]``, the
@@ -469,8 +568,9 @@ def test_the_carrys_read_and_its_snapshots_on_a_tick():
     assert bool((got == got[:, :1, :1]).all())
     # the carries after the tick's rows: 1000 + 10 s + column, time-major as
     # a scan hands them out
-    positions, valid = paged.slot_positions(lengths, n_new, C)
-    slot, col = paged.state_index(lengths, n_new, valid, positions, cols)
+    kind = paged.CacheKind("carry", 2, state=1, leaves={"h": (2, 3)})
+    slot, col = paged.carry_index(
+        _state_tick(kind, {"h": pool}, lengths, n_new, C), cols)
     after = jnp.broadcast_to(
         (1000.0 + 10 * jnp.arange(S)[None, :] + jnp.arange(C)[:, None]
          )[..., None, None], (C, S, 2, 3))
@@ -490,7 +590,7 @@ def test_the_carrys_read_and_its_snapshots_on_a_tick():
 
 # ------------- a state too large for a column a row: ONE a slot, and a replay
 def test_the_replay_kinds_addressing_on_a_tick():
-    """paged.replay_index / committed / replay_read / commit by hand, on a
+    """paged.state_lands / committed / replay_read / commit by hand, on a
     state with a shape behind it: a row that fits the ring behind its first
     column commits after that column and leaves the rest to the ring at
     ``[s, P % rows]``; a longer one (a prompt's chunk) commits after its
@@ -511,15 +611,16 @@ def test_the_replay_kinds_addressing_on_a_tick():
     lengths = jnp.asarray([9, 16, 0, 0], jnp.int32)
     n_new = jnp.asarray([5, 8, 0, 3], jnp.int32)
     assert paged.commit_row(n_new, rows).tolist() == [0, 7, 0, 0]
-    positions, valid = paged.slot_positions(lengths, n_new, C)
-    slot, col = paged.replay_index(lengths, n_new, valid, positions, rows)
-    assert slot.tolist() == [[4, 0, 0, 0, 0, 4, 4, 4], [4] * 8, [4] * 8,
-                             [4, 3, 3, 4, 4, 4, 4, 4]]
-    assert col[0, 1:5].tolist() == [2, 3, 0, 1] and col[3, 1:3].tolist() \
-        == [1, 2]
-    # the tick's own arithmetic gives the same
+    # the ring's entries by slot: slot 0's rows 1..4 (positions 10..13 at
+    # entries 2, 3, 0, 1), slot 3's rows 14 and 15 (positions 1 and 2),
+    # nothing of the chunk and of the idle slot
     t = paged.tick((kind,), {"delta": pool}, {}, lengths, n_new, C)
-    assert np.array_equal(t.lands["delta"][0], slot)
+    row, keeps = t.lands["delta"]
+    assert t.start.tolist() == [0, 8, 16, 24]
+    assert keeps.tolist() == [[True] * 4, [False] * 4, [False] * 4,
+                              [False, True, True, False]]
+    assert row[0].tolist() == [3, 4, 1, 2] and row[3, 1:3].tolist() \
+        == [25, 26]
     # what the pool held: state 100 s, standing after 7, 16, 5 and 11; the
     # ring's entry e of slot s holds 10 s + e
     held = dict(pool)

@@ -89,6 +89,11 @@ def _tick_program(cell, C, one_chip):
 
 
 _OPS = r" = \w+(\[[\d,]*\])\S* ([\w-]+)\("    # (dims, op) of every HLO line
+# how a pool is written in place: rows scattered into it (a paged or a ring
+# kind, a carry's snapshots, a committed state), or ONE layer's columns laid
+# over it a slot at a time (a state kind's inputs, a replay kind's ring:
+# paged.write_slots)
+_BY_SLOT = "dynamic-update-slice"
 
 
 # each leaf's axes from major to minor where the device's default is not
@@ -108,8 +113,8 @@ def test_no_tick_relays_a_pool_on_its_way_in_or_out(one_chip, cell, width):
     default layout for its SHAPE has it — a module's ``init_cache`` decides
     the layout by the shape it gives, nothing else can (docs/serving.md
     #where-the-pool-lies) — and work on it there: every pool is scattered
-    into in place, and no op shaped like a whole pool is a ``copy`` or a
-    ``concatenate``.  A pool that the chip would relay on the way into and
+    into in place (a state kind's: laid over a layer at a time), and no op
+    shaped like a whole pool is a ``copy`` or a ``concatenate``.  A pool that the chip would relay on the way into and
     out of every tick, as it did the latent pool at ``[5, 5120, 16, 576]``
     (blocks minor by default: PERF.md §6, PR 36), fails here, on a CPU."""
     import re
@@ -119,7 +124,8 @@ def test_no_tick_relays_a_pool_on_its_way_in_or_out(one_chip, cell, width):
         # (a pool of ONE layer is scattered into as that layer: the leading
         # 1 goes in a bitcast)
         forms = (pool, "[" + pool[3:]) if pool.startswith("[1,") else (pool,)
-        assert [f for f in forms if (f, "scatter") in ops], leaf
+        assert [f for f in forms if (f, "scatter") in ops
+                or (f, _BY_SLOT) in ops], leaf
         assert not [op for op in ops if op[0] in forms
                     and op[1] in ("copy", "concatenate")], leaf
     row_major = {leaf: tuple(range(pool.count(",") + 1))
@@ -188,8 +194,9 @@ def test_the_wide_tick_runs_its_head_on_the_rows_it_reads(one_chip, cell):
 def test_the_conv_tick_keeps_its_pools_where_they_lie(one_chip, C):
     """``serve-moe-conv-chat``'s two programs: the paged pool (a position's
     heads side by side, ``[3, 3072, 16, 512]``) and the conv layers' state
-    (``[11, 32, 7, 2048]``) are scattered into in place and never copied or
-    relaid whole — with a last axis of head_dim 64 the chip laid the pool
+    (``[11, 32, 7, 2048]``) are written in place — rows scattered into the
+    one, a layer's columns laid over the other — and never copied or relaid
+    whole — with a last axis of head_dim 64 the chip laid the pool
     out blocks-minor and relaid it on the way into and out of every tick
     (PERF.md §6, PR 33) —, the attention reads a tile of context inside the
     shared loop (nothing is shaped like a slot's whole context), the expert
@@ -200,7 +207,7 @@ def test_the_conv_tick_keeps_its_pools_where_they_lie(one_chip, C):
     ops = re.findall(_OPS, text)
     state = "[11,32,7,2048]"
     assert pool == "[3,3072,16,512]"
-    assert (pool, "scatter") in ops and (state, "scatter") in ops
+    assert (pool, "scatter") in ops and (state, _BY_SLOT) in ops
     for whole in (pool, state):
         assert not [op for op in ops if op[0] == whole
                     and op[1] in ("copy", "concatenate")]
@@ -234,7 +241,9 @@ def test_the_scan_tick_keeps_its_four_pools_where_they_lie(one_chip, C):
     # (the one-layer pool is scattered into as its layer, a bitcast away)
     for whole in ("[5120,16,1280]", "[8,1536,16,1280]", "[9,32,8,5120]",
                   "[9,32,6,16,5120]"):
-        assert (whole, "scatter") in ops, whole
+        # (the conv inputs a slot at a time, the carries a row at a time)
+        how = _BY_SLOT if whole == "[9,32,8,5120]" else "scatter"
+        assert (whole, how) in ops, whole
         assert not [op for op in ops if op[0] in (whole, pool)
                     and op[1] in ("copy", "concatenate")], whole
     assert not [op for op in ops if op[0].endswith(",2560,1280]")]
@@ -251,8 +260,11 @@ def test_the_delta_tick_keeps_one_state_a_slot_in_place(one_chip, C):
     ONE committed state a slot (``[12, 16, 1, 30, 192, 96]`` float32, which
     the commit scatters into as ``[12, 16, 30, 192, 96]``, a bitcast away)
     and the ring of rows to replay (``[12, 16, 4, 8700]``: k, v, g and beta
-    side by side) are scattered into in place and never relaid or
-    concatenated whole, and the states never copied; the program takes each
+    side by side) are written in place — rows scattered into the first and
+    the third, a layer's columns laid over the two others a slot at a time —
+    and never relaid, concatenated or copied whole (a read of a layer's
+    columns that is not the one the write reads made the compiler copy the
+    35 MB of conv inputs five times a tick, PR 49); the program takes each
     row-major.  NO state a row exists: nothing with the state's ``[30, 192,
     96]`` behind it holds more than the 16 slots' (576 rows of it would be
     1.27 GB; a loop over positions that kept its carries would make them),
@@ -275,15 +287,14 @@ def test_the_delta_tick_keeps_one_state_a_slot_in_place(one_chip, C):
         leaf: tuple(range(pool.count(",") + 1))
         for leaf, pool in pools.items() if leaf != "delta/at"}
     state = "[12,16,30,192,96]"
-    for whole in ("[4,3072,16,3840]", "[12,16,8,11520]", state,
-                  "[12,16,4,8700]"):
-        assert (whole, "scatter") in ops, whole
+    for whole, how in (("[4,3072,16,3840]", "scatter"), (state, "scatter"),
+                       ("[12,16,8,11520]", _BY_SLOT),
+                       ("[12,16,4,8700]", _BY_SLOT)):
+        assert (whole, how) in ops, whole
         assert not [op for op in ops if op[0] == whole
-                    and op[1] == "concatenate"], whole
-    for whole in ("[4,3072,16,3840]", state, pools["delta/S"]):
-        assert not [op for op in ops if op[0] == whole and op[1] == "copy"]
-    # (the compiler moves the 27 MB ring whole into its fast memory before
-    # some layers' scatters of the narrow program; its layout stays)
+                    and op[1] in ("concatenate", "copy")], whole
+    assert not [op for op in ops if op[0] == pools["delta/S"]
+                and op[1] == "copy"]
     assert not re.search(r"\[12,16,4,8700\]\{(?!3,2,1,0)", texts[C])
     behind = [tuple(map(int, d[1:-1].split(","))) for d, _ in ops
               if d.endswith(",30,192,96]")]
