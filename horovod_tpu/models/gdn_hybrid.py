@@ -45,8 +45,9 @@ from them is a config away:
     that slot's state, rows of two slots never share a chunk, and no state
     a row exists anywhere.
   * **Three kinds of cache** (models/paged.py ``CacheKind``): the full
-    layers' keys and values in the paged pool, a position's heads side by
-    side, read as far as a slot's context reaches; the linear layers' last ``conv_kernel - 1`` conv
+    layers' keys and values in the paged pool, BY HEAD inside a block
+    (``by_head``: a gathered tile is scored as it lies), read as far as a
+    slot's context reaches; the linear layers' last ``conv_kernel - 1`` conv
     inputs, a fixed state a slot read by position; and their matrix states,
     the ``delta`` kind above.
 
@@ -426,12 +427,16 @@ def cache_kinds(cfg: GdnHybridConfig) -> Tuple[paged.CacheKind, ...]:
     - 1`` inputs and the ONE matrix state a slot with the ring of rows a
     later tick may have to replay."""
     n, H = cfg.count(LINEAR), cfg.lin_heads
-    # a position's heads side by side, lanes-minor (by head, ``[n_heads,
-    # head_dim]`` behind, the compiler relays the whole pool into and out of
-    # every tick: two copies of 1.5 GB a leaf in both programs compiled for
-    # a described v5e, PR 48); the state ALWAYS float32
-    return (paged.CacheKind(KV, cfg.count(FULL),
-                            leaves={"k": (cfg.dim,), "v": (cfg.dim,)}),
+    # keys and values BY HEAD inside a block, ``[layers, blocks, n_heads,
+    # block_size, head_dim]``: a gathered tile is scored as it lies.  What
+    # made the compiler relay the whole pool into and out of every tick (two
+    # copies of 1.5 GB a leaf in both programs compiled for a described v5e,
+    # PR 48) was the WRITE by row, a scatter at ``[blk, :, off]`` whose
+    # window spans the head axis; blocks rewritten whole (paged.write_blocks)
+    # leave the pool in place (PR 50).  The state ALWAYS float32
+    return (paged.CacheKind(KV, cfg.count(FULL), by_head=True,
+                            leaves={"k": (cfg.n_heads, cfg.head_dim),
+                                    "v": (cfg.n_heads, cfg.head_dim)}),
             paged.CacheKind(CONV, n, state=cfg.conv_kernel - 1,
                             leaves={"u": (cfg.conv_dim,)}),
             paged.CacheKind(
@@ -448,8 +453,8 @@ def _index_in_kind(cfg: GdnHybridConfig, i: int) -> int:
 def init_cache(cfg: GdnHybridConfig, num_blocks: Dict[str, Any],
                block_size: int, dtype=None) -> Dict[str, Dict[str, jax.Array]]:
     """One pool a kind.  ``{KV: {"k", "v"}}`` of ``[full layers,
-    num_blocks[KV], block_size, dim]``; ``{CONV: {"u"}}`` of ``[linear
-    layers, slots, columns, conv_dim]``; ``{DELTA: {"S", "at", "row"}}``:
+    num_blocks[KV], n_heads, block_size, head_dim]``; ``{CONV: {"u"}}`` of
+    ``[linear layers, slots, columns, conv_dim]``; ``{DELTA: {"S", "at", "row"}}``:
     ``S`` ``[linear layers, slots, 1, heads, dv, dk]`` and the ring ``row``
     ``[.., slots, rows, heads x (dk + dv + 2)]`` (a row's k, v, g and beta
     side by side, lanes-minor) ALWAYS float32, ``at`` int32, the state
@@ -460,7 +465,7 @@ def init_cache(cfg: GdnHybridConfig, num_blocks: Dict[str, Any],
 
 def cache_shardings(mesh, cfg: GdnHybridConfig, num_blocks: Dict[str, Any]):
     """{kind: sharding}: the paged pool's blocks and the states' slots over
-    the data axis."""
+    the data axis, the paged pool's heads over a model axis."""
     return paged.pool_shardings(mesh, cache_kinds(cfg), num_blocks)
 
 
@@ -650,11 +655,12 @@ def _delta_cached(p, x, cfg, j, cache, t: paged.Tick):
 def _attend_tile(q, pos, ctx, start):
     """One tile of a block of slots' read of a full layer's pool
     (paged.attend_by_blocks with a bound); the tile's keys and values
-    ``ctx``, a position's heads side by side, begin at position ``start``."""
-    heads = lambda a: a.reshape(a.shape[:2] + q.shape[2:])
-    return L.attention_tile(
-        q, heads(ctx["k"]), heads(ctx["v"]),
-        paged.context_mask(pos - start, ctx["k"].shape[1]))
+    ``ctx``, by head as the pool keeps them ``[s, entries, heads, block,
+    head_dim]``, begin at position ``start``."""
+    k = ctx["k"]
+    return L.attention_tile_by_head(
+        q, k, ctx["v"],
+        paged.context_mask(pos - start, k.shape[1] * k.shape[3]))
 
 
 def _full_cached(p, x, cfg, j, cache, tables, t: paged.Tick):
@@ -663,13 +669,14 @@ def _full_cached(p, x, cfg, j, cache, tables, t: paged.Tick):
     context reaches."""
     with jax.named_scope("attn/full"):
         q, k, v = _qkv(p, x, cfg)
-        pool = paged.write(cache[KV], j, *t.where[KV], {"k": k, "v": v})
+        pool = paged.write_blocks(cache[KV], j, *t.where[KV],
+                                  {"k": k, "v": v})
         q = q.reshape(q.shape[:2] + (cfg.n_heads, cfg.head_dim))
         o = paged.attend_by_blocks(
             _attend_tile, (q, t.positions, tables[KV]), t.n_new,
-            *attn_blocks(cfg, *t.positions.shape,
-                         tables[KV].shape[1] * pool["k"].shape[2]),
-            bound=paged.Bound(t.lengths, pool, j, t.slab))
+            *attn_blocks(cfg, *t.positions.shape, tables[KV].shape[1]
+                         * paged.block_size(pool, by_head=True)),
+            bound=paged.Bound(t.lengths, pool, j, t.slab, by_head=True))
         # [S, H, 1, C, head_dim] -> the rows
         o = t.take(jnp.moveaxis(o, 3, 1))
         return (L.dense(p["wo"], o.reshape(x.shape)),

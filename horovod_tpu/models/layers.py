@@ -295,3 +295,26 @@ def attention_tile(q: jax.Array, k: jax.Array, v: jax.Array,
     s = jnp.where(jnp.expand_dims(mask, 2), s, jnp.finfo(jnp.float32).min)
     return s, lambda p: jnp.einsum("bhrqk,bkhd->bhrqd", p.astype(v.dtype), v,
                                    preferred_element_type=jnp.float32)
+
+
+def attention_tile_by_head(q: jax.Array, k: jax.Array, v: jax.Array,
+                           mask: jax.Array):
+    """:func:`attention_tile` of a tile BY HEAD, as a pool that keeps its
+    keys and values by head inside a block is gathered (models/paged.py
+    ``gather(.., by_head)``): k/v [B, T, Hkv, bs, D], T table entries of bs
+    positions, key ``t * bs + s`` at ``[t, :, s]``.  The entries stay an
+    axis of both products, so nothing of the tile's size is made between
+    the gather and the scores; the scores come back ``[B, Hkv, rep, S, K]``
+    with ``K = T * bs``, as ``mask`` [B, 1, S, K] and the softmax across
+    tiles count the keys, and ``weigh(p)`` takes probabilities so shaped."""
+    B, S, H, D = q.shape
+    T, Hkv, bs = k.shape[1:4]
+    qg = q.reshape(B, S, Hkv, H // Hkv, D)
+    s = jnp.einsum("bqhrd,bthsd->bhrqts", qg, k,
+                   preferred_element_type=jnp.float32) * (1.0 / math.sqrt(D))
+    s = jnp.where(jnp.expand_dims(mask, 2), s.reshape(s.shape[:4] + (T * bs,)),
+                  jnp.finfo(jnp.float32).min)
+    return s, lambda p: jnp.einsum(
+        "bhrqts,bthsd->bhrqd",
+        p.astype(v.dtype).reshape(p.shape[:4] + (T, bs)), v,
+        preferred_element_type=jnp.float32)

@@ -10,6 +10,20 @@ sequence.  A sequence owns whole blocks through its row of ``block_tables``
 shapes.  The stacked pool is indexed by layer, never unstacked: a donated
 cache stays one buffer through a tick (docs/serving.md).
 
+A paged kind may instead keep its keys and values BY HEAD inside a block
+(``CacheKind.by_head``; models/gdn_hybrid.py): the pool is ``[n_layers,
+num_blocks, heads, block_size, head_dim]``, a block the same bytes in
+another order.  Its read hands the model a tile as the gather leaves it,
+``[slots, entries, heads, block_size, head_dim]`` (:func:`gather`), to be
+scored with the entries as an axis of the products (models/layers.py
+``attention_tile_by_head``): no pass rewrites a tile of positions ``[..,
+heads x head_dim]`` into heads before its scores.  Its write goes BY BLOCK
+(:func:`block_lands`, :func:`write_blocks`): the blocks a tick touches are
+gathered, its rows laid in, the blocks scattered back whole — a scatter by
+row at ``[blk, :, off]``, whose window spans the head axis round a scattered
+position, makes the compiler relay the whole pool into and out of every
+tick (docs/serving.md#where-the-pool-lies).
+
 A model whose layers keep state of several KINDS declares them
 (:class:`CacheKind`) and holds one pool a kind.  There are three, the
 third in three forms (docs/serving.md#cache-kinds):
@@ -88,6 +102,22 @@ class CacheKind(NamedTuple):
     (:func:`init_pools`).  A paged pool whose leaves are ``[heads,
     head_dim]`` behind has a head axis to shard (:func:`pool_shardings`).
 
+    ``by_head`` (a paged or ring kind whose leaves are all ``[heads,
+    head_dim]``) puts the heads BEFORE a block's positions: behind
+    ``[layers, blocks]`` a leaf is ``[heads, block_size, head_dim]``, and
+    what :func:`gather` returns of it is scored as it lies.  Such a pool is
+    written by :func:`write_blocks` alone, whole blocks at ``[layer, blk]``
+    (:func:`tick` hands ``where`` by block for it, :func:`block_lands`): a
+    scatter by row would address ``[blk, :, off]``, a window across the
+    heads round a scattered position, which the TPU's compiler serves by
+    relaying the WHOLE pool into another order and back in every tick (PR
+    48: two copies of 1.5 GB a leaf), and rows of one head scattered into
+    the flat view ``[blocks x heads x block_size, head_dim]`` cost thirty
+    scattered rows for one (PR 50: 9.7 ms against 0.7 in a chunk-wide tick
+    of models/gdn_hybrid.py's four layers).  Every reader of a block's
+    length asks :func:`block_size`; a ring by head needs ``window >=
+    block_size``, so that no tick enters one block twice.
+
     ``replay`` (a state kind of ``state`` 1 alone) names what ONE ROW feeds
     the recurrence that made the state, name -> shape: the pool then holds
     ``leaves`` ONCE a slot (one column), the position that state stands
@@ -101,6 +131,7 @@ class CacheKind(NamedTuple):
     leaves: Optional[Dict[str, Tuple[int, ...]]] = None
     dtype: Any = None
     replay: Optional[Dict[str, Tuple[int, ...]]] = None
+    by_head: bool = False
 
 
 def state_columns(state: int, tick_cols: int) -> int:
@@ -379,6 +410,48 @@ def write_index(block_tables: jax.Array, positions: jax.Array,
     return blk, positions % block_size
 
 
+def block_size(pool: Any, by_head: bool = False) -> int:
+    """Positions a block of a paged or ring kind's ``pool`` holds: the axis
+    behind the blocks, or behind the heads where the kind declares that it
+    lies ``by_head`` (:class:`CacheKind`) — the shape alone cannot say."""
+    return jax.tree_util.tree_leaves(pool)[0].shape[3 if by_head else 2]
+
+
+def touched_blocks(S: int, C: int, rows: int, block_size: int) -> int:
+    """The most blocks that a ``[S, C]`` tick of ``rows`` rows writes into:
+    a slot's ``n`` positions in a row begin anywhere in a block and touch
+    at most ``n`` of them, ``1 + ceil((n - 1) / bs)``, which is ``2 + (n -
+    2) // bs`` from two rows on — so the plan that touches most gives every
+    slot two rows and a block more for every ``bs`` after."""
+    return min(rows, S * (1 + -(-(C - 1) // block_size)),
+               2 * S + (rows - 2 * S) // block_size)
+
+
+def block_lands(blk: jax.Array, off: jax.Array, num_blocks: int,
+                block_size: int, most: int
+                ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """(blk [U], row [U, bs], keeps [U, bs]) for :func:`write_blocks` into
+    a pool by head, BY BLOCK, of :func:`write_index`'s (blk, off) by row in
+    slab order (:func:`pack` keeps a slot's positions side by side, so the
+    rows of one block follow one another): the ``U = most`` blocks at most
+    that the tick touches (:func:`touched_blocks`; ``num_blocks``, off the
+    axis, past the last that it does), and for offset o of each the row
+    ``row[u, o]`` that lands there where ``keeps[u, o]``; elsewhere the
+    block keeps what it held."""
+    blk, off = blk.reshape(-1), off.reshape(-1)
+    N = blk.shape[0]
+    before = jnp.pad(blk, (1, 0), constant_values=num_blocks)[:N]
+    first, = jnp.nonzero((blk < num_blocks) & (blk != before), size=most,
+                         fill_value=N)          # a block's first row
+    blk = jnp.pad(blk, (0, 1), constant_values=num_blocks)
+    ublk = blk[first]
+    row = (first - jnp.pad(off, (0, 1))[first])[:, None] + jnp.arange(
+        block_size)[None, :]
+    keeps = ((row >= first[:, None]) & (ublk < num_blocks)[:, None]
+             & (blk[jnp.clip(row, 0, N)] == ublk[:, None]))
+    return ublk, jnp.clip(row, 0, N - 1), keeps
+
+
 def _of(tree: Any, kind: CacheKind) -> Any:
     """``kind``'s part of a cache, of block tables or of block counts: the
     entry by its name, or all of it for the one kind without a name."""
@@ -396,9 +469,10 @@ class Tick(NamedTuple):
                             # columns a tick reads), zero where left out
     start: jax.Array        # [S] where a slot's rows begin among the rows
     # by the name of a kind: where the rows land in a paged or a ring kind's
-    # pool, (blk, off) by row for write (write_index), and which of the rows
-    # land in a state kind's, (row, keeps) by slot for write_slots
-    # (state_lands)
+    # pool, (blk, off) by row for write (write_index) or, where the kind
+    # lies by head, (blk, row, keeps) by block for write_blocks
+    # (block_lands), and which of the rows land in a state kind's, (row,
+    # keeps) by slot for write_slots (state_lands)
     where: Dict[Optional[str], Tuple[jax.Array, jax.Array]]
     lands: Dict[Optional[str], Tuple[jax.Array, jax.Array]]
     valid: Optional[jax.Array] = None   # the rows that hold a token
@@ -440,10 +514,12 @@ def tick(kinds: Tuple[CacheKind, ...], cache: Any, tables: Any,
     for kind in kinds:
         pool = jax.tree_util.tree_leaves(_of(cache, kind))[0]
         if kind.state is None:
+            n, bs = pool.shape[1], block_size(pool, kind.by_head)
             blk, off = write_index(_of(tables, kind), positions, valid,
-                                   *pool.shape[1:3],
-                                   ring=kind.window is not None)
-            where[kind.name] = (take(blk), take(off))
+                                   n, bs, ring=kind.window is not None)
+            by_row = (take(blk), take(off))
+            where[kind.name] = by_row if not kind.by_head else block_lands(
+                *by_row, n, bs, touched_blocks(S, C, slab.kept or S * C, bs))
         else:
             if kind.replay:     # the ring's rows, not the ONE state's column
                 pool = _of(cache, kind)[next(iter(kind.replay))]
@@ -479,6 +555,26 @@ def write(pool: Any, layer: int, blk: jax.Array, off: jax.Array,
             pool, values)
 
 
+def write_blocks(pool: Any, layer: int, blk: jax.Array, row: jax.Array,
+                 keeps: jax.Array, values: Any) -> Any:
+    """``values`` (a pytree like ``pool``; the tick's rows ``[1, R, ...]``
+    or ``[S, C, ...]``, a row's heads side by side or apart) into layer
+    ``layer`` of a stacked pool BY HEAD ``[layers, blocks, heads,
+    block_size, head_dim]``, BY BLOCK (:func:`block_lands`): the blocks the
+    tick touches are gathered, the rows that land are laid over their
+    offsets, and the blocks go back whole, in place; out of range is
+    dropped.  The one write such a pool gets (:class:`CacheKind`)."""
+    def lay(p, v):
+        H, _, hd = p.shape[2:]
+        got = jnp.swapaxes(v.reshape((-1, H, hd))[row], 1, 2)
+        held = p[layer, jnp.minimum(blk, p.shape[1] - 1)]
+        return p.at[layer, blk].set(
+            jnp.where(keeps[:, None, :, None], got.astype(p.dtype), held),
+            mode="drop")
+    with jax.named_scope("kv_write"):
+        return jax.tree_util.tree_map(lay, pool, values)
+
+
 def write_slots(pool: Any, layer: int, row: jax.Array, keeps: jax.Array,
                 values: Any) -> Any:
     """``values`` (a pytree like ``pool``; the tick's rows ``[1, R, ...]``
@@ -495,13 +591,21 @@ def write_slots(pool: Any, layer: int, row: jax.Array, keeps: jax.Array,
         return jax.tree_util.tree_map(lay, pool, values)
 
 
-def gather(pool: Any, layer: int, block_tables: jax.Array) -> Any:
+def gather(pool: Any, layer: int, block_tables: jax.Array,
+           by_head: bool = False) -> Any:
     """Every slot's whole context of layer ``layer``, ``[S, max_blocks *
     block_size, ...]`` a leaf: index t IS position t.  Unassigned entries
-    (-1 -> block 0) only cover positions :func:`context_mask` excludes."""
+    (-1 -> block 0) only cover positions :func:`context_mask` excludes.
+    Of a pool ``by_head`` the blocks AS THEY LIE, ``[S, max_blocks, heads,
+    block_size, head_dim]``: position t is ``[t // bs, :, t % bs]``, and
+    nothing of the context's size is made between the gather and the
+    products that keep the entries as an axis
+    (models/layers.py ``attention_tile_by_head``)."""
     S, max_blocks = block_tables.shape
     bt = jnp.maximum(block_tables, 0)
     with jax.named_scope("kv_gather"):
+        if by_head:
+            return jax.tree_util.tree_map(lambda p: p[layer, bt], pool)
         return jax.tree_util.tree_map(
             lambda p: p[layer, bt].reshape(
                 (S, max_blocks * p.shape[2]) + p.shape[3:]), pool)
@@ -631,11 +735,13 @@ class Bound(NamedTuple):
     by what the slots hold: the positions each slot held before the tick
     (``lengths`` [S]), the stacked ``pool`` and the ``layer`` of it to read,
     and the tick's ``slab`` (:func:`pack`), through which a block's queries
-    come back from the rows."""
+    come back from the rows; ``by_head`` where the pool's kind declares it
+    (``attend`` is then handed its tile as :func:`gather` leaves it)."""
     lengths: jax.Array
     pool: Any
     layer: int
     slab: Slab
+    by_head: bool = False
 
 
 def attend_by_blocks(attend: Callable, args: Tuple[jax.Array, ...],
@@ -680,12 +786,12 @@ def attend_by_blocks(attend: Callable, args: Tuple[jax.Array, ...],
     if bound is None:
         return _attend_whole(attend, args, n_new, slots, narrow)
     q, pos, tables, *rest = args
-    bs = jax.tree_util.tree_leaves(bound.pool)[0].shape[2]
     return _attend_tiled(
         attend, bound.pool, jnp.int32(bound.layer), q, bound.slab.rows, pos,
         tables, tuple(rest), bound.lengths, n_new, slots=slots,
         narrow=narrow, kept=bound.slab.kept, per=narrow_slots(pos.shape[0]),
-        tb=tile_blocks(bs, tables.shape[1]))
+        tb=tile_blocks(block_size(bound.pool, bound.by_head),
+                       tables.shape[1]), by_head=bound.by_head)
 
 
 def _attend_whole(attend, args, n_new, slots, narrow):
@@ -707,10 +813,10 @@ def _attend_whole(attend, args, n_new, slots, narrow):
     return lax.fori_loop(0, S // slots, block, o)
 
 
-@functools.partial(jax.jit, static_argnums=(0,),
-                   static_argnames=("slots", "narrow", "kept", "per", "tb"))
+@functools.partial(jax.jit, static_argnums=(0,), static_argnames=(
+    "slots", "narrow", "kept", "per", "tb", "by_head"))
 def _attend_tiled(attend, pool, layer, q, rows, pos, tables, rest, lengths,
-                  n_new, *, slots, narrow, kept, per, tb):
+                  n_new, *, slots, narrow, kept, per, tb, by_head):
     """:func:`attend_by_blocks` with a bound: two passes of :func:`_bounded`
     blocks, each over the blocks that have something to read.  A function
     of arrays alone, jitted INSIDE the tick's program with the layer as a
@@ -719,7 +825,7 @@ def _attend_tiled(attend, pool, layer, q, rows, pos, tables, rest, lengths,
     PR 32); the compiler inlines the calls and sees each layer's constant.
     ``per`` slots a block in the first pass, ``tb`` table entries a tile."""
     slab = Slab(rows, kept)
-    bs = jax.tree_util.tree_leaves(pool)[0].shape[2]
+    bs = block_size(pool, by_head)
     S, C = pos.shape
     # a table that is no whole number of tiles: the entries past it are
     # unassigned, and cover positions that no query sees
@@ -733,7 +839,8 @@ def _attend_tiled(attend, pool, layer, q, rows, pos, tables, rest, lengths,
         tab, *more = map(cut, (tables, *rest))
         return lambda t: attend(
             own, p,
-            gather(pool, layer, lax.dynamic_slice_in_dim(tab, t * tb, tb, 1)),
+            gather(pool, layer, lax.dynamic_slice_in_dim(tab, t * tb, tb, 1),
+                   by_head),
             *more, t * (tb * bs))
 
     # what a tile's scores and value product look like, traced once: a
@@ -843,12 +950,14 @@ def no_prefix_blocks(cache: Any, src: jax.Array, dst: jax.Array) -> Any:
     return cache
 
 
-def shardings(mesh, num_blocks: int, head_axis_size: Optional[int] = None):
+def shardings(mesh, num_blocks: int, head_axis_size: Optional[int] = None,
+              by_head: bool = False):
     """NamedSharding for a pool ``[L, blocks, bs, ...]`` along the training
     mesh's own axes: a head axis (``[.., heads, head_dim]`` behind, of
-    ``head_axis_size``) over a model/tp axis that divides it, blocks over
-    the first remaining axis that divides them.  A pool without a head axis
-    (the latent is every head's) shards its blocks alone."""
+    ``head_axis_size``; ``by_head``, the pool's axis 2, before the block's
+    positions) over a model/tp axis that divides it, blocks over the first
+    remaining axis that divides them.  A pool without a head axis (the
+    latent is every head's) shards its blocks alone."""
     head_axis = None
     if head_axis_size is not None:
         head_axis = next(
@@ -860,20 +969,22 @@ def shardings(mesh, num_blocks: int, head_axis_size: Optional[int] = None):
          if a != head_axis and num_blocks % mesh.shape[a] == 0), None)
     if head_axis_size is None:
         return NamedSharding(mesh, P(None, block_axis, None, None))
-    return NamedSharding(mesh, P(None, block_axis, None, head_axis, None))
+    heads = (head_axis, None) if by_head else (None, head_axis)
+    return NamedSharding(mesh, P(None, block_axis, *heads, None))
 
 
 def init_pools(kinds: Tuple[CacheKind, ...], num_blocks: Any,
                block_size: int, dtype) -> Any:
     """One preallocated pool a kind, by what each declares
     (``CacheKind.leaves``): a paged or a ring kind's leaves are ``[kind's
-    layers, num_blocks[kind], block_size, ...]``, a state kind's ``[kind's
-    layers, slots, columns, ...]``, its ``num_blocks`` being ``(slots,
-    columns)`` — with ``replay``, the leaves at ONE column beside ``at`` and
-    the ring ``[kind's layers, slots, rows, ...]``, ``num_blocks`` ``(slots,
-    rows)`` —; in ``dtype`` unless the kind names its own.  ``{kind:
-    {leaf: array}}``, or the one pool of a kind without a name (whose
-    ``num_blocks`` is the number)."""
+    layers, num_blocks[kind], block_size, ...]`` — ``[.., num_blocks[kind],
+    heads, block_size, head_dim]`` where it lies ``by_head`` —, a state
+    kind's ``[kind's layers, slots, columns, ...]``, its ``num_blocks``
+    being ``(slots, columns)`` — with ``replay``, the leaves at ONE column
+    beside ``at`` and the ring ``[kind's layers, slots, rows, ...]``,
+    ``num_blocks`` ``(slots, rows)`` —; in ``dtype`` unless the kind names
+    its own.  ``{kind: {leaf: array}}``, or the one pool of a kind without a
+    name (whose ``num_blocks`` is the number)."""
     def pool(kind):
         n = _of(num_blocks, kind)
         lead = (kind.layers,) + (tuple(n) if kind.state is not None
@@ -881,6 +992,11 @@ def init_pools(kinds: Tuple[CacheKind, ...], num_blocks: Any,
         zeros = lambda lead, leaves, dtype: {
             name: jnp.zeros(lead + tuple(behind), dtype)
             for name, behind in leaves.items()}
+        if kind.by_head:    # the heads before the block's positions
+            return zeros(lead[:2], {
+                name: (heads, block_size, width)
+                for name, (heads, width) in kind.leaves.items()},
+                kind.dtype or dtype)
         if kind.replay is None:
             return zeros(lead, kind.leaves, kind.dtype or dtype)
         # ONE state a slot and where it stands, beside the ring of rows
@@ -897,14 +1013,15 @@ def pool_shardings(mesh, kinds: Tuple[CacheKind, ...], num_blocks: Any):
     or the one of a kind without a name): a paged pool's blocks and a
     state's slots over the data axis, each pool by its own number of them,
     and the heads of a paged pool that has a head axis (``[heads,
-    head_dim]`` behind) over a model axis."""
+    head_dim]`` behind, or the pool's axis 2 where the kind lies by head)
+    over a model axis."""
     def one(kind):
         n = _of(num_blocks, kind)
         if kind.state is not None:
             return shardings(mesh, n[0])
         behind = list(kind.leaves.values())
         return shardings(mesh, n, behind[0][0] if all(
-            len(b) == 2 for b in behind) else None)
+            len(b) == 2 for b in behind) else None, kind.by_head)
     out = {kind.name: one(kind) for kind in kinds}
     return out[None] if None in out else out
 

@@ -127,6 +127,20 @@ def test_the_tick_is_the_addressing_by_hand(name):
                           (np.cumsum(N_NEW) - N_NEW)[N_NEW > 0])
     for k in kinds:
         pool = jax.tree_util.tree_leaves(_part(cache, k.name))[0]
+        if k.state is None and k.by_head:
+            # by block: the blocks the rows touch, and for each offset of
+            # each the row that lands there
+            want = _by_hand(k, _part(tables, k.name), None)
+            blk, row, keeps = (np.asarray(a) for a in t.where[k.name])
+            most = paged.touched_blocks(S, C, ROWS, BS)
+            assert blk.shape == (most,) and row.shape == keeps.shape == (
+                most, BS)
+            assert {(blk[u], o): row[u, o]
+                    for u, o in zip(*np.nonzero(keeps))} == {
+                tuple(at): r for r, at in enumerate(want)}, k
+            touched = len({b for b, _ in want})
+            assert (blk[touched:] == pool.shape[1]).all(), k
+            continue
         if k.state is None:
             want = _by_hand(k, _part(tables, k.name), None)
             got = np.stack([np.asarray(a)[0] for a in t.where[k.name]],
@@ -163,8 +177,10 @@ F32, BF16 = jnp.float32, jnp.bfloat16
 #: name -> ({kind: {leaf: (shape behind [layers, blocks, BS] or [layers,
 #: slots, columns], dtype under a bfloat16 cache[, its columns where they
 #: are not the kind's: a ``replay`` kind keeps its state ONCE a slot])}},
-#: {kind: layers}, whether the pools have a head axis to shard), from the
-#: modules' own ``init_cache`` and ``cache_shardings`` before they shared one
+#: {kind: layers}, whether the pools have a head axis to shard — "by head"
+#: where it lies BEFORE the block's positions, the shape then written out
+#: behind [layers, blocks]), from the modules' own ``init_cache`` and
+#: ``cache_shardings`` before they shared one
 POOLS = {
     "llama": ({None: {"k": ((2, 16), BF16), "v": ((2, 16), BF16)}},
               {None: 2}, True),
@@ -184,12 +200,12 @@ POOLS = {
                 "conv": {"u": ((128,), BF16)},
                 "carry": {"h": ((16, 128), F32)}},
                {"kv": 1, "window": 2, "conv": 3, "carry": 3}, False),
-    "gdn_hybrid": ({"kv": {"k": ((64,), BF16), "v": ((64,), BF16)},
+    "gdn_hybrid": ({"kv": {"k": ((4, BS, 16), BF16), "v": ((4, BS, 16), BF16)},
                     "conv": {"u": ((128,), BF16)},
                     "delta": {"S": ((4, 16, 8), F32, 1),
                               "at": ((1,), jnp.int32, 1),
                               "row": ((4 * (8 + 16 + 2),), F32)}},
-                   {"kv": 2, "conv": 6, "delta": 6}, False),
+                   {"kv": 2, "conv": 6, "delta": 6}, "by head"),
 }
 STATE = {"conv_moe": {"conv": 2}, "sambay": {"conv": 3, "carry": 1},
          "gdn_hybrid": {"conv": 3, "delta": 1}}
@@ -210,7 +226,8 @@ def test_every_modules_pools_and_their_shardings(name):
     for kind, want in leaves.items():
         pool, n = _part(cache, kind), _part(blocks, kind)
         lead = lambda cols=7: (layers[kind],) + (
-            (S, cols) if kind in states else (n, BS))
+            (S, cols) if kind in states
+            else (n,) if heads == "by head" else (n, BS))
         assert {k: (v.shape, v.dtype) for k, v in pool.items()} == {
             k: (lead(*cols) + behind, dtype)
             for k, (behind, dtype, *cols) in want.items()}
@@ -227,6 +244,9 @@ def test_every_modules_pools_and_their_shardings(name):
         n, spec = _part(blocks, kind), _part(got, kind).spec
         if kind in states:      # the slots: 5 of them, which no axis divides
             assert spec == PS(None, None, None, None)
+        elif heads == "by head":    # four heads, the pool's axis 2
+            assert spec == PS(None, "data" if n % 4 == 0 else None, "model",
+                              None, None)
         elif heads:             # two kv heads over the model axis
             assert spec == PS(None, "data" if n % 4 == 0 else None, None,
                               "model", None)

@@ -10,6 +10,7 @@ the planted faults that the reference must fail."""
 import dataclasses
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -370,6 +371,71 @@ def test_the_head_runs_on_the_rows_the_tick_reads(toy):
     # replayed in a slot's first tick
     assert counted.tolist() == counted2.tolist() == [1, 6 * 23, 0]
     assert M.TICK_COUNTERS == ("ticks", "gdn_rows", "gdn_replayed_rows")
+
+
+# ------------------------------------- 3b. what the tick's text is free of
+def _dims(t):
+    return tuple(int(d) for d in t.split("x")[:-1])
+
+
+def _tile_reshapes(text, tile, cfg):
+    """The reshapes in a lowered text that rewrite something of a gathered
+    tile's size, a position's heads side by side ``[.., tile, .., dim]``,
+    into heads ``[.., n_heads, head_dim]``."""
+    found = []
+    for m in re.finditer(r"stablehlo\.reshape [^\n]*: \(tensor<([\dx]+\w+)>\)"
+                         r" -> tensor<([\dx]+\w+)>", text):
+        src, dst = _dims(m.group(1)), _dims(m.group(2))
+        if (src[-1:] == (cfg.dim,) and tile in src[:-1]
+                and dst[-2:] == (cfg.n_heads, cfg.head_dim)):
+            found.append((src, dst))
+    return found
+
+
+def _pool_scatters(text, pool):
+    """(operand axes its indices address) of every scatter in a lowered text
+    into an array shaped like ``pool``."""
+    return [tuple(int(d) for d in m.group(1).split(",") if d.strip())
+            for m in re.finditer(
+                r'"stablehlo\.scatter".*?scatter_dims_to_operand_dims = '
+                r"\[([\d, ]*)\].*?\}\) : \(tensor<([\dx]+\w+)>", text, re.S)
+            if _dims(m.group(2)) == tuple(pool)]
+
+
+def test_the_narrow_tick_relays_no_tile_and_scatters_across_no_heads(toy):
+    """What the CPU can count of the pool by head (PERF.md section 6, PR
+    50): the lowered narrow tick rewrites no gathered tile from ``[..,
+    dim]`` into ``[.., n_heads, head_dim]`` — the tile is scored as the
+    gather left it —, and every scatter into the paged pool addresses whole
+    blocks, its window never spanning the head axis round a scattered
+    position (the write that made the compiler relay the pool whole, PR
+    48).  Both counts find the forms they exclude when they are planted."""
+    config, model, cfg, params = toy
+    S, C, bs = 4, 5, 4
+    cache, tables = _pools(cfg, S, block_size=bs)
+    shape = cache[M.KV]["k"].shape
+    assert shape[2:] == (cfg.n_heads, bs, cfg.head_dim)
+    tile = paged.tile_blocks(bs, tables[M.KV].shape[1]) * bs
+    text = _TICK.lower(
+        params, cfg, tables, cache, jnp.zeros((S, C), jnp.int32),
+        jnp.asarray([9, 0, 30, 17]), jnp.asarray([5, 1, 0, 2])).as_text()
+    assert _tile_reshapes(text, tile, cfg) == []
+    into = _pool_scatters(text, shape)
+    # a key and a value leaf a full layer, each by (layer, block) alone
+    assert into == [(0, 1)] * (2 * cfg.count(M.FULL))
+
+    # the forms that went, planted: a pool side by side whose gathered tile
+    # is split into heads, and a write by row into the pool by head
+    side = jnp.zeros(shape[:2] + (bs, cfg.dim))
+    entries = jnp.zeros((2, tile // bs), jnp.int32)
+    relaid = jax.jit(lambda p: paged.gather(p, 1, entries).reshape(
+        2, tile, cfg.n_heads, cfg.head_dim)).lower(side).as_text()
+    assert len(_tile_reshapes(relaid, tile, cfg)) == 1
+    rows = jnp.zeros((3, cfg.n_heads, cfg.head_dim))
+    at = jnp.zeros(3, jnp.int32)
+    by_row = jax.jit(lambda p: p.at[1, at, :, at].set(rows)).lower(
+        cache[M.KV]["k"]).as_text()
+    assert _pool_scatters(by_row, shape) == [(0, 1, 3)]
 
 
 # ------------------------------------------------------ 4. planted faults

@@ -1,10 +1,13 @@
 """How a block table addresses a paged pool (horovod_tpu/models/paged.py),
-on both kinds of pool the served models keep — llama's five axes and the
-latent decoder's four —, how a fixed state a slot is addressed beside it, and
+on the kinds of pool the served models keep — llama's five axes, the latent
+decoder's four and gdn_hybrid's keys and values BY HEAD inside a block —,
+how a fixed state a slot is addressed beside it, and
 the contract ServeEngine holds a model module to
 (docs/serving.md#what-a-served-model-module-exports)."""
 
+import dataclasses
 import importlib
+import itertools
 import types
 
 import jax
@@ -12,28 +15,58 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.models import latent_moe, layers, llama, paged
+from horovod_tpu.models import gdn_hybrid, latent_moe, layers, llama, paged
 from horovod_tpu.serve.config import ServeConfig
-from horovod_tpu.serve.engine import (_MODEL_MODULES, ServeEngine,
-                                      block_length)
+from horovod_tpu.serve.engine import (_MODEL_MODULES, HostSpillPool,
+                                      ServeEngine, _PrefixNode, block_length,
+                                      decode_block_payload,
+                                      encode_block_payload)
 
 BLOCKS, BS = 12, 4
+# (two heads where a block holds four positions: a swapped axis shows)
 KINDS = {"llama": (llama, llama.CONFIGS["tiny"]),
-         "latent": (latent_moe, latent_moe.CONFIGS["tiny"])}
+         "latent": (latent_moe, latent_moe.CONFIGS["tiny"]),
+         "by-head": (gdn_hybrid, dataclasses.replace(
+             gdn_hybrid.CONFIGS["tiny"], n_heads=2))}
+
+
+def _by_head(model):
+    """Whether the module's paged pool lies by head inside a block."""
+    return model is gdn_hybrid
 
 
 @pytest.fixture(params=sorted(KINDS))
 def kind(request):
-    """(model module, its tiny config, a pool of noise as host numpy)."""
+    """(model module, its tiny config, a pool of noise as host numpy: the
+    module's paged pool)."""
     model, cfg = KINDS[request.param]
     rng = np.random.default_rng(3)
+    cache = (model.init_cache(cfg, {gdn_hybrid.KV: BLOCKS,
+                                    gdn_hybrid.CONV: (3, 4),
+                                    gdn_hybrid.DELTA: (3, 1)}, BS)[
+        gdn_hybrid.KV] if _by_head(model)
+        else model.init_cache(cfg, BLOCKS, BS))
     pool = {k: rng.normal(size=v.shape).astype(np.float32)
-            for k, v in model.init_cache(cfg, BLOCKS, BS).items()}
+            for k, v in cache.items()}
     return model, cfg, pool
 
 
 def _device(pool):
     return {k: jnp.asarray(v) for k, v in pool.items()}
+
+
+def _write(model, leaf, layer, blk, off, values):
+    """The rows at (blk, off) into a leaf, by the write its kind gets."""
+    if _by_head(model):
+        return paged.write_blocks(leaf, layer, *paged.block_lands(
+            blk, off, BLOCKS, BS, blk.size), values)
+    return paged.write(leaf, layer, blk, off, values)
+
+
+def _position(model, leaf, layer, block, offset):
+    """Where a position's values lie in a host leaf (an index)."""
+    return ((layer, block, slice(None), offset) if _by_head(model)
+            else (layer, block, offset))
 
 
 # slot 0 holds 2 positions and takes 3 more (crossing into its second block),
@@ -67,16 +100,18 @@ def test_a_position_lands_in_its_block_and_nothing_else_moves(kind):
                                  BLOCKS, BS)
     rng = np.random.default_rng(5)
     for name, leaf in pool.items():
+        behind = ((leaf.shape[2], leaf.shape[4]) if _by_head(model)
+                  else leaf.shape[3:])
         for layer in (0, leaf.shape[0] - 1):
-            values = rng.normal(size=(3, C) + leaf.shape[3:]).astype(
-                np.float32)
-            got = np.asarray(paged.write(jnp.asarray(leaf), layer, blk, off,
-                                         jnp.asarray(values)))
+            values = rng.normal(size=(3, C) + behind).astype(np.float32)
+            got = np.asarray(_write(model, jnp.asarray(leaf), layer, blk,
+                                    off, jnp.asarray(values)))
             want = leaf.copy()
             for s in range(3):
                 for j in range(N_NEW[s]):
                     P = LENGTHS[s] + j
-                    want[layer, TABLES[s, P // BS], P % BS] = values[s, j]
+                    want[_position(model, leaf, layer, TABLES[s, P // BS],
+                                   P % BS)] = values[s, j]
             assert np.array_equal(got, want), (name, layer)
 
 
@@ -85,12 +120,17 @@ def test_gathered_index_t_is_position_t(kind):
     for name, leaf in pool.items():
         layer = leaf.shape[0] - 1
         ctx = np.asarray(paged.gather(jnp.asarray(leaf), layer,
-                                      jnp.asarray(TABLES)))
-        assert ctx.shape == (3, 3 * BS) + leaf.shape[3:]
+                                      jnp.asarray(TABLES), _by_head(model)))
+        # by head the blocks as they lie, an entry a block
+        assert ctx.shape == ((3, 3) + leaf.shape[2:] if _by_head(model)
+                             else (3, 3 * BS) + leaf.shape[3:])
         for s in range(3):
             for t in range(3 * BS):
                 b = max(TABLES[s, t // BS], 0)      # -1 reads block 0
-                assert np.array_equal(ctx[s, t], leaf[layer, b, t % BS])
+                at = (ctx[s, t // BS, :, t % BS] if _by_head(model)
+                      else ctx[s, t])
+                assert np.array_equal(
+                    at, leaf[_position(model, leaf, layer, b, t % BS)])
 
 
 def test_context_mask_by_a_plain_loop():
@@ -165,19 +205,25 @@ def _attends(model, cfg):
                 lambda o: jnp.swapaxes(o, 1, 2),
                 lambda pool: {"latent": pool["latent"][..., :cfg.latent_dim]})
 
+    # gathered by head [s, entries, heads, bs, d] -> by position
+    flat = (lambda a: jnp.swapaxes(a, 2, 3).reshape(
+        a.shape[0], -1, a.shape[2], a.shape[4])) if _by_head(model) else (
+            lambda a: a)
+
     def whole(q, pos, ctx):
+        k, v = flat(ctx["k"]), flat(ctx["v"])
         return layers.causal_attention(
-            q, ctx["k"], ctx["v"], causal=False,
-            mask=paged.context_mask(pos, ctx["k"].shape[1]))
-    return (llama._attend_tile, whole, (cfg.n_heads, cfg.head_dim),
+            q, k, v, causal=False, mask=paged.context_mask(pos, k.shape[1]))
+    return (gdn_hybrid._attend_tile if _by_head(model)
+            else llama._attend_tile, whole, (cfg.n_heads, cfg.head_dim),
             lambda o: jnp.moveaxis(o, 3, 1).reshape(
                 o.shape[0], o.shape[3], cfg.n_heads, -1), lambda pool: pool)
 
 
-def _bound(lengths, pool, layer=1):
+def _bound(lengths, pool, layer=1, by_head=False):
     """A bound over ``pool`` for queries that are not packed."""
     return paged.Bound(jnp.asarray(lengths), pool, layer,
-                       paged.Slab(None, None))
+                       paged.Slab(None, None), by_head)
 
 
 @pytest.mark.parametrize("plan", sorted(PLANS))
@@ -199,9 +245,10 @@ def test_the_bounded_read_is_the_whole_table_read(kind, plan, small_tiles):
                                             jnp.asarray(n_new), C)
     got = back(jax.jit(lambda q: paged.attend_by_blocks(
         attend, (q, positions, jnp.asarray(tables)), jnp.asarray(n_new),
-        READ["slots"], READ["narrow"], bound=_bound(lengths, seen(pool))))(q))
-    want = whole(q, positions,
-                 paged.gather(seen(pool), 1, jnp.asarray(tables)))
+        READ["slots"], READ["narrow"],
+        bound=_bound(lengths, seen(pool), by_head=_by_head(model))))(q))
+    want = whole(q, positions, paged.gather(
+        seen(pool), 1, jnp.asarray(tables), _by_head(model)))
     assert got.shape == want.shape and bool(jnp.isfinite(got).all())
     assert np.asarray(valid).any()
     err = np.abs(np.asarray(got) - np.asarray(want))[np.asarray(valid)]
@@ -267,8 +314,9 @@ def test_a_table_that_is_no_whole_number_of_tiles(kind, monkeypatch):
     positions, valid = paged.slot_positions(lengths, n_new, C)
     got = back(paged.attend_by_blocks(
         attend, (q, positions, tables), n_new, 1, C,
-        bound=_bound(lengths, seen(_device(pool)))))
-    want = whole(q, positions, paged.gather(seen(_device(pool)), 1, tables))
+        bound=_bound(lengths, seen(_device(pool)), by_head=_by_head(model))))
+    want = whole(q, positions, paged.gather(seen(_device(pool)), 1, tables,
+                                            _by_head(model)))
     err = np.abs(np.asarray(got) - np.asarray(want))[np.asarray(valid)]
     assert float(err.max()) < 2e-6
 
@@ -279,7 +327,9 @@ def test_copy_blocks_padding_and_a_source_recycled_in_the_same_call(kind):
     # (-1 -> BLOCKS) are padding pairs: dropped, their source clamped
     src = jnp.array([3, 5, 0, -1], jnp.int32)
     dst = jnp.array([5, 8, BLOCKS, BLOCKS], jnp.int32)
-    for copy in (paged.copy_blocks, model.copy_blocks):
+    # (gdn_hybrid's own clones nothing: no prefix over its state kinds)
+    for copy in (paged.copy_blocks,) + (
+            () if _by_head(model) else (model.copy_blocks,)):
         got = copy(_device(pool), src, dst)
         assert set(got) == set(pool)
         for name, leaf in pool.items():
@@ -307,17 +357,143 @@ def test_copy_blocks_jitted_on_a_bare_leaf_and_all_padding_is_identity():
 
 
 def test_read_block_write_block_round_trip(kind):
+    """A block's payload, whatever lies behind the block axis (by head:
+    ``[L, heads, block_size, head_dim]``), is the same on both sides of a
+    spill (HostSpillPool over the two accessors) and of a hand-off's
+    record (the payload's codec)."""
     model, cfg, pool = kind
     payload = paged.read_block(_device(pool), 7)
     assert list(payload) == sorted(pool)
     for name, leaf in pool.items():
         assert isinstance(payload[name], np.ndarray)
         assert np.array_equal(payload[name], leaf[:, 7])
-    got = paged.write_block(_device(pool), 9, payload)
+    if _by_head(model):
+        assert payload["k"].shape == (cfg.count(gdn_hybrid.FULL), cfg.n_heads,
+                                      BS, cfg.head_dim)
+    sent = decode_block_payload(encode_block_payload(payload))
+    got = paged.write_block(_device(pool), 9, sent)
     for name, leaf in pool.items():
         want = leaf.copy()
         want[:, 9] = leaf[:, 7]
         assert np.array_equal(np.asarray(got[name]), want)
+    # spilled from block 7, reloaded into block 2
+    held = {"cache": _device(pool)}
+    tier = HostSpillPool(
+        1, lambda b: paged.read_block(held["cache"], b),
+        lambda b, p: held.update(cache=paged.write_block(held["cache"], b, p)))
+    node = _PrefixNode((1, 2, 3, 4), 7, None)
+    assert tier.spill(node) and tier.holds(node)
+    tier.reload(node, 2)
+    for name, leaf in pool.items():
+        want = leaf.copy()
+        want[:, 2] = leaf[:, 7]
+        assert np.array_equal(np.asarray(held["cache"][name]), want)
+
+
+# ------------------------------------------------- a kind by head
+def _pair(layers=2, heads=2, width=8):
+    """The same keys and values declared side by side in a block, a
+    position's heads apart, and BY HEAD inside it."""
+    leaves = {"k": (heads, width), "v": (heads, width)}
+    return (paged.CacheKind("kv", layers, leaves=leaves),
+            paged.CacheKind("kv", layers, leaves=leaves, by_head=True))
+
+
+@pytest.mark.parametrize("by_head", [False, True], ids=["side", "by-head"])
+def test_init_pools_and_pool_shardings_follow_the_declaration(by_head):
+    kind = _pair(layers=3, heads=4)[by_head]
+    pool = jax.eval_shape(lambda: paged.init_pools(
+        (kind,), {"kv": 64}, BS, jnp.bfloat16))["kv"]
+    want = (3, 64, 4, BS, 8) if by_head else (3, 64, BS, 4, 8)
+    assert {k: (v.shape, v.dtype) for k, v in pool.items()} == {
+        k: (want, jnp.bfloat16) for k in ("k", "v")}
+    assert paged.block_size(pool, by_head) == BS
+    PS = jax.sharding.PartitionSpec
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:8]).reshape(4, 2),
+                             ("data", "model"))
+    # the heads over the model axis wherever they lie, the blocks over data
+    spec = paged.pool_shardings(mesh, (kind,), {"kv": 64})["kv"].spec
+    assert spec == (PS(None, "data", "model", None, None) if by_head
+                    else PS(None, "data", None, "model", None))
+    # three heads, which the model axis does not divide, stay whole
+    odd = _pair(heads=3)[by_head]
+    assert paged.pool_shardings(mesh, (odd,), {"kv": 64})["kv"].spec == PS(
+        None, "data", None, None, None)
+
+
+@pytest.mark.parametrize("bs", [1, 2, 3, 16])
+def test_touched_blocks_bounds_every_plan(bs):
+    """No plan of ``rows`` rows touches more blocks than ``touched_blocks``
+    says — a slot's n positions from the offset that splits them worst —,
+    and some plan touches that many."""
+    touch = lambda n: -(-(bs - 1 + n) // bs) if n else 0
+    for S, C, rows in ((3, 5, 15), (3, 5, 7), (2, 9, 12), (4, 1, 4),
+                       (3, 6, 4), (1, 7, 7)):
+        worst = max(sum(map(touch, plan))
+                    for plan in itertools.product(range(C + 1), repeat=S)
+                    if sum(plan) <= rows)
+        assert paged.touched_blocks(S, C, rows, bs) == worst, (S, C, rows)
+
+
+@pytest.mark.parametrize("budget", [0, 14], ids=["slab", "packed"])
+@pytest.mark.parametrize("plan", sorted(PLANS))
+def test_a_pool_by_head_reads_what_one_side_by_side_reads(plan, budget,
+                                                          small_tiles):
+    """The same rows through one tick into a pool side by side (``write``
+    by row) and into one by head (``write_blocks`` by block), then read
+    through ``attend_by_blocks`` with a bound: every block of the two pools
+    holds the same values — padding rows, a dead slot's stale table row and
+    rows out of range dropped in both — and the reads agree in float32 to
+    the tiled read's tolerance."""
+    S, mb = READ["S"], READ["max_blocks"]
+    C, lengths, n_new, tables = _plan(plan)
+    if budget and n_new.sum() > budget:
+        budget = int(n_new.sum())
+    side, head = _pair()
+    H, W = side.leaves["k"]
+    rng = np.random.default_rng(11)
+    noise = rng.normal(size=(2, S * mb, BS, H, W)).astype(np.float32)
+    rows = {n: jnp.asarray(rng.normal(size=(S, C, H * W)).astype(np.float32))
+            for n in ("k", "v")}
+    q = jnp.asarray(rng.normal(size=(S, C, H, W)).astype(np.float32))
+    lens, new = jnp.asarray(lengths), jnp.asarray(n_new)
+
+    def run(kind, tile, pool):
+        pool = {"k": pool, "v": pool + 1.0}
+        t = paged.tick((kind,), {"kv": pool}, {"kv": jnp.asarray(tables)},
+                       lens, new, C, rows=budget)
+        # (a row's heads apart for the write by row; by block either way)
+        values = {n: t.take(a if kind.by_head else a.reshape(S, C, H, W))
+                  for n, a in rows.items()}
+        pool = (paged.write_blocks if kind.by_head else paged.write)(
+            pool, 1, *t.where["kv"], values)
+        o = paged.attend_by_blocks(
+            tile, (t.take(q), t.positions, jnp.asarray(tables)), new,
+            READ["slots"], READ["narrow"],
+            bound=paged.Bound(lens, pool, 1, t.slab, kind.by_head))
+        return pool, jnp.moveaxis(o, 3, 1)      # [S, C, heads, 1, width]
+    got_side, o_side = jax.jit(lambda p: run(side, llama._attend_tile, p))(
+        jnp.asarray(noise))
+    got_head, o_head = jax.jit(
+        lambda p: run(head, gdn_hybrid._attend_tile, p))(
+            jnp.asarray(np.swapaxes(noise, 2, 3)))
+    live = np.arange(C)[None] < n_new[:, None]
+    assert live.any()
+    for n in ("k", "v"):
+        a = np.asarray(got_side[n])
+        assert np.array_equal(np.swapaxes(np.asarray(got_head[n]), 2, 3), a)
+        # layer 0 and every block that no live position fell into are as
+        # they were; the rows that landed are in layer 1
+        was = noise + (n == "v")
+        assert np.array_equal(a[0], was[0])
+        for s, j in zip(*np.nonzero(live)):
+            P = lengths[s] + j
+            at = (1, tables[s, P // BS], P % BS)
+            assert np.array_equal(a[at].reshape(-1), np.asarray(rows[n])[s, j])
+            was[at] = a[at]
+        assert np.array_equal(a, was), n
+    err = np.abs(np.asarray(o_head) - np.asarray(o_side))[live]
+    assert float(err.max()) < 2e-6, plan
 
 
 def test_shardings_ride_the_meshs_own_axes():

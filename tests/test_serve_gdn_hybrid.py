@@ -108,7 +108,10 @@ def test_the_engine_serves_the_references_greedy_tokens_over_rejected_drafts(
     assert delta["row"].shape == (6, 3, width - 1, H * (dk + dv + 2))
     assert set(delta) == {"S", paged.AT, "row"}
     assert engine.cache[M.CONV]["u"].shape == (6, 3, 3 + width, cfg.conv_dim)
-    assert engine.cache[M.KV]["k"].shape == (2, 96, 4, cfg.dim)
+    # keys and values by head inside a block of 4 positions
+    assert engine.cache[M.KV]["k"].shape == (2, 96, cfg.n_heads, 4,
+                                             cfg.head_dim)
+    assert engine.stats()["kv_pool"]["layout"]["kv/k"] == [0, 1, 2, 3, 4]
     prompts = _prompts(cfg)
     reqs = _served(engine, prompts)
     st = engine.stats()

@@ -89,6 +89,22 @@ def _tick_program(cell, C, one_chip):
 
 
 _OPS = r" = \w+(\[[\d,]*\])\S* ([\w-]+)\("    # (dims, op) of every HLO line
+
+
+def _top_level(text):
+    """(dims, op) of the HLO instructions that are device ops of their own:
+    those outside fused computations (a ``copy`` INSIDE a fusion is how its
+    consumer reads an operand, no pass over memory)."""
+    import re
+    out, fused = [], False
+    for line in text.splitlines():
+        if line and not line.startswith(" "):
+            fused = "fused_computation" in line.split("(")[0]
+        elif not fused:
+            out += re.findall(_OPS, line)
+    return out
+
+
 # how a pool is written in place: rows scattered into it (a paged or a ring
 # kind, a carry's snapshots, a committed state), or ONE layer's columns laid
 # over it a slot at a time (a state kind's inputs, a replay kind's ring:
@@ -256,19 +272,27 @@ def test_the_scan_tick_keeps_its_four_pools_where_they_lie(one_chip, C):
 @pytest.mark.parametrize("C", [512, 5])
 def test_the_delta_tick_keeps_one_state_a_slot_in_place(one_chip, C):
     """``serve-gdn-mixedlen``'s two programs: the four full layers' pool
-    (``[4, 3072, 16, 3840]``), the twelve linear layers' conv inputs, their
+    (``[4, 3072, 30, 16, 128]``, keys and values by head inside a block,
+    written whole blocks at a time: models/paged.py ``write_blocks``), the
+    twelve linear layers' conv inputs, their
     ONE committed state a slot (``[12, 16, 1, 30, 192, 96]`` float32, which
     the commit scatters into as ``[12, 16, 30, 192, 96]``, a bitcast away)
     and the ring of rows to replay (``[12, 16, 4, 8700]``: k, v, g and beta
-    side by side) are written in place — rows scattered into the first and
-    the third, a layer's columns laid over the two others a slot at a time —
-    and never relaid, concatenated or copied whole (a read of a layer's
+    side by side) are written in place — blocks scattered into the first,
+    rows into the third, a layer's columns laid over the two others a slot
+    at a time — and never relaid, concatenated or copied whole (a write by
+    row into the pool by head relaid it whole twice a tick, PR 48; a read
+    of a layer's
     columns that is not the one the write reads made the compiler copy the
     35 MB of conv inputs five times a tick, PR 49); the program takes each
     row-major.  NO state a row exists: nothing with the state's ``[30, 192,
     96]`` behind it holds more than the 16 slots' (576 rows of it would be
     1.27 GB; a loop over positions that kept its carries would make them),
-    and nothing is shaped like a slot's whole context of 12,800.  The
+    and nothing is shaped like a slot's whole context of 12,800.  A tile of
+    the full layers' read is scored as the gather left it: no op of its own
+    rewrites, copies or transposes anything of a gathered tile's size
+    (``reshape bf16[2,256,30,128]`` was the cell's fifth costliest device
+    op, PERF.md section 6, PR 50).  The
     recurrence is the chunked form in both: a verify row's chunk of 9 a
     slot, a prompt's chunks of 64, eight at a time, and no solver's custom
     call (``lax.linalg.triangular_solve`` was 47 of a 139 ms wide tick on
@@ -281,13 +305,14 @@ def test_the_delta_tick_keeps_one_state_a_slot_in_place(one_chip, C):
                      "delta/S": "[12,16,1,30,192,96]",
                      "delta/at": "[12,16,1,1]",
                      "delta/row": "[12,16,4,8700]",
-                     "kv/k": "[4,3072,16,3840]", "kv/v": "[4,3072,16,3840]"}
+                     "kv/k": "[4,3072,30,16,128]",
+                     "kv/v": "[4,3072,30,16,128]"}
     # (``at``'s two axes of one element lie where the device likes: 768 B)
     assert {k: v for k, v in layouts.items() if k != "delta/at"} == {
         leaf: tuple(range(pool.count(",") + 1))
         for leaf, pool in pools.items() if leaf != "delta/at"}
     state = "[12,16,30,192,96]"
-    for whole, how in (("[4,3072,16,3840]", "scatter"), (state, "scatter"),
+    for whole, how in ((pools["kv/k"], "scatter"), (state, "scatter"),
                        ("[12,16,8,11520]", _BY_SLOT),
                        ("[12,16,4,8700]", _BY_SLOT)):
         assert (whole, how) in ops, whole
@@ -302,7 +327,20 @@ def test_the_delta_tick_keeps_one_state_a_slot_in_place(one_chip, C):
     assert behind and not [d for d in behind if d[0] != 12
                            and math.prod(d[:-3]) > 16], sorted(set(behind))
     assert not [op for op in ops if op[0].endswith((",12800,3840]",
-                                                    ",800,16,3840]"))]
+                                                    ",12800,30,128]",
+                                                    ",800,30,16,128]"))]
+    # a tile of 16 table entries of 16 positions, two slots' in the first
+    # pass and one slot's in the chunk's, in any order of its axes; side by
+    # side it was [.., 256, 3840] -> [.., 256, 30, 128]
+    tiles = {tuple(sorted(d)) for slots in ((2,), (), (32,), (16,))
+             for d in (slots + (16, 16, 30, 128), slots + (256, 30, 128),
+                       slots + (16, 30, 128), slots + (16, 3840),
+                       slots + (16, 16, 3840), slots + (256, 3840))}
+    shape = lambda d: tuple(sorted(int(n) for n in d[1:-1].split(",")
+                                   if n not in ("", "1")))
+    assert not [(d, op) for d, op in _top_level(texts[C])
+                if op in ("reshape", "copy", "transpose")
+                and shape(d) in tiles]
     # a verify row's chunk a slot; eight of a prompt's chunks at a time
     chunk = {512: "[8,30,64,288]", 5: "[16,30,9,288]"}[C]
     assert [op for op in ops if op[0] == chunk], chunk
